@@ -28,47 +28,11 @@ import argparse
 import contextlib
 import json
 import os
-import subprocess
 import sys
 
 sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
 
 REFERENCE_BEST_TOKENS_PER_SEC_PER_GPU = 18147.0 / 4  # ZeRO-2, 4x A10
-
-# graftcheck preflight scope: the lint rules plus the HLO audit of the arm
-# whose budget guards the headline number (the llama x tp GQA arm — the PR 1
-# resharding regression class). The full roster audit runs in CI and in
-# scripts/run_all_benchmarks.sh; here one representative compile (~10 s on
-# the host CPU) buys the fail-fast without delaying the measured run.
-PREFLIGHT_ARGS = ("--lint", "--audit", "--arms", "llama-tp2-gqa")
-
-
-def run_preflight() -> None:
-    """Run graftcheck in a subprocess; refuse to launch arms on failure.
-
-    A subprocess because the static audit must compile on the CPU backend
-    with its own forced 8-device geometry, while THIS process is about to
-    own the TPU runtime — the two backends must not share a process. The
-    CLI pins its env itself; output goes to stderr (stdout stays reserved
-    for the single JSON result line).
-    """
-    proc = subprocess.run(
-        [
-            sys.executable, "-m",
-            "distributed_llm_training_benchmark_framework_tpu"
-            ".analysis.static", *PREFLIGHT_ARGS,
-        ],
-        cwd=os.path.dirname(os.path.abspath(__file__)),
-        stdout=sys.stderr, stderr=sys.stderr,
-    )
-    if proc.returncode != 0:
-        print(
-            "bench.py: graftcheck preflight FAILED (see above) — refusing "
-            "to launch benchmark arms. Fix the findings, or rerun with "
-            "--skip-preflight to measure anyway.",
-            file=sys.stderr,
-        )
-        sys.exit(2)
 
 # The flagship arm's swept batch geometry (docs/PERFORMANCE.md §16: b2 fills
 # the MXU's M dimension without b4's activation pressure; unrolled beats the
@@ -223,6 +187,11 @@ def _measure_row(args, world, *, model_family, per_device_batch, grad_accum,
         "value": round(per_chip, 2),
         "unit": "tokens/sec/chip",
         "vs_baseline": round(per_chip / REFERENCE_BEST_TOKENS_PER_SEC_PER_GPU, 3),
+        # Where the number was taken, as jax reports it: a value is a
+        # device measurement only when platform says "tpu".
+        "platform": result.platform,
+        "device_kind": result.device_kind,
+        "device_count": result.device_count,
         # Visibility extras (additive; the contract keys above are unchanged):
         # exactly which semantics produced the number, and how far from peak.
         "attention_impl": result.attention_impl,
@@ -278,17 +247,13 @@ def build_parser():
                    choices=["reference", "flash", "ring", "ulysses"])
     p.add_argument("--dropout", type=float, default=None)
     # Hard-sync every N steps instead of every step: totals are identical
-    # (steps are device-sequential), but host RPC latency stays out of the
-    # hot loop — see the timing-discipline note in train/loop.py.
+    # (steps are device-sequential), but per-step host sync latency stays
+    # out of the hot loop — see the timing-discipline note in train/loop.py.
     p.add_argument("--sync-every", type=int, default=10)
     # Unrolled layer loop measures ~15% faster than lax.scan on one chip
     # (no dynamic-update-slice activation stacking); scan remains the
     # harness default for compile time and pipeline runs.
     p.add_argument("--layer-loop", default="unrolled", choices=["scan", "unrolled"])
-    # Static preflight (analysis.static: collective-budget audit + lint)
-    # runs before any arm launches; see run_preflight for scope.
-    p.add_argument("--skip-preflight", action="store_true",
-                   help="skip the graftcheck static preflight gate")
     # Checkpoint cadence (off by default): measure the checkpoint tax —
     # with --checkpoint-async the periodic saves leave the timed path and
     # time_in_checkpoint_sec shows the saving directly.
@@ -351,18 +316,18 @@ def build_parser():
 def main():
     args = build_parser().parse_args()
 
-    if not args.skip_preflight:
-        run_preflight()
-
     from distributed_llm_training_benchmark_framework_tpu.utils.platform import (
         apply_latency_hiding_flags,
-        honor_jax_platforms_env,
+        enable_compile_cache,
+        require_tpu,
     )
 
-    honor_jax_platforms_env()
     if args.xla_latency_hiding:
         # Must precede the first jax backend touch below.
         apply_latency_hiding_flags()
+    enable_compile_cache()
+    # One process per chip: this process measures, and starts no child.
+    require_tpu()
 
     import jax
 
@@ -484,39 +449,36 @@ def registry_rows(args, payload):
 def record_in_registry(args, payload) -> None:
     """Ingest this invocation's rows and report a verdict vs last-good.
 
-    Best-effort by design (telemetry posture): a broken registry must
-    degrade the accounting, never fail the benchmark that just measured.
-    Everything prints to stderr; exceptions are reported, not raised.
+    Runs after the result line is printed, so the measurement is already
+    out; a registry that cannot be read or written then fails the process
+    (non-zero exit) instead of passing as a warning. Everything prints to
+    stderr.
     """
     if args.regress == "off":
         return
-    try:
-        from distributed_llm_training_benchmark_framework_tpu.regress import (
-            compare as regress_compare,
-            store as regress_store,
-        )
+    from distributed_llm_training_benchmark_framework_tpu.regress import (
+        compare as regress_compare,
+        store as regress_store,
+    )
 
-        reg = regress_store.Registry(args.registry)
-        if args.regress == "auto" and not reg.exists():
-            print(
-                f"regress: no registry at {reg.root} — skipping ingest "
-                "(seed one with `regress ingest --legacy`, or pass "
-                "--regress on)", file=sys.stderr,
-            )
-            return
-        for source, row, extra in registry_rows(args, payload):
-            rec = regress_store.record_from_bench_row(
-                row, source=source, extra_result=extra,
-            )
-            rec, created = reg.ingest(rec)
-            tag = "" if created else " (already ingested)"
-            print(f"regress: recorded {rec['arm']} {rec['record_id']}"
-                  f"{tag} -> {reg.root}", file=sys.stderr)
-            print(regress_compare.verdict_line_for_bench(reg, rec),
-                  file=sys.stderr)
-    except Exception as e:  # never fail a measured run on bookkeeping
-        print(f"WARNING: regress registry unavailable: "
-              f"{type(e).__name__}: {e}", file=sys.stderr)
+    reg = regress_store.Registry(args.registry)
+    if args.regress == "auto" and not reg.exists():
+        print(
+            f"regress: no registry at {reg.root} — skipping ingest "
+            "(seed one with `regress ingest --legacy`, or pass "
+            "--regress on)", file=sys.stderr,
+        )
+        return
+    for source, row, extra in registry_rows(args, payload):
+        rec = regress_store.record_from_bench_row(
+            row, source=source, extra_result=extra,
+        )
+        rec, created = reg.ingest(rec)
+        tag = "" if created else " (already ingested)"
+        print(f"regress: recorded {rec['arm']} {rec['record_id']}"
+              f"{tag} -> {reg.root}", file=sys.stderr)
+        print(regress_compare.verdict_line_for_bench(reg, rec),
+              file=sys.stderr)
 
 
 if __name__ == "__main__":

@@ -20,22 +20,3 @@ Subpackages
 """
 
 __version__ = "0.1.0"
-
-# Older jax runtimes (0.4.x) lack a few new public API names the codebase
-# targets (set_mesh, shard_map, typeof, get_abstract_mesh); install the
-# equivalence shims before any subpackage import can touch them. No-op on
-# current jax — and skipped entirely when jax is not installed at all, so
-# the pure-stdlib analysis CLIs (validate_results, parse_metrics) keep
-# working on scrape-and-validate machines without a jax install.
-try:
-    from .utils import jax_compat as _jax_compat
-
-    _jax_compat.install()
-    del _jax_compat
-except ModuleNotFoundError as _e:
-    # Swallow ONLY "jax is not installed"; a partially-installed jax whose
-    # submodules fail mid-install must fail loudly here, not as an
-    # unexplained AttributeError at first use.
-    if _e.name != "jax":
-        raise
-    del _e

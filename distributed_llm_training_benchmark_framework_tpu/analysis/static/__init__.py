@@ -15,9 +15,10 @@ distributed_llm_training_benchmark_framework_tpu.analysis.static``):
   each with an id, a fix hint, and ``# graftcheck: disable=RULE``
   suppression.
 
-Both run as a preflight gate in ``bench.py`` and
-``scripts/run_all_benchmarks.sh`` (see ``scripts/graftcheck.sh``) and as the
-tier-1 module ``tests/test_graftcheck.py``. Docs: ``docs/STATIC_ANALYSIS.md``.
+Both run as a CLI (``scripts/graftcheck.sh``; ``scripts/run_all_benchmarks.sh``
+calls it before a suite) and as the tier-1 module
+``tests/test_graftcheck.py`` — never on the measured path of ``bench.py``.
+Docs: ``docs/STATIC_ANALYSIS.md``.
 """
 
 from .hlo_audit import (  # noqa: F401

@@ -8,8 +8,8 @@ Exit codes: 0 clean, 1 findings (budget deltas / lint violations),
 The audit engine is only meaningful under the conditions the budgets were
 frozen on — the CPU backend with 8 forced host devices — so this entry
 point pins both BEFORE jax initializes a backend, regardless of the
-caller's env (bench.py runs it as a TPU-process subprocess; the k8s image
-via scripts/graftcheck.sh). The budgets file records the freeze conditions
+caller's env (the k8s image runs it via scripts/graftcheck.sh; nothing in
+the package imports jax before this point). The budgets file records the freeze conditions
 and the audit refuses to compare across a jax-version mismatch.
 """
 
@@ -31,22 +31,6 @@ def _force_cpu_audit_env() -> None:
     else:
         flags = (flags + " " + want).strip()
     os.environ["XLA_FLAGS"] = flags
-
-    from ...utils.platform import honor_jax_platforms_env
-
-    honor_jax_platforms_env()
-    import jax
-
-    jax.config.update("jax_platforms", "cpu")
-    try:
-        from jax._src import xla_bridge as _xb
-
-        if _xb.backends_are_initialized():
-            from jax.extend.backend import clear_backends
-
-            clear_backends()
-    except Exception:
-        pass
 
 
 def _git_changed_files():

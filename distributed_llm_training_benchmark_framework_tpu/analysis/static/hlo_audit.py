@@ -213,19 +213,10 @@ def lower_arm(spec: ArmSpec, devices=None):
     Pure compiler work — no params are initialized and no device memory is
     allocated. Needs ``prod(mesh_shape)`` visible devices (the CLI forces
     8 virtual CPU devices; in-process callers run under the test mesh).
-    The lowering path goes through ``train.step`` and therefore through the
-    ``utils.jax_compat`` polyfills (``jax.set_mesh`` et al.), so it stays
-    green on the image's jax 0.4.37; ``jax.sharding.AbstractMesh`` lowering
-    is not used because the collective schedule only exists in the
-    POST-partitioning executable, which requires a concrete backend to
-    build.
+    ``jax.sharding.AbstractMesh`` lowering is not used because the
+    collective schedule only exists in the POST-partitioning executable,
+    which requires a concrete backend to build.
     """
-    # Idempotent: a strict no-op when the package import already installed
-    # the shims or the runtime has the real APIs.
-    from ...utils import jax_compat
-
-    jax_compat.install()
-
     import jax
 
     from ...parallel import get_strategy, make_mesh

@@ -24,8 +24,15 @@ kernels. Which is faster is S-dependent on v5e (einsum to S=2048, kernels
 from S=4096 with margins growing to +88% at 16K — docs/PERFORMANCE.md §12);
 ``pallas_backward=None`` auto-selects by the measured crossover.
 
-On non-TPU backends the forward kernel runs in Pallas interpret mode (slow but
-bit-honest), keeping the CPU test/smoke paths real.
+Dispatch (``_resolve_interpret``): on a TPU backend the Mosaic kernels are the
+only path — interpret mode is refused there, and the ``jnp`` fallbacks below
+are reachable only in interpret mode. On other backends the kernels run in
+Pallas interpret mode (slow but bit-honest), keeping the CPU test paths real.
+
+Partitioning: a Mosaic kernel is a custom call GSPMD cannot split, so under a
+mesh of more than one device ``flash_attention`` shard_maps itself, batch and
+heads split over the axes that shard them (see ``_kernel_mesh_axes``). The dropout hash is keyed by GLOBAL
+(batch, head) ids fed in as sharded data, so masks do not depend on the mesh.
 """
 
 from __future__ import annotations
@@ -39,6 +46,7 @@ import numpy as np
 from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+from jax.sharding import PartitionSpec as P
 
 NEG_INF = -1e30
 
@@ -125,7 +133,8 @@ _PALLAS_BWD_MIN_SEQ = 4096
 
 
 def _flash_fwd_kernel(
-    seed_ref, q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr,
+    seed_ref, bhv_ref, q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr,
+    acc_scr,
     *, bq: int, bk: int, scale: float, causal: bool,
     seq_len: int, dropout_rate: float,
 ):
@@ -178,7 +187,7 @@ def _flash_fwd_kernel(
         # exact), while the output accumulator sees the dropped+rescaled p.
         if dropout_rate > 0.0:
             keep = _dropout_keep(
-                seed_ref[0], bh, rows, cols,
+                seed_ref[0], bhv_ref[bh], rows, cols,
                 _dropout_threshold(dropout_rate),
             )
             p_acc = jnp.where(keep, p * (1.0 / (1.0 - dropout_rate)), 0.0)
@@ -222,14 +231,15 @@ def _vma_struct(shape, dtype, *like):
 
 def _jnp_reference_forward(
     q: jax.Array, k: jax.Array, v: jax.Array,
-    causal: bool, dropout_rate: float, seed: jax.Array,
+    causal: bool, dropout_rate: float, seed: jax.Array, bhv: jax.Array,
 ) -> Tuple[jax.Array, jax.Array]:
     """Materialized-softmax forward with the kernel's exact mask/accumulation
     semantics (same ``_dropout_keep`` coordinates, same un-dropped normalizer),
-    for contexts where the Pallas HLO interpreter cannot run — currently
-    vma-carrying manual regions on CPU (the interpreter's internal
-    dynamic_slice rejects mixed varying/invariant operands). Returns
-    (out, lse) exactly as ``_flash_forward`` does."""
+    for contexts where the Pallas HLO interpreter cannot run — vma-carrying
+    manual regions in interpret mode (the interpreter's internal
+    dynamic_slice rejects mixed varying/invariant operands). Never reached
+    on a TPU backend (``_resolve_interpret``). Returns (out, lse) exactly as
+    ``_flash_forward`` does."""
     BH, S, D = q.shape
     scale = 1.0 / (D ** 0.5)
     s = jnp.einsum(
@@ -245,9 +255,9 @@ def _jnp_reference_forward(
         p = jnp.where((rows >= cols)[None], p, 0.0)
     l = jnp.sum(p, axis=-1, keepdims=True)
     if dropout_rate > 0.0:
-        bh = jnp.arange(BH, dtype=jnp.uint32)[:, None, None]
         keep = _dropout_keep(
-            seed[0], bh, rows[None], cols[None], _dropout_threshold(dropout_rate)
+            seed[0], bhv[:, None, None], rows[None], cols[None],
+            _dropout_threshold(dropout_rate),
         )
         p_acc = jnp.where(keep, p * (1.0 / (1.0 - dropout_rate)), 0.0)
     else:
@@ -265,18 +275,20 @@ def _jnp_reference_forward(
 def _flash_forward(
     q: jax.Array, k: jax.Array, v: jax.Array,
     causal: bool, interpret: bool, bq: int, bk: int,
-    dropout_rate: float = 0.0, seed: Optional[jax.Array] = None,
+    dropout_rate: float, seed: jax.Array, bhv: jax.Array,
 ) -> Tuple[jax.Array, jax.Array]:
-    """Run the Pallas kernel on (BH, S, D) inputs -> (out, lse)."""
+    """Run the Pallas kernel on (BH, S, D) inputs -> (out, lse). ``bhv`` is
+    the (BH,) int32 vector of GLOBAL batch*head ids keying the dropout hash
+    (arange(BH) on one device; mesh-global ids under a shard_map)."""
     BH, S, D = q.shape
     scale = 1.0 / (D ** 0.5)
     grid = (BH, S // bq, S // bk)
-    if seed is None:
-        seed = jnp.zeros((1,), jnp.uint32)
     from ..utils.vma import vma_of
 
     if interpret and vma_of(q, k, v):
-        return _jnp_reference_forward(q, k, v, causal, dropout_rate, seed)
+        return _jnp_reference_forward(
+            q, k, v, causal, dropout_rate, seed, bhv
+        )
     out, lse = pl.pallas_call(
         functools.partial(
             _flash_fwd_kernel, bq=bq, bk=bk, scale=scale, causal=causal,
@@ -289,6 +301,7 @@ def _flash_forward(
         grid=grid,
         in_specs=[
             pl.BlockSpec(memory_space=pltpu.SMEM),  # dropout seed (1,) uint32
+            pl.BlockSpec(memory_space=pltpu.SMEM),  # global bh ids (BH,)
             pl.BlockSpec((1, bq, D), lambda b, qi, ki: (b, qi, 0)),
             pl.BlockSpec((1, bk, D), lambda b, qi, ki: (b, ki, 0)),
             pl.BlockSpec((1, bk, D), lambda b, qi, ki: (b, ki, 0)),
@@ -306,23 +319,28 @@ def _flash_forward(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
-    )(seed, q, k, v)
+    )(seed, bhv, q, k, v)
     return out, lse[:, 0, :]
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
 def _flash(
-    opts: Tuple, q: jax.Array, k: jax.Array, v: jax.Array, seed: jax.Array
+    opts: Tuple, q: jax.Array, k: jax.Array, v: jax.Array, seed: jax.Array,
+    bhv: jax.Array,
 ) -> jax.Array:
     causal, interpret, bq, bk, _, _, rate = opts
-    out, _ = _flash_forward(q, k, v, causal, interpret, bq, bk, rate, seed)
+    out, _ = _flash_forward(
+        q, k, v, causal, interpret, bq, bk, rate, seed, bhv
+    )
     return out
 
 
-def _flash_fwd_rule(opts, q, k, v, seed):
+def _flash_fwd_rule(opts, q, k, v, seed, bhv):
     causal, interpret, bq, bk, _, _, rate = opts
-    out, lse = _flash_forward(q, k, v, causal, interpret, bq, bk, rate, seed)
-    return out, (q, k, v, out, lse, seed)
+    out, lse = _flash_forward(
+        q, k, v, causal, interpret, bq, bk, rate, seed, bhv
+    )
+    return out, (q, k, v, out, lse, seed, bhv)
 
 
 def _bwd_dq_kernel(
@@ -482,7 +500,7 @@ def _jnp_blockwise_bwd(causal, bk, rate, res, do):
     forward kernel, so the decomposition mismatch (fwd 1024-wide tiles, bwd
     ``bk``-wide) is invisible.
     """
-    q, k, v, out, lse, seed = res
+    q, k, v, out, lse, seed, bhv = res
     BH, S, D = q.shape
     scale = 1.0 / (D ** 0.5)
     f32 = jnp.float32
@@ -497,7 +515,6 @@ def _jnp_blockwise_bwd(causal, bk, rate, res, do):
     vs = v.reshape(BH, nk, bk, D).transpose(1, 0, 2, 3)
     rows = jnp.arange(S)
     threshold = _dropout_threshold(rate)
-    bh_idx = jnp.arange(BH)
 
     def one_block(dq_acc, blk):
         ki, k_b, v_b = blk
@@ -511,7 +528,7 @@ def _jnp_blockwise_bwd(causal, bk, rate, res, do):
             p = jnp.where(mask[None], p, 0.0)
         if rate > 0.0:
             keep = _dropout_keep(
-                seed[0], bh_idx[:, None, None], rows[None, :, None],
+                seed[0], bhv[:, None, None], rows[None, :, None],
                 cols[None, None, :], threshold,
             )  # (BH, S, bk)
             inv = 1.0 / (1.0 - rate)
@@ -549,17 +566,21 @@ def _flash_bwd_rule(opts, res, do):
     hand-written Pallas kernel pair (dq; dk/dv) below.
     """
     causal, interpret, bq, bk_fwd, bk, pallas_bwd, rate = opts
-    seed_ct = np.zeros((1,), jax.dtypes.float0)  # seed is integral: no tangent
+    # seed and the bh ids are integral: no tangent.
+    int_cts = (
+        np.zeros((1,), jax.dtypes.float0),
+        np.zeros(res[6].shape, jax.dtypes.float0),
+    )
     from ..utils.vma import vma_of
 
     if pallas_bwd and interpret and vma_of(*res[:3], do):
         # Same limitation the forward's _jnp_reference_forward fallback works
         # around: the Pallas HLO interpreter cannot run on vma-carrying
-        # operands (seq-manual pipeline on CPU) — take the jnp backward.
+        # operands (manual regions in interpret mode) — take the jnp backward.
         pallas_bwd = False
     if not pallas_bwd:
-        return (*_jnp_blockwise_bwd(causal, bk, rate, res, do), seed_ct)
-    q, k, v, out, lse, seed = res
+        return (*_jnp_blockwise_bwd(causal, bk, rate, res, do), *int_cts)
+    q, k, v, out, lse, seed, bhv = res
     BH, S, D = q.shape
     scale = 1.0 / (D ** 0.5)
 
@@ -573,11 +594,9 @@ def _flash_bwd_rule(opts, res, do):
 
     seed_spec = pl.BlockSpec(memory_space=pltpu.SMEM)
     # Plain flash = the shared tile-base-aware kernels at identity bases
-    # (tile i starts at row i*b) with an identity batch*head index vector
-    # (ring attention feeds global ones).
+    # (tile i starts at row i*b); ring attention feeds shard offsets.
     qoffs = jnp.arange(S // bq, dtype=jnp.int32) * bq
     koffs = jnp.arange(S // bk, dtype=jnp.int32) * bk
-    bhv = jnp.arange(BH, dtype=jnp.int32)
     row_specs = dict(
         q=pl.BlockSpec((1, bq, D), lambda b, qi, ki: (b, qi, 0)),
         k=pl.BlockSpec((1, bk, D), lambda b, qi, ki: (b, ki, 0)),
@@ -630,10 +649,61 @@ def _flash_bwd_rule(opts, res, do):
         interpret=interpret,
     )(seed, qoffs, koffs, bhv, q, k, v, do, lse3, delta3)
 
-    return dq, dk, dv, seed_ct
+    return dq, dk, dv, *int_cts
 
 
 _flash.defvjp(_flash_fwd_rule, _flash_bwd_rule)
+
+
+def _resolve_interpret(interpret: Optional[bool]) -> bool:
+    """Kernel-mode dispatch shared by flash and ring attention.
+
+    ``None`` means "what the backend needs": the Mosaic kernel on a TPU,
+    Pallas interpret mode elsewhere (the CPU tests). Asking for interpret
+    mode on a TPU backend is refused rather than honoured — a chip run must
+    never measure the interpreter or the ``jnp`` fallbacks behind it.
+    """
+    on_tpu = jax.default_backend() == "tpu"
+    if interpret is None:
+        return not on_tpu
+    if interpret and on_tpu:
+        raise ValueError(
+            "interpret=True on a TPU backend: the chip path runs the Mosaic "
+            "kernels only (interpret mode is for CPU tests)"
+        )
+    return interpret
+
+
+#: Mesh axes the model shards attention's batch dim over
+#: (parallel.strategies.batch_partition_spec) and its head dim over
+#: (Megatron tensor parallelism) — the same names ring_attention composes
+#: with.
+_BATCH_AXES = ("data", "expert")
+_HEADS_AXIS = "model"
+
+
+def _kernel_mesh_axes():
+    """(manual axes, batch axes, heads axis) for the kernel's shard_map.
+
+    A Mosaic kernel is a custom call: GSPMD cannot partition it, and jax
+    refuses to lower one anywhere but a FULLY manual region ("Mosaic
+    kernels cannot be automatically partitioned"). So whenever the context
+    mesh spans more than one device the call goes manual over every axis,
+    with the batch and head dims split over the axes that shard them.
+    ``manual`` is empty — the call lowers bare, as it always has — on one
+    device, without a mesh context, and inside an enclosing shard_map,
+    which owns the layout (Ulysses is fully manual already; a nested
+    shard_map under the partially-manual pipeline schedules does not
+    survive jax's transpose, so pipeline x flash on several chips stays
+    refused by jax itself).
+    """
+    m = jax.sharding.get_abstract_mesh()
+    split = [n for n in m.axis_names if m.shape[n] > 1]
+    if m.manual_axes or not split:
+        return frozenset(), (), None
+    batch = tuple(a for a in _BATCH_AXES if a in split)
+    heads = _HEADS_AXIS if _HEADS_AXIS in split else None
+    return frozenset(m.axis_names), batch, heads
 
 
 @functools.partial(
@@ -668,10 +738,14 @@ def flash_attention(
     stateless hash of absolute coordinates, so fwd/bwd agree despite their
     different tilings. With ``dropout_seed=None`` the rate is ignored and a
     warning is emitted (the model's deterministic/no-key dropout convention).
+
+    Under a mesh context that spans several devices the call shard_maps
+    itself (``_kernel_mesh_axes``); the (batch, head) ids the
+    dropout hash sees enter as sharded iotas, so each shard hashes its
+    GLOBAL ids and the loss matches a one-device run of the same batch.
     """
     B, S, H, D = q.shape
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+    interpret = _resolve_interpret(interpret)
     if pallas_backward is None:
         # Auto: the measured S-dependent crossover (_PALLAS_BWD_MIN_SEQ).
         # Interpret mode keeps the einsum backward — the Pallas bwd kernels
@@ -693,16 +767,34 @@ def flash_attention(
         seed = jnp.asarray(dropout_seed, jnp.uint32).reshape((1,))
     if not 0.0 <= dropout_rate < 1.0:
         raise ValueError(f"dropout_rate must be in [0, 1), got {dropout_rate}")
+    opts = (causal, interpret, bq, bk, bk_bwd, pallas_backward, dropout_rate)
 
-    # (B, S, H, D) -> (B*H, S, D): one grid row per (batch, head) pair.
-    def to_bhsd(t):
-        return t.transpose(0, 2, 1, 3).reshape(B * H, S, D)
+    def local(ql, kl, vl, seed_l, b_ids, h_ids):
+        # (Bl, S, Hl, D) -> (Bl*Hl, S, D): one grid row per (batch, head)
+        # pair, keyed for dropout by its global id b*H + h.
+        Bl, Hl = ql.shape[0], ql.shape[2]
 
-    out = _flash(
-        (causal, interpret, bq, bk, bk_bwd, pallas_backward, dropout_rate),
-        to_bhsd(q), to_bhsd(k), to_bhsd(v), seed,
-    )
-    return out.reshape(B, H, S, D).transpose(0, 2, 1, 3)
+        def to_bhsd(t):
+            return t.transpose(0, 2, 1, 3).reshape(Bl * Hl, S, D)
+
+        bhv = (b_ids[:, None] * H + h_ids[None, :]).reshape(Bl * Hl)
+        out = _flash(opts, to_bhsd(ql), to_bhsd(kl), to_bhsd(vl), seed_l, bhv)
+        return out.reshape(Bl, Hl, S, D).transpose(0, 2, 1, 3)
+
+    b_ids = jnp.arange(B, dtype=jnp.int32)
+    h_ids = jnp.arange(H, dtype=jnp.int32)
+    manual, batch_axes, heads_axis = _kernel_mesh_axes()
+    if not manual:
+        return local(q, k, v, seed, b_ids, h_ids)
+    # The id vectors ride in as P(axis)-sharded iotas: each shard's slice IS
+    # its global ids, whatever the order of the axes that split the dim.
+    spec = P(batch_axes or None, None, heads_axis, None)
+    return jax.shard_map(
+        local,
+        in_specs=(spec, spec, spec, P(), P(batch_axes or None), P(heads_axis)),
+        out_specs=spec,
+        axis_names=manual,
+    )(q, k, v, seed, b_ids, h_ids)
 
 
 def reference_attention(q, k, v, causal: bool = False) -> jax.Array:

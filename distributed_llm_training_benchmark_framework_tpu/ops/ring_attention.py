@@ -63,6 +63,7 @@ from .flash_attention import (
     _dropout_keep,
     _dropout_threshold,
     _pick_block,
+    _resolve_interpret,
     _vma_struct,
     _warn_seedless_dropout,
     _FWD_BLOCK_Q,
@@ -667,11 +668,13 @@ def ring_attention_sharded(
     in the contiguous layout, and global coordinates keep dropout masks
     bit-identical to flash. Pass ``zigzag=False`` to force contiguous.
 
-    On TPU each ring hop runs the Pallas flash block kernel (VMEM-resident
-    score tiles); elsewhere (CPU test meshes, where the Pallas interpreter
-    cannot run inside vma-carrying manual regions) an einsum path with
-    identical semantics. Gradients flow through a custom VJP that makes a
-    second ring pass (see module docstring).
+    On a TPU backend each ring hop runs the Pallas flash block kernel
+    (VMEM-resident score tiles) and nothing else; on other backends (CPU test
+    meshes, where the Pallas interpreter cannot run inside vma-carrying
+    manual regions) an einsum path with identical semantics — one rule for
+    flash and ring, ``flash_attention._resolve_interpret``. Gradients flow
+    through a custom VJP that makes a second ring pass (see module
+    docstring).
     """
     B, Sl, H, D = q.shape
     if dropout_seed is None:
@@ -680,7 +683,7 @@ def ring_attention_sharded(
         seed = jnp.zeros((1,), jnp.uint32)
     else:
         seed = jnp.asarray(dropout_seed, jnp.uint32).reshape((1,))
-    interpret = jax.default_backend() != "tpu"
+    interpret = _resolve_interpret(None)
     n = lax.axis_size(axis_name)
     if zigzag is None:
         zig = causal and n > 1 and Sl % 2 == 0

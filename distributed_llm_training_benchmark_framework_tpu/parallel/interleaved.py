@@ -320,9 +320,7 @@ def interleaved_loss_and_grads(
             f"n_layer={config.n_layer} not divisible by pipe*virtual="
             f"{n_stages}*{V}"
         )
-    config, seq_ax, sp, data_ax, dp, manual_axes, batch_spec = _seq_setup(
-        config, mesh
-    )
+    config, seq_ax, sp, manual_axes, batch_spec = _seq_setup(config, mesh)
     # See the module docstring: XLA:CPU's collective rendezvous spans all
     # local devices per instruction, so 'seq' collectives inside the
     # device-varying switch deadlock there. Run all unit kinds and mask.
@@ -336,24 +334,13 @@ def interleaved_loss_and_grads(
     sched = build_schedule(n_stages, V, n_micro)
     perm_fwd = [(i, (i + 1) % n_stages) for i in range(n_stages)]
     perm_bwd = [(i, (i - 1) % n_stages) for i in range(n_stages)]
-    # Mean over microbatches AND manual data shards (dp=1 when 'data' is
-    # auto); the hand-seeded loss cotangent uses the same scale.
-    inv_m = 1.0 / (n_micro * dp)
+    # Mean over microbatches; the hand-seeded loss cotangent uses the same
+    # scale.
+    inv_m = 1.0 / n_micro
+    # Head/embed grads and activations vary over every manual axis; scalar
+    # (loss/aux) terms over 'pipe' only — CE/aux are already seq-invariant
+    # when sp>1 (psum'd inside).
     var_axes = (AXIS,) + ((seq_ax,) if seq_ax else ())
-    # Scalar (loss/aux) reductions: CE/aux are already seq-invariant when
-    # sp>1 (psum'd inside), so they span pipe + the manual data axis.
-    reduce_axes = (AXIS,) + ((data_ax,) if data_ax else ())
-    # Parameter-grad reductions: var_axes plus the manual data axis (on
-    # vma runtimes data stays auto and this equals var_axes exactly).
-    grad_axes = var_axes + ((data_ax,) if data_ax else ())
-    # Legacy cotangent-seed scale — pre-vma jax transposes psum to psum,
-    # so differentiating through the CE/aux internal 'seq' psum inflates a
-    # hand-seeded cotangent by sp; seed 1/sp to cancel (the explicit
-    # grad psums below restore the cross-shard sums). See the identical
-    # note in pipeline.pipeline_loss_and_grads_1f1b.
-    from .pipeline import _legacy_partial_auto
-
-    ct_scale = 1.0 / sp if (_legacy_partial_auto() and sp > 1) else 1.0
     moe = config.n_experts > 0
     key_data = _key_data_or_none(base_key)
 
@@ -397,7 +384,7 @@ def interleaved_loss_and_grads(
         # needed (unlike the lockstep schedules' fill/drain ticks).
         aux_sum = var_p(jnp.zeros((), jnp.float32))
         aux_ct_const = (
-            config.router_aux_coef * ct_scale / (config.n_layer * n_micro * dp)
+            config.router_aux_coef / (config.n_layer * n_micro)
             if moe else 0.0
         )
 
@@ -535,7 +522,7 @@ def interleaved_loss_and_grads(
                         )
                         return l, aux
                     (l, aux_p), vjp = jax.vjp(fn, blk_c, hp_in, x_saved)
-                    dl = var_p(jnp.asarray(inv_m * ct_scale, jnp.float32))
+                    dl = var_p(jnp.asarray(inv_m, jnp.float32))
                     d_blk, d_hp_t, d_x = vjp(
                         (dl, jnp.zeros_like(aux_p) + aux_ct_const)
                     )
@@ -618,27 +605,18 @@ def interleaved_loss_and_grads(
         carry, _ = lax.scan(tick, carry, xs)
 
         (_, _, _, _, _, d_blocks, d_hp, d_ep, loss_sum, aux_sum) = carry
-        loss = lax.psum(loss_sum, reduce_axes) * inv_m
+        loss = lax.psum(loss_sum, AXIS) * inv_m
         if moe:
             # Every (microbatch, chunk) contributed its layers' aux exactly
             # once; normalize as gpipe/1f1b do: coef * mean per layer per
-            # microbatch (averaged over manual data shards when present).
+            # microbatch.
             loss = loss + config.router_aux_coef * lax.psum(
-                aux_sum, reduce_axes
-            ) / (config.n_layer * n_micro * dp)
-        d_hp = jax.tree.map(lambda x: lax.psum(x, grad_axes), d_hp)
-        d_ep = jax.tree.map(lambda x: lax.psum(x, grad_axes), d_ep)
-        blk_axes = tuple(
-            a for a in (data_ax, seq_ax if ct_scale != 1.0 else None) if a
-        )
-        if blk_axes:
-            # Block grads stay per-stage (out_spec P('pipe', ...)) but sum
-            # across the manual data shards' local batches — and across
-            # 'seq' on the legacy runtime, where the 1/sp-scaled seeds
-            # leave per-shard partials (vma runtimes reduce implicitly).
-            d_blocks = jax.tree.map(
-                lambda x: lax.psum(x, blk_axes), d_blocks
-            )
+                aux_sum, AXIS
+            ) / (config.n_layer * n_micro)
+        d_hp = jax.tree.map(lambda x: lax.psum(x, var_axes), d_hp)
+        d_ep = jax.tree.map(lambda x: lax.psum(x, var_axes), d_ep)
+        # Block grads stay per-stage (out_spec P('pipe', ...)); their 'seq'
+        # sum happens implicitly inside the vjp.
         grads = {"blocks": d_blocks}
         for _dtree in (d_hp, d_ep):  # wte appears in both when tied: sum
             for _k, _v in _dtree.items():

@@ -40,14 +40,13 @@ from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
 
 from ..models import tinygpt
-from ..utils.vma import pcast_varying
+from ..utils.vma import pcast_missing
 
 AXIS = "pipe"
 
 
-#: The pipeline compile-fix switch: the typed-key boundary crossing AND
-#: the legacy data-manual lowering are two halves of the same repair of
-#: the seed-old pipeline compile failures. graftcheck's ``--inject
+#: The pipeline compile-fix switch: the typed-key boundary crossing that
+#: repaired the seed-old pipeline compile failures. graftcheck's ``--inject
 #: bad-pipeline-spec`` flips this off to resurrect the original lowering
 #: (typed key closed over a partial-auto shard_map beside a REAL auto
 #: 'data' axis -> the u32 tile-assignment XLA rejection) and prove the
@@ -58,14 +57,13 @@ _TYPED_KEY_BOUNDARY_FIX = True
 def _key_data_or_none(base_key):
     """Raw uint32 key data for a typed PRNG key (None passes through).
 
-    Typed key arrays must not cross the ``shard_map`` boundary here — on
-    pre-vma runtimes the partial-auto lowering builds the boundary sharding
-    from the rank-0 key aval but validates it against the rank-1 physical
+    Typed key arrays must not cross the ``shard_map`` boundary here — the
+    partial-auto lowering can build the boundary sharding
+    from the rank-0 key aval but validate it against the rank-1 physical
     u32 key data, which XLA rejects ("Number of tile assignment dimensions
     ... is different than the input rank", the seed-old interleaved compile
     failure). Raw key data is an ordinary u32 array whose rank the boundary
-    handles on every runtime; the body rebuilds the key with
-    :func:`_rebuild_key`.
+    always handles; the body rebuilds the key with :func:`_rebuild_key`.
     """
     if not _TYPED_KEY_BOUNDARY_FIX:
         return base_key
@@ -87,26 +85,11 @@ def _stage_iota(n_stages: int) -> jax.Array:
     ``lax.axis_index`` inside a PARTIALLY-manual region lowers to a bare
     partition-id instruction that XLA's SPMD partitioner refuses whenever a
     real auto axis exists ("PartitionId instruction is not supported for
-    SPMD partitioning"), which broke every pipeline x dp>1 composition on
-    the pre-vma runtime. A sharded iota derives the same value from data:
-    each stage's local shard of arange(P) is exactly its stage index.
+    SPMD partitioning"), which broke pipeline x dp>1 compositions. A
+    sharded iota derives the same value from data: each stage's local shard
+    of arange(P) is exactly its stage index.
     """
     return jnp.arange(n_stages, dtype=jnp.int32)
-
-
-def _legacy_partial_auto() -> bool:
-    """True on pre-vma runtimes (no ``lax.pcast``), where the legacy
-    partial-auto shard_map lowering cannot partition a REAL (size>1) auto
-    axis around the pipeline's collectives: a ppermute beside a >1 auto
-    axis dies in XLA's SPMD partitioner (manual-subgroup CHECK failure),
-    and ``lax.axis_index`` lowers to a bare partition-id the partitioner
-    refuses. Size-1 auto axes are fine (the sp ring arms run that shape),
-    so on these runtimes the pipeline region additionally goes manual over
-    'data' and the schedules reduce over it explicitly — the same
-    reductions GSPMD would have inserted for an auto data axis."""
-    from jax import lax as _lax
-
-    return not hasattr(_lax, "pcast")
 
 
 def _seq_setup(config: tinygpt.TinyGPTConfig, mesh: Mesh):
@@ -116,40 +99,15 @@ def _seq_setup(config: tinygpt.TinyGPTConfig, mesh: Mesh):
     activations hold local sequence chunks, attention runs the sharded
     ring/Ulysses bodies communicating over 'seq' (see
     tinygpt.TinyGPTConfig.seq_manual_axis), and losses/aux psum over 'seq'.
+    'data' (and 'model'/'expert') stay auto: GSPMD owns their reductions.
 
-    Data parallel on legacy runtimes (:func:`_legacy_partial_auto`): a >1
-    'data' axis ALSO goes manual — each shard runs the schedule on its
-    local microbatch rows and the schedules psum losses/grads over
-    'data' explicitly (scaled by ``dp`` for the means). On vma runtimes
-    'data' stays auto and ``data_ax`` is None — byte-identical lowering to
-    before.
-
-    Returns (config, seq_axis_or_None, sp, data_axis_or_None, dp,
-    manual_axes, batch_in_spec) — ``dp`` is the data-shard count the
-    schedule must normalize its means by, so it is 1 whenever 'data'
-    stays auto (GSPMD owns the normalization there).
+    Returns (config, seq_axis_or_None, sp, manual_axes, batch_in_spec).
     """
     sp = mesh.shape.get("seq", 1)
-    seq_ax = None
-    manual = {AXIS}
-    if sp > 1:
-        config = dataclasses.replace(config, seq_manual_axis="seq")
-        seq_ax = "seq"
-        manual.add("seq")
-    data_ax = None
-    dp = 1
-    if (
-        mesh.shape.get("data", 1) > 1 and _legacy_partial_auto()
-        and _TYPED_KEY_BOUNDARY_FIX
-    ):
-        data_ax = "data"
-        dp = mesh.shape["data"]
-        manual.add("data")
-    if seq_ax is None and data_ax is None:
-        batch_spec = P()
-    else:
-        batch_spec = P(None, data_ax, seq_ax)
-    return config, seq_ax, sp, data_ax, dp, frozenset(manual), batch_spec
+    if sp <= 1:
+        return config, None, sp, frozenset({AXIS}), P()
+    config = dataclasses.replace(config, seq_manual_axis="seq")
+    return config, "seq", sp, frozenset({AXIS, "seq"}), P(None, None, "seq")
 
 
 def pipeline_param_specs(params, mesh: Mesh):
@@ -178,18 +136,12 @@ def pipeline_loss_fn(
         raise ValueError(
             f"n_layer={config.n_layer} not divisible by pipe={n_stages}"
         )
-    config, seq_ax, sp, data_ax, dp, manual_axes, batch_spec = _seq_setup(
-        config, mesh
-    )
+    config, seq_ax, sp, manual_axes, batch_spec = _seq_setup(config, mesh)
     layers_per_stage = config.n_layer // n_stages
     n_micro = batch.shape[0]
     ticks = n_micro + n_stages - 1
     perm = [(i, (i + 1) % n_stages) for i in range(n_stages)]
     key_data = _key_data_or_none(base_key)
-    # Axes the scalar reductions span: 'pipe' always; 'data' too when the
-    # legacy runtime made it manual (each shard saw 1/dp of the batch, so
-    # the psum'd means divide by dp).
-    reduce_axes = (AXIS,) + ((data_ax,) if data_ax else ())
 
     def staged(params, batch, stage_arr):
         stage = stage_arr[0]
@@ -261,7 +213,7 @@ def pipeline_loss_fn(
                         ),
                         # pcast marks the zero as device-varying over 'pipe'
                         # so both branches carry the same manual-axes type.
-                        lambda: pcast_varying(
+                        lambda: pcast_missing(
                             jnp.zeros((), jnp.float32), (AXIS,)
                         ),
                     )
@@ -269,17 +221,16 @@ def pipeline_loss_fn(
             if t < ticks - 1:
                 state = lax.ppermute(state_out, AXIS, perm)
 
-        # Only the last stage accumulated loss; broadcast it to every stage
-        # (and average across data shards when 'data' is manual).
-        loss = lax.psum(loss_sum, reduce_axes) / (n_micro * dp)
+        # Only the last stage accumulated loss; broadcast it to every stage.
+        loss = lax.psum(loss_sum, AXIS) / n_micro
         if config.n_experts > 0:
             # Every (stage, microbatch) pair contributed its layers' aux once:
             # psum over stages = sum over all n_layer layers for all M
             # microbatches. Same normalization as tinygpt.forward
             # (coef * aux / n_layer), averaged over microbatches.
             loss = loss + config.router_aux_coef * lax.psum(
-                aux_sum, reduce_axes
-            ) / (config.n_layer * n_micro * dp)
+                aux_sum, AXIS
+            ) / (config.n_layer * n_micro)
         return loss
 
     fn = jax.shard_map(
@@ -336,35 +287,18 @@ def pipeline_loss_and_grads_1f1b(
         raise ValueError(
             f"n_layer={config.n_layer} not divisible by pipe={n_stages}"
         )
-    config, seq_ax, sp, data_ax, dp, manual_axes, batch_spec = _seq_setup(
-        config, mesh
-    )
+    config, seq_ax, sp, manual_axes, batch_spec = _seq_setup(config, mesh)
     layers_per_stage = config.n_layer // n_stages
     n_micro = batch.shape[0]
     ticks = n_micro + 2 * (n_stages - 1)
     depth = 2 * n_stages - 1  # rolling residual-buffer depth
     perm_fwd = [(i, (i + 1) % n_stages) for i in range(n_stages)]
     perm_bwd = [(i, (i - 1) % n_stages) for i in range(n_stages)]
-    # The loss is the mean over microbatches AND data shards (dp=1 when
-    # 'data' stays auto); every hand-seeded cotangent uses the same scale
-    # so the backward stays consistent with the published loss.
-    inv_m = 1.0 / (n_micro * dp)
+    # The loss is the mean over microbatches; every hand-seeded cotangent
+    # uses the same scale so the backward stays consistent with the
+    # published loss.
+    inv_m = 1.0 / n_micro
     key_data = _key_data_or_none(base_key)
-    reduce_axes = (AXIS,) + ((data_ax,) if data_ax else ())
-    legacy_vma = _legacy_partial_auto()
-    # Axes replicated-parameter grads sum over in the LEGACY explicit
-    # reductions (vma runtimes never take these branches for 'seq': the
-    # implicit invariant->varying transpose covers it, and pcast_missing
-    # skips already-varying axes).
-    grad_axes = reduce_axes + (
-        (seq_ax,) if (seq_ax and legacy_vma) else ()
-    )
-    # Legacy cotangent-seed scale: pre-vma jax transposes psum to psum, so
-    # differentiating through the CE/aux internal psum over 'seq' inflates
-    # a hand-seeded cotangent by sp. Seeding 1/sp of the true cotangent
-    # cancels it exactly (verified against plain-model ground truth); the
-    # explicit grad_axes psums then restore the cross-shard sums.
-    ct_scale = 1.0 / sp if (legacy_vma and sp > 1) else 1.0
 
     def staged(params, batch, stage_arr):
         stage = stage_arr[0]
@@ -394,7 +328,7 @@ def pipeline_loss_and_grads_1f1b(
         head_cond = jax.default_backend() != "cpu"
         if head_cond:
             hp_in = jax.tree.map(
-                lambda x: pcast_varying(x, (AXIS,)), hp
+                lambda x: pcast_missing(x, (AXIS,)), hp
             )
         else:
             hp_in = hp
@@ -413,7 +347,7 @@ def pipeline_loss_and_grads_1f1b(
         moe = config.n_experts > 0
         aux_sum = jnp.zeros((), jnp.float32)
         aux_ct_const = (
-            config.router_aux_coef * ct_scale / (config.n_layer * n_micro * dp)
+            config.router_aux_coef / (config.n_layer * n_micro)
             if moe else 0.0
         )
 
@@ -464,18 +398,17 @@ def pipeline_loss_and_grads_1f1b(
                 if head_cond:
                     def head_work(so=state_out, fn=head_loss):
                         l, vjp_head = jax.vjp(fn, hp_in, so)
-                        dl = pcast_varying(
-                            jnp.asarray(inv_m * ct_scale, jnp.float32),
-                            (AXIS,),
+                        dl = pcast_missing(
+                            jnp.asarray(inv_m, jnp.float32), (AXIS,)
                         )
                         d_hp_t, d_xh = vjp_head(dl)
                         return l, d_hp_t, d_xh
 
                     def head_zero(so=state_out):
-                        var = lambda z: pcast_varying(z, (AXIS,))
+                        var = lambda z: pcast_missing(z, (AXIS,))
                         # The state cotangent is additionally seq-varying
                         # (it is a local sequence chunk's gradient).
-                        var_x = lambda z: pcast_varying(
+                        var_x = lambda z: pcast_missing(
                             z, (AXIS,) + ((seq_ax,) if seq_ax else ())
                         )
                         return (
@@ -491,7 +424,7 @@ def pipeline_loss_and_grads_1f1b(
                     # cotangents, so no cross-stage control flow is needed
                     l, vjp_head = jax.vjp(head_loss, hp_in, state_out)
                     loss_sum = loss_sum + jnp.where(is_last, l, 0.0)
-                    dl = jnp.where(is_last, inv_m * ct_scale, 0.0)
+                    dl = jnp.where(is_last, inv_m, 0.0)
                     d_hp_t, d_x_head = vjp_head(dl)
                 d_hp = jax.tree.map(jnp.add, d_hp, d_hp_t)
 
@@ -531,19 +464,13 @@ def pipeline_loss_and_grads_1f1b(
                     # varying over 'pipe' so it accepts the varying cotangent;
                     # pcast's transpose is a psum, so d_ep_t comes back
                     # already reduced across stages (invariant) — the final
-                    # grads need no further psum for wte/wpe.
-                    # Legacy runtime: NO pcast here. Its transpose would
-                    # psum the cotangent BEFORE the embed transpose, but
-                    # under sp>1 the wpe scatter offset differs per seq
-                    # shard, so the reduction only commutes with the
-                    # scatter when it runs AFTER — on the accumulated d_ep
-                    # below (the interleaved executor's structure). On vma
-                    # runtimes the pipe-psum transpose commutes (offsets
-                    # are pipe-uniform) and 'seq' is handled implicitly.
+                    # grads need no further psum for wte/wpe. The pipe-psum
+                    # transpose commutes with the wpe scatter (offsets are
+                    # pipe-uniform) and 'seq' is handled implicitly.
                     _, vjp_emb = jax.vjp(
-                        lambda ep: pcast_varying(
+                        lambda ep: pcast_missing(
                             tinygpt.embed(config, ep, batch[bi0], ek0, deterministic),
-                            () if legacy_vma else (AXIS,),
+                            (AXIS,),
                         ),
                         ep,
                     )
@@ -558,50 +485,23 @@ def pipeline_loss_and_grads_1f1b(
             if t < n_micro + n_stages - 2:
                 state = lax.ppermute(state_out, AXIS, perm_fwd)
 
-        loss = lax.psum(loss_sum, reduce_axes) * inv_m
+        loss = lax.psum(loss_sum, AXIS) * inv_m
         if moe:
             # Same accounting as the GPipe schedule: psum over stages covers
-            # all n_layer layers once per microbatch (and over data shards
-            # when 'data' is manual — dp normalizes the mean).
+            # all n_layer layers once per microbatch.
             loss = loss + config.router_aux_coef * lax.psum(
-                aux_sum, reduce_axes
-            ) / (config.n_layer * n_micro * dp)
+                aux_sum, AXIS
+            ) / (config.n_layer * n_micro)
         if head_cond:
             # cond path kept d_hp varying (nonzero on the last stage only);
-            # one psum re-replicates it — over 'data' too when that axis
-            # is manual (reduce_axes == (AXIS,) on vma runtimes), or the
-            # legacy data-manual path on a non-CPU backend would lose the
-            # head grads' cross-shard sum.
-            d_hp = jax.tree.map(lambda x: lax.psum(x, reduce_axes), d_hp)
-        elif legacy_vma:
-            # Pre-vma runtime: the compute-and-mask path's d_hp relies on
-            # the vma autodiff inserting the invariant->varying transpose
-            # psum inside jax.vjp — machinery the legacy shard_map does not
-            # have, so each stage still holds only its own (masked)
-            # contribution. Reduce explicitly; on vma runtimes this branch
-            # must NOT run or d_hp would double-count.
-            d_hp = jax.tree.map(lambda x: lax.psum(x, grad_axes), d_hp)
+            # one psum re-replicates it.
+            d_hp = jax.tree.map(lambda x: lax.psum(x, AXIS), d_hp)
         # Otherwise d_hp is already pipe-invariant: the vjp of using an
         # invariant primal (hp) in a varying computation transposes the
-        # implicit broadcast into a psum. On vma runtimes d_ep likewise
-        # came back invariant through the embed's explicit pcast — no
-        # further reduction, it would double-count. The legacy runtime
-        # skipped that pcast (see the vjp_emb note) and reduces here,
-        # after the scatter.
-        if legacy_vma:
-            d_ep = jax.tree.map(lambda x: lax.psum(x, grad_axes), d_ep)
-        blk_axes = tuple(
-            a for a in (data_ax, seq_ax if ct_scale != 1.0 else None) if a
-        )
-        if blk_axes:
-            # Block grads are per-stage (out_spec P('pipe', ...)) but must
-            # still SUM across the manual data shards' local batches — and
-            # across 'seq' on the legacy runtime, where the 1/sp-scaled
-            # seeds leave per-shard partials (vma runtimes reduce
-            # implicitly inside the vjp).
-            d_blocks = jax.tree.map(
-                lambda x: lax.psum(x, blk_axes), d_blocks
-            )
+        # implicit broadcast into a psum. d_ep likewise came back invariant
+        # through the embed's explicit pcast — no further reduction, it
+        # would double-count. Block grads are per-stage (out_spec
+        # P('pipe', ...)); their 'seq' sum happens inside the vjp.
         grads = {"blocks": d_blocks}
         for _dtree in (d_hp, d_ep):  # wte appears in both when tied: sum
             for _k, _v in _dtree.items():

@@ -322,16 +322,18 @@ def resolve_strategy(args: argparse.Namespace):
 
 
 def main(argv=None) -> int:
-    from ..utils.platform import honor_jax_platforms_env
+    from ..utils.platform import (
+        apply_latency_hiding_flags,
+        enable_compile_cache,
+        require_tpu,
+    )
 
-    honor_jax_platforms_env()
     args = build_parser().parse_args(argv)
     if args.xla_latency_hiding:
         # Must land in XLA_FLAGS before the first backend client exists —
         # setup_distributed below initializes it.
-        from ..utils.platform import apply_latency_hiding_flags
-
         apply_latency_hiding_flags()
+    enable_compile_cache()
     if args.flash_pallas_backward and args.flash_blockwise_backward:
         raise SystemExit(
             "--flash-pallas-backward and --flash-blockwise-backward are "
@@ -392,6 +394,9 @@ def main(argv=None) -> int:
     )
 
     try:
+        # After the rendezvous (jax.distributed must initialize before the
+        # first backend touch): no chip found is an error, not a CPU run.
+        require_tpu()
         from .loop import run_benchmark
 
         run_benchmark(

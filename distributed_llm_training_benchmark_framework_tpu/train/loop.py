@@ -796,7 +796,8 @@ def _run_benchmark_impl(
     # a poisoned tail and the replay can re-measure honestly (replayed
     # step TIMES stay excluded — their windows fold the restore; the
     # values are extracted into plain lists for compute_result below).
-    timed_times: list = []   # (step, window-mean step time)
+    # (step, window-mean step time, the same stopped after the loss fetch)
+    timed_times: list = []
     timed_losses: list = []  # (step, loss)
     trace_started = False
 
@@ -978,13 +979,16 @@ def _run_benchmark_impl(
         jax.block_until_ready(pending[-1][1])
         dt = (time.perf_counter() - t_start) / len(pending)
         last = pending[-1][0]
-        window_losses = []
-        for s, l, g in pending:
-            lf = float(l)
-            window_losses.append(lf)
+        window_losses = [float(l) for _s, l, _g in pending]
+        # The same window read again once the losses are on the host. The
+        # published time is dt; the two are printed side by side at the end
+        # of the run, because a runtime whose block_until_ready returned
+        # early would show up as a gap between them.
+        dt_fetched = (time.perf_counter() - t_start) / len(pending)
+        for (s, _l, g), lf in zip(pending, window_losses):
             if s >= warmup_steps:
                 if s > cursor.replay_until:
-                    timed_times.append((s, dt))
+                    timed_times.append((s, dt, dt_fetched))
                 timed_losses.append((s, lf))
             if is_main and s % log_every == 0:
                 print(f"[Step {s:04d}] Loss: {lf:.4f}, Time: {dt:.3f}s")
@@ -1653,22 +1657,18 @@ def _run_benchmark_impl(
     # (when the allocator can't report a peak), the step-anatomy
     # roofline, and the memory-anatomy reconciliation (which ALWAYS
     # wants the compile-time half). Cache hit after the run — the AOT
-    # path shares the jit executable cache, <1ms.
-    compiled_step = None
-    try:
-        # Streaming runs compile against an abstract batch aval (their
-        # step takes a per-step batch, not the table); shapes/shardings
-        # match the prefetcher's device puts, so it is the same cache-hit.
-        aot_batch = table
-        if use_stream:
-            aot_batch = jax.ShapeDtypeStruct(
-                (grad_accum, global_micro, seq_len), jnp.int32,
-                sharding=batch_sharding,
-            )
-        compiled_step = active_state.aot_compile(params, opt_state, aot_batch, 0)
-    except Exception as e:  # degrade down the fallback chain, never fail a run
-        if is_main:
-            print(f"WARNING: step AOT compile for memory accounting failed: {e}")
+    # path shares the jit executable cache, <1ms — so a failure here is a
+    # fault in the step the run just measured, and it raises.
+    # Streaming runs compile against an abstract batch aval (their
+    # step takes a per-step batch, not the table); shapes/shardings
+    # match the prefetcher's device puts, so it is the same cache-hit.
+    aot_batch = table
+    if use_stream:
+        aot_batch = jax.ShapeDtypeStruct(
+            (grad_accum, global_micro, seq_len), jnp.int32,
+            sharding=batch_sharding,
+        )
+    compiled_step = active_state.aot_compile(params, opt_state, aot_batch, 0)
 
     # Step-anatomy attribution (analysis/step_anatomy.py, docs/
     # OBSERVABILITY.md): when this run captured a profiler trace, decompose
@@ -1677,23 +1677,20 @@ def _run_benchmark_impl(
     # step's cost_analysis() FLOPs+bytes — available even on the CPU
     # dryrun — against utils/platform.py peaks), and publish the fractions
     # as additive result fields. The cost JSON lands beside the trace so
-    # the offline CLI reproduces the same table later. Best-effort: a
-    # trace the engine cannot read degrades with a warning, never fails
-    # the measured run.
+    # the offline CLI reproduces the same table later. A trace the engine
+    # finds nothing usable in (its ValueError) degrades with a warning and
+    # None fields; any other failure raises.
     step_anatomy_fields = None
     if trace_started and is_main and profile_dir:
         try:
             from ..analysis import step_anatomy as anatomy_mod
 
-            cstep = compiled_step
-            cost = None
-            if cstep is not None:
-                cost = anatomy_mod.cost_from_compiled(
-                    cstep, device_kind=devices[0].device_kind,
-                    world_size=world_size,
-                )
-                if cost is not None:
-                    anatomy_mod.write_cost_json(profile_dir, cost)
+            cost = anatomy_mod.cost_from_compiled(
+                compiled_step, device_kind=devices[0].device_kind,
+                world_size=world_size,
+            )
+            if cost is not None:
+                anatomy_mod.write_cost_json(profile_dir, cost)
             report = anatomy_mod.analyze_profile_dir(
                 profile_dir, telemetry_path=recorder.path, cost=cost,
                 pipeline_schedule=(pipeline_schedule if pp > 1 else None),
@@ -1710,7 +1707,7 @@ def _run_benchmark_impl(
                 ),
             )
             print(anatomy_mod.format_report(report))
-        except Exception as e:
+        except ValueError as e:
             print(f"WARNING: step-anatomy attribution skipped: {e}")
 
     # Memory-anatomy reconciliation (analysis/memory_anatomy.py, docs/
@@ -1719,37 +1716,27 @@ def _run_benchmark_impl(
     # buffer accounting off the (cache-hit) step executable, and the
     # allocator's measured peak (explicitly null-with-reason on backends
     # without memory_stats) — into the per-class attribution + the
-    # hbm_model_drift_frac secondary metric. Best-effort like the step
-    # anatomy: a reconciliation failure degrades with a warning, never
-    # fails the measured run.
-    memory_anatomy_fields = None
-    try:
-        from ..analysis import memory_anatomy as memano
+    # hbm_model_drift_frac secondary metric.
+    from ..analysis import memory_anatomy as memano
 
-        measured_b, measured_reason = memano.measured_peak_bytes(
-            prior_peak_bytes
-        )
-        mem_report = memano.reconcile(
-            est,
-            compile_mem=memano.compile_memory_fields(compiled_step),
-            measured_bytes=measured_b,
-            measured_reason=measured_reason,
-        )
-        memory_anatomy_fields = memano.result_fields(
-            mem_report, est_breakdown=est.breakdown()
-        )
-        recorder.note("memory_anatomy", **memory_anatomy_fields)
-        if is_main:
-            print(memano.format_report(mem_report))
-    except Exception as e:
-        if is_main:
-            print(f"WARNING: memory-anatomy reconciliation skipped: {e}")
+    measured_b, measured_reason = memano.measured_peak_bytes(prior_peak_bytes)
+    mem_report = memano.reconcile(
+        est,
+        compile_mem=memano.compile_memory_fields(compiled_step),
+        measured_bytes=measured_b,
+        measured_reason=measured_reason,
+    )
+    memory_anatomy_fields = memano.result_fields(
+        mem_report, est_breakdown=est.breakdown()
+    )
+    recorder.note("memory_anatomy", **memory_anatomy_fields)
+    if is_main:
+        print(memano.format_report(mem_report))
 
     # MoE runs: measure the expert-capacity overflow (dropped-assignment
     # fraction) on the trained params with one diagnostic forward — the
     # published row's routing-health column (models.tinygpt
-    # .moe_overflow_fraction). Best-effort: sharded geometries the
-    # diagnostic can't replicate under skip with a warning, not a failure.
+    # .moe_overflow_fraction).
     expert_overflow_pct = None
     # The interleaved schedule physically PERMUTES the stacked layer axis
     # (parallel/interleaved.py layer_permutation), so a plain apply_blocks
@@ -1764,34 +1751,35 @@ def _run_benchmark_impl(
             print("NOTE: MoE overflow diagnostic skipped on the "
                   "streaming data path")
     elif n_experts > 0 and not interleaved_params:
-        try:
-            import functools
+        import functools
 
-            from jax.sharding import NamedSharding
+        from ..models import tinygpt as _tg
+        from ..parallel import strategies as strat_mod
 
-            from ..models import tinygpt as _tg
-            from ..parallel import strategies as strat_mod
-
-            ov_batch = jax.device_put(
-                ds.batch_for_step(0, global_micro),
-                NamedSharding(mesh, strat_mod.batch_partition_spec(mesh)),
-            )
-            with jax.set_mesh(mesh):
-                # One-off post-run diagnostic forward: params are read-only
-                # here and the scalar output needs no layout pin.
-                frac = jax.jit(  # graftcheck: disable=GC101
-                    functools.partial(_tg.moe_overflow_fraction, state.model_config)
-                )(params, ov_batch)
-            expert_overflow_pct = round(float(jax.device_get(frac)) * 100.0, 4)
-        except Exception as e:
-            if is_main:
-                print(f"WARNING: MoE overflow diagnostic skipped: {e}")
+        ov_batch = jax.device_put(
+            ds.batch_for_step(0, global_micro),
+            NamedSharding(mesh, strat_mod.batch_partition_spec(mesh)),
+        )
+        with jax.set_mesh(mesh):
+            # One-off post-run diagnostic forward: params are read-only
+            # here and the scalar output needs no layout pin.
+            frac = jax.jit(  # graftcheck: disable=GC101
+                functools.partial(_tg.moe_overflow_fraction, state.model_config)
+            )(params, ov_batch)
+        expert_overflow_pct = round(float(jax.device_get(frac)) * 100.0, 4)
 
     # Extract the timed distributions from their step-keyed form (the
     # sentinel's rollback truncation is why they carry step ids at all);
     # replayed steps are absent from timed_times by construction.
-    step_times = [dt for _s, dt in timed_times]
+    step_times = [dt for _s, dt, _f in timed_times]
     losses = [lf for _s, lf in timed_losses]
+    if is_main and step_times:
+        print(
+            "Window clock, median s/step: "
+            f"{np.median(step_times):.6f} stopped at block_until_ready, "
+            f"{np.median([f for _s, _dt, f in timed_times]):.6f} stopped "
+            "after the loss fetch"
+        )
     # Streaming-data accounting for the published row: data_stall_frac is
     # the fraction of TIMED step wall spent starved for input (the waits
     # happen inside the windows whose times the row publishes, so the
@@ -1838,6 +1826,8 @@ def _run_benchmark_impl(
         ),
         device_kind=devices[0].device_kind,
         backend=jax.default_backend(),
+        platform=devices[0].platform,
+        device_count=jax.device_count(),
         n_params=state.n_params,
         attention_impl=attention_impl,
         dropout=model_config.dropout,

@@ -9,8 +9,8 @@ throughput to what the silicon could do. We add the standard accounting:
   convention). Backward is counted as 2x forward; rematerialized recompute is
   deliberately NOT counted — MFU measures useful model FLOPs, so remat shows
   up as lower MFU, not higher FLOPs.
-- ``device_peak_tflops(device_kind)``: bf16 peak per chip for known TPU
-  generations (public spec-sheet numbers).
+- ``device_peak_tflops(device_kind)``: bf16 peak per chip, from the one
+  hardware table (``utils.platform.CHIP_SPECS``).
 - MFU = achieved model TFLOP/s/chip ÷ peak TFLOP/s/chip.
 
 Counting detail (per token, forward):
@@ -29,55 +29,22 @@ from __future__ import annotations
 
 from typing import Optional
 
-# bf16 peak TFLOP/s per chip, public spec numbers. Matched by substring
-# against jax's Device.device_kind (e.g. "TPU v5 lite", "TPU v4").
-# Order matters: more specific names first ("v5 lite" before "v5").
-_PEAK_TFLOPS_BF16 = (
-    ("TPU v6 lite", 918.0),  # Trillium / v6e
-    ("TPU v6", 918.0),
-    ("TPU v5 lite", 197.0),  # v5e
-    ("TPU v5e", 197.0),
-    ("TPU v5p", 459.0),
-    ("TPU v5", 459.0),
-    ("TPU v4 lite", 138.0),  # v4i
-    ("TPU v4", 275.0),
-    ("TPU v3", 123.0),
-    ("TPU v2", 45.0),
-)
+from .platform import chip_spec
 
 
 def device_peak_tflops(device_kind: str) -> Optional[float]:
-    """bf16 peak TFLOP/s for a device kind, or None if unknown (e.g. CPU)."""
-    for name, peak in _PEAK_TFLOPS_BF16:
-        if name.lower() in device_kind.lower():
-            return peak
-    return None
-
-
-# Public on-demand US-region list prices, USD per chip-hour (Cloud TPU pricing
-# page, mid-2025; multi-chip pod types priced per chip). The reference's
-# cost-efficiency metric (reference README.md:270-276) uses its cloud's A10
-# on-demand rate the same way. Same substring-match convention as the peak
-# table; order matters.
-_ONDEMAND_USD_PER_CHIP_HR = (
-    ("TPU v6 lite", 2.70),  # Trillium / v6e
-    ("TPU v6", 2.70),
-    ("TPU v5 lite", 1.20),  # v5e
-    ("TPU v5e", 1.20),
-    ("TPU v5p", 4.20),
-    ("TPU v5", 4.20),
-    ("TPU v4", 3.22),
-    ("TPU v3", 2.00),
-    ("TPU v2", 1.125),
-)
+    """bf16 peak TFLOP/s for a device kind; None off a TPU (CPU hosts), an
+    error for a TPU kind the table does not hold (utils.platform)."""
+    spec = chip_spec(device_kind)
+    return spec.bf16_tflops if spec else None
 
 
 def device_usd_per_chip_hour(device_kind: str) -> Optional[float]:
-    """On-demand $/chip-hour for a device kind, or None if unknown (CPU)."""
-    for name, price in _ONDEMAND_USD_PER_CHIP_HR:
-        if name.lower() in device_kind.lower():
-            return price
-    return None
+    """On-demand $/chip-hour (the reference's cost-efficiency metric,
+    reference README.md:270-276, uses its cloud's A10 rate the same way);
+    None off a TPU or where no list price is published."""
+    spec = chip_spec(device_kind)
+    return spec.usd_per_chip_hour if spec else None
 
 
 def tokens_per_dollar(
@@ -151,7 +118,7 @@ def mfu_pct(
     flops_per_token: float,
     device_kind: str,
 ) -> Optional[float]:
-    """Model-FLOPs utilization in percent, or None for unknown device kinds."""
+    """Model-FLOPs utilization in percent, or None off a TPU (CPU hosts)."""
     peak = device_peak_tflops(device_kind)
     if peak is None or flops_per_token <= 0 or tokens_per_sec_per_chip <= 0:
         return None

@@ -38,28 +38,14 @@ from typing import Any, Dict, Optional
 import jax
 import numpy as np
 
-# Per-chip HBM capacity in GiB, matched by substring against
-# Device.device_kind (same convention as flops._PEAK_TFLOPS_BF16).
-_HBM_GIB = (
-    ("TPU v6 lite", 32.0),
-    ("TPU v6", 32.0),
-    ("TPU v5 lite", 16.0),
-    ("TPU v5e", 16.0),
-    ("TPU v5p", 95.0),
-    ("TPU v5", 95.0),
-    ("TPU v4 lite", 8.0),
-    ("TPU v4", 32.0),
-    ("TPU v3", 16.0),
-    ("TPU v2", 8.0),
-)
+from .platform import chip_spec
 
 
 def device_hbm_bytes(device_kind: str) -> Optional[int]:
-    """Per-chip HBM capacity for a device kind, or None if unknown (CPU)."""
-    for name, gib in _HBM_GIB:
-        if name.lower() in device_kind.lower():
-            return int(gib * 1024**3)
-    return None
+    """Per-chip HBM capacity; None off a TPU (CPU hosts), an error for a
+    TPU kind the hardware table does not hold (utils.platform)."""
+    spec = chip_spec(device_kind)
+    return int(spec.hbm_gib * 1024**3) if spec else None
 
 
 def _sharded_bytes(shapes, specs, mesh) -> int:

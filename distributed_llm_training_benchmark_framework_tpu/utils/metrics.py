@@ -135,8 +135,8 @@ def measure_peak_hbm(
     1. ``allocator`` — per-device ``memory_stats()['peak_bytes_in_use']``,
        the runtime allocator's true high-water mark (reference parity:
        ``torch.cuda.max_memory_allocated``, ``train_harness.py:406-408``).
-       Works on standard Cloud TPU runtimes; returns None on some PJRT
-       plugins (and on CPU). The high-water mark is PROCESS-lifetime and
+       The TPU runtime reports it; the CPU backend does not. The
+       high-water mark is PROCESS-lifetime and
        has no reset API, so when several arms run in one process (bench.py
        measures parity then flagship) a later arm would silently inherit
        an earlier, larger arm's peak: callers pass ``prior_peak_bytes``
@@ -148,16 +148,12 @@ def measure_peak_hbm(
        for the train-step executable (arguments + outputs + temporaries,
        donation-aliased). This is what the device allocator actually
        reserves to run the step, i.e. a *measured* property of the compiled
-       program, not an analytic estimate. ``jax.profiler
-       .device_memory_profile()`` would be the natural rung here, but on
-       PJRT C-API runtimes that don't implement
-       ``PJRT_Executable_SizeOfGeneratedCodeInBytes`` it aborts the whole
-       process with an uncatchable CHECK failure (see
-       docs/TROUBLESHOOTING.md), so it is deliberately excluded.
+       program, not an analytic estimate.
     3. ``live_arrays`` — sum of bytes of all live ``jax.Array``s on the
        largest-resident device: a floor (params + opt state + dataset, no
-       in-step temporaries). Reported so the column is never silently zero.
-    4. ``unavailable`` — 0.0.
+       in-step temporaries).
+    4. ``unavailable`` — 0.0: no allocator statistic, no executable, no
+       live array. ``chip_smoke.py`` fails on it.
 
     Returns (peak_gb, method).
     """
@@ -250,6 +246,10 @@ class BenchmarkResult:
     est_hbm_gb: float = 0.0
     device_kind: str = ""
     backend: str = ""
+    # Where the row ran, as jax reports it (jax.devices()[0], device count):
+    # a number is a device measurement only when platform says "tpu".
+    platform: str = ""
+    device_count: int = 0
     n_params: int = 0
     attention_impl: str = "reference"
     dropout: float = 0.0
@@ -257,9 +257,9 @@ class BenchmarkResult:
     # FLOPs metric at all (train_harness.py:399-413 is its whole surface).
     flops_per_token: float = 0.0
     model_tflops_per_sec_per_chip: float = 0.0
-    mfu_pct: float = 0.0  # 0.0 when the device kind's peak is unknown (CPU)
-    # Cost efficiency at public on-demand $/chip-hr (utils.flops price table);
-    # 0.0 for unknown device kinds. Reference parity: README.md:270-276.
+    mfu_pct: float = 0.0  # 0.0 off a TPU (CPU runs have no peak)
+    # Cost efficiency at public on-demand $/chip-hr (utils.platform table);
+    # 0.0 off a TPU. Reference parity: README.md:270-276.
     usd_per_chip_hour: float = 0.0
     tokens_per_dollar: float = 0.0
     # Per-step wall-time distribution over the timed (post-warmup) steps.
@@ -471,6 +471,8 @@ def compute_result(
     losses: List[float],
     device_kind: str = "",
     backend: str = "",
+    platform: str = "",
+    device_count: int = 0,
     n_params: int = 0,
     attention_impl: str = "reference",
     dropout: float = 0.0,
@@ -628,6 +630,8 @@ def compute_result(
         est_hbm_gb=est_hbm_gb,
         device_kind=device_kind,
         backend=backend,
+        platform=platform,
+        device_count=device_count,
         n_params=n_params,
         attention_impl=attention_impl,
         dropout=dropout,
@@ -700,6 +704,10 @@ def emit_result(result: BenchmarkResult, results_dir: str, is_main: bool = True)
 
     print("\n" + "=" * 80)
     print("Benchmark Results:")
+    print(
+        f"  Device:           {result.platform} {result.device_kind!r}"
+        f" x{result.device_count}"
+    )
     print(f"  Tokens/sec:       {result.tokens_per_sec:,.0f}")
     if result.mfu_pct > 0:
         print(

@@ -10,8 +10,7 @@ one-file fix.
 
 from __future__ import annotations
 
-import functools
-from typing import FrozenSet, Iterable, Tuple
+from typing import FrozenSet, Iterable
 
 import jax
 from jax import lax
@@ -27,14 +26,9 @@ def vma_of(*arrays) -> FrozenSet[str]:
 
 def pcast_missing(x, axes: Iterable[str]):
     """pcast ``x`` to vary over ``axes``, skipping axes it already varies
-    over (pcast rejects varying->varying).
-
-    On jax runtimes without ``lax.pcast`` (pre-vma shard_map, where the
-    compat layer runs shard_map with replication checking off) there is no
-    varying-axes type system to satisfy, so this is the identity.
-    """
-    if not hasattr(lax, "pcast"):
-        return x
+    over (pcast rejects varying->varying). The transpose of
+    invariant->varying is a psum, which the pipeline backward passes lean
+    on (e.g. the 1F1B embed vjp)."""
     have = vma_of(x)
     need = tuple(a for a in axes if a not in have)
     return lax.pcast(x, need, to="varying") if need else x
@@ -43,47 +37,3 @@ def pcast_missing(x, axes: Iterable[str]):
 def pcast_like(x, *like):
     """pcast ``x`` to vary over every axis any of ``like`` varies over."""
     return pcast_missing(x, sorted(vma_of(*like)))
-
-
-@functools.lru_cache(maxsize=None)
-def _legacy_pcast_varying(axes: Tuple[str, ...]):
-    """Identity whose cotangent psums over ``axes`` — pcast's transpose.
-
-    Pre-vma runtimes have no ``lax.pcast``, but some call sites depend on
-    more than the type cast: the transpose of invariant->varying is a psum,
-    and pipeline backward passes lean on exactly that reduction (e.g. the
-    1F1B embed vjp, where the cotangent is nonzero on stage 0 only and the
-    parameter gradient must come back already summed across stages). A
-    plain-identity degrade (``pcast_missing``'s contract) would silently
-    drop that psum, so this reconstructs it with a custom_vjp.
-    """
-
-    @jax.custom_vjp
-    def cast(x):
-        return x
-
-    def fwd(x):
-        return x, None
-
-    def bwd(_, g):
-        return (lax.psum(g, axes),)
-
-    cast.defvjp(fwd, bwd)
-    return cast
-
-
-def pcast_varying(x, axes: Iterable[str]):
-    """``lax.pcast(x, axes, to='varying')`` with a legacy-jax fallback
-    whose TRANSPOSE is preserved.
-
-    Unlike :func:`pcast_missing` (identity on pre-vma runtimes — right for
-    pure type plumbing, wrong wherever the pcast transpose psum carries
-    real gradient flow), this keeps the backward psum alive on both
-    runtimes. Use it when the call site differentiates through the cast.
-    """
-    axes = tuple(axes)
-    if not axes:
-        return x
-    if hasattr(lax, "pcast"):
-        return pcast_missing(x, axes)
-    return _legacy_pcast_varying(axes)(x)

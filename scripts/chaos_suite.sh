@@ -543,7 +543,7 @@ PYEOF
       chmod +x "$bindir/kubectl"
       if ! env FAKE_KUBECTL_DIR="$dir" PATH="$bindir:$PATH" \
            RESULTS_DIR="$dir/results" STRATEGIES="ddp" WORLD_SIZES="8" \
-           COMPOSITIONS=off SKIP_PREFLIGHT=1 SKIP_CHAOS=1 SKIP_REGRESS=1 \
+           COMPOSITIONS=off SKIP_CHAOS=1 SKIP_REGRESS=1 \
            MAX_ARM_RETRIES=1 RETRY_BACKOFF_SEC=0 \
            bash scripts/run_all_benchmarks.sh --k8s > "$dir/phase1.log" 2>&1
       then

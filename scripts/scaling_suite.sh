@@ -80,10 +80,9 @@ TIMEOUT_PER_RUN="${TIMEOUT_PER_RUN:-1800}"
 REGISTRY_DIR="${REGISTRY_DIR:-$RESULTS_DIR/registry}"
 
 if [ -z "${SCALING_GEOMETRIES:-}" ]; then
-  NCHIPS=$(python -c "
-from $PKG.utils.platform import honor_jax_platforms_env
-honor_jax_platforms_env()
-import jax; print(jax.device_count())" 2>/dev/null || echo 1)
+  # A throwaway child that exits before any arm starts (a chip belongs to
+  # one process at a time).
+  NCHIPS=$(python -c "import jax; print(jax.device_count())" 2>/dev/null || echo 1)
   SCALING_GEOMETRIES="1"
   for ws in 2 4 8 16; do
     [ "$ws" -le "$NCHIPS" ] && SCALING_GEOMETRIES="$SCALING_GEOMETRIES $ws"

@@ -17,8 +17,6 @@ if [ "${1:-}" = "--image" ]; then MODE="docker"; IMAGE="$2"; fi
 PY_TESTS=$(cat <<'EOF'
 import os
 os.environ.setdefault("JAX_PLATFORMS", "cpu")
-from distributed_llm_training_benchmark_framework_tpu.utils.platform import honor_jax_platforms_env
-honor_jax_platforms_env()
 
 print("--- [1/5] imports ---")
 import jax, optax, numpy, pandas, matplotlib

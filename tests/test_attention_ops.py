@@ -224,6 +224,67 @@ def test_flash_backward_auto_selects_einsum_on_cpu(monkeypatch):
     assert calls == []
 
 
+def test_tpu_backend_never_reaches_interpret_or_jnp_fallback(monkeypatch):
+    """On a TPU backend the dispatch is the Mosaic kernel or an error: the
+    default resolves to interpret=False, and an explicit interpret=True is
+    refused instead of silently measuring the interpreter. The jnp
+    fallbacks (flash's reference forward, ring's einsum blocks) are gated
+    on the same flag, so neither is reachable there."""
+    from distributed_llm_training_benchmark_framework_tpu.ops import (
+        flash_attention as fa,
+    )
+
+    assert fa._resolve_interpret(None) is True  # this CPU backend
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert fa._resolve_interpret(None) is False
+    assert fa._resolve_interpret(False) is False
+    with pytest.raises(ValueError, match="interpret=True on a TPU backend"):
+        fa._resolve_interpret(True)
+    q, k, v = qkv(B=1, S=64, H=2, D=16)
+    with pytest.raises(ValueError, match="interpret=True on a TPU backend"):
+        fa.flash_attention(q, k, v, interpret=True, block_q=32, block_k=16)
+
+
+def test_flash_dp4_dropout_loss_matches_one_device(eight_devices):
+    """flash under a dp=4 mesh runs inside a shard_map, where a kernel's
+    grid index is LOCAL to the shard. The dropout hash is keyed by global
+    (batch, head) ids instead, so the same seed and global batch give the
+    same loss — through two optimizer steps, so the gradients too — as one
+    device. With local ids, examples on shards 1-3 would reuse shard 0's
+    masks and the losses would part at the first step. (On the CPU the
+    manual region takes the jnp reference forward, keyed by the same ids.)
+    """
+    from distributed_llm_training_benchmark_framework_tpu.data import (
+        SyntheticDataset,
+    )
+    from distributed_llm_training_benchmark_framework_tpu.models import (
+        get_model_config,
+    )
+    from distributed_llm_training_benchmark_framework_tpu.parallel import (
+        get_strategy,
+    )
+    from distributed_llm_training_benchmark_framework_tpu.train import (
+        create_train_state,
+    )
+
+    seq, gb = 64, 8
+    config = get_model_config("S", seq, dropout=0.3, attention_impl="flash")
+    ds = SyntheticDataset(vocab_size=config.vocab_size, seq_len=seq, size=64)
+    host = ds.batch_for_step(0, gb).reshape(1, gb, seq)
+
+    def two_losses(strategy, n_dev):
+        mesh = make_mesh((n_dev,), ("data",), devices=eight_devices[:n_dev])
+        st = create_train_state(config, get_strategy(strategy), mesh, seed=0)
+        batch = jax.device_put(host, st.batch_sharding)
+        params, opt, l0 = st.step_fn(st.params, st.opt_state, batch, 0)
+        _, _, l1 = st.step_fn(params, opt, batch, 1)
+        return float(l0), float(l1)
+
+    one = two_losses("ddp", 1)
+    four = two_losses("fsdp", 4)
+    np.testing.assert_allclose(four, one, rtol=5e-5)
+
+
 @pytest.mark.parametrize("causal", [False, True])
 def test_ring_matches_reference(causal, eight_devices):
     mesh = make_mesh((4,), ("seq",), devices=eight_devices[:4])

@@ -11,7 +11,11 @@ invocation; these CPU smoke runs (tier S, 3 steps) pin the contract shape:
 - the additive ``flagship`` sub-object present by default, carrying the
   llama arm's throughput/MFU/peak-HBM with run-identity provenance;
 - ``--model-family llama`` promotes the family to the top-level metric
-  (and, being the flagship family itself, emits no duplicate sub-object).
+  (and, being the flagship family itself, emits no duplicate sub-object);
+- every row names the platform, device kind and device count it ran on;
+- the process starts no child (one process per chip: a child that needed
+  the chip its parent holds would fail or hang), and refuses to run when
+  no TPU came up and the CPU was not asked for by name.
 """
 
 import json
@@ -31,9 +35,25 @@ SMOKE_ARGS = [
 ]
 
 
-def run_bench(*extra):
+# Runs bench.py as __main__ under an audit hook that turns any attempt to
+# start a child process, anywhere in the process, into a failure.
+NO_CHILD_RUNNER = """
+import runpy, sys
+
+def _refuse_children(event, args):
+    if event in ("subprocess.Popen", "os.fork", "os.forkpty",
+                 "os.posix_spawn", "os.system", "os.exec", "os.spawn"):
+        raise RuntimeError(f"bench.py started a child process: {event}")
+
+sys.addaudithook(_refuse_children)
+sys.argv = sys.argv[1:]
+runpy.run_path(sys.argv[0], run_name="__main__")
+"""
+
+
+def run_bench(*extra, platforms="cpu", check=True):
     env = dict(os.environ)
-    env["JAX_PLATFORMS"] = "cpu"
+    env["JAX_PLATFORMS"] = platforms
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=1"
     # Hermeticity: bench.py auto-ingests its rows into the regress
     # registry when one exists (the repo ships a seeded results/registry)
@@ -42,10 +62,11 @@ def run_bench(*extra):
     # covered by tests/test_regress.py.
     env["REGRESS_REGISTRY"] = tempfile.mkdtemp(prefix="bench_registry_")
     proc = subprocess.run(
-        [sys.executable, BENCH, *SMOKE_ARGS, *extra],
+        [sys.executable, "-c", NO_CHILD_RUNNER, BENCH, *SMOKE_ARGS, *extra],
         capture_output=True, text=True, env=env, timeout=900, cwd=REPO,
     )
-    assert proc.returncode == 0, proc.stderr[-4000:]
+    if check:
+        assert proc.returncode == 0, proc.stderr[-4000:]
     return proc
 
 
@@ -103,3 +124,32 @@ def test_llama_as_top_level_family():
     # The top-level row IS the flagship family: no duplicate sub-object
     # under --flagship auto.
     assert "flagship" not in r
+
+
+def test_every_row_names_platform_device_kind_and_count(default_run):
+    r = json.loads(default_run.stdout)
+    for row in (r, r["flagship"]):
+        # This run asked for the CPU by name; on the chip the same keys
+        # read "tpu" / "TPU v5 lite" / the chip count.
+        assert row["platform"] == "cpu"
+        assert row["device_kind"] == "cpu"
+        assert row["device_count"] == 1
+
+
+def test_bench_starts_no_child_process(default_run):
+    """default_run ran both arms under NO_CHILD_RUNNER's audit hook, which
+    raises on any spawn/fork/exec: exit 0 means none was attempted. The
+    graftcheck preflight that used to be bench.py's first act is gone."""
+    assert default_run.returncode == 0
+    src = open(BENCH).read()
+    assert "subprocess" not in src and "preflight" not in src
+
+
+def test_no_tpu_and_cpu_not_asked_for_is_an_error():
+    """The silent case: no chip found, run carries on and prints a CPU
+    number. With JAX_PLATFORMS unset jax falls back to the CPU here, and
+    bench.py must fail before measuring, printing no row."""
+    proc = run_bench(platforms="", check=False)
+    assert proc.returncode != 0
+    assert "no TPU" in proc.stderr
+    assert not proc.stdout.strip()

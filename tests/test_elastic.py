@@ -27,6 +27,7 @@ import signal
 import socket
 import subprocess
 import sys
+import time
 
 import numpy as np
 import pytest
@@ -545,10 +546,20 @@ def multihost_preemption(tmp_path_factory):
             stdout=subprocess.PIPE, stderr=subprocess.STDOUT, text=True,
             env=_env(),
         ))
+    # Alone, both ranks exit 75 inside a minute. One shared limit, and no
+    # rank left running when it expires: a bad run costs the suite two
+    # minutes, not five per rank.
+    deadline = time.monotonic() + 120
     outs = []
-    for p in procs:
-        out, _ = p.communicate(timeout=300)
-        outs.append(out)
+    try:
+        for p in procs:
+            out, _ = p.communicate(timeout=max(deadline - time.monotonic(), 1))
+            outs.append(out)
+    finally:
+        for p in procs:
+            if p.poll() is None:
+                p.kill()
+                p.wait()
     return {"base": base, "rcs": [p.returncode for p in procs], "outs": outs}
 
 

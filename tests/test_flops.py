@@ -5,6 +5,8 @@ The reference has no FLOPs metric anywhere (its metric surface is
 published MFU numbers are backed by a checked formula.
 """
 
+import pytest
+
 from distributed_llm_training_benchmark_framework_tpu.models import get_model_config
 from distributed_llm_training_benchmark_framework_tpu.utils import flops as flops_mod
 from distributed_llm_training_benchmark_framework_tpu.utils import metrics as metrics_mod
@@ -44,6 +46,34 @@ def test_device_peak_table():
     assert flops_mod.device_peak_tflops("TPU v6 lite") == 918.0
     assert flops_mod.device_peak_tflops("cpu") is None
     assert flops_mod.device_peak_tflops("Interpreter") is None
+
+
+@pytest.mark.parametrize(
+    "kind,peak", [("TPU v5 lite", 197.0), ("TPU v5", 459.0)],
+)
+def test_tpu_kinds_match_exactly(kind, peak):
+    """'TPU v5' is the v5p's own name, not a catch-all row for every v5:
+    the v5e ('TPU v5 lite') no longer inherits 459 by substring, nor the
+    other way round."""
+    assert flops_mod.device_peak_tflops(kind) == peak
+
+
+def test_unknown_tpu_kind_raises_in_every_reader():
+    """One table (utils.platform.CHIP_SPECS): a TPU it does not hold is an
+    error — never a neighbouring generation's peak, capacity or price."""
+    from distributed_llm_training_benchmark_framework_tpu.utils import (
+        memory as memory_mod,
+        platform as platform_mod,
+    )
+
+    for read in (
+        flops_mod.device_peak_tflops, flops_mod.device_usd_per_chip_hour,
+        memory_mod.device_hbm_bytes, platform_mod.device_peak_hbm_gbps,
+        platform_mod.device_peak_flops,
+        lambda kind: flops_mod.mfu_pct(1000.0, 1e9, kind),
+    ):
+        with pytest.raises(ValueError, match="unknown TPU device_kind"):
+            read("TPU v9 mega")
 
 
 def test_mfu_pct_known_and_unknown_device():

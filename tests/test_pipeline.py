@@ -266,19 +266,6 @@ def test_pp_composes_with_tp_subprocess():
     import sys
     import textwrap
 
-    from distributed_llm_training_benchmark_framework_tpu.parallel.pipeline import (
-        _legacy_partial_auto,
-    )
-
-    if _legacy_partial_auto():
-        pytest.skip(
-            "pp x tp needs the vma shard_map runtime: the legacy "
-            "partial-auto lowering cannot partition a REAL (>1) auto "
-            "'model' axis around the pipeline ring (XLA SPMD "
-            "manual-subgroup CHECK failure). The pipeline x dp and x sp "
-            "compositions run via the data-manual legacy path instead."
-        )
-
     script = textwrap.dedent("""
         import jax
         jax.config.update("jax_platforms", "cpu")

@@ -297,10 +297,53 @@ def test_schema_drift_refused_for_record_and_registry(tmp_path):
     assert rc == 2
 
 
+# The five driver rounds the committed registry seed was ingested from. The
+# BENCH_r0N.json snapshots left the tree with the runtime they were taken
+# on; these carry the same shape (n / cmd / rc / tail / parsed) and the same
+# parsed rows — which is all a record is built from — with the log tail cut.
+_R_FLASH = {"attention_impl": "flash", "dropout": 0.1}
+_R_HBM = {"peak_hbm_gb": 5.95, "peak_hbm_method": "xla_buffer_assignment"}
+_LEGACY_BENCH_ROUNDS = [
+    {"value": 23563.68, "vs_baseline": 5.194},
+    {"value": 41890.94, "vs_baseline": 9.234, **_R_FLASH,
+     "model_tflops_per_sec_per_chip": 75.71, "mfu_pct": 38.43},
+    {"value": 41578.75, "vs_baseline": 9.165, **_R_FLASH,
+     "model_tflops_per_sec_per_chip": 75.14, "mfu_pct": 38.14, **_R_HBM,
+     "tokens_per_dollar": 124736250},
+    {"value": 41670.33, "vs_baseline": 9.185, **_R_FLASH,
+     "model_tflops_per_sec_per_chip": 75.31, "mfu_pct": 38.23, **_R_HBM,
+     "tokens_per_dollar": 125011004},
+    {"value": 41483.37, "vs_baseline": 9.144, **_R_FLASH,
+     "model_tflops_per_sec_per_chip": 74.97, "mfu_pct": 38.06, **_R_HBM,
+     "tokens_per_dollar": 124450116},
+]
+
+
+def legacy_snapshot_root(tmp_path):
+    """A directory holding BENCH_r01-05 (written here) beside the repo's own
+    MULTICHIP_r01-05 — what ``ingest_legacy`` expects of a repo root."""
+    root = tmp_path / "legacy_root"
+    root.mkdir()
+    for n, extra in enumerate(_LEGACY_BENCH_ROUNDS, start=1):
+        parsed = {
+            "metric": "tinygpt_tierA_seq2048_tokens_per_sec_per_chip",
+            "value": extra["value"], "unit": "tokens/sec/chip",
+            **{k: v for k, v in extra.items() if k != "value"},
+        }
+        (root / f"BENCH_r{n:02d}.json").write_text(json.dumps({
+            "n": n, "cmd": "python bench.py", "rc": 0,
+            "tail": json.dumps(parsed) + "\n", "parsed": parsed,
+        }))
+        name = f"MULTICHIP_r{n:02d}.json"
+        shutil.copy(os.path.join(REPO, name), root / name)
+    return str(root)
+
+
 def test_legacy_seed_ingest(tmp_path):
     """BENCH_r*/MULTICHIP_r* snapshots -> day-one trend history."""
     reg = rstore.Registry(str(tmp_path / "reg"))
-    ingested = rstore.ingest_legacy(reg, REPO)
+    root = legacy_snapshot_root(tmp_path)
+    ingested = rstore.ingest_legacy(reg, root)
     created = [r for r, c in ingested if c]
     assert len(created) == 10  # 5 bench rounds + 5 multichip rounds
     assert "bench_tinygpt_tierA_seq2048" in reg.arms()
@@ -309,7 +352,7 @@ def test_legacy_seed_ingest(tmp_path):
     )
     assert vals[-1] == pytest.approx(41483.37)
     # Re-seeding is a no-op (content-addressed).
-    assert sum(1 for _, c in rstore.ingest_legacy(reg, REPO) if c) == 0
+    assert sum(1 for _, c in rstore.ingest_legacy(reg, root) if c) == 0
     # The committed registry seed matches what --legacy produces.
     committed = rstore.Registry(os.path.join(REPO, "results", "registry"))
     if committed.exists():
@@ -732,7 +775,7 @@ def test_bench_style_scalar_verdict(tmp_path):
     import bench
 
     reg = rstore.Registry(str(tmp_path / "reg"))
-    rstore.ingest_legacy(reg, REPO)
+    rstore.ingest_legacy(reg, legacy_snapshot_root(tmp_path))
     row = {
         "metric": "tinygpt_tierA_seq2048_tokens_per_sec_per_chip",
         "value": 37335.03, "unit": "tokens/sec/chip", "vs_baseline": 8.2,
@@ -769,7 +812,7 @@ def test_default_bench_invocation_joins_committed_seed_lineage(tmp_path):
     import bench
 
     reg = rstore.Registry(str(tmp_path / "reg"))
-    rstore.ingest_legacy(reg, REPO)
+    rstore.ingest_legacy(reg, legacy_snapshot_root(tmp_path))
     args = bench.build_parser().parse_args([])  # a default invocation
     payload = {
         "metric": "tinygpt_tierA_seq2048_tokens_per_sec_per_chip",
@@ -1010,7 +1053,7 @@ def test_bench_auto_ingest_and_verdict(tmp_path):
         [sys.executable, os.path.join(REPO, "bench.py"),
          "--tier", "S", "--seq-len", "64", "--steps", "3",
          "--warmup-steps", "1", "--world-size", "1", "--flagship", "off",
-         "--skip-preflight", "--regress", "on", "--registry", registry],
+         "--regress", "on", "--registry", registry],
         capture_output=True, text=True, env=env, timeout=900, cwd=REPO,
     )
     assert proc.returncode == 0, proc.stderr[-3000:]
@@ -1025,7 +1068,7 @@ def test_bench_auto_ingest_and_verdict(tmp_path):
         [sys.executable, os.path.join(REPO, "bench.py"),
          "--tier", "S", "--seq-len", "64", "--steps", "3",
          "--warmup-steps", "1", "--world-size", "1", "--flagship", "off",
-         "--skip-preflight", "--regress", "on", "--registry", registry],
+         "--regress", "on", "--registry", registry],
         capture_output=True, text=True, env=env, timeout=900, cwd=REPO,
     )
     assert proc2.returncode == 0, proc2.stderr[-3000:]
