@@ -41,6 +41,8 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from ..utils import scopes
+
 Params = Dict[str, Any]
 
 REMAT_POLICIES = ("none", "dots", "full")
@@ -440,6 +442,7 @@ def _rope(
     return out.astype(x.dtype)
 
 
+@jax.named_scope(scopes.DROPOUT)
 def _dropout(x: jax.Array, rate: float, key: Optional[jax.Array], deterministic: bool) -> jax.Array:
     if deterministic or rate == 0.0 or key is None:
         return x
@@ -633,8 +636,6 @@ def _block(
 
     Parity: reference train_harness.py:108-131 for the dense path."""
     c = config
-    B, S, D = x.shape
-    cd = c.compute_dtype
     keys = (
         jax.random.split(dropout_key, 2) if dropout_key is not None else (None, None)
     )
@@ -654,18 +655,34 @@ def _block(
     # pipeline schedules' manual sequence region (the stream is already
     # manual over 'seq' there) — refused loudly rather than silently
     # computing a doubly-sharded projection.
-    use_cmm = c.tp_collective_matmul
-    if use_cmm and c.seq_manual_axis is not None:
+    if c.tp_collective_matmul and c.seq_manual_axis is not None:
         raise ValueError(
             "tp_collective_matmul cannot run inside a sequence-manual "
             "pipeline region (the residual stream is already sharded "
             "over the manual 'seq' axis; drop --tp-collective-matmul "
             "for pipeline arms)"
         )
+    with jax.named_scope(scopes.ATTENTION):
+        x = _attention_sublayer(c, x, layer, keys[0], deterministic)
+    with jax.named_scope(scopes.MLP):
+        return _mlp_sublayer(c, x, layer, keys[1], deterministic)
+
+
+def _attention_sublayer(
+    c: TinyGPTConfig,
+    x: jax.Array,
+    layer: Params,
+    dropout_key: Optional[jax.Array],
+    deterministic: bool,
+) -> jax.Array:
+    """Norm -> q/k/v projections -> rope -> attention -> output projection
+    -> residual: the first half of ``_block``."""
+    B, S, D = x.shape
+    cd = c.compute_dtype
+    use_cmm = c.tp_collective_matmul
     if use_cmm:
         from ..ops import collective_matmul as _cm
 
-    # --- attention sublayer ---
     h = _norm(c, x, layer["ln1_scale"], layer.get("ln1_bias"))
     if "wqkv" in layer:  # fused MHA projection (kv_heads == n_head)
         if use_cmm:
@@ -721,7 +738,7 @@ def _block(
         rep = c.n_head // c.kv_heads
         k = jnp.repeat(k, rep, axis=2)
         v = jnp.repeat(v, rep, axis=2)
-    attn = _attention(c, q, k, v, keys[0], deterministic)
+    attn = _attention(c, q, k, v, dropout_key, deterministic)
     attn = attn.reshape(B, S, D)
     if use_cmm:
         attn = _cm.rs_proj(attn, layer["wo"].astype(cd)).astype(cd)
@@ -731,15 +748,29 @@ def _block(
         ).astype(cd)
     if "bo" in layer:
         attn = attn + layer["bo"].astype(cd)
-    x = x + attn
+    return x + attn
 
-    # --- MLP sublayer: dense D -> mlp_dim -> GELU(exact) -> D -> dropout,
-    #     SwiGLU (silu(gate)*up -> down), or the routed expert layer ---
+
+def _mlp_sublayer(
+    c: TinyGPTConfig,
+    x: jax.Array,
+    layer: Params,
+    dropout_key: Optional[jax.Array],
+    deterministic: bool,
+) -> Tuple[jax.Array, jax.Array]:
+    """Norm -> MLP -> dropout -> residual, with the MoE aux: the second half
+    of ``_block``. Dense D -> mlp_dim -> GELU(exact) -> D, SwiGLU
+    (silu(gate)*up -> down), or the routed expert layer."""
+    cd = c.compute_dtype
+    use_cmm = c.tp_collective_matmul
+    if use_cmm:
+        from ..ops import collective_matmul as _cm
+
     h = _norm(c, x, layer["ln2_scale"], layer.get("ln2_bias"))
     if c.n_experts > 0:
         from .moe import moe_mlp
 
-        h, aux = moe_mlp(c, layer, h, keys[1], deterministic)
+        h, aux = moe_mlp(c, layer, h, dropout_key, deterministic)
         return x + h, aux
     if c.mlp_act == "swiglu":
         if use_cmm:
@@ -769,10 +800,11 @@ def _block(
         ).astype(cd)
     if "bproj" in layer:
         h = h + layer["bproj"].astype(cd)
-    h = _dropout(h, c.dropout, keys[1], deterministic)
+    h = _dropout(h, c.dropout, dropout_key, deterministic)
     return x + h, jnp.zeros((), jnp.float32)
 
 
+@jax.named_scope(scopes.EMBED)
 def embed(
     config: TinyGPTConfig,
     params: Params,
@@ -913,6 +945,7 @@ def head_param_names(config: TinyGPTConfig) -> Tuple[str, ...]:
     return tuple(names)
 
 
+@jax.named_scope(scopes.HEAD)
 def head(config: TinyGPTConfig, params: Params, x: jax.Array) -> jax.Array:
     """Final norm + LM head -> fp32 logits (B, S, V).
 
@@ -991,6 +1024,7 @@ def moe_overflow_fraction(
     return aux / c.n_layer
 
 
+@jax.named_scope(scopes.LOSS)
 def _cross_entropy_parts(
     logits: jax.Array, targets: jax.Array
 ) -> Tuple[jax.Array, jax.Array]:
@@ -1019,7 +1053,8 @@ def _cross_entropy(
     if seq_axis is not None:
         nll_sum = lax.psum(nll_sum, seq_axis)
         count = lax.psum(count, seq_axis)
-    return nll_sum / jnp.maximum(count, 1)
+    with jax.named_scope(scopes.LOSS):
+        return nll_sum / jnp.maximum(count, 1)
 
 
 def loss_fn(

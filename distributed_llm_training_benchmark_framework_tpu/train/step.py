@@ -29,6 +29,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..models import tinygpt
 from ..parallel import strategies as strat
+from ..utils import scopes
 
 Params = Any
 
@@ -456,7 +457,8 @@ def make_train_step(
         # Sentinel guard value: the global grad-norm, BEFORE any layout
         # constraint (the norm is layout-invariant; computing it here lets
         # XLA fuse the partial sums into the backward pass it just ran).
-        gnorm = global_norm_f32(grads) if sentinel else None
+        with jax.named_scope(scopes.OPTIMIZER):
+            gnorm = global_norm_f32(grads) if sentinel else None
 
         if strategy.shard_grads:
             # Pin the gradient layout for every sharded-grad strategy.
@@ -478,24 +480,28 @@ def make_train_step(
             # host memory, the full update + apply run on the host CPU, and
             # the device's bf16 compute params are refreshed from the
             # masters (see strategies.offload_update_and_apply).
-            new_params, new_opt_state = strat.offload_update_and_apply(
-                strategy, grads, opt_state, params, mesh,
-                grad_sharded_specs if (
-                    strategy.shard_grads and not strategy.shard_params
-                ) else param_specs,
-                param_specs,
-            )
+            with jax.named_scope(scopes.OPTIMIZER):
+                new_params, new_opt_state = strat.offload_update_and_apply(
+                    strategy, grads, opt_state, params, mesh,
+                    grad_sharded_specs if (
+                        strategy.shard_grads and not strategy.shard_params
+                    ) else param_specs,
+                    param_specs,
+                )
             if sentinel:
                 return new_params, new_opt_state, loss, gnorm
             return new_params, new_opt_state, loss
 
-        updates, new_opt_state = optimizer.update(grads, opt_state, params)
+        with jax.named_scope(scopes.OPTIMIZER):
+            updates, new_opt_state = optimizer.update(grads, opt_state, params)
 
-        if strategy.shard_grads and not strategy.shard_params:
-            # ZeRO-2: all-gather the (sharded) updates back onto replicated params.
-            updates = lax.with_sharding_constraint(updates, strat.named(mesh, param_specs))
+            if strategy.shard_grads and not strategy.shard_params:
+                # ZeRO-2: all-gather the (sharded) updates back onto replicated params.
+                updates = lax.with_sharding_constraint(
+                    updates, strat.named(mesh, param_specs)
+                )
 
-        new_params = optax.apply_updates(params, updates)
+            new_params = optax.apply_updates(params, updates)
         if sentinel:
             return new_params, new_opt_state, loss, gnorm
         return new_params, new_opt_state, loss

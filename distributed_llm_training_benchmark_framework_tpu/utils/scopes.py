@@ -1,0 +1,12 @@
+"""The names the model and the train step put into a profile.
+
+Each is a ``jax.named_scope``: it writes a path component into the
+``op_name`` metadata of every HLO instruction traced under it and costs
+nothing at run time. What forms a name takes in ``op_name`` after
+differentiation and remat, and how a trace is read by them, is in
+docs/OBSERVABILITY.md ("Names in a profile").
+"""
+
+EMBED, ATTENTION, MLP, DROPOUT, HEAD, LOSS, OPTIMIZER = SCOPES = (
+    "embed", "attention", "mlp", "dropout", "head", "loss", "optimizer",
+)
