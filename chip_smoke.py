@@ -8,7 +8,7 @@ One process, no child: a chip belongs to one process at a time. It fails
 unless ``jax.devices()[0].platform`` is ``"tpu"``, and then
 
 (a) runs the flash-attention forward and both backward paths (the XLA
-    einsum backward and the Pallas dq / dk+dv kernels) as Mosaic kernels,
+    einsum backward and the fused Pallas backward kernel) as Mosaic kernels,
     never interpret mode, at TinyGPT tier-A widths, and compares outputs
     and gradients with ``reference_attention`` on the device;
 (b) drives ``train.harness.main`` — the CLI behind
@@ -126,7 +126,7 @@ def kernel_phase():
         compiled = step.lower(q, k, v).compile()
         if not INTERPRET:
             n = compiled.as_text().count('custom_call_target="tpu_custom_call"')
-            check(n == (3 if pallas_backward else 1),
+            check(n == (2 if pallas_backward else 1),
                   f"{name}: expected Mosaic kernels in the HLO, found {n}")
         (_, got_out), got_grads = compiled(q, k, v)
         err_out = rel_err(got_out, want_out)

@@ -16,13 +16,17 @@ Forward (Pallas kernel):
 - also emits the per-row logsumexp, the residual the backward pass needs;
 - ``causal=True`` masks by global position and skips fully-masked k blocks.
 
-Backward (custom VJP): recomputes attention probabilities blockwise over K
-from the saved logsumexp — the standard flash backward — with two
-implementations sharing the same math: an XLA-fused ``lax.scan`` of dense
-jnp blocks (peak memory O(S * block)), and hand-written Pallas dq / dk+dv
-kernels. Which is faster is S-dependent on v5e (einsum to S=2048, kernels
-from S=4096 with margins growing to +88% at 16K — docs/PERFORMANCE.md §12);
-``pallas_backward=None`` auto-selects by the measured crossover.
+Backward (custom VJP): recomputes attention probabilities tile by tile from
+the saved logsumexp — the standard flash backward — with two implementations
+sharing the same math: an XLA-fused ``lax.scan`` of dense jnp blocks over K
+(peak memory O(S * block)), and one hand-written Pallas kernel that makes
+dq, dk and dv from a single visit of each score tile (``_bwd_fused_kernel``).
+Which is faster is S-dependent on v5e (einsum to S=2048, the kernel from
+S=4096 — docs/PERFORMANCE.md §12); ``pallas_backward=None`` auto-selects by
+that crossover. The older dq and dk+dv kernel pair visits every tile twice;
+ring attention's per-hop backward still runs it (dq stays local there while
+dk / dv travel), and plain flash falls back to it only where the fused
+kernel's resident dq row would not fit VMEM (``_fused_fits``).
 
 Dispatch (``_resolve_interpret``): on a TPU backend the Mosaic kernels are the
 only path — interpret mode is refused there, and the ``jnp`` fallbacks below
@@ -122,13 +126,19 @@ def _pick_block(seq_len: int, preferred: int = 512) -> int:
 _FWD_BLOCK_Q = 1024
 _FWD_BLOCK_K = 1024
 _BWD_BLOCK_K = 512
+# The fused Pallas backward (S >= _PALLAS_BWD_MIN_SEQ) is fastest at
+# 1024x1024 at both head dims the benchmark runs (PERF.md, PR 25's sweep);
+# its q tile is the forward's block_q.
+_FUSED_BWD_BLOCK_K = 1024
 
-# Backward implementation crossover, measured on v5e tier A (docs/
-# PERFORMANCE.md §12): the XLA-fused blockwise-einsum backward wins at
-# S=2048 (41.6k vs 38.4k tok/s) but the Pallas backward kernels win from
-# S=4096 up, by growing margins (+14% @4K, +45% @8K, +88% @16K) — the
+# Backward implementation crossover, measured on v5e tier A with the dq /
+# dk+dv kernel pair (docs/PERFORMANCE.md §12): the XLA-fused blockwise-einsum
+# backward won at S=2048 (41.6k vs 38.4k tok/s) but the Pallas backward won
+# from S=4096 up, by growing margins (+14% @4K, +45% @8K, +88% @16K) — the
 # einsum path's (BH, S, bk) probability tiles become HBM-bandwidth-bound
-# while the kernels keep them in VMEM. pallas_backward=None picks by S.
+# while a kernel keeps them in VMEM. pallas_backward=None picks by S. Not
+# re-measured with the fused kernel (the benchmark's attn_kernel_roofline
+# reader hard-codes the same 4096).
 _PALLAS_BWD_MIN_SEQ = 4096
 
 
@@ -351,9 +361,10 @@ def _bwd_dq_kernel(
 ):
     """dq = sum over k blocks of ds @ k, ds = p * (dp - delta) * scale.
 
-    Shared by plain flash, ring attention's per-block backward, and the
-    zigzag ring layout: the SMEM vectors ``qoff_ref`` (nq,) / ``koff_ref``
-    (nk,) carry each TILE's global base row/col — arange(n)*b for plain
+    Ring attention's per-block backward (contiguous and zigzag layouts) and
+    plain flash's fallback past the fused kernel's VMEM cap: the SMEM
+    vectors ``qoff_ref`` (nq,) / ``koff_ref`` (nk,) carry each TILE's global
+    base row/col — arange(n)*b for plain
     flash, shard-offset + arange for contiguous ring blocks, per-half-chunk
     bases for zigzag — so causal masking and the dropout hash always see
     absolute coordinates from one kernel implementation. Tiles must be
@@ -488,10 +499,11 @@ def _bwd_dkv_kernel(
 def _jnp_blockwise_bwd(causal, bk, rate, res, do):
     """Blockwise flash backward as batched einsums over a K-block scan.
 
-    Same math as the Pallas kernels below, expressed as XLA-fused dense
-    einsums: only (S, bk) tiles materialize. Measured FASTER than the Pallas
-    backward on v5e (XLA schedules the batched-over-heads contractions onto
-    the MXU better than the per-(head, tile) kernel grid) — hence the default.
+    Same math as the Pallas kernels, expressed as XLA-fused dense einsums:
+    only (S, bk) tiles materialize, in HBM. The backward below
+    ``_PALLAS_BWD_MIN_SEQ`` on a TPU (there XLA's batched-over-heads
+    contractions beat the kernel pair's per-(head, tile) grid), and in
+    interpret mode at any S.
 
     With dropout (out = (D∘P) @ V, D = keep/keep_prob): dV = (D∘P)^T dO, and
     the softmax-Jacobian identity dS = P∘(D∘dP - delta) still holds with
@@ -559,39 +571,169 @@ def _jnp_blockwise_bwd(causal, bk, rate, res, do):
     return dq.astype(q.dtype), dk.astype(k.dtype), dv.astype(v.dtype)
 
 
-def _flash_bwd_rule(opts, res, do):
-    """Flash backward: recompute attention probabilities per tile from the
-    saved logsumexp. Two implementations, selected by ``pallas_backward``:
-    the default XLA-fused blockwise einsum path (faster on v5e), and the
-    hand-written Pallas kernel pair (dq; dk/dv) below.
-    """
-    causal, interpret, bq, bk_fwd, bk, pallas_bwd, rate = opts
-    # seed and the bh ids are integral: no tangent.
-    int_cts = (
-        np.zeros((1,), jax.dtypes.float0),
-        np.zeros(res[6].shape, jax.dtypes.float0),
-    )
-    from ..utils.vma import vma_of
+def _bwd_fused_kernel(
+    seed_ref, bhv_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
+    dq_ref, dk_ref, dv_ref, dq_acc, dk_acc, dv_acc,
+    *, bq: int, bk: int, scale: float, causal: bool, dropout_rate: float,
+):
+    """dq, dk and dv from ONE visit of each live (k tile, q tile): s, p, dp,
+    the keep mask and ds are computed once and feed all three products
+    (5 tile matmuls, 1 exp, 1 hash; the dq + dk/dv pair spends 7, 2, 2).
 
-    if pallas_bwd and interpret and vma_of(*res[:3], do):
-        # Same limitation the forward's _jnp_reference_forward fallback works
-        # around: the Pallas HLO interpreter cannot run on vma-carrying
-        # operands (manual regions in interpret mode) — take the jnp backward.
-        pallas_bwd = False
-    if not pallas_bwd:
-        return (*_jnp_blockwise_bwd(causal, bk, rate, res, do), *int_cts)
-    q, k, v, out, lse, seed, bhv = res
+    Grid (BH, k tiles, q tiles), q innermost: ``dk_acc`` / ``dv_acc`` hold
+    one k tile and are written out at its last q tile; ``dq_acc`` holds the
+    whole (S, D) row of the (batch, head) pair in VMEM, each q tile's slice
+    zeroed in the first k pass, added to in every pass (k tiles ascending,
+    the order _bwd_dq_kernel sums in) and written out in the last. Tile i
+    starts at row i*b: plain flash only (ring keeps the kernel pair).
+
+    The score tile is held k-major, (bk, bq): dv and dk are then plain
+    products, only dq contracts over the tile's first dim (one tile
+    transpose where a q-major body has two), and lse / delta broadcast
+    along sublanes from the (8, bq) blocks as they arrive. Same tile
+    products as the pair: bit-identical to it at equal tile sizes."""
+    bh = pl.program_id(0)
+    ki = pl.program_id(1)
+    qi = pl.program_id(2)
+    nk = pl.num_programs(1)
+    nq = pl.num_programs(2)
+    q_off = qi * bq
+    k_off = ki * bk
+    q_rows = pl.ds(pl.multiple_of(q_off, bq), bq)
+
+    @pl.when(qi == 0)
+    def _init_kv():
+        dk_acc[:] = jnp.zeros_like(dk_acc)
+        dv_acc[:] = jnp.zeros_like(dv_acc)
+
+    @pl.when(ki == 0)
+    def _init_q():
+        dq_acc[q_rows, :] = jnp.zeros((bq, dq_acc.shape[1]), dq_acc.dtype)
+
+    live = True if not causal else (q_off + bq - 1 >= k_off)
+
+    @pl.when(live)
+    def _accumulate():
+        q = q_ref[0]
+        k = k_ref[0]
+        v = v_ref[0]
+        do = do_ref[0]
+        lse = lse_ref[0][:1]      # (1, bq)
+        delta = delta_ref[0][:1]  # (1, bq)
+        s = lax.dot_general(
+            k, q, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+        ) * scale  # (bk, bq)
+        # Narrow coordinate operands, as in the other kernels; query
+        # positions ("rows" of the hash) run along lanes here.
+        rows = q_off + lax.broadcasted_iota(jnp.int32, (1, bq), 1)
+        cols = k_off + lax.broadcasted_iota(jnp.int32, (bk, 1), 0)
+        if causal:
+            mask = rows >= cols
+            s = jnp.where(mask, s, NEG_INF)
+        p = jnp.exp(s - lse)
+        if causal:
+            p = jnp.where(mask, p, 0.0)
+        dp = lax.dot_general(
+            v, do, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+        )
+        if dropout_rate > 0.0:
+            keep = _dropout_keep(
+                seed_ref[0], bhv_ref[bh], rows, cols,
+                _dropout_threshold(dropout_rate),
+            )
+            inv = 1.0 / (1.0 - dropout_rate)
+            pd = jnp.where(keep, p * inv, 0.0)
+            dp = jnp.where(keep, dp * inv, 0.0)
+        else:
+            pd = p
+        dv_acc[:] = dv_acc[:] + lax.dot_general(
+            pd.astype(q.dtype), do, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )
+        ds = (p * (dp - delta) * scale).astype(q.dtype)
+        dk_acc[:] = dk_acc[:] + lax.dot_general(
+            ds, q, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
+        )
+        dq_acc[q_rows, :] = dq_acc[q_rows, :] + lax.dot_general(
+            ds, k, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32
+        )
+
+    @pl.when(qi == nq - 1)
+    def _finalize_kv():
+        dk_ref[0] = dk_acc[:].astype(dk_ref.dtype)
+        dv_ref[0] = dv_acc[:].astype(dv_ref.dtype)
+
+    @pl.when(ki == nk - 1)
+    def _finalize_q():
+        dq_ref[0, q_rows, :] = dq_acc[q_rows, :].astype(dq_ref.dtype)
+
+
+# VMEM the fused backward may take. The resident dq row is what grows with
+# S: an f32 accumulator plus the double-buffered output block, lanes padded
+# to 128. The tiles and Mosaic's temporaries at (1024, 1024) stayed under
+# 16 MiB at every shape compiled; 32 is their allowance. A v5e core has
+# 128 MiB; past the cap (S 65536 at head dims to 128, bf16) the kernel pair
+# runs instead.
+_FUSED_TILE_VMEM = 32 * 2**20
+_FUSED_MAX_VMEM = 96 * 2**20
+
+
+def _fused_vmem_bytes(S: int, D: int, dtype) -> int:
+    lanes = -(-D // 128) * 128
+    return S * lanes * (4 + 2 * jnp.dtype(dtype).itemsize) + _FUSED_TILE_VMEM
+
+
+def _fused_fits(S: int, D: int, dtype) -> bool:
+    return _fused_vmem_bytes(S, D, dtype) <= _FUSED_MAX_VMEM
+
+
+def _fused_backward(
+    q, k, v, do, lse3, delta3, seed, bhv, causal, rate, bq, bk, interpret,
+):
+    """The one-kernel Pallas backward on (BH, S, D) operands."""
+    BH, S, D = q.shape
+    q_spec = pl.BlockSpec((1, bq, D), lambda b, ki, qi: (b, qi, 0))
+    k_spec = pl.BlockSpec((1, bk, D), lambda b, ki, qi: (b, ki, 0))
+    stat_spec = pl.BlockSpec((1, 8, bq), lambda b, ki, qi: (b, 0, qi))
+    smem = pl.BlockSpec(memory_space=pltpu.SMEM)
+    return pl.pallas_call(
+        functools.partial(
+            _bwd_fused_kernel, bq=bq, bk=bk, scale=1.0 / (D ** 0.5),
+            causal=causal, dropout_rate=rate,
+        ),
+        out_shape=[
+            _vma_struct((BH, S, D), q.dtype, q, k, v, do),
+            _vma_struct((BH, S, D), k.dtype, q, k, v, do),
+            _vma_struct((BH, S, D), v.dtype, q, k, v, do),
+        ],
+        grid=(BH, S // bk, S // bq),
+        in_specs=[smem, smem, q_spec, k_spec, k_spec, q_spec,
+                  stat_spec, stat_spec],
+        out_specs=[
+            pl.BlockSpec((1, S, D), lambda b, ki, qi: (b, 0, 0)),
+            k_spec, k_spec,
+        ],
+        scratch_shapes=[
+            pltpu.VMEM((S, D), jnp.float32),   # dq, the whole row
+            pltpu.VMEM((bk, D), jnp.float32),  # dk, one k tile
+            pltpu.VMEM((bk, D), jnp.float32),  # dv
+        ],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary", "arbitrary"),
+            vmem_limit_bytes=_fused_vmem_bytes(S, D, q.dtype),
+        ),
+        name="flash_bwd_fused",
+        interpret=interpret,
+    )(seed, bhv, q, k, v, do, lse3, delta3)
+
+
+def _pair_backward(
+    q, k, v, do, lse3, delta3, seed, bhv, causal, rate, bq, bk, interpret,
+):
+    """The dq and dk+dv kernel pair on (BH, S, D) operands: every score tile
+    visited twice. Only for shapes ``_fused_fits`` turns away."""
     BH, S, D = q.shape
     scale = 1.0 / (D ** 0.5)
-
-    delta = jnp.sum(
-        do.astype(jnp.float32) * out.astype(jnp.float32), axis=-1
-    )  # (BH, S)
-    # lse/delta enter the kernels sublane-broadcast as (BH, 8, S) to satisfy
-    # the (8, 128) input-tile constraint (same trick as the forward's output).
-    lse3 = jnp.broadcast_to(lse[:, None, :], (BH, 8, S))
-    delta3 = jnp.broadcast_to(delta[:, None, :], (BH, 8, S))
-
     seed_spec = pl.BlockSpec(memory_space=pltpu.SMEM)
     # Plain flash = the shared tile-base-aware kernels at identity bases
     # (tile i starts at row i*b); ring attention feeds shard offsets.
@@ -648,7 +790,44 @@ def _flash_bwd_rule(opts, res, do):
         ),
         interpret=interpret,
     )(seed, qoffs, koffs, bhv, q, k, v, do, lse3, delta3)
+    return dq, dk, dv
 
+
+def _flash_bwd_rule(opts, res, do):
+    """Flash backward: recompute attention probabilities per tile from the
+    saved logsumexp. ``pallas_backward`` (from S, in ``flash_attention``)
+    selects the XLA-fused blockwise einsum path or the Pallas one: the fused
+    kernel, or the dq / dk+dv pair where a whole dq row would not fit VMEM.
+    """
+    causal, interpret, bq, bk_fwd, bk, pallas_bwd, rate = opts
+    # seed and the bh ids are integral: no tangent.
+    int_cts = (
+        np.zeros((1,), jax.dtypes.float0),
+        np.zeros(res[6].shape, jax.dtypes.float0),
+    )
+    from ..utils.vma import vma_of
+
+    if pallas_bwd and interpret and vma_of(*res[:3], do):
+        # Same limitation the forward's _jnp_reference_forward fallback works
+        # around: the Pallas HLO interpreter cannot run on vma-carrying
+        # operands (manual regions in interpret mode) — take the jnp backward.
+        pallas_bwd = False
+    if not pallas_bwd:
+        return (*_jnp_blockwise_bwd(causal, bk, rate, res, do), *int_cts)
+    q, k, v, out, lse, seed, bhv = res
+    BH, S, D = q.shape
+
+    delta = jnp.sum(
+        do.astype(jnp.float32) * out.astype(jnp.float32), axis=-1
+    )  # (BH, S)
+    # lse/delta enter the kernels sublane-broadcast as (BH, 8, S) to satisfy
+    # the (8, 128) input-tile constraint (same trick as the forward's output).
+    lse3 = jnp.broadcast_to(lse[:, None, :], (BH, 8, S))
+    delta3 = jnp.broadcast_to(delta[:, None, :], (BH, 8, S))
+    backward = _fused_backward if _fused_fits(S, D, q.dtype) else _pair_backward
+    dq, dk, dv = backward(
+        q, k, v, do, lse3, delta3, seed, bhv, causal, rate, bq, bk, interpret
+    )
     return dq, dk, dv, *int_cts
 
 
@@ -753,7 +932,10 @@ def flash_attention(
         pallas_backward = (not interpret) and S >= _PALLAS_BWD_MIN_SEQ
     bq = block_q or _pick_block(S, _FWD_BLOCK_Q)
     bk = block_k or _pick_block(S, _FWD_BLOCK_K)
-    bk_bwd = block_k_bwd or _pick_block(S, _BWD_BLOCK_K)
+    fused = pallas_backward and _fused_fits(S, D, q.dtype)
+    bk_bwd = block_k_bwd or _pick_block(
+        S, _FUSED_BWD_BLOCK_K if fused else _BWD_BLOCK_K
+    )
     if S % bq != 0 or S % bk != 0 or S % bk_bwd != 0:
         raise ValueError(
             f"block sizes (block_q={bq}, block_k={bk}, block_k_bwd={bk_bwd}) "

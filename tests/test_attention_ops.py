@@ -133,7 +133,7 @@ def test_flash_dropout_block_size_invariant():
 
 @pytest.mark.parametrize("pallas_backward", [False, True])
 def test_flash_dropout_grad_matches_masked_reference(pallas_backward):
-    """Backward (both the jnp blockwise path and the Pallas kernel pair)
+    """Backward (both the jnp blockwise path and the fused Pallas kernel)
     regenerates the identical mask, at a different block size than the
     forward ran with."""
     rate = 0.2
@@ -156,6 +156,125 @@ def test_flash_dropout_grad_matches_masked_reference(pallas_backward):
     g2 = jax.grad(loss_ref, argnums=(0, 1, 2))(q, k, v)
     for a, b in zip(g1, g2):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=5e-3, atol=5e-3)
+
+
+def _backward_operands(S, H, D, causal, rate, dtype=jnp.float32):
+    """(BH, S, D) operands of the Pallas backward: q, k, v, do and the lse /
+    delta residuals of the real forward kernel (run at its own tiling)."""
+    from distributed_llm_training_benchmark_framework_tpu.ops import (
+        flash_attention as fa,
+    )
+
+    q, k, v = (t[0].transpose(1, 0, 2) for t in qkv(B=1, S=S, H=H, D=D, dtype=dtype))
+    do = jax.random.normal(jax.random.key(5), q.shape, dtype)
+    seed = jnp.asarray([99], jnp.uint32)
+    bhv = jnp.arange(H, dtype=jnp.int32)
+    out, lse = fa._flash_forward(q, k, v, causal, True, S // 2, S, rate, seed, bhv)
+    delta = jnp.sum(do.astype(jnp.float32) * out.astype(jnp.float32), axis=-1)
+    stat3 = lambda x: jnp.broadcast_to(x[:, None, :], (H, 8, S))
+    return q, k, v, do, stat3(lse), stat3(delta), seed, bhv
+
+
+@pytest.mark.parametrize("rate", [0.0, 0.2], ids=["nodrop", "dropout"])
+@pytest.mark.parametrize("causal", [False, True], ids=["full", "causal"])
+def test_flash_fused_backward_matches_kernel_pair(causal, rate):
+    """The one-kernel backward against the dq / dk+dv pair (ring's kernels)
+    on the same residuals: same tile products summed in the same order, so
+    the same numbers. block_q != block_k_bwd, neither the forward's."""
+    from distributed_llm_training_benchmark_framework_tpu.ops import (
+        flash_attention as fa,
+    )
+
+    args = _backward_operands(64, 2, 16, causal, rate)
+    fused = fa._fused_backward(*args, causal, rate, 16, 32, True)
+    pair = fa._pair_backward(*args, causal, rate, 16, 32, True)
+    for got, want in zip(fused, pair):
+        np.testing.assert_allclose(
+            np.asarray(got), np.asarray(want), rtol=1e-6, atol=1e-6
+        )
+
+
+@pytest.mark.parametrize("rate", [0.0, 0.2], ids=["nodrop", "dropout"])
+@pytest.mark.parametrize("causal", [False, True], ids=["full", "causal"])
+def test_flash_fused_backward_matches_masked_reference(causal, rate):
+    """jax.grad through flash_attention with the Pallas backward (the fused
+    kernel) against the materialized f32 reference under the same mask."""
+    B, S, H, D = 1, 64, 2, 16
+    q, k, v = qkv(B=B, S=S, H=H, D=D)
+    seed = jnp.asarray(99, jnp.uint32)
+    keep = (
+        _hash_keep_mask(99, B, H, S, rate) if rate
+        else jnp.ones((B, H, S, S), bool)
+    )
+    w = jax.random.normal(jax.random.key(3), q.shape)
+
+    def loss_flash(q, k, v):
+        return (w * flash_attention(
+            q, k, v, causal=causal, interpret=True, block_q=16, block_k=64,
+            block_k_bwd=32, dropout_rate=rate,
+            dropout_seed=seed if rate else None, pallas_backward=True,
+        )).sum()
+
+    def loss_ref(q, k, v):
+        return (w * _masked_reference(q, k, v, keep, rate, causal=causal)).sum()
+
+    g1 = jax.grad(loss_flash, argnums=(0, 1, 2))(q, k, v)
+    g2 = jax.grad(loss_ref, argnums=(0, 1, 2))(q, k, v)
+    for a, b in zip(g1, g2):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=5e-3, atol=5e-3)
+
+
+def test_flash_fused_backward_revisits_the_dq_row():
+    """8 k tiles x 4 q tiles, bf16 operands: every slice of the resident dq
+    row is zeroed once, added to in each of the 8 passes and written out in
+    the last; dk / dv restart at every k tile."""
+    from distributed_llm_training_benchmark_framework_tpu.ops import (
+        flash_attention as fa,
+    )
+
+    args = _backward_operands(128, 2, 32, True, 0.1, dtype=jnp.bfloat16)
+    fused = fa._fused_backward(*args, True, 0.1, 32, 16, True)
+    pair = fa._pair_backward(*args, True, 0.1, 32, 16, True)
+    for got, want in zip(fused, pair):
+        assert got.dtype == jnp.bfloat16
+        np.testing.assert_array_equal(
+            np.asarray(got, np.float32), np.asarray(want, np.float32)
+        )
+
+
+def test_flash_backward_takes_the_pair_when_the_dq_row_outgrows_vmem(monkeypatch):
+    """The fused kernel keeps a whole (S, D) dq row in VMEM; a shape past the
+    cap runs the kernel pair, chosen from the shape alone."""
+    from distributed_llm_training_benchmark_framework_tpu.ops import (
+        flash_attention as fa,
+    )
+
+    for S, D in ((8192, 64), (4096, 128), (65536, 128)):
+        assert fa._fused_fits(S, D, jnp.bfloat16)
+    assert not fa._fused_fits(131072, 64, jnp.bfloat16)
+
+    calls = []
+    for name in ("_fused_backward", "_pair_backward"):
+        real = getattr(fa, name)
+        monkeypatch.setattr(
+            fa, name,
+            lambda *a, _n=name, _r=real: calls.append(_n) or _r(*a),
+        )
+    q, k, v = qkv(B=1, S=48, H=1, D=16)  # a shape no other test traces
+
+    def grad(block):
+        return jax.grad(lambda q: fa.flash_attention(
+            q, k, v, interpret=True, pallas_backward=True, block_q=block,
+            block_k=block, block_k_bwd=block,
+        ).sum())(q)
+
+    fused = grad(16)
+    monkeypatch.setattr(fa, "_FUSED_MAX_VMEM", 0)
+    pair = grad(24)
+    assert calls == ["_fused_backward", "_pair_backward"]
+    np.testing.assert_allclose(
+        np.asarray(fused), np.asarray(pair), rtol=1e-5, atol=1e-5
+    )
 
 
 def test_flash_dropout_keep_statistics():

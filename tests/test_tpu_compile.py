@@ -81,11 +81,13 @@ def test_flash_forward_compiles(v5e_devices, width):
 
 @pytest.mark.parametrize("width", [TIER_A, LLAMA_A], ids=["16x64", "8x128"])
 @pytest.mark.parametrize(
-    "seq,n_kernels", [(2048, 1), (4096, 3)], ids=["einsum-bwd", "pallas-bwd"]
+    "seq,n_kernels", [(2048, 1), (4096, 2), (8192, 2)],
+    ids=["einsum-bwd", "pallas-bwd", "pallas-bwd-8k"],
 )
 def test_flash_backward_compiles(v5e_devices, width, seq, n_kernels):
     """fwd + the backward the S crossover picks: the XLA einsum backward at
-    2048 (one kernel: the forward), the dq and dk/dv kernels from 4096."""
+    2048 (one kernel: the forward), the one fused kernel from 4096 (8192 at
+    16x64 with dropout is what ``tinygpt-a.seq8192`` runs)."""
     heads, head_dim, causal, rate = width
     one = SingleDeviceSharding(v5e_devices[0])
     text = _compile(
@@ -93,6 +95,28 @@ def test_flash_backward_compiles(v5e_devices, width, seq, n_kernels):
         *_qkv(one, one, 1, seq, heads, head_dim),
     )
     assert text.count("custom_call_target=\"tpu_custom_call\"") == n_kernels
+    assert ("flash_bwd_fused" in text) == (n_kernels == 2)
+
+
+def test_fused_backward_compiles_at_its_vmem_cap(v5e_devices):
+    """The longest sequence ``_fused_fits`` lets through (the resident dq row
+    is what grows with S) compiles under the limit the call asks for."""
+    one = SingleDeviceSharding(v5e_devices[0])
+    S, D = 65536, 128
+    assert fa._fused_fits(S, D, jnp.bfloat16)
+    assert not fa._fused_fits(2 * S, D, jnp.bfloat16)  # so this is the cap
+
+    def aval(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+
+    x, stat = aval((1, S, D), jnp.bfloat16), aval((1, 8, S), jnp.float32)
+    text = _compile(
+        lambda q, k, v, do, lse, d, seed, bh: fa._fused_backward(
+            q, k, v, do, lse, d, seed, bh, True, 0.0, 1024, 1024, False
+        ),
+        x, x, x, x, stat, stat, aval((1,), jnp.uint32), aval((1,), jnp.int32),
+    )
+    assert "flash_bwd_fused" in text
 
 
 def test_ring_block_kernels_compile(v5e_devices):
