@@ -1,10 +1,32 @@
-"""Mixture-of-Experts MLP with expert parallelism — GShard-style dispatch.
+"""Mixture-of-Experts MLP: two families of routing.
 
 The reference lists "Mistral/Mixtral architectures" and MoE only as future
 work (reference ``README.md:1025``); here sparse expert layers are a
 first-class model family with their own mesh axis.
 
-Two dispatch formulations share the same routing math:
+**Dropless** (``capacity_factor=None``; ``_moe_mlp_dropless``) is what
+OLMoE-class models train with: every token's ``expert_top_k`` assignments are
+computed, none dropped and none padded away. The N x K assignments are sorted
+by expert, the rows gathered into expert order, the experts run as grouped
+matmuls over the per-expert row counts (the Pallas ``gmm`` / ``tgmm`` that
+ship with jax, ``jax.experimental.pallas.ops.tpu.megablox``; they show in a
+trace under those names), and the rows go back to token order weighted by
+their gates. Experts are SwiGLU without bias (``moe_wgu`` (E, D, 2F), gate
+columns then up columns, and ``moe_wd`` (E, F, D); the config pairs
+``mlp_act='swiglu'`` with this path); gates are renormalised only if
+``norm_topk_prob``; the auxiliary channel carries the load-balance term over
+all K choices plus the router z-loss. Its parts carry the scopes ``router`` /
+``dispatch`` / ``experts`` / ``combine`` (``utils/scopes.MOE_SCOPES``). It
+runs on one chip's tokens: the 'expert' axis all-to-all around it is not
+written yet.
+
+**Capacity** (a number for ``capacity_factor``; the GShard-style paths below)
+is the E = 8 top-2 GELU toy with biases and the only path across an 'expert'
+mesh axis today. It drops what overflows an expert's buffer, and its one-hot
+dispatch tensors are (N, E, C): fine at toy sizes, 0.67 G elements each at
+OLMoE's.
+
+Two capacity formulations share the same routing math:
 
 1. **Explicit all-to-all** (``_moe_mlp_a2a``) — the expert-parallel path.
    The batch is sharded over ``('data', 'expert')``
@@ -46,12 +68,16 @@ formulations optimize the same global statistic.
 
 from __future__ import annotations
 
+import math
 from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.experimental.pallas.ops.tpu.megablox import ops as megablox
 from jax.sharding import PartitionSpec as P
+
+from ..utils import scopes
 
 
 def capacity(n_tokens: int, n_experts: int, top_k: int, factor: float) -> int:
@@ -239,22 +265,133 @@ def _moe_mlp_a2a(c, layer, x, dropout_key, deterministic, mesh, ep, dp):
     )
 
 
+@jax.custom_vjp
+def _permute_rows(x: jax.Array, perm: jax.Array, inverse: jax.Array) -> jax.Array:
+    """``x[perm]`` for a permutation of the rows. Its transpose is the gather
+    by ``inverse``; autodiff of the indexing alone would emit a scatter-add
+    over rows it cannot know are distinct."""
+    return x[perm]
+
+
+def _permute_rows_fwd(x, perm, inverse):
+    return x[perm], inverse
+
+
+def _permute_rows_bwd(inverse, g):
+    return g[inverse], None, None
+
+
+_permute_rows.defvjp(_permute_rows_fwd, _permute_rows_bwd)
+
+
+def _route_dropless(c, xt: jax.Array, router: jax.Array):
+    """Token-choice routing with nothing dropped -> (gates (N, K) fp32,
+    expert_idx (N, K), counts (E,) int32, aux).
+
+    fp32 throughout, the logits at the highest matmul precision (a TPU's
+    default would round the router to bfloat16, and near-ties in the top-k
+    flip on less). ``counts[e]`` is how many of the N x K assignments chose
+    expert e; they sum to N x K. ``aux`` is in units of ``router_aux_coef``,
+    the one scalar the layer loop carries: E * sum_e f_e * P_e over all K
+    choices (f_e = counts / (N K), P_e the mean probability) plus
+    ``router_z_coef / router_aux_coef`` times the z-loss, the mean over tokens
+    of logsumexp(logits)^2.
+    """
+    N = xt.shape[0]
+    E, K = c.n_experts, c.expert_top_k
+    logits = jnp.einsum(
+        "nd,de->ne", xt.astype(jnp.float32), router.astype(jnp.float32),
+        precision=lax.Precision.HIGHEST, preferred_element_type=jnp.float32,
+    )
+    probs = jax.nn.softmax(logits, axis=-1)
+    gates, expert_idx = lax.top_k(probs, K)
+    if c.norm_topk_prob:
+        gates = gates / jnp.maximum(jnp.sum(gates, axis=-1, keepdims=True), 1e-9)
+    counts = jnp.sum(jax.nn.one_hot(expert_idx, E, dtype=jnp.int32), axis=(0, 1))
+    aux = E * jnp.sum(counts.astype(jnp.float32) / (N * K) * jnp.mean(probs, axis=0))
+    if c.router_z_coef:
+        z = jnp.mean(jnp.square(jax.nn.logsumexp(logits, axis=-1)))
+        aux = aux + (c.router_z_coef / c.router_aux_coef) * z
+    return gates, expert_idx, counts, aux
+
+
+# (rows, contraction, columns) tile of the grouped matmuls, from a sweep on the
+# v5e at OLMoE's sizes (scripts/microbench_moe_experts.py; PERF.md, PR 26).
+_GMM_TILING = (512, 1024, 1024)
+
+
+def _grouped_matmul(c, rows: jax.Array, weights: jax.Array, counts: jax.Array) -> jax.Array:
+    """(M, K) rows sorted by expert x (E, K, N) -> (M, N): the rows of group e
+    times ``weights[e]``, bf16 MXU / fp32 accumulation. The Pallas grouped
+    matmul that ships with jax; its backward is one more ``gmm`` (the rows'
+    gradient) and a ``tgmm`` (the weights'). Off a TPU it runs interpreted."""
+    tm, tk, tn = _GMM_TILING
+    tiling = (math.gcd(rows.shape[0], tm), min(tk, rows.shape[1]), min(tn, weights.shape[2]))
+    return megablox.gmm(
+        rows, weights.astype(c.compute_dtype), counts, c.compute_dtype, tiling,
+        interpret=jax.default_backend() != "tpu",
+    )
+
+
+def _experts_dropless(c, layer, rows: jax.Array, counts: jax.Array) -> jax.Array:
+    """SwiGLU experts over rows in expert order, (M, D) -> (M, D)."""
+    F = c.mlp_dim
+    gu = _grouped_matmul(c, rows, layer["moe_wgu"], counts)  # gate and up in one matmul
+    h = jax.nn.silu(gu[:, :F]) * gu[:, F:]
+    return _grouped_matmul(c, h, layer["moe_wd"], counts)
+
+
+def _moe_mlp_dropless(c, layer, x, dropout_key, deterministic):
+    """Sort by expert, grouped matmuls, weighted sum back (module docstring)."""
+    from .tinygpt import _dropout
+
+    B, S, D = x.shape
+    N, K = B * S, c.expert_top_k
+    xt = x.reshape(N, D)
+    with jax.named_scope(scopes.ROUTER):
+        gates, expert_idx, counts, aux = _route_dropless(c, xt, layer["router"])
+    with jax.named_scope(scopes.DISPATCH):
+        # Assignment n*K + k is token n's k-th choice; ``order`` lists the
+        # assignments expert by expert, ``inverse`` is where each one went.
+        order = jnp.argsort(expert_idx.reshape(N * K), stable=True)
+        inverse = jnp.argsort(order)
+        rows = _permute_rows(jnp.repeat(xt, K, axis=0), order, inverse)
+    with jax.named_scope(scopes.EXPERTS):
+        out = _experts_dropless(c, layer, rows, counts)
+    with jax.named_scope(scopes.COMBINE):
+        back = _permute_rows(out, inverse, order).reshape(N, K, D)
+        y = jnp.sum(back.astype(jnp.float32) * gates[:, :, None], axis=1).astype(x.dtype)
+    y = _dropout(y, c.dropout, dropout_key, deterministic)
+    if c.moe_aux_mode == "overflow":
+        aux = jnp.zeros((), jnp.float32)  # nothing is ever dropped here
+    return y.reshape(B, S, D), aux
+
+
+def expert_counts(config, layer: dict, x: jax.Array) -> jax.Array:
+    """(E,) int32: how many of the N x K assignments of ``x`` (B, S, D), the
+    MLP's normed input, chose each expert at this layer's router."""
+    return _route_dropless(config, x.reshape(-1, x.shape[-1]), layer["router"])[2]
+
+
 def moe_mlp(
     config,
-    layer: dict,  # one layer's params: router, moe_w1/b1, moe_w2/b2
+    layer: dict,  # one layer's params: router, moe_w1/b1 + moe_w2/b2 or moe_wgu + moe_wd
     x: jax.Array,  # (B, S, D) compute dtype
     dropout_key: Optional[jax.Array],
     deterministic: bool,
 ) -> Tuple[jax.Array, jax.Array]:
     """-> (output (B,S,D), aux load-balance loss scalar fp32).
 
-    Picks the dispatch formulation per ``config.moe_dispatch`` (module
-    docstring): the explicit all-to-all path needs a mesh in scope with a
-    >1 'expert' axis, divisible geometry, and no manual/sequence/tensor/
-    pipeline axes in play; anything else falls back to the GSPMD einsums.
+    ``capacity_factor=None`` is the dropless path. Otherwise picks the
+    capacity formulation per ``config.moe_dispatch`` (module docstring): the
+    explicit all-to-all path needs a mesh in scope with a >1 'expert' axis,
+    divisible geometry, and no manual/sequence/tensor/pipeline axes in play;
+    anything else falls back to the GSPMD einsums.
     """
     c = config
     B, S, D = x.shape
+    if c.capacity_factor is None:
+        return _moe_mlp_dropless(c, layer, x, dropout_key, deterministic)
     mesh = None
     if c.moe_dispatch != "einsum" and c.seq_manual_axis is None:
         m = jax.sharding.get_abstract_mesh()

@@ -114,8 +114,19 @@ class TinyGPTConfig:
     # the Switch load-balance auxiliary term.
     n_experts: int = 0
     expert_top_k: int = 2
-    capacity_factor: float = 1.25
+    # A number: each expert accepts at most ceil(factor * k * N / E) tokens and
+    # drops the rest (the GELU toy; the 'expert'-axis path). None: dropless
+    # routing, every assignment computed through grouped matmuls (OLMoE-class
+    # SwiGLU experts; models.moe module docstring).
+    capacity_factor: Optional[float] = 1.25
     router_aux_coef: float = 0.01
+    # Renormalise each token's chosen gates to sum to 1 (the capacity path
+    # always does). OLMoE does not: norm_topk_prob false.
+    norm_topk_prob: bool = True
+    # Router z-loss coefficient (mean over tokens of logsumexp(logits)^2;
+    # dropless path only). The layer loop's one aux scalar carries it in
+    # units of router_aux_coef, which must then be > 0.
+    router_z_coef: float = 0.0
     # Zigzag causal load balancing on ring attention: None = auto (on for
     # causal rings with even local shards — ops/ring_attention.py), True =
     # force (errors when the geometry can't), False = force the contiguous
@@ -161,6 +172,10 @@ class TinyGPTConfig:
     # projection splits into separate wq/wkv leaves (the fused wqkv layout
     # only exists for the square MHA case).
     n_kv_head: Optional[int] = None
+    # QK-norm (OLMoE): an RMSNorm with its own learned scale over the whole
+    # projected q vector and over the whole projected k vector, before the
+    # split into heads and before rope. Leaves q_norm / k_norm.
+    qk_norm: bool = False
     # Linear/LayerNorm biases (Llama ships none anywhere).
     bias: bool = True
     # Weight-tied LM head (reference train_harness.py:61-62). False adds a
@@ -235,10 +250,24 @@ class TinyGPTConfig:
             raise ValueError(
                 f"n_kv_head={self.n_kv_head} must divide n_head={self.n_head}"
             )
-        if self.n_experts > 0 and self.mlp_act != "gelu":
+        dropless = self.capacity_factor is None
+        if self.n_experts > 0 and dropless != (self.mlp_act == "swiglu" and not self.bias):
             raise ValueError(
-                "MoE blocks are defined for the dense-GELU MLP only "
-                "(n_experts > 0 with mlp_act='swiglu' is not supported)"
+                "MoE blocks come in two kinds: GELU experts with biases under a "
+                "capacity_factor, and SwiGLU experts without bias under dropless "
+                f"routing (capacity_factor=None); got mlp_act={self.mlp_act!r}, "
+                f"bias={self.bias}, capacity_factor={self.capacity_factor!r}"
+            )
+        if (self.router_z_coef or not self.norm_topk_prob) and not dropless:
+            raise ValueError(
+                "router_z_coef and norm_topk_prob=False belong to dropless routing "
+                "(capacity_factor=None); the capacity path renormalises its gates "
+                "and has no z-loss"
+            )
+        if self.router_z_coef and not self.router_aux_coef > 0:
+            raise ValueError(
+                "router_z_coef rides the aux channel in units of router_aux_coef, "
+                "which must be > 0"
             )
 
 
@@ -303,6 +332,15 @@ PARAM_AXIS_RULES: Dict[str, Tuple[Optional[str], ...]] = {
     "blocks/moe_b1": ("layers", "experts", "mlp"),
     "blocks/moe_w2": ("layers", "experts", "mlp", "embed"),
     "blocks/moe_b2": ("layers", "experts", "embed"),
+    # Dropless SwiGLU experts (present instead of moe_w1..b2 when
+    # capacity_factor is None). Gate and up share one matrix's columns (F of
+    # gate, then F of up): one grouped matmul, and no relayout of 268M
+    # parameters a step, which a (.., 2, F) pair of minor axes costs on a TPU.
+    "blocks/moe_wgu": ("layers", "experts", "embed", "gate_up"),
+    "blocks/moe_wd": ("layers", "experts", "mlp", "embed"),
+    # QK-norm scales (present when qk_norm): one per projected q / k feature.
+    "blocks/q_norm": ("layers", "heads"),
+    "blocks/k_norm": ("layers", "kv_heads"),
     "lnf_scale": ("embed",),
     "lnf_bias": ("embed",),
     # Untied LM head (present when tie_embeddings=False): same logical axes
@@ -349,18 +387,26 @@ def init_params(config: TinyGPTConfig, key: jax.Array) -> Params:
         if c.bias:
             blocks["bq"] = zeros((L, H * Dh))
             blocks["bkv"] = zeros((L, 2, Hkv * Dh))
+    if c.qk_norm:
+        blocks.update(q_norm=ones((L, H * Dh)), k_norm=ones((L, Hkv * Dh)))
     blocks["wo"] = normal(next(k), (L, D, D))
     if c.bias:
         blocks["bo"] = zeros((L, D))
     if c.n_experts > 0:
         E = c.n_experts
-        blocks.update(
-            router=normal(next(k), (L, D, E)),
-            moe_w1=normal(next(k), (L, E, D, F)),
-            moe_b1=zeros((L, E, F)),
-            moe_w2=normal(next(k), (L, E, F, D)),
-            moe_b2=zeros((L, E, D)),
-        )
+        blocks["router"] = normal(next(k), (L, D, E))
+        if c.capacity_factor is None:  # dropless SwiGLU experts, no bias
+            blocks.update(
+                moe_wgu=normal(next(k), (L, E, D, 2 * F)),
+                moe_wd=normal(next(k), (L, E, F, D)),
+            )
+        else:
+            blocks.update(
+                moe_w1=normal(next(k), (L, E, D, F)),
+                moe_b1=zeros((L, E, F)),
+                moe_w2=normal(next(k), (L, E, F, D)),
+                moe_b2=zeros((L, E, D)),
+            )
     elif c.mlp_act == "swiglu":
         blocks["wgu"] = normal(next(k), (L, D, 2, F))
         blocks["wproj"] = normal(next(k), (L, F, D))
@@ -675,8 +721,8 @@ def _attention_sublayer(
     dropout_key: Optional[jax.Array],
     deterministic: bool,
 ) -> jax.Array:
-    """Norm -> q/k/v projections -> rope -> attention -> output projection
-    -> residual: the first half of ``_block``."""
+    """Norm -> q/k/v projections -> QK-norm -> rope -> attention -> output
+    projection -> residual: the first half of ``_block``."""
     B, S, D = x.shape
     cd = c.compute_dtype
     use_cmm = c.tp_collective_matmul
@@ -693,8 +739,7 @@ def _attention_sublayer(
             ).astype(cd)
         if "bqkv" in layer:
             qkv = qkv + layer["bqkv"].astype(cd)
-        to_heads = lambda t: t.reshape(B, S, c.n_head, c.head_dim)
-        q, k, v = (to_heads(qkv[:, :, i]) for i in range(3))
+        q, k, v = (qkv[:, :, i] for i in range(3))
     else:  # GQA: separate q and stacked k/v projections
         if use_cmm:
             q = _cm.ag_proj(h, layer["wq"].astype(cd)).astype(cd)
@@ -714,9 +759,13 @@ def _attention_sublayer(
         if "bq" in layer:
             q = q + layer["bq"].astype(cd)
             kv = kv + layer["bkv"].astype(cd)
-        q = q.reshape(B, S, c.n_head, c.head_dim)
-        k = kv[:, :, 0].reshape(B, S, c.kv_heads, c.head_dim)
-        v = kv[:, :, 1].reshape(B, S, c.kv_heads, c.head_dim)
+        k, v = kv[:, :, 0], kv[:, :, 1]
+    if c.qk_norm:
+        q = _rms_norm(q, layer["q_norm"], c.norm_eps)
+        k = _rms_norm(k, layer["k_norm"], c.norm_eps)
+    q = q.reshape(B, S, c.n_head, c.head_dim)
+    k = k.reshape(B, S, c.kv_heads, c.head_dim)
+    v = v.reshape(B, S, c.kv_heads, c.head_dim)
     if c.pos_embed == "rope":
         # Global token positions; under a sequence-manual pipeline this
         # shard holds positions [shard*S, shard*S + S) (same offset rule as
@@ -999,7 +1048,8 @@ def forward(
     if targets is not None:
         loss = _cross_entropy(logits, targets)
         if c.n_experts > 0:
-            # Mean aux per layer, Switch-style coefficient.
+            # Mean aux per layer: the load-balance term (and, dropless, the
+            # z-loss riding it in units of router_aux_coef).
             loss = loss + c.router_aux_coef * aux / c.n_layer
     return logits, loss
 
@@ -1016,12 +1066,33 @@ def moe_overflow_fraction(
     overflow accounting (``moe_aux_mode='overflow'``) — zero impact on the
     training step itself.
     """
-    import dataclasses
-
     c = dataclasses.replace(config, moe_aux_mode="overflow", dropout=0.0)
     x = embed(c, params, idx, None, True)
     _, aux = apply_blocks(c, params["blocks"], x, None, True)
     return aux / c.n_layer
+
+
+def moe_expert_counts(
+    config: TinyGPTConfig, params: Params, idx: jax.Array
+) -> jax.Array:
+    """Diagnostic: (n_layer, n_experts) int32, how many of one batch's
+    N x expert_top_k assignments chose each expert at each layer's router, at
+    the given weights, on a dropout-free forward. Max over mean of a row is
+    the routing's imbalance; under dropless routing every assignment is
+    computed, so a row sums to N x expert_top_k and what is missing from that
+    sum was dropped."""
+    from .moe import expert_counts
+
+    c = dataclasses.replace(config, dropout=0.0)
+    x = embed(c, params, idx, None, True)
+    rows = []
+    for i in range(c.n_layer):
+        layer = jax.tree_util.tree_map(lambda t: t[i], params["blocks"])
+        x = _attention_sublayer(c, x, layer, None, True)
+        h = _norm(c, x, layer["ln2_scale"], layer.get("ln2_bias"))
+        rows.append(expert_counts(c, layer, h))
+        x, _ = _mlp_sublayer(c, x, layer, None, True)
+    return jnp.stack(rows)
 
 
 @jax.named_scope(scopes.LOSS)

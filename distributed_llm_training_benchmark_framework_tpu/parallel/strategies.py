@@ -605,6 +605,8 @@ _EP_RULES = {
     "blocks/moe_b1": 1,
     "blocks/moe_w2": 1,
     "blocks/moe_b2": 1,
+    "blocks/moe_wgu": 1,
+    "blocks/moe_wd": 1,
 }
 
 

@@ -67,7 +67,8 @@ def forward_flops_per_token(config) -> float:
 
     Generalized over the architecture-family knobs (models.tinygpt): GQA
     shrinks the K/V projection to ``2*kv_heads*head_dim`` columns, SwiGLU's
-    MLP runs three matrices (``6*D*F`` vs GELU's ``4*D*F``), and RoPE adds
+    MLP runs three matrices (``6*D*F`` vs GELU's ``4*D*F``), a routed MLP is
+    ``expert_top_k`` such MLPs plus the router (active parameters), and RoPE adds
     no matmul FLOPs (elementwise rotation — not counted, per the
     PaLM/Chinchilla convention). The LM head term is ``2*D*V`` tied or
     untied alike. Defaults reproduce the original TinyGPT accounting
@@ -78,12 +79,12 @@ def forward_flops_per_token(config) -> float:
     Hkv = getattr(config, "kv_heads", H) or H
     F = getattr(config, "mlp_dim", 4 * D) or 4 * D
     Dh = D // H
-    if getattr(config, "n_experts", 0) > 0:
-        mlp = 2 * config.expert_top_k * (2 * D * F) + 2 * D * config.n_experts
-    elif getattr(config, "mlp_act", "gelu") == "swiglu":
+    if getattr(config, "mlp_act", "gelu") == "swiglu":
         mlp = 2 * (2 * D * F + F * D)  # gate + up + down
     else:
         mlp = 2 * (D * F + F * D)
+    if getattr(config, "n_experts", 0) > 0:  # k active experts + the router
+        mlp = config.expert_top_k * mlp + 2 * D * config.n_experts
     # Causal masking halves the score-matrix work: the flash/ring kernels
     # skip fully-masked tiles (ops/flash_attention.py `live`), so charging
     # full S would overstate MFU on --causal runs by up to ~1.5x at 16K.

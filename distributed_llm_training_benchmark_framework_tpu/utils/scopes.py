@@ -10,3 +10,10 @@ docs/OBSERVABILITY.md ("Names in a profile").
 EMBED, ATTENTION, MLP, DROPOUT, HEAD, LOSS, OPTIMIZER = SCOPES = (
     "embed", "attention", "mlp", "dropout", "head", "loss", "optimizer",
 )
+
+# Inside ``mlp``, where the MLP is the dropless routed layer (models/moe.py).
+# A tuple of their own: a dense step has none of them, and ``SCOPES`` is what
+# every step must show.
+ROUTER, DISPATCH, EXPERTS, COMBINE = MOE_SCOPES = (
+    "router", "dispatch", "experts", "combine",
+)

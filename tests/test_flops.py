@@ -40,6 +40,19 @@ def test_moe_flops_counts_topk_experts():
     assert delta == float(expected_delta)
 
 
+def test_moe_flops_follow_the_experts_activation():
+    """SwiGLU experts are three matmuls each (gate, up, down), not two."""
+    from distributed_llm_training_benchmark_framework_tpu.models.tinygpt import TinyGPTConfig
+
+    knobs = dict(vocab_size=512, n_embd=128, n_head=4, n_layer=2, block_size=64,
+                 mlp_act="swiglu", mlp_hidden=96, bias=False)
+    dense = TinyGPTConfig(**knobs)
+    moe = TinyGPTConfig(**knobs, n_experts=8, expert_top_k=2, capacity_factor=None)
+    D, F, L = 128, 96, 2
+    delta = flops_mod.forward_flops_per_token(moe) - flops_mod.forward_flops_per_token(dense)
+    assert delta == float(L * ((2 - 1) * 6 * D * F + 2 * D * 8))
+
+
 def test_device_peak_table():
     assert flops_mod.device_peak_tflops("TPU v5 lite") == 197.0
     assert flops_mod.device_peak_tflops("TPU v4") == 275.0

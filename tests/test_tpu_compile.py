@@ -150,6 +150,37 @@ def test_ring_block_kernels_compile(v5e_devices):
     )
 
 
+def test_dropless_expert_matmuls_compile_at_olmoe_widths(v5e_devices, monkeypatch):
+    """The routed MLP's expert stack at OLMoE-1B-7B's sizes (8192 tokens x 8
+    experts a token = 65536 rows, hidden 2048, 64 experts of width 1024),
+    forward and backward: six Pallas grouped matmuls (gate+up and down; the
+    rows' gradient of each is one more ``gmm``, the weights' a ``tgmm``) at
+    the tile ``models/moe.py`` picked on the chip."""
+    from distributed_llm_training_benchmark_framework_tpu.models import moe
+    from distributed_llm_training_benchmark_framework_tpu.models.tinygpt import TinyGPTConfig
+
+    # The program asks the backend whether to run its kernels or interpret
+    # them; the target here is the described chip.
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    config = TinyGPTConfig(n_embd=2048, n_head=16, mlp_act="swiglu", mlp_hidden=1024,
+                           bias=False, n_experts=64, expert_top_k=8, capacity_factor=None)
+    one = SingleDeviceSharding(v5e_devices[0])
+    aval = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+
+    def loss(rows, wgu, wd, counts):
+        out = moe._experts_dropless(config, {"moe_wgu": wgu, "moe_wd": wd}, rows, counts)
+        return jnp.sum(jnp.square(out.astype(jnp.float32)))  # its gradient needs ``out``
+
+    text = _compile(
+        jax.grad(loss, argnums=(0, 1, 2)),
+        aval((65536, 2048), jnp.bfloat16), aval((64, 2048, 2048), jnp.float32),
+        aval((64, 1024, 2048), jnp.float32), aval((64,), jnp.int32),
+    )
+    assert text.count('custom_call_target="tpu_custom_call"') == 6
+    for scope in ("jit(gmm)", "jit(tgmm)"):  # how a trace tells them from the flash kernels
+        assert scope in text
+
+
 def test_flash_partitions_over_a_four_device_data_mesh(v5e_devices):
     """The case GSPMD refuses bare ("Mosaic kernels cannot be automatically
     partitioned"): batch sharded over a 4-chip 'data' axis. flash_attention
