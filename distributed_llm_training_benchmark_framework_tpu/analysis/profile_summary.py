@@ -106,8 +106,10 @@ def _lane_names(events) -> Tuple[Dict[int, str], Dict[Tuple[int, int], str]]:
 
 def op_class(name: str) -> str:
     """Collapse XLA op names to a class: 'fusion.1234' -> 'fusion',
-    'while.35' -> 'while', 'jvp_jit_flash_attention__.3' -> 'flash_kernel'."""
-    if "flash_attention" in name:
+    'while.35' -> 'while'; the flash kernels, by their own names ('flash_fwd.3',
+    'flash_bwd_fused.1') or, where a kernel has none, its jit's
+    ('jvp_jit_flash_attention__.3') -> 'flash_kernel'."""
+    if any(k in name for k in ("flash_attention", "flash_fwd", "flash_bwd")):
         return "flash_kernel"
     base = re.sub(r"[.\d]+$", "", name)
     return base or name

@@ -13,6 +13,9 @@ Forward (Pallas kernel):
 - grid (batch*heads, q_blocks, k_blocks) with the k axis innermost and
   sequential, so the VMEM scratch accumulator persists across k blocks
   (TPU grids execute the trailing axis as the inner sequential loop);
+- the block a grid step brings in (the DMA tile) is not the block it computes
+  on: the body walks its keys in pieces of 128, held k-major, each piece's
+  QK^T issued under the piece before's softmax (``_flash_fwd_kernel``);
 - also emits the per-row logsumexp, the residual the backward pass needs;
 - ``causal=True`` masks by global position and skips fully-masked k blocks.
 
@@ -42,6 +45,7 @@ heads split over the axes that shard them (see ``_kernel_mesh_axes``). The dropo
 from __future__ import annotations
 
 import functools
+import math
 from typing import Optional, Tuple
 
 import jax
@@ -87,10 +91,16 @@ def _dropout_keep(seed, bh, rows, cols, threshold) -> jax.Array:
     unchanged (one finalizer on the broadcast (rows, cols) product); the
     row mix runs on the narrow rows operand.
     """
+    rowbase = _dropout_rowbase(seed, bh, rows)
+    return _mix32(rowbase + cols.astype(jnp.uint32)) < threshold
+
+
+def _dropout_rowbase(seed, bh, rows) -> jax.Array:
+    """The (bh, row) stream base of ``_dropout_keep``: narrow, and the same
+    for every column, so a kernel that walks a tile's columns in pieces makes
+    it once a tile."""
     base = _mix32(seed + jnp.uint32(bh) * jnp.uint32(0x9E3779B9))
-    rowbase = _mix32(base + rows.astype(jnp.uint32) * jnp.uint32(0x85EBCA6B))
-    h = _mix32(rowbase + cols.astype(jnp.uint32))
-    return h < threshold
+    return _mix32(base + rows.astype(jnp.uint32) * jnp.uint32(0x85EBCA6B))
 
 
 def _dropout_threshold(rate: float) -> jnp.uint32:
@@ -123,9 +133,29 @@ def _pick_block(seq_len: int, preferred: int = 512) -> int:
 # (0.275 ms/layer vs 0.568 ms at 512x512 — fewer grid cells amortize per-cell
 # overhead), while the blockwise backward is fastest with 512-wide K blocks
 # (1024 doubles its time). Hence separate fwd/bwd defaults.
+#
+# In plain flash's forward that tile is the DMA tile: what a grid step brings
+# into VMEM, and what --flash-block-q/-k set. What the body computes on at a
+# time is a (bq, _FWD_SUB_K) piece of it (_flash_fwd_kernel). One call of
+# _flash_forward in us a (1024, 1024) tile (my chip run, PR 27,
+# scripts/microbench_flash_fwd.py; what the MXU allows is 2.73, the two
+# products alone take 3.0; "before" is the q-major whole-tile body):
+#
+#   (BH, S, D, causal, dropout)             (16, 8192, 64, no, 0.1)  (64, 4096, 128, yes, 0)
+#   before PR 27                                     5.72                    5.34
+#   that body cut along k, sub_k 512 / 256 / 128     8.80 / 10.44 / 10.66 (at S 2048: 5.99 whole)
+#   k-major, whole tile                              5.04                    5.24
+#   k-major, keys in pieces of 512 / 256 / 128       4.68 / 4.78 / 4.50      4.62 / 4.69 / 4.37
+#   DMA tile 2048x2048, pieces (1024 q, 128 k)       4.25                    3.74
+#   ... (256 q, 128 k), above the diagonal skipped   5.78                    3.00
+#
+# The last two rows are faster and not taken: the pieces are unrolled, and a
+# kernel of 32 to 200 of them cost 2 to 4.6 s of every run's set-up (trace,
+# lower, cache read) in all three cells measured, past the benchmark's bound.
 _FWD_BLOCK_Q = 1024
 _FWD_BLOCK_K = 1024
 _BWD_BLOCK_K = 512
+_FWD_SUB_K = 128
 # The fused Pallas backward (S >= _PALLAS_BWD_MIN_SEQ) is fastest at
 # 1024x1024 at both head dims the benchmark runs (PERF.md, PR 25's sweep);
 # its q tile is the forward's block_q.
@@ -141,17 +171,51 @@ _FUSED_BWD_BLOCK_K = 1024
 # reader hard-codes the same 4096).
 _PALLAS_BWD_MIN_SEQ = 4096
 
+_LOG2_E = math.log2(math.e)
+
+
+def _fwd_sub_k(bk: int) -> int:
+    """Keys of the compute piece the forward kernel walks a (bq, bk) DMA tile
+    in: 128, one MXU weight tile of P. A tile no wider than that, or one it
+    does not divide (the CPU tests' small tiles), is walked whole."""
+    return _FWD_SUB_K if bk > _FWD_SUB_K and bk % _FWD_SUB_K == 0 else bk
+
 
 def _flash_fwd_kernel(
     seed_ref, bhv_ref, q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr,
     acc_scr,
-    *, bq: int, bk: int, scale: float, causal: bool,
-    seq_len: int, dropout_rate: float,
+    *, bq: int, bk: int, sub_k: int, scale: float, causal: bool,
+    dropout_rate: float,
 ):
+    """One grid step brings the operands of a (bq, bk) score tile into VMEM
+    (the DMA tile) and walks its keys in compute pieces of ``sub_k``,
+    unrolled, one online-softmax update each (``_fwd_sub_k``).
+
+    A piece is held k-major, (sub_k, bq), as the fused backward holds its
+    tile: the softmax's max and sum run down the sublanes (plain vector ops,
+    no cross-lane reduction), the statistics m, l and alpha are (1, bq)
+    lane-dense rows, so an update a piece is cheap, and the accumulator is
+    out^T, (D, bq), rescaled by a sublane broadcast and transposed once a q
+    tile. (Cut along k in the q-major layout, (bq, 1) statistics and a lane
+    reduction a piece made every point slower than the whole tile.)
+
+    The next piece's QK^T is issued before this piece's softmax: the
+    scheduler keeps program order across pieces, so that is what puts the
+    MXU's work under the VPU's; without it the pieces are slower than the
+    whole tile.
+
+    Scores stay unscaled until the exponent: p = exp2(s * c - m), with
+    c = scale * log2(e) and m the running maximum of s * c: one multiply a
+    score for the softmax scale and exp's own base change. Dropout's
+    1 / keep_prob rides in the subtracted maximum (p comes out pre-scaled),
+    so ``l_scr`` sums p / keep_prob and ``_finalize`` takes the factor out.
+    """
     bh = pl.program_id(0)
     qi = pl.program_id(1)
     ki = pl.program_id(2)
     nk = pl.num_programs(2)
+    c = scale * _LOG2_E
+    keep_prob = 1.0 - dropout_rate
 
     @pl.when(ki == 0)
     def _init():
@@ -168,60 +232,70 @@ def _flash_fwd_kernel(
         # bf16 operands on the MXU, fp32 accumulation via
         # preferred_element_type — softmax statistics stay fp32 throughout.
         q = q_ref[0]  # (bq, d) input dtype
-        k = k_ref[0]  # (bk, d)
-        v = v_ref[0]
-        s = lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        ) * scale  # (bq, bk) fp32
+
+        def scores(c0):
+            return lax.dot_general(
+                k_ref[0, pl.ds(c0, sub_k), :], q, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            )  # (sub_k, bq) fp32, unscaled
 
         # Narrow coordinate operands: the causal compare and the dropout
-        # hash broadcast (bq,1)x(1,bk); the row-fold mix runs per-row only.
-        rows = qi * bq + lax.broadcasted_iota(jnp.int32, (bq, 1), 0)
-        cols = ki * bk + lax.broadcasted_iota(jnp.int32, (1, bk), 1)
-        if causal:
-            mask = rows >= cols
-            s = jnp.where(mask, s, NEG_INF)
-
-        m_prev = m_scr[:, :1]                       # (bq, 1)
-        m_cur = jnp.max(s, axis=-1, keepdims=True)  # (bq, 1)
-        m_new = jnp.maximum(m_prev, m_cur)
-        alpha = jnp.exp(m_prev - m_new)             # (bq, 1)
-        p = jnp.exp(s - m_new)                      # (bq, bk) fp32
-        if causal:
-            p = jnp.where(mask, p, 0.0)
-
-        # Attention-probability dropout (parity with the reference model,
-        # train_harness.py:114-116): the softmax normalizer l accumulates the
-        # UN-dropped p (dropout acts after normalization, and normalization is
-        # linear, so dropping the unnormalized p against the full-l divisor is
-        # exact), while the output accumulator sees the dropped+rescaled p.
+        # hash broadcast (1, bq) x (sub_k, 1); the row-fold mix runs per
+        # query only, and once a tile: it does not depend on the key.
+        rows = qi * bq + lax.broadcasted_iota(jnp.int32, (1, bq), 1)
         if dropout_rate > 0.0:
-            keep = _dropout_keep(
-                seed_ref[0], bhv_ref[bh], rows, cols,
-                _dropout_threshold(dropout_rate),
+            rowbase = _dropout_rowbase(seed_ref[0], bhv_ref[bh], rows)
+        m = m_scr[:]      # (1, bq), log2 units of the scaled scores
+        l = l_scr[:]
+        acc = acc_scr[:]  # (d, bq) fp32
+        s_next = scores(0)
+        for c0 in range(0, bk, sub_k):
+            s = s_next
+            if c0 + sub_k < bk:
+                s_next = scores(c0 + sub_k)
+            cols = ki * bk + c0 + lax.broadcasted_iota(
+                jnp.int32, (sub_k, 1), 0
             )
-            p_acc = jnp.where(keep, p * (1.0 / (1.0 - dropout_rate)), 0.0)
-        else:
-            p_acc = p
-
-        l_prev = l_scr[:, :1]
-        l_new = alpha * l_prev + jnp.sum(p, axis=-1, keepdims=True)
-        acc_scr[:] = acc_scr[:] * alpha + lax.dot_general(
-            p_acc.astype(q.dtype), v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        m_scr[:] = jnp.broadcast_to(m_new, m_scr.shape)
-        l_scr[:] = jnp.broadcast_to(l_new, l_scr.shape)
+            if causal:
+                # No second mask on p: exp2(NEG_INF * c - m) is exactly 0
+                # once m is finite, and every query's first piece (keys from
+                # 0) has a live key.
+                s = jnp.where(rows >= cols, s, NEG_INF)
+            m_new = jnp.maximum(m, jnp.max(s, axis=0, keepdims=True) * c)
+            alpha = jnp.exp2(m - m_new)  # (1, bq)
+            # Attention-probability dropout (parity with the reference
+            # model, train_harness.py:114-116): the softmax normalizer l
+            # accumulates the UN-dropped p (dropout acts after
+            # normalization, and normalization is linear, so dropping the
+            # unnormalized p against the full-l divisor is exact), while the
+            # output accumulator sees the dropped p / keep_prob.
+            if dropout_rate > 0.0:
+                p = jnp.exp2(s * c - (m_new + math.log2(keep_prob)))
+                keep = _mix32(rowbase + cols.astype(jnp.uint32)) < (
+                    _dropout_threshold(dropout_rate)
+                )
+                p_acc = jnp.where(keep, p, 0.0)
+            else:
+                p = p_acc = jnp.exp2(s * c - m_new)  # (sub_k, bq) fp32
+            l = alpha * l + jnp.sum(p, axis=0, keepdims=True)
+            acc = acc * alpha + lax.dot_general(  # out^T: V^T P
+                v_ref[0, pl.ds(c0, sub_k), :], p_acc.astype(q.dtype),
+                (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32,
+            )
+            m = m_new
+        m_scr[:] = m
+        l_scr[:] = l
+        acc_scr[:] = acc
 
     @pl.when(ki == nk - 1)
     def _finalize():
-        l = l_scr[:, :1]
+        l = l_scr[:] * keep_prob
         l_safe = jnp.where(l == 0.0, 1.0, l)  # fully-masked rows -> zero output
-        o_ref[0] = (acc_scr[:] / l_safe).astype(o_ref.dtype)
+        o_ref[0] = (acc_scr[:] / l_safe).T.astype(o_ref.dtype)
         # lse is logically (bq,); stored sublane-broadcast as (8, bq) because
         # TPU output blocks must tile to (8, 128).
-        lse = (m_scr[:, :1] + jnp.log(l_safe))[:, 0]
-        lse_ref[0] = jnp.broadcast_to(lse[None, :], (8, lse.shape[0]))
+        lse = m_scr[:] * (1.0 / _LOG2_E) + jnp.log(l_safe)
+        lse_ref[0] = jnp.broadcast_to(lse, (8, bq))
 
 
 def _vma_struct(shape, dtype, *like):
@@ -286,13 +360,17 @@ def _flash_forward(
     q: jax.Array, k: jax.Array, v: jax.Array,
     causal: bool, interpret: bool, bq: int, bk: int,
     dropout_rate: float, seed: jax.Array, bhv: jax.Array,
+    sub_k: Optional[int] = None,
 ) -> Tuple[jax.Array, jax.Array]:
     """Run the Pallas kernel on (BH, S, D) inputs -> (out, lse). ``bhv`` is
     the (BH,) int32 vector of GLOBAL batch*head ids keying the dropout hash
-    (arange(BH) on one device; mesh-global ids under a shard_map)."""
+    (arange(BH) on one device; mesh-global ids under a shard_map).
+    ``sub_k`` forces the compute piece (tests and the microbench; no flag or
+    config field reaches it); ``_fwd_sub_k`` chooses it otherwise."""
     BH, S, D = q.shape
     scale = 1.0 / (D ** 0.5)
     grid = (BH, S // bq, S // bk)
+    sub_k = sub_k or _fwd_sub_k(bk)
     from ..utils.vma import vma_of
 
     if interpret and vma_of(q, k, v):
@@ -301,8 +379,8 @@ def _flash_forward(
         )
     out, lse = pl.pallas_call(
         functools.partial(
-            _flash_fwd_kernel, bq=bq, bk=bk, scale=scale, causal=causal,
-            seq_len=S, dropout_rate=dropout_rate,
+            _flash_fwd_kernel, bq=bq, bk=bk, sub_k=sub_k, scale=scale,
+            causal=causal, dropout_rate=dropout_rate,
         ),
         out_shape=[
             _vma_struct((BH, S, D), q.dtype, q, k, v),
@@ -321,13 +399,14 @@ def _flash_forward(
             pl.BlockSpec((1, 8, bq), lambda b, qi, ki: (b, 0, qi)),
         ],
         scratch_shapes=[
-            pltpu.VMEM((bq, 128), jnp.float32),  # running max (lane-broadcast)
-            pltpu.VMEM((bq, 128), jnp.float32),  # running sum
-            pltpu.VMEM((bq, D), jnp.float32),    # output accumulator
+            pltpu.VMEM((1, bq), jnp.float32),  # running max, log2 units
+            pltpu.VMEM((1, bq), jnp.float32),  # running sum / keep_prob
+            pltpu.VMEM((D, bq), jnp.float32),  # output accumulator, out^T
         ],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
+        name="flash_fwd",
         interpret=interpret,
     )(seed, bhv, q, k, v)
     return out, lse[:, 0, :]

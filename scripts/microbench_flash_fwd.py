@@ -1,45 +1,56 @@
 #!/usr/bin/env python
-"""Head-dim-64 MXU wall prototypes — the measured battery behind
-docs/PERFORMANCE.md §15.
+"""Flash-attention forward: the (bq, bk) DMA tile walked in compute pieces,
+against the whole-tile body, at the shapes the benchmark's cells run.
 
-Context (§9): at the parity config the flash forward kernel's in-kernel
-efficiency is ~23% of bf16 peak, and the score matmuls contract over
-head_dim = 64 — half the MXU's 128-wide contraction. The round-4 verdict
-asked for kernel-layout prototypes rather than concession. This script
-times, at tier-A attention shapes (BH=16, S=2048, D=64, bf16):
+  tinygpt-a.seq8192   BH 16, S 8192, D 64, not causal, dropout 0.1
+  tinygpt-a.seq2048   BH 16, S 2048, D 64, not causal, dropout 0.1
+  mistral-7b.d2       BH 64, S 4096, D 128, causal, no dropout
 
-  xla_sdpa        — plain XLA dot_general chain (materialized scores), the
-                    no-kernel ceiling check
-  matmul_floor    — the two dots alone (q@k^T then s@v), no softmax, no
-                    masking: the in-kernel MXU floor the other variants
-                    chase
-  flash_current   — the production kernel (ops/flash_attention.py)
-  flash_headpair  — grid halved over batch*heads; each program computes a
-                    2-head batched dot (batch dims on the MXU call) so
-                    Mosaic may pack two 64-contractions per pass
-  flash_kt        — k fed pre-transposed (D, bk): the q@k^T contraction
-                    becomes a plain (bq,64)x(64,bk) matmul with no
-                    transposed operand, minor-dim-contiguous on both sides
-  flash_qscaled   — softmax scale folded into the narrow (bq, D) q tile
-                    instead of the wide (bq, bk) score tile; bit-exact
-                    when the scale is a power of two (D=64 -> 2^-3)
-  flash_production— the repo's real ops/flash_attention.py forward
-                    (dropout off), so prototype wins/losses are judged
-                    against what the model actually runs
+docs/PERFORMANCE.md section 15 measured that the two products of a score tile
+alone took twice the MXU's time, and refuted three operand layouts that keep
+the whole-tile chain (one product, every elementwise op over the whole
+(bq, bk) f32 tile, one product). ``flash_subtiled`` below changes the chain:
+one grid step still brings a (bq, bk) tile into VMEM, and the body walks it
+in (sub_q, sub_k) pieces, unrolled, each with its own online-softmax update.
+Its knobs are what PR 27 swept (``PERF.md`` section 6 has the table):
 
-Timing discipline: a kernel call here is a fraction of a millisecond, less
-than one host dispatch plus fetch, so every variant is timed by chaining N
-calls inside ONE jit (output feeding input) and fetching a single scalar
-(docs/TROUBLESHOOTING.md §17).
+  layout     qmajor: a piece is (sub_q, sub_k), statistics (sub_q, 1) columns,
+             the production layout before PR 27; at sub_q = bq and sub_k = bk
+             it is that body. kmajor: a piece is (sub_k, sub_q), statistics
+             (1, sub_q) rows, the accumulator out^T: what production runs now
+             (at sub_q = bq, lookahead and trim on)
+  lookahead  the next piece's QK^T issued before this piece's softmax
+  trim       one multiply a score: scale, log2(e) and 1 / keep_prob folded
 
-Run on the chip:  python scripts/microbench_flash_fwd.py [--iters 50]
+The rows:
+
+  matmul_floor      the two products with a cast between them, no softmax
+  xla_sdpa          XLA's materialised-score chain (skipped over 2 GiB of scores)
+  flash_production  ops/flash_attention.py::_flash_forward as the model runs it
+                    (``--rows prod:bq:bk:0:sub_k:0:0`` forces its tile and piece)
+  flash_subtiled    every (layout, DMA tile, sub_q, sub_k, lookahead, trim) asked for
+
+A call is a fraction of a millisecond to a few, so each row chains enough
+calls for ``--target-ms`` inside one jit (a ``fori_loop``, each output the next q)
+and fetches one scalar (docs/TROUBLESHOOTING.md section 17).
+
+  chiprun -- python scripts/microbench_flash_fwd.py              # the q-major and k-major grids
+  JAX_PLATFORMS=cpu python scripts/microbench_flash_fwd.py --describe
+      # no chip: compile every row for a described v5e
+  ... --shapes tinygpt-a.seq2048 --dropout 0 --causal 1          # one shape, overridden
+  ... --rows kmajor:2048:2048:1024:128:1:1 prod:1024:1024:0:256:0:0   # named rows only
 """
 
 import argparse
 import functools
+import itertools
+import json
+import math
 import os
 import sys
 import time
+
+os.environ.setdefault("TPU_LOG_DIR", "disabled")
 
 import jax
 import jax.numpy as jnp
@@ -50,38 +61,56 @@ from jax.experimental.pallas import tpu as pltpu
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
-NEG_INF = -1e30
+from distributed_llm_training_benchmark_framework_tpu.ops import (  # noqa: E402
+    flash_attention as fa,
+)
+
+NEG_INF = fa.NEG_INF
+LOG2_E = math.log2(math.e)
+PEAK_FLOPS = 197e12  # v5e bf16
+
+SHAPES = {
+    "tinygpt-a.seq8192": dict(BH=16, S=8192, D=64, causal=False, rate=0.1),
+    "tinygpt-a.seq2048": dict(BH=16, S=2048, D=64, causal=False, rate=0.1),
+    "mistral-7b.d2": dict(BH=64, S=4096, D=128, causal=True, rate=0.0),
+}
+DMA_TILES = [(1024, 1024), (2048, 1024), (1024, 2048), (2048, 2048)]
+SUB_K = [128, 256, 512, 1024]
+ROW_CHUNKS = [0, 128, 256, 512]  # 0: the whole tile's q positions
 
 
-def timeit_chained(fn, args, chain=500, n=5):
-    """Median ms per call, measured as `chain` sequential calls inside ONE
-    jitted computation (each output feeds the next input, forcing the device
-    to actually execute them in series) with a single scalar fetched at the
-    end, so per-call dispatch and fetch latency stay out of a
-    sub-millisecond kernel's time (docs/TROUBLESHOOTING.md §17)."""
+def _fwd_kernel_subtiled(
+    seed_ref, bhv_ref, q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr,
+    acc_scr,
+    *, kmajor, bq, bk, sub_q, sub_k, lookahead, scale, causal, rate, trim,
+):
+    """The forward with its (bq, bk) tile cut both ways: q positions in chunks
+    of ``sub_q`` (independent of each other), k positions in sub-tiles of
+    ``sub_k`` (one online-softmax update each). ``lookahead`` issues the next
+    piece's QK^T before this piece's softmax in program order.
 
-    @jax.jit
-    def many(*a):
-        x = a[0]
-        for _ in range(chain):
-            x = fn(x, *a[1:])
-        return jnp.float32(x).sum()
-
-    float(many(*args))  # compile + warm
-    ts = []
-    for _ in range(n):
-        t0 = time.perf_counter()
-        float(many(*args))
-        ts.append(time.perf_counter() - t0)
-    return float(np.median(ts) / chain * 1e3)
-
-
-# --- variant kernels (softmax, no dropout — isolate the matmul layout) ---
-
-def _fwd_kernel_current(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr,
-                        *, bq, bk, scale):
+    ``kmajor`` holds a score piece as (sub_k, sub_q), as the fused backward
+    holds its tile: the softmax's max and sum run down the rows (plain vector
+    ops, no cross-lane reduction), m, l and alpha are (1, sub_q) lane-dense
+    vectors, and the accumulator is out^T, (D, bq), rescaled by a sublane
+    broadcast and transposed once a q tile. There the cut along q is the one
+    that costs the MXU nothing: Q^T chunks are QK^T's stationary operand, P
+    chunks V^T P's. Otherwise (q-major, the production layout before PR 27)
+    a piece is (sub_q, sub_k) and the statistics are (sub_q, 1) columns."""
+    bh = pl.program_id(0)
+    qi = pl.program_id(1)
     ki = pl.program_id(2)
     nk = pl.num_programs(2)
+    axis = 0 if kmajor else 1  # of a score piece, the k positions
+
+    def along(n, q_axis):  # (1, n) or (n, 1) iota: positions along one axis
+        lanes = q_axis == kmajor
+        return lax.broadcasted_iota(
+            jnp.int32, (1, n) if lanes else (n, 1), 1 if lanes else 0
+        )
+
+    def at(q_part, rest=slice(None)):  # index into m / l / acc: q x the rest
+        return (rest, q_part) if kmajor else (q_part, rest)
 
     @pl.when(ki == 0)
     def _init():
@@ -89,200 +118,170 @@ def _fwd_kernel_current(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr,
         l_scr[:] = jnp.zeros_like(l_scr)
         acc_scr[:] = jnp.zeros_like(acc_scr)
 
-    q = q_ref[0]
-    k = k_ref[0]
-    v = v_ref[0]
-    s = lax.dot_general(
-        q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-    ) * scale
-    m_prev = m_scr[:, :1]
-    m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-    alpha = jnp.exp(m_prev - m_new)
-    p = jnp.exp(s - m_new)
-    l_scr[:, :1] = l_scr[:, :1] * alpha + jnp.sum(p, axis=-1, keepdims=True)
-    m_scr[:, :1] = m_new
-    acc_scr[:] = acc_scr[:] * alpha + lax.dot_general(
-        p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )
+    live = (not causal) or (ki * bk < (qi + 1) * bq)
+
+    @pl.when(live)
+    def _accumulate():
+        def scores(r0, c0):
+            q = q_ref[0, r0:r0 + sub_q, :]
+            k = k_ref[0, c0:c0 + sub_k, :]
+            return lax.dot_general(
+                *((k, q) if kmajor else (q, k)), (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            )
+
+        pieces = [(r0, c0) for r0 in range(0, bq, sub_q)
+                  for c0 in range(0, bk, sub_k)]
+        s_next = scores(*pieces[0]) if lookahead else None
+        for i, (r0, c0) in enumerate(pieces):
+            if lookahead:
+                s = s_next
+                if i + 1 < len(pieces):
+                    s_next = scores(*pieces[i + 1])
+            else:
+                s = scores(r0, c0)
+            if c0 == 0:
+                rows = qi * bq + r0 + along(sub_q, True)
+                if rate > 0.0:
+                    rowbase = fa._dropout_rowbase(seed_ref[0], bhv_ref[bh], rows)
+                chunk = slice(r0, r0 + sub_q)
+                m = m_scr[at(chunk, slice(0, 1))]
+                l = l_scr[at(chunk, slice(0, 1))]
+                acc = acc_scr[at(chunk)]
+            cols = ki * bk + c0 + along(sub_k, False)
+            if causal:
+                mask = rows >= cols
+                s = jnp.where(mask, s, NEG_INF)
+            if trim:
+                # One multiply a score where the plain body has three: the
+                # softmax scale and exp's log2(e) are one constant, and the
+                # dropout's 1 / keep_prob rides in the subtracted maximum
+                # (l then sums p / keep_prob; _finalize takes it out).
+                c = scale * LOG2_E
+                m_new = jnp.maximum(m, jnp.max(s, axis=axis, keepdims=True) * c)
+                alpha = jnp.exp2(m - m_new)
+                shift = m_new + math.log2(1.0 - rate) if rate > 0.0 else m_new
+                p = jnp.exp2(s * c - shift)
+            else:
+                s = s * scale
+                m_new = jnp.maximum(m, jnp.max(s, axis=axis, keepdims=True))
+                alpha = jnp.exp(m - m_new)
+                p = jnp.exp(s - m_new)
+                if causal:
+                    p = jnp.where(mask, p, 0.0)
+            if rate > 0.0:
+                keep = fa._mix32(
+                    rowbase + cols.astype(jnp.uint32)
+                ) < fa._dropout_threshold(rate)
+                scaled = p if trim else p * (1.0 / (1.0 - rate))
+                p_acc = jnp.where(keep, scaled, 0.0)
+            else:
+                p_acc = p
+            l = alpha * l + jnp.sum(p, axis=axis, keepdims=True)
+            p_acc = p_acc.astype(q_ref.dtype)
+            v = v_ref[0, c0:c0 + sub_k, :]
+            acc = acc * alpha + (
+                lax.dot_general(  # out^T: V^T P
+                    v, p_acc, (((0,), (0,)), ((), ())),
+                    preferred_element_type=jnp.float32,
+                ) if kmajor else lax.dot_general(
+                    p_acc, v, (((1,), (0,)), ((), ())),
+                    preferred_element_type=jnp.float32,
+                )
+            )
+            m = m_new
+            if c0 + sub_k == bk:
+                copies = (1, sub_q) if kmajor else (sub_q, 128)
+                m_scr[at(chunk)] = jnp.broadcast_to(m, copies)
+                l_scr[at(chunk)] = jnp.broadcast_to(l, copies)
+                acc_scr[at(chunk)] = acc
 
     @pl.when(ki == nk - 1)
-    def _done():
-        o_ref[0] = (acc_scr[:] / l_scr[:, :1]).astype(o_ref.dtype)
+    def _finalize():
+        l = l_scr[at(slice(None), slice(0, 1))]
+        l_safe = jnp.where(l == 0.0, 1.0, l)  # fully-masked rows -> zero output
+        m = m_scr[at(slice(None), slice(0, 1))]
+        if trim:  # m in log2 units of the scaled scores, l over p / keep_prob
+            l_safe = l_safe * (1.0 - rate)
+            m = m * (1.0 / LOG2_E)
+        out = acc_scr[:] / l_safe
+        lse = m + jnp.log(l_safe)
+        o_ref[0] = (out.T if kmajor else out).astype(o_ref.dtype)
+        lse_ref[0] = jnp.broadcast_to(lse if kmajor else lse[:, 0][None, :], (8, bq))
 
 
-def flash_current(q, k, v, bq=1024, bk=1024):
+def flash_subtiled(
+    q, k, v, seed, bhv, *, causal, rate, layout, bq, bk, sub_q, sub_k,
+    lookahead, trim=False,
+):
     BH, S, D = q.shape
-    scale = 1.0 / (D ** 0.5)
-    return pl.pallas_call(
-        functools.partial(_fwd_kernel_current, bq=bq, bk=bk, scale=scale),
-        out_shape=jax.ShapeDtypeStruct((BH, S, D), q.dtype),
+    smem = pl.BlockSpec(memory_space=pltpu.SMEM)
+    kmajor = layout == "kmajor"
+    out, lse = pl.pallas_call(
+        functools.partial(
+            _fwd_kernel_subtiled, kmajor=kmajor, bq=bq, bk=bk, sub_q=sub_q,
+            sub_k=sub_k, trim=trim,
+            lookahead=lookahead, scale=1.0 / (D ** 0.5), causal=causal,
+            rate=rate,
+        ),
+        out_shape=[
+            jax.ShapeDtypeStruct((BH, S, D), q.dtype),
+            jax.ShapeDtypeStruct((BH, 8, S), jnp.float32),
+        ],
         grid=(BH, S // bq, S // bk),
         in_specs=[
+            smem, smem,
             pl.BlockSpec((1, bq, D), lambda b, qi, ki: (b, qi, 0)),
             pl.BlockSpec((1, bk, D), lambda b, qi, ki: (b, ki, 0)),
             pl.BlockSpec((1, bk, D), lambda b, qi, ki: (b, ki, 0)),
         ],
-        out_specs=pl.BlockSpec((1, bq, D), lambda b, qi, ki: (b, qi, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((bq, 8), jnp.float32),
-            pltpu.VMEM((bq, 8), jnp.float32),
-            pltpu.VMEM((bq, D), jnp.float32),
-        ],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary"),
-        ),
-    )(q, k, v)
-
-
-def _fwd_kernel_headpair(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr,
-                         *, bq, bk, scale):
-    """2 heads per program; the dots carry a batch dim so the compiler can
-    interleave two 64-deep contractions per MXU pass (if it can)."""
-    ki = pl.program_id(2)
-    nk = pl.num_programs(2)
-
-    @pl.when(ki == 0)
-    def _init():
-        m_scr[:] = jnp.full_like(m_scr, NEG_INF)
-        l_scr[:] = jnp.zeros_like(l_scr)
-        acc_scr[:] = jnp.zeros_like(acc_scr)
-
-    q = q_ref[:]  # (2, bq, D)
-    k = k_ref[:]
-    v = v_ref[:]
-    s = lax.dot_general(
-        q, k, (((2,), (2,)), ((0,), (0,))), preferred_element_type=jnp.float32
-    ) * scale  # (2, bq, bk)
-    m_prev = m_scr[:, :, :1]  # (2, bq, 1)
-    m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-    alpha = jnp.exp(m_prev - m_new)
-    p = jnp.exp(s - m_new)
-    l_scr[:, :, :1] = l_scr[:, :, :1] * alpha + jnp.sum(p, -1, keepdims=True)
-    m_scr[:, :, :1] = m_new
-    acc_scr[:] = acc_scr[:] * alpha + lax.dot_general(
-        p.astype(v.dtype), v, (((2,), (1,)), ((0,), (0,))),
-        preferred_element_type=jnp.float32,
-    )
-
-    @pl.when(ki == nk - 1)
-    def _done():
-        o_ref[:] = (acc_scr[:] / l_scr[:, :, :1]).astype(o_ref.dtype)
-
-
-def flash_headpair(q, k, v, bq=1024, bk=1024):
-    BH, S, D = q.shape
-    scale = 1.0 / (D ** 0.5)
-    return pl.pallas_call(
-        functools.partial(_fwd_kernel_headpair, bq=bq, bk=bk, scale=scale),
-        out_shape=jax.ShapeDtypeStruct((BH, S, D), q.dtype),
-        grid=(BH // 2, S // bq, S // bk),
-        in_specs=[
-            pl.BlockSpec((2, bq, D), lambda b, qi, ki: (b, qi, 0)),
-            pl.BlockSpec((2, bk, D), lambda b, qi, ki: (b, ki, 0)),
-            pl.BlockSpec((2, bk, D), lambda b, qi, ki: (b, ki, 0)),
-        ],
-        out_specs=pl.BlockSpec((2, bq, D), lambda b, qi, ki: (b, qi, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((2, bq, 8), jnp.float32),
-            pltpu.VMEM((2, bq, 8), jnp.float32),
-            pltpu.VMEM((2, bq, D), jnp.float32),
-        ],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary"),
-        ),
-    )(q, k, v)
-
-
-def _fwd_kernel_kt(q_ref, kt_ref, v_ref, o_ref, m_scr, l_scr, acc_scr,
-                   *, bq, bk, scale):
-    """k arrives pre-transposed (D, bk): contraction is minor-dim of q
-    against major-dim of kt — a plain untransposed matmul."""
-    ki = pl.program_id(2)
-    nk = pl.num_programs(2)
-
-    @pl.when(ki == 0)
-    def _init():
-        m_scr[:] = jnp.full_like(m_scr, NEG_INF)
-        l_scr[:] = jnp.zeros_like(l_scr)
-        acc_scr[:] = jnp.zeros_like(acc_scr)
-
-    q = q_ref[0]   # (bq, D)
-    kt = kt_ref[0]  # (D, bk)
-    v = v_ref[0]
-    s = lax.dot_general(
-        q, kt, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
-    ) * scale
-    m_prev = m_scr[:, :1]
-    m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-    alpha = jnp.exp(m_prev - m_new)
-    p = jnp.exp(s - m_new)
-    l_scr[:, :1] = l_scr[:, :1] * alpha + jnp.sum(p, axis=-1, keepdims=True)
-    m_scr[:, :1] = m_new
-    acc_scr[:] = acc_scr[:] * alpha + lax.dot_general(
-        p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )
-
-    @pl.when(ki == nk - 1)
-    def _done():
-        o_ref[0] = (acc_scr[:] / l_scr[:, :1]).astype(o_ref.dtype)
-
-
-def flash_kt(q, kt, v, bq=1024, bk=1024):
-    BH, S, D = q.shape
-    scale = 1.0 / (D ** 0.5)
-    return pl.pallas_call(
-        functools.partial(_fwd_kernel_kt, bq=bq, bk=bk, scale=scale),
-        out_shape=jax.ShapeDtypeStruct((BH, S, D), q.dtype),
-        grid=(BH, S // bq, S // bk),
-        in_specs=[
+        out_specs=[
             pl.BlockSpec((1, bq, D), lambda b, qi, ki: (b, qi, 0)),
-            pl.BlockSpec((1, D, bk), lambda b, qi, ki: (b, 0, ki)),
-            pl.BlockSpec((1, bk, D), lambda b, qi, ki: (b, ki, 0)),
+            pl.BlockSpec((1, 8, bq), lambda b, qi, ki: (b, 0, qi)),
         ],
-        out_specs=pl.BlockSpec((1, bq, D), lambda b, qi, ki: (b, qi, 0)),
         scratch_shapes=[
-            pltpu.VMEM((bq, 8), jnp.float32),
-            pltpu.VMEM((bq, 8), jnp.float32),
-            pltpu.VMEM((bq, D), jnp.float32),
+            pltpu.VMEM((1, bq) if kmajor else (bq, 128), jnp.float32),
+            pltpu.VMEM((1, bq) if kmajor else (bq, 128), jnp.float32),
+            pltpu.VMEM((D, bq) if kmajor else (bq, D), jnp.float32),
         ],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
+            # Room for a whole (2048, 2048) f32 tile and its temporaries, so
+            # that a refusal in the sweep is never this limit's.
+            vmem_limit_bytes=100 * 2**20,
         ),
-    )(q, kt, v)
+        name=f"flash_fwd_{layout}",
+    )(seed, bhv, q, k, v)
+    return out, lse[:, 0, :]
 
 
-def _fwd_kernel_matmul_only(q_ref, k_ref, v_ref, o_ref, acc_scr, *, bq, bk, scale):
-    """The two dots with a trivial elementwise between — the MXU floor."""
+def _matmul_only_kernel(q_ref, k_ref, v_ref, o_ref, acc_scr, *, scale):
+    """The two dots with a scale and a cast between them: the MXU floor."""
     ki = pl.program_id(2)
-    nk = pl.num_programs(2)
 
     @pl.when(ki == 0)
     def _init():
         acc_scr[:] = jnp.zeros_like(acc_scr)
 
     q = q_ref[0]
-    k = k_ref[0]
-    v = v_ref[0]
     s = lax.dot_general(
-        q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+        q, k_ref[0], (((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32,
     ) * scale
     acc_scr[:] = acc_scr[:] + lax.dot_general(
-        s.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+        s.astype(q.dtype), v_ref[0], (((1,), (0,)), ((), ())),
         preferred_element_type=jnp.float32,
     )
 
-    @pl.when(ki == nk - 1)
+    @pl.when(ki == pl.num_programs(2) - 1)
     def _done():
         o_ref[0] = acc_scr[:].astype(o_ref.dtype)
 
 
 def matmul_floor(q, k, v, bq=1024, bk=1024):
     BH, S, D = q.shape
-    scale = 1.0 / (D ** 0.5)
     return pl.pallas_call(
-        functools.partial(_fwd_kernel_matmul_only, bq=bq, bk=bk, scale=scale),
+        functools.partial(_matmul_only_kernel, scale=1.0 / (D ** 0.5)),
         out_shape=jax.ShapeDtypeStruct((BH, S, D), q.dtype),
         grid=(BH, S // bq, S // bk),
         in_specs=[
@@ -298,77 +297,6 @@ def matmul_floor(q, k, v, bq=1024, bk=1024):
     )(q, k, v)
 
 
-def _fwd_kernel_qscaled(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr,
-                        *, bq, bk, scale):
-    """The softmax scale folded into the narrow (bq, D) q tile instead of
-    the wide (bq, bk) score tile. Bit-exact when scale is a power of two
-    (D=64 -> 2^-3: exponent shift, no mantissa change) — verified max|Δ|=0
-    vs flash_current on-chip."""
-    ki = pl.program_id(2)
-    nk = pl.num_programs(2)
-
-    @pl.when(ki == 0)
-    def _init():
-        m_scr[:] = jnp.full_like(m_scr, NEG_INF)
-        l_scr[:] = jnp.zeros_like(l_scr)
-        acc_scr[:] = jnp.zeros_like(acc_scr)
-
-    q = q_ref[0] * jnp.asarray(scale, q_ref.dtype)  # narrow mul
-    k = k_ref[0]
-    v = v_ref[0]
-    s = lax.dot_general(
-        q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-    )
-    m_prev = m_scr[:, :1]
-    m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-    alpha = jnp.exp(m_prev - m_new)
-    p = jnp.exp(s - m_new)
-    l_scr[:, :1] = l_scr[:, :1] * alpha + jnp.sum(p, axis=-1, keepdims=True)
-    m_scr[:, :1] = m_new
-    acc_scr[:] = acc_scr[:] * alpha + lax.dot_general(
-        p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )
-
-    @pl.when(ki == nk - 1)
-    def _done():
-        o_ref[0] = (acc_scr[:] / l_scr[:, :1]).astype(o_ref.dtype)
-
-
-def flash_qscaled(q, k, v, bq=1024, bk=1024):
-    BH, S, D = q.shape
-    scale = 1.0 / (D ** 0.5)
-    return pl.pallas_call(
-        functools.partial(_fwd_kernel_qscaled, bq=bq, bk=bk, scale=scale),
-        out_shape=jax.ShapeDtypeStruct((BH, S, D), q.dtype),
-        grid=(BH, S // bq, S // bk),
-        in_specs=[
-            pl.BlockSpec((1, bq, D), lambda b, qi, ki: (b, qi, 0)),
-            pl.BlockSpec((1, bk, D), lambda b, qi, ki: (b, ki, 0)),
-            pl.BlockSpec((1, bk, D), lambda b, qi, ki: (b, ki, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, bq, D), lambda b, qi, ki: (b, qi, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((bq, 8), jnp.float32),
-            pltpu.VMEM((bq, 8), jnp.float32),
-            pltpu.VMEM((bq, D), jnp.float32),
-        ],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary"),
-        ),
-    )(q, k, v)
-
-
-def flash_production(q, k, v):
-    """The repo's real forward (ops/flash_attention.py), dropout off.
-    Takes/returns (B, S, H, D); the caller reshapes."""
-    from distributed_llm_training_benchmark_framework_tpu.ops.flash_attention import (
-        flash_attention,
-    )
-
-    return flash_attention(q, k, v)
-
-
 def xla_sdpa(q, k, v):
     scale = 1.0 / (q.shape[-1] ** 0.5)
     s = jnp.einsum("bqd,bkd->bqk", q, k, preferred_element_type=jnp.float32) * scale
@@ -376,83 +304,186 @@ def xla_sdpa(q, k, v):
     return jnp.einsum("bqk,bkd->bqd", p.astype(v.dtype), v)
 
 
-def device_bf16_peak_flops() -> float:
-    """bf16 peak for the local device from the repo's own table (utils/
-    flops.py); 197 TFLOP/s (v5e) when the kind is unknown."""
-    try:
-        from distributed_llm_training_benchmark_framework_tpu.utils.flops import (
-            device_peak_tflops,
-        )
+def chained(fn):
+    """``n`` calls of ``fn(q, *rest) -> (out, lse)`` in one jit, each output
+    the next call's q; ``n`` is an argument, so the one compilation serves
+    the timed chain and the single call whose results are compared."""
 
-        peak = device_peak_tflops(jax.devices()[0].device_kind)
-        if peak:
-            return peak * 1e12
-    except Exception:
-        pass
-    return 197e12
+    def many(n, q, *rest):
+        def body(_, carry):
+            out, lse = fn(carry[0], *rest)
+            return out, carry[1] if lse is None else lse
+
+        stat = jnp.zeros(q.shape[:2], jnp.float32)
+        out, lse = lax.fori_loop(0, n, body, (q, stat))
+        return jnp.sum(out.astype(jnp.float32)), out, lse
+
+    return jax.jit(many)
+
+
+def time_ms(many, args, chain, reps):
+    float(many(chain, *args)[0])  # compile + warm
+    ts = []
+    for _ in range(reps):
+        t0 = time.perf_counter()
+        float(many(chain, *args)[0])
+        ts.append(time.perf_counter() - t0)
+    return float(np.median(ts) / chain * 1e3)
+
+
+def sweep_rows(S, layouts):
+    """(layout, bq, bk, sub_q, sub_k, lookahead) of the sweep. The whole grid
+    at the (1024, 1024) DMA tile; at the larger tiles only pieces of at most
+    512 q positions and at least 512 k positions (a whole (2048, 2048) f32
+    tile is what the pieces are there to avoid)."""
+    rows = []
+    for layout, (bq, bk) in itertools.product(layouts, DMA_TILES):
+        if S % bq or S % bk:
+            continue
+        for sub_k, chunk, lookahead in itertools.product(
+            SUB_K + [bk], ROW_CHUNKS, (False, True)
+        ):
+            sub_q, sub_k = chunk or bq, min(sub_k, bk)
+            if lookahead and sub_k == bk and sub_q == bq:
+                continue  # one piece: nothing to look ahead to
+            if (bq, bk) != (1024, 1024) and (sub_q > 512 or sub_k < 512):
+                continue
+            rows.append((layout, bq, bk, sub_q, sub_k, lookahead))
+    return sorted(set(rows))
 
 
 def main():
     ap = argparse.ArgumentParser()
-    ap.add_argument("--chain", type=int, default=500,
-                    help="kernel calls chained per timed jit execution")
+    ap.add_argument("--describe", action="store_true",
+                    help="compile every row for a described v5e; no timing")
+    ap.add_argument("--shapes", nargs="*", default=list(SHAPES))
+    ap.add_argument("--dropout", type=float, default=None,
+                    help="override the shapes' dropout rate")
+    ap.add_argument("--causal", type=int, default=None, choices=(0, 1),
+                    help="override the shapes' masking")
+    ap.add_argument("--layouts", nargs="*", default=["qmajor", "kmajor"],
+                    help="score tile (rows q, lanes k) or (rows k, lanes q)")
+    ap.add_argument("--trim", type=int, nargs="*", default=[0],
+                    help="1 = one multiply a score (scale, log2 e and "
+                         "1 / keep_prob folded), 0 = the plain arithmetic")
+    ap.add_argument("--rows", nargs="*", default=None,
+                    help="explicit rows layout:bq:bk:sub_q:sub_k:lookahead:trim "
+                         "instead of the grid; layout prod = the real kernel "
+                         "at that tile with that sub_k (sub_q is ignored)")
+    ap.add_argument("--target-ms", type=float, default=150.0,
+                    help="device time one timed execution should take")
     ap.add_argument("--reps", type=int, default=5)
-    ap.add_argument("--bh", type=int, default=16)
-    ap.add_argument("--seq", type=int, default=2048)
-    ap.add_argument("--dim", type=int, default=64)
+    ap.add_argument("--out", default="chiprun_out/flash_fwd_sweep.jsonl")
     args = ap.parse_args()
 
-    BH, S, D = args.bh, args.seq, args.dim
-    # The prototype kernels hard-code 1024-wide tiles and the headpair
-    # variant pairs heads; refuse geometries that would silently produce a
-    # zero-size grid (a kernel that never runs times as "very fast").
-    if S % 1024 != 0:
-        ap.error(f"--seq must be a multiple of 1024 (got {S})")
-    if BH % 2 != 0:
-        ap.error(f"--bh must be even for the headpair variant (got {BH})")
-    rng = np.random.default_rng(0)
-    q = jnp.asarray(rng.standard_normal((BH, S, D)), jnp.bfloat16)
-    k = jnp.asarray(rng.standard_normal((BH, S, D)), jnp.bfloat16)
-    v = jnp.asarray(rng.standard_normal((BH, S, D)), jnp.bfloat16)
-    kt = jnp.swapaxes(k, 1, 2)
-    # Production API takes (B, S, H, D).
-    q4 = jnp.swapaxes(q, 0, 1)[None]
-    k4 = jnp.swapaxes(k, 0, 1)[None]
-    v4 = jnp.swapaxes(v, 0, 1)[None]
+    sharding = None
+    if args.describe:
+        from jax.experimental import topologies
+        from jax.sharding import SingleDeviceSharding
 
-    flops = 2 * 2 * BH * S * S * D
-    peak = device_bf16_peak_flops()
-    print(f"shapes BH={BH} S={S} D={D}; bf16 peak {peak/1e12:.0f} TFLOP/s; "
-          f"analytic MXU floor {flops / peak * 1e3:.3f} ms")
+        topo = topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2"
+        )
+        sharding = SingleDeviceSharding(topo.devices[0])
+    elif jax.default_backend() != "tpu":
+        sys.exit("no TPU: --describe compiles without one, timing needs one")
 
-    variants = {
-        "xla_sdpa": (xla_sdpa, (q, k, v)),
-        "matmul_floor": (matmul_floor, (q, k, v)),
-        "flash_current": (flash_current, (q, k, v)),
-        "flash_headpair": (flash_headpair, (q, k, v)),
-        "flash_kt": (flash_kt, (q, kt, v)),
-        "flash_qscaled": (flash_qscaled, (q, k, v)),
-        "flash_production": (flash_production, (q4, k4, v4)),
-    }
-    ref = None
-    for name, (fn, a) in variants.items():
-        try:
-            chain = args.chain if name != "xla_sdpa" else max(args.chain // 5, 20)
-            ms = timeit_chained(fn, a, chain=chain, n=args.reps)
-        except Exception as e:
-            print(f"{name:16s} FAILED: {type(e).__name__}: {str(e)[:160]}")
-            continue
-        out = np.asarray(jax.jit(fn)(*a), np.float32)
-        if name == "flash_production":
-            out = np.swapaxes(out[0], 0, 1)
-        if name == "xla_sdpa":
-            ref = out
-        tag = ""
-        if ref is not None and name not in ("xla_sdpa", "matmul_floor"):
-            err = np.max(np.abs(out - ref))
-            tag = f"  max|Δ| vs sdpa {err:.3e}"
-        eff = flops / (ms / 1e3) / peak * 100
-        print(f"{name:16s} {ms:8.3f} ms   {eff:5.1f}% of bf16 peak{tag}")
+    os.makedirs(os.path.dirname(args.out), exist_ok=True)
+    for name in args.shapes:
+        shape = dict(SHAPES[name])
+        if args.dropout is not None:
+            shape["rate"] = args.dropout
+        if args.causal is not None:
+            shape["causal"] = bool(args.causal)
+        BH, S, D = shape["BH"], shape["S"], shape["D"]
+        causal, rate = shape["causal"], shape["rate"]
+        flops = 4 * BH * S * S * D / (2 if causal else 1)
+        least_ms = flops / PEAK_FLOPS * 1e3
+        # Score tiles a call visits, in units of (1024, 1024). (What the MXU
+        # allows one: 2 products x 8192 row pushes over 4 MXUs at 1.5 GHz =
+        # 2.73 us, at D 64 as at D 128.)
+        tiles = BH * (S // 1024) ** 2
+        if causal:
+            tiles = BH * (S // 1024) * (S // 1024 + 1) // 2
+        print(f"{name}: {shape}; least time {least_ms:.3f} ms a call at "
+              f"full-width peak; {tiles} live (1024, 1024) tiles", flush=True)
+
+        x = jax.ShapeDtypeStruct((BH, S, D), jnp.bfloat16, sharding=sharding)
+        seed_a = jax.ShapeDtypeStruct((1,), jnp.uint32, sharding=sharding)
+        bhv_a = jax.ShapeDtypeStruct((BH,), jnp.int32, sharding=sharding)
+        n_a = jax.ShapeDtypeStruct((), jnp.int32, sharding=sharding)
+        if not args.describe:
+            keys = jax.random.split(jax.random.key(0), 3)
+            q, k, v = (jax.random.normal(key, (BH, S, D), jnp.bfloat16)
+                       for key in keys)
+            seed = jnp.asarray([1234], jnp.uint32)
+            bhv = jnp.arange(BH, dtype=jnp.int32)
+        chain = max(4, int(args.target_ms / max(2.0 * least_ms, 0.05)))
+
+        def production(q, k, v, seed, bhv, bq=None, bk=None, sub_k=None):
+            return fa._flash_forward(
+                q, k, v, causal, False,
+                bq or fa._pick_block(S, fa._FWD_BLOCK_Q),
+                bk or fa._pick_block(S, fa._FWD_BLOCK_K),
+                rate, seed, bhv, sub_k=sub_k,
+            )
+
+        variants = [
+            ("matmul_floor", {}, lambda q, k, v, seed, bhv: (matmul_floor(q, k, v), None)),
+            ("flash_production", {}, production),
+        ]
+        if BH * S * S * 4 <= 2 * 2**30:
+            variants.insert(1, ("xla_sdpa", {}, lambda q, k, v, seed, bhv: (xla_sdpa(q, k, v), None)))
+        fields = ("layout", "bq", "bk", "sub_q", "sub_k", "lookahead", "trim")
+        if args.rows:
+            rows = [tuple(f if i == 0 else int(f) for i, f in enumerate(r.split(":")))
+                    for r in args.rows]
+            rows = [r for r in rows if S % r[1] == 0 and S % r[2] == 0]
+        else:
+            rows = [r + (t,) for t in args.trim
+                    for r in sweep_rows(S, args.layouts)]
+        for row in rows:
+            cfg = dict(zip(fields, row))
+            if cfg["layout"] == "prod":  # the real kernel, its piece forced
+                cfg = {k: cfg[k] for k in ("bq", "bk", "sub_k")}
+                variants.append((
+                    "flash_production", cfg, functools.partial(production, **cfg)
+                ))
+                continue
+            cfg.update(lookahead=bool(cfg["lookahead"]), trim=bool(cfg["trim"]))
+            variants.append((
+                "flash_subtiled", cfg,
+                functools.partial(flash_subtiled, causal=causal, rate=rate, **cfg),
+            ))
+
+        want = None
+        for variant, cfg, fn in variants:
+            row = dict(shape=name, variant=variant, **cfg)
+            n = chain if variant != "xla_sdpa" else max(chain // 8, 2)
+            many = chained(fn)
+            try:
+                if args.describe:
+                    many.lower(n_a, x, x, x, seed_a, bhv_a).compile()
+                    row["compiles"] = True
+                else:
+                    row["ms"] = time_ms(many, (q, k, v, seed, bhv), n, args.reps)
+                    row["pct_of_peak"] = 100 * least_ms / row["ms"]
+                    row["us_a_tile"] = row["ms"] * 1e3 / tiles
+                    _, out, lse = many(1, q, k, v, seed, bhv)
+                    if want is None and variant == "flash_production":
+                        want = (out, lse)
+                    elif variant.startswith("flash_"):
+                        row["max_abs_diff_out"] = float(jnp.max(jnp.abs(
+                            out.astype(jnp.float32) - want[0].astype(jnp.float32)
+                        )))
+                        row["max_abs_diff_lse"] = float(
+                            jnp.max(jnp.abs(lse - want[1]))
+                        )
+            except Exception as e:  # Mosaic's refusal is the finding
+                row["error"] = str(e).splitlines()[0][:200]
+            print(json.dumps(row), flush=True)
+            with open(args.out, "a") as f:
+                f.write(json.dumps(row) + "\n")
 
 
 if __name__ == "__main__":

@@ -113,7 +113,12 @@ def test_flash_dropout_matches_masked_reference(causal):
 
 def test_flash_dropout_block_size_invariant():
     """The keep mask is a function of absolute coordinates, so different
-    tilings (the fwd/bwd situation) produce the same output."""
+    tilings (the fwd/bwd situation) and different compute sub-tiles inside a
+    tile produce the same output."""
+    from distributed_llm_training_benchmark_framework_tpu.ops import (
+        flash_attention as fa,
+    )
+
     rate = 0.1
     q, k, v = qkv(B=1, S=128, H=2, D=32)
     seed = jnp.asarray(7, jnp.uint32)
@@ -125,10 +130,103 @@ def test_flash_dropout_block_size_invariant():
     np.testing.assert_allclose(
         np.asarray(out32), np.asarray(out64), rtol=1e-5, atol=1e-5
     )
+    # The same tiles, their keys walked in compute pieces of 16 and 32 (forced
+    # below the public call, which has no argument for it).
+    bhsd = lambda t: t[0].transpose(1, 0, 2)
+    for bq, bk, sub_k in [(64, 64, 16), (32, 128, 32), (128, 64, 16)]:
+        out, _ = fa._flash_forward(
+            bhsd(q), bhsd(k), bhsd(v), False, True, bq, bk, rate,
+            seed.reshape(1), jnp.arange(2, dtype=jnp.int32), sub_k=sub_k,
+        )
+        np.testing.assert_allclose(
+            np.asarray(out), np.asarray(bhsd(out64)), rtol=1e-5, atol=1e-5
+        )
     # And both agree with the materialized-mask reference.
     keep = _hash_keep_mask(7, 1, 2, 128, rate)
     ref = _masked_reference(q, k, v, keep, rate)
     np.testing.assert_allclose(np.asarray(out64), np.asarray(ref), rtol=2e-3, atol=2e-3)
+
+
+def _forward_operands(S, H, D):
+    """(BH, S, D) float32 operands of ``_flash_forward`` with its seed and
+    (batch, head) ids."""
+    q, k, v = (t[0].transpose(1, 0, 2) for t in qkv(B=1, S=S, H=H, D=D))
+    return q, k, v, jnp.asarray([21], jnp.uint32), jnp.arange(H, dtype=jnp.int32)
+
+
+@pytest.mark.parametrize(
+    "bq,bk,sub_k", [(64, 64, 16), (32, 128, 32), (128, 32, 32)],
+    ids=["64x64/16", "32x128/32", "128x32/32"],
+)
+@pytest.mark.parametrize("rate", [0.0, 0.1], ids=["nodrop", "dropout"])
+@pytest.mark.parametrize("causal", [False, True], ids=["full", "causal"])
+def test_flash_forward_pieces_match_whole_tile(causal, rate, bq, bk, sub_k):
+    """A (bq, bk) tile whose keys are walked in compute pieces of ``sub_k``
+    (one online-softmax update a piece) gives the whole-tile walk's ``out``
+    and ``lse`` to f32 rounding, and the materialised reference's with the
+    same seed: the keep mask did not move. (128, 32, 32) is the tile no wider
+    than the piece: one piece, the same code."""
+    from distributed_llm_training_benchmark_framework_tpu.ops import (
+        flash_attention as fa,
+    )
+
+    q, k, v, seed, bhv = _forward_operands(S=128, H=2, D=32)
+    args = (q, k, v, causal, True, bq, bk, rate, seed, bhv)
+    out, lse = fa._flash_forward(*args, sub_k=sub_k)
+    whole_out, whole_lse = fa._flash_forward(*args, sub_k=bk)
+    ref_out, ref_lse = fa._jnp_reference_forward(q, k, v, causal, rate, seed, bhv)
+    for got, whole, ref in [(out, whole_out, ref_out), (lse, whole_lse, ref_lse)]:
+        np.testing.assert_allclose(np.asarray(got), np.asarray(whole), rtol=1e-5, atol=1e-5)
+        np.testing.assert_allclose(np.asarray(got), np.asarray(ref), rtol=1e-5, atol=1e-5)
+
+
+@pytest.mark.parametrize(
+    "bq,bk,sub_k", [(64, 128, 32), (128, 64, 16)], ids=["64x128/32", "128x64/16"]
+)
+def test_flash_forward_diagonal_cuts_through_pieces(bq, bk, sub_k):
+    """Causal, bq != bk, pieces narrower than both: in the live tiles of a q
+    tile some pieces lie wholly below the diagonal, some are cut by it at a
+    different offset each, and some lie wholly above it (every score masked:
+    the running max and sum must pass through unchanged, with no second mask
+    on the probabilities)."""
+    from distributed_llm_training_benchmark_framework_tpu.ops import (
+        flash_attention as fa,
+    )
+
+    q, k, v, seed, bhv = _forward_operands(S=256, H=2, D=32)
+    out, lse = fa._flash_forward(
+        q, k, v, True, True, bq, bk, 0.1, seed, bhv, sub_k=sub_k
+    )
+    ref_out, ref_lse = fa._jnp_reference_forward(q, k, v, True, 0.1, seed, bhv)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref_out), rtol=1e-5, atol=1e-5)
+    np.testing.assert_allclose(np.asarray(lse), np.asarray(ref_lse), rtol=1e-5, atol=1e-5)
+    assert np.isfinite(np.asarray(lse)).all()
+
+
+@pytest.mark.parametrize(
+    "cell,seq",
+    [
+        ("tinygpt-a.seq2048", 2048), ("tinygpt-a.seq8192", 8192),
+        ("mistral-7b.d2", 4096), ("mistral-7b.fsdp4", 4096),
+        ("olmoe-1b-7b.d1", 4096),
+    ],
+)
+def test_forward_piece_chooser(cell, seq):
+    """For each benchmark cell's sequence the chooser returns a divisor of
+    the DMA tile's keys that the MXU takes whole (a multiple of 128) and that
+    is a real cut (the tile is wider); a tile no wider than the piece, or one
+    the piece does not divide, is walked whole. Head dim, dropout and mask do
+    not enter: the sweep found one width for all three shapes."""
+    from distributed_llm_training_benchmark_framework_tpu.ops import (
+        flash_attention as fa,
+    )
+
+    bk = fa._pick_block(seq, fa._FWD_BLOCK_K)
+    sub_k = fa._fwd_sub_k(bk)
+    assert bk % sub_k == 0 and sub_k % 128 == 0 and sub_k < bk
+    for small in (8, 32, 64, sub_k):
+        assert fa._fwd_sub_k(small) == small
+    assert fa._fwd_sub_k(sub_k + 8) == sub_k + 8
 
 
 @pytest.mark.parametrize("pallas_backward", [False, True])

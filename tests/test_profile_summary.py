@@ -58,6 +58,14 @@ def test_find_and_summarize(tmp_path):
     assert "%fusion.12" in text  # provenance surfaced
 
 
+def test_op_class_knows_the_flash_kernels_by_name():
+    """The forward and the fused backward carry their pallas_call's name in
+    a trace; the ring block kernels their jit's."""
+    for name in ("flash_fwd.3", "flash_bwd_fused.1", "jvp_jit_flash_attention__.3"):
+        assert ps.op_class(name) == "flash_kernel"
+    assert ps.op_class("fusion.1234") == "fusion"
+
+
 def test_cli_missing_trace_errors_to_stderr(tmp_path, capsys):
     """ERROR lines belong on stderr: a scripted `$(...)` capture of the
     summary must not swallow the failure into the captured variable."""
