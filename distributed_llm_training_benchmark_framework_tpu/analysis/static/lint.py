@@ -80,13 +80,9 @@ PACKAGE = "distributed_llm_training_benchmark_framework_tpu"
 #: Harness flags deliberately NOT reachable from the container env, with the
 #: reason each is exempt from GC201 (moved here from the PR 1 ad-hoc test so
 #: there is exactly one registry):
-#:   --local-rank        accepted for reference-CLI parity only; device
-#:                       selection is mesh-driven on TPU (harness help text)
 #:   --deepspeed-config  alias of --strategy-config, which the entrypoint
 #:   --fsdp-config       already sets for the ZeRO arms
-ENTRYPOINT_EXEMPT_FLAGS = frozenset(
-    {"--local-rank", "--deepspeed-config", "--fsdp-config"}
-)
+ENTRYPOINT_EXEMPT_FLAGS = frozenset({"--deepspeed-config", "--fsdp-config"})
 
 #: Flags the entrypoint passes to scripts/with_retries.sh (the retry
 #: wrapper it execs in retry mode) — wrapper surface, not harness surface,

@@ -75,17 +75,6 @@ class TinyGPTConfig:
     # 'reference' = jnp softmax attention; 'flash' = Pallas TPU kernel;
     # 'ring' = ring attention over a sequence-parallel mesh axis.
     attention_impl: str = "reference"
-    # Flash-kernel tile sizes (None = kernel's tuned default). Exposed as a
-    # real tuning surface (--flash-block-q/k/k-bwd) because the optima are
-    # device-generation dependent — and differ between forward and backward.
-    flash_block_q: Optional[int] = None
-    flash_block_k: Optional[int] = None
-    flash_block_k_bwd: Optional[int] = None
-    # Flash backward implementation: None = auto (the measured S-dependent
-    # crossover in ops/flash_attention — einsum backward to S=2048, Pallas
-    # kernels from S=4096); True forces the Pallas kernels, False forces the
-    # XLA-fused blockwise einsum backward.
-    flash_pallas_backward: Optional[bool] = None
     param_dtype: Any = jnp.float32
     compute_dtype: Any = jnp.bfloat16
     # Per-layer rematerialization policy inside the scan:
@@ -519,6 +508,13 @@ def _attention(
     seed = None
     if not deterministic and config.dropout > 0.0 and dropout_key is not None:
         seed = jax.random.bits(dropout_key, (), jnp.uint32)
+    # Which attention, never how: tiles and the backward's choice belong to
+    # ops/flash_attention.py, which picks them from S, D and VMEM.
+    kwargs = dict(
+        causal=config.causal,
+        dropout_rate=config.dropout if seed is not None else 0.0,
+        dropout_seed=seed,
+    )
     if config.seq_manual_axis is not None:
         # Inside a shard_map that is manual over the sequence axis (the
         # pipeline schedules): q/k/v hold LOCAL sequence chunks, so dispatch
@@ -531,24 +527,12 @@ def _attention(
             from ..ops.ring_attention import ring_attention_sharded
 
             return ring_attention_sharded(
-                q, k, v, axis_name=ax, causal=config.causal,
-                dropout_rate=config.dropout if seed is not None else 0.0,
-                dropout_seed=seed,
-                block_q=config.flash_block_q, block_k=config.flash_block_k,
-                block_k_bwd=config.flash_block_k_bwd,
-                zigzag=config.ring_zigzag,
+                q, k, v, axis_name=ax, zigzag=config.ring_zigzag, **kwargs
             )
         if config.attention_impl == "ulysses":
             from ..ops.ulysses_attention import ulysses_attention_sharded
 
-            return ulysses_attention_sharded(
-                q, k, v, axis_name=ax, causal=config.causal,
-                dropout_rate=config.dropout if seed is not None else 0.0,
-                dropout_seed=seed,
-                block_q=config.flash_block_q, block_k=config.flash_block_k,
-                block_k_bwd=config.flash_block_k_bwd,
-                pallas_backward=config.flash_pallas_backward,
-            )
+            return ulysses_attention_sharded(q, k, v, axis_name=ax, **kwargs)
         raise ValueError(
             "sequence-parallel pipeline needs attention_impl 'ring' or "
             f"'ulysses' (local '{config.attention_impl}' attention over a "
@@ -558,33 +542,15 @@ def _attention(
         # Pallas TPU kernel; fp32 online-softmax accumulation internally.
         from ..ops.flash_attention import flash_attention
 
-        return flash_attention(
-            q, k, v, causal=config.causal,
-            block_q=config.flash_block_q, block_k=config.flash_block_k,
-            block_k_bwd=config.flash_block_k_bwd,
-            pallas_backward=config.flash_pallas_backward,
-            dropout_rate=config.dropout if seed is not None else 0.0,
-            dropout_seed=seed,
-        )
+        return flash_attention(q, k, v, **kwargs)
     if config.attention_impl == "ring":
         from ..ops.ring_attention import ring_attention
 
-        return ring_attention(
-            q, k, v, causal=config.causal,
-            dropout_rate=config.dropout if seed is not None else 0.0,
-            dropout_seed=seed,
-            block_q=config.flash_block_q, block_k=config.flash_block_k,
-            block_k_bwd=config.flash_block_k_bwd,
-            zigzag=config.ring_zigzag,
-        )
+        return ring_attention(q, k, v, zigzag=config.ring_zigzag, **kwargs)
     if config.attention_impl == "ulysses":
         from ..ops.ulysses_attention import ulysses_attention
 
-        return ulysses_attention(
-            q, k, v, causal=config.causal,
-            dropout_rate=config.dropout if seed is not None else 0.0,
-            dropout_seed=seed,
-        )
+        return ulysses_attention(q, k, v, **kwargs)
 
     # Reference jnp implementation: softmax(QK^T/sqrt(d))V with fp32 softmax.
     scale = 1.0 / (q.shape[-1] ** 0.5)

@@ -135,8 +135,8 @@ def _pick_block(seq_len: int, preferred: int = 512) -> int:
 # (1024 doubles its time). Hence separate fwd/bwd defaults.
 #
 # In plain flash's forward that tile is the DMA tile: what a grid step brings
-# into VMEM, and what --flash-block-q/-k set. What the body computes on at a
-# time is a (bq, _FWD_SUB_K) piece of it (_flash_fwd_kernel). One call of
+# into VMEM (flash_attention's block_q / block_k). What the body computes on
+# at a time is a (bq, _FWD_SUB_K) piece of it (_flash_fwd_kernel). One call of
 # _flash_forward in us a (1024, 1024) tile (my chip run, PR 27,
 # scripts/microbench_flash_fwd.py; what the MXU allows is 2.73, the two
 # products alone take 3.0; "before" is the q-major whole-tile body):
@@ -808,16 +808,30 @@ def _fused_backward(
 
 def _pair_backward(
     q, k, v, do, lse3, delta3, seed, bhv, causal, rate, bq, bk, interpret,
+    *, q_tile_offsets=None, k_tile_offsets=None, out_dtype=None,
 ):
-    """The dq and dk+dv kernel pair on (BH, S, D) operands: every score tile
-    visited twice. Only for shapes ``_fused_fits`` turns away."""
-    BH, S, D = q.shape
+    """The dq and dk+dv kernel pair on (BH, Sq, D) queries and (BH, Sk, D)
+    keys: every score tile visited twice. The one caller of
+    ``_bwd_dq_kernel`` / ``_bwd_dkv_kernel``.
+
+    Plain flash runs it only for shapes ``_fused_fits`` turns away, with
+    tile i starting at row i*b (the default offsets) and gradients in the
+    operands' dtypes. Ring attention runs it on each resident block with the
+    block's global tile bases (``q_tile_offsets`` (Sq//bq,) /
+    ``k_tile_offsets`` (Sk//bk,) int32: shard offsets, or the zigzag
+    half-chunk bases) and ``out_dtype`` float32, the dtype its dk / dv ride
+    the ring in."""
+    BH, Sq, D = q.shape
+    Sk = k.shape[1]
     scale = 1.0 / (D ** 0.5)
     seed_spec = pl.BlockSpec(memory_space=pltpu.SMEM)
-    # Plain flash = the shared tile-base-aware kernels at identity bases
-    # (tile i starts at row i*b); ring attention feeds shard offsets.
-    qoffs = jnp.arange(S // bq, dtype=jnp.int32) * bq
-    koffs = jnp.arange(S // bk, dtype=jnp.int32) * bk
+    qoffs = (jnp.arange(Sq // bq, dtype=jnp.int32) * bq
+             if q_tile_offsets is None else q_tile_offsets)
+    koffs = (jnp.arange(Sk // bk, dtype=jnp.int32) * bk
+             if k_tile_offsets is None else k_tile_offsets)
+    dq_dtype, dk_dtype, dv_dtype = (
+        (q.dtype, k.dtype, v.dtype) if out_dtype is None else (out_dtype,) * 3
+    )
     row_specs = dict(
         q=pl.BlockSpec((1, bq, D), lambda b, qi, ki: (b, qi, 0)),
         k=pl.BlockSpec((1, bk, D), lambda b, qi, ki: (b, ki, 0)),
@@ -828,8 +842,8 @@ def _pair_backward(
             _bwd_dq_kernel, bq=bq, bk=bk, scale=scale, causal=causal,
             dropout_rate=rate,
         ),
-        out_shape=_vma_struct((BH, S, D), q.dtype, q, k, v, do),
-        grid=(BH, S // bq, S // bk),
+        out_shape=_vma_struct((BH, Sq, D), dq_dtype, q, k, v, do),
+        grid=(BH, Sq // bq, Sk // bk),
         in_specs=[seed_spec, seed_spec, seed_spec, seed_spec,
                   row_specs["q"], row_specs["k"], row_specs["k"],
                   row_specs["q"], row_specs["stat"], row_specs["stat"]],
@@ -852,10 +866,10 @@ def _pair_backward(
             dropout_rate=rate,
         ),
         out_shape=[
-            _vma_struct((BH, S, D), k.dtype, q, k, v, do),
-            _vma_struct((BH, S, D), v.dtype, q, k, v, do),
+            _vma_struct((BH, Sk, D), dk_dtype, q, k, v, do),
+            _vma_struct((BH, Sk, D), dv_dtype, q, k, v, do),
         ],
-        grid=(BH, S // bk, S // bq),
+        grid=(BH, Sk // bk, Sq // bq),
         in_specs=[seed_spec, seed_spec, seed_spec, seed_spec,
                   col_specs["q"], col_specs["k"], col_specs["k"],
                   col_specs["q"], col_specs["stat"], col_specs["stat"]],

@@ -58,10 +58,9 @@ from jax.experimental.pallas import tpu as pltpu
 from jax.sharding import PartitionSpec as P
 
 from .flash_attention import (
-    _bwd_dq_kernel,
-    _bwd_dkv_kernel,
     _dropout_keep,
     _dropout_threshold,
+    _pair_backward,
     _pick_block,
     _resolve_interpret,
     _vma_struct,
@@ -209,71 +208,6 @@ def _block_stats_kernel(
     return m[:, 0, :], l[:, 0, :], o
 
 
-def _block_bwd_kernel(
-    q3, k_b, v_b, do3, lse, delta, seed, qoffs, koffs, bh_vec,
-    causal: bool, dropout_rate: float, bq: int, bk: int,
-) -> Tuple[jax.Array, jax.Array, jax.Array]:
-    """Pallas path for one resident ring block's backward ->
-    (dq_partial, dk_b, dv_b), all fp32 (BH, Sl, D). Runs the SHARED
-    offset-aware flash backward kernels (flash_attention._bwd_dq_kernel /
-    _bwd_dkv_kernel) with this block's global offsets and batch*head
-    indices in SMEM — one kernel implementation serves flash and ring."""
-    BH, Sq, D = q3.shape
-    Sk = k_b.shape[1]
-    scale = 1.0 / (D ** 0.5)
-    lse3 = jnp.broadcast_to(lse[:, None, :], (BH, 8, Sq))
-    delta3 = jnp.broadcast_to(delta[:, None, :], (BH, 8, Sq))
-    smem = pl.BlockSpec(memory_space=pltpu.SMEM)
-    row = dict(
-        q=pl.BlockSpec((1, bq, D), lambda b, qi, ki: (b, qi, 0)),
-        k=pl.BlockSpec((1, bk, D), lambda b, qi, ki: (b, ki, 0)),
-        stat=pl.BlockSpec((1, 8, bq), lambda b, qi, ki: (b, 0, qi)),
-    )
-    dq = pl.pallas_call(
-        functools.partial(
-            _bwd_dq_kernel, bq=bq, bk=bk, scale=scale, causal=causal,
-            dropout_rate=dropout_rate,
-        ),
-        out_shape=_vma_struct((BH, Sq, D), jnp.float32, q3, k_b, v_b, do3),
-        grid=(BH, Sq // bq, Sk // bk),
-        in_specs=[smem, smem, smem, smem, row["q"], row["k"], row["k"],
-                  row["q"], row["stat"], row["stat"]],
-        out_specs=row["q"],
-        scratch_shapes=[pltpu.VMEM((bq, D), jnp.float32)],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary"),
-        ),
-    )(seed, qoffs, koffs, bh_vec, q3, k_b, v_b, do3, lse3, delta3)
-
-    col = dict(
-        q=pl.BlockSpec((1, bq, D), lambda b, ki, qi: (b, qi, 0)),
-        k=pl.BlockSpec((1, bk, D), lambda b, ki, qi: (b, ki, 0)),
-        stat=pl.BlockSpec((1, 8, bq), lambda b, ki, qi: (b, 0, qi)),
-    )
-    dk, dv = pl.pallas_call(
-        functools.partial(
-            _bwd_dkv_kernel, bq=bq, bk=bk, scale=scale, causal=causal,
-            dropout_rate=dropout_rate,
-        ),
-        out_shape=[
-            _vma_struct((BH, Sk, D), jnp.float32, q3, k_b, v_b, do3),
-            _vma_struct((BH, Sk, D), jnp.float32, q3, k_b, v_b, do3),
-        ],
-        grid=(BH, Sk // bk, Sq // bq),
-        in_specs=[smem, smem, smem, smem, col["q"], col["k"], col["k"],
-                  col["q"], col["stat"], col["stat"]],
-        out_specs=[col["k"], col["k"]],
-        scratch_shapes=[
-            pltpu.VMEM((bk, D), jnp.float32),
-            pltpu.VMEM((bk, D), jnp.float32),
-        ],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary"),
-        ),
-    )(seed, qoffs, koffs, bh_vec, q3, k_b, v_b, do3, lse3, delta3)
-    return dq, dk, dv
-
-
 def _block_stats_jnp(
     q3, k3, v3, seed, row_idx, col_idx, bh_vec,
     causal: bool, dropout_rate: float,
@@ -313,6 +247,76 @@ def _block_stats_jnp(
     )
     return m, l, o
 
+
+def _block_bwd_jnp(
+    q3, k_b, v_b, do3, lse, delta, seed, row_idx, col_idx, bh_vec,
+    causal: bool, dropout_rate: float, tile: int,
+) -> Tuple[jax.Array, jax.Array, jax.Array]:
+    """Einsum path for one resident ring block's backward ->
+    (dq_partial, dk_b, dv_b), all fp32 (BH, Sl, D), tiled over K so only
+    (Sl, tile) score tiles materialize — flash_attention._jnp_blockwise_bwd
+    restricted to this block, with per-row GLOBAL index vectors
+    (``row_idx``/``col_idx``, contiguous or zigzag) as _block_stats_jnp."""
+    from ..utils.vma import pcast_like
+
+    BH, Sl, D = q3.shape
+    f32 = jnp.float32
+    cd = q3.dtype
+    scale = 1.0 / (D ** 0.5)
+    dof = do3.astype(cd)
+    threshold = _dropout_threshold(dropout_rate)
+    nt = Sl // tile
+    ks = k_b.reshape(BH, nt, tile, D).transpose(1, 0, 2, 3)
+    vs = v_b.reshape(BH, nt, tile, D).transpose(1, 0, 2, 3)
+    col_tiles = col_idx.reshape(nt, tile)
+
+    def one_tile(dq_acc, blk):
+        ti, k_t, v_t = blk
+        cols = jnp.take(col_tiles, ti, axis=0)
+        s = jnp.einsum(
+            "bqd,bkd->bqk", q3, k_t, preferred_element_type=f32
+        ) * scale
+        if causal:
+            mask = row_idx[:, None] >= cols[None, :]
+            s = jnp.where(mask[None], s, NEG_INF)
+        p = jnp.exp(s - lse[:, :, None])  # (BH, Sl, tile) fp32
+        if causal:
+            p = jnp.where(mask[None], p, 0.0)
+        if dropout_rate > 0.0:
+            keep = _dropout_keep(
+                seed[0], bh_vec[:, None, None], row_idx[None, :, None],
+                cols[None, None, :], threshold,
+            )
+            inv = 1.0 / (1.0 - dropout_rate)
+            pd = jnp.where(keep, p * inv, 0.0)
+            dp_scale = jnp.where(keep, inv, 0.0)
+        else:
+            pd = p
+            dp_scale = None
+        dv_t = jnp.einsum(
+            "bqk,bqd->bkd", pd.astype(cd), dof, preferred_element_type=f32
+        )
+        dp = jnp.einsum(
+            "bqd,bkd->bqk", dof, v_t, preferred_element_type=f32
+        )
+        if dp_scale is not None:
+            dp = dp * dp_scale
+        ds = (p * (dp - delta[:, :, None]) * scale).astype(cd)
+        dq_acc = dq_acc + jnp.einsum(
+            "bqk,bkd->bqd", ds, k_t, preferred_element_type=f32
+        )
+        dk_t = jnp.einsum(
+            "bqk,bqd->bkd", ds, q3, preferred_element_type=f32
+        )
+        return dq_acc, (dk_t, dv_t)
+
+    dq0 = pcast_like(jnp.zeros((BH, Sl, D), f32), q3, k_b, v_b, do3)
+    dq_p, (dk_tiles, dv_tiles) = lax.scan(
+        one_tile, dq0, (jnp.arange(nt), ks, vs)
+    )
+    dk_b = dk_tiles.transpose(1, 0, 2, 3).reshape(BH, Sl, D)
+    dv_b = dv_tiles.transpose(1, 0, 2, 3).reshape(BH, Sl, D)
+    return dq_p, dk_b, dv_b
 
 
 def _zig_chunk_bases(c, n, h):
@@ -487,8 +491,6 @@ def _ring_bwd(opts, res, do):
     bh_vec = _global_bh_vec(B, H, b_off, h_off, n_heads)
     perm = [(j, (j + 1) % n) for j in range(n)]
     f32 = jnp.float32
-    cd = q.dtype
-    scale = 1.0 / (D ** 0.5)
     import numpy as np
 
     from ..utils.vma import pcast_like
@@ -514,73 +516,17 @@ def _ring_bwd(opts, res, do):
     else:
         h = Sl
         q_bases = (my * Sl,)
-    dof = do3.astype(cd)
     rows = _bases_to_rows(q_bases, h)
-    threshold = _dropout_threshold(rate)
     tile = min(bk_bwd, h)
     # Same S-dependent backward crossover as flash_attention (measured,
     # docs/PERFORMANCE.md §12): the einsum tiles win at short blocks, the
     # Pallas kernels from _PALLAS_BWD_MIN_SEQ-sized local shards up — the
     # regime multi-chip sequence parallelism actually runs in.
     use_kernels = (not interpret) and Sl >= _PALLAS_BWD_MIN_SEQ
-
-    def block_bwd(k_b, v_b, k_rows):
-        """One resident block's (dq_partial, dk_b, dv_b), tiled over K so
-        only (Sl, tile) score tiles materialize — flash_attention.
-        _jnp_blockwise_bwd restricted to this block, with global row/col
-        index vectors (contiguous or zigzag)."""
-        nt = Sl // tile
-        ks = k_b.reshape(B * H, nt, tile, D).transpose(1, 0, 2, 3)
-        vs = v_b.reshape(B * H, nt, tile, D).transpose(1, 0, 2, 3)
-        col_tiles = k_rows.reshape(nt, tile)
-
-        def one_tile(dq_acc, blk):
-            ti, k_t, v_t = blk
-            cols = jnp.take(col_tiles, ti, axis=0)
-            s = jnp.einsum(
-                "bqd,bkd->bqk", q3, k_t, preferred_element_type=f32
-            ) * scale
-            if causal:
-                mask = rows[:, None] >= cols[None, :]
-                s = jnp.where(mask[None], s, NEG_INF)
-            p = jnp.exp(s - lse[:, :, None])  # (BH, Sl, tile) fp32
-            if causal:
-                p = jnp.where(mask[None], p, 0.0)
-            if rate > 0.0:
-                keep = _dropout_keep(
-                    seed[0], bh_vec[:, None, None], rows[None, :, None],
-                    cols[None, None, :], threshold,
-                )
-                inv = 1.0 / (1.0 - rate)
-                pd = jnp.where(keep, p * inv, 0.0)
-                dp_scale = jnp.where(keep, inv, 0.0)
-            else:
-                pd = p
-                dp_scale = None
-            dv_t = jnp.einsum(
-                "bqk,bqd->bkd", pd.astype(cd), dof, preferred_element_type=f32
-            )
-            dp = jnp.einsum(
-                "bqd,bkd->bqk", dof, v_t, preferred_element_type=f32
-            )
-            if dp_scale is not None:
-                dp = dp * dp_scale
-            ds = (p * (dp - delta[:, :, None]) * scale).astype(cd)
-            dq_acc = dq_acc + jnp.einsum(
-                "bqk,bkd->bqd", ds, k_t, preferred_element_type=f32
-            )
-            dk_t = jnp.einsum(
-                "bqk,bqd->bkd", ds, q3, preferred_element_type=f32
-            )
-            return dq_acc, (dk_t, dv_t)
-
-        dq0 = pcast_like(jnp.zeros((B * H, Sl, D), f32), q3, k_b, v_b, do3)
-        dq_p, (dk_tiles, dv_tiles) = lax.scan(
-            one_tile, dq0, (jnp.arange(nt), ks, vs)
-        )
-        dk_b = dk_tiles.transpose(1, 0, 2, 3).reshape(B * H, Sl, D)
-        dv_b = dv_tiles.transpose(1, 0, 2, 3).reshape(B * H, Sl, D)
-        return dq_p, dk_b, dv_b
+    if use_kernels:
+        # lse/delta enter the kernels sublane-broadcast, as in plain flash.
+        lse3 = jnp.broadcast_to(lse[:, None, :], (B * H, 8, Sl))
+        delta3 = jnp.broadcast_to(delta[:, None, :], (B * H, 8, Sl))
 
     dq3 = pcast_like(jnp.zeros((B * H, Sl, D), f32), q3, k3, v3, do3)
     k_cur, v_cur = k3, v3
@@ -593,15 +539,17 @@ def _ring_bwd(opts, res, do):
         src = (my - t) % n
         k_bases = _zig_chunk_bases(src, n, h) if zig else (src * Sl,)
         if use_kernels:
-            dq_p, dk_b, dv_b = _block_bwd_kernel(
-                q3, k_cur, v_cur, do3, lse, delta, seed,
-                _bases_to_tiles(q_bases, h, bq),
-                _bases_to_tiles(k_bases, h, tile),
-                bh_vec, causal, rate, bq, tile,
+            dq_p, dk_b, dv_b = _pair_backward(
+                q3, k_cur, v_cur, do3, lse3, delta3, seed, bh_vec,
+                causal, rate, bq, tile, False,
+                q_tile_offsets=_bases_to_tiles(q_bases, h, bq),
+                k_tile_offsets=_bases_to_tiles(k_bases, h, tile),
+                out_dtype=f32,
             )
         else:
-            dq_p, dk_b, dv_b = block_bwd(
-                k_cur, v_cur, _bases_to_rows(k_bases, h)
+            dq_p, dk_b, dv_b = _block_bwd_jnp(
+                q3, k_cur, v_cur, do3, lse, delta, seed, rows,
+                _bases_to_rows(k_bases, h), bh_vec, causal, rate, tile,
             )
         dq3 = dq3 + dq_p
         dk_cur = dk_cur + dk_b

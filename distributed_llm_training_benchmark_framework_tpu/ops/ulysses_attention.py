@@ -89,10 +89,6 @@ def ulysses_attention_sharded(
     batch_axis: Optional[str] = None,
     heads_axis: Optional[str] = None,
     interpret: Optional[bool] = None,
-    block_q: Optional[int] = None,
-    block_k: Optional[int] = None,
-    block_k_bwd: Optional[int] = None,
-    pallas_backward: Optional[bool] = None,
 ) -> jax.Array:
     """Ulysses body; call inside shard_map with seq sharded on axis_name.
 
@@ -126,8 +122,6 @@ def ulysses_attention_sharded(
         rate = dropout_rate
     out = flash_attention(
         qg, kg, vg, causal=causal, interpret=interpret,
-        block_q=block_q, block_k=block_k, block_k_bwd=block_k_bwd,
-        pallas_backward=pallas_backward,
         dropout_rate=rate, dropout_seed=seed,
     )  # (B, S, H/n, D)
     # heads-sharded -> seq-sharded
@@ -143,24 +137,16 @@ def ulysses_attention(
     mesh: Optional[jax.sharding.Mesh] = None,
     dropout_rate: float = 0.0,
     dropout_seed: Optional[jax.Array] = None,
-    block_q: Optional[int] = None,
-    block_k: Optional[int] = None,
-    block_k_bwd: Optional[int] = None,
-    pallas_backward: Optional[bool] = None,
 ) -> jax.Array:
     """Shard the sequence over ``axis_name`` and run Ulysses. Falls back to
     plain flash when no such mesh axis is in scope (mirrors ring_attention's
-    contract, so attention_impl='ulysses' runs anywhere). Flash tuning
-    parameters pass straight through — the local compute IS the flash
-    kernel, so tier-tuned tile sizes apply under Ulysses too."""
+    contract, so attention_impl='ulysses' runs anywhere)."""
     mesh, batch_ax, model_ax = resolve_seq_mesh(mesh, axis_name)
     if mesh is None:
         from .flash_attention import flash_attention
 
         return flash_attention(
             q, k, v, causal=causal,
-            block_q=block_q, block_k=block_k, block_k_bwd=block_k_bwd,
-            pallas_backward=pallas_backward,
             dropout_rate=dropout_rate, dropout_seed=dropout_seed,
         )
 
@@ -179,8 +165,6 @@ def ulysses_attention(
             qs, ks, vs, axis_name=axis_name, causal=causal,
             dropout_rate=dropout_rate, dropout_seed=seed_s,
             batch_axis=batch_ax, heads_axis=model_ax,
-            block_q=block_q, block_k=block_k, block_k_bwd=block_k_bwd,
-            pallas_backward=pallas_backward,
         )
 
     fn = jax.shard_map(

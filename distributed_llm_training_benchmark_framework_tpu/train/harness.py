@@ -1,16 +1,18 @@
 """CLI harness — flag-compatible with the reference, TPU semantics underneath.
 
-Reference CLI: ``benchmarking/train_harness.py:465-504``. Every reference flag
-is accepted; unlike the reference, every accepted flag is *live* (SURVEY §2.1
-C9 lists ``--synthetic`` and ``--fsdp-config`` as accepted-but-inert there,
-and ``--grad-accum`` as silently ignored for DDP/FSDP).
+Reference CLI: ``benchmarking/train_harness.py:465-504``. Unlike the
+reference, every accepted flag is *live* (SURVEY §2.1 C9 lists ``--synthetic``
+and ``--fsdp-config`` as accepted-but-inert there, and ``--grad-accum`` as
+silently ignored for DDP/FSDP). Two reference flags that could only be inert
+here are not accepted: ``--synthetic`` (the data path is chosen by
+``--data-path`` alone) and ``--local-rank`` (device selection is mesh-driven).
 
 Semantics mapping:
 - ``--world-size`` counts chips (== the reference's GPU count). On a single
   host it selects the first N local devices; multi-host runs additionally set
   ``--num-processes``/``--process-id`` (or the env contract in
   ``runtime.distributed``).
-- ``--rank``/``--local-rank``/``--master-addr``/``--master-port`` map onto the
+- ``--rank``/``--master-addr``/``--master-port`` map onto the
   jax.distributed coordinator contract.
 - ``--deepspeed-config``/``--fsdp-config`` are accepted aliases for
   ``--strategy-config`` pointing at ``configs/strategies/*.json`` (our live
@@ -42,9 +44,6 @@ def build_parser() -> argparse.ArgumentParser:
     p.add_argument("--world-size", type=int, required=True,
                    help="Total number of chips (== reference GPU count)")
     p.add_argument("--rank", type=int, default=0, help="Global process rank")
-    p.add_argument("--local-rank", type=int, default=0,
-                   help="Accepted for contract parity; device selection is "
-                        "mesh-driven on TPU")
     p.add_argument("--master-addr", type=str, default="localhost",
                    help="Coordinator address (multi-host only)")
     p.add_argument("--master-port", type=int, default=29500)
@@ -91,9 +90,6 @@ def build_parser() -> argparse.ArgumentParser:
                         "default) or 'llama' (RMSNorm/RoPE/SwiGLU/GQA, "
                         "causal, head_dim-128 tiers — models.llama)")
     p.add_argument("--seq-len", type=int, required=True)
-    p.add_argument("--synthetic", action="store_true", default=True,
-                   help="Use synthetic data (the default zero-IO table; "
-                        "--data-path overrides it with the streaming path)")
     p.add_argument("--data-path", type=str, default=None,
                    help="Directory of tokenized record shards "
                         "(scripts/make_tokenized_shards.py format): the "
@@ -127,10 +123,6 @@ def build_parser() -> argparse.ArgumentParser:
                         "off for reference parity (train_harness.py:127 "
                         "applies no mask); on causal rings this auto-enables "
                         "the zigzag load-balanced layout")
-    p.add_argument("--flash-block-q", type=int, default=None,
-                   help="Flash-attention q tile size (default: kernel-tuned)")
-    p.add_argument("--flash-block-k", type=int, default=None,
-                   help="Flash-attention k tile size (default: kernel-tuned)")
     p.add_argument("--prng-impl", choices=["rbg", "threefry"], default="rbg",
                    help="Dropout-key PRNG: rbg (fast, default) or threefry "
                         "(bit-reproducible across backends)")
@@ -149,17 +141,6 @@ def build_parser() -> argparse.ArgumentParser:
                    help="Transformer layer iteration: lax.scan over stacked "
                         "weights (fast compile) or an unrolled loop (~15%% "
                         "faster single-chip step; slower compile)")
-    p.add_argument("--flash-pallas-backward", action="store_true",
-                   help="Force the hand-written Pallas backward kernels. "
-                        "Default is auto: the measured S-dependent crossover "
-                        "(einsum backward to seq 2048, Pallas kernels from "
-                        "4096 — docs/PERFORMANCE.md)")
-    p.add_argument("--flash-blockwise-backward", action="store_true",
-                   help="Force the XLA-fused blockwise einsum backward "
-                        "(overrides the auto S-dependent selection)")
-    p.add_argument("--flash-block-k-bwd", type=int, default=None,
-                   help="Flash-attention backward k tile size (the fwd/bwd "
-                        "optima differ; default: kernel-tuned)")
     # Training
     p.add_argument("--steps", type=int, required=True)
     p.add_argument("--warmup-steps", type=int, default=5)
@@ -334,11 +315,6 @@ def main(argv=None) -> int:
         # setup_distributed below initializes it.
         apply_latency_hiding_flags()
     enable_compile_cache()
-    if args.flash_pallas_backward and args.flash_blockwise_backward:
-        raise SystemExit(
-            "--flash-pallas-backward and --flash-blockwise-backward are "
-            "mutually exclusive (omit both for the auto S-dependent choice)"
-        )
     # Reference parity: ZeRO arms demand a config path (train_harness.py:501-502).
     if args.strategy in ("zero2", "zero3") and not (
         args.strategy_config or args.deepspeed_config or args.fsdp_config
@@ -424,14 +400,6 @@ def main(argv=None) -> int:
             dropout=args.dropout,
             causal=args.causal,
             ring_zigzag={"auto": None, "on": True, "off": False}[args.ring_zigzag],
-            flash_block_q=args.flash_block_q,
-            flash_block_k=args.flash_block_k,
-            flash_block_k_bwd=args.flash_block_k_bwd,
-            flash_pallas_backward=(
-                True if args.flash_pallas_backward
-                else False if args.flash_blockwise_backward
-                else None
-            ),
             layer_loop=args.layer_loop,
             tp_collective_matmul=args.tp_collective_matmul,
             offload_dpu_start_step=args.offload_dpu_start_step,

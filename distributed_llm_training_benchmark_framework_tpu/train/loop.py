@@ -96,6 +96,9 @@ from ..utils import flops as flops_mod
 from ..utils import metrics as metrics_mod
 from .step import create_train_state
 
+# A "[Step NNNN] Loss:" line every this many steps (rank 0).
+_LOG_EVERY = 10
+
 
 class _StepCursor:
     """The loop's step iterator, with in-run rollback support.
@@ -334,15 +337,10 @@ def _run_benchmark_impl(
     dropout: Optional[float] = None,
     causal: bool = False,
     ring_zigzag: Optional[bool] = None,
-    flash_block_q: Optional[int] = None,
-    flash_block_k: Optional[int] = None,
-    flash_block_k_bwd: Optional[int] = None,
-    flash_pallas_backward: Optional[bool] = None,
     layer_loop: str = "scan",
     tp_collective_matmul: bool = False,
     offload_dpu_start_step: int = 0,
     dataset_size: int = 1000,
-    log_every: int = 10,
     sync_every: int = 1,
     skip_memory_check: bool = False,
     profile_dir: Optional[str] = None,
@@ -556,14 +554,6 @@ def _run_benchmark_impl(
                 "expert dispatch owns the token layout; dense MLPs only)"
             )
         overrides["tp_collective_matmul"] = True
-    if flash_block_q is not None:
-        overrides["flash_block_q"] = flash_block_q
-    if flash_block_k is not None:
-        overrides["flash_block_k"] = flash_block_k
-    if flash_block_k_bwd is not None:
-        overrides["flash_block_k_bwd"] = flash_block_k_bwd
-    if flash_pallas_backward is not None:
-        overrides["flash_pallas_backward"] = flash_pallas_backward
     if layer_loop == "unrolled":
         # Unrolled layer loop: ~15% faster single-chip (activations save as
         # distinct buffers, no dynamic-update-slice stacking) at the cost of
@@ -990,7 +980,7 @@ def _run_benchmark_impl(
                 if s > cursor.replay_until:
                     timed_times.append((s, dt, dt_fetched))
                 timed_losses.append((s, lf))
-            if is_main and s % log_every == 0:
+            if is_main and s % _LOG_EVERY == 0:
                 print(f"[Step {s:04d}] Loss: {lf:.4f}, Time: {dt:.3f}s")
             if numerics is not None:
                 numerics.observe(

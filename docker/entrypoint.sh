@@ -44,7 +44,6 @@ export PER_DEVICE_BATCH="${PER_DEVICE_BATCH:-1}"
 export GRAD_ACCUM="${GRAD_ACCUM:-1}"
 export ATTENTION="${ATTENTION:-reference}"
 export LAYER_LOOP="${LAYER_LOOP:-scan}"
-export SYNTHETIC="${SYNTHETIC:-true}"
 export RESULTS_DIR="${RESULTS_DIR:-/results}"
 # Extended axes (defaults = off); set via pod env overlays for composition
 # runs — every accepted knob is live (no inert flags).
@@ -78,11 +77,6 @@ export DATA_STALL_TIMEOUT_SEC="${DATA_STALL_TIMEOUT_SEC:-}"
 export DROPOUT="${DROPOUT:-}"
 export PRNG_IMPL="${PRNG_IMPL:-}"
 export SKIP_MEMORY_CHECK="${SKIP_MEMORY_CHECK:-0}"
-export FLASH_BLOCK_Q="${FLASH_BLOCK_Q:-}"
-export FLASH_BLOCK_K="${FLASH_BLOCK_K:-}"
-export FLASH_BLOCK_K_BWD="${FLASH_BLOCK_K_BWD:-}"
-export FLASH_PALLAS_BACKWARD="${FLASH_PALLAS_BACKWARD:-0}"
-export FLASH_BLOCKWISE_BACKWARD="${FLASH_BLOCKWISE_BACKWARD:-0}"
 export PROFILE_DIR="${PROFILE_DIR:-}"
 export CHECKPOINT_DIR="${CHECKPOINT_DIR:-}"
 export CHECKPOINT_EVERY="${CHECKPOINT_EVERY:-}"
@@ -209,12 +203,6 @@ if [ -n "${DATA_STALL_TIMEOUT_SEC}" ]; then
   ARGS="${ARGS} --data-stall-timeout-sec ${DATA_STALL_TIMEOUT_SEC}"; fi
 if [ -n "${DROPOUT}" ]; then ARGS="${ARGS} --dropout ${DROPOUT}"; fi
 if [ -n "${PRNG_IMPL}" ]; then ARGS="${ARGS} --prng-impl ${PRNG_IMPL}"; fi
-if [ -n "${FLASH_BLOCK_Q}" ]; then
-  ARGS="${ARGS} --flash-block-q ${FLASH_BLOCK_Q}"; fi
-if [ -n "${FLASH_BLOCK_K}" ]; then
-  ARGS="${ARGS} --flash-block-k ${FLASH_BLOCK_K}"; fi
-if [ -n "${FLASH_BLOCK_K_BWD}" ]; then
-  ARGS="${ARGS} --flash-block-k-bwd ${FLASH_BLOCK_K_BWD}"; fi
 if [ -n "${PROFILE_DIR}" ]; then
   ARGS="${ARGS} --profile-dir ${PROFILE_DIR}"; fi
 if [ -n "${CHECKPOINT_DIR}" ]; then
@@ -228,10 +216,6 @@ if [ -n "${HEARTBEAT_SEC}" ]; then
 # Boolean knobs: 1 = pass the flag.
 if [ "${SKIP_MEMORY_CHECK}" = "1" ]; then
   ARGS="${ARGS} --skip-memory-check"; fi
-if [ "${FLASH_PALLAS_BACKWARD}" = "1" ]; then
-  ARGS="${ARGS} --flash-pallas-backward"; fi
-if [ "${FLASH_BLOCKWISE_BACKWARD}" = "1" ]; then
-  ARGS="${ARGS} --flash-blockwise-backward"; fi
 if [ "${RESUME}" = "1" ]; then ARGS="${ARGS} --resume"; fi
 if [ "${XLA_LATENCY_HIDING}" = "1" ]; then
   ARGS="${ARGS} --xla-latency-hiding"; fi
@@ -259,7 +243,6 @@ if [ "${GRAFTCHECK}" = "1" ]; then
   /app/scripts/graftcheck.sh || exit 1
   echo ""
 fi
-if [[ "${SYNTHETIC}" == "true" ]]; then ARGS="${ARGS} --synthetic"; fi
 if [[ "${STRATEGY}" == "zero2" || "${STRATEGY}" == "zero3" ]]; then
   ARGS="${ARGS} --strategy-config /app/configs/strategies/${STRATEGY}.json"
 fi

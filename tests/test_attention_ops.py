@@ -340,6 +340,70 @@ def test_flash_fused_backward_revisits_the_dq_row():
         )
 
 
+def test_pair_backward_identity_offsets_equal_the_default():
+    """Plain flash's tile bases (tile i starts at row i*b) are what
+    ``_pair_backward`` builds when it is handed none."""
+    from distributed_llm_training_benchmark_framework_tpu.ops import (
+        flash_attention as fa,
+    )
+
+    args = _backward_operands(64, 2, 16, True, 0.2)
+    default = fa._pair_backward(*args, True, 0.2, 16, 32, True)
+    explicit = fa._pair_backward(
+        *args, True, 0.2, 16, 32, True,
+        q_tile_offsets=jnp.arange(4, dtype=jnp.int32) * 16,
+        k_tile_offsets=jnp.arange(2, dtype=jnp.int32) * 32,
+    )
+    for got, want in zip(explicit, default):
+        assert got.dtype == want.dtype
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
+@pytest.mark.parametrize("layout", ["past", "diagonal", "zigzag"])
+def test_pair_backward_at_ring_offsets_matches_ring_einsum_block(layout):
+    """What ring attention runs on a chip from S_local 4096 up (the shared
+    pair wrapper at a resident block's global tile bases, float32 out)
+    against the einsum block backward it runs below that and on the CPU
+    meshes. Non-zero bases reach the causal mask and the dropout hash."""
+    from distributed_llm_training_benchmark_framework_tpu.ops import (
+        flash_attention as fa,
+        ring_attention as ra,
+    )
+
+    Sl, H, D, bq, tile, rate = 64, 2, 16, 16, 32, 0.2
+    h = Sl // 2 if layout == "zigzag" else Sl
+    q_bases, k_bases = {
+        "past": ((2 * Sl,), (0,)),       # a block wholly in these queries' past
+        "diagonal": ((Sl,), (Sl,)),      # the mask cuts through the block
+        "zigzag": (ra._zig_chunk_bases(1, 2, h), ra._zig_chunk_bases(0, 2, h)),
+    }[layout]
+    q, k, v = (t[0].transpose(1, 0, 2) for t in qkv(B=1, S=Sl, H=H, D=D))
+    do = jax.random.normal(jax.random.key(5), q.shape)
+    seed = jnp.asarray([99], jnp.uint32)
+    bhv = jnp.arange(H, dtype=jnp.int32) + 6  # a batch / head shard's global ids
+    rows, cols = ra._bases_to_rows(q_bases, h), ra._bases_to_rows(k_bases, h)
+    m, l, o = ra._block_stats_jnp(q, k, v, seed, rows, cols, bhv, True, rate)
+    lse = m + jnp.log(l)
+    delta = jnp.sum(do * o / l[..., None], axis=-1)
+
+    want = ra._block_bwd_jnp(
+        q, k, v, do, lse, delta, seed, rows, cols, bhv, True, rate, tile
+    )
+    stat3 = lambda x: jnp.broadcast_to(x[:, None, :], (H, 8, Sl))
+    args = (q, k, v, do, stat3(lse), stat3(delta), seed, bhv, True, rate, bq, tile, True)
+    got = fa._pair_backward(
+        *args,
+        q_tile_offsets=ra._bases_to_tiles(q_bases, h, bq),
+        k_tile_offsets=ra._bases_to_tiles(k_bases, h, tile),
+        out_dtype=jnp.float32,
+    )
+    for a, b in zip(got, want):
+        assert a.dtype == jnp.float32
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=1e-5, atol=1e-5)
+    at_zero = fa._pair_backward(*args)
+    assert not np.allclose(np.asarray(got[0]), np.asarray(at_zero[0]), atol=1e-3)
+
+
 def test_flash_backward_takes_the_pair_when_the_dq_row_outgrows_vmem(monkeypatch):
     """The fused kernel keeps a whole (S, D) dq row in VMEM; a shape past the
     cap runs the kernel pair, chosen from the shape alone."""

@@ -191,3 +191,51 @@ def test_unrolled_layer_loop_matches_scan():
         a, b = np.asarray(a, np.float64), np.asarray(b, np.float64)
         denom = np.linalg.norm(a) + 1e-12
         assert np.linalg.norm(a - b) / denom < 1e-2
+
+
+@pytest.mark.parametrize(
+    "impl, manual_axis, op, own",
+    [
+        ("flash", None, "flash_attention.flash_attention", {}),
+        ("ring", None, "ring_attention.ring_attention", {"zigzag": False}),
+        ("ulysses", None, "ulysses_attention.ulysses_attention", {}),
+        ("ring", "seq", "ring_attention.ring_attention_sharded",
+         {"axis_name": "seq", "zigzag": False}),
+        ("ulysses", "seq", "ulysses_attention.ulysses_attention_sharded",
+         {"axis_name": "seq"}),
+    ],
+    ids=["flash", "ring", "ulysses", "ring-manual", "ulysses-manual"],
+)
+def test_attention_dispatch_says_which_attention_never_how(
+    monkeypatch, impl, manual_axis, op, own
+):
+    """``_attention`` hands every op ``causal``, ``dropout_rate`` and
+    ``dropout_seed`` plus that branch's own arguments, and nothing else:
+    tiles and the backward's choice belong to ``ops/flash_attention.py``."""
+    import importlib
+
+    from distributed_llm_training_benchmark_framework_tpu.models import tinygpt
+
+    module, name = op.split(".")
+    calls = []
+    monkeypatch.setattr(
+        importlib.import_module(
+            f"distributed_llm_training_benchmark_framework_tpu.ops.{module}"
+        ),
+        name,
+        lambda q, k, v, **kwargs: calls.append(kwargs) or q,
+    )
+    cfg = small_cfg(
+        dropout=0.1, causal=True, attention_impl=impl, ring_zigzag=False,
+        seq_manual_axis=manual_axis,
+    )
+    q = jnp.zeros((1, 8, 2, 4))
+    assert tinygpt._attention(cfg, q, q, q, jax.random.key(0), False) is q
+    assert tinygpt._attention(cfg, q, q, q, jax.random.key(0), True) is q
+    training, deterministic = calls
+    seed = training.pop("dropout_seed")
+    assert seed.dtype == jnp.uint32 and seed.shape == ()
+    assert training == {"causal": True, "dropout_rate": 0.1, **own}
+    assert deterministic == {
+        "causal": True, "dropout_rate": 0.0, "dropout_seed": None, **own
+    }

@@ -130,7 +130,7 @@ def test_ring_block_kernels_compile(v5e_devices):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
 
     x = aval((heads, chunk, head_dim), jnp.bfloat16)
-    stat = aval((heads, chunk), jnp.float32)
+    stat = aval((heads, 8, chunk), jnp.float32)
     seed = aval((1,), jnp.uint32)
     bh = aval((heads,), jnp.int32)
     q_tiles = aval((chunk // 1024,), jnp.int32)
@@ -143,8 +143,9 @@ def test_ring_block_kernels_compile(v5e_devices):
         x, x, x, seed, q_tiles, q_tiles, bh,
     )
     _compile(
-        lambda q, k, v, do, lse, d, s, qo, ko, b: ra._block_bwd_kernel(
-            q, k, v, do, lse, d, s, qo, ko, b, False, rate, 1024, 512
+        lambda q, k, v, do, lse, d, s, qo, ko, b: fa._pair_backward(
+            q, k, v, do, lse, d, s, b, False, rate, 1024, 512, False,
+            q_tile_offsets=qo, k_tile_offsets=ko, out_dtype=jnp.float32,
         ),
         x, x, x, x, stat, stat, seed, q_tiles, k_tiles, bh,
     )
