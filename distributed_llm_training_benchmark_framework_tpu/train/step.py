@@ -199,6 +199,43 @@ def scan_carry_spec(
     return P(batch[0], batch[1], None)
 
 
+def _axes(entry) -> tuple:
+    """The mesh axes one PartitionSpec entry names (None, a name or a tuple)."""
+    return (entry,) if isinstance(entry, str) else tuple(entry or ())
+
+
+def mlp_hidden_spec(
+    strategy: strat.StrategyConfig,
+    mesh: Mesh,
+    cfg: tinygpt.TinyGPTConfig,
+    param_specs: Params,
+    pipelined: bool,
+):
+    """P(batch, seq, hidden) for the MLP's F-wide intermediates, or None.
+
+    Armed as ``block_param_spec`` is (sharded-param strategy, not pipelined)
+    and only where the strategy's own table shards the first projection's
+    weight (``wgu`` / ``wfc``) over 'data' along F, the largest-axis rule's
+    choice on every pure-dp mesh: the hidden dim then takes that placement
+    and the batch dims give the axis up, so each chip multiplies the F/n
+    slice of ``wgu`` and ``wproj`` it already holds and only (B, S, D)
+    activations travel. ddp / zero2 (replicated parameters), one-chip
+    meshes, the pipeline schedules, the MoE branch (no ``wgu`` leaf),
+    composed dp x tp meshes (F is 'model''s there and 'data' takes D) and
+    the collective-matmul path (owns its layout) trace the same program as
+    without the field.
+    """
+    if not strategy.shard_params or pipelined or cfg.tp_collective_matmul:
+        return None
+    spec = param_specs["blocks"].get("wgu" if cfg.mlp_act == "swiglu" else "wfc")
+    hidden = None if spec is None else list(spec)[-1]
+    if "data" not in _axes(hidden):
+        return None
+    batch = list(strat.batch_partition_spec(mesh)) + [None, None]
+    free = tuple(ax for ax in _axes(batch[0]) if ax not in _axes(hidden))
+    return P(free or None, batch[1], hidden)
+
+
 def zero2_block_grad_spec(
     strategy: strat.StrategyConfig,
     grad_sharded_specs: Params,
@@ -385,6 +422,9 @@ def make_train_step(
     carry_spec = scan_carry_spec(strategy, mesh, cfg, pipelined)
     if carry_spec is not None:
         cfg = dataclasses.replace(cfg, scan_carry_spec=carry_spec)
+    hidden_spec = mlp_hidden_spec(strategy, mesh, cfg, param_specs, pipelined)
+    if hidden_spec is not None:
+        cfg = dataclasses.replace(cfg, mlp_hidden_spec=hidden_spec)
 
     def train_step(params, opt_state, batch, step):
         if from_table:

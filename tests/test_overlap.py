@@ -194,16 +194,29 @@ def test_zero2_shape_arms_block_grad_spec(eight_devices):
 # ---------------------------------------------------------------------------
 
 
+def _tier_s_specs(mesh, cfg, shard=True):
+    import functools
+
+    from distributed_llm_training_benchmark_framework_tpu.parallel import (
+        strategies as strat,
+    )
+
+    params_shape = jax.eval_shape(
+        functools.partial(tinygpt.init_params, cfg), jax.random.key(0)
+    )
+    return strat.param_partition_specs(
+        params_shape, mesh, shard=shard, kv_heads=cfg.kv_heads,
+        scan_stacked=cfg.scan_layers,
+    )
+
+
 def test_fsdp_shape_arms_block_param_spec(eight_devices):
     """The step arms the per-layer-slice PARAM placement exactly for the
     sharded-param shapes (fsdp/zero3, incl. composed dp x tp meshes) —
     ddp/zero2 have nothing to gather, pipeline keeps the manual path, and
     layers-axis-sharded leaves are skipped like the zero2 grad rule."""
-    import functools
-
     from distributed_llm_training_benchmark_framework_tpu.parallel import (
         get_strategy,
-        strategies as strat,
     )
     from distributed_llm_training_benchmark_framework_tpu.train import (
         step as step_mod,
@@ -211,12 +224,7 @@ def test_fsdp_shape_arms_block_param_spec(eight_devices):
 
     mesh = make_mesh((4,), ("data",), devices=jax.devices()[:4])
     cfg = tinygpt.get_model_config("S", 64)
-    params_shape = jax.eval_shape(
-        functools.partial(tinygpt.init_params, cfg), jax.random.key(0)
-    )
-    specs = strat.param_partition_specs(
-        params_shape, mesh, shard=True, kv_heads=cfg.kv_heads,
-    )
+    specs = _tier_s_specs(mesh, cfg)
     for name in ("fsdp", "zero3"):
         armed = step_mod.fsdp_block_param_spec(get_strategy(name), specs, False)
         assert armed, f"{name} must arm the per-block param placement"
@@ -242,41 +250,84 @@ def test_fsdp_shape_arms_block_param_spec(eight_devices):
         step_mod._FORWARD_GATHER_OVERLAP = True
 
 
-FSDP_UNROLLED = hlo_audit.ArmSpec(
-    "fsdp-dp4-unrolled", "fsdp", (4,), ("data",),
-    global_batch=4, model_family="tinygpt",
-    config_overrides=(("scan_layers", False),),
-)
-ZERO3_UNROLLED = hlo_audit.ArmSpec(
-    "zero3-dp4-unrolled", "zero3", (4,), ("data",),
-    global_batch=4, model_family="tinygpt",
-    config_overrides=(("scan_layers", False), ("remat", "none")),
-)
+# The forward-overlap shape itself (weight movement interleaved with the
+# forward's dots, never one bundle above the first) is asserted on the compile
+# that counts, tests/test_tpu_compile.py::
+# test_forward_weight_rings_interleave_with_dots: jax 0.9.0's CPU scheduler
+# hoists every all-gather above the first dot whatever the program says.
 
 
-@pytest.mark.parametrize(
-    "spec", [FSDP_UNROLLED, ZERO3_UNROLLED], ids=["fsdp", "zero3"]
-)
-def test_forward_param_gathers_interleave_with_forward_dots(
-    eight_devices, spec
-):
-    """Round-15 forward overlap shape: the unrolled sharded-param arms'
-    weight all-gathers must appear INTERLEAVED with the forward's dot ops
-    in the optimized module — never bundled wholesale above the first dot,
-    where the layer stack would serialize behind one monolithic gather
-    phase."""
-    txt = hlo_audit.lower_arm(spec).as_text()
-    lines = txt.splitlines()
-    ags = [i for i, l in enumerate(lines)
-           if re.search(r"= \S+ all-gather\(", l)]
-    dots = [i for i, l in enumerate(lines) if re.search(r"= \S+ dot\(", l)]
-    assert ags and dots
-    first_dot = min(dots)
-    hoisted = [i for i in ags if i < first_dot]
-    assert len(hoisted) < len(ags) // 2, (
-        f"{len(hoisted)}/{len(ags)} weight all-gathers sit above the first "
-        "dot — the forward gathers have collapsed into a head bundle"
+def test_mlp_hidden_spec_arming_matrix(eight_devices):
+    """mlp_hidden_spec arms exactly where a sharded-param strategy shards
+    the first projection's weight over 'data' along F (every pure-dp mesh:
+    the largest-axis rule): the hidden dim takes 'data' and the batch dim
+    gives it up. Never for replicated parameters, a pipeline, the
+    collective-matmul path, one chip, the MoE branch, or a composed dp x tp
+    mesh (F is 'model''s there, 'data' takes D)."""
+    from distributed_llm_training_benchmark_framework_tpu.parallel import (
+        get_strategy,
     )
+    from distributed_llm_training_benchmark_framework_tpu.train import (
+        step as step_mod,
+    )
+
+    pure_dp = make_mesh((4,), ("data",), devices=jax.devices()[:4])
+    dp_sp = make_mesh((2, 2), ("data", "seq"), devices=jax.devices()[:4])
+    composed = make_mesh(
+        (2, 1, 2), ("data", "seq", "model"), devices=jax.devices()[:4]
+    )
+    one_chip = make_mesh((1,), ("data",), devices=jax.devices()[:1])
+    gelu = tinygpt.get_model_config("S", 64)
+    swiglu = dataclasses.replace(gelu, mlp_act="swiglu", bias=False)
+
+    def armed(strategy, mesh, cfg, pipelined=False):
+        shard = get_strategy(strategy).shard_params
+        return step_mod.mlp_hidden_spec(
+            get_strategy(strategy), mesh, cfg, _tier_s_specs(mesh, cfg, shard),
+            pipelined,
+        )
+
+    for cfg in (gelu, swiglu):
+        for name in ("fsdp", "zero3"):
+            assert armed(name, pure_dp, cfg) == P(None, None, "data")
+        assert armed("fsdp", dp_sp, cfg) == P(None, "seq", "data")
+        assert armed("ddp", pure_dp, cfg) is None
+        assert armed("zero2", pure_dp, cfg) is None
+        assert armed("fsdp", pure_dp, cfg, pipelined=True) is None
+        assert armed("fsdp", one_chip, cfg) is None
+        assert armed("fsdp", composed, cfg) is None
+        assert armed(
+            "fsdp", pure_dp, dataclasses.replace(cfg, tp_collective_matmul=True)
+        ) is None
+    assert armed("fsdp", pure_dp, dataclasses.replace(gelu, n_experts=4)) is None
+    # The model's hook: an unset field is an exact no-op.
+    x = jnp.ones((2, 8, 16))
+    assert tinygpt._pin_mlp_hidden(gelu, x) is x
+
+
+@pytest.mark.parametrize("strategy", ["ddp", "zero2", "fsdp"])
+def test_replicated_param_steps_do_not_see_mlp_hidden_spec(
+    eight_devices, monkeypatch, strategy
+):
+    """The one-chip cells' guarantee, on a dp=4 mesh: under ddp and zero2
+    the compiled step's text is the same with ``mlp_hidden_spec`` as the
+    step arms it and with it forced off; under fsdp it is not (so the
+    comparison can see the field)."""
+    from distributed_llm_training_benchmark_framework_tpu.train import (
+        step as step_mod,
+    )
+
+    spec = hlo_audit.ArmSpec(
+        f"{strategy}-dp4-unrolled", strategy, (4,), ("data",),
+        global_batch=4, model_family="tinygpt",
+        config_overrides=(("scan_layers", False),),
+    )
+    texts = []
+    for forced_off in (False, True):  # one call site: the text names its lines
+        if forced_off:
+            monkeypatch.setattr(step_mod, "mlp_hidden_spec", lambda *a, **k: None)
+        texts.append(hlo_audit.lower_arm(spec).as_text())
+    assert (texts[0] == texts[1]) == (strategy != "fsdp")
 
 
 def test_scan_carry_spec_arming_matrix(eight_devices):

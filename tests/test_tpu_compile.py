@@ -205,6 +205,123 @@ def test_flash_partitions_over_a_four_device_data_mesh(v5e_devices):
     assert "all-gather" not in text and "all-to-all" not in text
 
 
+# --- the sharded-parameter step's MLP at Mistral widths (about 10 s a compile) ---
+
+def _fsdp_collectives_tool():
+    import importlib.util
+
+    path = os.path.join(
+        os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+        "scripts", "fsdp_collectives.py",
+    )
+    spec = importlib.util.spec_from_file_location("fsdp_collectives", path)
+    module = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(module)
+    return module
+
+
+_MISTRAL_DP4_STEPS = {}  # (strategy, mlp_act, pinned) -> Compiled: two tests share them
+
+
+def _mistral_dp4_step(v5e_devices, monkeypatch, strategy, mlp_act, pinned=True):
+    """The fsdp4 cell's step (one 4096-token sequence a chip, flash, unrolled,
+    remat dots) at Mistral-7B's widths and depth 1, compiled for the
+    described 2x2; ``pinned=False`` leaves the MLP's layout to propagation."""
+    import dataclasses
+
+    from distributed_llm_training_benchmark_framework_tpu.models.tinygpt import TinyGPTConfig
+    from distributed_llm_training_benchmark_framework_tpu.parallel import get_strategy, make_mesh
+    from distributed_llm_training_benchmark_framework_tpu.train import step as step_mod
+
+    key = (strategy, mlp_act, pinned)
+    if key not in _MISTRAL_DP4_STEPS:
+        config = TinyGPTConfig(
+            vocab_size=32768, n_embd=4096, n_head=32, n_kv_head=8, n_layer=1,
+            block_size=4096, dropout=0.0, causal=True, attention_impl="flash",
+            scan_layers=False, norm="rmsnorm", norm_eps=1e-5, pos_embed="rope",
+            rope_theta=1e6, mlp_act=mlp_act, mlp_hidden=14336, bias=False,
+            tie_embeddings=False,
+        )
+        mesh = make_mesh((4,), ("data",), devices=v5e_devices[:4])
+        with monkeypatch.context() as patch:
+            # The program asks jax.default_backend() to choose kernel vs
+            # interpret mode; the compile target is the described chip.
+            patch.setattr(jax, "default_backend", lambda: "tpu")
+            if not pinned:
+                patch.setattr(step_mod, "mlp_hidden_spec", lambda *a, **k: None)
+            _MISTRAL_DP4_STEPS[key] = step_mod.abstract_compile_step(
+                config, dataclasses.replace(get_strategy(strategy), remat="dots"), mesh,
+                grad_accum=1, global_micro=4, seq_len=4096, dataset_size=1000,
+            )
+    return _MISTRAL_DP4_STEPS[key]
+
+
+# What the pins may add to a chip's peak, a layer: the whole fsdp4 cell (depth
+# 8) goes from 12.65 to 13.29 GB, 0.08 GB a layer.
+MLP_PIN_PEAK_ALLOWANCE = 0.15e9
+
+
+@pytest.mark.parametrize("mlp_act", ["swiglu", "gelu"])
+@pytest.mark.parametrize("strategy", ["fsdp", "zero3"])
+def test_sharded_param_mlp_moves_no_weight_and_no_all_to_all(
+    v5e_devices, monkeypatch, strategy, mlp_act
+):
+    """With ``mlp_hidden_spec`` armed, the MLP's F-wide activations sit where
+    the weight shards are: no all-to-all anywhere in the step, and every
+    collective under ``mlp`` carries (B, S, D) activations or a norm scale,
+    never a slice of ``wgu`` / ``wfc`` / ``wproj`` (F / 4 = 3584 wide). Left
+    to propagation (the field unarmed) the SwiGLU step reshards the wide
+    activations with all-to-alls; the pins may cost the stated allowance."""
+    tool = _fsdp_collectives_tool()
+    pinned = _mistral_dp4_step(v5e_devices, monkeypatch, strategy, mlp_act)
+    rows = tool.collectives(pinned.as_text())
+    assert rows and not [r for r in rows if r.kind == "all-to-all"]
+    mlp = [r for r in rows if r.module == "mlp"]
+    assert mlp and not [r for r in mlp if "3584" in r.shapes], mlp
+
+    unpinned = _mistral_dp4_step(v5e_devices, monkeypatch, strategy, mlp_act, pinned=False)
+    crossings = [r for r in tool.collectives(unpinned.as_text()) if r.kind == "all-to-all"]
+    # (the GELU branch's one elementwise op between the projections follows
+    # the matmuls' layout unpinned too: the pin states what it had)
+    assert all(r.module == "mlp" for r in crossings)
+    assert crossings or mlp_act == "gelu"
+    peaks = [c.memory_analysis().peak_memory_in_bytes for c in (pinned, unpinned)]
+    assert peaks[0] <= peaks[1] + MLP_PIN_PEAK_ALLOWANCE, peaks
+    print(f"{strategy}/{mlp_act}: peak {peaks[0] / 1e9:.2f} GB pinned, "
+          f"{peaks[1] / 1e9:.2f} unpinned; {len(crossings)} all-to-alls unpinned")
+
+
+@pytest.mark.parametrize("strategy", ["fsdp", "zero3"])
+def test_forward_weight_rings_interleave_with_dots(v5e_devices, monkeypatch, strategy):
+    """What ``tests/test_overlap.py`` pinned on the CPU lowering until jax
+    0.9.0's CPU scheduler stopped producing it (weight gathers interleaved
+    with the forward's dots, never bundled above the first one), on the
+    compile that counts. On the TPU the attention weights travel on
+    collective-permute rings inside their own windowed einsum, so the guard
+    reads: in the scheduled step, most forward hops of ``attention``'s rings
+    have a matmul between their start and their done (a ring's first hop
+    starts above the first matmul by design: that is the prefetch)."""
+    import re
+
+    lines = _mistral_dp4_step(v5e_devices, monkeypatch, strategy, "swiglu").as_text().splitlines()
+    entry = next(i for i, line in enumerate(lines) if line.startswith("ENTRY"))
+    started, dots, covered, hops = {}, [], 0, 0
+    for i, line in enumerate(lines[entry:]):
+        if " fusion(" in line and "kind=kOutput" in line:  # the TPU's matmul fusions
+            dots.append(i)
+        elif found := re.match(r"\s+%(collective-permute-start[\w.\-]*) = ", line):
+            op_name = re.search(r'op_name="([^"]*)"', line)
+            if op_name and "jvp(attention)" in op_name.group(1) \
+                    and "transpose(" not in op_name.group(1):
+                started[found.group(1)] = i
+        elif found := re.search(r" collective-permute-done\(%?([\w.\-]+)\)", line):
+            if (start := started.get(found.group(1))) is not None:
+                hops += 1
+                covered += any(start < dot < i for dot in dots)
+    assert hops >= 9 and dots  # wq, wkv, wo: three hops each on four chips
+    assert covered >= 0.75 * hops, (covered, hops)
+
+
 # --- whole train steps at tier-A size (48-76 s each: slow set) -------------
 
 WHOLE_STEPS = {
