@@ -20,6 +20,24 @@ all K choices plus the router z-loss. Its parts carry the scopes ``router`` /
 runs on one chip's tokens: the 'expert' axis all-to-all around it is not
 written yet.
 
+**A chip's share of the experts** (``experts_held = (first, count)``;
+``_moe_mlp_held``) is the dropless layer told which experts it holds, as
+expert parallelism tells it: the router scores all ``n_experts`` and keeps its
+``expert_top_k`` choices a token, the leaves ``moe_wgu`` / ``moe_wd`` hold
+``count`` experts, and the layer computes the part of the routed sum its own
+experts give. Assignments on experts held elsewhere add nothing here; no code
+stands in for the chips that hold them or for the exchange between them. How
+many assignments land on the held experts is data, so the rows go into a
+static buffer: N x K rows (nothing can overflow) or, with
+``held_rows_factor``, that multiple of the expected N x K x count / n_experts,
+in which case the layer also counts its rows and the held assignments that
+did not fit, and the train step returns both after its loss. A part of the
+experts does not train its routing (``TinyGPTConfig.trains_routing``: the
+gradient through the gates is the held experts' part only, and its sum over
+the chips is the exchange's to make). ``n_shared_experts``
+adds one SwiGLU of that many experts' width that every token passes (scope
+``shared``): every chip of the deployment computes it alike.
+
 **Capacity** (a number for ``capacity_factor``; the GShard-style paths below)
 is the E = 8 top-2 GELU toy with biases and the only path across an 'expert'
 mesh axis today. It drops what overflows an expert's buffer, and its one-hot
@@ -284,9 +302,10 @@ def _permute_rows_bwd(inverse, g):
 _permute_rows.defvjp(_permute_rows_fwd, _permute_rows_bwd)
 
 
-def _route_dropless(c, xt: jax.Array, router: jax.Array):
+def _route_dropless(c, xt: jax.Array, router: jax.Array, sequences: int = 1):
     """Token-choice routing with nothing dropped -> (gates (N, K) fp32,
-    expert_idx (N, K), counts (E,) int32, aux).
+    expert_idx (N, K), counts (E,) int32, aux). ``sequences`` is how many
+    equal sequences the N tokens are, read only under ``seq_aux``.
 
     fp32 throughout, the logits at the highest matmul precision (a TPU's
     default would round the router to bfloat16, and near-ties in the top-k
@@ -308,7 +327,16 @@ def _route_dropless(c, xt: jax.Array, router: jax.Array):
     if c.norm_topk_prob:
         gates = gates / jnp.maximum(jnp.sum(gates, axis=-1, keepdims=True), 1e-9)
     counts = jnp.sum(jax.nn.one_hot(expert_idx, E, dtype=jnp.int32), axis=(0, 1))
-    aux = E * jnp.sum(counts.astype(jnp.float32) / (N * K) * jnp.mean(probs, axis=0))
+    if c.seq_aux:
+        # DeepSeek: the same statistic a sequence (f its share of the S x K
+        # assignments, P its mean probability), averaged over sequences.
+        S = N // sequences
+        onehot = jax.nn.one_hot(expert_idx.reshape(sequences, S * K), E, dtype=jnp.float32)
+        share = jnp.sum(onehot, axis=1) / (S * K)  # (sequences, E)
+        mean_prob = jnp.mean(probs.reshape(sequences, S, E), axis=1)
+        aux = E * jnp.mean(jnp.sum(share * mean_prob, axis=-1))
+    else:
+        aux = E * jnp.sum(counts.astype(jnp.float32) / (N * K) * jnp.mean(probs, axis=0))
     if c.router_z_coef:
         z = jnp.mean(jnp.square(jax.nn.logsumexp(logits, axis=-1)))
         aux = aux + (c.router_z_coef / c.router_aux_coef) * z
@@ -341,6 +369,156 @@ def _experts_dropless(c, layer, rows: jax.Array, counts: jax.Array) -> jax.Array
     return _grouped_matmul(c, h, layer["moe_wd"], counts)
 
 
+@jax.named_scope(scopes.SHARED)
+def _shared_experts(c, layer, x: jax.Array) -> jax.Array:
+    """The shared experts as one SwiGLU, (B, S, D) -> (B, S, D): gate columns
+    then up columns in one matrix, as the routed experts store theirs."""
+    cd = c.compute_dtype
+    Fs = layer["shared_wd"].shape[0]
+    gu = jnp.einsum(
+        "bsd,df->bsf", x, layer["shared_wgu"].astype(cd), preferred_element_type=jnp.float32
+    ).astype(cd)
+    h = jax.nn.silu(gu[..., :Fs]) * gu[..., Fs:]
+    return jnp.einsum(
+        "bsf,fd->bsd", h, layer["shared_wd"].astype(cd), preferred_element_type=jnp.float32
+    ).astype(cd)
+
+
+def held_buffer_rows(c, n_tokens: int) -> int:
+    """Rows of the held experts' static buffer for ``n_tokens`` tokens: all N x
+    K assignments, or ``held_rows_factor`` times the N x K x count / E expected
+    on the held experts, up to a whole row tile of the grouped matmuls."""
+    assignments = n_tokens * c.expert_top_k
+    if c.held_rows_factor is None:
+        return assignments
+    expected = assignments * c.experts_held[1] / c.n_experts
+    tile = math.gcd(assignments, _GMM_TILING[0])
+    return min(assignments, -(-math.ceil(c.held_rows_factor * expected) // tile) * tile)
+
+
+def _held_plan(c, expert_idx: jax.Array, counts: jax.Array):
+    """Where the held experts' assignments go -> (take (M,) the assignment in
+    each buffer row, slot (N*K,) the buffer row of each assignment or M, sizes
+    (count,) rows an expert, rows the number filled, overflow the held
+    assignments that did not fit).
+
+    Assignments sort by held expert (stable: token order inside an expert),
+    everything held elsewhere behind them; the first M of that order are the
+    buffer. Past ``rows`` the buffer is padding: never read, by anyone.
+    """
+    first, count = c.experts_held
+    flat = expert_idx.reshape(-1)
+    M = held_buffer_rows(c, flat.shape[0] // c.expert_top_k)
+    local = flat - first
+    order = jnp.argsort(jnp.where((local >= 0) & (local < count), local, count), stable=True)
+    ends = jnp.minimum(jnp.cumsum(counts[first:first + count]), M)
+    rows = ends[-1]
+    sizes = jnp.diff(ends, prepend=0).astype(jnp.int32)
+    position = jnp.argsort(order)  # where each assignment went in the order
+    slot = jnp.where(position < rows, position, M)
+    overflow = jnp.sum(counts[first:first + count]) - rows
+    return order[:M], slot, sizes, rows, overflow
+
+
+def routing_rows(config, layer: dict, x: jax.Array):
+    """One layer's routing of ``x`` (B, S, D), the MLP's normed input ->
+    ((E,) int32 assignments an expert, (2,) int32 or None: the rows the
+    dispatch puts into the held experts' buffer and the held assignments that
+    do not fit it)."""
+    _, expert_idx, counts, _ = _route_dropless(
+        config, x.reshape(-1, x.shape[-1]), layer["router"])
+    if config.experts_held is None:
+        return counts, None
+    _, _, _, rows, overflow = _held_plan(config, expert_idx, counts)
+    return counts, jnp.stack([rows, overflow]).astype(jnp.int32)
+
+
+@jax.custom_vjp
+def _gather_held(xt, take_token, slot, valid):
+    """Buffer rows from tokens: ``xt[take_token]`` where ``valid``, else 0. Its
+    transpose sums, for each token, the rows its K assignments went to (a
+    gather by ``slot``; the row M is zero), where autodiff of the indexing
+    would scatter-add."""
+    return jnp.where(valid[:, None], xt[take_token], jnp.zeros((), xt.dtype))
+
+
+def _gather_held_fwd(xt, take_token, slot, valid):
+    return _gather_held(xt, take_token, slot, valid), (slot, valid, xt.shape[0])
+
+
+def _gather_held_bwd(res, g):
+    slot, valid, N = res
+    g = jnp.where(valid[:, None], g, jnp.zeros((), g.dtype))  # padding may hold anything
+    padded = jnp.concatenate([g, jnp.zeros((1, g.shape[1]), g.dtype)])
+    back = padded[slot].reshape(N, -1, g.shape[1])
+    return jnp.sum(back.astype(jnp.float32), axis=1).astype(g.dtype), None, None, None
+
+
+_gather_held.defvjp(_gather_held_fwd, _gather_held_bwd)
+
+
+@jax.custom_vjp
+def _combine_held(out, gates, take, slot, valid):
+    """(N, D): each token's sum over its K choices of gate x the buffer row
+    that choice went to (row M: zero, for a choice held elsewhere or not
+    fitted). Backward: a row's cotangent is its token's times its gate, a
+    gate's is its row dotted with its token's cotangent, both by gathers."""
+    N, K = gates.shape
+    out = jnp.where(valid[:, None], out, jnp.zeros((), out.dtype))
+    padded = jnp.concatenate([out, jnp.zeros((1, out.shape[1]), out.dtype)])
+    back = padded[slot].reshape(N, K, out.shape[1])
+    return jnp.sum(back.astype(jnp.float32) * gates[:, :, None], axis=1).astype(out.dtype)
+
+
+def _combine_held_fwd(out, gates, take, slot, valid):
+    return _combine_held(out, gates, take, slot, valid), (out, gates, take, slot, valid)
+
+
+def _combine_held_bwd(res, dy):
+    out, gates, take, slot, valid = res
+    N, K = gates.shape
+    dy_rows = dy[take // K].astype(jnp.float32)  # (M, D): each row's token
+    live = valid[:, None]
+    d_out = jnp.where(live, dy_rows * gates.reshape(-1)[take][:, None], 0.0).astype(out.dtype)
+    d_gate_rows = jnp.sum(jnp.where(live, out.astype(jnp.float32) * dy_rows, 0.0), axis=1)
+    d_gates = jnp.concatenate([d_gate_rows, jnp.zeros((1,), jnp.float32)])[slot]
+    return d_out, d_gates.reshape(N, K), None, None, None
+
+
+_combine_held.defvjp(_combine_held_fwd, _combine_held_bwd)
+
+
+def _moe_mlp_held(c, layer, x, dropout_key, deterministic):
+    """The dropless layer over the experts this chip holds (module docstring)
+    -> (y, aux): aux is the load-balance scalar, or with ``held_rows_factor``
+    (3,): that, the rows the buffer took and the held assignments that did
+    not fit."""
+    from .tinygpt import _dropout
+
+    B, S, D = x.shape
+    N, K = B * S, c.expert_top_k
+    xt = x.reshape(N, D)
+    with jax.named_scope(scopes.ROUTER):
+        gates, expert_idx, counts, aux = _route_dropless(c, xt, layer["router"], B)
+        if not c.trains_routing:
+            gates, aux = lax.stop_gradient((gates, aux))
+    with jax.named_scope(scopes.DISPATCH):
+        take, slot, sizes, n_rows, overflow = _held_plan(c, expert_idx, counts)
+        valid = jnp.arange(take.shape[0]) < n_rows
+        rows = _gather_held(xt, take // K, slot, valid)
+    with jax.named_scope(scopes.EXPERTS):
+        out = _experts_dropless(c, layer, rows, sizes)
+    with jax.named_scope(scopes.COMBINE):
+        y = _combine_held(out, gates, take, slot, valid)
+    y = _dropout(y, c.dropout, dropout_key, deterministic).reshape(B, S, D)
+    if c.moe_aux_mode == "overflow":
+        aux = jnp.zeros((), jnp.float32)
+    if c.reports_held_overflow:
+        aux = jnp.concatenate([aux[None], lax.stop_gradient(
+            jnp.stack([n_rows, overflow])).astype(jnp.float32)])
+    return y, aux
+
+
 def _moe_mlp_dropless(c, layer, x, dropout_key, deterministic):
     """Sort by expert, grouped matmuls, weighted sum back (module docstring)."""
     from .tinygpt import _dropout
@@ -349,7 +527,7 @@ def _moe_mlp_dropless(c, layer, x, dropout_key, deterministic):
     N, K = B * S, c.expert_top_k
     xt = x.reshape(N, D)
     with jax.named_scope(scopes.ROUTER):
-        gates, expert_idx, counts, aux = _route_dropless(c, xt, layer["router"])
+        gates, expert_idx, counts, aux = _route_dropless(c, xt, layer["router"], B)
     with jax.named_scope(scopes.DISPATCH):
         # Assignment n*K + k is token n's k-th choice; ``order`` lists the
         # assignments expert by expert, ``inverse`` is where each one went.
@@ -370,7 +548,7 @@ def _moe_mlp_dropless(c, layer, x, dropout_key, deterministic):
 def expert_counts(config, layer: dict, x: jax.Array) -> jax.Array:
     """(E,) int32: how many of the N x K assignments of ``x`` (B, S, D), the
     MLP's normed input, chose each expert at this layer's router."""
-    return _route_dropless(config, x.reshape(-1, x.shape[-1]), layer["router"])[2]
+    return routing_rows(config, layer, x)[0]
 
 
 def moe_mlp(
@@ -391,7 +569,11 @@ def moe_mlp(
     c = config
     B, S, D = x.shape
     if c.capacity_factor is None:
-        return _moe_mlp_dropless(c, layer, x, dropout_key, deterministic)
+        routed = _moe_mlp_dropless if c.experts_held is None else _moe_mlp_held
+        y, aux = routed(c, layer, x, dropout_key, deterministic)
+        if c.n_shared_experts:
+            y = y + _shared_experts(c, layer, x)
+        return y, aux
     mesh = None
     if c.moe_dispatch != "einsum" and c.seq_manual_axis is None:
         m = jax.sharding.get_abstract_mesh()

@@ -35,10 +35,12 @@ from __future__ import annotations
 
 import dataclasses
 import functools
+import math
 from typing import Any, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import lax
 from jax.sharding import PartitionSpec as P
 
@@ -61,6 +63,55 @@ def normalize_remat(value: Any) -> str:
         f"invalid remat policy {value!r} (expected one of {REMAT_POLICIES}, "
         "a bool, or 'auto' resolved upstream)"
     )
+
+
+@dataclasses.dataclass(frozen=True)
+class YarnScaling:
+    """YaRN's rotary frequencies (arXiv:2309.00071) as DeepSeek-V2 uses them:
+    the config's ``rope_scaling`` group. Dimensions that turn more than
+    ``beta_fast`` times over the original context keep their frequency, those
+    that turn fewer than ``beta_slow`` times have it divided by ``factor``,
+    and a linear ramp blends the ones between."""
+
+    factor: float
+    original_max_position_embeddings: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 0.0
+
+    def correction_range(self, dim: int, theta: float) -> Tuple[int, int]:
+        """(low, high): the ramp's first and last frequency index."""
+        def turns_at(n):  # the (fractional) index whose frequency turns n times
+            return dim * math.log(
+                self.original_max_position_embeddings / (n * 2 * math.pi)
+            ) / (2 * math.log(theta))
+
+        return (max(math.floor(turns_at(self.beta_fast)), 0),
+                min(math.ceil(turns_at(self.beta_slow)), dim - 1))
+
+    def inv_freq(self, dim: int, theta: float) -> np.ndarray:
+        """(dim / 2,) float32 rotary frequencies."""
+        i = np.arange(dim // 2, dtype=np.float64)
+        plain = theta ** (-2.0 * i / dim)
+        low, high = self.correction_range(dim, theta)
+        ramp = np.clip((i - low) / max(high - low, 1e-3), 0.0, 1.0)
+        return (plain / self.factor * ramp + plain * (1.0 - ramp)).astype(np.float32)
+
+    @staticmethod
+    def _mscale(factor: float, mscale: float) -> float:
+        return 0.1 * mscale * math.log(factor) + 1.0 if factor > 1 else 1.0
+
+    @property
+    def cos_sin_factor(self) -> float:
+        """What multiplies cos and sin: 1 where the two mscales agree."""
+        return (self._mscale(self.factor, self.mscale)
+                / self._mscale(self.factor, self.mscale_all_dim))
+
+    @property
+    def softmax_factor(self) -> float:
+        """What multiplies 1 / sqrt(width of q) in the softmax: m^2."""
+        return self._mscale(self.factor, self.mscale_all_dim) ** 2
 
 
 @dataclasses.dataclass(frozen=True)
@@ -166,6 +217,46 @@ class TinyGPTConfig:
     # projected q vector and over the whole projected k vector, before the
     # split into heads and before rope. Leaves q_norm / k_norm.
     qk_norm: bool = False
+    # Latent attention (MLA, DeepSeek-V2): set ``kv_lora_rank`` and the three
+    # head widths. q is projected whole to heads of qk_nope + qk_rope; the
+    # input is projected down to kv_lora_rank + qk_rope, the first part
+    # RMS-normed (its own scale, leaf kv_norm) and expanded per head to
+    # [k_nope | v], the last part one rotary key that every head shares.
+    # Keys are qk_nope + qk_rope wide, values v_head_dim: the flash kernels
+    # take both widths. Leaves wq / wkv_a / kv_norm / wkv_b; wo is
+    # (n_head * v_head_dim, n_embd).
+    kv_lora_rank: Optional[int] = None
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    # YaRN frequencies for the rotary part (None: plain rope_theta), and its
+    # factor on the softmax scale.
+    rope_scaling: Optional[YarnScaling] = None
+    # Leading dense layers (DeepSeek first_k_dense_replace): the first
+    # ``first_k_dense`` of the n_layer layers have a dense SwiGLU MLP of
+    # width ``dense_mlp_hidden`` where the rest route; they are a stack of
+    # their own, params['dense_blocks'], beside 'blocks' (n_layer -
+    # first_k_dense layers).
+    first_k_dense: int = 0
+    dense_mlp_hidden: Optional[int] = None
+    # Shared experts beside the routed sum: one SwiGLU of width
+    # n_shared_experts * mlp_dim that every token passes (leaves shared_wgu /
+    # shared_wd; scope 'shared').
+    n_shared_experts: int = 0
+    # The experts this chip holds, (first, count), of the n_experts the router
+    # scores (dropless path): the leaves moe_wgu / moe_wd hold ``count``
+    # experts, assignments on the others add nothing here (their chips add
+    # them). None: all of them.
+    experts_held: Optional[Tuple[int, int]] = None
+    # Rows of the held experts' buffer, as a multiple of the expected N * K *
+    # count / n_experts (rounded up to the grouped matmul's row tile). None:
+    # N * K rows, which nothing can overflow. With a number the train step
+    # returns, after its loss, (rows the buffers took, held assignments that
+    # did not fit), summed over layers and micro-batches.
+    held_rows_factor: Optional[float] = None
+    # Load-balance term per sequence and averaged over sequences (DeepSeek
+    # seq_aux), not over the whole batch.
+    seq_aux: bool = False
     # Linear/LayerNorm biases (Llama ships none anywhere).
     bias: bool = True
     # Weight-tied LM head (reference train_harness.py:61-62). False adds a
@@ -237,6 +328,74 @@ class TinyGPTConfig:
     def mlp_dim(self) -> int:
         return self.mlp_hidden if self.mlp_hidden is not None else 4 * self.n_embd
 
+    @property
+    def latent_attention(self) -> bool:
+        return self.kv_lora_rank is not None
+
+    @property
+    def qk_dim(self) -> int:
+        """Width of one head's q and k."""
+        if self.latent_attention:
+            return self.qk_nope_head_dim + self.qk_rope_head_dim
+        return self.head_dim
+
+    @property
+    def v_dim(self) -> int:
+        """Width of one head's v and output."""
+        return self.v_head_dim if self.latent_attention else self.head_dim
+
+    @property
+    def attn_scale(self) -> Optional[float]:
+        """The softmax scale where it is not 1 / sqrt(qk_dim), else None."""
+        if self.latent_attention and self.rope_scaling is not None:
+            return self.qk_dim ** -0.5 * self.rope_scaling.softmax_factor
+        return None
+
+    @property
+    def aux_shape(self) -> Tuple[int, ...]:
+        """The layer loop's aux carry: the load-balance scalar, or with it the
+        held experts' rows and the held assignments that did not fit."""
+        return (3,) if self.reports_held_overflow else ()
+
+    @property
+    def n_moe_layers(self) -> int:
+        return self.n_layer - self.first_k_dense if self.n_experts > 0 else 0
+
+    @property
+    def n_experts_held(self) -> int:
+        return self.n_experts if self.experts_held is None else self.experts_held[1]
+
+    @property
+    def trains_routing(self) -> bool:
+        """Whether the gates and the load-balance term are differentiated. A
+        chip that holds a part of the experts sees the gradient through the
+        gates of its own experts only; the deployment sums it over the chips
+        that share the layer, and that sum belongs to the exchange on the
+        'expert' axis, which is not written. Applied alone, the partial
+        gradient pulls every token onto the held experts within tens of steps
+        (PERF.md, PR 30: held rows 0.97 -> 2.81 of the expected in 35 steps,
+        -> 1.43 with only the router's weights left out). So a part of the
+        experts does not train its routing: gates and the load-balance term
+        are constants of its backward pass (neither the router's weights nor
+        its input get a gradient through them); the experts, and everything
+        else, train."""
+        return self.experts_held is None or self.experts_held[1] == self.n_experts
+
+    @property
+    def reports_held_overflow(self) -> bool:
+        return self.experts_held is not None and self.held_rows_factor is not None
+
+    def refuse_pipeline(self) -> None:
+        """The pipeline schedules slice one homogeneous stack and run the
+        sharded attention bodies; they do not slice this."""
+        if self.first_k_dense or self.latent_attention:
+            raise ValueError(
+                "the pipeline schedules take one homogeneous stack of blocks with "
+                f"ordinary attention; got first_k_dense={self.first_k_dense}, "
+                f"kv_lora_rank={self.kv_lora_rank} (latent attention). Run this "
+                "config with pipe=1"
+            )
+
     def __post_init__(self):
         if self.norm not in ("layernorm", "rmsnorm"):
             raise ValueError(f"norm must be 'layernorm'|'rmsnorm', got {self.norm!r}")
@@ -269,6 +428,43 @@ class TinyGPTConfig:
                 "router_z_coef rides the aux channel in units of router_aux_coef, "
                 "which must be > 0"
             )
+        if self.latent_attention and not (
+            self.pos_embed == "rope" and not self.bias and not self.qk_norm
+            and self.n_kv_head is None and self.qk_nope_head_dim > 0
+            and self.qk_rope_head_dim > 0 and self.v_head_dim > 0
+            and self.attention_impl in ("flash", "reference")
+            and not self.tp_collective_matmul
+        ):
+            raise ValueError(
+                "latent attention (kv_lora_rank) needs pos_embed='rope', bias=False, "
+                "no qk_norm, no n_kv_head, the three head widths, attention_impl "
+                "'flash' or 'reference' and no tp_collective_matmul"
+            )
+        if self.rope_scaling is not None and not self.latent_attention:
+            raise ValueError("rope_scaling (YaRN) is wired for latent attention only")
+        if self.first_k_dense and not (
+            0 < self.first_k_dense < self.n_layer and self.n_experts > 0
+            and self.mlp_act == "swiglu" and not self.bias
+            and self.dense_mlp_hidden
+        ):
+            raise ValueError(
+                "first_k_dense leading layers are dense SwiGLU layers (no bias) of "
+                "width dense_mlp_hidden before routed ones: 0 < first_k_dense < n_layer"
+            )
+        if (self.n_shared_experts or self.experts_held is not None
+                or self.seq_aux) and not (self.n_experts > 0 and dropless):
+            raise ValueError(
+                "n_shared_experts, experts_held and seq_aux belong to dropless routing"
+            )
+        if self.experts_held is not None:
+            first, count = self.experts_held
+            if not (0 <= first and 0 < count and first + count <= self.n_experts):
+                raise ValueError(
+                    f"experts_held={self.experts_held} must lie inside the "
+                    f"{self.n_experts} experts the router scores"
+                )
+        if self.held_rows_factor is not None and self.experts_held is None:
+            raise ValueError("held_rows_factor sizes the buffer of experts_held")
 
 
 def get_model_config(tier: str, seq_len: int, **overrides) -> TinyGPTConfig:
@@ -341,6 +537,17 @@ PARAM_AXIS_RULES: Dict[str, Tuple[Optional[str], ...]] = {
     # QK-norm scales (present when qk_norm): one per projected q / k feature.
     "blocks/q_norm": ("layers", "heads"),
     "blocks/k_norm": ("layers", "kv_heads"),
+    # Latent attention (present instead of wqkv / wkv when kv_lora_rank): wq
+    # as above with heads of qk_dim; the shared down projection to
+    # [latent | rotary key], the latent's norm scale, and the per-head
+    # expansion to [k_nope | v].
+    "blocks/wkv_a": ("layers", "embed", "latent_rope"),
+    "blocks/kv_norm": ("layers", "latent"),
+    "blocks/wkv_b": ("layers", "latent", "heads"),
+    # Shared experts (present beside router / moe_wgu / moe_wd when
+    # n_shared_experts): one SwiGLU, gate columns then up columns.
+    "blocks/shared_wgu": ("layers", "embed", "gate_up"),
+    "blocks/shared_wd": ("layers", "mlp", "embed"),
     "lnf_scale": ("embed",),
     "lnf_bias": ("embed",),
     # Untied LM head (present when tie_embeddings=False): same logical axes
@@ -366,7 +573,8 @@ def init_params(config: TinyGPTConfig, key: jax.Array) -> Params:
     # trace) stays bit-reproducible. Family configs with extra leaves use a
     # wider split; they are new surface with no reproduction constraint.
     legacy = Hkv == H and c.tie_embeddings and c.pos_embed == "learned"
-    k = iter(jax.random.split(key, 8 if legacy else 12))
+    wide = c.latent_attention or c.first_k_dense or c.n_shared_experts
+    k = iter(jax.random.split(key, 8 if legacy else 24 if wide else 12))
 
     def normal(key, shape):
         return (0.02 * jax.random.normal(key, shape)).astype(c.param_dtype)
@@ -374,32 +582,53 @@ def init_params(config: TinyGPTConfig, key: jax.Array) -> Params:
     zeros = lambda shape: jnp.zeros(shape, c.param_dtype)
     ones = lambda shape: jnp.ones(shape, c.param_dtype)
 
-    blocks = {"ln1_scale": ones((L, D)), "ln2_scale": ones((L, D))}
-    if c.norm == "layernorm":
-        blocks.update(ln1_bias=zeros((L, D)), ln2_bias=zeros((L, D)))
-    if Hkv == H:
-        blocks["wqkv"] = normal(next(k), (L, D, 3, D))
+    def norms_and_attention(L):
+        """One stack's norm scales and attention leaves, L layers."""
+        blocks = {"ln1_scale": ones((L, D)), "ln2_scale": ones((L, D))}
+        if c.norm == "layernorm":
+            blocks.update(ln1_bias=zeros((L, D)), ln2_bias=zeros((L, D)))
+        if c.latent_attention:
+            R, Dr = c.kv_lora_rank, c.qk_rope_head_dim
+            blocks.update(
+                wq=normal(next(k), (L, D, H * c.qk_dim)),
+                wkv_a=normal(next(k), (L, D, R + Dr)),
+                kv_norm=ones((L, R)),
+                wkv_b=normal(next(k), (L, R, H * (c.qk_nope_head_dim + c.v_dim))),
+            )
+        elif Hkv == H:
+            blocks["wqkv"] = normal(next(k), (L, D, 3, D))
+            if c.bias:
+                blocks["bqkv"] = zeros((L, 3, D))
+        else:
+            blocks["wq"] = normal(next(k), (L, D, H * Dh))
+            blocks["wkv"] = normal(next(k), (L, D, 2, Hkv * Dh))
+            if c.bias:
+                blocks["bq"] = zeros((L, H * Dh))
+                blocks["bkv"] = zeros((L, 2, Hkv * Dh))
+        if c.qk_norm:
+            blocks.update(q_norm=ones((L, H * Dh)), k_norm=ones((L, Hkv * Dh)))
+        blocks["wo"] = normal(next(k), (L, H * c.v_dim, D))
         if c.bias:
-            blocks["bqkv"] = zeros((L, 3, D))
-    else:
-        blocks["wq"] = normal(next(k), (L, D, H * Dh))
-        blocks["wkv"] = normal(next(k), (L, D, 2, Hkv * Dh))
-        if c.bias:
-            blocks["bq"] = zeros((L, H * Dh))
-            blocks["bkv"] = zeros((L, 2, Hkv * Dh))
-    if c.qk_norm:
-        blocks.update(q_norm=ones((L, H * Dh)), k_norm=ones((L, Hkv * Dh)))
-    blocks["wo"] = normal(next(k), (L, D, D))
-    if c.bias:
-        blocks["bo"] = zeros((L, D))
+            blocks["bo"] = zeros((L, D))
+        return blocks
+
+    L -= c.first_k_dense  # 'blocks' holds the layers after the leading dense ones
+    blocks = norms_and_attention(L)
     if c.n_experts > 0:
         E = c.n_experts
         blocks["router"] = normal(next(k), (L, D, E))
         if c.capacity_factor is None:  # dropless SwiGLU experts, no bias
+            held = c.n_experts_held  # the router scores E; this chip's leaves hold these
             blocks.update(
-                moe_wgu=normal(next(k), (L, E, D, 2 * F)),
-                moe_wd=normal(next(k), (L, E, F, D)),
+                moe_wgu=normal(next(k), (L, held, D, 2 * F)),
+                moe_wd=normal(next(k), (L, held, F, D)),
             )
+            if c.n_shared_experts:
+                Fs = c.n_shared_experts * F
+                blocks.update(
+                    shared_wgu=normal(next(k), (L, D, 2 * Fs)),
+                    shared_wd=normal(next(k), (L, Fs, D)),
+                )
         else:
             blocks.update(
                 moe_w1=normal(next(k), (L, E, D, F)),
@@ -430,6 +659,13 @@ def init_params(config: TinyGPTConfig, key: jax.Array) -> Params:
         params["lnf_bias"] = zeros((D,))
     if not c.tie_embeddings:
         params["lm_head"] = normal(next(k), (V, D))
+    if c.first_k_dense:
+        Ld, Fd = c.first_k_dense, c.dense_mlp_hidden
+        params["dense_blocks"] = dict(
+            norms_and_attention(Ld),
+            wgu=normal(next(k), (Ld, D, 2, Fd)),
+            wproj=normal(next(k), (Ld, Fd, D)),
+        )
     return params
 
 
@@ -468,6 +704,7 @@ def _rope(
     x: jax.Array,  # (B, S, H, Dh)
     positions: jax.Array,  # (S,) int32 global token positions
     theta: float,
+    scaling: Optional[YarnScaling] = None,
 ) -> jax.Array:
     """Rotary position embedding, HF-Llama rotate-half convention.
 
@@ -478,10 +715,15 @@ def _rope(
     """
     Dh = x.shape[-1]
     half = Dh // 2
-    inv_freq = 1.0 / (theta ** (jnp.arange(0, half, dtype=jnp.float32) * 2.0 / Dh))
+    if scaling is None:
+        inv_freq = 1.0 / (theta ** (jnp.arange(0, half, dtype=jnp.float32) * 2.0 / Dh))
+    else:
+        inv_freq = jnp.asarray(scaling.inv_freq(Dh, theta), dtype=jnp.float32)
     freqs = positions.astype(jnp.float32)[:, None] * inv_freq[None, :]  # (S, Dh/2)
     cos = jnp.cos(freqs)[None, :, None, :]  # (1, S, 1, Dh/2)
     sin = jnp.sin(freqs)[None, :, None, :]
+    if scaling is not None and scaling.cos_sin_factor != 1.0:
+        cos, sin = cos * scaling.cos_sin_factor, sin * scaling.cos_sin_factor
     xf = x.astype(jnp.float32)
     x1, x2 = xf[..., :half], xf[..., half:]
     out = jnp.concatenate((x1 * cos - x2 * sin, x2 * cos + x1 * sin), axis=-1)
@@ -526,6 +768,15 @@ def _attention(
         dropout_rate=config.dropout if seed is not None else 0.0,
         dropout_seed=seed,
     )
+    if config.latent_attention and (
+        config.seq_manual_axis is not None
+        or config.attention_impl not in ("flash", "reference")
+    ):
+        raise ValueError(
+            "latent attention runs attention_impl 'flash' or 'reference' outside "
+            "the pipeline schedules; the ring and Ulysses bodies take one head "
+            "width and their own scale"
+        )
     if config.seq_manual_axis is not None:
         # Inside a shard_map that is manual over the sequence axis (the
         # pipeline schedules): q/k/v hold LOCAL sequence chunks, so dispatch
@@ -553,6 +804,8 @@ def _attention(
         # Pallas TPU kernel; fp32 online-softmax accumulation internally.
         from ..ops.flash_attention import flash_attention
 
+        if config.attn_scale is not None:
+            kwargs["scale"] = config.attn_scale
         return flash_attention(q, k, v, **kwargs)
     if config.attention_impl == "ring":
         from ..ops.ring_attention import ring_attention
@@ -564,7 +817,7 @@ def _attention(
         return ulysses_attention(q, k, v, **kwargs)
 
     # Reference jnp implementation: softmax(QK^T/sqrt(d))V with fp32 softmax.
-    scale = 1.0 / (q.shape[-1] ** 0.5)
+    scale = config.attn_scale or 1.0 / (q.shape[-1] ** 0.5)
     scores = jnp.einsum(
         "bqhd,bkhd->bhqk", q, k, preferred_element_type=jnp.float32
     ) * scale
@@ -707,6 +960,8 @@ def _attention_sublayer(
         from ..ops import collective_matmul as _cm
 
     h = _norm(c, x, layer["ln1_scale"], layer.get("ln1_bias"))
+    if c.latent_attention:
+        return x + _latent_attention(c, h, layer, dropout_key, deterministic)
     if "wqkv" in layer:  # fused MHA projection (kv_heads == n_head)
         if use_cmm:
             qkv = _cm.ag_proj(h, layer["wqkv"].astype(cd)).astype(cd)
@@ -777,6 +1032,48 @@ def _attention_sublayer(
     return x + attn
 
 
+def _latent_attention(
+    c: TinyGPTConfig,
+    h: jax.Array,  # (B, S, D), the normed input
+    layer: Params,
+    dropout_key: Optional[jax.Array],
+    deterministic: bool,
+) -> jax.Array:
+    """MLA as DeepSeek-V2 computes it in training (no absorbed matrices: k and
+    v are expanded per head), in three scopes: ``mla_proj`` (the three
+    projections, the latent's norm, rotary, assembling k), ``mla_core`` (the
+    attention itself: the flash kernels at qk_dim over v_dim) and
+    ``mla_out``. One departure from the source's ``modeling_deepseek.py``: it
+    de-interleaves q_pe / k_pe before rotate-half; with seeded weights that is
+    one fixed permutation of both and leaves q k^T unchanged."""
+    B, S, _ = h.shape
+    cd = c.compute_dtype
+    H, Dn, Dr, Dv, R = (c.n_head, c.qk_nope_head_dim, c.qk_rope_head_dim,
+                        c.v_dim, c.kv_lora_rank)
+    proj = functools.partial(jnp.einsum, preferred_element_type=jnp.float32)
+    with jax.named_scope(scopes.MLA_PROJ):
+        q = proj("bsd,de->bse", h, layer["wq"].astype(cd)).astype(cd)
+        q = q.reshape(B, S, H, Dn + Dr)
+        kv_a = proj("bsd,de->bse", h, layer["wkv_a"].astype(cd)).astype(cd)
+        latent = _rms_norm(kv_a[..., :R], layer["kv_norm"], c.norm_eps)
+        kv_b = proj("bsr,re->bse", latent, layer["wkv_b"].astype(cd)).astype(cd)
+        kv_b = kv_b.reshape(B, S, H, Dn + Dv)
+        pos = jnp.arange(S, dtype=jnp.int32)
+        q_pe = _rope(q[..., Dn:], pos, c.rope_theta, c.rope_scaling)
+        k_pe = _rope(kv_a[:, :, None, R:], pos, c.rope_theta, c.rope_scaling)
+        q = jnp.concatenate((q[..., :Dn], q_pe), axis=-1)
+        k = jnp.concatenate(
+            (kv_b[..., :Dn], jnp.broadcast_to(k_pe, (B, S, H, Dr))), axis=-1
+        )
+        v = kv_b[..., Dn:]
+    with jax.named_scope(scopes.MLA_CORE):
+        attn = _attention(c, q, k, v, dropout_key, deterministic)
+    with jax.named_scope(scopes.MLA_OUT):
+        return proj(
+            "bse,ed->bsd", attn.reshape(B, S, H * Dv), layer["wo"].astype(cd)
+        ).astype(cd)
+
+
 def _pin_mlp_hidden(c: TinyGPTConfig, h: jax.Array) -> jax.Array:
     """Pin an F-wide MLP intermediate, (B, S, F) or (B, S, 2, F), to
     ``config.mlp_hidden_spec``; an unset spec is an exact no-op."""
@@ -804,7 +1101,7 @@ def _mlp_sublayer(
         from ..ops import collective_matmul as _cm
 
     h = _norm(c, x, layer["ln2_scale"], layer.get("ln2_bias"))
-    if c.n_experts > 0:
+    if "router" in layer:  # every layer of 'blocks' when n_experts > 0
         from .moe import moe_mlp
 
         h, aux = moe_mlp(c, layer, h, dropout_key, deterministic)
@@ -841,7 +1138,7 @@ def _mlp_sublayer(
     if "bproj" in layer:
         h = h + layer["bproj"].astype(cd)
     h = _dropout(h, c.dropout, dropout_key, deterministic)
-    return x + h, jnp.zeros((), jnp.float32)
+    return x + h, jnp.zeros(c.aux_shape, jnp.float32)
 
 
 @jax.named_scope(scopes.EMBED)
@@ -919,7 +1216,7 @@ def apply_blocks(
     def _aux0():
         from ..utils.vma import pcast_like
 
-        return pcast_like(jnp.zeros((), jnp.float32), x)
+        return pcast_like(jnp.zeros(c.aux_shape, jnp.float32), x)
 
     if not c.scan_layers:
         n_local = jax.tree_util.tree_leaves(blocks)[0].shape[0]
@@ -1021,28 +1318,54 @@ def forward(
     the embed/apply_blocks/head pieces are reused by the pipeline-parallel
     schedule (parallel.pipeline), which runs them stage-by-stage.
     """
+    logits, loss, _ = _forward(config, params, idx, targets, dropout_key, deterministic)
+    return logits, loss
+
+
+def apply_layers(
+    config: TinyGPTConfig,
+    params: Params,
+    x: jax.Array,
+    base_key: Optional[jax.Array] = None,
+    deterministic: bool = True,
+) -> Tuple[jax.Array, jax.Array]:
+    """The whole depth: the leading dense stack where the config has one, then
+    'blocks', each through ``apply_blocks`` (the same loop, remat policy and
+    per-layer placement hooks) -> (x, aux_sum)."""
     c = config
-    B, S = idx.shape
+    if not c.first_k_dense:
+        return apply_blocks(c, params["blocks"], x, base_key, deterministic)
+    x, aux_dense = apply_blocks(c, params["dense_blocks"], x, base_key, deterministic)
+    x, aux = apply_blocks(
+        c, params["blocks"], x, base_key, deterministic, layer_offset=c.first_k_dense
+    )
+    return x, aux_dense + aux
+
+
+def _forward(c, params, idx, targets, dropout_key, deterministic):
+    """-> (logits, loss or None, (2,) held rows and overflow or None)."""
+    S = idx.shape[1]
     if S > c.block_size:
         raise ValueError(f"Sequence {S} exceeds block size {c.block_size}")
-
     if dropout_key is not None and not deterministic:
         emb_key, scan_key = jax.random.split(dropout_key)
     else:
         emb_key = scan_key = None
-
     x = embed(c, params, idx, emb_key, deterministic)
-    x, aux = apply_blocks(c, params["blocks"], x, scan_key, deterministic)
+    x, aux = apply_layers(c, params, x, scan_key, deterministic)
     logits = head(c, params, x)
 
+    overflow = None
+    if c.reports_held_overflow:
+        aux, overflow = aux[0], aux[1:]
     loss = None
     if targets is not None:
         loss = _cross_entropy(logits, targets)
         if c.n_experts > 0:
-            # Mean aux per layer: the load-balance term (and, dropless, the
-            # z-loss riding it in units of router_aux_coef).
-            loss = loss + c.router_aux_coef * aux / c.n_layer
-    return logits, loss
+            # Mean aux per routed layer: the load-balance term (and, dropless,
+            # the z-loss riding it in units of router_aux_coef).
+            loss = loss + c.router_aux_coef * aux / c.n_moe_layers
+    return logits, loss, overflow
 
 
 def moe_overflow_fraction(
@@ -1059,31 +1382,53 @@ def moe_overflow_fraction(
     """
     c = dataclasses.replace(config, moe_aux_mode="overflow", dropout=0.0)
     x = embed(c, params, idx, None, True)
-    _, aux = apply_blocks(c, params["blocks"], x, None, True)
-    return aux / c.n_layer
+    _, aux = apply_layers(c, params, x, None, True)
+    return aux / c.n_moe_layers
 
 
 def moe_expert_counts(
     config: TinyGPTConfig, params: Params, idx: jax.Array
 ) -> jax.Array:
-    """Diagnostic: (n_layer, n_experts) int32, how many of one batch's
+    """Diagnostic: (routed layers, n_experts) int32, how many of one batch's
     N x expert_top_k assignments chose each expert at each layer's router, at
     the given weights, on a dropout-free forward. Max over mean of a row is
     the routing's imbalance; under dropless routing every assignment is
     computed, so a row sums to N x expert_top_k and what is missing from that
     sum was dropped."""
-    from .moe import expert_counts
+    return moe_routing_rows(config, params, idx)[0]
+
+
+def moe_held_rows(
+    config: TinyGPTConfig, params: Params, idx: jax.Array
+) -> jax.Array:
+    """Diagnostic beside ``moe_expert_counts`` for a config with
+    ``experts_held``: (routed layers, 2) int32, the rows the program's own
+    dispatch put into the held experts' buffer at each layer and the held
+    assignments that did not fit it (``moe._held_plan``'s own numbers). The
+    first column equals the router's count for the held experts when the
+    second is 0."""
+    return moe_routing_rows(config, params, idx)[1]
+
+
+def moe_routing_rows(config: TinyGPTConfig, params: Params, idx: jax.Array):
+    """Both diagnostics from one walk over the layers: (``moe_expert_counts``,
+    ``moe_held_rows`` or None without ``experts_held``)."""
+    from .moe import routing_rows
 
     c = dataclasses.replace(config, dropout=0.0)
     x = embed(c, params, idx, None, True)
-    rows = []
-    for i in range(c.n_layer):
+    if c.first_k_dense:
+        x, _ = apply_blocks(c, params["dense_blocks"], x, None, True)
+    counts, held = [], []
+    for i in range(c.n_layer - c.first_k_dense):
         layer = jax.tree_util.tree_map(lambda t: t[i], params["blocks"])
         x = _attention_sublayer(c, x, layer, None, True)
         h = _norm(c, x, layer["ln2_scale"], layer.get("ln2_bias"))
-        rows.append(expert_counts(c, layer, h))
+        count, rows = routing_rows(c, layer, h)
+        counts.append(count)
+        held.append(rows)
         x, _ = _mlp_sublayer(c, x, layer, None, True)
-    return jnp.stack(rows)
+    return jnp.stack(counts), (None if c.experts_held is None else jnp.stack(held))
 
 
 @jax.named_scope(scopes.LOSS)
@@ -1132,3 +1477,19 @@ def loss_fn(
         config, params, batch, targets, dropout_key=dropout_key, deterministic=deterministic
     )
     return loss
+
+
+def loss_and_held_fn(
+    config: TinyGPTConfig,
+    params: Params,
+    batch: jax.Array,
+    targets: jax.Array,
+    dropout_key: Optional[jax.Array] = None,
+    deterministic: bool = True,
+) -> Tuple[jax.Array, jax.Array]:
+    """``loss_fn`` for a config whose held experts' buffer is bounded
+    (``reports_held_overflow``): (loss, (2,) float32: the rows the buffers
+    took and the held assignments that did not fit, summed over layers), for
+    ``jax.value_and_grad(has_aux=True)``."""
+    _, loss, held = _forward(config, params, batch, targets, dropout_key, deterministic)
+    return loss, held

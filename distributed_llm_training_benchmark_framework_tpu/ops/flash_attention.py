@@ -174,6 +174,12 @@ _PALLAS_BWD_MIN_SEQ = 4096
 _LOG2_E = math.log2(math.e)
 
 
+def _softmax_scale(scale: Optional[float], d_qk: int) -> float:
+    """The factor on q k^T: the caller's (latent attention under YaRN gives
+    its own), else 1 / sqrt(width of q and k)."""
+    return 1.0 / (d_qk ** 0.5) if scale is None else scale
+
+
 def _fwd_sub_k(bk: int) -> int:
     """Keys of the compute piece the forward kernel walks a (bq, bk) DMA tile
     in: 128, one MXU weight tile of P. A tile no wider than that, or one it
@@ -316,6 +322,7 @@ def _vma_struct(shape, dtype, *like):
 def _jnp_reference_forward(
     q: jax.Array, k: jax.Array, v: jax.Array,
     causal: bool, dropout_rate: float, seed: jax.Array, bhv: jax.Array,
+    scale: Optional[float] = None,
 ) -> Tuple[jax.Array, jax.Array]:
     """Materialized-softmax forward with the kernel's exact mask/accumulation
     semantics (same ``_dropout_keep`` coordinates, same un-dropped normalizer),
@@ -325,7 +332,7 @@ def _jnp_reference_forward(
     on a TPU backend (``_resolve_interpret``). Returns (out, lse) exactly as
     ``_flash_forward`` does."""
     BH, S, D = q.shape
-    scale = 1.0 / (D ** 0.5)
+    scale = _softmax_scale(scale, D)
     s = jnp.einsum(
         "bqd,bkd->bqk", q, k, preferred_element_type=jnp.float32
     ) * scale
@@ -360,22 +367,25 @@ def _flash_forward(
     q: jax.Array, k: jax.Array, v: jax.Array,
     causal: bool, interpret: bool, bq: int, bk: int,
     dropout_rate: float, seed: jax.Array, bhv: jax.Array,
-    sub_k: Optional[int] = None,
+    sub_k: Optional[int] = None, scale: Optional[float] = None,
 ) -> Tuple[jax.Array, jax.Array]:
-    """Run the Pallas kernel on (BH, S, D) inputs -> (out, lse). ``bhv`` is
+    """Run the Pallas kernel on (BH, S, D) q and k and (BH, S, Dv) v ->
+    (out (BH, S, Dv), lse); Dv is D everywhere but latent attention, whose
+    keys carry a rotary part the values lack. ``bhv`` is
     the (BH,) int32 vector of GLOBAL batch*head ids keying the dropout hash
     (arange(BH) on one device; mesh-global ids under a shard_map).
     ``sub_k`` forces the compute piece (tests and the microbench; no flag or
     config field reaches it); ``_fwd_sub_k`` chooses it otherwise."""
     BH, S, D = q.shape
-    scale = 1.0 / (D ** 0.5)
+    Dv = v.shape[-1]
+    scale = _softmax_scale(scale, D)
     grid = (BH, S // bq, S // bk)
     sub_k = sub_k or _fwd_sub_k(bk)
     from ..utils.vma import vma_of
 
     if interpret and vma_of(q, k, v):
         return _jnp_reference_forward(
-            q, k, v, causal, dropout_rate, seed, bhv
+            q, k, v, causal, dropout_rate, seed, bhv, scale
         )
     out, lse = pl.pallas_call(
         functools.partial(
@@ -383,7 +393,7 @@ def _flash_forward(
             causal=causal, dropout_rate=dropout_rate,
         ),
         out_shape=[
-            _vma_struct((BH, S, D), q.dtype, q, k, v),
+            _vma_struct((BH, S, Dv), q.dtype, q, k, v),
             _vma_struct((BH, 8, S), jnp.float32, q, k, v),
         ],
         grid=grid,
@@ -392,16 +402,16 @@ def _flash_forward(
             pl.BlockSpec(memory_space=pltpu.SMEM),  # global bh ids (BH,)
             pl.BlockSpec((1, bq, D), lambda b, qi, ki: (b, qi, 0)),
             pl.BlockSpec((1, bk, D), lambda b, qi, ki: (b, ki, 0)),
-            pl.BlockSpec((1, bk, D), lambda b, qi, ki: (b, ki, 0)),
+            pl.BlockSpec((1, bk, Dv), lambda b, qi, ki: (b, ki, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((1, bq, D), lambda b, qi, ki: (b, qi, 0)),
+            pl.BlockSpec((1, bq, Dv), lambda b, qi, ki: (b, qi, 0)),
             pl.BlockSpec((1, 8, bq), lambda b, qi, ki: (b, 0, qi)),
         ],
         scratch_shapes=[
             pltpu.VMEM((1, bq), jnp.float32),  # running max, log2 units
             pltpu.VMEM((1, bq), jnp.float32),  # running sum / keep_prob
-            pltpu.VMEM((D, bq), jnp.float32),  # output accumulator, out^T
+            pltpu.VMEM((Dv, bq), jnp.float32),  # output accumulator, out^T
         ],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
@@ -417,17 +427,17 @@ def _flash(
     opts: Tuple, q: jax.Array, k: jax.Array, v: jax.Array, seed: jax.Array,
     bhv: jax.Array,
 ) -> jax.Array:
-    causal, interpret, bq, bk, _, _, rate = opts
+    causal, interpret, bq, bk, _, _, rate, scale = opts
     out, _ = _flash_forward(
-        q, k, v, causal, interpret, bq, bk, rate, seed, bhv
+        q, k, v, causal, interpret, bq, bk, rate, seed, bhv, scale=scale
     )
     return out
 
 
 def _flash_fwd_rule(opts, q, k, v, seed, bhv):
-    causal, interpret, bq, bk, _, _, rate = opts
+    causal, interpret, bq, bk, _, _, rate, scale = opts
     out, lse = _flash_forward(
-        q, k, v, causal, interpret, bq, bk, rate, seed, bhv
+        q, k, v, causal, interpret, bq, bk, rate, seed, bhv, scale=scale
     )
     return out, (q, k, v, out, lse, seed, bhv)
 
@@ -575,7 +585,7 @@ def _bwd_dkv_kernel(
         dv_ref[0] = dv_acc[:].astype(dv_ref.dtype)
 
 
-def _jnp_blockwise_bwd(causal, bk, rate, res, do):
+def _jnp_blockwise_bwd(causal, bk, rate, res, do, scale=None):
     """Blockwise flash backward as batched einsums over a K-block scan.
 
     Same math as the Pallas kernels, expressed as XLA-fused dense einsums:
@@ -593,7 +603,8 @@ def _jnp_blockwise_bwd(causal, bk, rate, res, do):
     """
     q, k, v, out, lse, seed, bhv = res
     BH, S, D = q.shape
-    scale = 1.0 / (D ** 0.5)
+    Dv = v.shape[-1]
+    scale = _softmax_scale(scale, D)
     f32 = jnp.float32
     cd = q.dtype  # matmul operand dtype (bf16 on TPU); accumulation is fp32
     dof = do.astype(cd)
@@ -603,7 +614,7 @@ def _jnp_blockwise_bwd(causal, bk, rate, res, do):
 
     nk = S // bk
     ks = k.reshape(BH, nk, bk, D).transpose(1, 0, 2, 3)  # (nk, BH, bk, D)
-    vs = v.reshape(BH, nk, bk, D).transpose(1, 0, 2, 3)
+    vs = v.reshape(BH, nk, bk, Dv).transpose(1, 0, 2, 3)
     rows = jnp.arange(S)
     threshold = _dropout_threshold(rate)
 
@@ -646,7 +657,7 @@ def _jnp_blockwise_bwd(causal, bk, rate, res, do):
     dq0 = pcast_like(jnp.zeros((BH, S, D), f32), q, k, v, do)
     dq, (dk_blocks, dv_blocks) = lax.scan(one_block, dq0, (jnp.arange(nk), ks, vs))
     dk = dk_blocks.transpose(1, 0, 2, 3).reshape(BH, S, D)
-    dv = dv_blocks.transpose(1, 0, 2, 3).reshape(BH, S, D)
+    dv = dv_blocks.transpose(1, 0, 2, 3).reshape(BH, S, Dv)
     return dq.astype(q.dtype), dk.astype(k.dtype), dv.astype(v.dtype)
 
 
@@ -749,7 +760,7 @@ def _bwd_fused_kernel(
 
 # VMEM the fused backward may take. The resident dq row is what grows with
 # S: an f32 accumulator plus the double-buffered output block, lanes padded
-# to 128. The tiles and Mosaic's temporaries at (1024, 1024) stayed under
+# to 128 (``D`` is the width of q and k: 192 pads to 256). The tiles and Mosaic's temporaries at (1024, 1024) stayed under
 # 16 MiB at every shape compiled; 32 is their allowance. A v5e core has
 # 128 MiB; past the cap (S 65536 at head dims to 128, bf16) the kernel pair
 # runs instead.
@@ -768,34 +779,39 @@ def _fused_fits(S: int, D: int, dtype) -> bool:
 
 def _fused_backward(
     q, k, v, do, lse3, delta3, seed, bhv, causal, rate, bq, bk, interpret,
+    scale=None,
 ):
-    """The one-kernel Pallas backward on (BH, S, D) operands."""
+    """The one-kernel Pallas backward on (BH, S, D) q and k and (BH, S, Dv)
+    v and do."""
     BH, S, D = q.shape
+    Dv = v.shape[-1]
     q_spec = pl.BlockSpec((1, bq, D), lambda b, ki, qi: (b, qi, 0))
     k_spec = pl.BlockSpec((1, bk, D), lambda b, ki, qi: (b, ki, 0))
+    do_spec = pl.BlockSpec((1, bq, Dv), lambda b, ki, qi: (b, qi, 0))
+    v_spec = pl.BlockSpec((1, bk, Dv), lambda b, ki, qi: (b, ki, 0))
     stat_spec = pl.BlockSpec((1, 8, bq), lambda b, ki, qi: (b, 0, qi))
     smem = pl.BlockSpec(memory_space=pltpu.SMEM)
     return pl.pallas_call(
         functools.partial(
-            _bwd_fused_kernel, bq=bq, bk=bk, scale=1.0 / (D ** 0.5),
+            _bwd_fused_kernel, bq=bq, bk=bk, scale=_softmax_scale(scale, D),
             causal=causal, dropout_rate=rate,
         ),
         out_shape=[
             _vma_struct((BH, S, D), q.dtype, q, k, v, do),
             _vma_struct((BH, S, D), k.dtype, q, k, v, do),
-            _vma_struct((BH, S, D), v.dtype, q, k, v, do),
+            _vma_struct((BH, S, Dv), v.dtype, q, k, v, do),
         ],
         grid=(BH, S // bk, S // bq),
-        in_specs=[smem, smem, q_spec, k_spec, k_spec, q_spec,
+        in_specs=[smem, smem, q_spec, k_spec, v_spec, do_spec,
                   stat_spec, stat_spec],
         out_specs=[
             pl.BlockSpec((1, S, D), lambda b, ki, qi: (b, 0, 0)),
-            k_spec, k_spec,
+            k_spec, v_spec,
         ],
         scratch_shapes=[
             pltpu.VMEM((S, D), jnp.float32),   # dq, the whole row
             pltpu.VMEM((bk, D), jnp.float32),  # dk, one k tile
-            pltpu.VMEM((bk, D), jnp.float32),  # dv
+            pltpu.VMEM((bk, Dv), jnp.float32),  # dv
         ],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary", "arbitrary"),
@@ -808,10 +824,10 @@ def _fused_backward(
 
 def _pair_backward(
     q, k, v, do, lse3, delta3, seed, bhv, causal, rate, bq, bk, interpret,
-    *, q_tile_offsets=None, k_tile_offsets=None, out_dtype=None,
+    scale=None, *, q_tile_offsets=None, k_tile_offsets=None, out_dtype=None,
 ):
-    """The dq and dk+dv kernel pair on (BH, Sq, D) queries and (BH, Sk, D)
-    keys: every score tile visited twice. The one caller of
+    """The dq and dk+dv kernel pair on (BH, Sq, D) queries, (BH, Sk, D) keys
+    and (BH, Sk, Dv) values: every score tile visited twice. The one caller of
     ``_bwd_dq_kernel`` / ``_bwd_dkv_kernel``.
 
     Plain flash runs it only for shapes ``_fused_fits`` turns away, with
@@ -822,8 +838,8 @@ def _pair_backward(
     half-chunk bases) and ``out_dtype`` float32, the dtype its dk / dv ride
     the ring in."""
     BH, Sq, D = q.shape
-    Sk = k.shape[1]
-    scale = 1.0 / (D ** 0.5)
+    Sk, Dv = k.shape[1], v.shape[-1]
+    scale = _softmax_scale(scale, D)
     seed_spec = pl.BlockSpec(memory_space=pltpu.SMEM)
     qoffs = (jnp.arange(Sq // bq, dtype=jnp.int32) * bq
              if q_tile_offsets is None else q_tile_offsets)
@@ -835,6 +851,8 @@ def _pair_backward(
     row_specs = dict(
         q=pl.BlockSpec((1, bq, D), lambda b, qi, ki: (b, qi, 0)),
         k=pl.BlockSpec((1, bk, D), lambda b, qi, ki: (b, ki, 0)),
+        v=pl.BlockSpec((1, bk, Dv), lambda b, qi, ki: (b, ki, 0)),
+        do=pl.BlockSpec((1, bq, Dv), lambda b, qi, ki: (b, qi, 0)),
         stat=pl.BlockSpec((1, 8, bq), lambda b, qi, ki: (b, 0, qi)),
     )
     dq = pl.pallas_call(
@@ -845,8 +863,8 @@ def _pair_backward(
         out_shape=_vma_struct((BH, Sq, D), dq_dtype, q, k, v, do),
         grid=(BH, Sq // bq, Sk // bk),
         in_specs=[seed_spec, seed_spec, seed_spec, seed_spec,
-                  row_specs["q"], row_specs["k"], row_specs["k"],
-                  row_specs["q"], row_specs["stat"], row_specs["stat"]],
+                  row_specs["q"], row_specs["k"], row_specs["v"],
+                  row_specs["do"], row_specs["stat"], row_specs["stat"]],
         out_specs=row_specs["q"],
         scratch_shapes=[pltpu.VMEM((bq, D), jnp.float32)],
         compiler_params=pltpu.CompilerParams(
@@ -858,6 +876,8 @@ def _pair_backward(
     col_specs = dict(
         q=pl.BlockSpec((1, bq, D), lambda b, ki, qi: (b, qi, 0)),
         k=pl.BlockSpec((1, bk, D), lambda b, ki, qi: (b, ki, 0)),
+        v=pl.BlockSpec((1, bk, Dv), lambda b, ki, qi: (b, ki, 0)),
+        do=pl.BlockSpec((1, bq, Dv), lambda b, ki, qi: (b, qi, 0)),
         stat=pl.BlockSpec((1, 8, bq), lambda b, ki, qi: (b, 0, qi)),
     )
     dk, dv = pl.pallas_call(
@@ -867,16 +887,16 @@ def _pair_backward(
         ),
         out_shape=[
             _vma_struct((BH, Sk, D), dk_dtype, q, k, v, do),
-            _vma_struct((BH, Sk, D), dv_dtype, q, k, v, do),
+            _vma_struct((BH, Sk, Dv), dv_dtype, q, k, v, do),
         ],
         grid=(BH, Sk // bk, Sq // bq),
         in_specs=[seed_spec, seed_spec, seed_spec, seed_spec,
-                  col_specs["q"], col_specs["k"], col_specs["k"],
-                  col_specs["q"], col_specs["stat"], col_specs["stat"]],
-        out_specs=[col_specs["k"], col_specs["k"]],
+                  col_specs["q"], col_specs["k"], col_specs["v"],
+                  col_specs["do"], col_specs["stat"], col_specs["stat"]],
+        out_specs=[col_specs["k"], col_specs["v"]],
         scratch_shapes=[
             pltpu.VMEM((bk, D), jnp.float32),
-            pltpu.VMEM((bk, D), jnp.float32),
+            pltpu.VMEM((bk, Dv), jnp.float32),
         ],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
@@ -892,7 +912,7 @@ def _flash_bwd_rule(opts, res, do):
     selects the XLA-fused blockwise einsum path or the Pallas one: the fused
     kernel, or the dq / dk+dv pair where a whole dq row would not fit VMEM.
     """
-    causal, interpret, bq, bk_fwd, bk, pallas_bwd, rate = opts
+    causal, interpret, bq, bk_fwd, bk, pallas_bwd, rate, scale = opts
     # seed and the bh ids are integral: no tangent.
     int_cts = (
         np.zeros((1,), jax.dtypes.float0),
@@ -906,7 +926,7 @@ def _flash_bwd_rule(opts, res, do):
         # operands (manual regions in interpret mode) — take the jnp backward.
         pallas_bwd = False
     if not pallas_bwd:
-        return (*_jnp_blockwise_bwd(causal, bk, rate, res, do), *int_cts)
+        return (*_jnp_blockwise_bwd(causal, bk, rate, res, do, scale), *int_cts)
     q, k, v, out, lse, seed, bhv = res
     BH, S, D = q.shape
 
@@ -919,7 +939,8 @@ def _flash_bwd_rule(opts, res, do):
     delta3 = jnp.broadcast_to(delta[:, None, :], (BH, 8, S))
     backward = _fused_backward if _fused_fits(S, D, q.dtype) else _pair_backward
     dq, dk, dv = backward(
-        q, k, v, do, lse3, delta3, seed, bhv, causal, rate, bq, bk, interpret
+        q, k, v, do, lse3, delta3, seed, bhv, causal, rate, bq, bk, interpret,
+        scale,
     )
     return dq, dk, dv, *int_cts
 
@@ -982,13 +1003,13 @@ def _kernel_mesh_axes():
     jax.jit,
     static_argnames=(
         "causal", "interpret", "block_q", "block_k", "block_k_bwd",
-        "pallas_backward", "dropout_rate",
+        "pallas_backward", "dropout_rate", "scale",
     ),
 )
 def flash_attention(
     q: jax.Array,  # (B, S, H, D)
     k: jax.Array,
-    v: jax.Array,
+    v: jax.Array,  # (B, S, H, Dv); Dv == D everywhere but latent attention
     causal: bool = False,
     interpret: Optional[bool] = None,
     block_q: Optional[int] = None,
@@ -997,8 +1018,13 @@ def flash_attention(
     pallas_backward: Optional[bool] = None,
     dropout_rate: float = 0.0,
     dropout_seed: Optional[jax.Array] = None,
+    scale: Optional[float] = None,
 ) -> jax.Array:
     """Multi-head flash attention over (batch, seq, heads, head_dim) inputs.
+
+    q and k share one width, v and the output another (latent attention:
+    192-wide keys over 128-wide values, no padding of either); ``scale``
+    multiplies q k^T and defaults to 1 / sqrt(width of q).
 
     Forward and backward take separate K-block sizes because their optima
     differ on v5e (see _FWD_BLOCK_* notes above).
@@ -1042,7 +1068,7 @@ def flash_attention(
         seed = jnp.asarray(dropout_seed, jnp.uint32).reshape((1,))
     if not 0.0 <= dropout_rate < 1.0:
         raise ValueError(f"dropout_rate must be in [0, 1), got {dropout_rate}")
-    opts = (causal, interpret, bq, bk, bk_bwd, pallas_backward, dropout_rate)
+    opts = (causal, interpret, bq, bk, bk_bwd, pallas_backward, dropout_rate, scale)
 
     def local(ql, kl, vl, seed_l, b_ids, h_ids):
         # (Bl, S, Hl, D) -> (Bl*Hl, S, D): one grid row per (batch, head)
@@ -1050,11 +1076,11 @@ def flash_attention(
         Bl, Hl = ql.shape[0], ql.shape[2]
 
         def to_bhsd(t):
-            return t.transpose(0, 2, 1, 3).reshape(Bl * Hl, S, D)
+            return t.transpose(0, 2, 1, 3).reshape(Bl * Hl, S, t.shape[-1])
 
         bhv = (b_ids[:, None] * H + h_ids[None, :]).reshape(Bl * Hl)
         out = _flash(opts, to_bhsd(ql), to_bhsd(kl), to_bhsd(vl), seed_l, bhv)
-        return out.reshape(Bl, Hl, S, D).transpose(0, 2, 1, 3)
+        return out.reshape(Bl, Hl, S, vl.shape[-1]).transpose(0, 2, 1, 3)
 
     b_ids = jnp.arange(B, dtype=jnp.int32)
     h_ids = jnp.arange(H, dtype=jnp.int32)
@@ -1072,10 +1098,10 @@ def flash_attention(
     )(q, k, v, seed, b_ids, h_ids)
 
 
-def reference_attention(q, k, v, causal: bool = False) -> jax.Array:
+def reference_attention(q, k, v, causal: bool = False, scale=None) -> jax.Array:
     """Materialized-softmax attention for correctness comparison (same math
     as models.tinygpt's in-model path, without dropout)."""
-    scale = 1.0 / (q.shape[-1] ** 0.5)
+    scale = _softmax_scale(scale, q.shape[-1])
     s = jnp.einsum("bqhd,bkhd->bhqk", q, k, preferred_element_type=jnp.float32) * scale
     if causal:
         S = q.shape[1]

@@ -594,6 +594,12 @@ _TP_RULES = {
     "blocks/moe_w1": (3,),
     "blocks/moe_b1": (2,),
     "blocks/moe_w2": (2,),
+    # Latent attention: the per-head expansion is column-parallel over heads;
+    # the down projection and its norm serve every head and stay whole.
+    "blocks/wkv_b": (2,),
+    # Shared experts: column-parallel gate|up would split across the gate /
+    # up boundary, so only the down projection's input rows are named.
+    "blocks/shared_wd": (1,),
 }
 
 # Expert parallelism over the 'expert' mesh axis: each device group owns a
@@ -611,7 +617,11 @@ _EP_RULES = {
 
 
 def _leaf_name(path) -> str:
-    return "/".join(str(getattr(p, "key", p)) for p in path)
+    """'blocks/<leaf>' for a leaf of either stack of layers: the leading dense
+    stack ('dense_blocks', models/tinygpt.py first_k_dense) holds leaves of
+    the same names, shapes and roles as 'blocks' and takes the same rules."""
+    name = "/".join(str(getattr(p, "key", p)) for p in path)
+    return name.removeprefix("dense_")
 
 
 #: Self-test escape hatch (graftcheck `--inject bad-fsdp-axis`): False

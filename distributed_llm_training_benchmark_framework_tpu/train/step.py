@@ -82,6 +82,8 @@ def _resolve_model_config(
     # (utils.memory.resolve_auto_remat); a direct create_train_state caller
     # that skips that step gets the conservative policy.
     remat = "full" if strategy.remat == "auto" else strategy.remat
+    if mesh is not None and mesh.shape.get("pipe", 1) > 1:
+        model_config.refuse_pipeline()
     # bf16 parameter storage halves params+grads+Adam state — the knob that
     # fits tier B on one chip (see StrategyConfig.param_dtype). The
     # ZeRO-Offload arm also runs bf16 DEVICE params — its fp32 master
@@ -120,9 +122,12 @@ def _per_block_slice_specs(stacked_specs: Params):
     replicated, and pinning it mid-loop would add a per-layer round-trip
     instead of hiding one. Returns None when nothing is armable.
     """
+    # A leading dense stack holds leaves of the same names and shapes as
+    # 'blocks' (same slice spec) plus its own MLP's: one table serves both.
+    stacks = {**stacked_specs.get("dense_blocks", {}), **stacked_specs["blocks"]}
     per_block = tuple(sorted(
         (name, P(*list(spec)[1:]))
-        for name, spec in stacked_specs["blocks"].items()
+        for name, spec in stacks.items()
         if list(spec)[0] is None
     ))
     return per_block or None
@@ -389,8 +394,16 @@ def make_train_step(
     # (accum, batch, seq): shard the *batch* dim, accum dim is sequential.
     full_batch_spec = P(None, *batch_spec)
 
+    # A bounded buffer for the experts this chip holds: the step returns,
+    # after its loss, (2,) float32: the rows the buffers took and the held
+    # assignments that did not fit, summed over layers and micro-batches; any
+    # other config traces what it always did.
+    reports = cfg.reports_held_overflow
+    if reports and (sentinel or mesh.shape.get("pipe", 1) > 1):
+        raise ValueError("held_rows_factor does not compose with sentinel or pipe > 1")
+
     def micro_loss(params: Params, micro: jax.Array, key: jax.Array) -> jax.Array:
-        return tinygpt.loss_fn(
+        return (tinygpt.loss_and_held_fn if reports else tinygpt.loss_fn)(
             cfg,
             params,
             micro,
@@ -443,9 +456,9 @@ def make_train_step(
         def one_micro(carry, inp):
             loss_acc, grad_acc = carry
             micro, key = inp
-            loss, grads = jax.value_and_grad(micro_loss)(params, micro, key)
+            loss, grads = jax.value_and_grad(micro_loss, has_aux=reports)(params, micro, key)
             grad_acc = jax.tree.map(jnp.add, grad_acc, grads)
-            return (loss_acc + loss, grad_acc), None
+            return (jax.tree.map(jnp.add, loss_acc, loss), grad_acc), None
 
         if pipelined and pipeline_schedule == "interleaved":
             # Virtual stages (Megatron interleaved 1F1B): the bubble-shrinking
@@ -476,7 +489,9 @@ def make_train_step(
             )(params)
         elif grad_accum == 1:
             key = jax.random.fold_in(base_key, 0)
-            loss, grads = jax.value_and_grad(micro_loss)(params, batch[0], key)
+            loss, grads = jax.value_and_grad(micro_loss, has_aux=reports)(params, batch[0], key)
+            if reports:
+                loss, overflow = loss
         else:
             keys = jax.random.split(base_key, grad_accum)
             # Accumulator dtype follows the parameter dtype (cotangents
@@ -488,9 +503,14 @@ def make_train_step(
             zero_grads = jax.tree.map(
                 lambda p: jnp.zeros(p.shape, p.dtype), params
             )
+            zero = jnp.zeros((), jnp.float32)
             (loss_sum, grads), _ = lax.scan(
-                one_micro, (jnp.zeros((), jnp.float32), zero_grads), (batch, keys)
+                one_micro,
+                ((zero, jnp.zeros((2,), jnp.float32)) if reports else zero, zero_grads),
+                (batch, keys),
             )
+            if reports:
+                loss_sum, overflow = loss_sum
             loss = loss_sum / grad_accum
             grads = jax.tree.map(lambda g: g / grad_accum, grads)
 
@@ -544,6 +564,8 @@ def make_train_step(
             new_params = optax.apply_updates(params, updates)
         if sentinel:
             return new_params, new_opt_state, loss, gnorm
+        if reports:
+            return new_params, new_opt_state, loss, overflow
         return new_params, new_opt_state, loss
 
     opt_shardings = strat.opt_state_shardings(mesh, opt_specs, strategy)
@@ -553,7 +575,7 @@ def make_train_step(
         opt_shardings,
         scalar,
     )
-    if sentinel:
+    if sentinel or reports:
         out_shardings = out_shardings + (scalar,)
     jitted = jax.jit(
         train_step,

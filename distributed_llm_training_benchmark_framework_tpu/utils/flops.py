@@ -74,6 +74,8 @@ def forward_flops_per_token(config) -> float:
     untied alike. Defaults reproduce the original TinyGPT accounting
     exactly (kv=H, F=4D, gelu -> 8*D^2 attention projections + 16*D^2 MLP).
     """
+    if getattr(config, "latent_attention", False) or getattr(config, "first_k_dense", 0):
+        return _deepseek_forward_flops_per_token(config)
     D, L, V, S = config.n_embd, config.n_layer, config.vocab_size, config.block_size
     H = config.n_head
     Hkv = getattr(config, "kv_heads", H) or H
@@ -100,6 +102,34 @@ def forward_flops_per_token(config) -> float:
         + 4 * attn_tokens * (H * Dh)  # QK^T and probs@V
     )
     return float(L * per_layer + 2 * D * V)
+
+
+def _deepseek_forward_flops_per_token(c) -> float:
+    """A DeepSeek-V2-class config: latent attention (the three projections at
+    their own widths, scores over qk_dim and values over v_dim), leading
+    dense layers, and routed layers counted by their ACTIVE parameters on
+    this chip: the router over all experts, the shared experts, and the
+    expert_top_k * held / n_experts routed rows a token the held experts see
+    at uniform routing."""
+    D, H, S = c.n_embd, c.n_head, c.block_size
+    attn_tokens = S / 2 if c.causal else S
+    if c.latent_attention:
+        R, Dn, Dr, Dv = c.kv_lora_rank, c.qk_nope_head_dim, c.qk_rope_head_dim, c.v_dim
+        attention = (
+            2 * D * H * (Dn + Dr) + 2 * D * (R + Dr) + 2 * R * H * (Dn + Dv)
+            + 2 * H * Dv * D + 2 * attn_tokens * H * (Dn + Dr + Dv)
+        )
+    else:
+        Dh = c.head_dim
+        attention = 2 * D * (H + 2 * c.kv_heads) * Dh + 2 * H * Dh * D + 4 * attn_tokens * H * Dh
+    F = c.mlp_dim
+    routed_rows = c.expert_top_k * c.n_experts_held / c.n_experts
+    routed = 2 * D * c.n_experts + 6 * D * F * (routed_rows + c.n_shared_experts)
+    dense = 6 * D * (c.dense_mlp_hidden or 0)
+    return float(
+        c.n_layer * attention + c.first_k_dense * dense + c.n_moe_layers * routed
+        + 2 * D * c.vocab_size
+    )
 
 
 def train_flops_per_token(config) -> float:

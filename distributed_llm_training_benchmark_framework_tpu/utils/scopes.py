@@ -17,3 +17,12 @@ EMBED, ATTENTION, MLP, DROPOUT, HEAD, LOSS, OPTIMIZER = SCOPES = (
 ROUTER, DISPATCH, EXPERTS, COMBINE = MOE_SCOPES = (
     "router", "dispatch", "experts", "combine",
 )
+
+# ``shared``: the shared experts, beside the four above where the config has
+# them (DeepSeek-class layers).
+SHARED = "shared"
+
+# Inside ``attention``, where it is latent attention (models/tinygpt.py
+# ``_latent_attention``): the projections with the latent's norm and rotary,
+# the attention itself (the flash kernels), the output projection.
+MLA_PROJ, MLA_CORE, MLA_OUT = MLA_SCOPES = ("mla_proj", "mla_core", "mla_out")

@@ -383,3 +383,20 @@ def test_whole_step_compiles_at_tier_a(v5e_devices, monkeypatch, name):
     peak = compiled.memory_analysis().peak_memory_in_bytes
     assert 0 < peak < 16 * 1024**3
     print(f"{name}: peak {peak / 1e9:.2f} GB/chip")
+
+
+def test_flash_compiles_at_latent_attention_widths(v5e_devices):
+    """192-wide q and k over 128-wide v with the caller's scale (DeepSeek-V2's
+    MLA under YaRN), forward and the fused backward at S 8192: two Mosaic
+    calls, no padding of either width."""
+    one = SingleDeviceSharding(v5e_devices[0])
+    aval = lambda d: jax.ShapeDtypeStruct((1, 8192, 16, d), jnp.bfloat16, sharding=one)
+
+    def loss(q, k, v):
+        out = fa.flash_attention(q, k, v, causal=True, interpret=False, scale=0.114721)
+        return jnp.sum(out.astype(jnp.float32))
+
+    text = _compile(jax.grad(loss, argnums=(0, 1, 2)), aval(192), aval(192), aval(128))
+    assert text.count("tpu_custom_call") == 2
+    assert "bf16[16,8192,192]" in text and "bf16[16,8192,128]" in text
+    assert "bf16[16,8192,256]" not in text  # nothing padded to a lane multiple in HBM
