@@ -131,9 +131,12 @@ class TinyGPTConfig:
     compute_dtype: Any = jnp.bfloat16
     # Per-layer rematerialization policy inside the scan:
     #   "none" — save every intermediate (fastest, most memory);
-    #   "dots" — jax.checkpoint with the save-dots-class policy: matmul
-    #            outputs are kept, only cheap elementwise/softmax work is
-    #            recomputed in backward (the low-tax middle ground);
+    #   "dots" — jax.checkpoint keeping what is dear to redo and cheap to
+    #            keep: matmul outputs (dot_general without batch dims) and
+    #            the flash kernel's two results (out, lse); only cheap
+    #            elementwise/norm work (and reference attention's batched
+    #            products) is recomputed in backward (the low-tax middle
+    #            ground);
     #   "full" — all-or-nothing jax.checkpoint per layer (least memory,
     #            ~full forward recompute in backward).
     # Booleans are accepted for backward compatibility (True="full").
@@ -1202,12 +1205,19 @@ def apply_blocks(
         block = jax.checkpoint(block)
     elif pol == "dots":
         # Save matmul (dot_general without dot-batch dims, i.e. x @ W)
-        # outputs; recompute only LN/GELU/softmax/dropout in backward —
-        # removes most of full remat's recompute tax while still dropping
-        # the elementwise intermediates from liveness.
+        # outputs and the flash kernel's two results (no dot_general: see
+        # FLASH_RESIDUAL_NAMES); recompute only LN/GELU/softmax/dropout in
+        # backward — removes most of full remat's recompute tax while still
+        # dropping the elementwise intermediates from liveness.
+        from ..ops.flash_attention import FLASH_RESIDUAL_NAMES
+
+        policies = jax.checkpoint_policies
         block = jax.checkpoint(
             block,
-            policy=jax.checkpoint_policies.dots_with_no_batch_dims_saveable,
+            policy=policies.save_from_both_policies(
+                policies.dots_with_no_batch_dims_saveable,
+                policies.save_only_these_names(*FLASH_RESIDUAL_NAMES),
+            ),
         )
 
     # Inside a partially-manual shard_map (the pipeline), x is varying over

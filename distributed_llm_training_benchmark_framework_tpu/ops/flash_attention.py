@@ -52,6 +52,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 from jax.sharding import PartitionSpec as P
@@ -434,11 +435,25 @@ def _flash(
     return out
 
 
+#: ``jax.ad_checkpoint.checkpoint_name``s of the forward's two results, (out,
+#: lse), as the backward's residuals. A Mosaic call is no ``dot_general``, so a
+#: dots-class remat policy would drop them and run the whole O(S^2) kernel
+#: again in the backward pass; the model's ``dots`` policy keeps these names
+#: (models/tinygpt.py::apply_blocks). Outside a checkpoint a name is the
+#: identity.
+FLASH_RESIDUAL_NAMES = ("flash_out", "flash_lse")
+
+
 def _flash_fwd_rule(opts, q, k, v, seed, bhv):
     causal, interpret, bq, bk, _, _, rate, scale = opts
     out, lse = _flash_forward(
         q, k, v, causal, interpret, bq, bk, rate, seed, bhv, scale=scale
     )
+    # The kernel's results feed nothing but the two names (the primal result
+    # is the named ``out``), so where a policy saves them the recompute copy
+    # of the call is dead code.
+    out = checkpoint_name(out, FLASH_RESIDUAL_NAMES[0])
+    lse = checkpoint_name(lse, FLASH_RESIDUAL_NAMES[1])
     return out, (q, k, v, out, lse, seed, bhv)
 
 

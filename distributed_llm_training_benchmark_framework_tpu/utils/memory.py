@@ -176,8 +176,10 @@ def estimate_hbm(
         act_b = layers_here * 2 * B * S * D * cbytes + dense_per_layer
     elif pol == "dots":
         # Matmul outputs are saved (~qkv 3BSD + attn-out BSD + mlp 5BSD +
-        # boundary 2BSD ≈ 11·BSD per layer); elementwise intermediates are
-        # recomputed within one layer's working set.
+        # boundary 2BSD ≈ 11·BSD per layer; the attention output is in
+        # fact kept under flash too: apply_blocks saves the kernel's two
+        # results by name); elementwise intermediates are recomputed
+        # within one layer's working set.
         act_b = layers_here * 11 * B * S * D * cbytes + dense_per_layer
     else:
         act_b = layers_here * dense_per_layer
