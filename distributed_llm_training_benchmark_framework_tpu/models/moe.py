@@ -31,7 +31,15 @@ many assignments land on the held experts is data, so the rows go into a
 static buffer: N x K rows (nothing can overflow) or, with
 ``held_rows_factor``, that multiple of the expected N x K x count / n_experts,
 in which case the layer also counts its rows and the held assignments that
-did not fit, and the train step returns both after its loss. A part of the
+did not fit, and the train step returns both after its loss. Every pass over
+the held rows costs what the M buffer rows cost, not what the N x K
+assignments would (seven in eight of them are held elsewhere): tokens go to
+rows by a gather of M rows (``_rows_from_tokens``), and rows come back to
+tokens (``_tokens_from_rows``: the weighted sum in ``combine`` and the
+transpose of the gather in ``dispatch``'s backward are the same operation)
+sorted by token, where a tile of tokens owns one contiguous span of rows, as
+one more grouped matmul: a one-hot of the token inside its tile times the
+span's rows (``tgmm``, f32 accumulation). A part of the
 experts does not train its routing (``TinyGPTConfig.trains_routing``: the
 gradient through the gates is the held experts' part only, and its sum over
 the chips is the exchange's to make). ``n_shared_experts``
@@ -86,6 +94,7 @@ formulations optimize the same global statistic.
 
 from __future__ import annotations
 
+import functools
 import math
 from typing import Optional, Tuple
 
@@ -93,6 +102,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.experimental.pallas.ops.tpu.megablox import ops as megablox
+from jax.experimental.pallas.ops.tpu.megablox.gmm import tgmm
 from jax.sharding import PartitionSpec as P
 
 from ..utils import scopes
@@ -396,28 +406,39 @@ def held_buffer_rows(c, n_tokens: int) -> int:
     return min(assignments, -(-math.ceil(c.held_rows_factor * expected) // tile) * tile)
 
 
-def _held_plan(c, expert_idx: jax.Array, counts: jax.Array):
-    """Where the held experts' assignments go -> (take (M,) the assignment in
-    each buffer row, slot (N*K,) the buffer row of each assignment or M, sizes
-    (count,) rows an expert, rows the number filled, overflow the held
-    assignments that did not fit).
+def _held_plan(c, expert_idx: jax.Array, counts: jax.Array, gates: jax.Array):
+    """Where the held experts' assignments go -> (gate (M,) each buffer row's
+    gate, live (M,) whether the row holds an assignment, rows_of (token (M,)
+    each row's token, by_token (M,) the rows in token order, sorted_token (M,)
+    their tokens, spans (N / T,) the rows of each tile of T tokens: what moves
+    rows, see ``_rows_from_tokens``), sizes (count,) rows an expert, rows the
+    number filled, overflow the held assignments that did not fit).
 
     Assignments sort by held expert (stable: token order inside an expert),
-    everything held elsewhere behind them; the first M of that order are the
-    buffer. Past ``rows`` the buffer is padding: never read, by anyone.
+    everything held elsewhere behind them, and their tokens and gates ride
+    along; the first M of that order are the buffer. Past ``rows`` the buffer
+    is padding: never read, by anyone.
     """
     first, count = c.experts_held
+    K = c.expert_top_k
     flat = expert_idx.reshape(-1)
-    M = held_buffer_rows(c, flat.shape[0] // c.expert_top_k)
+    N = flat.shape[0] // K
+    M = held_buffer_rows(c, N)
     local = flat - first
-    order = jnp.argsort(jnp.where((local >= 0) & (local < count), local, count), stable=True)
+    _, token, gate = lax.sort(
+        (jnp.where((local >= 0) & (local < count), local, count),
+         jnp.arange(N * K, dtype=jnp.int32) // K, gates.reshape(-1)),
+        num_keys=1, is_stable=True)
     ends = jnp.minimum(jnp.cumsum(counts[first:first + count]), M)
     rows = ends[-1]
     sizes = jnp.diff(ends, prepend=0).astype(jnp.int32)
-    position = jnp.argsort(order)  # where each assignment went in the order
-    slot = jnp.where(position < rows, position, M)
     overflow = jnp.sum(counts[first:first + count]) - rows
-    return order[:M], slot, sizes, rows, overflow
+    live = jnp.arange(M) < rows
+    token = jnp.where(live, token[:M], N)  # padding sorts behind every token
+    sorted_token, by_token = lax.sort_key_val(token, jnp.arange(M, dtype=jnp.int32))
+    tile = _token_tile(N)
+    spans = jnp.sum(jax.nn.one_hot(token // tile, N // tile, dtype=jnp.int32), axis=0)
+    return gate[:M], live, (token, by_token, sorted_token, spans), sizes, rows, overflow
 
 
 def routing_rows(config, layer: dict, x: jax.Array):
@@ -425,67 +446,77 @@ def routing_rows(config, layer: dict, x: jax.Array):
     ((E,) int32 assignments an expert, (2,) int32 or None: the rows the
     dispatch puts into the held experts' buffer and the held assignments that
     do not fit it)."""
-    _, expert_idx, counts, _ = _route_dropless(
+    gates, expert_idx, counts, _ = _route_dropless(
         config, x.reshape(-1, x.shape[-1]), layer["router"])
     if config.experts_held is None:
         return counts, None
-    _, _, _, rows, overflow = _held_plan(config, expert_idx, counts)
+    *_, rows, overflow = _held_plan(config, expert_idx, counts, gates)
     return counts, jnp.stack([rows, overflow]).astype(jnp.int32)
 
 
-@jax.custom_vjp
-def _gather_held(xt, take_token, slot, valid):
-    """Buffer rows from tokens: ``xt[take_token]`` where ``valid``, else 0. Its
-    transpose sums, for each token, the rows its K assignments went to (a
-    gather by ``slot``; the row M is zero), where autodiff of the indexing
-    would scatter-add."""
-    return jnp.where(valid[:, None], xt[take_token], jnp.zeros((), xt.dtype))
+# Tokens a group and (buffer rows, columns) tile of the sum back to tokens, from
+# a sweep on the v5e at DeepSeek-V2-Lite's sizes (scripts/microbench_moe_rows.py;
+# PERF.md, PR 32).
+_SUM_TOKENS, _SUM_TILING = 128, (256, 2048)
 
 
-def _gather_held_fwd(xt, take_token, slot, valid):
-    return _gather_held(xt, take_token, slot, valid), (slot, valid, xt.shape[0])
-
-
-def _gather_held_bwd(res, g):
-    slot, valid, N = res
-    g = jnp.where(valid[:, None], g, jnp.zeros((), g.dtype))  # padding may hold anything
-    padded = jnp.concatenate([g, jnp.zeros((1, g.shape[1]), g.dtype)])
-    back = padded[slot].reshape(N, -1, g.shape[1])
-    return jnp.sum(back.astype(jnp.float32), axis=1).astype(g.dtype), None, None, None
-
-
-_gather_held.defvjp(_gather_held_fwd, _gather_held_bwd)
+def _token_tile(n_tokens: int) -> int:
+    return math.gcd(n_tokens, _SUM_TOKENS)
 
 
 @jax.custom_vjp
-def _combine_held(out, gates, take, slot, valid):
-    """(N, D): each token's sum over its K choices of gate x the buffer row
-    that choice went to (row M: zero, for a choice held elsewhere or not
-    fitted). Backward: a row's cotangent is its token's times its gate, a
-    gate's is its row dotted with its token's cotangent, both by gathers."""
-    N, K = gates.shape
-    out = jnp.where(valid[:, None], out, jnp.zeros((), out.dtype))
-    padded = jnp.concatenate([out, jnp.zeros((1, out.shape[1]), out.dtype)])
-    back = padded[slot].reshape(N, K, out.shape[1])
-    return jnp.sum(back.astype(jnp.float32) * gates[:, :, None], axis=1).astype(out.dtype)
+def _rows_from_tokens(xt, rows_of):
+    """(N, D) tokens -> (M, D) buffer rows: each live row its token's; a row
+    of the padding holds the last token's, for nobody to read (a mask would be
+    one more pass over the rows: XLA's gather fuses with nothing).
+    ``rows_of`` is ``_held_plan``'s. Its transpose is ``_tokens_from_rows``
+    (autodiff of the indexing would scatter-add row by row), and that one's is
+    this."""
+    return xt.at[rows_of[0]].get(mode="clip")
 
 
-def _combine_held_fwd(out, gates, take, slot, valid):
-    return _combine_held(out, gates, take, slot, valid), (out, gates, take, slot, valid)
+def _rows_from_tokens_fwd(xt, rows_of):
+    return _rows_from_tokens(xt, rows_of), (rows_of, xt.shape[0])
 
 
-def _combine_held_bwd(res, dy):
-    out, gates, take, slot, valid = res
-    N, K = gates.shape
-    dy_rows = dy[take // K].astype(jnp.float32)  # (M, D): each row's token
-    live = valid[:, None]
-    d_out = jnp.where(live, dy_rows * gates.reshape(-1)[take][:, None], 0.0).astype(out.dtype)
-    d_gate_rows = jnp.sum(jnp.where(live, out.astype(jnp.float32) * dy_rows, 0.0), axis=1)
-    d_gates = jnp.concatenate([d_gate_rows, jnp.zeros((1,), jnp.float32)])[slot]
-    return d_out, d_gates.reshape(N, K), None, None, None
+def _rows_from_tokens_bwd(res, g):
+    rows_of, n_tokens = res
+    return _tokens_from_rows(g, rows_of, n_tokens), None
 
 
-_combine_held.defvjp(_combine_held_fwd, _combine_held_bwd)
+_rows_from_tokens.defvjp(_rows_from_tokens_fwd, _rows_from_tokens_bwd)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2,))
+def _tokens_from_rows(rows, rows_of, n_tokens: int):
+    """(M, D) buffer rows -> (N, D): each token's sum of its live rows (at most
+    K, on average K x count / E), f32 accumulation. It costs what the M rows
+    cost, not what the N x K assignments would: with the rows in token order a
+    tile of T tokens owns one contiguous span of them, so the sum is a grouped
+    matmul over the spans, (T, span) one-hot of the token inside its tile x
+    (span, D) rows: the ``tgmm`` the experts' backward already runs. The
+    padding sorts behind every span, and ``tgmm`` selects a span's rows out of
+    what it loads before it multiplies: whatever the padding holds, NaN too,
+    reaches no sum."""
+    _, by_token, sorted_token, spans = rows_of
+    (M, D), tile = rows.shape, _token_tile(n_tokens)
+    inside = (sorted_token % tile)[None, :] == jnp.arange(tile)[:, None]  # (T, M)
+    tm, tn = _SUM_TILING
+    out = tgmm(
+        inside.astype(rows.dtype), rows[by_token], spans, rows.dtype,
+        (math.gcd(M, tm), tile, min(tn, D)), interpret=jax.default_backend() != "tpu")
+    return out.reshape(n_tokens, D)
+
+
+def _tokens_from_rows_fwd(rows, rows_of, n_tokens):
+    return _tokens_from_rows(rows, rows_of, n_tokens), rows_of
+
+
+def _tokens_from_rows_bwd(n_tokens, rows_of, g):
+    return _rows_from_tokens(g, rows_of), None
+
+
+_tokens_from_rows.defvjp(_tokens_from_rows_fwd, _tokens_from_rows_bwd)
 
 
 def _moe_mlp_held(c, layer, x, dropout_key, deterministic):
@@ -496,20 +527,21 @@ def _moe_mlp_held(c, layer, x, dropout_key, deterministic):
     from .tinygpt import _dropout
 
     B, S, D = x.shape
-    N, K = B * S, c.expert_top_k
+    N = B * S
     xt = x.reshape(N, D)
     with jax.named_scope(scopes.ROUTER):
         gates, expert_idx, counts, aux = _route_dropless(c, xt, layer["router"], B)
         if not c.trains_routing:
             gates, aux = lax.stop_gradient((gates, aux))
     with jax.named_scope(scopes.DISPATCH):
-        take, slot, sizes, n_rows, overflow = _held_plan(c, expert_idx, counts)
-        valid = jnp.arange(take.shape[0]) < n_rows
-        rows = _gather_held(xt, take // K, slot, valid)
+        gate, live, rows_of, sizes, n_rows, overflow = _held_plan(c, expert_idx, counts, gates)
+        rows = _rows_from_tokens(xt, rows_of)
     with jax.named_scope(scopes.EXPERTS):
         out = _experts_dropless(c, layer, rows, sizes)
     with jax.named_scope(scopes.COMBINE):
-        y = _combine_held(out, gates, take, slot, valid)
+        out = jnp.where(live[:, None], out, jnp.zeros((), out.dtype))  # padding may hold anything
+        y = _tokens_from_rows(
+            (out.astype(jnp.float32) * gate[:, None]).astype(out.dtype), rows_of, N)
     y = _dropout(y, c.dropout, dropout_key, deterministic).reshape(B, S, D)
     if c.moe_aux_mode == "overflow":
         aux = jnp.zeros((), jnp.float32)

@@ -153,7 +153,7 @@ def test_gradient_of_every_leaf_matches_the_reference(batch, file):
     """A part of the experts does not train its routing, in the program and in
     the reference alike (the router's leaf gets exactly nothing); with every
     expert held the gates' and the load-balance term's gradients are compared
-    too, through ``_combine_held``'s hand-written transpose."""
+    too, through the gate weighting in ``_moe_mlp_held``."""
     shape = build_mla.mla_shape(JOB, file)
     config = dataclasses.replace(build_mla.deepseek_config(JOB, file), compute_dtype=jnp.float32)
     assert config.trains_routing == shape["routing_trained"] == (file is EVERY_EXPERT)
@@ -215,6 +215,156 @@ def test_the_shares_add_up_to_the_uncut_layer(weights, batch):
     with jax.default_matmul_precision("highest"):
         want = jax.vmap(lambda h: reference_mla._routed_mlp(shape, h, w)[0])(x)
     assert relative(uncut, want) < TOLERANCE["logits"]
+
+
+def routing_trained(config):
+    """``config`` with its gates and load-balance term differentiated through,
+    whatever it holds: ``trains_routing`` is a property, so a subclass."""
+    through = type("Trained", (TinyGPTConfig,), {"trains_routing": property(lambda self: True)})
+    return through(**{f.name: getattr(config, f.name) for f in dataclasses.fields(config)})
+
+
+def layer_and_gradients(config, layer, x, cotangent):
+    """-> (y, aux, the gradient of sum(y * cotangent) by (``layer``, ``x``))."""
+    def program(layer, x):
+        y, aux = moe.moe_mlp(config, layer, x, None, True)
+        return jnp.sum(y * cotangent), (y, aux)
+
+    (_, (y, aux)), grads = jax.value_and_grad(program, argnums=(0, 1), has_aux=True)(layer, x)
+    return y, aux, grads
+
+
+def steered_layer(weights, chosen):
+    """A routed layer and its input whose router sends token n to the experts
+    ``chosen[n % len(chosen)]`` (the router reads the input's first E columns
+    alone, a margin of 2 over everything else and distinct steps between the
+    choices, so no top-k is a near-tie); ``chosen`` None keeps the seeded
+    router."""
+    layer = jax.tree.map(lambda t: t[0], weights["blocks"])
+    x = jax.random.normal(jax.random.key(5), (BATCH, SEQ, CONFIG.n_embd))
+    if chosen is None:
+        return layer, x
+    wanted = np.zeros((BATCH * SEQ, EXPERTS), np.float32)
+    for n in range(BATCH * SEQ):
+        wanted[n, list(chosen[n % len(chosen)])] = 2.0 + 0.25 * np.arange(TOP_K)
+    x = x.at[..., :EXPERTS].set(wanted.reshape(BATCH, SEQ, EXPERTS))
+    router = jnp.zeros_like(layer["router"]).at[:EXPERTS].set(jnp.eye(EXPERTS))
+    return {**layer, "router": router}, x
+
+
+@jax.custom_vjp
+def poison(rows, filled):
+    """NaN in the rows that are not ``filled``, and in their cotangent."""
+    return jnp.where(filled[:, None], rows, jnp.nan)
+
+
+poison.defvjp(lambda rows, filled: (poison(rows, filled), filled),
+              lambda filled, g: (poison(g, filled), None))
+
+
+def poisoned_padding(monkeypatch):
+    """The grouped matmuls neither read nor write the buffer's rows past the
+    last group: make them NaN on the way in and out, forward and backward, so a
+    pass that reads one shows."""
+    experts = moe._experts_dropless
+
+    def poisoned(c, layer, rows, counts):
+        filled = jnp.arange(rows.shape[0]) < jnp.sum(counts)
+        return poison(experts(c, layer, poison(rows, filled), counts), filled)
+
+    monkeypatch.setattr(moe, "_experts_dropless", poisoned)
+
+
+# Experts 2..5 are held, 3 choices a token. Which of a token's choices are held:
+STEERED = {
+    "seeded-router": None,
+    "none-one-all-held": [(0, 1, 6), (0, 2, 7), (2, 3, 4), (5, 3, 1), (7, 6, 0)],
+    "a-held-expert-without-rows": [(2, 4, 0), (5, 1, 7), (4, 5, 2), (0, 6, 7)],  # 3 gets none
+    "the-first-held-expert-without-rows": [(3, 4, 5), (0, 1, 5), (6, 3, 7)],
+    "only-the-last-held-expert": [(5, 0, 1), (6, 7, 0)],
+    "nothing-held": [(0, 1, 6), (7, 6, 1)],
+}
+
+
+@pytest.mark.parametrize("trained", [False, True], ids=["routing-constant", "routing-trained"])
+@pytest.mark.parametrize("case", sorted(STEERED))
+def test_the_held_rows_go_out_and_come_back_as_the_reference_says(
+        weights, monkeypatch, case, trained):
+    """The rows' two passes (tokens -> buffer rows -> tokens, each the other's
+    transpose) against the plain reference: the layer's output and the gradient
+    of every leaf that feeds it, the input's too, with the gates constants of
+    the backward pass (a part of the experts) and differentiated through."""
+    poisoned_padding(monkeypatch)
+    layer, x = steered_layer(weights, STEERED[case])
+    config = routing_trained(CONFIG) if trained else CONFIG
+    shape = {**SHAPE, "routing_trained": trained}
+    cotangent = jax.random.normal(jax.random.key(6), x.shape)
+    counts, held = moe.routing_rows(config, layer, x)
+    if STEERED[case] is not None:
+        per_expert = np.zeros(EXPERTS, int)
+        for n in range(BATCH * SEQ):
+            per_expert[list(STEERED[case][n % len(STEERED[case])])] += 1
+        np.testing.assert_array_equal(counts, per_expert)
+    assert (int(held[0]), int(held[1])) == (int(counts[HELD[0]:HELD[0] + HELD[1]].sum()), 0)
+
+    def reference(layer, x):
+        with jax.default_matmul_precision("highest"):
+            y = jax.vmap(lambda h: reference_mla._routed_mlp(shape, h, layer)[0])(x)
+        return jnp.sum(y * cotangent), y
+
+    y, aux, got = layer_and_gradients(config, layer, x, cotangent)
+    (_, want_y), want = jax.value_and_grad(reference, argnums=(0, 1), has_aux=True)(layer, x)
+    assert (int(aux[1]), int(aux[2])) == (int(held[0]), 0)
+    assert relative(y, want_y) < TOLERANCE["logits"]
+    used = {"moe_wgu", "moe_wd", "shared_wgu", "shared_wd"} | ({"router"} if trained else set())
+    for name in sorted(used):
+        scale = float(jnp.max(jnp.abs(want[0][name]))) or 1.0
+        assert float(jnp.max(jnp.abs(got[0][name] - want[0][name]))) < 1e-4 * scale, name
+    assert float(jnp.abs(got[0]["router"]).max()) > 0.0 or not trained or "nothing" in case
+    assert relative(got[1], want[1]) < TOLERANCE["logits"]
+
+
+@pytest.mark.parametrize("trained", [False, True], ids=["routing-constant", "routing-trained"])
+def test_an_overflowing_buffer_computes_what_fits_and_reads_no_padding(
+        weights, monkeypatch, trained):
+    """Every token on the held experts 2 and 4, every other one on 3 too, and a
+    buffer of 256 rows for those 320: the layer computes the first 256
+    assignments in buffer order (expert by expert, token order inside one),
+    counts the rest, and its gradients are those of that sum."""
+    poisoned_padding(monkeypatch)
+    layer, x = steered_layer(weights, [(2, 3, 4), (2, 4, 0)])
+    N = BATCH * SEQ
+    config = dataclasses.replace(CONFIG, held_rows_factor=1.0)
+    config = routing_trained(config) if trained else config
+    M = moe.held_buffer_rows(config, N)
+    assert M == 2 * N  # the expected 3 N / 2 rows, up to a whole row tile
+    fits = np.zeros((N, EXPERTS), bool)  # experts 2 and 3 whole, expert 4's first N / 2 tokens
+    fits[:, 2], fits[:, 3], fits[:N // 2, 4] = True, True, True
+    cotangent = jax.random.normal(jax.random.key(6), x.shape)
+
+    def what_fits(layer, x):
+        h = x.reshape(N, -1)
+        probs = jax.nn.softmax(h @ layer["router"], -1)
+        gates = reference_mla._gate_weights(SHAPE, probs) * fits
+        if not trained:
+            gates = jax.lax.stop_gradient(gates)
+        F, y = SHAPE["expert_width"], 0.0
+        with jax.default_matmul_precision("highest"):
+            for e in range(HELD[1]):
+                wgu, wd = layer["moe_wgu"][e], layer["moe_wd"][e]
+                y = y + gates[:, HELD[0] + e, None] * reference_mla._swiglu(
+                    h, wgu[:, :F], wgu[:, F:], wd)
+        return jnp.sum((y.reshape(x.shape) + moe._shared_experts(config, layer, x)) * cotangent), y
+
+    y, aux, got = layer_and_gradients(config, layer, x, cotangent)
+    (_, want_y), want = jax.value_and_grad(what_fits, argnums=(0, 1), has_aux=True)(layer, x)
+    assert (int(aux[1]), int(aux[2])) == (M, 5 * N // 2 - M)
+    routed = y - moe._shared_experts(config, layer, x)
+    assert relative(routed.reshape(N, -1), want_y) < TOLERANCE["logits"]
+    for name in ("moe_wgu", "moe_wd") + (("router",) if trained else ()):
+        scale = float(jnp.max(jnp.abs(want[0][name])))
+        assert float(jnp.max(jnp.abs(got[0][name] - want[0][name]))) < 1e-4 * scale, name
+    assert relative(got[1], want[1]) < TOLERANCE["logits"]
 
 
 def test_a_bounded_buffer_counts_what_does_not_fit(weights, batch):
@@ -391,8 +541,7 @@ def test_a_part_of_the_experts_does_not_train_its_routing(weights, batch):
     assert not CONFIG.trains_routing
     assert dataclasses.replace(CONFIG, experts_held=(0, EXPERTS)).trains_routing
     assert dataclasses.replace(CONFIG, experts_held=None, held_rows_factor=None).trains_routing
-    trained = type("Trained", (TinyGPTConfig,), {"trains_routing": property(lambda self: True)})
-    through = trained(**{f.name: getattr(CONFIG, f.name) for f in dataclasses.fields(CONFIG)})
+    through = routing_trained(CONFIG)
     got = jax.value_and_grad(lambda p: tinygpt.loss_fn(CONFIG, p, batch, batch))(weights)
     want = jax.value_and_grad(lambda p: tinygpt.loss_fn(through, p, batch, batch))(weights)
     assert float(got[0]) == float(want[0])

@@ -182,6 +182,38 @@ def test_dropless_expert_matmuls_compile_at_olmoe_widths(v5e_devices, monkeypatc
         assert scope in text
 
 
+def test_the_held_rows_passes_compile_at_deepseek_sizes(v5e_devices, monkeypatch):
+    """A chip's share of DeepSeek-V2-Lite's routed layer at the benchmark
+    cell's sizes (16,384 tokens x 6 choices over 64 experts, 8 held, a buffer
+    of 18,432 rows of 2048): tokens to rows, rows back to tokens, and the
+    transposes of both. The way back is a ``tgmm`` over the spans of rows that
+    tiles of tokens own: one in the forward pass, one in the backward."""
+    from distributed_llm_training_benchmark_framework_tpu.models import moe
+    from distributed_llm_training_benchmark_framework_tpu.models.tinygpt import TinyGPTConfig
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    config = TinyGPTConfig(n_embd=2048, n_head=16, mlp_act="swiglu", mlp_hidden=1408,
+                           bias=False, n_experts=64, expert_top_k=6, capacity_factor=None,
+                           experts_held=(0, 8), held_rows_factor=1.5)
+    tokens = 16384
+    assert moe.held_buffer_rows(config, tokens) == 18432
+    one = SingleDeviceSharding(v5e_devices[0])
+    aval = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+
+    def loss(xt, expert_idx, counts):
+        gates = jnp.ones(expert_idx.shape, jnp.float32)
+        _, _, rows_of, _, _, _ = moe._held_plan(config, expert_idx, counts, gates)
+        back = moe._tokens_from_rows(moe._rows_from_tokens(xt, rows_of), rows_of, tokens)
+        return jnp.sum(jnp.square(back.astype(jnp.float32)))  # its gradient needs ``back``
+
+    text = _compile(
+        jax.grad(loss), aval((tokens, 2048), jnp.bfloat16), aval((tokens, 6), jnp.int32),
+        aval((64,), jnp.int32),
+    )
+    assert text.count('custom_call_target="tpu_custom_call"') == 2
+    assert "jit(tgmm)" in text
+
+
 def test_flash_partitions_over_a_four_device_data_mesh(v5e_devices):
     """The case GSPMD refuses bare ("Mosaic kernels cannot be automatically
     partitioned"): batch sharded over a 4-chip 'data' axis. flash_attention
