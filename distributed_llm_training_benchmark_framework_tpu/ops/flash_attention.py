@@ -23,7 +23,8 @@ Backward (custom VJP): recomputes attention probabilities tile by tile from
 the saved logsumexp — the standard flash backward — with two implementations
 sharing the same math: an XLA-fused ``lax.scan`` of dense jnp blocks over K
 (peak memory O(S * block)), and one hand-written Pallas kernel that makes
-dq, dk and dv from a single visit of each score tile (``_bwd_fused_kernel``).
+dq, dk and dv from a single visit of each score tile, its queries walked in
+pieces of 128 or 256 (``_bwd_fused_kernel``).
 Which is faster is S-dependent on v5e (einsum to S=2048, the kernel from
 S=4096 — docs/PERFORMANCE.md §12); ``pallas_backward=None`` auto-selects by
 that crossover. The older dq and dk+dv kernel pair visits every tile twice;
@@ -159,8 +160,39 @@ _BWD_BLOCK_K = 512
 _FWD_SUB_K = 128
 # The fused Pallas backward (S >= _PALLAS_BWD_MIN_SEQ) is fastest at
 # 1024x1024 at both head dims the benchmark runs (PERF.md, PR 25's sweep);
-# its q tile is the forward's block_q.
+# its q tile is the forward's block_q. That is its DMA tile too; the body
+# walks the tile's queries in pieces (_bwd_fused_kernel, _bwd_sub_q). One
+# call of _fused_backward in us a live (1024, 1024) tile (my chip runs, PR 33,
+# scripts/microbench_flash_bwd.py; "products" is the five tile products with
+# a cast between them and no softmax: what the MXU leaves a chain to hide in;
+# "whole" is one piece, the body PR 25 wrote with PR 33's shorter chain):
+#
+#   (BH, S, D / Dv, causal, dropout)   (16, 8192, 64, no, 0.1)  (64, 4096, 128, yes, 0)  (32, 8192, 192 / 128, yes, 0)
+#   the dq / dk+dv pair                        15.52                  13.55                   21.06
+#   before PR 33 (whole, 24 ops a score)        9.85                   8.16                   13.77
+#   whole, the chain of 19 (8 causal)           9.45                   8.22                   13.83
+#   queries in pieces of 512 / 256 / 128        9.53 / 8.35 / 7.87     8.18 / 8.15 / 8.34     13.80 / 13.77 / 14.04
+#   keys in pieces of 256 / 128                 8.82 / 8.83            8.20 / -               13.79 / -
+#   pieces of 128 queries, lookahead            7.92                   (512: 8.24)            -
+#   products alone, whole / in pieces of 128    7.73 / 7.98            8.19 / -               13.79 / -
+#   DMA tile (2048 q, 1024 k) / (2048, 2048)    7.64 / 7.55            -                      -
+#
+# With dropout the chain (10 of its 19 ops the hash) is what a whole tile
+# waits on, and pieces of 128 queries put it under the products: the kernel
+# then runs at what the products alone take. Without dropout the tile waits
+# on the MXU whole or cut, and 256 is level with the old body where the
+# shorter chain whole is 0.5-0.8 % slower (more spills in its schedule). At
+# D 64 without dropout 256 is the fastest too (7.67 against 7.74 whole, 7.86
+# at 128), and at D 128 with dropout 128 (8.33 against 9.11 at 256, 9.89
+# whole): the dropout rate decides, the head width does not. Lookahead (the
+# next piece's two leading products issued first, as the forward does) is
+# level: the pieces carry no chain from one to the next, so the scheduler
+# overlaps them as they stand. The last row is faster and not taken: the DMA
+# tile is PR 25's and the forward's, and 16 unrolled pieces a call would be
+# paid in every run's set-up.
 _FUSED_BWD_BLOCK_K = 1024
+_BWD_SUB_Q = 256
+_BWD_SUB_Q_DROPOUT = 128
 
 # Backward implementation crossover, measured on v5e tier A with the dq /
 # dk+dv kernel pair (docs/PERFORMANCE.md §12): the XLA-fused blockwise-einsum
@@ -676,10 +708,21 @@ def _jnp_blockwise_bwd(causal, bk, rate, res, do, scale=None):
     return dq.astype(q.dtype), dk.astype(k.dtype), dv.astype(v.dtype)
 
 
+def _bwd_sub_q(bq: int, dropout_rate: float) -> int:
+    """Queries of the compute piece the fused backward walks a (bk, bq) DMA
+    tile in: 128 with dropout (the hash makes the chain long), 256 without;
+    the table above ``_FUSED_BWD_BLOCK_K``. Head width does not enter: the
+    same piece is fastest at each. A tile no wider than the piece, or one it
+    does not divide (the CPU tests' small tiles), is walked whole."""
+    sub = _BWD_SUB_Q_DROPOUT if dropout_rate > 0.0 else _BWD_SUB_Q
+    return sub if bq > sub and bq % sub == 0 else bq
+
+
 def _bwd_fused_kernel(
     seed_ref, bhv_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     dq_ref, dk_ref, dv_ref, dq_acc, dk_acc, dv_acc,
-    *, bq: int, bk: int, scale: float, causal: bool, dropout_rate: float,
+    *, bq: int, bk: int, sub_q: int, scale: float, causal: bool,
+    dropout_rate: float,
 ):
     """dq, dk and dv from ONE visit of each live (k tile, q tile): s, p, dp,
     the keep mask and ds are computed once and feed all three products
@@ -693,10 +736,26 @@ def _bwd_fused_kernel(
     starts at row i*b: plain flash only (ring keeps the kernel pair).
 
     The score tile is held k-major, (bk, bq): dv and dk are then plain
-    products, only dq contracts over the tile's first dim (one tile
-    transpose where a q-major body has two), and lse / delta broadcast
-    along sublanes from the (8, bq) blocks as they arrive. Same tile
-    products as the pair: bit-identical to it at equal tile sizes."""
+    products, only dq contracts over the tile's first dim (one transpose
+    where a q-major body has two), and lse / delta are (1, bq) rows that
+    broadcast along sublanes. The body walks the tile's queries in compute
+    pieces of ``sub_q`` lanes, unrolled (``_bwd_sub_q``): a piece's two
+    leading products, its vector chain and its three trailing products, dq
+    written a slice a piece, dk / dv accumulated over the pieces (the
+    products' own contraction chunks, taken to the outer loop).
+
+    The chain, by the score: p = exp2(s * c - lse2) with c = scale * log2(e)
+    and lse2 = lse * log2(e) made on the row (one multiply for the softmax
+    scale and exp's base change); ds's ``* scale`` is left to where dk and
+    dq are written out; dropout's 1 / keep_prob rides in the subtracted row
+    (p comes out as p / keep_prob, which is what dv's product wants), and
+    ds = p' * (where(keep, dp, 0) - delta * keep_prob), the last factor on
+    the row; no second causal select on p (exp2(NEG_INF * c - lse2) is
+    exactly 0: with tile i at row i*b every query has a live key, so lse is
+    finite); the hash's row half once a piece. Against the kernel pair that
+    moves dk and dq by f32 rounding of the folded factors and by bf16
+    rounding of ds before ``scale`` instead of after it; the keep mask is
+    the same bits."""
     bh = pl.program_id(0)
     ki = pl.program_id(1)
     qi = pl.program_id(2)
@@ -705,6 +764,8 @@ def _bwd_fused_kernel(
     q_off = qi * bq
     k_off = ki * bk
     q_rows = pl.ds(pl.multiple_of(q_off, bq), bq)
+    c = scale * _LOG2_E
+    keep_prob = 1.0 - dropout_rate
 
     @pl.when(qi == 0)
     def _init_kv():
@@ -719,66 +780,73 @@ def _bwd_fused_kernel(
 
     @pl.when(live)
     def _accumulate():
-        q = q_ref[0]
         k = k_ref[0]
         v = v_ref[0]
-        do = do_ref[0]
-        lse = lse_ref[0][:1]      # (1, bq)
-        delta = delta_ref[0][:1]  # (1, bq)
-        s = lax.dot_general(
-            k, q, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        ) * scale  # (bk, bq)
-        # Narrow coordinate operands, as in the other kernels; query
-        # positions ("rows" of the hash) run along lanes here.
-        rows = q_off + lax.broadcasted_iota(jnp.int32, (1, bq), 1)
+        # Narrow coordinate operands, as in the other kernels; key positions
+        # ("cols" of the hash) run down the sublanes here.
         cols = k_off + lax.broadcasted_iota(jnp.int32, (bk, 1), 0)
-        if causal:
-            mask = rows >= cols
-            s = jnp.where(mask, s, NEG_INF)
-        p = jnp.exp(s - lse)
-        if causal:
-            p = jnp.where(mask, p, 0.0)
-        dp = lax.dot_general(
-            v, do, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        )
-        if dropout_rate > 0.0:
-            keep = _dropout_keep(
-                seed_ref[0], bhv_ref[bh], rows, cols,
-                _dropout_threshold(dropout_rate),
+        hash_cols = cols.astype(jnp.uint32)
+        for r0 in range(0, bq, sub_q):
+            q = q_ref[0, pl.ds(r0, sub_q), :]
+            do = do_ref[0, pl.ds(r0, sub_q), :]
+            s = lax.dot_general(
+                k, q, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            )  # (bk, sub_q) fp32, unscaled
+            dp = lax.dot_general(
+                v, do, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32,
             )
-            inv = 1.0 / (1.0 - dropout_rate)
-            pd = jnp.where(keep, p * inv, 0.0)
-            dp = jnp.where(keep, dp * inv, 0.0)
-        else:
-            pd = p
-        dv_acc[:] = dv_acc[:] + lax.dot_general(
-            pd.astype(q.dtype), do, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        ds = (p * (dp - delta) * scale).astype(q.dtype)
-        dk_acc[:] = dk_acc[:] + lax.dot_general(
-            ds, q, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
-        )
-        dq_acc[q_rows, :] = dq_acc[q_rows, :] + lax.dot_general(
-            ds, k, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32
-        )
+            shift = lse_ref[0, :1, pl.ds(r0, sub_q)] * _LOG2_E  # (1, sub_q)
+            delta = delta_ref[0, :1, pl.ds(r0, sub_q)]
+            rows = q_off + r0 + lax.broadcasted_iota(jnp.int32, (1, sub_q), 1)
+            if causal:
+                s = jnp.where(rows >= cols, s, NEG_INF)
+            if dropout_rate > 0.0:
+                # p / keep_prob: dv's operand as it is, and ds's with
+                # keep_prob taken into delta's row.
+                p = jnp.exp2(s * c - (shift + math.log2(keep_prob)))
+                keep = _mix32(
+                    _dropout_rowbase(seed_ref[0], bhv_ref[bh], rows) + hash_cols
+                ) < _dropout_threshold(dropout_rate)
+                pd = jnp.where(keep, p, 0.0)
+                dp = jnp.where(keep, dp, 0.0)
+                delta = delta * keep_prob
+            else:
+                p = pd = jnp.exp2(s * c - shift)
+            ds = (p * (dp - delta)).astype(q.dtype)  # ds / scale
+            dv_acc[:] = dv_acc[:] + lax.dot_general(
+                pd.astype(q.dtype), do, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            )
+            dk_acc[:] = dk_acc[:] + lax.dot_general(
+                ds, q, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            )
+            dq_rows = pl.ds(pl.multiple_of(q_off + r0, sub_q), sub_q)
+            dq_acc[dq_rows, :] = dq_acc[dq_rows, :] + lax.dot_general(
+                ds, k, (((0,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            )
 
     @pl.when(qi == nq - 1)
     def _finalize_kv():
-        dk_ref[0] = dk_acc[:].astype(dk_ref.dtype)
+        dk_ref[0] = (dk_acc[:] * scale).astype(dk_ref.dtype)
         dv_ref[0] = dv_acc[:].astype(dv_ref.dtype)
 
     @pl.when(ki == nk - 1)
     def _finalize_q():
-        dq_ref[0, q_rows, :] = dq_acc[q_rows, :].astype(dq_ref.dtype)
+        dq_ref[0, q_rows, :] = (dq_acc[q_rows, :] * scale).astype(dq_ref.dtype)
 
 
 # VMEM the fused backward may take. The resident dq row is what grows with
 # S: an f32 accumulator plus the double-buffered output block, lanes padded
-# to 128 (``D`` is the width of q and k: 192 pads to 256). The tiles and Mosaic's temporaries at (1024, 1024) stayed under
-# 16 MiB at every shape compiled; 32 is their allowance. A v5e core has
-# 128 MiB; past the cap (S 65536 at head dims to 128, bf16) the kernel pair
-# runs instead.
+# to 128 (``D`` is the width of q and k: 192 pads to 256). The (1024, 1024)
+# tile's operand blocks, the dk / dv accumulators and Mosaic's temporaries
+# (a (1024, 256) f32 piece is 1 MiB where the whole tile was 4) compile
+# under 6 MiB at the three cell shapes and at the cap (under 16 before PR
+# 33); 32 stays their allowance. A v5e core has 128 MiB; past the cap
+# (S 65536 at head dims to 128, bf16) the kernel pair runs instead.
 _FUSED_TILE_VMEM = 32 * 2**20
 _FUSED_MAX_VMEM = 96 * 2**20
 
@@ -794,10 +862,11 @@ def _fused_fits(S: int, D: int, dtype) -> bool:
 
 def _fused_backward(
     q, k, v, do, lse3, delta3, seed, bhv, causal, rate, bq, bk, interpret,
-    scale=None,
+    scale=None, sub=None,
 ):
     """The one-kernel Pallas backward on (BH, S, D) q and k and (BH, S, Dv)
-    v and do."""
+    v and do. ``sub`` forces the compute piece (tests and the microbench; no
+    flag or config field reaches it); ``_bwd_sub_q`` chooses it otherwise."""
     BH, S, D = q.shape
     Dv = v.shape[-1]
     q_spec = pl.BlockSpec((1, bq, D), lambda b, ki, qi: (b, qi, 0))
@@ -808,8 +877,9 @@ def _fused_backward(
     smem = pl.BlockSpec(memory_space=pltpu.SMEM)
     return pl.pallas_call(
         functools.partial(
-            _bwd_fused_kernel, bq=bq, bk=bk, scale=_softmax_scale(scale, D),
-            causal=causal, dropout_rate=rate,
+            _bwd_fused_kernel, bq=bq, bk=bk,
+            sub_q=sub or _bwd_sub_q(bq, rate),
+            scale=_softmax_scale(scale, D), causal=causal, dropout_rate=rate,
         ),
         out_shape=[
             _vma_struct((BH, S, D), q.dtype, q, k, v, do),

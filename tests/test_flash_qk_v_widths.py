@@ -73,7 +73,8 @@ def test_pair_backward_matches_fused(causal):
     pair = fa._pair_backward(*args, scale=SCALE)
     for f, p, width in zip(fused, pair, (DQK, DQK, DV)):
         assert f.shape == (B * H, S, width)
-        np.testing.assert_allclose(f, p, atol=1e-6, rtol=1e-6)
+        # f32 rounding of the factors the fused chain folds (PR 33).
+        np.testing.assert_allclose(f, p, atol=5e-6, rtol=1e-5)
 
 
 def test_fused_vmem_bound_follows_the_wider_width():
