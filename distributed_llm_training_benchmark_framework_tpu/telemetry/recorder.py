@@ -197,6 +197,32 @@ def read_events(path: str) -> List[Dict[str, Any]]:
     return events
 
 
+def _host_split(n: int) -> Dict[str, float]:
+    """Where the window of ``n`` steps that just ended went on the host, ms,
+    from the program's own record (``utils/scopes.host_records``): its longest
+    ``step_dispatch`` (a stall inside the step's call), its wait since the
+    last dispatch over the median wait before the earlier windows (a stall
+    outside it: the device, the transfer, the loop) and the longest
+    collection inside it. Empty where the record does not hold the window."""
+    from ..utils import scopes
+
+    steps = scopes.host_records(scopes.STEP_DISPATCH)
+    if n < 1 or len(steps) < n:
+        return {}
+    mine, now = steps[-n:], time.perf_counter_ns()
+    waits = sorted(steps[i][1] - steps[i - 1][2]
+                   for i in range(len(steps) - n, 0, -n))
+    pauses = [r[2] - r[1] for r in scopes.host_records(scopes.GC)
+              if r[2] > mine[0][1]]
+    return {
+        "dispatch_max_ms": round(max(r[2] - r[1] for r in mine) / 1e6, 3),
+        "wait_excess_ms": round(
+            (now - mine[-1][2] - (waits[len(waits) // 2] if waits else 0))
+            / 1e6, 3),
+        "gc_max_ms": round(max(pauses, default=0) / 1e6, 3),
+    }
+
+
 class TelemetryRecorder:
     """Streams run telemetry; tracks phase-time attribution for the result.
 
@@ -522,6 +548,7 @@ class TelemetryRecorder:
                     "anomaly", kind="step_time_spike", step=last_step,
                     detail=(f"window mean {dt:.4f}s > {SPIKE_FACTOR}x "
                             f"median {med:.4f}s"),
+                    host_split=_host_split(len(losses)),
                 )
             elif self._open_spike is not None:
                 if dt <= SPIKE_RESOLVE_FACTOR * med:

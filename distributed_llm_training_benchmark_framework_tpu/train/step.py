@@ -593,7 +593,10 @@ def make_train_step(
     def step_with_mesh(params, opt_state, batch, step):
         # Trace/execute under the mesh context so mesh-aware ops (ring
         # attention's shard_map) can discover the axes via get_abstract_mesh.
-        with jax.set_mesh(mesh):
+        # The step's number goes into the record where the caller holds it
+        # as a Python int; a device scalar is not fetched for it.
+        args = {"step": step} if type(step) is int else {}
+        with scopes.host_span(scopes.STEP_DISPATCH, **args), jax.set_mesh(mesh):
             return jitted(params, opt_state, batch, step)
 
     def aot_compile(params, opt_state, batch, step=0):
@@ -605,7 +608,10 @@ def make_train_step(
         peak) on runtimes whose allocator exposes no ``memory_stats()``.
         """
         with jax.set_mesh(mesh):
-            return jitted.lower(params, opt_state, batch, step).compile()
+            with scopes.host_span(scopes.STEP_LOWER):
+                lowered = jitted.lower(params, opt_state, batch, step)
+            with scopes.host_span(scopes.STEP_COMPILE):
+                return lowered.compile()
 
     return step_with_mesh, aot_compile
 
@@ -816,14 +822,16 @@ def create_train_state(
         )
     else:
         with mesh:
-            params = jax.jit(
-                init_fn,
-                out_shardings=strat.named(mesh, param_specs),
-            )(jax.random.key(seed))
-            opt_state = jax.jit(
-                optimizer.init,
-                out_shardings=strat.opt_state_shardings(mesh, opt_specs, strategy),
-            )(params)
+            with scopes.host_span(scopes.INIT_PARAMS):
+                params = jax.jit(
+                    init_fn,
+                    out_shardings=strat.named(mesh, param_specs),
+                )(jax.random.key(seed))
+            with scopes.host_span(scopes.INIT_OPT_STATE):
+                opt_state = jax.jit(
+                    optimizer.init,
+                    out_shardings=strat.opt_state_shardings(mesh, opt_specs, strategy),
+                )(params)
 
     step_fn, aot_compile = make_train_step(
         model_config,

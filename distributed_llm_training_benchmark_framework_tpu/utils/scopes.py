@@ -1,11 +1,23 @@
 """The names the model and the train step put into a profile.
 
-Each is a ``jax.named_scope``: it writes a path component into the
-``op_name`` metadata of every HLO instruction traced under it and costs
-nothing at run time. What forms a name takes in ``op_name`` after
+The device's half: each is a ``jax.named_scope``. It writes a path component
+into the ``op_name`` metadata of every HLO instruction traced under it and
+costs nothing at run time. What forms a name takes in ``op_name`` after
 differentiation and remat, and how a trace is read by them, is in
 docs/OBSERVABILITY.md ("Names in a profile").
+
+The host's half, below it: five spans round the work ``train/step.py`` does on
+the host, jax's own compile and cache events, and the collector's pauses, kept
+in the process for whoever asks (``host_records``, ``compile_events``).
+Nothing here prints or writes a file.
 """
+
+import collections
+import gc
+import time
+
+import jax
+
 
 EMBED, ATTENTION, MLP, DROPOUT, HEAD, LOSS, OPTIMIZER = SCOPES = (
     "embed", "attention", "mlp", "dropout", "head", "loss", "optimizer",
@@ -26,3 +38,143 @@ SHARED = "shared"
 # ``_latent_attention``): the projections with the latent's norm and rotary,
 # the attention itself (the flash kernels), the output projection.
 MLA_PROJ, MLA_CORE, MLA_OUT = MLA_SCOPES = ("mla_proj", "mla_core", "mla_out")
+
+
+# ---------------------------------------------------------------------------
+# The host's half: what the Python side of a run was doing, kept in the
+# process. A device name above is metadata in the compiled step; a host name
+# is a span on the host's clock. Both halves are read by name
+# (docs/OBSERVABILITY.md, "Names in a profile", second table).
+# ---------------------------------------------------------------------------
+
+# ``train/step.py`` opens each where the work happens. None may equal a span
+# of whoever drives the step (the benchmark's runner has ``dispatch`` and
+# ``loss_fetch``, found by exact name).
+INIT_PARAMS, INIT_OPT_STATE, STEP_LOWER, STEP_COMPILE, STEP_DISPATCH = HOST_SPANS = (
+    "init_params", "init_opt_state", "step_lower", "step_compile", "step_dispatch",
+)
+GC = "gc"  # a collection of Python's collector, recorded beside the spans
+
+# (perf_counter_ns, time_ns) read together: where the program's part of set-up
+# begins, and what turns a record's times (``perf_counter_ns``: monotonic, the
+# clock of a driver's own ``perf_counter``) into the profiler's (the wall
+# clock), see ``wall_ns``.
+IMPORTED_AT = (time.perf_counter_ns(), time.time_ns())
+
+# What jax reports of a compilation through ``jax.monitoring``, with the
+# function's name: its trace, its lowering, the compiler's run (a cache read
+# included, which the fourth times alone).
+TRACE_EVENT = "/jax/core/compile/jaxpr_trace_duration"
+LOWER_EVENT = "/jax/core/compile/jaxpr_to_mlir_module_duration"
+BACKEND_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+CACHE_READ_EVENT = "/jax/compilation_cache/cache_retrieval_time_sec"
+COMPILE_EVENTS = (TRACE_EVENT, LOWER_EVENT, BACKEND_COMPILE_EVENT, CACHE_READ_EVENT)
+CACHE_HIT_EVENT = "/jax/compilation_cache/cache_hits"
+CACHE_MISS_EVENT = "/jax/compilation_cache/cache_misses"
+
+# Bounded: a long run keeps its newest windows. Collections have a record of
+# their own because tracing a large step makes thousands of them, which would
+# push the set-up's few spans out of a shared one.
+_MAXLEN = 4096
+_spans = collections.deque(maxlen=_MAXLEN)
+_collections = collections.deque(maxlen=_MAXLEN)
+_compile_sums = {}  # (event, fun_name) -> [count, seconds]
+_backend_compiles = collections.deque(maxlen=_MAXLEN)  # (fun_name, end_ns, seconds)
+_jit_busy = collections.deque(maxlen=_MAXLEN)  # [start_ns, end_ns], disjoint, in order
+_cache = {"hits": 0, "misses": collections.deque(maxlen=_MAXLEN)}  # a miss: its time, ns
+_gc_started = [0]
+
+
+def wall_ns(perf_ns):
+    """A record's time on the wall clock, which is the profiler's."""
+    return perf_ns - IMPORTED_AT[0] + IMPORTED_AT[1]
+
+
+class host_span:
+    """``with host_span(name, **args):`` is a ``jax.profiler.TraceAnnotation``
+    (free while no profile is taken) and one ``(name, start_ns, end_ns, args)``
+    in the process's record, on ``time.perf_counter_ns``. It reads two clocks
+    and appends; it never waits for the device."""
+
+    __slots__ = ("name", "args", "annotation", "start")
+
+    def __init__(self, name, **args):
+        self.name, self.args = name, args
+        self.annotation = jax.profiler.TraceAnnotation(name, **args)
+
+    def __enter__(self):
+        self.annotation.__enter__()
+        self.start = time.perf_counter_ns()
+        return self
+
+    def __exit__(self, *exc):
+        end = time.perf_counter_ns()
+        self.annotation.__exit__(*exc)
+        _spans.append((self.name, self.start, end, self.args))
+
+
+def host_records(name=None):
+    """The newest records, oldest first: ``(name, start_ns, end_ns, args)`` of
+    every ``host_span`` and every collection (``"gc"``, with its generation)."""
+    if name == GC:
+        return list(_collections)
+    if name is not None:
+        return [r for r in _spans if r[0] == name]
+    return sorted([*_spans, *_collections], key=lambda r: r[1])
+
+
+def compile_events():
+    """What jax has reported since the import. ``sums``: {(event, fun_name):
+    (count, seconds)} of ``COMPILE_EVENTS``, a function's seconds including
+    those of the functions traced inside it; ``busy``: the disjoint
+    ``(start_ns, end_ns)`` in which any of them ran, each nested event counted
+    once; ``backend_compiles``: ``(fun_name, end_ns, seconds)`` of each run of
+    the compiler or read from the cache; ``cache_hits``; ``cache_misses``:
+    when each was written to the cache, ns."""
+    return {
+        "sums": {key: tuple(value) for key, value in _compile_sums.items()},
+        "busy": [tuple(interval) for interval in _jit_busy],
+        "backend_compiles": list(_backend_compiles),
+        "cache_hits": _cache["hits"],
+        "cache_misses": list(_cache["misses"]),
+    }
+
+
+def _on_duration(event, seconds, fun_name="", **_):
+    if event not in COMPILE_EVENTS:
+        return
+    end = time.perf_counter_ns()
+    start = end - int(seconds * 1e9)
+    total = _compile_sums.setdefault((event, fun_name), [0, 0.0])
+    total[0] += 1
+    total[1] += seconds
+    if event == BACKEND_COMPILE_EVENT:
+        _backend_compiles.append((fun_name, end, seconds))
+    # An event is reported when it ends, after everything nested in it.
+    while _jit_busy and _jit_busy[-1][0] >= start:
+        _jit_busy.pop()
+    if _jit_busy and _jit_busy[-1][1] > start:
+        _jit_busy[-1][1] = end
+    else:
+        _jit_busy.append([start, end])
+
+
+def _on_event(event, **_):
+    if event == CACHE_HIT_EVENT:
+        _cache["hits"] += 1
+    elif event == CACHE_MISS_EVENT:
+        _cache["misses"].append(time.perf_counter_ns())
+
+
+def _on_collection(phase, info):
+    if phase == "start":
+        _gc_started[0] = time.perf_counter_ns()
+    else:
+        _collections.append(
+            (GC, _gc_started[0], time.perf_counter_ns(), {"generation": info["generation"]})
+        )
+
+
+jax.monitoring.register_event_duration_secs_listener(_on_duration)
+jax.monitoring.register_event_listener(_on_event)
+gc.callbacks.append(_on_collection)

@@ -176,6 +176,40 @@ def test_step_time_spike_opens_and_resolves(tmp_path):
     assert events[-1]["n_unresolved_anomalies"] == 0
 
 
+@pytest.mark.parametrize("planted", ["dispatch", "wait"])
+def test_step_time_spike_says_where_the_window_went(tmp_path, planted):
+    """The spike event carries the long window's split from the program's own
+    host record: a stall inside the step's call reads in ``dispatch_max_ms``,
+    one after it (the wait for the losses) in ``wait_excess_ms``, and each
+    leaves the other still."""
+    import time
+
+    from distributed_llm_training_benchmark_framework_tpu.utils import scopes
+
+    def window(first, inside=0.0, after=0.0):
+        for step in range(first, first + 2):
+            with scopes.host_span(scopes.STEP_DISPATCH, step=step):
+                time.sleep(inside if step == first else 0.0)
+        time.sleep(after)
+
+    rec = make_recorder(tmp_path)
+    rec.begin_phase("timed")
+    for w in range(4):
+        window(2 * w)
+        rec.step_window(last_step=2 * w + 1, losses=[5.0, 5.0],
+                        window_mean_step_time_sec=0.1)
+    window(8, **{"dispatch": {"inside": 0.05}, "wait": {"after": 0.05}}[planted])
+    rec.step_window(last_step=9, losses=[5.0, 5.0],
+                    window_mean_step_time_sec=1.0)
+    rec.close()
+    (spike,) = [e for e in read(tmp_path) if e["event"] == "anomaly"]
+    split = spike["host_split"]
+    long, still = (("dispatch_max_ms", "wait_excess_ms") if planted == "dispatch"
+                   else ("wait_excess_ms", "dispatch_max_ms"))
+    assert split[long] >= 50 and abs(split[still]) < 25
+    assert split["gc_max_ms"] < 25
+
+
 def test_sustained_slowdown_rebaselines_instead_of_staying_open(tmp_path):
     """A spike that persists becomes the new baseline: a thermally
     throttled (but completed) run must not be rejected by the validator
