@@ -25,6 +25,7 @@ import jax
 import jax.numpy as jnp
 import optax
 from jax import lax
+from jax.experimental.layout import Layout, with_layout_constraint
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..models import tinygpt
@@ -344,6 +345,32 @@ def make_param_norm_fn(mesh: Mesh) -> Callable:
     return checksum
 
 
+def _in_the_layouts_the_state_lives_in(grads, shardings):
+    """``grads`` with every matrix and stack of matrices held to the device
+    layout its parameter and moments live in: the default one for its shard's
+    shape, as the device's client gives it.
+
+    Left to itself the compiler runs AdamW in the layout the gradient's matmul
+    writes, and where the state lives in another one it copies the new
+    parameter and both moments back through a relayout at the step's boundary,
+    every step: ``wgu`` (L, D, 2, F) lives in tiles of two rows over (2, F)
+    and its gradient comes out in tiles over (D, F). Pinned here, the update
+    runs in the layout the state lives in, and only the gradient crosses.
+    Where the two agree already the compiled step is the one it was. A layout
+    is not a value: the program's arithmetic is unchanged, and the compiler's
+    agrees to rounding (it fuses on either side of a pin).
+    """
+    def pin(grad, sharding):
+        if grad.ndim < 2:
+            return grad
+        device = sharding.mesh.devices.flat[0]
+        default = device.client.get_default_layout(
+            grad.dtype, sharding.shard_shape(grad.shape), device)
+        return with_layout_constraint(grad, Layout.from_pjrt_layout(default))
+
+    return jax.tree.map(pin, grads, shardings)
+
+
 def make_train_step(
     model_config: tinygpt.TinyGPTConfig,
     strategy: strat.StrategyConfig,
@@ -552,6 +579,9 @@ def make_train_step(
                 return new_params, new_opt_state, loss, gnorm
             return new_params, new_opt_state, loss
 
+        # (after the offloaded step's return: its moments live on the host)
+        grads = _in_the_layouts_the_state_lives_in(grads, strat.named(
+            mesh, grad_sharded_specs if strategy.shard_grads else param_specs))
         with jax.named_scope(scopes.OPTIMIZER):
             updates, new_opt_state = optimizer.update(grads, opt_state, params)
 

@@ -239,33 +239,40 @@ def test_flash_partitions_over_a_four_device_data_mesh(v5e_devices):
 
 # --- the sharded-parameter step's MLP at Mistral widths (about 10 s a compile) ---
 
-def _fsdp_collectives_tool():
+def _script(name):
     import importlib.util
 
     path = os.path.join(
         os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
-        "scripts", "fsdp_collectives.py",
+        "scripts", name + ".py",
     )
-    spec = importlib.util.spec_from_file_location("fsdp_collectives", path)
+    spec = importlib.util.spec_from_file_location(name, path)
     module = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(module)
     return module
 
 
-_MISTRAL_DP4_STEPS = {}  # (strategy, mlp_act, pinned) -> Compiled: two tests share them
+def _fsdp_collectives_tool():
+    return _script("fsdp_collectives")
 
 
-def _mistral_dp4_step(v5e_devices, monkeypatch, strategy, mlp_act, pinned=True):
+_MISTRAL_DP4_STEPS = {}  # (strategy, mlp_act, pinned, chips, pinned_grads) -> Compiled: tests share them
+
+
+def _mistral_dp4_step(v5e_devices, monkeypatch, strategy, mlp_act, pinned=True, chips=4,
+                      pinned_grads=True):
     """The fsdp4 cell's step (one 4096-token sequence a chip, flash, unrolled,
     remat dots) at Mistral-7B's widths and depth 1, compiled for the
-    described 2x2; ``pinned=False`` leaves the MLP's layout to propagation."""
+    described 2x2; ``pinned=False`` leaves the MLP's layout to propagation,
+    ``chips=1`` is one chip of it, ``pinned_grads=False`` leaves the gradients'
+    device layouts to the compiler."""
     import dataclasses
 
     from distributed_llm_training_benchmark_framework_tpu.models.tinygpt import TinyGPTConfig
     from distributed_llm_training_benchmark_framework_tpu.parallel import get_strategy, make_mesh
     from distributed_llm_training_benchmark_framework_tpu.train import step as step_mod
 
-    key = (strategy, mlp_act, pinned)
+    key = (strategy, mlp_act, pinned, chips, pinned_grads)
     if key not in _MISTRAL_DP4_STEPS:
         config = TinyGPTConfig(
             vocab_size=32768, n_embd=4096, n_head=32, n_kv_head=8, n_layer=1,
@@ -274,16 +281,19 @@ def _mistral_dp4_step(v5e_devices, monkeypatch, strategy, mlp_act, pinned=True):
             rope_theta=1e6, mlp_act=mlp_act, mlp_hidden=14336, bias=False,
             tie_embeddings=False,
         )
-        mesh = make_mesh((4,), ("data",), devices=v5e_devices[:4])
+        mesh = make_mesh((chips,), ("data",), devices=v5e_devices[:chips])
         with monkeypatch.context() as patch:
             # The program asks jax.default_backend() to choose kernel vs
             # interpret mode; the compile target is the described chip.
             patch.setattr(jax, "default_backend", lambda: "tpu")
             if not pinned:
                 patch.setattr(step_mod, "mlp_hidden_spec", lambda *a, **k: None)
+            if not pinned_grads:
+                patch.setattr(step_mod, "_in_the_layouts_the_state_lives_in",
+                              lambda grads, shardings: grads)
             _MISTRAL_DP4_STEPS[key] = step_mod.abstract_compile_step(
                 config, dataclasses.replace(get_strategy(strategy), remat="dots"), mesh,
-                grad_accum=1, global_micro=4, seq_len=4096, dataset_size=1000,
+                grad_accum=1, global_micro=chips, seq_len=4096, dataset_size=1000,
             )
     return _MISTRAL_DP4_STEPS[key]
 
@@ -321,6 +331,61 @@ def test_sharded_param_mlp_moves_no_weight_and_no_all_to_all(
     assert peaks[0] <= peaks[1] + MLP_PIN_PEAK_ALLOWANCE, peaks
     print(f"{strategy}/{mlp_act}: peak {peaks[0] / 1e9:.2f} GB pinned, "
           f"{peaks[1] / 1e9:.2f} unpinned; {len(crossings)} all-to-alls unpinned")
+
+
+@pytest.mark.parametrize("strategy, chips", [("fsdp", 4), ("zero3", 4), ("zero2", 1)])
+def test_no_entry_copy_has_a_state_leafs_shape(v5e_devices, monkeypatch, strategy, chips):
+    """AdamW runs in the layout the parameters and moments live in
+    (``train/step.py``: the gradients are pinned to it): the compiler makes no
+    copy of a state leaf's shape at the step's boundary, and the state is
+    where it was: ``wgu`` (its second-to-last axis has size 2) in the default
+    layout's tiles of two rows."""
+    tool = _script("state_copies")
+    compiled = _mistral_dp4_step(v5e_devices, monkeypatch, strategy, "swiglu", chips=chips)
+    leaves = tool.state_leaves(compiled)
+    same, _ = tool.state_shaped(tool.entry_copies(compiled.as_text()), leaves)
+    assert not same, same
+    assert compiled.input_formats[0][:2] == tuple(compiled.output_formats[:2])
+    wgu = {(layout.major_to_minor, layout.tiling) for path, _, dims, layout in leaves
+           if path.endswith("['wgu']")}
+    assert wgu == {((0, 1, 2, 3), ((2, 128),))}, wgu
+
+
+def test_unpinned_gradients_copy_the_state_at_the_boundary(v5e_devices, monkeypatch):
+    """The control: its gradients' layouts left to the compiler, the same
+    one-chip step runs AdamW in the layout the matmul writes and copies the new
+    ``wgu`` and ``wkv`` and their two moments back through a relayout, so the
+    case above can fail."""
+    tool = _script("state_copies")
+    compiled = _mistral_dp4_step(v5e_devices, monkeypatch, "zero2", "swiglu", chips=1,
+                                 pinned_grads=False)
+    same, _ = tool.state_shaped(
+        tool.entry_copies(compiled.as_text()), tool.state_leaves(compiled))
+    assert {c.dims for c in same} == {(1, 4096, 2, 14336), (1, 4096, 2, 1024)}, same
+    assert len(same) == 6 and not any(c.named for c in same)
+
+
+def test_entry_copies_reads_the_entry_computation_only():
+    tool = _script("state_copies")
+    text = """
+%fused (p: f32[4,8]) -> f32[4,8] {
+  %copy.9 = f32[4,8]{0,1:T(8,128)} copy(%p)
+}
+
+ENTRY %main (a: f32[2,4,2,8]) -> f32[2,4,2,8] {
+  %a = f32[2,4,2,8]{3,1,2,0:T(8,128)} parameter(0)
+  %copy.1 = f32[2,4,2,8]{3,2,1,0:T(2,128)} copy(%a), backend_config={"estimated_cycles":"1200"}
+  %copy.2 = bf16[2,4,2,8]{3,1,2,0:T(8,128)(2,1)} copy(%x), metadata={op_name="jit(f)/convert"}
+  ROOT %copy.3 = s32[] copy(%s)
+}
+"""
+    rows = tool.entry_copies(text)
+    assert [(r.dtype, r.dims, r.nbytes, r.cycles, r.named) for r in rows] == [
+        ("f32", (2, 4, 2, 8), 512, 1200, False), ("bf16", (2, 4, 2, 8), 256, 0, True),
+        ("s32", (), 4, 0, False)]
+    leaves = [("params['w']", "float32", (2, 4, 2, 8), None), ("params['b']", "float32", (8,), None)]
+    same, cast = tool.state_shaped(rows, leaves)
+    assert [r.dims for r in same] == [(2, 4, 2, 8)] and [r.dtype for r in cast] == ["bf16"]
 
 
 @pytest.mark.parametrize("strategy", ["fsdp", "zero3"])
