@@ -115,6 +115,24 @@ class YarnScaling:
 
 
 @dataclasses.dataclass(frozen=True)
+class BlockDiffusionObjective:
+    """Block-diffusion training (BD3-LM, arXiv:2503.09573; the SDAR recipe): a
+    document of L tokens is cut into blocks of ``block`` tokens, each block b
+    draws t_b ~ U[t_min, t_max] and each of its tokens is replaced by
+    ``mask_id`` with probability t_b (the linear schedule). The model runs
+    once over the stream [noisy copy ; clean copy] of 2L tokens, both copies at
+    positions 0..L-1, under ``ops.flash_attention.BlockDiffusion``'s mask, and
+    the loss is (1/L) sum over masked i of CE(logits_noisy[i], x[i]) / t_blk(i):
+    a masked position predicts its own token (no shift), and only the noisy
+    copy goes through the head."""
+
+    block: int
+    mask_id: int
+    t_min: float = 1e-3
+    t_max: float = 1.0
+
+
+@dataclasses.dataclass(frozen=True)
 class TinyGPTConfig:
     vocab_size: int = 32000
     n_embd: int = 768
@@ -216,10 +234,16 @@ class TinyGPTConfig:
     # projection splits into separate wq/wkv leaves (the fused wqkv layout
     # only exists for the square MHA case).
     n_kv_head: Optional[int] = None
-    # QK-norm (OLMoE): an RMSNorm with its own learned scale over the whole
-    # projected q vector and over the whole projected k vector, before the
-    # split into heads and before rope. Leaves q_norm / k_norm.
-    qk_norm: bool = False
+    # Width of one head where it is not n_embd / n_head (Qwen3-MoE, SDAR: 32
+    # heads of 128 over a hidden size of 2048, so q and the attention's output
+    # are n_head * head_width = 4096 wide). Split projections only (n_kv_head).
+    head_width: Optional[int] = None
+    # QK-norm, leaves q_norm / k_norm, before rope. True (OLMoE): an RMSNorm
+    # with its own learned scale over the whole projected q vector and over
+    # the whole projected k vector, before the split into heads. "head"
+    # (Qwen3-MoE, SDAR): over each head's head_dim, after the split, one
+    # (head_dim,) scale for q's heads and one for k's.
+    qk_norm: Any = False
     # Latent attention (MLA, DeepSeek-V2): set ``kv_lora_rank`` and the three
     # head widths. q is projected whole to heads of qk_nope + qk_rope; the
     # input is projected down to kv_lora_rank + qk_rope, the first part
@@ -260,6 +284,13 @@ class TinyGPTConfig:
     # Load-balance term per sequence and averaged over sequences (DeepSeek
     # seq_aux), not over the whole batch.
     seq_aux: bool = False
+    # The training objective where it is not cross-entropy at every position:
+    # the stream, the mask rule and the loss of BlockDiffusionObjective. The
+    # batch still holds clean documents of block_size tokens at most; forward
+    # builds the stream of twice that from its key (its dropout_key, which the
+    # train step folds from seed, step and micro-batch), and the step returns
+    # the masked-token count after its loss (``step_report``).
+    block_diffusion: Optional[BlockDiffusionObjective] = None
     # Linear/LayerNorm biases (Llama ships none anywhere).
     bias: bool = True
     # Weight-tied LM head (reference train_harness.py:61-62). False adds a
@@ -320,6 +351,8 @@ class TinyGPTConfig:
 
     @property
     def head_dim(self) -> int:
+        if self.head_width is not None:
+            return self.head_width
         assert self.n_embd % self.n_head == 0
         return self.n_embd // self.n_head
 
@@ -353,6 +386,18 @@ class TinyGPTConfig:
         if self.latent_attention and self.rope_scaling is not None:
             return self.qk_dim ** -0.5 * self.rope_scaling.softmax_factor
         return None
+
+    def mask_rule(self, stream_len: int):
+        """The attention mask as ``ops.flash_attention.MaskRule``: ``causal``,
+        or under ``block_diffusion`` its rule over a stream of ``stream_len``
+        = 2L positions."""
+        if self.block_diffusion is None:
+            return self.causal
+        from ..ops.flash_attention import BlockDiffusion
+
+        if stream_len % 2:
+            raise ValueError(f"a block-diffusion stream holds two copies; got {stream_len}")
+        return BlockDiffusion(stream_len // 2, self.block_diffusion.block)
 
     @property
     def aux_shape(self) -> Tuple[int, ...]:
@@ -388,6 +433,13 @@ class TinyGPTConfig:
     def reports_held_overflow(self) -> bool:
         return self.experts_held is not None and self.held_rows_factor is not None
 
+    @property
+    def step_report(self) -> Tuple[str, ...]:
+        """What the train step returns after its loss, one float32 each, summed
+        over layers and micro-batches; empty for a config that reports nothing."""
+        held = ("held_rows", "held_overflow") if self.reports_held_overflow else ()
+        return held + (("masked_tokens",) if self.block_diffusion is not None else ())
+
     def refuse_pipeline(self) -> None:
         """The pipeline schedules slice one homogeneous stack and run the
         sharded attention bodies; they do not slice this."""
@@ -396,6 +448,12 @@ class TinyGPTConfig:
                 "the pipeline schedules take one homogeneous stack of blocks with "
                 f"ordinary attention; got first_k_dense={self.first_k_dense}, "
                 f"kv_lora_rank={self.kv_lora_rank} (latent attention). Run this "
+                "config with pipe=1"
+            )
+        if self.block_diffusion is not None:
+            raise ValueError(
+                "the pipeline schedules run next-token stages; block diffusion "
+                "builds its stream and weighs its loss in forward(). Run this "
                 "config with pipe=1"
             )
 
@@ -468,6 +526,38 @@ class TinyGPTConfig:
                 )
         if self.held_rows_factor is not None and self.experts_held is None:
             raise ValueError("held_rows_factor sizes the buffer of experts_held")
+        if self.head_width is not None and (
+                self.kv_heads == self.n_head or self.latent_attention
+                or self.tp_collective_matmul):
+            raise ValueError(
+                "head_width (heads that are not n_embd / n_head wide) is wired for the "
+                "split q and k/v projections of n_kv_head < n_head, without "
+                "tp_collective_matmul"
+            )
+        if self.qk_norm not in (False, True, "head"):
+            raise ValueError(f"qk_norm must be False|True|'head', got {self.qk_norm!r}")
+        bd = self.block_diffusion
+        if bd is not None:
+            if self.attention_impl not in ("flash", "reference") or (
+                    self.seq_manual_axis is not None):
+                raise ValueError(
+                    "block diffusion runs attention_impl 'flash' or 'reference' on "
+                    "whole streams: ring attention, Ulysses and the sequence-parallel "
+                    "pipeline cut the sequence, and their bodies take causal or no "
+                    f"mask only; got attention_impl={self.attention_impl!r}, "
+                    f"seq_manual_axis={self.seq_manual_axis!r}"
+                )
+            if self.causal or self.pos_embed != "rope":
+                raise ValueError(
+                    "block diffusion brings its own mask rule (causal=False) and "
+                    "rotates both copies at positions 0..L-1 (pos_embed='rope')"
+                )
+            if not (bd.block > 0 and 0 <= bd.mask_id < self.vocab_size
+                    and 0.0 < bd.t_min <= bd.t_max <= 1.0):
+                raise ValueError(
+                    f"{bd}: block > 0, mask_id inside the vocabulary of "
+                    f"{self.vocab_size}, 0 < t_min <= t_max <= 1"
+                )
 
 
 def get_model_config(tier: str, seq_len: int, **overrides) -> TinyGPTConfig:
@@ -537,7 +627,9 @@ PARAM_AXIS_RULES: Dict[str, Tuple[Optional[str], ...]] = {
     # parameters a step, which a (.., 2, F) pair of minor axes costs on a TPU.
     "blocks/moe_wgu": ("layers", "experts", "embed", "gate_up"),
     "blocks/moe_wd": ("layers", "experts", "mlp", "embed"),
-    # QK-norm scales (present when qk_norm): one per projected q / k feature.
+    # QK-norm scales (present when qk_norm): one per projected q / k feature,
+    # or under qk_norm="head" one (head_dim,) vector that every head shares
+    # (no strategy splits it over 'model': parallel/strategies._TP_RULES).
     "blocks/q_norm": ("layers", "heads"),
     "blocks/k_norm": ("layers", "kv_heads"),
     # Latent attention (present instead of wqkv / wkv when kv_lora_rank): wq
@@ -608,7 +700,9 @@ def init_params(config: TinyGPTConfig, key: jax.Array) -> Params:
             if c.bias:
                 blocks["bq"] = zeros((L, H * Dh))
                 blocks["bkv"] = zeros((L, 2, Hkv * Dh))
-        if c.qk_norm:
+        if c.qk_norm == "head":
+            blocks.update(q_norm=ones((L, Dh)), k_norm=ones((L, Dh)))
+        elif c.qk_norm:
             blocks.update(q_norm=ones((L, H * Dh)), k_norm=ones((L, Hkv * Dh)))
         blocks["wo"] = normal(next(k), (L, H * c.v_dim, D))
         if c.bias:
@@ -771,6 +865,7 @@ def _attention(
         dropout_rate=config.dropout if seed is not None else 0.0,
         dropout_seed=seed,
     )
+    rule = config.mask_rule(q.shape[1])
     if config.latent_attention and (
         config.seq_manual_axis is not None
         or config.attention_impl not in ("flash", "reference")
@@ -809,6 +904,7 @@ def _attention(
 
         if config.attn_scale is not None:
             kwargs["scale"] = config.attn_scale
+        kwargs["causal"] = rule
         return flash_attention(q, k, v, **kwargs)
     if config.attention_impl == "ring":
         from ..ops.ring_attention import ring_attention
@@ -824,7 +920,12 @@ def _attention(
     scores = jnp.einsum(
         "bqhd,bkhd->bhqk", q, k, preferred_element_type=jnp.float32
     ) * scale
-    if config.causal:
+    if config.block_diffusion is not None:
+        pos = jnp.arange(q.shape[1], dtype=jnp.int32)
+        scores = jnp.where(
+            rule.allowed(pos[:, None], pos[None, :]), scores, jnp.finfo(jnp.float32).min
+        )
+    elif config.causal:
         s = q.shape[1]
         mask = jnp.tril(jnp.ones((s, s), bool))
         scores = jnp.where(mask, scores, jnp.finfo(jnp.float32).min)
@@ -995,12 +1096,15 @@ def _attention_sublayer(
             q = q + layer["bq"].astype(cd)
             kv = kv + layer["bkv"].astype(cd)
         k, v = kv[:, :, 0], kv[:, :, 1]
-    if c.qk_norm:
+    if c.qk_norm and c.qk_norm != "head":
         q = _rms_norm(q, layer["q_norm"], c.norm_eps)
         k = _rms_norm(k, layer["k_norm"], c.norm_eps)
     q = q.reshape(B, S, c.n_head, c.head_dim)
     k = k.reshape(B, S, c.kv_heads, c.head_dim)
     v = v.reshape(B, S, c.kv_heads, c.head_dim)
+    if c.qk_norm == "head":
+        q = _rms_norm(q, layer["q_norm"], c.norm_eps)
+        k = _rms_norm(k, layer["k_norm"], c.norm_eps)
     if c.pos_embed == "rope":
         # Global token positions; under a sequence-manual pipeline this
         # shard holds positions [shard*S, shard*S + S) (same offset rule as
@@ -1010,6 +1114,8 @@ def _attention_sublayer(
         pos = jnp.arange(S, dtype=jnp.int32)
         if c.seq_manual_axis is not None:
             pos = pos + S * lax.axis_index(c.seq_manual_axis)
+        if c.block_diffusion is not None:
+            pos = pos % (S // 2)  # both copies of the document at 0..L-1
         q = _rope(q, pos, c.rope_theta)
         k = _rope(k, pos, c.rope_theta)
     if c.kv_heads != c.n_head:
@@ -1023,7 +1129,7 @@ def _attention_sublayer(
         k = jnp.repeat(k, rep, axis=2)
         v = jnp.repeat(v, rep, axis=2)
     attn = _attention(c, q, k, v, dropout_key, deterministic)
-    attn = attn.reshape(B, S, D)
+    attn = attn.reshape(B, S, c.n_head * c.head_dim)
     if use_cmm:
         attn = _cm.rs_proj(attn, layer["wo"].astype(cd)).astype(cd)
     else:
@@ -1179,6 +1285,66 @@ def embed(
     if dropout_key is not None and not deterministic:
         x = _dropout(x, c.dropout, dropout_key, deterministic)
     return x
+
+
+def _noise_and_dropout_keys(key: jax.Array) -> Tuple[jax.Array, jax.Array]:
+    """The two halves of the key ``forward`` is given under block diffusion."""
+    noise_key, dropout_key = jax.random.split(key)
+    return noise_key, dropout_key
+
+
+def bd_noise(
+    config: TinyGPTConfig, key: jax.Array, shape: Tuple[int, int]
+) -> Tuple[jax.Array, jax.Array]:
+    """The noise of one (B, L) batch of documents under ``block_diffusion``,
+    from the key ``forward`` is given -> (t (B, L // block) float32, one level
+    a block, uniform on [t_min, t_max]; masked (B, L) bool, each token masked
+    with its block's probability). ``forward`` draws exactly this: a check
+    hands it to a reference that draws nothing."""
+    bd = config.block_diffusion
+    B, L = shape
+    if L % bd.block:
+        raise ValueError(f"documents of {L} tokens are not whole blocks of {bd.block}")
+    t_key, token_key = jax.random.split(_noise_and_dropout_keys(key)[0])
+    t = jax.random.uniform(
+        t_key, (B, L // bd.block), jnp.float32, minval=bd.t_min, maxval=bd.t_max
+    )
+    masked = jax.random.uniform(token_key, (B, L), jnp.float32) < jnp.repeat(t, bd.block, axis=1)
+    return t, masked
+
+
+@jax.named_scope(scopes.EMBED)
+@jax.named_scope(scopes.NOISE)
+def bd_stream(
+    config: TinyGPTConfig, idx: jax.Array, key: jax.Array
+) -> Tuple[jax.Array, jax.Array, jax.Array]:
+    """(B, L) clean documents -> (the (B, 2L) stream [x_t ; x], the (B, L)
+    loss weights 1 / t of the masked positions and 0 elsewhere, the masked
+    positions), with ``bd_noise``'s noise."""
+    bd = config.block_diffusion
+    t, masked = bd_noise(config, key, idx.shape)
+    noisy = jnp.where(masked, jnp.asarray(bd.mask_id, idx.dtype), idx)
+    weights = jnp.where(masked, 1.0 / jnp.repeat(t, bd.block, axis=1), 0.0)
+    return jnp.concatenate([noisy, idx], axis=1), weights, masked
+
+
+def bd_mask_stats(config: TinyGPTConfig, seq_len: int) -> Dict[str, int]:
+    """Counters of one head's attention over documents of ``seq_len`` tokens
+    under ``block_diffusion``, from the mask rule (no array is made): the true
+    pairs, and the tiles the forward and the backward kernel visit of all
+    their tiles, at the tiles ``ops.flash_attention`` picks for the stream."""
+    from ..ops import flash_attention as fa
+
+    rule = config.mask_rule(2 * seq_len)
+    bq, bk, bk_bwd, _ = fa.pick_tiles(
+        2 * seq_len, config.qk_dim, config.compute_dtype, causal=rule)
+    live_fwd, tiles_fwd, pairs = rule.tile_counts(bq, bk)
+    live_bwd, tiles_bwd, _ = rule.tile_counts(bq, bk_bwd)
+    return {
+        "true_pairs": pairs,
+        "fwd_live_tiles": live_fwd, "fwd_tiles": tiles_fwd, "fwd_tile_pairs": bq * bk,
+        "bwd_live_tiles": live_bwd, "bwd_tiles": tiles_bwd, "bwd_tile_pairs": bq * bk_bwd,
+    }
 
 
 def apply_blocks(
@@ -1353,29 +1519,45 @@ def apply_layers(
 
 
 def _forward(c, params, idx, targets, dropout_key, deterministic):
-    """-> (logits, loss or None, (2,) held rows and overflow or None)."""
+    """-> (logits, loss or None, the float32 vector of ``c.step_report`` or
+    None). Under ``block_diffusion`` the logits are the noisy copy's, (B, L,
+    V), and the key is needed whatever ``deterministic`` says of dropout."""
     S = idx.shape[1]
     if S > c.block_size:
         raise ValueError(f"Sequence {S} exceeds block size {c.block_size}")
+    report = []
+    diffusion = c.block_diffusion is not None
+    if diffusion:
+        if dropout_key is None:
+            raise ValueError("block diffusion draws its noise from forward's dropout_key")
+        idx, weights, masked = bd_stream(c, idx, dropout_key)
+        dropout_key = _noise_and_dropout_keys(dropout_key)[1]
     if dropout_key is not None and not deterministic:
         emb_key, scan_key = jax.random.split(dropout_key)
     else:
         emb_key = scan_key = None
     x = embed(c, params, idx, emb_key, deterministic)
     x, aux = apply_layers(c, params, x, scan_key, deterministic)
+    if diffusion:
+        x = x[:, :S]  # only the noisy copy goes through the head
     logits = head(c, params, x)
 
-    overflow = None
     if c.reports_held_overflow:
-        aux, overflow = aux[0], aux[1:]
+        aux, held = aux[0], aux[1:]
+        report.append(held)
     loss = None
     if targets is not None:
-        loss = _cross_entropy(logits, targets)
+        if diffusion:
+            loss = _weighted_cross_entropy(logits, targets, weights)
+        else:
+            loss = _cross_entropy(logits, targets)
         if c.n_experts > 0:
             # Mean aux per routed layer: the load-balance term (and, dropless,
             # the z-loss riding it in units of router_aux_coef).
             loss = loss + c.router_aux_coef * aux / c.n_moe_layers
-    return logits, loss, overflow
+    if diffusion:
+        report.append(jnp.sum(masked, dtype=jnp.float32)[None])
+    return logits, loss, jnp.concatenate(report) if report else None
 
 
 def moe_overflow_fraction(
@@ -1425,20 +1607,39 @@ def moe_routing_rows(config: TinyGPTConfig, params: Params, idx: jax.Array):
     ``moe_held_rows`` or None without ``experts_held``)."""
     from .moe import routing_rows
 
+    found = _walk_routers(config, params, idx, routing_rows)
+    counts, held = zip(*found)
+    return jnp.stack(counts), (None if config.experts_held is None else jnp.stack(held))
+
+
+def _walk_routers(config: TinyGPTConfig, params: Params, idx: jax.Array, read):
+    """``read(config, layer, the routed MLP's normed input)`` at every routed
+    layer of a dropout-free forward over ``idx`` (under block diffusion: the
+    stream)."""
     c = dataclasses.replace(config, dropout=0.0)
     x = embed(c, params, idx, None, True)
     if c.first_k_dense:
         x, _ = apply_blocks(c, params["dense_blocks"], x, None, True)
-    counts, held = [], []
+    found = []
     for i in range(c.n_layer - c.first_k_dense):
         layer = jax.tree_util.tree_map(lambda t: t[i], params["blocks"])
         x = _attention_sublayer(c, x, layer, None, True)
-        h = _norm(c, x, layer["ln2_scale"], layer.get("ln2_bias"))
-        count, rows = routing_rows(c, layer, h)
-        counts.append(count)
-        held.append(rows)
+        found.append(read(c, layer, _norm(c, x, layer["ln2_scale"], layer.get("ln2_bias"))))
         x, _ = _mlp_sublayer(c, x, layer, None, True)
-    return jnp.stack(counts), (None if c.experts_held is None else jnp.stack(held))
+    return found
+
+
+def _token_nll(logits: jax.Array, targets: jax.Array) -> Tuple[jax.Array, jax.Array]:
+    """(nll (N,) float32, 0 where target == -1; valid (N,)) over the flattened
+    positions."""
+    V = logits.shape[-1]
+    logits = logits.reshape(-1, V).astype(jnp.float32)
+    targets = targets.reshape(-1)
+    valid = targets != -1
+    safe = jnp.where(valid, targets, 0)
+    logz = jax.nn.logsumexp(logits, axis=-1)
+    gold = jnp.take_along_axis(logits, safe[:, None], axis=-1)[:, 0]
+    return jnp.where(valid, logz - gold, 0.0), valid
 
 
 @jax.named_scope(scopes.LOSS)
@@ -1448,15 +1649,19 @@ def _cross_entropy_parts(
     """(nll_sum, valid_count) over positions where target != -1 — the
     unreduced halves of the mean CE, so sequence-parallel callers can psum
     both across shards before dividing."""
-    V = logits.shape[-1]
-    logits = logits.reshape(-1, V).astype(jnp.float32)
-    targets = targets.reshape(-1)
-    valid = targets != -1
-    safe = jnp.where(valid, targets, 0)
-    logz = jax.nn.logsumexp(logits, axis=-1)
-    gold = jnp.take_along_axis(logits, safe[:, None], axis=-1)[:, 0]
-    nll = jnp.where(valid, logz - gold, 0.0)
+    nll, valid = _token_nll(logits, targets)
     return nll.sum(), valid.sum()
+
+
+@jax.named_scope(scopes.LOSS)
+def _weighted_cross_entropy(
+    logits: jax.Array, targets: jax.Array, weights: jax.Array
+) -> jax.Array:
+    """sum of weights x CE over all positions (weight 0: not counted; target
+    -1: ignored), over the number of positions: block diffusion's (1 / L) sum
+    over the masked tokens of CE / t, averaged over the batch's documents."""
+    nll, _ = _token_nll(logits, targets)
+    return jnp.sum(nll * weights.reshape(-1)) / weights.size
 
 
 def _cross_entropy(
@@ -1489,7 +1694,7 @@ def loss_fn(
     return loss
 
 
-def loss_and_held_fn(
+def loss_and_report_fn(
     config: TinyGPTConfig,
     params: Params,
     batch: jax.Array,
@@ -1497,9 +1702,9 @@ def loss_and_held_fn(
     dropout_key: Optional[jax.Array] = None,
     deterministic: bool = True,
 ) -> Tuple[jax.Array, jax.Array]:
-    """``loss_fn`` for a config whose held experts' buffer is bounded
-    (``reports_held_overflow``): (loss, (2,) float32: the rows the buffers
-    took and the held assignments that did not fit, summed over layers), for
+    """``loss_fn`` for a config that reports (``step_report``): (loss, float32
+    vector: the rows the held experts' buffers took and the held assignments
+    that did not fit, summed over layers; the masked tokens), for
     ``jax.value_and_grad(has_aux=True)``."""
-    _, loss, held = _forward(config, params, batch, targets, dropout_key, deterministic)
-    return loss, held
+    _, loss, report = _forward(config, params, batch, targets, dropout_key, deterministic)
+    return loss, report

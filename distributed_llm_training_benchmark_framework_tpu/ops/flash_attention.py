@@ -17,7 +17,9 @@ Forward (Pallas kernel):
   on: the body walks its keys in pieces of 128, held k-major, each piece's
   QK^T issued under the piece before's softmax (``_flash_fwd_kernel``);
 - also emits the per-row logsumexp, the residual the backward pass needs;
-- ``causal=True`` masks by global position and skips fully-masked k blocks.
+- the mask is a *rule* (``MaskRule``): none, causal, or block diffusion over a
+  stream of a noisy and a clean copy; inside a live tile it is computed from
+  global positions, and tiles the rule leaves no pair in are skipped.
 
 Backward (custom VJP): recomputes attention probabilities tile by tile from
 the saved logsumexp — the standard flash backward — with two implementations
@@ -45,9 +47,10 @@ heads split over the axes that shard them (see ``_kernel_mesh_axes``). The dropo
 
 from __future__ import annotations
 
+import dataclasses
 import functools
 import math
-from typing import Optional, Tuple
+from typing import Optional, Tuple, Union
 
 import jax
 import jax.numpy as jnp
@@ -59,6 +62,118 @@ from jax.experimental.pallas import tpu as pltpu
 from jax.sharding import PartitionSpec as P
 
 NEG_INF = -1e30
+
+
+@dataclasses.dataclass(frozen=True)
+class BlockDiffusion:
+    """The mask of block-diffusion training (BD3-LM, SDAR) over the stream
+    ``[x_t ; x]``: positions 0..L-1 the noisy copy of a document of
+    ``seq_len`` = L tokens, L..2L-1 the clean one, both cut into blocks of
+    ``block`` tokens. Query i may see key j (blk = position in its copy //
+    block):
+
+      noisy -> noisy   iff blk(i) == blk(j)    (its own block, both ways)
+      noisy -> clean   iff blk(j) <  blk(i)    (the clean past)
+      clean -> clean   iff blk(j) <= blk(i)    (block-causal)
+      clean -> noisy   never
+
+    Every query has a live key, itself, which is what keeps a row's
+    logsumexp finite (the fused backward leans on it); ``__post_init__``
+    holds the two sizes to what makes that so. A query's *first* live key may
+    lie anywhere: a noisy query's own block sits in the middle of a tile
+    (``first_piece_live`` is False, and the forward kernel floors its running
+    maximum). True pairs a head: L^2 + L * block.
+    """
+
+    seq_len: int
+    block: int
+
+    def __post_init__(self):
+        if not (0 < self.block <= self.seq_len and self.seq_len % self.block == 0):
+            raise ValueError(
+                f"block diffusion cuts a document of seq_len={self.seq_len} into "
+                f"whole blocks of block={self.block} tokens"
+            )
+
+    def _blk(self, stream_pos):
+        """Block number of a stream position inside its own copy."""
+        L, B = self.seq_len, self.block
+        if not isinstance(stream_pos, jax.Array):  # the host's counts (numpy)
+            return (stream_pos - L * (stream_pos >= L)) // B
+        local = jnp.where(stream_pos >= L, stream_pos - L, stream_pos)
+        if B & (B - 1) == 0:
+            return lax.shift_right_logical(local, jnp.int32(B.bit_length() - 1))
+        return lax.div(local, jnp.int32(B))
+
+    def allowed(self, rows, cols):
+        """The rule itself on broadcastable int32 stream positions, any
+        mixture of the two copies: the ``jnp`` references' mask."""
+        L = self.seq_len
+        r_blk, c_blk = self._blk(rows), self._blk(cols)
+        return jnp.where(
+            cols < L, (rows < L) & (r_blk == c_blk),
+            jnp.where(rows < L, c_blk < r_blk, c_blk <= r_blk),
+        )
+
+    def tile_live(self, q_off, bq, k_off, bk):
+        """Whether the (bq, bk) tile at (q_off, k_off) holds an allowed pair.
+        Tiles lie inside one copy each (``check_tiles``). Scalars of a kernel's
+        grid, or numpy arrays of offsets (``tile_counts``)."""
+        L = self.seq_len
+        q_noisy, k_noisy = q_off < L, k_off < L
+        q_lo, q_hi = self._blk(q_off), self._blk(q_off + (bq - 1))
+        k_lo, k_hi = self._blk(k_off), self._blk(k_off + (bk - 1))
+        same_block = (q_lo <= k_hi) & (k_lo <= q_hi)
+        return ((q_noisy & k_noisy & same_block) | (q_noisy & ~k_noisy & (k_lo < q_hi))
+                | (~q_noisy & ~k_noisy & (k_lo <= q_hi)))
+
+    def in_tile(self, q_off, k_off, rows, cols):
+        """The rule inside one live tile, whose queries lie in one copy and
+        whose keys lie in one copy: one subtract and one unsigned compare a
+        score on the narrow operands' block numbers, d = blk(row) - blk(col)
+        allowed iff 0 <= d - shift <= width, with (shift, width) = (0, 0)
+        noisy -> noisy, (1, all) noisy -> clean, (0, all) clean -> clean. A
+        clean -> noisy tile is never live."""
+        L = self.seq_len
+        q_noisy, k_noisy = q_off < L, k_off < L
+        shift = (q_noisy & ~k_noisy).astype(jnp.int32)
+        width = jnp.where(k_noisy, jnp.uint32(0), jnp.uint32(0x7FFFFFFF))
+        d = (self._blk(rows) - shift) - self._blk(cols)
+        return lax.bitcast_convert_type(d, jnp.uint32) <= width
+
+    def check_tiles(self, S: int, *tiles: int) -> None:
+        if S != 2 * self.seq_len or any(self.seq_len % t for t in tiles):
+            raise ValueError(
+                f"block diffusion over seq_len={self.seq_len} runs on a stream of "
+                f"{2 * self.seq_len} positions in tiles that divide {self.seq_len}; "
+                f"got S={S}, tiles {tiles}"
+            )
+
+    def tile_counts(self, bq: int, bk: int) -> Tuple[int, int, int]:
+        """(live tiles, all tiles, true pairs) of one head's (2L, 2L) scores
+        at (bq, bk) tiles: what a kernel visits, and what the rule needs."""
+        S, L, B = 2 * self.seq_len, self.seq_len, self.block
+        self.check_tiles(S, bq, bk)
+        q_off = np.arange(0, S, bq, dtype=np.int64)[:, None]
+        k_off = np.arange(0, S, bk, dtype=np.int64)[None, :]
+        live = int(np.sum(self.tile_live(q_off, bq, k_off, bk)))
+        return live, (S // bq) * (S // bk), L * L + L * B
+
+
+#: The rule a kernel masks by: ``False`` every pair, ``True`` causal (query i
+#: sees keys j <= i), or a ``BlockDiffusion``.
+MaskRule = Union[bool, BlockDiffusion]
+
+
+def first_piece_live(mask: MaskRule) -> bool:
+    """Whether every query has a live key in the first compute piece the
+    forward kernel visits for it: no mask and causal do (plain flash's tile i
+    starts at row i*b and its keys at 0: key 0 is live for every query); block
+    diffusion does not. Where it holds, the running maximum is finite before
+    any masked score is exponentiated, and exp2(NEG_INF * c - m) is exactly 0
+    with no second select on p; where it does not, the kernel floors the
+    maximum it subtracts (``_flash_fwd_kernel``)."""
+    return not isinstance(mask, BlockDiffusion)
 
 
 def _mix32(x: jax.Array) -> jax.Array:
@@ -223,7 +338,7 @@ def _fwd_sub_k(bk: int) -> int:
 def _flash_fwd_kernel(
     seed_ref, bhv_ref, q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr,
     acc_scr,
-    *, bq: int, bk: int, sub_k: int, scale: float, causal: bool,
+    *, bq: int, bk: int, sub_k: int, scale: float, mask: MaskRule,
     dropout_rate: float,
 ):
     """One grid step brings the operands of a (bq, bk) score tile into VMEM
@@ -255,6 +370,8 @@ def _flash_fwd_kernel(
     nk = pl.num_programs(2)
     c = scale * _LOG2_E
     keep_prob = 1.0 - dropout_rate
+    bd = isinstance(mask, BlockDiffusion)
+    causal = not bd and mask
 
     @pl.when(ki == 0)
     def _init():
@@ -262,9 +379,12 @@ def _flash_fwd_kernel(
         l_scr[:] = jnp.zeros_like(l_scr)
         acc_scr[:] = jnp.zeros_like(acc_scr)
 
-    # With causal masking, k blocks strictly above the diagonal contribute
-    # nothing — skip their compute entirely.
-    live = (not causal) or (ki * bk < (qi + 1) * bq)
+    # Tiles the rule leaves no pair in contribute nothing — skip their compute
+    # entirely: with causal masking the k blocks strictly above the diagonal.
+    if bd:
+        live = mask.tile_live(qi * bq, bq, ki * bk, bk)
+    else:
+        live = (not causal) or (ki * bk < (qi + 1) * bq)
 
     @pl.when(live)
     def _accumulate():
@@ -295,13 +415,26 @@ def _flash_fwd_kernel(
             cols = ki * bk + c0 + lax.broadcasted_iota(
                 jnp.int32, (sub_k, 1), 0
             )
+            # No second mask on p: exp2(NEG_INF * c - m) is exactly 0 once
+            # the maximum it subtracts is finite.
             if causal:
-                # No second mask on p: exp2(NEG_INF * c - m) is exactly 0
-                # once m is finite, and every query's first piece (keys from
-                # 0) has a live key.
+                # ``first_piece_live``: m is finite from a query's first piece.
                 s = jnp.where(rows >= cols, s, NEG_INF)
+            elif bd:
+                s = jnp.where(mask.in_tile(qi * bq, ki * bk, rows, cols), s, NEG_INF)
             m_new = jnp.maximum(m, jnp.max(s, axis=0, keepdims=True) * c)
             alpha = jnp.exp2(m - m_new)  # (1, bq)
+            if not first_piece_live(mask):
+                # A query may meet masked scores before its first live key:
+                # its running maximum is then still a masked score's, and
+                # exp2(s * c - m) would be 1. Subtract a maximum floored half
+                # way to the masked value instead (a row op, not a score op):
+                # masked scores still come out 0, and a row that has met a
+                # live key has a maximum far above the floor. alpha is 0
+                # until then (m starts at NEG_INF, below any masked score's).
+                m_sub = jnp.maximum(m_new, 0.5 * NEG_INF * c)
+            else:
+                m_sub = m_new
             # Attention-probability dropout (parity with the reference
             # model, train_harness.py:114-116): the softmax normalizer l
             # accumulates the UN-dropped p (dropout acts after
@@ -309,13 +442,13 @@ def _flash_fwd_kernel(
             # unnormalized p against the full-l divisor is exact), while the
             # output accumulator sees the dropped p / keep_prob.
             if dropout_rate > 0.0:
-                p = jnp.exp2(s * c - (m_new + math.log2(keep_prob)))
+                p = jnp.exp2(s * c - (m_sub + math.log2(keep_prob)))
                 keep = _mix32(rowbase + cols.astype(jnp.uint32)) < (
                     _dropout_threshold(dropout_rate)
                 )
                 p_acc = jnp.where(keep, p, 0.0)
             else:
-                p = p_acc = jnp.exp2(s * c - m_new)  # (sub_k, bq) fp32
+                p = p_acc = jnp.exp2(s * c - m_sub)  # (sub_k, bq) fp32
             l = alpha * l + jnp.sum(p, axis=0, keepdims=True)
             acc = acc * alpha + lax.dot_general(  # out^T: V^T P
                 v_ref[0, pl.ds(c0, sub_k), :], p_acc.astype(q.dtype),
@@ -352,9 +485,17 @@ def _vma_struct(shape, dtype, *like):
     return jax.ShapeDtypeStruct(shape, dtype)
 
 
+def _dense_mask(mask: MaskRule, rows, cols):
+    """The rule on broadcastable global positions, as the ``jnp`` paths
+    materialize it; None where every pair is allowed."""
+    if isinstance(mask, BlockDiffusion):
+        return mask.allowed(rows, cols)
+    return (rows >= cols) if mask else None
+
+
 def _jnp_reference_forward(
     q: jax.Array, k: jax.Array, v: jax.Array,
-    causal: bool, dropout_rate: float, seed: jax.Array, bhv: jax.Array,
+    mask: MaskRule, dropout_rate: float, seed: jax.Array, bhv: jax.Array,
     scale: Optional[float] = None,
 ) -> Tuple[jax.Array, jax.Array]:
     """Materialized-softmax forward with the kernel's exact mask/accumulation
@@ -371,12 +512,13 @@ def _jnp_reference_forward(
     ) * scale
     rows = lax.broadcasted_iota(jnp.int32, (S, 1), 0)
     cols = lax.broadcasted_iota(jnp.int32, (1, S), 1)
-    if causal:
-        s = jnp.where((rows >= cols)[None], s, NEG_INF)
+    allowed = _dense_mask(mask, rows, cols)
+    if allowed is not None:
+        s = jnp.where(allowed[None], s, NEG_INF)
     m = jnp.max(s, axis=-1, keepdims=True)
     p = jnp.exp(s - m)
-    if causal:
-        p = jnp.where((rows >= cols)[None], p, 0.0)
+    if allowed is not None:
+        p = jnp.where(allowed[None], p, 0.0)
     l = jnp.sum(p, axis=-1, keepdims=True)
     if dropout_rate > 0.0:
         keep = _dropout_keep(
@@ -398,7 +540,7 @@ def _jnp_reference_forward(
 
 def _flash_forward(
     q: jax.Array, k: jax.Array, v: jax.Array,
-    causal: bool, interpret: bool, bq: int, bk: int,
+    mask: MaskRule, interpret: bool, bq: int, bk: int,
     dropout_rate: float, seed: jax.Array, bhv: jax.Array,
     sub_k: Optional[int] = None, scale: Optional[float] = None,
 ) -> Tuple[jax.Array, jax.Array]:
@@ -418,12 +560,12 @@ def _flash_forward(
 
     if interpret and vma_of(q, k, v):
         return _jnp_reference_forward(
-            q, k, v, causal, dropout_rate, seed, bhv, scale
+            q, k, v, mask, dropout_rate, seed, bhv, scale
         )
     out, lse = pl.pallas_call(
         functools.partial(
             _flash_fwd_kernel, bq=bq, bk=bk, sub_k=sub_k, scale=scale,
-            causal=causal, dropout_rate=dropout_rate,
+            mask=mask, dropout_rate=dropout_rate,
         ),
         out_shape=[
             _vma_struct((BH, S, Dv), q.dtype, q, k, v),
@@ -460,9 +602,9 @@ def _flash(
     opts: Tuple, q: jax.Array, k: jax.Array, v: jax.Array, seed: jax.Array,
     bhv: jax.Array,
 ) -> jax.Array:
-    causal, interpret, bq, bk, _, _, rate, scale = opts
+    mask, interpret, bq, bk, _, _, rate, scale = opts
     out, _ = _flash_forward(
-        q, k, v, causal, interpret, bq, bk, rate, seed, bhv, scale=scale
+        q, k, v, mask, interpret, bq, bk, rate, seed, bhv, scale=scale
     )
     return out
 
@@ -477,9 +619,9 @@ FLASH_RESIDUAL_NAMES = ("flash_out", "flash_lse")
 
 
 def _flash_fwd_rule(opts, q, k, v, seed, bhv):
-    causal, interpret, bq, bk, _, _, rate, scale = opts
+    mask, interpret, bq, bk, _, _, rate, scale = opts
     out, lse = _flash_forward(
-        q, k, v, causal, interpret, bq, bk, rate, seed, bhv, scale=scale
+        q, k, v, mask, interpret, bq, bk, rate, seed, bhv, scale=scale
     )
     # The kernel's results feed nothing but the two names (the primal result
     # is the named ``out``), so where a policy saves them the recompute copy
@@ -489,10 +631,22 @@ def _flash_fwd_rule(opts, q, k, v, seed, bhv):
     return out, (q, k, v, out, lse, seed, bhv)
 
 
+def _tile_rule(mask: MaskRule, q_off, bq: int, k_off, bk: int):
+    """The backward kernels' two uses of the rule at the (bq, bk) tile at
+    (q_off, k_off) -> (whether the tile holds an allowed pair: a Python True
+    without a mask; (rows, cols) -> the allowed pairs inside it, or None)."""
+    if isinstance(mask, BlockDiffusion):
+        return (mask.tile_live(q_off, bq, k_off, bk),
+                functools.partial(mask.in_tile, q_off, k_off))
+    if mask:
+        return q_off + bq - 1 >= k_off, lambda rows, cols: rows >= cols
+    return True, lambda rows, cols: None
+
+
 def _bwd_dq_kernel(
     seed_ref, qoff_ref, koff_ref, bhv_ref, q_ref, k_ref, v_ref, do_ref,
     lse_ref, delta_ref, dq_ref, acc,
-    *, bq: int, bk: int, scale: float, causal: bool,
+    *, bq: int, bk: int, scale: float, mask: MaskRule,
     dropout_rate: float,
 ):
     """dq = sum over k blocks of ds @ k, ds = p * (dp - delta) * scale.
@@ -516,7 +670,7 @@ def _bwd_dq_kernel(
     def _init():
         acc[:] = jnp.zeros_like(acc)
 
-    live = True if not causal else (q_off + bq - 1 >= k_off)
+    live, in_tile = _tile_rule(mask, q_off, bq, k_off, bk)
 
     @pl.when(live)
     def _accumulate():
@@ -533,12 +687,12 @@ def _bwd_dq_kernel(
         # hash broadcast (bq,1)x(1,bk); the row-fold mix runs per-row only.
         rows = q_off + lax.broadcasted_iota(jnp.int32, (bq, 1), 0)
         cols = k_off + lax.broadcasted_iota(jnp.int32, (1, bk), 1)
-        if causal:
-            mask = rows >= cols
-            s = jnp.where(mask, s, NEG_INF)
+        allowed = in_tile(rows, cols)
+        if allowed is not None:
+            s = jnp.where(allowed, s, NEG_INF)
         p = jnp.exp(s - lse[:, None])
-        if causal:
-            p = jnp.where(mask, p, 0.0)
+        if allowed is not None:
+            p = jnp.where(allowed, p, 0.0)
         dp = lax.dot_general(
             do, v, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
         )
@@ -561,7 +715,7 @@ def _bwd_dq_kernel(
 def _bwd_dkv_kernel(
     seed_ref, qoff_ref, koff_ref, bhv_ref, q_ref, k_ref, v_ref, do_ref,
     lse_ref, delta_ref, dk_ref, dv_ref, dk_acc, dv_acc,
-    *, bq: int, bk: int, scale: float, causal: bool,
+    *, bq: int, bk: int, scale: float, mask: MaskRule,
     dropout_rate: float,
 ):
     """dk = sum over q blocks of ds^T @ q; dv = sum of (D∘p)^T @ do.
@@ -581,7 +735,7 @@ def _bwd_dkv_kernel(
         dk_acc[:] = jnp.zeros_like(dk_acc)
         dv_acc[:] = jnp.zeros_like(dv_acc)
 
-    live = True if not causal else (q_off + bq - 1 >= k_off)
+    live, in_tile = _tile_rule(mask, q_off, bq, k_off, bk)
 
     @pl.when(live)
     def _accumulate():
@@ -598,12 +752,12 @@ def _bwd_dkv_kernel(
         # hash broadcast (bq,1)x(1,bk); the row-fold mix runs per-row only.
         rows = q_off + lax.broadcasted_iota(jnp.int32, (bq, 1), 0)
         cols = k_off + lax.broadcasted_iota(jnp.int32, (1, bk), 1)
-        if causal:
-            mask = rows >= cols
-            s = jnp.where(mask, s, NEG_INF)
+        allowed = in_tile(rows, cols)
+        if allowed is not None:
+            s = jnp.where(allowed, s, NEG_INF)
         p = jnp.exp(s - lse[:, None])
-        if causal:
-            p = jnp.where(mask, p, 0.0)
+        if allowed is not None:
+            p = jnp.where(allowed, p, 0.0)
         dp = lax.dot_general(
             do, v, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
         )
@@ -632,7 +786,7 @@ def _bwd_dkv_kernel(
         dv_ref[0] = dv_acc[:].astype(dv_ref.dtype)
 
 
-def _jnp_blockwise_bwd(causal, bk, rate, res, do, scale=None):
+def _jnp_blockwise_bwd(mask, bk, rate, res, do, scale=None):
     """Blockwise flash backward as batched einsums over a K-block scan.
 
     Same math as the Pallas kernels, expressed as XLA-fused dense einsums:
@@ -669,12 +823,12 @@ def _jnp_blockwise_bwd(causal, bk, rate, res, do, scale=None):
         ki, k_b, v_b = blk
         cols = ki * bk + jnp.arange(bk)
         s = jnp.einsum("bqd,bkd->bqk", q, k_b, preferred_element_type=f32) * scale
-        if causal:
-            mask = rows[:, None] >= cols[None, :]
-            s = jnp.where(mask[None], s, NEG_INF)
+        allowed = _dense_mask(mask, rows[:, None], cols[None, :]) if mask else None
+        if allowed is not None:
+            s = jnp.where(allowed[None], s, NEG_INF)
         p = jnp.exp(s - lse[:, :, None])  # (BH, S, bk) fp32
-        if causal:
-            p = jnp.where(mask[None], p, 0.0)
+        if allowed is not None:
+            p = jnp.where(allowed[None], p, 0.0)
         if rate > 0.0:
             keep = _dropout_keep(
                 seed[0], bhv[:, None, None], rows[None, :, None],
@@ -721,7 +875,7 @@ def _bwd_sub_q(bq: int, dropout_rate: float) -> int:
 def _bwd_fused_kernel(
     seed_ref, bhv_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     dq_ref, dk_ref, dv_ref, dq_acc, dk_acc, dv_acc,
-    *, bq: int, bk: int, sub_q: int, scale: float, causal: bool,
+    *, bq: int, bk: int, sub_q: int, scale: float, mask: MaskRule,
     dropout_rate: float,
 ):
     """dq, dk and dv from ONE visit of each live (k tile, q tile): s, p, dp,
@@ -750,9 +904,11 @@ def _bwd_fused_kernel(
     dq are written out; dropout's 1 / keep_prob rides in the subtracted row
     (p comes out as p / keep_prob, which is what dv's product wants), and
     ds = p' * (where(keep, dp, 0) - delta * keep_prob), the last factor on
-    the row; no second causal select on p (exp2(NEG_INF * c - lse2) is
-    exactly 0: with tile i at row i*b every query has a live key, so lse is
-    finite); the hash's row half once a piece. Against the kernel pair that
+    the row; no second select on p under a mask (exp2(NEG_INF * c - lse2) is
+    exactly 0 where lse is finite, and it is for every rule: each has a live
+    key for every query, causal its own position with tile i at row i*b,
+    block diffusion by ``BlockDiffusion.__post_init__``); the hash's row half
+    once a piece. Against the kernel pair that
     moves dk and dq by f32 rounding of the folded factors and by bf16
     rounding of ds before ``scale`` instead of after it; the keep mask is
     the same bits."""
@@ -776,7 +932,7 @@ def _bwd_fused_kernel(
     def _init_q():
         dq_acc[q_rows, :] = jnp.zeros((bq, dq_acc.shape[1]), dq_acc.dtype)
 
-    live = True if not causal else (q_off + bq - 1 >= k_off)
+    live, in_tile = _tile_rule(mask, q_off, bq, k_off, bk)
 
     @pl.when(live)
     def _accumulate():
@@ -800,8 +956,9 @@ def _bwd_fused_kernel(
             shift = lse_ref[0, :1, pl.ds(r0, sub_q)] * _LOG2_E  # (1, sub_q)
             delta = delta_ref[0, :1, pl.ds(r0, sub_q)]
             rows = q_off + r0 + lax.broadcasted_iota(jnp.int32, (1, sub_q), 1)
-            if causal:
-                s = jnp.where(rows >= cols, s, NEG_INF)
+            allowed = in_tile(rows, cols)
+            if allowed is not None:
+                s = jnp.where(allowed, s, NEG_INF)
             if dropout_rate > 0.0:
                 # p / keep_prob: dv's operand as it is, and ds's with
                 # keep_prob taken into delta's row.
@@ -861,7 +1018,7 @@ def _fused_fits(S: int, D: int, dtype) -> bool:
 
 
 def _fused_backward(
-    q, k, v, do, lse3, delta3, seed, bhv, causal, rate, bq, bk, interpret,
+    q, k, v, do, lse3, delta3, seed, bhv, mask, rate, bq, bk, interpret,
     scale=None, sub=None,
 ):
     """The one-kernel Pallas backward on (BH, S, D) q and k and (BH, S, Dv)
@@ -879,7 +1036,7 @@ def _fused_backward(
         functools.partial(
             _bwd_fused_kernel, bq=bq, bk=bk,
             sub_q=sub or _bwd_sub_q(bq, rate),
-            scale=_softmax_scale(scale, D), causal=causal, dropout_rate=rate,
+            scale=_softmax_scale(scale, D), mask=mask, dropout_rate=rate,
         ),
         out_shape=[
             _vma_struct((BH, S, D), q.dtype, q, k, v, do),
@@ -908,7 +1065,7 @@ def _fused_backward(
 
 
 def _pair_backward(
-    q, k, v, do, lse3, delta3, seed, bhv, causal, rate, bq, bk, interpret,
+    q, k, v, do, lse3, delta3, seed, bhv, mask, rate, bq, bk, interpret,
     scale=None, *, q_tile_offsets=None, k_tile_offsets=None, out_dtype=None,
 ):
     """The dq and dk+dv kernel pair on (BH, Sq, D) queries, (BH, Sk, D) keys
@@ -942,7 +1099,7 @@ def _pair_backward(
     )
     dq = pl.pallas_call(
         functools.partial(
-            _bwd_dq_kernel, bq=bq, bk=bk, scale=scale, causal=causal,
+            _bwd_dq_kernel, bq=bq, bk=bk, scale=scale, mask=mask,
             dropout_rate=rate,
         ),
         out_shape=_vma_struct((BH, Sq, D), dq_dtype, q, k, v, do),
@@ -967,7 +1124,7 @@ def _pair_backward(
     )
     dk, dv = pl.pallas_call(
         functools.partial(
-            _bwd_dkv_kernel, bq=bq, bk=bk, scale=scale, causal=causal,
+            _bwd_dkv_kernel, bq=bq, bk=bk, scale=scale, mask=mask,
             dropout_rate=rate,
         ),
         out_shape=[
@@ -997,7 +1154,7 @@ def _flash_bwd_rule(opts, res, do):
     selects the XLA-fused blockwise einsum path or the Pallas one: the fused
     kernel, or the dq / dk+dv pair where a whole dq row would not fit VMEM.
     """
-    causal, interpret, bq, bk_fwd, bk, pallas_bwd, rate, scale = opts
+    mask, interpret, bq, bk_fwd, bk, pallas_bwd, rate, scale = opts
     # seed and the bh ids are integral: no tangent.
     int_cts = (
         np.zeros((1,), jax.dtypes.float0),
@@ -1011,7 +1168,7 @@ def _flash_bwd_rule(opts, res, do):
         # operands (manual regions in interpret mode) — take the jnp backward.
         pallas_bwd = False
     if not pallas_bwd:
-        return (*_jnp_blockwise_bwd(causal, bk, rate, res, do, scale), *int_cts)
+        return (*_jnp_blockwise_bwd(mask, bk, rate, res, do, scale), *int_cts)
     q, k, v, out, lse, seed, bhv = res
     BH, S, D = q.shape
 
@@ -1024,7 +1181,7 @@ def _flash_bwd_rule(opts, res, do):
     delta3 = jnp.broadcast_to(delta[:, None, :], (BH, 8, S))
     backward = _fused_backward if _fused_fits(S, D, q.dtype) else _pair_backward
     dq, dk, dv = backward(
-        q, k, v, do, lse3, delta3, seed, bhv, causal, rate, bq, bk, interpret,
+        q, k, v, do, lse3, delta3, seed, bhv, mask, rate, bq, bk, interpret,
         scale,
     )
     return dq, dk, dv, *int_cts
@@ -1084,6 +1241,32 @@ def _kernel_mesh_axes():
     return frozenset(m.axis_names), batch, heads
 
 
+def pick_tiles(
+    S: int, D: int, dtype, interpret: bool = False,
+    pallas_backward: Optional[bool] = None, block_q: Optional[int] = None,
+    block_k: Optional[int] = None, block_k_bwd: Optional[int] = None,
+    causal: MaskRule = False,
+) -> Tuple[int, int, int, bool]:
+    """(block_q, block_k, block_k_bwd, pallas_backward) of one
+    ``flash_attention`` call over S positions at q / k width D: the caller's
+    where given, else the measured defaults (the table above ``_FWD_BLOCK_Q``;
+    the Mosaic path's with ``interpret`` False), which divide S, and under a
+    ``BlockDiffusion`` rule each copy of the document (S / 2)."""
+    if pallas_backward is None:
+        # Auto: the measured S-dependent crossover (_PALLAS_BWD_MIN_SEQ).
+        # Interpret mode keeps the einsum backward — the Pallas bwd kernels
+        # would run under the slow HLO interpreter for no fidelity gain.
+        pallas_backward = (not interpret) and S >= _PALLAS_BWD_MIN_SEQ
+    whole = causal.seq_len if isinstance(causal, BlockDiffusion) else S
+    bq = block_q or _pick_block(whole, _FWD_BLOCK_Q)
+    bk = block_k or _pick_block(whole, _FWD_BLOCK_K)
+    fused = pallas_backward and _fused_fits(S, D, dtype)
+    bk_bwd = block_k_bwd or _pick_block(
+        whole, _FUSED_BWD_BLOCK_K if fused else _BWD_BLOCK_K
+    )
+    return bq, bk, bk_bwd, pallas_backward
+
+
 @functools.partial(
     jax.jit,
     static_argnames=(
@@ -1095,7 +1278,7 @@ def flash_attention(
     q: jax.Array,  # (B, S, H, D)
     k: jax.Array,
     v: jax.Array,  # (B, S, H, Dv); Dv == D everywhere but latent attention
-    causal: bool = False,
+    causal: MaskRule = False,
     interpret: Optional[bool] = None,
     block_q: Optional[int] = None,
     block_k: Optional[int] = None,
@@ -1106,6 +1289,10 @@ def flash_attention(
     scale: Optional[float] = None,
 ) -> jax.Array:
     """Multi-head flash attention over (batch, seq, heads, head_dim) inputs.
+
+    ``causal`` is the mask's rule (``MaskRule``): False none, True causal, or
+    a ``BlockDiffusion`` over a stream of S = 2L positions, whose tiles must
+    lie inside one copy of the document.
 
     q and k share one width, v and the output another (latent attention:
     192-wide keys over 128-wide values, no padding of either); ``scale``
@@ -1129,22 +1316,16 @@ def flash_attention(
     """
     B, S, H, D = q.shape
     interpret = _resolve_interpret(interpret)
-    if pallas_backward is None:
-        # Auto: the measured S-dependent crossover (_PALLAS_BWD_MIN_SEQ).
-        # Interpret mode keeps the einsum backward — the Pallas bwd kernels
-        # would run under the slow HLO interpreter for no fidelity gain.
-        pallas_backward = (not interpret) and S >= _PALLAS_BWD_MIN_SEQ
-    bq = block_q or _pick_block(S, _FWD_BLOCK_Q)
-    bk = block_k or _pick_block(S, _FWD_BLOCK_K)
-    fused = pallas_backward and _fused_fits(S, D, q.dtype)
-    bk_bwd = block_k_bwd or _pick_block(
-        S, _FUSED_BWD_BLOCK_K if fused else _BWD_BLOCK_K
+    bq, bk, bk_bwd, pallas_backward = pick_tiles(
+        S, D, q.dtype, interpret, pallas_backward, block_q, block_k, block_k_bwd, causal
     )
     if S % bq != 0 or S % bk != 0 or S % bk_bwd != 0:
         raise ValueError(
             f"block sizes (block_q={bq}, block_k={bk}, block_k_bwd={bk_bwd}) "
             f"must divide seq_len={S}"
         )
+    if isinstance(causal, BlockDiffusion):
+        causal.check_tiles(S, bq, bk, bk_bwd)
     if dropout_seed is None:
         _warn_seedless_dropout(dropout_rate, "flash_attention")
         dropout_rate = 0.0
@@ -1183,12 +1364,15 @@ def flash_attention(
     )(q, k, v, seed, b_ids, h_ids)
 
 
-def reference_attention(q, k, v, causal: bool = False, scale=None) -> jax.Array:
+def reference_attention(q, k, v, causal: MaskRule = False, scale=None) -> jax.Array:
     """Materialized-softmax attention for correctness comparison (same math
     as models.tinygpt's in-model path, without dropout)."""
     scale = _softmax_scale(scale, q.shape[-1])
     s = jnp.einsum("bqhd,bkhd->bhqk", q, k, preferred_element_type=jnp.float32) * scale
-    if causal:
+    if isinstance(causal, BlockDiffusion):
+        rows = jnp.arange(q.shape[1], dtype=jnp.int32)
+        s = jnp.where(causal.allowed(rows[:, None], rows[None, :]), s, NEG_INF)
+    elif causal:
         S = q.shape[1]
         mask = jnp.tril(jnp.ones((S, S), bool))
         s = jnp.where(mask, s, NEG_INF)

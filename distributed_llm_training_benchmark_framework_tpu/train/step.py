@@ -421,16 +421,21 @@ def make_train_step(
     # (accum, batch, seq): shard the *batch* dim, accum dim is sequential.
     full_batch_spec = P(None, *batch_spec)
 
-    # A bounded buffer for the experts this chip holds: the step returns,
-    # after its loss, (2,) float32: the rows the buffers took and the held
-    # assignments that did not fit, summed over layers and micro-batches; any
-    # other config traces what it always did.
-    reports = cfg.reports_held_overflow
+    # What the config reports (``TinyGPTConfig.step_report``): the step
+    # returns, after its loss, one float32 each, summed over layers and
+    # micro-batches: the rows the held experts' buffers took and the held
+    # assignments that did not fit (a bounded buffer), the masked tokens
+    # (block diffusion, whose noise comes from ``key`` below: folded from
+    # seed, step and micro-batch, as dropout's is); any other config traces
+    # what it always did.
+    reports = len(cfg.step_report)
     if reports and (sentinel or mesh.shape.get("pipe", 1) > 1):
-        raise ValueError("held_rows_factor does not compose with sentinel or pipe > 1")
+        raise ValueError(
+            f"a config that reports {cfg.step_report} does not compose with sentinel "
+            "or pipe > 1")
 
     def micro_loss(params: Params, micro: jax.Array, key: jax.Array) -> jax.Array:
-        return (tinygpt.loss_and_held_fn if reports else tinygpt.loss_fn)(
+        return (tinygpt.loss_and_report_fn if reports else tinygpt.loss_fn)(
             cfg,
             params,
             micro,
@@ -483,7 +488,7 @@ def make_train_step(
         def one_micro(carry, inp):
             loss_acc, grad_acc = carry
             micro, key = inp
-            loss, grads = jax.value_and_grad(micro_loss, has_aux=reports)(params, micro, key)
+            loss, grads = jax.value_and_grad(micro_loss, has_aux=bool(reports))(params, micro, key)
             grad_acc = jax.tree.map(jnp.add, grad_acc, grads)
             return (jax.tree.map(jnp.add, loss_acc, loss), grad_acc), None
 
@@ -516,9 +521,9 @@ def make_train_step(
             )(params)
         elif grad_accum == 1:
             key = jax.random.fold_in(base_key, 0)
-            loss, grads = jax.value_and_grad(micro_loss, has_aux=reports)(params, batch[0], key)
+            loss, grads = jax.value_and_grad(micro_loss, has_aux=bool(reports))(params, batch[0], key)
             if reports:
-                loss, overflow = loss
+                loss, report = loss
         else:
             keys = jax.random.split(base_key, grad_accum)
             # Accumulator dtype follows the parameter dtype (cotangents
@@ -533,11 +538,11 @@ def make_train_step(
             zero = jnp.zeros((), jnp.float32)
             (loss_sum, grads), _ = lax.scan(
                 one_micro,
-                ((zero, jnp.zeros((2,), jnp.float32)) if reports else zero, zero_grads),
+                ((zero, jnp.zeros((reports,), jnp.float32)) if reports else zero, zero_grads),
                 (batch, keys),
             )
             if reports:
-                loss_sum, overflow = loss_sum
+                loss_sum, report = loss_sum
             loss = loss_sum / grad_accum
             grads = jax.tree.map(lambda g: g / grad_accum, grads)
 
@@ -595,7 +600,7 @@ def make_train_step(
         if sentinel:
             return new_params, new_opt_state, loss, gnorm
         if reports:
-            return new_params, new_opt_state, loss, overflow
+            return new_params, new_opt_state, loss, report
         return new_params, new_opt_state, loss
 
     opt_shardings = strat.opt_state_shardings(mesh, opt_specs, strategy)

@@ -74,7 +74,9 @@ def forward_flops_per_token(config) -> float:
     untied alike. Defaults reproduce the original TinyGPT accounting
     exactly (kv=H, F=4D, gelu -> 8*D^2 attention projections + 16*D^2 MLP).
     """
-    if getattr(config, "latent_attention", False) or getattr(config, "first_k_dense", 0):
+    if getattr(config, "latent_attention", False) or getattr(config, "first_k_dense", 0) or (
+            getattr(config, "block_diffusion", None) is not None
+            or getattr(config, "head_width", None) is not None):
         return _deepseek_forward_flops_per_token(config)
     D, L, V, S = config.n_embd, config.n_layer, config.vocab_size, config.block_size
     H = config.n_head
@@ -110,24 +112,37 @@ def _deepseek_forward_flops_per_token(c) -> float:
     dense layers, and routed layers counted by their ACTIVE parameters on
     this chip: the router over all experts, the shared experts, and the
     expert_top_k * held / n_experts routed rows a token the held experts see
-    at uniform routing."""
+    at uniform routing.
+
+    Or a config trained by block diffusion, counted by the DATA token: every
+    layer runs over the stream of two copies (2 x its matmuls a data token), a
+    document of S tokens has S^2 + S * block true pairs a head (S + block keys
+    a data token), and only the noisy copy goes through the head."""
     D, H, S = c.n_embd, c.n_head, c.block_size
     attn_tokens = S / 2 if c.causal else S
+    copies = 1
+    if c.block_diffusion is not None:
+        attn_tokens, copies = S + c.block_diffusion.block, 2
     if c.latent_attention:
         R, Dn, Dr, Dv = c.kv_lora_rank, c.qk_nope_head_dim, c.qk_rope_head_dim, c.v_dim
-        attention = (
-            2 * D * H * (Dn + Dr) + 2 * D * (R + Dr) + 2 * R * H * (Dn + Dv)
-            + 2 * H * Dv * D + 2 * attn_tokens * H * (Dn + Dr + Dv)
-        )
+        projections = (2 * D * H * (Dn + Dr) + 2 * D * (R + Dr) + 2 * R * H * (Dn + Dv)
+                       + 2 * H * Dv * D)
+        scores = 2 * attn_tokens * H * (Dn + Dr + Dv)
     else:
         Dh = c.head_dim
-        attention = 2 * D * (H + 2 * c.kv_heads) * Dh + 2 * H * Dh * D + 4 * attn_tokens * H * Dh
+        projections = 2 * D * (H + 2 * c.kv_heads) * Dh + 2 * H * Dh * D
+        scores = 4 * attn_tokens * H * Dh
     F = c.mlp_dim
-    routed_rows = c.expert_top_k * c.n_experts_held / c.n_experts
-    routed = 2 * D * c.n_experts + 6 * D * F * (routed_rows + c.n_shared_experts)
+    if c.n_experts > 0:
+        routed_rows = c.expert_top_k * c.n_experts_held / c.n_experts
+        mlp = 2 * D * c.n_experts + 6 * D * F * (routed_rows + c.n_shared_experts)
+    else:
+        mlp = (6 if c.mlp_act == "swiglu" else 4) * D * F
     dense = 6 * D * (c.dense_mlp_hidden or 0)
+    routed_layers = c.n_layer - c.first_k_dense
     return float(
-        c.n_layer * attention + c.first_k_dense * dense + c.n_moe_layers * routed
+        c.n_layer * (copies * projections + scores)
+        + copies * (c.first_k_dense * dense + routed_layers * mlp)
         + 2 * D * c.vocab_size
     )
 

@@ -150,6 +150,9 @@ def estimate_hbm(
     # --- analytic activations for one microbatch's fwd+bwd on this chip ---
     B = per_device_batch  # per-data-parallel-shard batch
     S, D, L, H, V = seq_len, cfg.n_embd, cfg.n_layer, cfg.n_head, cfg.vocab_size
+    # Block diffusion runs its layers over the stream of two copies of each
+    # document; the head sees the noisy copy only (the logits below stay S).
+    layer_S = 2 * S if getattr(cfg, "block_diffusion", None) is not None else S
     tp = mesh.shape.get("model", 1)
     pp = mesh.shape.get("pipe", 1)
     cbytes = jnp_itemsize(cfg.compute_dtype)
@@ -160,12 +163,12 @@ def estimate_hbm(
     # activation credit is taken for kv_heads < n_head.
     F = getattr(cfg, "mlp_dim", 4 * D) or 4 * D
     mlp_widths = (2 if getattr(cfg, "mlp_act", "gelu") == "swiglu" else 1) * F / D
-    dense_per_layer = int((10 + mlp_widths) * B * S * D) * cbytes
+    dense_per_layer = int((10 + mlp_widths) * B * layer_S * D) * cbytes
     # Megatron TP shards the head and MLP activations.
     dense_per_layer = dense_per_layer // max(tp, 1)
     if cfg.attention_impl == "reference":
         # scores + probs materialize per head, fp32 softmax: the O(S^2) term.
-        dense_per_layer += 2 * B * (H // max(tp, 1)) * S * S * 4
+        dense_per_layer += 2 * B * (H // max(tp, 1)) * layer_S * layer_S * 4
     layers_here = L // max(pp, 1)
     from ..models.tinygpt import normalize_remat
 
@@ -173,14 +176,14 @@ def estimate_hbm(
     if pol == "full":
         # Only the layer-boundary residual (+grad) survives; one layer's
         # working set is live during its backward recompute.
-        act_b = layers_here * 2 * B * S * D * cbytes + dense_per_layer
+        act_b = layers_here * 2 * B * layer_S * D * cbytes + dense_per_layer
     elif pol == "dots":
         # Matmul outputs are saved (~qkv 3BSD + attn-out BSD + mlp 5BSD +
         # boundary 2BSD ≈ 11·BSD per layer; the attention output is in
         # fact kept under flash too: apply_blocks saves the kernel's two
         # results by name); elementwise intermediates are recomputed
         # within one layer's working set.
-        act_b = layers_here * 11 * B * S * D * cbytes + dense_per_layer
+        act_b = layers_here * 11 * B * layer_S * D * cbytes + dense_per_layer
     else:
         act_b = layers_here * dense_per_layer
     # fp32 logits + cotangent at the LM head.

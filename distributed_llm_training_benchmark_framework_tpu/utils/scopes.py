@@ -39,6 +39,11 @@ SHARED = "shared"
 # the attention itself (the flash kernels), the output projection.
 MLA_PROJ, MLA_CORE, MLA_OUT = MLA_SCOPES = ("mla_proj", "mla_core", "mla_out")
 
+# Inside ``embed``, under block diffusion (models/tinygpt.py ``bd_stream``):
+# drawing a noise level a block, masking, and joining the noisy copy to the
+# clean one.
+NOISE = "noise"
+
 
 # ---------------------------------------------------------------------------
 # The host's half: what the Python side of a run was doing, kept in the

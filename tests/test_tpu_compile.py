@@ -98,6 +98,24 @@ def test_flash_backward_compiles(v5e_devices, width, seq, n_kernels):
     assert ("flash_bwd_fused" in text) == (n_kernels == 2)
 
 
+@pytest.mark.parametrize("block", [4, 24], ids=["shift", "divide"])
+def test_block_diffusion_kernels_compile(v5e_devices, block):
+    """Forward and fused backward under the block-diffusion rule at the SDAR
+    cell's shape (a stream of 2 x 8192, head width 128): the rule's integer
+    work on the narrow operands, a block length that is a power of two (a
+    shift) and one that is not (a divide), and the unsigned compare a score."""
+    one = SingleDeviceSharding(v5e_devices[0])
+    rule = fa.BlockDiffusion(8192 if block == 4 else 6144, block)
+
+    def loss(q, k, v):
+        out = fa.flash_attention(q, k, v, causal=rule, interpret=False)
+        return jnp.sum(out.astype(jnp.float32))
+
+    q, k, v, _ = _qkv(one, one, 1, 2 * rule.seq_len, 4, 128)
+    text = _compile(jax.grad(loss, argnums=(0, 1, 2)), q, k, v)
+    assert "flash_fwd" in text and "flash_bwd_fused" in text
+
+
 def test_fused_backward_compiles_at_its_vmem_cap(v5e_devices):
     """The longest sequence ``_fused_fits`` lets through (the resident dq row
     is what grows with S) compiles under the limit the call asks for."""
