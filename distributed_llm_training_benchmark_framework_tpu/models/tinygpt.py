@@ -1331,19 +1331,24 @@ def bd_stream(
 def bd_mask_stats(config: TinyGPTConfig, seq_len: int) -> Dict[str, int]:
     """Counters of one head's attention over documents of ``seq_len`` tokens
     under ``block_diffusion``, from the mask rule (no array is made): the true
-    pairs, and the tiles the forward and the backward kernel visit of all
-    their tiles, at the tiles ``ops.flash_attention`` picks for the stream."""
+    pairs, and what the forward and the backward kernel visit of all there
+    is, at the tiles and pieces ``ops.flash_attention`` picks for the stream
+    and in the unit each kernel skips by (``visited_units``): the (piece,
+    piece) piece where the rule gives its tiles shapes (``*_live_tiles``
+    pieces visited of ``*_tiles``, ``*_tile_pairs`` pairs a piece), the
+    whole tile where it does not."""
     from ..ops import flash_attention as fa
 
-    rule = config.mask_rule(2 * seq_len)
-    bq, bk, bk_bwd, _ = fa.pick_tiles(
-        2 * seq_len, config.qk_dim, config.compute_dtype, causal=rule)
-    live_fwd, tiles_fwd, pairs = rule.tile_counts(bq, bk)
-    live_bwd, tiles_bwd, _ = rule.tile_counts(bq, bk_bwd)
+    S = 2 * seq_len
+    rule = config.mask_rule(S)
+    bq, bk, bk_bwd, _ = fa.pick_tiles(S, config.qk_dim, config.compute_dtype, causal=rule)
+    live_fwd, all_fwd, unit_fwd = fa.visited_units(rule, S, bq, bk, fa._fwd_sub_k(bk))
+    live_bwd, all_bwd, unit_bwd = fa.visited_units(
+        rule, S, bq, bk_bwd, fa._bwd_sub_q(bq, config.dropout))
     return {
-        "true_pairs": pairs,
-        "fwd_live_tiles": live_fwd, "fwd_tiles": tiles_fwd, "fwd_tile_pairs": bq * bk,
-        "bwd_live_tiles": live_bwd, "bwd_tiles": tiles_bwd, "bwd_tile_pairs": bq * bk_bwd,
+        "true_pairs": rule.tile_counts(bq, bk)[2],
+        "fwd_live_tiles": live_fwd, "fwd_tiles": all_fwd, "fwd_tile_pairs": unit_fwd,
+        "bwd_live_tiles": live_bwd, "bwd_tiles": all_bwd, "bwd_tile_pairs": unit_bwd,
     }
 
 

@@ -19,7 +19,10 @@ Forward (Pallas kernel):
 - also emits the per-row logsumexp, the residual the backward pass needs;
 - the mask is a *rule* (``MaskRule``): none, causal, or block diffusion over a
   stream of a noisy and a clean copy; inside a live tile it is computed from
-  global positions, and tiles the rule leaves no pair in are skipped.
+  global positions, and tiles the rule leaves no pair in are skipped;
+- a live tile has a *shape* (``_tile_shape``): full, or, on the diagonal of
+  square tiles, lower; the forward and the fused backward run a body a shape,
+  and the lower one leaves out the pieces above the piece diagonal.
 
 Backward (custom VJP): recomputes attention probabilities tile by tile from
 the saved logsumexp — the standard flash backward — with two implementations
@@ -95,12 +98,19 @@ class BlockDiffusion:
                 f"whole blocks of block={self.block} tokens"
             )
 
+    def _local(self, stream_pos):
+        """A stream position inside its own copy."""
+        L = self.seq_len
+        if not isinstance(stream_pos, jax.Array):  # the host's counts (numpy)
+            return stream_pos - L * (stream_pos >= L)
+        return lax.select(lax.ge(stream_pos, L), lax.sub(stream_pos, L), stream_pos)
+
     def _blk(self, stream_pos):
         """Block number of a stream position inside its own copy."""
-        L, B = self.seq_len, self.block
-        if not isinstance(stream_pos, jax.Array):  # the host's counts (numpy)
-            return (stream_pos - L * (stream_pos >= L)) // B
-        local = jnp.where(stream_pos >= L, stream_pos - L, stream_pos)
+        B = self.block
+        local = self._local(stream_pos)
+        if not isinstance(stream_pos, jax.Array):
+            return local // B
         if B & (B - 1) == 0:
             return lax.shift_right_logical(local, jnp.int32(B.bit_length() - 1))
         return lax.div(local, jnp.int32(B))
@@ -138,8 +148,20 @@ class BlockDiffusion:
         q_noisy, k_noisy = q_off < L, k_off < L
         shift = (q_noisy & ~k_noisy).astype(jnp.int32)
         width = jnp.where(k_noisy, jnp.uint32(0), jnp.uint32(0x7FFFFFFF))
-        d = (self._blk(rows) - shift) - self._blk(cols)
-        return lax.bitcast_convert_type(d, jnp.uint32) <= width
+        d = lax.sub(lax.sub(self._blk(rows), shift), self._blk(cols))
+        return lax.le(lax.bitcast_convert_type(d, jnp.uint32), width)
+
+    def tile_is_lower(self, q_off, k_off):
+        """Whether the live square tile at (q_off, k_off), cut into square
+        pieces that hold whole blocks (``_tile_shape``), is *lower*: a tile on
+        the diagonal of its two copies. No piece above its piece diagonal
+        holds a pair (onto the clean copy a query sees earlier blocks, or
+        earlier and its own; from noisy to noisy its own block alone, so there
+        the pieces below the diagonal are empty too, and walked all the same:
+        a body for them did not pay its set-up, ``PERF.md`` section 6, PR
+        37); the pieces on the diagonal keep ``in_tile``. Scalars of a
+        kernel's grid, or numpy arrays of offsets."""
+        return self._local(q_off) == self._local(k_off)
 
     def check_tiles(self, S: int, *tiles: int) -> None:
         if S != 2 * self.seq_len or any(self.seq_len % t for t in tiles):
@@ -176,6 +198,88 @@ def first_piece_live(mask: MaskRule) -> bool:
     return not isinstance(mask, BlockDiffusion)
 
 
+#: The shapes a live tile can have, by which of its pieces may hold a pair.
+FULL, LOWER = "full", "lower"
+
+
+def _tile_shape(mask: MaskRule, qi, bq: int, ki, bk: int, piece: int):
+    """The third thing a rule says of (bq, bk) tile (qi, ki) of the grid,
+    after whether it is live and which pairs inside it are allowed: which
+    shape it has when cut into (piece, piece) pieces -> whether it is *lower*,
+    a scalar of a kernel's grid (a numpy array for ``visited_units``), or
+    False where the rule has no such tile. *lower*: no piece above the piece
+    diagonal holds a pair (the causal diagonal tile; block diffusion's
+    diagonal tiles). Else *full*: every piece may. A kernel runs a body a
+    shape, and the *lower* one walks the pieces on and below the diagonal
+    alone (``_piece_span``).
+
+    Decided by what the call can observe and no knob: square tiles with tile
+    i at row i*b, cut into two or more whole pieces, each of whole blocks.
+    Anything else (no mask, bq != bk, the CPU tests' small tiles) is *full*
+    everywhere, the body there was before."""
+    if not mask or bq != bk or piece >= bq or bq % piece:
+        return False
+    if isinstance(mask, BlockDiffusion):
+        return False if piece % mask.block else mask.tile_is_lower(qi * bq, ki * bk)
+    return qi == ki
+
+
+def _piece_span(shape: str, i: int, n: int, keys_walked: bool) -> Tuple[int, int]:
+    """[lo, hi), in pieces, of what piece ``i`` of a tile of ``n`` x ``n``
+    pieces meets on the other axis: the query pieces that key piece i meets
+    where the keys are walked (the forward), the key pieces that query piece
+    i meets otherwise (the fused backward)."""
+    if shape == LOWER:
+        return (i, n) if keys_walked else (0, i + 1)
+    return 0, n
+
+
+def tiles_by_shape(mask: MaskRule, S: int, bq: int, bk: int, piece: int):
+    """{shape: which of one head's (S // bq, S // bk) tiles are live and have
+    it} as numpy bools, at (bq, bk) tiles walked in pieces of ``piece``: the
+    host's count of what the kernels' grids decide a step at a time."""
+    qi, ki = np.arange(S // bq)[:, None], np.arange(S // bk)[None, :]
+    live = np.broadcast_to(_tile_rule(mask, qi * bq, bq, ki * bk, bk)[0], (S // bq, S // bk))
+    lower = live & _tile_shape(mask, qi, bq, ki, bk, piece)
+    return {FULL: live & ~lower, LOWER: lower}
+
+
+def visited_units(mask: MaskRule, S: int, bq: int, bk: int, piece: int) -> Tuple[int, int, int]:
+    """(units visited, all units, pairs a unit) of one head's (S, S) scores
+    in a kernel that brings (bq, bk) tiles and walks them in pieces of
+    ``piece``: the unit is the (piece, piece) piece where the rule gives
+    tiles shapes (``_tile_shape``), else the whole tile. What the kernels
+    multiply, against ``BlockDiffusion.tile_counts``'s true pairs."""
+    tiles = tiles_by_shape(mask, S, bq, bk, piece)
+    if not tiles[LOWER].any():
+        return int(tiles[FULL].sum()), tiles[FULL].size, bq * bk
+    n = bq // piece
+    units = sum(
+        int(tiles[shape].sum()) * (hi - lo)
+        for shape in tiles
+        for lo, hi in (_piece_span(shape, i, n, True) for i in range(n))
+    )
+    return units, tiles[FULL].size * n * n, piece * piece
+
+
+# What a kernel's body does a score, a piece at a time, is written in ``lax``
+# and not in ``jnp`` operators. The primitives are the same and so is the
+# kernel; the trace is not: every ``jnp`` function and every operator of a
+# tracer is a jit of its own, traced anew for each new shape, and a *lower*
+# body has a new shape a piece (8 key pieces a forward body, 4 or 8 query
+# pieces a backward one, about 20 ops each, 3 to 4 ms a trace on the chip's
+# host: PERF.md section 6, PR 37). ``lax`` binds the primitive and nothing else.
+def _fill_where(keep: jax.Array, x: jax.Array, fill: float) -> jax.Array:
+    """``jnp.where(keep, x, fill)`` for a Python number ``fill``."""
+    return lax.select(keep, x, lax.full_like(x, fill))
+
+
+def _over_sublanes(reduce, x: jax.Array) -> jax.Array:
+    """``reduce`` (``lax.reduce_max``, ``lax.reduce_sum``) down the first axis
+    of a (n, m) piece -> (1, m)."""
+    return lax.expand_dims(reduce(x, (0,)), (0,))
+
+
 def _mix32(x: jax.Array) -> jax.Array:
     """32-bit integer finalizer (murmur3-style avalanche) on uint32 lanes.
 
@@ -185,10 +289,13 @@ def _mix32(x: jax.Array) -> jax.Array:
     0.490 expected at rate 0.3) — biased dropout. Two multiplies is the
     floor that passes the adjacency tests in tests/test_attention_ops.py.
     """
+    def xorshift(x, n):
+        return lax.bitwise_xor(x, lax.shift_right_logical(x, jnp.uint32(n)))
+
     x = x.astype(jnp.uint32)
-    x = (x ^ (x >> 16)) * jnp.uint32(0x7FEB352D)
-    x = (x ^ (x >> 15)) * jnp.uint32(0x846CA68B)
-    return x ^ (x >> 16)
+    x = lax.mul(xorshift(x, 16), jnp.uint32(0x7FEB352D))
+    x = lax.mul(xorshift(x, 15), jnp.uint32(0x846CA68B))
+    return xorshift(x, 16)
 
 
 def _dropout_keep(seed, bh, rows, cols, threshold) -> jax.Array:
@@ -217,7 +324,7 @@ def _dropout_rowbase(seed, bh, rows) -> jax.Array:
     for every column, so a kernel that walks a tile's columns in pieces makes
     it once a tile."""
     base = _mix32(seed + jnp.uint32(bh) * jnp.uint32(0x9E3779B9))
-    return _mix32(base + rows.astype(jnp.uint32) * jnp.uint32(0x85EBCA6B))
+    return _mix32(lax.add(base, lax.mul(rows.astype(jnp.uint32), jnp.uint32(0x85EBCA6B))))
 
 
 def _dropout_threshold(rate: float) -> jnp.uint32:
@@ -269,6 +376,25 @@ def _pick_block(seq_len: int, preferred: int = 512) -> int:
 # The last two rows are faster and not taken: the pieces are unrolled, and a
 # kernel of 32 to 200 of them cost 2 to 4.6 s of every run's set-up (trace,
 # lower, cache read) in all three cells measured, past the benchmark's bound.
+#
+# Under a mask rule a live tile has a shape (_tile_shape) and, since PR 37, a
+# body a shape at the same piece: us a (1024, 1024) tile at D 128, no dropout
+# (my chip run, PR 37, the same script's by_shape line; in brackets the
+# bundles of the compiler's schedule for the body, 1.5 a ns). Every figure
+# holds the dead grid steps' DMAs, 2.2 a live tile under block diffusion, 0.6
+# causal, which is why a body's time does not fall as far as its area:
+#
+#   (BH, S, rule)                  (64, 4096, causal)   (32, 16384, BlockDiffusion(8192, 4))
+#   full, all 64 pieces of (128, 128)     4.36                 5.71 (5,085)
+#   lower, 36 of 64                       3.22                 4.40 (3,071)
+#   band, the 8 on the diagonal           -                    2.95 (929)
+#
+# The last row is a third body, for block diffusion's noisy -> noisy diagonal
+# tiles (8 of a head's 80; they run the lower body), measured and not taken:
+# every body is traced and lowered in every program that holds the kernel, and
+# with the chains still in jnp the two of them (here and in the backward) cost
+# the SDAR cell 3 s of warm set-up for 0.9 % of its step (PERF.md section 6,
+# PR 37; what one would cost now, its lowering, is not measured).
 _FWD_BLOCK_Q = 1024
 _FWD_BLOCK_K = 1024
 _BWD_BLOCK_K = 512
@@ -305,6 +431,14 @@ _FWD_SUB_K = 128
 # overlaps them as they stand. The last row is faster and not taken: the DMA
 # tile is PR 25's and the forward's, and 16 unrolled pieces a call would be
 # paid in every run's set-up.
+#
+# The bodies a shape (PR 37; as above the forward's table; no dropout, so
+# pieces of 256 queries, 16 of (256, 256) a tile):
+#
+#   (BH, S, D / Dv, rule)      (64, 4096, 128, causal)  (32, 8192, 192 / 128, causal)  (32, 16384, 128, BlockDiffusion(8192, 4))
+#   full, all 16 pieces               8.33                    13.86 (15,928)                 9.34 (10,060)
+#   lower, 10 of 16                   5.80                    10.06 (10,010)                 6.76 (6,182)
+#   band, the 4 on the diagonal       -                       -                              5.00 (2,648): not taken, as in the forward
 _FUSED_BWD_BLOCK_K = 1024
 _BWD_SUB_Q = 256
 _BWD_SUB_Q_DROPOUT = 128
@@ -363,6 +497,11 @@ def _flash_fwd_kernel(
     score for the softmax scale and exp's own base change. Dropout's
     1 / keep_prob rides in the subtracted maximum (p comes out pre-scaled),
     so ``l_scr`` sums p / keep_prob and ``_finalize`` takes the factor out.
+
+    That is the body of a *full* tile. A *lower* tile (``_tile_shape``)
+    runs a body of its own under its own ``pl.when``: the same pieces and the
+    same update, each piece against the queries it may hold a pair with and
+    no other (``_accumulate_lower``).
     """
     bh = pl.program_id(0)
     qi = pl.program_id(1)
@@ -386,7 +525,59 @@ def _flash_fwd_kernel(
     else:
         live = (not causal) or (ki * bk < (qi + 1) * bq)
 
-    @pl.when(live)
+    def update(s, c0, rows, rowbase, m, l, acc):
+        """One online-softmax update: the unscaled scores ``s`` of the keys
+        [c0, c0 + sub_k) against the queries at ``rows`` (1, n), folded into
+        those queries' statistics (1, n) and their columns of out^T."""
+        cols = lax.add(
+            ki * bk + c0, lax.broadcasted_iota(jnp.int32, (sub_k, 1), 0)
+        )
+        # No second mask on p: exp2(NEG_INF * c - m) is exactly 0 once
+        # the maximum it subtracts is finite.
+        if causal:
+            # ``first_piece_live``: m is finite from a query's first piece.
+            s = _fill_where(lax.ge(rows, cols), s, NEG_INF)
+        elif bd:
+            s = _fill_where(
+                mask.in_tile(qi * bq, ki * bk, rows, cols), s, NEG_INF
+            )
+        m_new = lax.max(m, lax.mul(_over_sublanes(lax.reduce_max, s), c))
+        alpha = lax.exp2(lax.sub(m, m_new))  # (1, n)
+        if not first_piece_live(mask):
+            # A query may meet masked scores before its first live key:
+            # its running maximum is then still a masked score's, and
+            # exp2(s * c - m) would be 1. Subtract a maximum floored half
+            # way to the masked value instead (a row op, not a score op):
+            # masked scores still come out 0, and a row that has met a
+            # live key has a maximum far above the floor. alpha is 0
+            # until then (m starts at NEG_INF, below any masked score's).
+            m_sub = lax.max(m_new, 0.5 * NEG_INF * c)
+        else:
+            m_sub = m_new
+        # Attention-probability dropout (parity with the reference
+        # model, train_harness.py:114-116): the softmax normalizer l
+        # accumulates the UN-dropped p (dropout acts after
+        # normalization, and normalization is linear, so dropping the
+        # unnormalized p against the full-l divisor is exact), while the
+        # output accumulator sees the dropped p / keep_prob.
+        sc = lax.mul(s, c)
+        if dropout_rate > 0.0:
+            m_sub = lax.add(m_sub, math.log2(keep_prob))
+        p = p_acc = lax.exp2(lax.sub(sc, m_sub))  # (sub_k, n) fp32
+        if dropout_rate > 0.0:
+            keep = lax.lt(
+                _mix32(lax.add(rowbase, cols.astype(jnp.uint32))),
+                _dropout_threshold(dropout_rate),
+            )
+            p_acc = _fill_where(keep, p, 0.0)
+        l = lax.add(lax.mul(alpha, l), _over_sublanes(lax.reduce_sum, p))
+        acc = lax.mul(acc, alpha)
+        acc = lax.add(acc, lax.dot_general(  # out^T: V^T P
+            v_ref[0, pl.ds(c0, sub_k), :], p_acc.astype(q_ref.dtype),
+            (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32,
+        ))
+        return m_new, l, acc
+
     def _accumulate():
         # bf16 operands on the MXU, fp32 accumulation via
         # preferred_element_type — softmax statistics stay fp32 throughout.
@@ -401,7 +592,8 @@ def _flash_fwd_kernel(
         # Narrow coordinate operands: the causal compare and the dropout
         # hash broadcast (1, bq) x (sub_k, 1); the row-fold mix runs per
         # query only, and once a tile: it does not depend on the key.
-        rows = qi * bq + lax.broadcasted_iota(jnp.int32, (1, bq), 1)
+        rows = lax.add(qi * bq, lax.broadcasted_iota(jnp.int32, (1, bq), 1))
+        rowbase = None
         if dropout_rate > 0.0:
             rowbase = _dropout_rowbase(seed_ref[0], bhv_ref[bh], rows)
         m = m_scr[:]      # (1, bq), log2 units of the scaled scores
@@ -412,52 +604,52 @@ def _flash_fwd_kernel(
             s = s_next
             if c0 + sub_k < bk:
                 s_next = scores(c0 + sub_k)
-            cols = ki * bk + c0 + lax.broadcasted_iota(
-                jnp.int32, (sub_k, 1), 0
-            )
-            # No second mask on p: exp2(NEG_INF * c - m) is exactly 0 once
-            # the maximum it subtracts is finite.
-            if causal:
-                # ``first_piece_live``: m is finite from a query's first piece.
-                s = jnp.where(rows >= cols, s, NEG_INF)
-            elif bd:
-                s = jnp.where(mask.in_tile(qi * bq, ki * bk, rows, cols), s, NEG_INF)
-            m_new = jnp.maximum(m, jnp.max(s, axis=0, keepdims=True) * c)
-            alpha = jnp.exp2(m - m_new)  # (1, bq)
-            if not first_piece_live(mask):
-                # A query may meet masked scores before its first live key:
-                # its running maximum is then still a masked score's, and
-                # exp2(s * c - m) would be 1. Subtract a maximum floored half
-                # way to the masked value instead (a row op, not a score op):
-                # masked scores still come out 0, and a row that has met a
-                # live key has a maximum far above the floor. alpha is 0
-                # until then (m starts at NEG_INF, below any masked score's).
-                m_sub = jnp.maximum(m_new, 0.5 * NEG_INF * c)
-            else:
-                m_sub = m_new
-            # Attention-probability dropout (parity with the reference
-            # model, train_harness.py:114-116): the softmax normalizer l
-            # accumulates the UN-dropped p (dropout acts after
-            # normalization, and normalization is linear, so dropping the
-            # unnormalized p against the full-l divisor is exact), while the
-            # output accumulator sees the dropped p / keep_prob.
-            if dropout_rate > 0.0:
-                p = jnp.exp2(s * c - (m_sub + math.log2(keep_prob)))
-                keep = _mix32(rowbase + cols.astype(jnp.uint32)) < (
-                    _dropout_threshold(dropout_rate)
-                )
-                p_acc = jnp.where(keep, p, 0.0)
-            else:
-                p = p_acc = jnp.exp2(s * c - m_sub)  # (sub_k, bq) fp32
-            l = alpha * l + jnp.sum(p, axis=0, keepdims=True)
-            acc = acc * alpha + lax.dot_general(  # out^T: V^T P
-                v_ref[0, pl.ds(c0, sub_k), :], p_acc.astype(q.dtype),
-                (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32,
-            )
-            m = m_new
+            m, l, acc = update(s, c0, rows, rowbase, m, l, acc)
         m_scr[:] = m
         l_scr[:] = l
         acc_scr[:] = acc
+
+    def _accumulate_lower():
+        """A *lower* tile: a key piece against the queries it may hold a pair
+        with and no other (``_piece_span``), their statistics and columns of
+        out^T updated where they lie, the rest left alone. A piece left out
+        would have been alpha = 1 and p = 0: exact."""
+        n = bk // sub_k
+
+        def lanes(j):
+            lo, hi = _piece_span(LOWER, j, n, keys_walked=True)
+            return pl.ds(lo * sub_k, (hi - lo) * sub_k)
+
+        def scores(j):
+            return lax.dot_general(
+                k_ref[0, pl.ds(j * sub_k, sub_k), :], q_ref[0, lanes(j), :],
+                (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32,
+            )
+
+        s_next = scores(0)
+        for j in range(n):
+            s = s_next
+            if j + 1 < n:
+                s_next = scores(j + 1)
+            at = lanes(j)
+            rows = lax.add(
+                qi * bq + at.start,
+                lax.broadcasted_iota(jnp.int32, (1, at.size), 1),
+            )
+            rowbase = None
+            if dropout_rate > 0.0:
+                rowbase = _dropout_rowbase(seed_ref[0], bhv_ref[bh], rows)
+            m_scr[:, at], l_scr[:, at], acc_scr[:, at] = update(
+                s, j * sub_k, rows, rowbase,
+                m_scr[:, at], l_scr[:, at], acc_scr[:, at],
+            )
+
+    lower = _tile_shape(mask, qi, bq, ki, bk, sub_k)
+    if lower is False:
+        pl.when(live)(_accumulate)
+    else:
+        pl.when(live & lower)(_accumulate_lower)
+        pl.when(live & ~lower)(_accumulate)
 
     @pl.when(ki == nk - 1)
     def _finalize():
@@ -479,7 +671,10 @@ def _vma_struct(shape, dtype, *like):
     operands do (the kernel is pointwise in the shard dimension)."""
     from ..utils.vma import vma_of
 
-    vma = vma_of(*like)
+    return _struct(shape, dtype, vma_of(*like))
+
+
+def _struct(shape, dtype, vma):
     if vma:
         return jax.ShapeDtypeStruct(shape, dtype, vma=vma)
     return jax.ShapeDtypeStruct(shape, dtype)
@@ -538,40 +733,32 @@ def _jnp_reference_forward(
     return out, lse
 
 
-def _flash_forward(
-    q: jax.Array, k: jax.Array, v: jax.Array,
-    mask: MaskRule, interpret: bool, bq: int, bk: int,
-    dropout_rate: float, seed: jax.Array, bhv: jax.Array,
-    sub_k: Optional[int] = None, scale: Optional[float] = None,
-) -> Tuple[jax.Array, jax.Array]:
-    """Run the Pallas kernel on (BH, S, D) q and k and (BH, S, Dv) v ->
-    (out (BH, S, Dv), lse); Dv is D everywhere but latent attention, whose
-    keys carry a rotary part the values lack. ``bhv`` is
-    the (BH,) int32 vector of GLOBAL batch*head ids keying the dropout hash
-    (arange(BH) on one device; mesh-global ids under a shard_map).
-    ``sub_k`` forces the compute piece (tests and the microbench; no flag or
-    config field reaches it); ``_fwd_sub_k`` chooses it otherwise."""
-    BH, S, D = q.shape
-    Dv = v.shape[-1]
-    scale = _softmax_scale(scale, D)
-    grid = (BH, S // bq, S // bk)
-    sub_k = sub_k or _fwd_sub_k(bk)
-    from ..utils.vma import vma_of
-
-    if interpret and vma_of(q, k, v):
-        return _jnp_reference_forward(
-            q, k, v, mask, dropout_rate, seed, bhv, scale
-        )
-    out, lse = pl.pallas_call(
+@functools.lru_cache(maxsize=64)
+def _forward_call(
+    BH, S, D, Dv, dtype, vma, mask, interpret, bq, bk, sub_k, scale,
+    dropout_rate,
+):
+    """The forward's ``pallas_call`` on (seed, bhv, q, k, v) for one shape and
+    one set of static choices, made once a process. What ``pl.pallas_call``
+    returns is a jit of its own, inlined where it is called: the same object
+    called again on the same shapes in the same trace context
+    (``_flash_forward`` sees to that) does not trace the kernel's unrolled
+    bodies again, where a new one would at every call. A differentiated
+    ``flash_attention`` makes two, the primal inside its jit and the forward
+    rule, and a kernel's trace was a third of ``mistral-7b.d2``'s warm
+    set-up in the program (PERF.md section 6, PR 37). The jaxpr a caller sees
+    is the same either way. Whoever patches what a kernel's body reads
+    (``_tile_shape``, ``first_piece_live``) calls ``forget_kernel_calls``."""
+    return pl.pallas_call(
         functools.partial(
             _flash_fwd_kernel, bq=bq, bk=bk, sub_k=sub_k, scale=scale,
             mask=mask, dropout_rate=dropout_rate,
         ),
         out_shape=[
-            _vma_struct((BH, S, Dv), q.dtype, q, k, v),
-            _vma_struct((BH, 8, S), jnp.float32, q, k, v),
+            _struct((BH, S, Dv), dtype, vma),
+            _struct((BH, 8, S), jnp.float32, vma),
         ],
-        grid=grid,
+        grid=(BH, S // bq, S // bk),
         in_specs=[
             pl.BlockSpec(memory_space=pltpu.SMEM),  # dropout seed (1,) uint32
             pl.BlockSpec(memory_space=pltpu.SMEM),  # global bh ids (BH,)
@@ -593,7 +780,40 @@ def _flash_forward(
         ),
         name="flash_fwd",
         interpret=interpret,
-    )(seed, bhv, q, k, v)
+    )
+
+
+def _flash_forward(
+    q: jax.Array, k: jax.Array, v: jax.Array,
+    mask: MaskRule, interpret: bool, bq: int, bk: int,
+    dropout_rate: float, seed: jax.Array, bhv: jax.Array,
+    sub_k: Optional[int] = None, scale: Optional[float] = None,
+) -> Tuple[jax.Array, jax.Array]:
+    """Run the Pallas kernel on (BH, S, D) q and k and (BH, S, Dv) v ->
+    (out (BH, S, Dv), lse); Dv is D everywhere but latent attention, whose
+    keys carry a rotary part the values lack. ``bhv`` is
+    the (BH,) int32 vector of GLOBAL batch*head ids keying the dropout hash
+    (arange(BH) on one device; mesh-global ids under a shard_map).
+    ``sub_k`` forces the compute piece (tests and the microbench; no flag or
+    config field reaches it); ``_fwd_sub_k`` chooses it otherwise."""
+    BH, S, D = q.shape
+    scale = _softmax_scale(scale, D)
+    from ..utils.vma import vma_of
+
+    vma = vma_of(q, k, v)
+    if interpret and vma:
+        return _jnp_reference_forward(
+            q, k, v, mask, dropout_rate, seed, bhv, scale
+        )
+    call = _forward_call(
+        BH, S, D, v.shape[-1], q.dtype, vma, mask, interpret, bq, bk,
+        sub_k or _fwd_sub_k(bk), scale, dropout_rate,
+    )
+    # jit keys a trace by its context too, and with no mesh set the primal
+    # is traced under none and the forward rule under an empty one: name the
+    # mesh that is there, so that the two are one trace.
+    with jax.sharding.use_abstract_mesh(jax.sharding.get_abstract_mesh()):
+        out, lse = call(seed, bhv, q, k, v)
     return out, lse[:, 0, :]
 
 
@@ -639,7 +859,7 @@ def _tile_rule(mask: MaskRule, q_off, bq: int, k_off, bk: int):
         return (mask.tile_live(q_off, bq, k_off, bk),
                 functools.partial(mask.in_tile, q_off, k_off))
     if mask:
-        return q_off + bq - 1 >= k_off, lambda rows, cols: rows >= cols
+        return q_off + bq - 1 >= k_off, lax.ge
     return True, lambda rows, cols: None
 
 
@@ -896,7 +1116,10 @@ def _bwd_fused_kernel(
     pieces of ``sub_q`` lanes, unrolled (``_bwd_sub_q``): a piece's two
     leading products, its vector chain and its three trailing products, dq
     written a slice a piece, dk / dv accumulated over the pieces (the
-    products' own contraction chunks, taken to the outer loop).
+    products' own contraction chunks, taken to the outer loop). A *lower*
+    tile (``_tile_shape``) runs the same walk under its own ``pl.when`` with
+    every piece's keys trimmed to those it may hold a pair with
+    (``_accumulate``).
 
     The chain, by the score: p = exp2(s * c - lse2) with c = scale * log2(e)
     and lse2 = lse * log2(e) made on the row (one multiply for the softmax
@@ -934,57 +1157,80 @@ def _bwd_fused_kernel(
 
     live, in_tile = _tile_rule(mask, q_off, bq, k_off, bk)
 
-    @pl.when(live)
-    def _accumulate():
-        k = k_ref[0]
-        v = v_ref[0]
-        # Narrow coordinate operands, as in the other kernels; key positions
-        # ("cols" of the hash) run down the sublanes here.
-        cols = k_off + lax.broadcasted_iota(jnp.int32, (bk, 1), 0)
-        hash_cols = cols.astype(jnp.uint32)
+    def _accumulate(shape):
+        """The tile's query pieces, each against the keys it may hold a pair
+        with (``_piece_span``): all of them in a *full* tile; in a *lower*
+        one dk and dv take the partial rows, dq's contraction the partial
+        sum. What is left out was p = ds = 0: exact."""
+        n = bq // sub_q
         for r0 in range(0, bq, sub_q):
+            if shape == LOWER or r0 == 0:  # a *full* tile's keys: once
+                _, hi = _piece_span(shape, r0 // sub_q, n, keys_walked=False)
+                keys = slice(None) if shape == FULL else pl.ds(0, hi * sub_q)
+                k = k_ref[0, keys, :]
+                v = v_ref[0, keys, :]
+                # Narrow coordinate operands, as in the other kernels; key
+                # positions ("cols" of the hash) run down the sublanes here.
+                cols = lax.add(
+                    k_off, lax.broadcasted_iota(jnp.int32, (k.shape[0], 1), 0)
+                )
+                hash_cols = cols.astype(jnp.uint32)
             q = q_ref[0, pl.ds(r0, sub_q), :]
             do = do_ref[0, pl.ds(r0, sub_q), :]
             s = lax.dot_general(
                 k, q, (((1,), (1,)), ((), ())),
                 preferred_element_type=jnp.float32,
-            )  # (bk, sub_q) fp32, unscaled
+            )  # (keys, sub_q) fp32, unscaled
             dp = lax.dot_general(
                 v, do, (((1,), (1,)), ((), ())),
                 preferred_element_type=jnp.float32,
             )
             shift = lse_ref[0, :1, pl.ds(r0, sub_q)] * _LOG2_E  # (1, sub_q)
             delta = delta_ref[0, :1, pl.ds(r0, sub_q)]
-            rows = q_off + r0 + lax.broadcasted_iota(jnp.int32, (1, sub_q), 1)
+            rows = lax.add(
+                q_off + r0, lax.broadcasted_iota(jnp.int32, (1, sub_q), 1)
+            )
             allowed = in_tile(rows, cols)
             if allowed is not None:
-                s = jnp.where(allowed, s, NEG_INF)
+                s = _fill_where(allowed, s, NEG_INF)
+            sc = lax.mul(s, c)
             if dropout_rate > 0.0:
                 # p / keep_prob: dv's operand as it is, and ds's with
                 # keep_prob taken into delta's row.
-                p = jnp.exp2(s * c - (shift + math.log2(keep_prob)))
-                keep = _mix32(
-                    _dropout_rowbase(seed_ref[0], bhv_ref[bh], rows) + hash_cols
-                ) < _dropout_threshold(dropout_rate)
-                pd = jnp.where(keep, p, 0.0)
-                dp = jnp.where(keep, dp, 0.0)
-                delta = delta * keep_prob
-            else:
-                p = pd = jnp.exp2(s * c - shift)
-            ds = (p * (dp - delta)).astype(q.dtype)  # ds / scale
-            dv_acc[:] = dv_acc[:] + lax.dot_general(
+                shift = lax.add(shift, math.log2(keep_prob))
+            p = pd = lax.exp2(lax.sub(sc, shift))
+            if dropout_rate > 0.0:
+                keep = lax.lt(
+                    _mix32(lax.add(
+                        _dropout_rowbase(seed_ref[0], bhv_ref[bh], rows),
+                        hash_cols,
+                    )),
+                    _dropout_threshold(dropout_rate),
+                )
+                pd = _fill_where(keep, p, 0.0)
+                dp = _fill_where(keep, dp, 0.0)
+                delta = lax.mul(delta, keep_prob)
+            ds = lax.mul(p, lax.sub(dp, delta)).astype(q.dtype)  # ds / scale
+            dv_acc[keys, :] = lax.add(dv_acc[keys, :], lax.dot_general(
                 pd.astype(q.dtype), do, (((1,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32,
-            )
-            dk_acc[:] = dk_acc[:] + lax.dot_general(
+            ))
+            dk_acc[keys, :] = lax.add(dk_acc[keys, :], lax.dot_general(
                 ds, q, (((1,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32,
-            )
+            ))
             dq_rows = pl.ds(pl.multiple_of(q_off + r0, sub_q), sub_q)
-            dq_acc[dq_rows, :] = dq_acc[dq_rows, :] + lax.dot_general(
+            dq_acc[dq_rows, :] = lax.add(dq_acc[dq_rows, :], lax.dot_general(
                 ds, k, (((0,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32,
-            )
+            ))
+
+    lower = _tile_shape(mask, qi, bq, ki, bk, sub_q)
+    if lower is False:
+        pl.when(live)(functools.partial(_accumulate, FULL))
+    else:
+        pl.when(live & lower)(functools.partial(_accumulate, LOWER))
+        pl.when(live & ~lower)(functools.partial(_accumulate, FULL))
 
     @pl.when(qi == nq - 1)
     def _finalize_kv():
@@ -1017,15 +1263,13 @@ def _fused_fits(S: int, D: int, dtype) -> bool:
     return _fused_vmem_bytes(S, D, dtype) <= _FUSED_MAX_VMEM
 
 
-def _fused_backward(
-    q, k, v, do, lse3, delta3, seed, bhv, mask, rate, bq, bk, interpret,
-    scale=None, sub=None,
+@functools.lru_cache(maxsize=64)
+def _fused_call(
+    BH, S, D, Dv, dtypes, vma, mask, interpret, bq, bk, sub_q, scale, rate,
 ):
-    """The one-kernel Pallas backward on (BH, S, D) q and k and (BH, S, Dv)
-    v and do. ``sub`` forces the compute piece (tests and the microbench; no
-    flag or config field reaches it); ``_bwd_sub_q`` chooses it otherwise."""
-    BH, S, D = q.shape
-    Dv = v.shape[-1]
+    """The fused backward's ``pallas_call`` on (seed, bhv, q, k, v, do, lse3,
+    delta3), made once a process for a shape and its static choices, as
+    ``_forward_call`` and for its reason."""
     q_spec = pl.BlockSpec((1, bq, D), lambda b, ki, qi: (b, qi, 0))
     k_spec = pl.BlockSpec((1, bk, D), lambda b, ki, qi: (b, ki, 0))
     do_spec = pl.BlockSpec((1, bq, Dv), lambda b, ki, qi: (b, qi, 0))
@@ -1034,14 +1278,13 @@ def _fused_backward(
     smem = pl.BlockSpec(memory_space=pltpu.SMEM)
     return pl.pallas_call(
         functools.partial(
-            _bwd_fused_kernel, bq=bq, bk=bk,
-            sub_q=sub or _bwd_sub_q(bq, rate),
-            scale=_softmax_scale(scale, D), mask=mask, dropout_rate=rate,
+            _bwd_fused_kernel, bq=bq, bk=bk, sub_q=sub_q, scale=scale,
+            mask=mask, dropout_rate=rate,
         ),
         out_shape=[
-            _vma_struct((BH, S, D), q.dtype, q, k, v, do),
-            _vma_struct((BH, S, D), k.dtype, q, k, v, do),
-            _vma_struct((BH, S, Dv), v.dtype, q, k, v, do),
+            _struct((BH, S, D), dtypes[0], vma),
+            _struct((BH, S, D), dtypes[1], vma),
+            _struct((BH, S, Dv), dtypes[2], vma),
         ],
         grid=(BH, S // bk, S // bq),
         in_specs=[smem, smem, q_spec, k_spec, v_spec, do_spec,
@@ -1057,10 +1300,35 @@ def _fused_backward(
         ],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary", "arbitrary"),
-            vmem_limit_bytes=_fused_vmem_bytes(S, D, q.dtype),
+            vmem_limit_bytes=_fused_vmem_bytes(S, D, dtypes[0]),
         ),
         name="flash_bwd_fused",
         interpret=interpret,
+    )
+
+
+def forget_kernel_calls() -> None:
+    """Drop the kernels kept for the process (``_forward_call``,
+    ``_fused_call``): the next call builds and traces its kernel anew. For
+    tests and the microbenches, after patching what a body reads."""
+    _forward_call.cache_clear()
+    _fused_call.cache_clear()
+
+
+def _fused_backward(
+    q, k, v, do, lse3, delta3, seed, bhv, mask, rate, bq, bk, interpret,
+    scale=None, sub=None,
+):
+    """The one-kernel Pallas backward on (BH, S, D) q and k and (BH, S, Dv)
+    v and do. ``sub`` forces the compute piece (tests and the microbench; no
+    flag or config field reaches it); ``_bwd_sub_q`` chooses it otherwise."""
+    from ..utils.vma import vma_of
+
+    BH, S, D = q.shape
+    return _fused_call(
+        BH, S, D, v.shape[-1], (q.dtype, k.dtype, v.dtype),
+        vma_of(q, k, v, do), mask, interpret, bq, bk,
+        sub or _bwd_sub_q(bq, rate), _softmax_scale(scale, D), rate,
     )(seed, bhv, q, k, v, do, lse3, delta3)
 
 

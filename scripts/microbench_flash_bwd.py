@@ -7,9 +7,19 @@ S 2048) the einsum backward, at the shapes the benchmark's cells run.
   mistral-7b.d2                     BH 64, S 4096, D 128, causal, no dropout
   deepseek-v2-lite.share8-seq8192   BH 32, S 8192, 192-wide q / k over 128-wide
                                     v, causal, YaRN's scale
+  sdar-30b-a3b.share8-bd8192        BH 32, a stream of 16,384 (a document of 8192
+                                    twice), D 128, ``BlockDiffusion(8192, 4)``
   tinygpt-a.seq2048                 BH 16, S 2048, D 64: below _PALLAS_BWD_MIN_SEQ
                                     the model runs the einsum backward; the
                                     kernel against it is a number for PERF.md
+
+Under a mask rule a live tile has a shape (``fa._tile_shape``: *full* or
+*lower*) and the kernel a body a shape. ``prod`` rows take a fourth field, the
+bodies the kernel may run: ``all`` (what the model runs; the default) or
+``none`` (every live tile the *full* body: the whole-tile walk the kernel was
+until PR 37). From the two the script prints us a tile by shape beside the
+mean over live tiles (the dead grid steps' DMAs are in every one), and the
+area a call visits (``fa.visited_units``).
 
 ``bwd_pieced`` below is the prototype PR 33 swept (``PERF.md`` section 6 has
 the table): one grid step still brings the operands of a (bk, bq) score tile
@@ -34,8 +44,8 @@ At sub_q = bq, sub_k = bk, trim 0 it is the body production ran until PR 33.
 The rows:
 
   pair    ``_pair_backward`` at (1024, 512): every tile visited twice
-  prod    ``_fused_backward`` as the model runs it (``prod:bq:bk:sub`` forces
-          its tile and piece; sub 0 = the chooser's)
+  prod    ``_fused_backward`` as the model runs it (``prod:bq:bk:sub[:bodies]``
+          forces its tile and piece; sub 0 = the chooser's)
   proto   ``bwd_pieced``: ``proto:bq:bk:sub_q:sub_k:lookahead:trim[:late_dq]``
   einsum  ``_jnp_blockwise_bwd`` at bk 512, what runs below S 4096
 
@@ -52,11 +62,13 @@ clock around ``--iters`` queued calls and one fetch is the device time.
 """
 
 import argparse
+import contextlib
 import functools
 import glob
 import json
 import math
 import os
+import re
 import sys
 import time
 
@@ -64,6 +76,7 @@ os.environ.setdefault("TPU_LOG_DIR", "disabled")
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
@@ -85,8 +98,12 @@ SHAPES = {
     "deepseek-v2-lite.share8-seq8192": dict(
         BH=32, S=8192, D=192, Dv=128, causal=True, rate=0.0, scale=0.114721
     ),
+    "sdar-30b-a3b.share8-bd8192": dict(
+        BH=32, S=16384, D=128, Dv=128, causal=fa.BlockDiffusion(8192, 4), rate=0.0, scale=None
+    ),
     "tinygpt-a.seq2048": dict(BH=16, S=2048, D=64, Dv=64, causal=False, rate=0.1, scale=None),
 }
+BODIES = ("all", "none")
 DEFAULT_ROWS = [
     "pair", "proto:1024:1024:1024:1024:0:0", "prod:1024:1024:0",
     "prod:1024:1024:1024", "prod:1024:1024:512", "prod:1024:1024:256",
@@ -106,7 +123,49 @@ compile here (a report template it lacks), after the files are written.
 
 reads ``*<kernel>*final_hlo-static-per-bundle-utilization.txt`` (a line a
 bundle, a column a slot kind) for the Mosaic kernels in <dir>;
-``*final_bundles.txt`` beside it is the schedule itself."""
+``*final_bundles.txt`` beside it is the schedule itself, and gives the
+bundles of each predicated region: under a mask rule the bodies, in the order
+the kernel emits them (*lower*, *full*; the accumulators' zeroing before
+them and their write-out behind)."""
+
+
+@contextlib.contextmanager
+def bodies(which):
+    """Trace a kernel with ``all`` the bodies the rule gives it or with
+    ``none``: every live tile sent to the *full* body."""
+    rule = fa._tile_shape
+    if which == "none":
+        fa._tile_shape = lambda *a: False
+    elif which != "all":
+        raise SystemExit(f"bodies are {BODIES}, not {which!r}")
+    fa.forget_kernel_calls()  # a kernel a shape is kept for the process
+    try:
+        yield
+    finally:
+        fa._tile_shape = rule
+        fa.forget_kernel_calls()
+
+
+def shape_counts(mask, S, tile, piece):
+    """Live tiles a head by shape at square tiles, and the area a call visits
+    (``fa.visited_units``) in tiles' worth."""
+    counts = {shape: int(tiles.sum())
+              for shape, tiles in fa.tiles_by_shape(mask, S, tile, tile, piece).items()}
+    units, _, pairs = fa.visited_units(mask, S, tile, tile, piece)
+    counts["visited_tiles"] = units * pairs / tile ** 2
+    return counts
+
+
+def us_by_shape(ms, counts, BH):
+    """us a tile by shape from a call's time with all the bodies and with
+    none (``bodies``): a *lower* tile costs the *full* body's time less what
+    its own body saved on each."""
+    live = counts["full"] + counts["lower"]
+    us = {k: v * 1e3 / BH for k, v in ms.items()}  # a head
+    table = {"mean": us["all"] / live, "full": us["none"] / live}
+    if counts["lower"]:
+        table["lower"] = table["full"] - (us["none"] - us["all"]) / counts["lower"]
+    return table
 
 
 def _bwd_kernel_pieced(
@@ -292,6 +351,7 @@ def backward_fn(row, shape):
     """The jitted call a row names, on (q, k, v, do, lse3, delta3, seed, bhv)."""
     causal, rate, scale = shape["causal"], shape["rate"], shape["scale"]
     kind, *nums = row.split(":")
+    which = nums.pop() if nums and nums[-1] in BODIES else "all"
     nums = [int(n) for n in nums]
     if kind == "pair":
         def run(*a):
@@ -300,9 +360,10 @@ def backward_fn(row, shape):
         bq, bk, sub = nums
 
         def run(*a):
-            return fa._fused_backward(
-                *a, causal, rate, bq, bk, False, scale, sub=sub or None
-            )
+            with bodies(which):  # entered when the call is traced
+                return fa._fused_backward(
+                    *a, causal, rate, bq, bk, False, scale, sub=sub or None
+                )
     elif kind == "proto":
         bq, bk, sub_q, sub_k, lookahead, trim, *late = nums
         run = functools.partial(
@@ -352,18 +413,49 @@ def residuals(shape):
 
 
 def time_ms(fn, args, iters):
+    """The faster of two timed loops: a call's first row has read 17 % over
+    its second (the chip's clock, not the kernel: PERF.md, PR 37)."""
     out = fn(*args)
     jax.block_until_ready(out)
-    t0 = time.perf_counter()
-    for _ in range(iters):
-        out = fn(*args)
-    float(out[0][0, 0, 0])
-    return (time.perf_counter() - t0) / iters * 1e3, out
+    times = []
+    for _ in range(2):
+        t0 = time.perf_counter()
+        for _ in range(iters):
+            out = fn(*args)
+        float(out[0][0, 0, 0])
+        times.append((time.perf_counter() - t0) / iters * 1e3)
+    return min(times), out
+
+
+def read_regions(path):
+    """[(first bundle, bundles)] of the innermost predicated regions of a
+    ``*final_bundles.txt`` that are 100 bundles or longer: a forward branch
+    (``sbr.rel``) opens one, the next fallthrough mark (``PF:``) closes it."""
+    address = re.compile(r"^\s*(0x[0-9a-f]+|\d+)\s+(PF:)?\s*:?\s*>?\s*\{")
+    target = re.compile(r"sbr\.rel .*?target bundleno = (\d+)")
+    open_at, regions = [], []
+    for line in open(path):
+        head = address.match(line)
+        if not head:
+            continue
+        at = int(head.group(1), 0)
+        if head.group(2) and open_at:
+            start, leaf = open_at.pop()
+            if leaf and at - start >= 100:
+                regions.append((start, at - start))
+            if open_at:
+                open_at[-1][1] = False
+        jump = target.search(line)
+        if jump and int(jump.group(1)) > at:  # not a loop's back edge
+            open_at.append([at + 1, True])
+    return regions
 
 
 def read_bundles(directory):
     """A line a Mosaic kernel in an LLO dump: bundles, and how full each kind
-    of slot is over them (ops / (slots x bundles))."""
+    of slot is over them (ops / (slots x bundles)); then its predicated
+    regions (``read_regions``: under a mask rule, the bodies), each with its
+    bundles and its MXU and VALU share."""
     pattern = os.path.join(
         directory, "*final_hlo-static-per-bundle-utilization.txt"
     )
@@ -377,12 +469,25 @@ def read_bundles(directory):
         slots = [int(x) for x in lines[at + 2].split()]
         bundles = [[int(x) for x in line.split()] for line in lines[at + 4:]
                    if line[:1].isdigit()]
-        n = len(bundles)
-        row = dict(kernel=kernel, bundles=n, us=n / CLOCK_HZ * 1e6)
-        for j, kind in enumerate(kinds):
-            ops = sum(b[j] for b in bundles)
-            row[kind] = dict(ops=ops, pct=round(100 * ops / (slots[j] * n), 1))
-        print(json.dumps(row), flush=True)
+
+        def summary(part):
+            n = len(part)
+            row = dict(bundles=n, us=n / CLOCK_HZ * 1e6)
+            for j, kind in enumerate(kinds):
+                ops = sum(b[j] for b in part)
+                row[kind] = dict(ops=ops, pct=round(100 * ops / (slots[j] * n), 1))
+            return row
+
+        print(json.dumps(dict(kernel=kernel, **summary(bundles))), flush=True)
+        stem = "-".join(os.path.basename(path).split("-")[:2])
+        schedule = glob.glob(os.path.join(directory, stem + "-*-final_bundles.txt"))
+        for start, n in read_regions(schedule[0]) if schedule else ():
+            row = summary(bundles[start:start + n])
+            print(json.dumps(dict(
+                kernel=kernel, region_at=start, bundles=n, us=row["us"],
+                MXU=row["MXU"]["pct"], VALU=row["VALU"]["pct"],
+                spills=row["VSTORE:SPILL"]["ops"],
+            )), flush=True)
 
 
 def main():
@@ -428,20 +533,26 @@ def main():
         if args.causal is not None:
             shape["causal"] = bool(args.causal)
         BH, S, D, Dv = shape["BH"], shape["S"], shape["D"], shape["Dv"]
+        mask = shape["causal"]
+        ruled = isinstance(mask, fa.BlockDiffusion)
+        pairs = (mask.tile_counts(1024, 1024)[2] if ruled
+                 else S * S / (2 if mask else 1))
         # FlashAttention-2's count of the fused pass: five tile products.
-        flops = 2 * BH * S * S * (3 * D + 2 * Dv) / (2 if shape["causal"] else 1)
+        flops = 2 * BH * pairs * (3 * D + 2 * Dv)
         least_ms = flops / PEAK_FLOPS * 1e3
-        n = S // 1024
-        tiles = BH * (n * (n + 1) // 2 if shape["causal"] else n * n)
+        counts = shape_counts(mask, S, 1024, fa._bwd_sub_q(1024, shape["rate"]))
+        tiles = BH * (counts["full"] + counts["lower"])
         print(f"{name}: {shape}; least time for one fused pass at full-width "
-              f"peak {least_ms:.2f} ms; {tiles} live (1024, 1024) tiles",
-              flush=True)
+              f"peak {least_ms:.2f} ms; {tiles} live (1024, 1024) tiles, a "
+              f"head {counts}", flush=True)
+        by_bodies_rows = [f"prod:1024:1024:0:{which}" for which in BODIES]
         rows = args.rows or (
             ["einsum", "pair", "prod:1024:1024:0"] if S < fa._PALLAS_BWD_MIN_SEQ
-            else DEFAULT_ROWS
+            else ["pair"] + by_bodies_rows if ruled
+            else DEFAULT_ROWS + by_bodies_rows[1:] * bool(mask)
         )
         data = None if args.describe else residuals(shape)
-        want = None
+        want, with_all, by_bodies = None, None, {}
         for spec in rows:
             row = dict(shape=name, row=spec)
             try:
@@ -453,6 +564,18 @@ def main():
                     row["ms"], got = time_ms(fn, data, args.iters)
                     row["us_a_tile"] = row["ms"] * 1e3 / tiles
                     row["pct_of_peak"] = 100 * least_ms / row["ms"]
+                    if spec in by_bodies_rows or spec == "prod:1024:1024:0":
+                        which = spec.split(":")[4] if spec.count(":") == 4 else "all"
+                        by_bodies[which] = row["ms"]
+                        if which == "all":
+                            with_all = got
+                        elif with_all is not None:  # the skipped products were zeros: 0.0
+                            row["max_abs_diff_vs_all_bodies"] = max(
+                                float(jnp.max(jnp.abs(
+                                    g.astype(jnp.float32) - w.astype(jnp.float32)
+                                )))
+                                for g, w in zip(got, with_all)
+                            )
                     if spec == "pair":
                         want = got
                     elif want is not None and spec != "einsum":
@@ -467,6 +590,11 @@ def main():
             print(json.dumps(row), flush=True)
             with open(args.out, "a") as f:
                 f.write(json.dumps(row) + "\n")
+        if set(by_bodies) == set(BODIES):
+            print(json.dumps(dict(
+                shape=name, by_shape=True, tiles_a_head=counts,
+                us_a_tile=us_by_shape(by_bodies, counts, BH),
+            )), flush=True)
 
 
 if __name__ == "__main__":

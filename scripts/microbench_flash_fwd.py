@@ -5,6 +5,10 @@ against the whole-tile body, at the shapes the benchmark's cells run.
   tinygpt-a.seq8192   BH 16, S 8192, D 64, not causal, dropout 0.1
   tinygpt-a.seq2048   BH 16, S 2048, D 64, not causal, dropout 0.1
   mistral-7b.d2       BH 64, S 4096, D 128, causal, no dropout
+  sdar-30b-a3b.share8-bd8192   BH 32, a stream of 16,384 (a document of 8192
+                      twice), D 128, ``BlockDiffusion(8192, 4)``, no dropout:
+                      the production kernel only (the prototype below and the
+                      floors know no rule)
 
 docs/PERFORMANCE.md section 15 measured that the two products of a score tile
 alone took twice the MXU's time, and refuted three operand layouts that keep
@@ -27,7 +31,13 @@ The rows:
   matmul_floor      the two products with a cast between them, no softmax
   xla_sdpa          XLA's materialised-score chain (skipped over 2 GiB of scores)
   flash_production  ops/flash_attention.py::_flash_forward as the model runs it
-                    (``--rows prod:bq:bk:0:sub_k:0:0`` forces its tile and piece)
+                    (``--rows prod:bq:bk:0:sub_k:0:0`` forces its tile and piece).
+                    Under a mask rule a live tile has a shape (``fa._tile_shape``:
+                    *full* or *lower*) and the kernel a body a shape: the row
+                    is timed with ``--bodies`` all (what the model runs) and
+                    none (every live tile the *full* body, the kernel until
+                    PR 37), and a ``by_shape`` line gives us a tile by shape
+                    beside the mean over live tiles and the area a call visits
   flash_subtiled    every (layout, DMA tile, sub_q, sub_k, lookahead, trim) asked for
 
 A call is a fraction of a millisecond to a few, so each row chains enough
@@ -36,7 +46,9 @@ and fetches one scalar (docs/TROUBLESHOOTING.md section 17).
 
   chiprun -- python scripts/microbench_flash_fwd.py              # the q-major and k-major grids
   JAX_PLATFORMS=cpu python scripts/microbench_flash_fwd.py --describe
-      # no chip: compile every row for a described v5e
+      # no chip: compile every row for a described v5e, and print how to take
+      # the compiler's own schedule of a row (``--bundles <dir>`` reads it, a
+      # body a line)
   ... --shapes tinygpt-a.seq2048 --dropout 0 --causal 1          # one shape, overridden
   ... --rows kmajor:2048:2048:1024:128:1:1 prod:1024:1024:0:256:0:0   # named rows only
 """
@@ -64,6 +76,9 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 from distributed_llm_training_benchmark_framework_tpu.ops import (  # noqa: E402
     flash_attention as fa,
 )
+from microbench_flash_bwd import (  # noqa: E402  (beside this file)
+    BODIES, CLOCK_HZ, LLO_DUMP_HELP, bodies, read_bundles, shape_counts, us_by_shape,
+)
 
 NEG_INF = fa.NEG_INF
 LOG2_E = math.log2(math.e)
@@ -73,6 +88,8 @@ SHAPES = {
     "tinygpt-a.seq8192": dict(BH=16, S=8192, D=64, causal=False, rate=0.1),
     "tinygpt-a.seq2048": dict(BH=16, S=2048, D=64, causal=False, rate=0.1),
     "mistral-7b.d2": dict(BH=64, S=4096, D=128, causal=True, rate=0.0),
+    "sdar-30b-a3b.share8-bd8192": dict(
+        BH=32, S=16384, D=128, causal=fa.BlockDiffusion(8192, 4), rate=0.0),
 }
 DMA_TILES = [(1024, 1024), (2048, 1024), (1024, 2048), (2048, 2048)]
 SUB_K = [128, 256, 512, 1024]
@@ -356,6 +373,10 @@ def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--describe", action="store_true",
                     help="compile every row for a described v5e; no timing")
+    ap.add_argument("--bundles", metavar="DIR",
+                    help="summarise the LLO dump in DIR and exit")
+    ap.add_argument("--bodies", nargs="*", default=list(BODIES), choices=BODIES,
+                    help="under a mask rule, the bodies flash_production may run")
     ap.add_argument("--shapes", nargs="*", default=list(SHAPES))
     ap.add_argument("--dropout", type=float, default=None,
                     help="override the shapes' dropout rate")
@@ -376,11 +397,15 @@ def main():
     ap.add_argument("--out", default="chiprun_out/flash_fwd_sweep.jsonl")
     args = ap.parse_args()
 
+    if args.bundles:
+        read_bundles(args.bundles)
+        return
     sharding = None
     if args.describe:
         from jax.experimental import topologies
         from jax.sharding import SingleDeviceSharding
 
+        print(LLO_DUMP_HELP.format(clock=CLOCK_HZ / 1e9).replace("_bwd", "_fwd"), flush=True)
         topo = topologies.get_topology_desc(
             platform="tpu", topology_name="v5e:2x2"
         )
@@ -397,16 +422,19 @@ def main():
             shape["causal"] = bool(args.causal)
         BH, S, D = shape["BH"], shape["S"], shape["D"]
         causal, rate = shape["causal"], shape["rate"]
-        flops = 4 * BH * S * S * D / (2 if causal else 1)
+        ruled = isinstance(causal, fa.BlockDiffusion)
+        pairs = (causal.tile_counts(1024, 1024)[2] if ruled
+                 else S * S / (2 if causal else 1))
+        flops = 4 * BH * pairs * D
         least_ms = flops / PEAK_FLOPS * 1e3
         # Score tiles a call visits, in units of (1024, 1024). (What the MXU
         # allows one: 2 products x 8192 row pushes over 4 MXUs at 1.5 GHz =
         # 2.73 us, at D 64 as at D 128.)
-        tiles = BH * (S // 1024) ** 2
-        if causal:
-            tiles = BH * (S // 1024) * (S // 1024 + 1) // 2
+        counts = shape_counts(causal, S, 1024, fa._fwd_sub_k(1024))
+        tiles = BH * (counts["full"] + counts["lower"])
         print(f"{name}: {shape}; least time {least_ms:.3f} ms a call at "
-              f"full-width peak; {tiles} live (1024, 1024) tiles", flush=True)
+              f"full-width peak; {tiles} live (1024, 1024) tiles, a head "
+              f"{counts}", flush=True)
 
         x = jax.ShapeDtypeStruct((BH, S, D), jnp.bfloat16, sharding=sharding)
         seed_a = jax.ShapeDtypeStruct((1,), jnp.uint32, sharding=sharding)
@@ -428,17 +456,28 @@ def main():
                 rate, seed, bhv, sub_k=sub_k,
             )
 
-        variants = [
-            ("matmul_floor", {}, lambda q, k, v, seed, bhv: (matmul_floor(q, k, v), None)),
-            ("flash_production", {}, production),
-        ]
-        if BH * S * S * 4 <= 2 * 2**30:
+        def with_bodies(which, **cfg):
+            def run(*a):
+                with bodies(which):  # entered when the call is traced
+                    return production(*a, **cfg)
+            return run
+
+        variants = [("flash_production", {}, production)]
+        if counts["lower"]:
+            variants = [("flash_production", dict(bodies=which), with_bodies(which))
+                        for which in args.bodies]
+        if not ruled:
+            variants.insert(0, ("matmul_floor", {}, lambda q, k, v, seed, bhv: (matmul_floor(q, k, v), None)))
+        if not ruled and BH * S * S * 4 <= 2 * 2**30:
             variants.insert(1, ("xla_sdpa", {}, lambda q, k, v, seed, bhv: (xla_sdpa(q, k, v), None)))
         fields = ("layout", "bq", "bk", "sub_q", "sub_k", "lookahead", "trim")
-        if args.rows:
+        if ruled and not args.rows:
+            rows = []
+        elif args.rows:
             rows = [tuple(f if i == 0 else int(f) for i, f in enumerate(r.split(":")))
                     for r in args.rows]
-            rows = [r for r in rows if S % r[1] == 0 and S % r[2] == 0]
+            rows = [r for r in rows if S % r[1] == 0 and S % r[2] == 0
+                    and (r[0] == "prod" or not ruled)]
         else:
             rows = [r + (t,) for t in args.trim
                     for r in sweep_rows(S, args.layouts)]
@@ -456,7 +495,7 @@ def main():
                 functools.partial(flash_subtiled, causal=causal, rate=rate, **cfg),
             ))
 
-        want = None
+        want, by_bodies = None, {}
         for variant, cfg, fn in variants:
             row = dict(shape=name, variant=variant, **cfg)
             n = chain if variant != "xla_sdpa" else max(chain // 8, 2)
@@ -469,6 +508,8 @@ def main():
                     row["ms"] = time_ms(many, (q, k, v, seed, bhv), n, args.reps)
                     row["pct_of_peak"] = 100 * least_ms / row["ms"]
                     row["us_a_tile"] = row["ms"] * 1e3 / tiles
+                    if list(cfg) == ["bodies"]:
+                        by_bodies[cfg["bodies"]] = row["ms"]
                     _, out, lse = many(1, q, k, v, seed, bhv)
                     if want is None and variant == "flash_production":
                         want = (out, lse)
@@ -484,6 +525,11 @@ def main():
             print(json.dumps(row), flush=True)
             with open(args.out, "a") as f:
                 f.write(json.dumps(row) + "\n")
+        if set(by_bodies) == set(BODIES):
+            print(json.dumps(dict(
+                shape=name, by_shape=True, tiles_a_head=counts,
+                us_a_tile=us_by_shape(by_bodies, counts, BH),
+            )), flush=True)
 
 
 if __name__ == "__main__":

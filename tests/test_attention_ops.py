@@ -322,6 +322,48 @@ def test_flash_fused_backward_pieces_match_whole_tile(causal, rate, bq, bk, sub)
         np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=1e-5, atol=5e-6)
 
 
+@pytest.mark.parametrize("widths", [(32, 32), (48, 32)], ids=["D=Dv", "Dqk>Dv"])
+@pytest.mark.parametrize("rate", [0.0, 0.1], ids=["nodrop", "dropout"])
+@pytest.mark.parametrize("kernel", ["forward", "fused-backward"])
+def test_flash_causal_diagonal_tiles_walk_their_live_pieces(kernel, rate, widths, monkeypatch, request):
+    """A causal diagonal tile of four pieces a side is *lower*
+    (``fa._tile_shape``): its body meets ten of the sixteen and leaves the six
+    above the piece diagonal out, operands trimmed to them (the forward's
+    lookahead over spans that shrink, the backward's partial rows of dk and
+    dv). What it leaves out were exact zeros: the results are the whole-tile
+    walk's (every live tile *full*, at the same tiles and pieces) bit for
+    bit, keep mask and all. (Tiles of 256: XLA's CPU dot sums in one order
+    whatever the operands' extent only from about that size; at tiles of 64
+    the two walks differ by an ulp. On the chip the microbenches compare
+    them, ``max_abs_diff``.)"""
+    from distributed_llm_training_benchmark_framework_tpu.ops import (
+        flash_attention as fa,
+    )
+
+    S, H, (d_qk, d_v), tile, piece = 512, 2, widths, 256, 64
+    keys = jax.random.split(jax.random.key(3), 4)
+    q, k = (jax.random.normal(key, (H, S, d_qk), jnp.float32) for key in keys[:2])
+    v, do = (jax.random.normal(key, (H, S, d_v), jnp.float32) for key in keys[2:])
+    seed, bhv = jnp.asarray([21], jnp.uint32), jnp.arange(H, dtype=jnp.int32)
+    out, lse = fa._flash_forward(q, k, v, True, True, tile, tile, rate, seed, bhv, sub_k=piece)
+    stat3 = lambda x: jnp.broadcast_to(x[:, None, :], (H, 8, S))
+
+    def run():
+        if kernel == "forward":
+            return fa._flash_forward(q, k, v, True, True, tile, tile, rate, seed, bhv, sub_k=piece)
+        return fa._fused_backward(
+            q, k, v, do, stat3(lse), stat3(jnp.sum(do * out, -1)), seed, bhv, True, rate,
+            tile, tile, True, sub=piece)
+
+    assert fa._tile_shape(True, 1, tile, 1, tile, piece) and not fa._tile_shape(True, 1, tile, 0, tile, piece)
+    by_pieces = run()
+    monkeypatch.setattr(fa, "_tile_shape", lambda *a: False)
+    fa.forget_kernel_calls()  # a kernel a shape is kept for the process
+    request.addfinalizer(fa.forget_kernel_calls)
+    for got, whole in zip(by_pieces, run()):
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(whole))
+
+
 @pytest.mark.parametrize("sub", [16, 64], ids=["pieces", "whole"])
 def test_flash_fused_backward_pieces_match_masked_reference(sub, monkeypatch):
     """The pieced walk through the public call's custom VJP (the chooser
@@ -356,13 +398,19 @@ def test_flash_fused_backward_pieces_match_masked_reference(sub, monkeypatch):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=5e-3, atol=5e-3)
 
 
-def test_flash_fused_backward_keep_mask_is_the_forwards():
+@pytest.mark.parametrize(
+    "causal,bq,bk,sub", [(False, 64, 32, 16), (True, 32, 32, 8)],
+    ids=["full", "causal-live-pieces"],
+)
+def test_flash_fused_backward_keep_mask_is_the_forwards(causal, bq, bk, sub):
     """The pieces' mask (the hash's row half made once a piece, ``_mix32`` of
     ``rowbase + cols`` over it) is the forward kernel's bit for bit, at
     another tiling than the forward's: with v = 1 and do = 1 every dp is 1
     and delta is the kept share of the row, so ds, and with k = e_0 and
     q = 0 the first column of dq, is p' x (keep - kept share): one flipped
-    bit moves it by a whole probability, far over rounding."""
+    bit moves it by a whole probability, far over rounding. Under ``causal``
+    at square tiles the diagonal tiles are walked by their live pieces, keys
+    trimmed to them: the hash takes global coordinates, so the same bits."""
     from distributed_llm_training_benchmark_framework_tpu.ops import (
         flash_attention as fa,
     )
@@ -373,22 +421,25 @@ def test_flash_fused_backward_keep_mask_is_the_forwards():
     v = do = jnp.ones((H, S, D), jnp.float32)
     seed = jnp.asarray([5], jnp.uint32)
     bhv = jnp.arange(H, dtype=jnp.int32) + 3
-    out, lse = fa._flash_forward(q, k, v, False, True, 32, 128, rate, seed, bhv)
+    out, lse = fa._flash_forward(q, k, v, causal, True, 32, 128, rate, seed, bhv)
     delta = jnp.sum(do * out, axis=-1)
     stat3 = lambda x: jnp.broadcast_to(x[:, None, :], (H, 8, S))
     dq, _, _ = fa._fused_backward(
-        q, k, v, do, stat3(lse), stat3(delta), seed, bhv, False, rate,
-        64, 32, True, sub=16,
+        q, k, v, do, stat3(lse), stat3(delta), seed, bhv, causal, rate,
+        bq, bk, True, sub=sub,
     )
     rows = jnp.arange(S)[None, :, None]
     cols = jnp.arange(S)[None, None, :]
     keep = fa._dropout_keep(
         seed[0], bhv[:, None, None], rows, cols, fa._dropout_threshold(rate)
     )
-    kept = jnp.mean(keep, axis=-1) / (1.0 - rate)  # = out = delta / D, p = 1 / S
+    # q = 0: p is 1 / (the keys a query sees), S of them or its own position + 1
+    seen = (rows >= cols) if causal else jnp.ones((1, S, S), bool)
+    p = seen / jnp.sum(seen, axis=-1, keepdims=True)
+    kept = jnp.sum(keep * p, axis=-1) / (1.0 - rate)  # = out = delta / D
     np.testing.assert_allclose(np.asarray(out[:, :, 0]), np.asarray(kept), rtol=1e-5)
     want = jnp.sum(
-        (keep / (1.0 - rate) * D - delta[:, :, None]) / S, axis=-1
+        (keep / (1.0 - rate) * D - delta[:, :, None]) * p, axis=-1
     ) / (D ** 0.5)
     # One flipped bit is worth D / keep_prob / S / sqrt(D) = 0.03 here.
     np.testing.assert_allclose(

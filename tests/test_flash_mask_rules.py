@@ -22,6 +22,16 @@ BH, D = 2, 16
 RULE = fa.BlockDiffusion(L, B)
 
 
+@pytest.fixture(autouse=True)
+def kernels_built_by_this_test():
+    """The kernels are kept a shape for the life of the process: a test that
+    patches what a body reads (``_tile_shape``, ``first_piece_live``) has to
+    build its own."""
+    fa.forget_kernel_calls()
+    yield
+    fa.forget_kernel_calls()
+
+
 def dense_mask(seq_len, block):
     """(2L, 2L) bool from the definition: copy 0 noisy, copy 1 clean."""
     pos = np.arange(2 * seq_len)
@@ -115,14 +125,22 @@ def test_forward_kernel_matches_materialized_attention(rate, sub_k):
         np.testing.assert_allclose(ref_lse, want_lse, atol=2e-5, rtol=2e-5)
 
 
-def test_forward_without_the_floor_on_its_maximum_is_wrong(monkeypatch):
+@pytest.mark.parametrize("walk", ["whole-tiles", "lower-bodies"])
+def test_forward_without_the_floor_on_its_maximum_is_wrong(walk, monkeypatch):
     """The shortcut the causal shape allowed (no second select on p, because a
     query's first piece holds a live key) does not hold here: a noisy query
-    meets masked scores first. Taking the floor away must show."""
+    meets masked scores first, with every live tile walked whole (tiles that
+    are not square, or this walk with the shapes turned off) and with the
+    diagonal tiles walked by their *lower* body too (a noisy -> noisy one
+    still starts a query at the key pieces before its own). Taking the floor
+    away must show."""
     q, k, v, _, seed, bhv = stream_operands()
     want, _ = materialized(q, k, v, dense_mask(L, B))
     assert not fa.first_piece_live(RULE) and fa.first_piece_live(True) and fa.first_piece_live(False)
     monkeypatch.setattr(fa, "first_piece_live", lambda mask: True)
+    if walk == "whole-tiles":
+        monkeypatch.setattr(fa, "_tile_shape", lambda *a: False)
+    fa.forget_kernel_calls()
     out, _ = fa._flash_forward(q, k, v, RULE, True, TILE, TILE, 0.0, seed, bhv, sub_k=PIECE)
     assert not float(jnp.max(jnp.abs(out - want))) < 0.1  # far off, or not a number
 
@@ -194,12 +212,20 @@ def test_a_wrong_logsumexp_shows_in_the_fused_backward():
 
 @pytest.mark.parametrize("causal", [False, True], ids=["none", "causal"])
 @pytest.mark.parametrize("pallas_backward", [False, True], ids=["einsum", "fused"])
-def test_the_calls_that_existed_trace_what_they_traced(causal, pallas_backward):
+def test_the_calls_that_existed_trace_what_they_traced(causal, pallas_backward, monkeypatch):
     """``causal`` true / false are two of the rule's values, false the
     default: the jaxpr holds none of the integer work block diffusion's rule
     brings (a shift to block numbers, a bit-cast for the unsigned compare).
     The parent commit's jaxprs of these calls, the pair's too, were compared
-    with this code's once, equal (CHANGES.md, PR 36)."""
+    with this code's once, equal (CHANGES.md, PR 36). Since PR 37 a rule also
+    gives its tiles shapes: with no mask there are none and the kernels trace
+    what they trace with the shapes turned off, which is PR 36's kernel (the
+    Mosaic modules compared once with the parent's, printed without
+    locations, forward and fused backward, three widths, with and without
+    dropout, equal: CHANGES.md, PR 37; the jaxprs name their ops otherwise
+    since the bodies' chains bind ``lax`` primitives); under ``causal`` the
+    forward (a tile of two pieces here) gains the diagonal tiles' body, and
+    with the shapes off is PR 36's again."""
     keys = jax.random.split(jax.random.key(4), 4)
     q, k, v, w = (jax.random.normal(key, (1, 2 * L, BH, D), jnp.float32) for key in keys)
 
@@ -216,6 +242,10 @@ def test_the_calls_that_existed_trace_what_they_traced(causal, pallas_backward):
     assert "shift_right_logical" not in default and "bitcast_convert_type" not in default
     under_the_rule = program(causal=RULE)
     assert "shift_right_logical" in under_the_rule and "bitcast_convert_type" in under_the_rule
+    monkeypatch.setattr(fa, "flash_attention", fa.flash_attention.__wrapped__)  # past its jit's cache
+    as_it_is = program(causal=causal)
+    shapes_off(monkeypatch)
+    assert (as_it_is == program(causal=causal)) == (not causal)
 
 
 def test_what_the_rule_refuses():
@@ -230,3 +260,308 @@ def test_what_the_rule_refuses():
     assert fa.pick_tiles(2 * L, D, jnp.float32, True, causal=RULE)[:3] == (512, 512, 512)
     assert fa.pick_tiles(2 * 8192, 128, jnp.bfloat16, False, causal=fa.BlockDiffusion(8192, 4)) == (
         1024, 1024, 1024, True)
+
+
+# ---- The shape of a live tile, and the bodies that walk only its live pieces ----
+
+def shapes_off(monkeypatch):
+    """The whole-tile walk at the same tiles and pieces: every live tile *full*."""
+    monkeypatch.setattr(fa, "_tile_shape", lambda *a: False)
+    fa.forget_kernel_calls()
+
+
+def causal_mask(rows, cols):
+    return rows[:, None] >= cols[None, :]
+
+
+def bd_mask(seq_len, block):
+    def allowed(rows, cols):  # the definition, on stream positions
+        q_copy, k_copy = rows[:, None] // seq_len, cols[None, :] // seq_len
+        q_blk, k_blk = (rows[:, None] % seq_len) // block, (cols[None, :] % seq_len) // block
+        return (((q_copy == 0) & (k_copy == 0) & (q_blk == k_blk))
+                | ((q_copy == 0) & (k_copy == 1) & (k_blk < q_blk))
+                | ((q_copy == 1) & (k_copy == 1) & (k_blk <= q_blk)))
+    return allowed
+
+
+CELL_SHAPES = {
+    # rule, its dense mask, S, {shape: tiles a head} at (1024, 1024) tiles
+    "causal-4096": (True, causal_mask, 4096, {fa.FULL: 6, fa.LOWER: 4}),
+    "causal-8192": (True, causal_mask, 8192, {fa.FULL: 28, fa.LOWER: 8}),
+    "block-diffusion-8192x4": (
+        fa.BlockDiffusion(8192, 4), bd_mask(8192, 4), 16384, {fa.FULL: 56, fa.LOWER: 24}),
+}
+
+
+@pytest.mark.parametrize("piece", [128, 256], ids=["forward-128", "backward-256"])
+@pytest.mark.parametrize("cell", sorted(CELL_SHAPES))
+def test_no_piece_a_body_skips_holds_an_allowed_pair(cell, piece):
+    """The shape the rule gives every tile of the cell shapes, against the
+    dense mask written from the definition: a piece outside the *lower*
+    body's span (``_piece_span``, the forward's walk and the backward's) holds
+    no allowed pair; every piece inside it holds one, but in block diffusion's
+    noisy -> noisy diagonal tiles, where only the diagonal pieces do (they
+    run the *lower* body all the same); and the count is ``visited_units``'s."""
+    rule, dense, S, want = CELL_SHAPES[cell]
+    tile, n = 1024, 1024 // piece
+    tiles = fa.tiles_by_shape(rule, S, tile, tile, piece)
+    assert {shape: int(here.sum()) for shape, here in tiles.items()} == want
+    visited = n * n * want[fa.FULL]
+    for qi, ki in zip(*np.nonzero(tiles[fa.LOWER])):
+        inside = dense(qi * tile + np.arange(tile), ki * tile + np.arange(tile))
+        holds = inside.reshape(n, piece, n, piece).any((1, 3))  # [query piece, key piece]
+        for keys_walked in (True, False):
+            walked = np.zeros((n, n), bool)
+            for i in range(n):
+                lo, hi = fa._piece_span(fa.LOWER, i, n, keys_walked)
+                walked[(slice(lo, hi), i) if keys_walked else (i, slice(lo, hi))] = True
+            assert not (holds & ~walked).any(), f"tile ({qi}, {ki})"
+            noisy_to_noisy = rule is not True and ki * tile < rule.seq_len
+            np.testing.assert_array_equal(holds, np.eye(n, dtype=bool) if noisy_to_noisy else walked)
+        visited += int(walked.sum())
+    assert fa.visited_units(rule, S, tile, tile, piece) == (visited, (S // tile) ** 2 * n * n, piece * piece)
+
+
+def test_the_cell_shape_visits_69_and_71_tiles_worth_of_its_80():
+    """The sibling of the 80 tiles above: what the kernels multiply inside
+    them. 56 whole tiles and 24 *lower* ones (36 of 64 forward pieces of 128,
+    10 of 16 backward pieces of 256): 69.5 and 71 tiles' worth."""
+    rule = fa.BlockDiffusion(8192, 4)
+    pairs = rule.tile_counts(1024, 1024)[2]
+    fwd = fa.visited_units(rule, 16384, 1024, 1024, fa._fwd_sub_k(1024))
+    bwd = fa.visited_units(rule, 16384, 1024, 1024, fa._bwd_sub_q(1024, 0.0))
+    assert fwd == (56 * 64 + 24 * 36, 256 * 64, 128 * 128) and bwd == (71 * 16, 256 * 16, 256 * 256)
+    assert round(100 * 2 * pairs / (fwd[0] * fwd[2] + bwd[0] * bwd[2]), 1) == 91.1
+    # with dropout the backward's piece is 128: 69.5, as the forward
+    assert fa.visited_units(rule, 16384, 1024, 1024, fa._bwd_sub_q(1024, 0.1))[0] == fwd[0]
+    # where the rule gives no shapes the unit is the tile: not square, and a piece of 6 blocks and a half
+    assert fa.visited_units(rule, 16384, 1024, 512, 128) == (rule.tile_counts(1024, 512)[0], 512, 1024 * 512)
+    assert fa.visited_units(fa.BlockDiffusion(6144, 24), 12288, 1024, 1024, 128) == (
+        fa.BlockDiffusion(6144, 24).tile_counts(1024, 1024)[0], 144, 1024 * 1024)
+    assert fa.visited_units(False, 4096, 1024, 1024, 128) == (16, 16, 1024 * 1024)
+    # causal at S 4096: 6 + 4 x 36 / 64 forward, 6 + 4 x 10 / 16 backward
+    assert fa.visited_units(True, 4096, 1024, 1024, 128)[0] == 6 * 64 + 4 * 36
+    assert fa.visited_units(True, 4096, 1024, 1024, 256)[0] == 6 * 16 + 4 * 10
+
+
+def widths_operands(d_qk, d_v, seed=5):
+    keys = jax.random.split(jax.random.key(seed), 4)
+    q, k = (jax.random.normal(key, (BH, 2 * L, d_qk), jnp.float32) for key in keys[:2])
+    v, do = (jax.random.normal(key, (BH, 2 * L, d_v), jnp.float32) for key in keys[2:])
+    return q, k, v, do, jnp.asarray([7], jnp.uint32), jnp.arange(BH, dtype=jnp.int32)
+
+
+RULES = {"causal": True, "block-diffusion": RULE}
+WIDTHS = {"D=Dv": (16, 16), "Dqk>Dv": (24, 16)}
+
+
+@pytest.mark.parametrize("widths", sorted(WIDTHS))
+@pytest.mark.parametrize("rate", [0.0, 0.1], ids=["no-dropout", "dropout"])
+@pytest.mark.parametrize("rule", sorted(RULES))
+def test_forward_bodies_give_the_whole_tile_walks_bits(rule, rate, widths, monkeypatch):
+    """*lower* tiles walked by the pieces on and below their diagonal against
+    every live tile walked whole, at the same tiles and pieces: ``out`` and
+    ``lse`` bit for bit (the products left out were exact zeros, alpha 1),
+    in every region of the stream, the keep mask with them; and the
+    materialized reference to rounding."""
+    mask = RULES[rule]
+    q, k, v, _, seed, bhv = widths_operands(*WIDTHS[widths])
+    args = (q, k, v, mask, True, TILE, TILE, rate, seed, bhv)
+    out, lse = fa._flash_forward(*args, sub_k=PIECE)
+    ref_out, ref_lse = fa._jnp_reference_forward(q, k, v, mask, rate, seed, bhv)
+    shapes_off(monkeypatch)
+    whole_out, whole_lse = fa._flash_forward(*args, sub_k=PIECE)
+    for name, (rows, _) in REGIONS.items():
+        np.testing.assert_array_equal(out[:, rows], whole_out[:, rows], err_msg=name)
+        np.testing.assert_array_equal(lse[:, rows], whole_lse[:, rows], err_msg=name)
+    np.testing.assert_allclose(out, ref_out, atol=2e-5, rtol=2e-5)
+    np.testing.assert_allclose(lse, ref_lse, atol=2e-5, rtol=2e-5)
+
+
+@pytest.mark.parametrize("widths", sorted(WIDTHS))
+@pytest.mark.parametrize("rate", [0.0, 0.1], ids=["no-dropout", "dropout"])
+@pytest.mark.parametrize("rule", sorted(RULES))
+def test_fused_backward_bodies_give_the_whole_tile_walks_bits(rule, rate, widths, monkeypatch):
+    """The same for dq, dk and dv (partial rows of dk and dv, a partial sum
+    in dq's contraction: what is left out was p = ds = 0), and the einsum
+    path to rounding."""
+    mask = RULES[rule]
+    q, k, v, do, seed, bhv = widths_operands(*WIDTHS[widths], seed=6)
+    out, lse = fa._flash_forward(q, k, v, mask, True, TILE, TILE, rate, seed, bhv)
+    lse3 = jnp.broadcast_to(lse[:, None, :], (BH, 8, 2 * L))
+    delta3 = jnp.broadcast_to(jnp.sum(do * out, -1)[:, None, :], (BH, 8, 2 * L))
+    args = (q, k, v, do, lse3, delta3, seed, bhv, mask, rate, TILE, TILE, True)
+    got = fa._fused_backward(*args, sub=PIECE)
+    einsum = fa._jnp_blockwise_bwd(mask, TILE, rate, (q, k, v, out, lse, seed, bhv), do)
+    shapes_off(monkeypatch)
+    whole = fa._fused_backward(*args, sub=PIECE)
+    for name, g, w, e in zip(("dq", "dk", "dv"), got, whole, einsum):
+        np.testing.assert_array_equal(g, w, err_msg=name)
+        np.testing.assert_allclose(g, e, atol=2e-5, rtol=2e-5, err_msg=name)
+
+
+@pytest.mark.parametrize("rate", [0.0, 0.1], ids=["no-dropout", "dropout"])
+@pytest.mark.parametrize("rule", sorted(RULES))
+def test_gradients_through_the_bodies_match_jax_grad_of_materialized_attention(rule, rate, monkeypatch):
+    """Through the public call with both kernels walking their diagonal tiles
+    by pieces (the backward's piece forced: the chooser walks a tile of 256
+    whole): the gradient by q, k and v against ``jax.grad`` of the dense-mask
+    softmax with the forward's keep mask laid over it."""
+    mask = RULES[rule]
+    keys = jax.random.split(jax.random.key(8), 4)
+    q, k, v, w = (jax.random.normal(key, (1, 2 * L, BH, D), jnp.float32) for key in keys)
+    pos = np.arange(2 * L)
+    dense = jnp.asarray(causal_mask(pos, pos) if mask is True else dense_mask(L, B))
+    seed = jnp.uint32(11)
+    keep = fa._dropout_keep(seed, jnp.arange(BH)[:, None, None], pos[None, :, None], pos[None, None, :],
+                            fa._dropout_threshold(rate)) if rate else jnp.ones((BH, 2 * L, 2 * L), bool)
+    fused = fa._fused_backward
+    monkeypatch.setattr(fa, "_fused_backward", lambda *a: fused(*a, sub=PIECE))
+
+    def flash(q, k, v):  # not through the call's jit: its cache would not see the forced piece
+        return jnp.sum(w * fa.flash_attention.__wrapped__(
+            q, k, v, causal=mask, interpret=True, block_q=TILE, block_k=TILE, block_k_bwd=TILE,
+            pallas_backward=True, dropout_rate=rate, dropout_seed=seed if rate else None))
+
+    def plain(q, k, v):
+        to = lambda t: t[0].transpose(1, 0, 2)
+        scores = jnp.einsum("bqd,bkd->bqk", to(q), to(k)) * D ** -0.5
+        p = jax.nn.softmax(jnp.where(dense[None], scores, -jnp.inf), -1)
+        out = jnp.einsum("bqk,bkd->bqd", jnp.where(keep, p / (1.0 - rate), 0.0), to(v))
+        return jnp.sum(w * out.transpose(1, 0, 2)[None])
+
+    np.testing.assert_allclose(flash(q, k, v), plain(q, k, v), rtol=1e-5)
+    got = jax.grad(flash, argnums=(0, 1, 2))(q, k, v)
+    want = jax.grad(plain, argnums=(0, 1, 2))(q, k, v)
+    for g, r, name in zip(got, want, "qkv"):
+        np.testing.assert_allclose(g, r, atol=5e-5, rtol=5e-4, err_msg=name)
+
+
+def kernel_bodies(fn, *args):
+    """{kernel name: [the dot_generals' operand shapes of each body]} of the
+    Pallas calls ``fn`` traces: a body is a branch a ``pl.when`` made (a
+    ``cond`` of the kernel's jaxpr) that multiplies, or the kernel's top level
+    where a body runs unconditionally (no mask)."""
+    def dots(jaxpr):
+        found = []
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "dot_general":
+                found.append(tuple(tuple(x.aval.shape) for x in eqn.invars))
+        return found
+
+    def calls(jaxpr):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "pallas_call":
+                yield eqn
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                yield from calls(sub)
+
+    kernels = {}
+    for call in calls(jax.make_jaxpr(fn)(*args).jaxpr):
+        kernel = call.params["jaxpr"]
+        bodies = [dots(kernel)] + [
+            dots(branch.jaxpr) for eqn in kernel.eqns if eqn.primitive.name == "cond"
+            for branch in eqn.params["branches"]]
+        kernels[call.params["name"]] = [b for b in bodies if b]
+    return kernels
+
+
+@pytest.mark.parametrize("rule", ["none"] + sorted(RULES))
+def test_the_bodies_issue_the_products_of_the_area_they_visit(rule):
+    """What the kernels' jaxprs multiply: a body's score products (k q^T:
+    its first operand the keys of a piece, its second the queries it meets)
+    add up to the area ``_piece_span`` gives its shape, a body a shape the
+    rule has, and over the grid to ``visited_units``; with no mask there is
+    one body, the one there was: 2 products a key piece forward, 5 a query
+    piece backward."""
+    mask = RULES.get(rule, False)
+    q, k, v, do, seed, bhv = widths_operands(24, 16)
+    stat = jnp.zeros((BH, 8, 2 * L), jnp.float32)
+    S, n = 2 * L, TILE // PIECE
+    kernels = kernel_bodies(
+        lambda q, k, v, do: (
+            fa._flash_forward(q, k, v, mask, True, TILE, TILE, 0.0, seed, bhv, sub_k=PIECE),
+            fa._fused_backward(q, k, v, do, stat, stat, seed, bhv, mask, 0.0, TILE, TILE, True, sub=PIECE)),
+        q, k, v, do)
+    assert sorted(kernels) == ["flash_bwd_fused", "flash_fwd"]
+    shapes = [fa.LOWER, fa.FULL] if mask else [fa.FULL]  # in the order they are emitted
+    tiles = {shape: int(here.sum()) for shape, here in fa.tiles_by_shape(mask, S, TILE, TILE, PIECE).items()}
+    for name, products_a_piece, score in (("flash_fwd", 2, lambda a, b: a[0] == PIECE and a[1] == b[1] == 24),
+                                          ("flash_bwd_fused", 5, lambda a, b: b[0] == PIECE and a[1] == b[1] == 24)):
+        bodies = kernels[name]
+        assert len(bodies) == len(shapes), (name, len(bodies))
+        total = 0
+        for shape, body in zip(shapes, bodies):
+            assert len(body) == products_a_piece * n
+            area = sum(a[0] * b[0] for a, b in body if score(a, b))
+            assert area == PIECE * PIECE * sum(
+                hi - lo for lo, hi in (fa._piece_span(shape, i, n, name == "flash_fwd") for i in range(n)))
+            total += tiles[shape] * area
+        units, _, unit_pairs = fa.visited_units(mask, S, TILE, TILE, PIECE)
+        assert total == units * unit_pairs
+
+
+@pytest.mark.parametrize("tiles,sub_k,sub", [((256, 128), 128, 128), ((128, 256), 128, 64), ((192, 192), None, None),
+                                             ((128, 128), 128, 128)],
+                         ids=["bq>bk", "bq<bk", "a-piece-that-does-not-divide", "a-tile-no-wider-than-its-piece"])
+@pytest.mark.parametrize("rule", sorted(RULES))
+def test_tiles_that_are_not_square_or_not_cut_whole_run_the_full_body(rule, tiles, sub_k, sub, monkeypatch):
+    """bq != bk, a piece the tile is not a whole number of (the choosers then
+    walk it whole) or a tile of one piece: the rule gives no shapes and the
+    kernels trace what they traced with none."""
+    mask, (bq, bk) = RULES[rule], tiles
+    seq_len = 384 if bq == 192 else L
+    mask = fa.BlockDiffusion(seq_len, B) if rule == "block-diffusion" else mask
+    q = jnp.zeros((BH, 2 * seq_len, D), jnp.float32)
+    stat = jnp.zeros((BH, 8, 2 * seq_len), jnp.float32)
+    seed, bhv = jnp.asarray([7], jnp.uint32), jnp.arange(BH, dtype=jnp.int32)
+
+    def program():
+        return str(jax.make_jaxpr(lambda q: (
+            fa._flash_forward(q, q, q, mask, True, bq, bk, 0.0, seed, bhv, sub_k=sub_k),
+            fa._fused_backward(q, q, q, q, stat, stat, seed, bhv, mask, 0.0, bq, bk, True, sub=sub)))(q))
+
+    for piece in (sub_k or fa._FWD_SUB_K, sub or fa._BWD_SUB_Q_DROPOUT):
+        assert fa._tile_shape(mask, 0, bq, 0, bk, piece) is False
+    as_it_is = program()
+    shapes_off(monkeypatch)
+    assert as_it_is == program()
+
+
+@pytest.mark.parametrize("rule", sorted(RULES))
+def test_a_differentiated_call_traces_each_kernel_once_and_in_few_jits(rule, monkeypatch):
+    """What the bodies cost a run's set-up (PERF.md section 6, PR 37). A
+    call under ``jax.grad`` with no mesh set traces the forward kernel once:
+    the primal and the forward rule share one ``pallas_call`` and one trace
+    context. And a body's chain binds ``lax`` primitives: every ``jnp``
+    function or operator of a tracer is a jit of its own, traced anew at each
+    new shape, and a *lower* body has a new shape a piece. At the Mosaic
+    path's tiles (traced, not lowered) a differentiated call of the one-body
+    kernels written in ``jnp`` made 238 such traces causal and 482 under
+    block diffusion, the two bodies in ``jnp`` 484 and 924; they make 114 and
+    305 (the rule's scalars of a tile, made again a piece, are the rest)."""
+    from distributed_llm_training_benchmark_framework_tpu.utils import scopes
+
+    S = 4096
+    mask = fa.BlockDiffusion(S // 2, B) if rule == "block-diffusion" else RULES[rule]
+    traced = {"flash_fwd": 0, "flash_bwd_fused": 0}
+    for name, kernel in (("flash_fwd", fa._flash_fwd_kernel), ("flash_bwd_fused", fa._bwd_fused_kernel)):
+        def counted(*args, _name=name, _kernel=kernel, **kwargs):
+            traced[_name] += 1
+            return _kernel(*args, **kwargs)
+        monkeypatch.setattr(fa, kernel.__name__, counted)
+    q = jax.ShapeDtypeStruct((1, S, 2, 128), jnp.bfloat16)
+
+    def loss(q, k, v):
+        out = fa.flash_attention.__wrapped__(  # past its jit's cache
+            q, k, v, causal=mask, interpret=False, pallas_backward=True)
+        return jnp.sum(out.astype(jnp.float32))
+
+    def jit_traces():
+        return sum(count for (event, _), (count, _) in scopes.compile_events()["sums"].items()
+                   if event.endswith("jaxpr_trace_duration"))
+
+    before = jit_traces()
+    jax.make_jaxpr(jax.grad(loss, argnums=(0, 1, 2)))(q, q, q)
+    assert traced == {"flash_fwd": 1, "flash_bwd_fused": 1}
+    assert jit_traces() - before < {"causal": 150, "block-diffusion": 350}[rule]

@@ -331,8 +331,15 @@ def test_flops_and_memory_count_the_objective():
     assert flops_bd.true_pairs(shape) == 8192 * 8192 + 8192 * 4
     stats = tinygpt.bd_mask_stats(config, 8192)
     assert stats["true_pairs"] == flops_bd.true_pairs(shape)
+    # the unit is the piece a kernel skips by: 69.5 of 256 tiles' worth of forward
+    # pieces of 128 x 128, 71 of 256 of backward pieces of 256 x 256
     assert (stats["fwd_live_tiles"], stats["fwd_tiles"], stats["fwd_tile_pairs"]) == (
-        80, 256, 1024 * 1024)
+        56 * 64 + 24 * 36, 256 * 64, 128 * 128)
+    assert (stats["bwd_live_tiles"], stats["bwd_tiles"], stats["bwd_tile_pairs"]) == (
+        71 * 16, 256 * 16, 256 * 256)
+    visited = (stats["fwd_live_tiles"] * stats["fwd_tile_pairs"]
+               + stats["bwd_live_tiles"] * stats["bwd_tile_pairs"])
+    assert round(100 * 2 * stats["true_pairs"] / visited, 2) == 91.15  # bd_live_fill_pct's arithmetic
     # the causal next-token model of the same widths: one copy, half the pairs
     plain = dataclasses.replace(config, block_diffusion=None, causal=True)
     D, H, Dh = 2048, 32, 128

@@ -116,6 +116,38 @@ def test_block_diffusion_kernels_compile(v5e_devices, block):
     assert "flash_fwd" in text and "flash_bwd_fused" in text
 
 
+@pytest.mark.parametrize("kernel", ["fwd", "bwd"])
+def test_the_bodies_change_the_mosaic_module_and_a_retrace_does_not(v5e_devices, kernel, monkeypatch):
+    """``scripts/flash_mosaic_modules.py``: what a kernel hands the compiler,
+    printed without source locations, is how two checkouts are shown to run
+    the same kernel with no chip (PR 37: the bodies' chains from ``jnp`` to
+    ``lax``). The text names no file, a kernel built and traced again gives
+    it again, and the causal kernel with its tiles' shapes turned off, the
+    whole-tile walk, gives another."""
+    import importlib.util
+    import os
+
+    path = os.path.join(os.path.dirname(os.path.dirname(__file__)), "scripts", "flash_mosaic_modules.py")
+    spec = importlib.util.spec_from_file_location("flash_mosaic_modules", path)
+    tool = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(tool)
+
+    def module():
+        fa.forget_kernel_calls()
+        (found,) = [tool.mosaic_modules(call.lower(*operands).as_text())
+                    for name, call, operands in tool.kernels(v5e_devices[0])
+                    if name == f"{kernel}.causal.0.0.128.128"]
+        return found[0]
+
+    text = module()
+    assert "flash_attention.py" not in text and "loc(" not in text
+    assert {"fwd": "flash_fwd", "bwd": "flash_bwd_fused"}[kernel] in text
+    assert module() == text
+    monkeypatch.setattr(fa, "_tile_shape", lambda *a: False)
+    assert module() != text
+    fa.forget_kernel_calls()
+
+
 def test_fused_backward_compiles_at_its_vmem_cap(v5e_devices):
     """The longest sequence ``_fused_fits`` lets through (the resident dq row
     is what grows with S) compiles under the limit the call asks for."""
