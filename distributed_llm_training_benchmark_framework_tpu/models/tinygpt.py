@@ -114,6 +114,22 @@ class YarnScaling:
         return self._mscale(self.factor, self.mscale_all_dim) ** 2
 
 
+#: The kinds of attention layer a stack can mix (``TinyGPTConfig.layer_types``):
+#: ``global`` sees every earlier position, ``window`` the last
+#: ``sliding_window`` of them. Also the names of their scopes under ``attention``.
+LAYER_KINDS = (scopes.GLOBAL, scopes.WINDOW)
+
+
+@dataclasses.dataclass(frozen=True)
+class Rotary:
+    """One kind of layer's rotary table: plain ``theta``, or YaRN's
+    frequencies over it with cos and sin times ``scaling.cos_sin_factor``
+    (the Hugging Face ``attention_factor``; the softmax scale is untouched)."""
+
+    theta: float
+    scaling: Optional[YarnScaling] = None
+
+
 @dataclasses.dataclass(frozen=True)
 class BlockDiffusionObjective:
     """Block-diffusion training (BD3-LM, arXiv:2503.09573; the SDAR recipe): a
@@ -291,6 +307,17 @@ class TinyGPTConfig:
     # train step folds from seed, step and micro-batch), and the step returns
     # the masked-token count after its loss (``step_report``).
     block_diffusion: Optional[BlockDiffusionObjective] = None
+    # Layers of more than one kind in one stack (Gemma-2/3, Mellum-2: sliding
+    # window layers beside global ones): one of LAYER_KINDS a layer, in order.
+    # The kind is data on the layer, not a second stack: every layer has the
+    # same leaves, and the layer loop hands each its kind statically, which
+    # chooses its mask rule (``mask_rule``), its rotary table (``rotary``) and
+    # its scope under ``attention``. None: every layer as ``causal`` says.
+    layer_types: Optional[Tuple[str, ...]] = None
+    # Keys a ``window`` layer's query sees, its own included.
+    sliding_window: Optional[int] = None
+    # ((kind, Rotary), ...) for the kinds whose table is not plain rope_theta.
+    layer_rotary: Optional[Tuple[Tuple[str, Rotary], ...]] = None
     # Linear/LayerNorm biases (Llama ships none anywhere).
     bias: bool = True
     # Weight-tied LM head (reference train_harness.py:61-62). False adds a
@@ -387,10 +414,14 @@ class TinyGPTConfig:
             return self.qk_dim ** -0.5 * self.rope_scaling.softmax_factor
         return None
 
-    def mask_rule(self, stream_len: int):
+    def mask_rule(self, stream_len: int, kind: Optional[str] = None):
         """The attention mask as ``ops.flash_attention.MaskRule``: ``causal``,
-        or under ``block_diffusion`` its rule over a stream of ``stream_len``
-        = 2L positions."""
+        a ``window`` layer's ``SlidingWindow``, or under ``block_diffusion``
+        its rule over a stream of ``stream_len`` = 2L positions."""
+        if kind == scopes.WINDOW:
+            from ..ops.flash_attention import SlidingWindow
+
+            return SlidingWindow(self.sliding_window)
         if self.block_diffusion is None:
             return self.causal
         from ..ops.flash_attention import BlockDiffusion
@@ -398,6 +429,20 @@ class TinyGPTConfig:
         if stream_len % 2:
             raise ValueError(f"a block-diffusion stream holds two copies; got {stream_len}")
         return BlockDiffusion(stream_len // 2, self.block_diffusion.block)
+
+    def rotary(self, kind: Optional[str] = None) -> Rotary:
+        """The rotary table of a layer of ``kind``."""
+        return dict(self.layer_rotary or ()).get(kind, Rotary(self.rope_theta))
+
+    @property
+    def layer_period(self) -> int:
+        """Layers after which ``layer_types`` repeats: what the scanned loop's
+        body holds (1 for a stack of one kind)."""
+        kinds = self.layer_types
+        if kinds is None:
+            return 1
+        return next(p for p in range(1, len(kinds) + 1)
+                    if len(kinds) % p == 0 and kinds == kinds[:p] * (len(kinds) // p))
 
     @property
     def aux_shape(self) -> Tuple[int, ...]:
@@ -455,6 +500,12 @@ class TinyGPTConfig:
                 "the pipeline schedules run next-token stages; block diffusion "
                 "builds its stream and weighs its loss in forward(). Run this "
                 "config with pipe=1"
+            )
+        if self.layer_types is not None:
+            raise ValueError(
+                "the pipeline schedules slice one homogeneous stack; layer_types "
+                "gives each layer a kind of its own (sliding_window layers beside "
+                "global ones). Run this config with pipe=1"
             )
 
     def __post_init__(self):
@@ -536,6 +587,45 @@ class TinyGPTConfig:
             )
         if self.qk_norm not in (False, True, "head"):
             raise ValueError(f"qk_norm must be False|True|'head', got {self.qk_norm!r}")
+        kinds = self.layer_types
+        if kinds is not None:
+            if len(kinds) != self.n_layer or any(k not in LAYER_KINDS for k in kinds):
+                raise ValueError(
+                    f"layer_types names one of {LAYER_KINDS} for each of the "
+                    f"{self.n_layer} layers; got {kinds}"
+                )
+            if self.attention_impl not in ("flash", "reference") or (
+                    self.seq_manual_axis is not None):
+                raise ValueError(
+                    "layer_types (sliding_window layers beside global ones) runs "
+                    "attention_impl 'flash' or 'reference' on whole sequences: ring "
+                    "attention, Ulysses and the sequence-parallel pipeline cut the "
+                    "sequence, and their bodies take causal or no mask only; got "
+                    f"attention_impl={self.attention_impl!r}, "
+                    f"seq_manual_axis={self.seq_manual_axis!r}"
+                )
+            if (not self.causal or self.latent_attention or self.first_k_dense
+                    or self.block_diffusion is not None):
+                raise ValueError(
+                    "layer_types mixes causal layers of ordinary attention in one "
+                    "stack: causal=True, no kv_lora_rank, no first_k_dense, no "
+                    "block_diffusion"
+                )
+        if (scopes.WINDOW in (kinds or ())) != (self.sliding_window is not None) or (
+                self.sliding_window is not None and self.sliding_window < 1):
+            raise ValueError(
+                "sliding_window (>= 1 keys, the query's own included) is what a "
+                f"'window' layer of layer_types sees; got sliding_window="
+                f"{self.sliding_window}, layer_types={kinds}"
+            )
+        if self.layer_rotary is not None and (
+                self.pos_embed != "rope" or kinds is None
+                or any(k not in kinds or not isinstance(r, Rotary)
+                       for k, r in self.layer_rotary)):
+            raise ValueError(
+                "layer_rotary gives ((kind, Rotary), ...) for kinds of layer_types "
+                f"under pos_embed='rope'; got {self.layer_rotary}"
+            )
         bd = self.block_diffusion
         if bd is not None:
             if self.attention_impl not in ("flash", "reference") or (
@@ -843,8 +933,10 @@ def _attention(
     v: jax.Array,
     dropout_key: Optional[jax.Array],
     deterministic: bool,
+    kind: Optional[str] = None,
 ) -> jax.Array:
     """Dispatch to the configured attention implementation. Returns (B,S,H,Dh).
+    ``kind`` is the layer's (``TinyGPTConfig.layer_types``): its mask rule.
 
     Attention-probability dropout (reference train_harness.py:116) applies in
     ALL THREE impls: materialized bernoulli in 'reference', and the shared
@@ -865,7 +957,7 @@ def _attention(
         dropout_rate=config.dropout if seed is not None else 0.0,
         dropout_seed=seed,
     )
-    rule = config.mask_rule(q.shape[1])
+    rule = config.mask_rule(q.shape[1], kind)
     if config.latent_attention and (
         config.seq_manual_axis is not None
         or config.attention_impl not in ("flash", "reference")
@@ -920,7 +1012,7 @@ def _attention(
     scores = jnp.einsum(
         "bqhd,bkhd->bhqk", q, k, preferred_element_type=jnp.float32
     ) * scale
-    if config.block_diffusion is not None:
+    if not isinstance(rule, bool):  # a rule that is an object says which pairs
         pos = jnp.arange(q.shape[1], dtype=jnp.int32)
         scores = jnp.where(
             rule.allowed(pos[:, None], pos[None, :]), scores, jnp.finfo(jnp.float32).min
@@ -1010,9 +1102,12 @@ def _block(
     layer: Params,  # one layer's slice of the stacked block params
     dropout_key: Optional[jax.Array],
     deterministic: bool,
+    kind: Optional[str] = None,
 ) -> Tuple[jax.Array, jax.Array]:
     """Pre-LN transformer block -> (x, aux) where aux is the MoE load-balance
-    loss contribution (0 for dense blocks).
+    loss contribution (0 for dense blocks). ``kind`` is the layer's, of
+    ``config.layer_types``, static: its attention runs under a scope of that
+    name, with that kind's mask rule and rotary table.
 
     Parity: reference train_harness.py:108-131 for the dense path."""
     c = config
@@ -1043,7 +1138,11 @@ def _block(
             "for pipeline arms)"
         )
     with jax.named_scope(scopes.ATTENTION):
-        x = _attention_sublayer(c, x, layer, keys[0], deterministic)
+        if kind is None:
+            x = _attention_sublayer(c, x, layer, keys[0], deterministic)
+        else:
+            with jax.named_scope(kind):
+                x = _attention_sublayer(c, x, layer, keys[0], deterministic, kind)
     with jax.named_scope(scopes.MLP):
         return _mlp_sublayer(c, x, layer, keys[1], deterministic)
 
@@ -1054,6 +1153,7 @@ def _attention_sublayer(
     layer: Params,
     dropout_key: Optional[jax.Array],
     deterministic: bool,
+    kind: Optional[str] = None,
 ) -> jax.Array:
     """Norm -> q/k/v projections -> QK-norm -> rope -> attention -> output
     projection -> residual: the first half of ``_block``."""
@@ -1116,8 +1216,9 @@ def _attention_sublayer(
             pos = pos + S * lax.axis_index(c.seq_manual_axis)
         if c.block_diffusion is not None:
             pos = pos % (S // 2)  # both copies of the document at 0..L-1
-        q = _rope(q, pos, c.rope_theta)
-        k = _rope(k, pos, c.rope_theta)
+        rotary = c.rotary(kind)
+        q = _rope(q, pos, rotary.theta, rotary.scaling)
+        k = _rope(k, pos, rotary.theta, rotary.scaling)
     if c.kv_heads != c.n_head:
         # Broadcast each K/V head to its query group. Consecutive-block
         # repetition matches the TP layout: query-head shard j needs exactly
@@ -1128,7 +1229,7 @@ def _attention_sublayer(
         rep = c.n_head // c.kv_heads
         k = jnp.repeat(k, rep, axis=2)
         v = jnp.repeat(v, rep, axis=2)
-    attn = _attention(c, q, k, v, dropout_key, deterministic)
+    attn = _attention(c, q, k, v, dropout_key, deterministic, kind)
     attn = attn.reshape(B, S, c.n_head * c.head_dim)
     if use_cmm:
         attn = _cm.rs_proj(attn, layer["wo"].astype(cd)).astype(cd)
@@ -1352,6 +1453,42 @@ def bd_mask_stats(config: TinyGPTConfig, seq_len: int) -> Dict[str, int]:
     }
 
 
+def attn_mask_stats(config: TinyGPTConfig, seq_len: int) -> Dict[str, Dict[str, int]]:
+    """Counters of one head's attention over ``seq_len`` positions by kind of
+    layer (``layer_types``; one entry, ``global``, for a stack of one kind),
+    from each kind's mask rule at the tiles and pieces ``ops.flash_attention``
+    picks (no array is made): ``layers`` of the kind, ``true_pairs`` the rule
+    allows, and for the forward and the fused backward kernel ``*_live_tiles``
+    (tiles that hold a pair), ``*_grid_steps`` (steps a head's grid makes: the
+    square's under causal, the band's under a window; the difference brings a
+    tile, or holds the last one, and multiplies nothing) and
+    ``*_pairs_multiplied`` (the area of what the bodies walk: a *lower* tile's
+    pieces on and below its piece diagonal, else whole tiles)."""
+    from ..ops import flash_attention as fa
+
+    kinds = config.layer_types or (scopes.GLOBAL,) * config.n_layer
+    stats = {}
+    for kind in sorted(set(kinds)):
+        rule = config.mask_rule(seq_len, kind if config.layer_types else None)
+        bq, bk, bk_bwd, _ = fa.pick_tiles(
+            seq_len, config.qk_dim, config.compute_dtype, causal=rule)
+        window = isinstance(rule, fa.SlidingWindow)
+        entry = {"layers": kinds.count(kind),
+                 "true_pairs": (rule.true_pairs(seq_len) if window
+                                else seq_len * (seq_len + 1) // 2 if rule else seq_len ** 2)}
+        for name, keys, piece in (("fwd", bk, fa._fwd_sub_k(bk)),
+                                  ("bwd", bk_bwd, fa._bwd_sub_q(bq, config.dropout))):
+            units, _, unit_pairs = fa.visited_units(rule, seq_len, bq, keys, piece)
+            tiles = fa.tiles_by_shape(rule, seq_len, bq, keys, piece)
+            entry[f"{name}_live_tiles"] = int(sum(t.sum() for t in tiles.values()))
+            entry[f"{name}_grid_steps"] = (
+                rule.grid_counts(seq_len, bq, keys, name == "fwd")[1] if window
+                else (seq_len // bq) * (seq_len // keys))
+            entry[f"{name}_pairs_multiplied"] = units * unit_pairs
+        stats[kind] = entry
+    return stats
+
+
 def apply_blocks(
     config: TinyGPTConfig,
     blocks: Params,  # stacked block params, leading 'layers' axis (may be a slice)
@@ -1368,10 +1505,87 @@ def apply_blocks(
 
     Returns (x, aux_sum): aux_sum accumulates MoE load-balance contributions
     over the scanned layers (0 for dense models).
+
+    Under ``layer_types`` each layer gets its kind, statically: the unrolled
+    loop by the layer's index, the scanned loop by scanning whole periods of
+    the pattern (the stack viewed as (periods, period), the body a period's
+    layers in a row), so the given stack starts and ends on a period.
     """
     c = config
-    block = functools.partial(_block, c, deterministic=deterministic)
     pol = normalize_remat(c.remat)
+    wrapped = {}
+
+    def block_of(kind):
+        """``_block`` for layers of ``kind`` under the remat policy, made once."""
+        if kind not in wrapped:
+            wrapped[kind] = _under_remat(
+                pol, functools.partial(_block, c, deterministic=deterministic, kind=kind))
+        return wrapped[kind]
+
+    def kind_at(i):  # of the i-th layer of the given stack
+        return None if c.layer_types is None else c.layer_types[layer_offset + i]
+
+    # Inside a partially-manual shard_map (the pipeline), x is varying over
+    # the manual axes; the scalar aux carry must match that type or the scan
+    # rejects the carry (invariant in, varying out after the first MoE add).
+    def _aux0():
+        from ..utils.vma import pcast_like
+
+        return pcast_like(jnp.zeros(c.aux_shape, jnp.float32), x)
+
+    n_local = jax.tree_util.tree_leaves(blocks)[0].shape[0]
+    if not c.scan_layers:
+        aux = _aux0()
+        live = base_key is not None and not deterministic
+        for i in range(n_local):
+            layer = jax.tree_util.tree_map(lambda t: t[i], blocks)
+            ki = (
+                jax.random.fold_in(base_key, layer_offset + i) if live else None
+            )
+            x, a = block_of(kind_at(i))(x, _constrain_layer(c, layer), ki)
+            aux = aux + a
+        return x, aux
+
+    def _pin_carry(x):
+        # Scan-carry placement (round 15): pinning the residual stream at
+        # the body boundary pins the backward's stacked activation-stash
+        # layout with it — without this XLA picks a stash layout of its own
+        # and reconciles per iteration with collective-permute chains (the
+        # banked llama-fsdp-dp4-tp2-scan reshard residue).
+        if c.scan_carry_spec is None:
+            return x
+        return lax.with_sharding_constraint(x, c.scan_carry_spec)
+
+    period = c.layer_period
+    if period > 1:
+        if n_local % period or layer_offset % period:
+            raise ValueError(
+                f"the scanned layer loop scans whole periods of layer_types ({period} "
+                f"layers); got {n_local} layers from layer {layer_offset}"
+            )
+        # (periods, period, ...): one scan step runs a period's layers in a row
+        blocks = jax.tree_util.tree_map(
+            lambda t: t.reshape(n_local // period, period, *t.shape[1:]), blocks)
+    live = base_key is not None and not deterministic
+
+    def scan_body(carry, li):
+        x, aux = carry
+        layers, idx = li if live else (li, None)
+        for j in range(period):
+            layer = layers if period == 1 else jax.tree_util.tree_map(lambda t: t[j], layers)
+            key = jax.random.fold_in(base_key, idx[j]) if live else None
+            x, a = block_of(kind_at(j))(_pin_carry(x), _constrain_layer(c, layer), key)
+            aux = aux + a
+        return (x, aux), None
+
+    # the layers' global indices key their dropout: scanned beside them when it is on
+    idxs = (jnp.arange(n_local) + layer_offset).reshape(n_local // period, period)
+    (x, aux), _ = lax.scan(scan_body, (x, _aux0()), (blocks, idxs) if live else blocks)
+    return x, aux
+
+
+def _under_remat(pol: str, block):
+    """``block`` under the layer loop's remat policy ``pol`` (normalized)."""
     if pol == "full":
         block = jax.checkpoint(block)
     elif pol == "dots":
@@ -1390,61 +1604,7 @@ def apply_blocks(
                 policies.save_only_these_names(*FLASH_RESIDUAL_NAMES),
             ),
         )
-
-    # Inside a partially-manual shard_map (the pipeline), x is varying over
-    # the manual axes; the scalar aux carry must match that type or the scan
-    # rejects the carry (invariant in, varying out after the first MoE add).
-    def _aux0():
-        from ..utils.vma import pcast_like
-
-        return pcast_like(jnp.zeros(c.aux_shape, jnp.float32), x)
-
-    if not c.scan_layers:
-        n_local = jax.tree_util.tree_leaves(blocks)[0].shape[0]
-        aux = _aux0()
-        live = base_key is not None and not deterministic
-        for i in range(n_local):
-            layer = jax.tree_util.tree_map(lambda t: t[i], blocks)
-            ki = (
-                jax.random.fold_in(base_key, layer_offset + i) if live else None
-            )
-            x, a = block(x, _constrain_layer(c, layer), ki)
-            aux = aux + a
-        return x, aux
-
-    def _pin_carry(x):
-        # Scan-carry placement (round 15): pinning the residual stream at
-        # the body boundary pins the backward's stacked activation-stash
-        # layout with it — without this XLA picks a stash layout of its own
-        # and reconciles per iteration with collective-permute chains (the
-        # banked llama-fsdp-dp4-tp2-scan reshard residue).
-        if c.scan_carry_spec is None:
-            return x
-        return lax.with_sharding_constraint(x, c.scan_carry_spec)
-
-    if base_key is None or deterministic:
-        def scan_body(carry, layer):
-            x, aux = carry
-            x, a = block(_pin_carry(x), _constrain_layer(c, layer), None)
-            return (x, aux + a), None
-
-        (x, aux), _ = lax.scan(scan_body, (x, _aux0()), blocks)
-    else:
-        n_local = jax.tree_util.tree_leaves(blocks)[0].shape[0]
-        idxs = jnp.arange(n_local) + layer_offset
-
-        def scan_body(carry, li):
-            x, aux = carry
-            x, a = block(
-                _pin_carry(x), _constrain_layer(c, li[0]),
-                jax.random.fold_in(base_key, li[1]),
-            )
-            return (x, aux + a), None
-
-        (x, aux), _ = lax.scan(
-            scan_body, (x, _aux0()), (blocks, idxs)
-        )
-    return x, aux
+    return block
 
 
 def embed_param_names(config: TinyGPTConfig) -> Tuple[str, ...]:
@@ -1628,7 +1788,8 @@ def _walk_routers(config: TinyGPTConfig, params: Params, idx: jax.Array, read):
     found = []
     for i in range(c.n_layer - c.first_k_dense):
         layer = jax.tree_util.tree_map(lambda t: t[i], params["blocks"])
-        x = _attention_sublayer(c, x, layer, None, True)
+        kind = None if c.layer_types is None else c.layer_types[i]
+        x = _attention_sublayer(c, x, layer, None, True, kind)
         found.append(read(c, layer, _norm(c, x, layer["ln2_scale"], layer.get("ln2_bias"))))
         x, _ = _mlp_sublayer(c, x, layer, None, True)
     return found

@@ -17,9 +17,11 @@ Forward (Pallas kernel):
   on: the body walks its keys in pieces of 128, held k-major, each piece's
   QK^T issued under the piece before's softmax (``_flash_fwd_kernel``);
 - also emits the per-row logsumexp, the residual the backward pass needs;
-- the mask is a *rule* (``MaskRule``): none, causal, or block diffusion over a
-  stream of a noisy and a clean copy; inside a live tile it is computed from
-  global positions, and tiles the rule leaves no pair in are skipped;
+- the mask is a *rule* (``MaskRule``): none, causal, block diffusion over a
+  stream of a noisy and a clean copy, or a causal sliding window; inside a
+  live tile it is computed from global positions, and tiles the rule leaves no
+  pair in are skipped, or, under a window, never brought: its band of live
+  tiles is the grid;
 - a live tile has a *shape* (``_tile_shape``): full, or, on the diagonal of
   square tiles, lower; the forward and the fused backward run a body a shape,
   and the lower one leaves out the pieces above the piece diagonal.
@@ -182,20 +184,113 @@ class BlockDiffusion:
         return live, (S // bq) * (S // bk), L * L + L * B
 
 
+@dataclasses.dataclass(frozen=True)
+class SlidingWindow:
+    """A causal window of ``window`` keys: query i sees key j iff
+    i - window < j <= i, its own position and the window - 1 before it (the
+    Hugging Face convention). Every query has a live key, itself. A query's
+    *first visited* piece may hold none: a tile's keys start before
+    i - window for its later queries (``first_piece_live`` is False, and the
+    forward kernel floors its running maximum). True pairs a head over S
+    positions: W (W + 1) / 2 + (S - W) W with W = min(window, S).
+
+    The live tiles are a band along the diagonal, and under this rule the
+    band is the grid of ``flash_fwd`` and ``flash_bwd_fused``: a query tile's
+    steps walk its ``band_steps`` key tiles and no other (``key_tile``), a
+    key tile's steps its query tiles (``query_tile``), where the other rules'
+    grids bring every tile of the square and skip the dead ones' bodies."""
+
+    window: int
+
+    def __post_init__(self):
+        if self.window < 1:
+            raise ValueError(f"a sliding window holds at least the query's own key; got {self.window}")
+
+    def allowed(self, rows, cols):
+        """The rule itself on broadcastable int32 positions: the ``jnp``
+        references' mask."""
+        return (cols <= rows) & (cols > rows - self.window)
+
+    def tile_live(self, q_off, bq, k_off, bk):
+        """Whether the (bq, bk) tile at (q_off, k_off) holds an allowed pair:
+        its first key is not after its last query, and its last key is inside
+        its first query's window. Scalars of a kernel's grid, or numpy arrays
+        of offsets (``tile_counts``)."""
+        return (k_off <= q_off + (bq - 1)) & (k_off + (bk - 1) > q_off - self.window)
+
+    def in_tile(self, q_off, k_off, rows, cols):
+        """The rule inside one live tile: one subtract and one unsigned
+        compare a score, 0 <= row - col < window."""
+        d = lax.sub(rows, cols)
+        return lax.lt(lax.bitcast_convert_type(d, jnp.uint32), jnp.uint32(self.window))
+
+    def true_pairs(self, S: int) -> int:
+        W = min(self.window, S)
+        return W * (W + 1) // 2 + (S - W) * W
+
+    def tile_counts(self, S: int, bq: int, bk: int) -> Tuple[int, int, int]:
+        """(live tiles, all tiles, true pairs) of one head's (S, S) scores at
+        (bq, bk) tiles."""
+        q_off = np.arange(0, S, bq, dtype=np.int64)[:, None]
+        k_off = np.arange(0, S, bk, dtype=np.int64)[None, :]
+        live = int(np.sum(self.tile_live(q_off, bq, k_off, bk)))
+        return live, (S // bq) * (S // bk), self.true_pairs(S)
+
+    # The band as a grid. Key tiles of bk that meet the keys
+    # [q_off - window + 1, q_off + bq - 1] of a query tile, and query tiles of
+    # bq that meet the queries [k_off, k_off + bk + window - 2] of a key tile:
+    # the most any tile meets is the grid's inner extent, the same for every
+    # tile; a tile near an end of the sequence meets fewer (the band's clipped
+    # corner), and where bq != bk some tiles in the middle do too: those steps
+    # bring the nearest tile inside the sequence again (no new DMA: the block
+    # index repeats) and multiply nothing.
+    def band_steps(self, S: int, bq: int, bk: int, keys_inner: bool) -> int:
+        """Inner grid extent: key tiles a query tile walks (the forward), or
+        query tiles a key tile walks (the fused backward)."""
+        if keys_inner:
+            off = np.arange(0, S, bq, dtype=np.int64)
+            first = np.maximum(off - (self.window - 1), 0) // bk
+            last = (off + (bq - 1)) // bk
+        else:
+            off = np.arange(0, S, bk, dtype=np.int64)
+            first = off // bq
+            last = np.minimum(off + (bk + self.window - 2), S - 1) // bq
+        return int((last - first).max()) + 1
+
+    def key_tile(self, qi, bq: int, bk: int, step, steps: int):
+        """The key tile that step ``step`` of ``steps`` brings to query tile
+        ``qi``: the band's last tile (the diagonal's) at the last step,
+        ascending; negative in the clipped corner."""
+        last = qi if bq == bk else (qi * bq + (bq - 1)) // bk
+        return last - (steps - 1) + step
+
+    def query_tile(self, ki, bq: int, bk: int, step):
+        """The query tile that step ``step`` brings to key tile ``ki``: the
+        diagonal's first, ascending; past the last tile in the clipped corner."""
+        return (ki if bq == bk else (ki * bk) // bq) + step
+
+    def grid_counts(self, S: int, bq: int, bk: int, keys_inner: bool) -> Tuple[int, int]:
+        """(live steps, all steps) of one head's band grid."""
+        steps = self.band_steps(S, bq, bk, keys_inner)
+        return self.tile_counts(S, bq, bk)[0], (S // (bq if keys_inner else bk)) * steps
+
+
 #: The rule a kernel masks by: ``False`` every pair, ``True`` causal (query i
-#: sees keys j <= i), or a ``BlockDiffusion``.
-MaskRule = Union[bool, BlockDiffusion]
+#: sees keys j <= i), a ``BlockDiffusion`` or a ``SlidingWindow``.
+MaskRule = Union[bool, BlockDiffusion, SlidingWindow]
+_RULES = (BlockDiffusion, SlidingWindow)  # the rules that are objects
 
 
 def first_piece_live(mask: MaskRule) -> bool:
     """Whether every query has a live key in the first compute piece the
     forward kernel visits for it: no mask and causal do (plain flash's tile i
     starts at row i*b and its keys at 0: key 0 is live for every query); block
-    diffusion does not. Where it holds, the running maximum is finite before
+    diffusion does not, nor does a sliding window (a tile's first keys lie
+    before the window of its later queries). Where it holds, the running maximum is finite before
     any masked score is exponentiated, and exp2(NEG_INF * c - m) is exactly 0
     with no second select on p; where it does not, the kernel floors the
     maximum it subtracts (``_flash_fwd_kernel``)."""
-    return not isinstance(mask, BlockDiffusion)
+    return not isinstance(mask, _RULES)
 
 
 #: The shapes a live tile can have, by which of its pieces may hold a pair.
@@ -221,6 +316,9 @@ def _tile_shape(mask: MaskRule, qi, bq: int, ki, bk: int, piece: int):
         return False
     if isinstance(mask, BlockDiffusion):
         return False if piece % mask.block else mask.tile_is_lower(qi * bq, ki * bk)
+    # causal, and a sliding window (its diagonal tile is causal's; the
+    # trailing-edge tile, whose pairs lie above its diagonal where the window
+    # is a multiple of the tile, runs the *full* body)
     return qi == ki
 
 
@@ -473,7 +571,7 @@ def _flash_fwd_kernel(
     seed_ref, bhv_ref, q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr,
     acc_scr,
     *, bq: int, bk: int, sub_k: int, scale: float, mask: MaskRule,
-    dropout_rate: float,
+    dropout_rate: float, band: Optional[int] = None,
 ):
     """One grid step brings the operands of a (bq, bk) score tile into VMEM
     (the DMA tile) and walks its keys in compute pieces of ``sub_k``,
@@ -502,17 +600,24 @@ def _flash_fwd_kernel(
     runs a body of its own under its own ``pl.when``: the same pieces and the
     same update, each piece against the queries it may hold a pair with and
     no other (``_accumulate_lower``).
+
+    The grid's last axis is a query tile's steps. Under every rule but one a
+    step is a key tile, all of them in turn; under a ``SlidingWindow`` the
+    ``band`` steps are the key tiles of the query tile's band and no other
+    (``SlidingWindow.key_tile``; negative in the band's clipped corner, where
+    the step multiplies nothing).
     """
     bh = pl.program_id(0)
     qi = pl.program_id(1)
-    ki = pl.program_id(2)
-    nk = pl.num_programs(2)
+    step = pl.program_id(2)
+    steps = pl.num_programs(2)
+    ki = step if band is None else mask.key_tile(qi, bq, bk, step, band)
     c = scale * _LOG2_E
     keep_prob = 1.0 - dropout_rate
-    bd = isinstance(mask, BlockDiffusion)
-    causal = not bd and mask
+    ruled = isinstance(mask, _RULES)
+    causal = not ruled and mask
 
-    @pl.when(ki == 0)
+    @pl.when(step == 0)
     def _init():
         m_scr[:] = jnp.full_like(m_scr, NEG_INF)
         l_scr[:] = jnp.zeros_like(l_scr)
@@ -520,8 +625,10 @@ def _flash_fwd_kernel(
 
     # Tiles the rule leaves no pair in contribute nothing — skip their compute
     # entirely: with causal masking the k blocks strictly above the diagonal.
-    if bd:
+    if ruled:
         live = mask.tile_live(qi * bq, bq, ki * bk, bk)
+        if band is not None:
+            live &= ki >= 0
     else:
         live = (not causal) or (ki * bk < (qi + 1) * bq)
 
@@ -537,7 +644,7 @@ def _flash_fwd_kernel(
         if causal:
             # ``first_piece_live``: m is finite from a query's first piece.
             s = _fill_where(lax.ge(rows, cols), s, NEG_INF)
-        elif bd:
+        elif ruled:
             s = _fill_where(
                 mask.in_tile(qi * bq, ki * bk, rows, cols), s, NEG_INF
             )
@@ -651,7 +758,7 @@ def _flash_fwd_kernel(
         pl.when(live & lower)(_accumulate_lower)
         pl.when(live & ~lower)(_accumulate)
 
-    @pl.when(ki == nk - 1)
+    @pl.when(step == steps - 1)
     def _finalize():
         l = l_scr[:] * keep_prob
         l_safe = jnp.where(l == 0.0, 1.0, l)  # fully-masked rows -> zero output
@@ -683,7 +790,7 @@ def _struct(shape, dtype, vma):
 def _dense_mask(mask: MaskRule, rows, cols):
     """The rule on broadcastable global positions, as the ``jnp`` paths
     materialize it; None where every pair is allowed."""
-    if isinstance(mask, BlockDiffusion):
+    if isinstance(mask, _RULES):
         return mask.allowed(rows, cols)
     return (rows >= cols) if mask else None
 
@@ -748,23 +855,34 @@ def _forward_call(
     rule, and a kernel's trace was a third of ``mistral-7b.d2``'s warm
     set-up in the program (PERF.md section 6, PR 37). The jaxpr a caller sees
     is the same either way. Whoever patches what a kernel's body reads
-    (``_tile_shape``, ``first_piece_live``) calls ``forget_kernel_calls``."""
+    (``_tile_shape``, ``first_piece_live``) calls ``forget_kernel_calls``.
+
+    Under a ``SlidingWindow`` the grid's last axis is the band and not the
+    square's row: ``band_steps`` steps a query tile, the key tile's block
+    index computed from the step (held at 0 in the clipped corner, where the
+    block repeats and nothing is fetched anew)."""
+    band = mask.band_steps(S, bq, bk, True) if isinstance(mask, SlidingWindow) else None
+    if band is None:
+        key_spec = lambda b, qi, ki: (b, ki, 0)
+    else:
+        key_spec = lambda b, qi, step: (
+            b, jnp.maximum(mask.key_tile(qi, bq, bk, step, band), 0), 0)
     return pl.pallas_call(
         functools.partial(
             _flash_fwd_kernel, bq=bq, bk=bk, sub_k=sub_k, scale=scale,
-            mask=mask, dropout_rate=dropout_rate,
+            mask=mask, dropout_rate=dropout_rate, band=band,
         ),
         out_shape=[
             _struct((BH, S, Dv), dtype, vma),
             _struct((BH, 8, S), jnp.float32, vma),
         ],
-        grid=(BH, S // bq, S // bk),
+        grid=(BH, S // bq, S // bk if band is None else band),
         in_specs=[
             pl.BlockSpec(memory_space=pltpu.SMEM),  # dropout seed (1,) uint32
             pl.BlockSpec(memory_space=pltpu.SMEM),  # global bh ids (BH,)
             pl.BlockSpec((1, bq, D), lambda b, qi, ki: (b, qi, 0)),
-            pl.BlockSpec((1, bk, D), lambda b, qi, ki: (b, ki, 0)),
-            pl.BlockSpec((1, bk, Dv), lambda b, qi, ki: (b, ki, 0)),
+            pl.BlockSpec((1, bk, D), key_spec),
+            pl.BlockSpec((1, bk, Dv), key_spec),
         ],
         out_specs=[
             pl.BlockSpec((1, bq, Dv), lambda b, qi, ki: (b, qi, 0)),
@@ -855,7 +973,7 @@ def _tile_rule(mask: MaskRule, q_off, bq: int, k_off, bk: int):
     """The backward kernels' two uses of the rule at the (bq, bk) tile at
     (q_off, k_off) -> (whether the tile holds an allowed pair: a Python True
     without a mask; (rows, cols) -> the allowed pairs inside it, or None)."""
-    if isinstance(mask, BlockDiffusion):
+    if isinstance(mask, _RULES):
         return (mask.tile_live(q_off, bq, k_off, bk),
                 functools.partial(mask.in_tile, q_off, k_off))
     if mask:
@@ -1096,7 +1214,7 @@ def _bwd_fused_kernel(
     seed_ref, bhv_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     dq_ref, dk_ref, dv_ref, dq_acc, dk_acc, dv_acc,
     *, bq: int, bk: int, sub_q: int, scale: float, mask: MaskRule,
-    dropout_rate: float,
+    dropout_rate: float, band: Optional[int] = None,
 ):
     """dq, dk and dv from ONE visit of each live (k tile, q tile): s, p, dp,
     the keep mask and ds are computed once and feed all three products
@@ -1134,28 +1252,47 @@ def _bwd_fused_kernel(
     once a piece. Against the kernel pair that
     moves dk and dq by f32 rounding of the folded factors and by bf16
     rounding of ds before ``scale`` instead of after it; the keep mask is
-    the same bits."""
+    the same bits.
+
+    Under a ``SlidingWindow`` the grid's last axis is the ``band`` query tiles
+    a key tile meets and no other (``SlidingWindow.query_tile``; past the
+    last tile in the band's clipped corner, where the step multiplies
+    nothing): a q tile's slice of ``dq_acc`` is zeroed at the first key tile
+    of its band and written out at the last, the diagonal's."""
     bh = pl.program_id(0)
     ki = pl.program_id(1)
-    qi = pl.program_id(2)
+    step = pl.program_id(2)
     nk = pl.num_programs(1)
-    nq = pl.num_programs(2)
+    steps = pl.num_programs(2)
+    if band is None:
+        qi = step
+    else:
+        qi = mask.query_tile(ki, bq, bk, step)
+        inside = qi < dq_acc.shape[0] // bq
+        qi = lax.select(inside, qi, lax.full_like(qi, 0))  # a slice that exists
     q_off = qi * bq
     k_off = ki * bk
     q_rows = pl.ds(pl.multiple_of(q_off, bq), bq)
     c = scale * _LOG2_E
     keep_prob = 1.0 - dropout_rate
 
-    @pl.when(qi == 0)
+    @pl.when(step == 0)
     def _init_kv():
         dk_acc[:] = jnp.zeros_like(dk_acc)
         dv_acc[:] = jnp.zeros_like(dv_acc)
 
-    @pl.when(ki == 0)
+    if band is None:
+        first_pass = ki == 0
+    else:
+        first_pass = inside & (ki == lax.max(q_off - (mask.window - 1), 0) // bk)
+
+    @pl.when(first_pass)
     def _init_q():
         dq_acc[q_rows, :] = jnp.zeros((bq, dq_acc.shape[1]), dq_acc.dtype)
 
     live, in_tile = _tile_rule(mask, q_off, bq, k_off, bk)
+    if band is not None:
+        live &= inside
 
     def _accumulate(shape):
         """The tile's query pieces, each against the keys it may hold a pair
@@ -1232,12 +1369,17 @@ def _bwd_fused_kernel(
         pl.when(live & lower)(functools.partial(_accumulate, LOWER))
         pl.when(live & ~lower)(functools.partial(_accumulate, FULL))
 
-    @pl.when(qi == nq - 1)
+    @pl.when(step == steps - 1)
     def _finalize_kv():
         dk_ref[0] = (dk_acc[:] * scale).astype(dk_ref.dtype)
         dv_ref[0] = dv_acc[:].astype(dv_ref.dtype)
 
-    @pl.when(ki == nk - 1)
+    if band is None:
+        last_pass = ki == nk - 1
+    else:
+        last_pass = inside & (ki == (q_off + (bq - 1)) // bk)
+
+    @pl.when(last_pass)
     def _finalize_q():
         dq_ref[0, q_rows, :] = (dq_acc[q_rows, :] * scale).astype(dq_ref.dtype)
 
@@ -1269,24 +1411,31 @@ def _fused_call(
 ):
     """The fused backward's ``pallas_call`` on (seed, bhv, q, k, v, do, lse3,
     delta3), made once a process for a shape and its static choices, as
-    ``_forward_call`` and for its reason."""
-    q_spec = pl.BlockSpec((1, bq, D), lambda b, ki, qi: (b, qi, 0))
+    ``_forward_call`` and for its reason; under a ``SlidingWindow`` its
+    grid's last axis is the band, as the forward's (the query tile's block
+    index held at the last tile in the clipped corner)."""
+    band = mask.band_steps(S, bq, bk, False) if isinstance(mask, SlidingWindow) else None
+    if band is None:
+        q_tile = lambda ki, qi: qi
+    else:
+        q_tile = lambda ki, step: jnp.minimum(mask.query_tile(ki, bq, bk, step), S // bq - 1)
+    q_spec = pl.BlockSpec((1, bq, D), lambda b, ki, qi: (b, q_tile(ki, qi), 0))
     k_spec = pl.BlockSpec((1, bk, D), lambda b, ki, qi: (b, ki, 0))
-    do_spec = pl.BlockSpec((1, bq, Dv), lambda b, ki, qi: (b, qi, 0))
+    do_spec = pl.BlockSpec((1, bq, Dv), lambda b, ki, qi: (b, q_tile(ki, qi), 0))
     v_spec = pl.BlockSpec((1, bk, Dv), lambda b, ki, qi: (b, ki, 0))
-    stat_spec = pl.BlockSpec((1, 8, bq), lambda b, ki, qi: (b, 0, qi))
+    stat_spec = pl.BlockSpec((1, 8, bq), lambda b, ki, qi: (b, 0, q_tile(ki, qi)))
     smem = pl.BlockSpec(memory_space=pltpu.SMEM)
     return pl.pallas_call(
         functools.partial(
             _bwd_fused_kernel, bq=bq, bk=bk, sub_q=sub_q, scale=scale,
-            mask=mask, dropout_rate=rate,
+            mask=mask, dropout_rate=rate, band=band,
         ),
         out_shape=[
             _struct((BH, S, D), dtypes[0], vma),
             _struct((BH, S, D), dtypes[1], vma),
             _struct((BH, S, Dv), dtypes[2], vma),
         ],
-        grid=(BH, S // bk, S // bq),
+        grid=(BH, S // bk, S // bq if band is None else band),
         in_specs=[smem, smem, q_spec, k_spec, v_spec, do_spec,
                   stat_spec, stat_spec],
         out_specs=[
@@ -1558,9 +1707,10 @@ def flash_attention(
 ) -> jax.Array:
     """Multi-head flash attention over (batch, seq, heads, head_dim) inputs.
 
-    ``causal`` is the mask's rule (``MaskRule``): False none, True causal, or
-    a ``BlockDiffusion`` over a stream of S = 2L positions, whose tiles must
-    lie inside one copy of the document.
+    ``causal`` is the mask's rule (``MaskRule``): False none, True causal, a
+    ``BlockDiffusion`` over a stream of S = 2L positions, whose tiles must
+    lie inside one copy of the document, or a ``SlidingWindow``, under which
+    the two kernels' grids walk the band of live tiles and not the square.
 
     q and k share one width, v and the output another (latent attention:
     192-wide keys over 128-wide values, no padding of either); ``scale``
@@ -1637,7 +1787,7 @@ def reference_attention(q, k, v, causal: MaskRule = False, scale=None) -> jax.Ar
     as models.tinygpt's in-model path, without dropout)."""
     scale = _softmax_scale(scale, q.shape[-1])
     s = jnp.einsum("bqhd,bkhd->bhqk", q, k, preferred_element_type=jnp.float32) * scale
-    if isinstance(causal, BlockDiffusion):
+    if isinstance(causal, _RULES):
         rows = jnp.arange(q.shape[1], dtype=jnp.int32)
         s = jnp.where(causal.allowed(rows[:, None], rows[None, :]), s, NEG_INF)
     elif causal:

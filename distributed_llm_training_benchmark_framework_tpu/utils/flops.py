@@ -76,7 +76,8 @@ def forward_flops_per_token(config) -> float:
     """
     if getattr(config, "latent_attention", False) or getattr(config, "first_k_dense", 0) or (
             getattr(config, "block_diffusion", None) is not None
-            or getattr(config, "head_width", None) is not None):
+            or getattr(config, "head_width", None) is not None
+            or getattr(config, "layer_types", None) is not None):
         return _deepseek_forward_flops_per_token(config)
     D, L, V, S = config.n_embd, config.n_layer, config.vocab_size, config.block_size
     H = config.n_head
@@ -117,7 +118,12 @@ def _deepseek_forward_flops_per_token(c) -> float:
     Or a config trained by block diffusion, counted by the DATA token: every
     layer runs over the stream of two copies (2 x its matmuls a data token), a
     document of S tokens has S^2 + S * block true pairs a head (S + block keys
-    a data token), and only the noisy copy goes through the head."""
+    a data token), and only the noisy copy goes through the head.
+
+    Or a stack of more than one kind of layer (``layer_types``): a ``window``
+    layer's scores are counted over its true pairs, W (W + 1) / 2 + (S - W) W
+    a head a sequence of S with W = min(sliding_window, S), the exact count
+    (causal's S / 2 keys a token is the convention for the global ones)."""
     D, H, S = c.n_embd, c.n_head, c.block_size
     attn_tokens = S / 2 if c.causal else S
     copies = 1
@@ -132,6 +138,11 @@ def _deepseek_forward_flops_per_token(c) -> float:
         Dh = c.head_dim
         projections = 2 * D * (H + 2 * c.kv_heads) * Dh + 2 * H * Dh * D
         scores = 4 * attn_tokens * H * Dh
+        windows = (c.layer_types or ()).count("window")
+        if windows:  # the mean over the stack: window layers by their true pairs
+            W = min(c.sliding_window, S)
+            window_tokens = (W * (W + 1) / 2 + (S - W) * W) / S
+            scores *= (windows * window_tokens / attn_tokens + c.n_layer - windows) / c.n_layer
     F = c.mlp_dim
     if c.n_experts > 0:
         routed_rows = c.expert_top_k * c.n_experts_held / c.n_experts

@@ -168,6 +168,9 @@ def estimate_hbm(
     dense_per_layer = dense_per_layer // max(tp, 1)
     if cfg.attention_impl == "reference":
         # scores + probs materialize per head, fp32 softmax: the O(S^2) term.
+        # A sliding-window layer's too: the reference path masks a whole
+        # (S, S) matrix, whatever the rule leaves of it (only the flash
+        # kernels, which keep no score, visit the band alone).
         dense_per_layer += 2 * B * (H // max(tp, 1)) * layer_S * layer_S * 4
     layers_here = L // max(pp, 1)
     from ..models.tinygpt import normalize_remat

@@ -39,6 +39,11 @@ SHARED = "shared"
 # the attention itself (the flash kernels), the output projection.
 MLA_PROJ, MLA_CORE, MLA_OUT = MLA_SCOPES = ("mla_proj", "mla_core", "mla_out")
 
+# Inside ``attention``, where the stack mixes kinds of layer
+# (``TinyGPTConfig.layer_types``): a layer's whole attention sublayer under
+# its kind's name, a sliding-window layer or a global one.
+WINDOW, GLOBAL = LAYER_KIND_SCOPES = ("window", "global")
+
 # Inside ``embed``, under block diffusion (models/tinygpt.py ``bd_stream``):
 # drawing a noise level a block, masking, and joining the noisy copy to the
 # clean one.

@@ -7,6 +7,8 @@ pieces a tile, against materialized attention; the two shortcuts the kernels
 take on the running maximum and on the logsumexp, which the causal shape used
 to justify; and the calls that existed before tracing what they traced."""
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -565,3 +567,220 @@ def test_a_differentiated_call_traces_each_kernel_once_and_in_few_jits(rule, mon
     jax.make_jaxpr(jax.grad(loss, argnums=(0, 1, 2)))(q, q, q)
     assert traced == {"flash_fwd": 1, "flash_bwd_fused": 1}
     assert jit_traces() - before < {"causal": 150, "block-diffusion": 350}[rule]
+
+
+# ---------------------------------------------------------------------------
+# The sliding-window rule (``fa.SlidingWindow``): a causal band, and the grid
+# of the forward and the fused backward kernel is the band.
+# ---------------------------------------------------------------------------
+
+# (S, window, tile): the window below, equal to and above the tile, not a
+# multiple of it, the whole sequence, one key; and tiles that are not square.
+WINDOWS = {
+    "below-the-tile": (512, 64, (128, 128)),
+    "the-tile": (512, 128, (128, 128)),
+    "two-tiles": (512, 256, (128, 128)),
+    "not-a-multiple": (512, 200, (128, 128)),
+    "the-sequence": (512, 512, (128, 128)),
+    "one-key": (256, 1, (128, 128)),
+    "bq>bk": (512, 300, (256, 128)),
+    "bq<bk": (512, 300, (128, 256)),
+}
+
+
+def window_mask(S, window):
+    i, j = np.arange(S)[:, None], np.arange(S)[None, :]
+    return (j <= i) & (j > i - window)
+
+
+def window_operands(S, seed=3):
+    keys = jax.random.split(jax.random.key(seed), 4)
+    q, k, v, do = (jax.random.normal(key, (BH, S, D), jnp.float32) for key in keys)
+    return q, k, v, do, jnp.asarray([7], jnp.uint32), jnp.arange(BH, dtype=jnp.int32)
+
+
+@pytest.mark.parametrize("case", sorted(WINDOWS))
+def test_the_window_rule_is_the_dense_mask_tile_by_tile(case):
+    """``allowed``, ``tile_live``, ``in_tile`` and ``tile_counts`` against the
+    mask written from its definition, and every query has itself."""
+    S, window, (bq, bk) = WINDOWS[case]
+    rule, want = fa.SlidingWindow(window), window_mask(S, window)
+    pos = jnp.arange(S, dtype=jnp.int32)
+    np.testing.assert_array_equal(np.asarray(rule.allowed(pos[:, None], pos[None, :])), want)
+    assert want.diagonal().all() and int(want.sum()) == rule.true_pairs(S)
+    q_offs, k_offs = jnp.arange(0, S, bq, dtype=jnp.int32), jnp.arange(0, S, bk, dtype=jnp.int32)
+
+    def tile_of(q_off, k_off):
+        rows = q_off + jnp.arange(bq, dtype=jnp.int32)[:, None]
+        cols = k_off + jnp.arange(bk, dtype=jnp.int32)[None, :]
+        return rule.tile_live(q_off, bq, k_off, bk), rule.in_tile(q_off, k_off, rows, cols)
+
+    live, inside = jax.jit(jax.vmap(jax.vmap(tile_of, (None, 0)), (0, None)))(q_offs, k_offs)
+    tiles = want.reshape(S // bq, bq, S // bk, bk).transpose(0, 2, 1, 3)
+    np.testing.assert_array_equal(np.asarray(live), tiles.any((2, 3)))
+    np.testing.assert_array_equal(np.asarray(inside), tiles)  # one compare, right everywhere
+    assert rule.tile_counts(S, bq, bk) == (int(tiles.any((2, 3)).sum()), tiles.shape[0] * tiles.shape[1],
+                                          int(want.sum()))
+
+
+@pytest.mark.parametrize("case", sorted(WINDOWS))
+def test_the_band_grid_visits_every_live_tile_and_little_else(case):
+    """``band_steps`` / ``key_tile`` / ``query_tile`` against a brute-force
+    walk: every live tile is some step's, no tile twice, and the steps that
+    multiply nothing are the band's clipped corner (and, where the tiles are
+    not square, the rows whose band is narrower than the widest)."""
+    S, window, (bq, bk) = WINDOWS[case]
+    rule = fa.SlidingWindow(window)
+    live = window_mask(S, window).reshape(S // bq, bq, S // bk, bk).any((1, 3))
+    steps = rule.band_steps(S, bq, bk, True)
+    seen = np.zeros_like(live)
+    for qi in range(S // bq):
+        for step in range(steps):
+            ki = rule.key_tile(qi, bq, bk, step, steps)
+            if 0 <= ki < S // bk:
+                assert not seen[qi, ki]
+                seen[qi, ki] = True
+            else:
+                assert ki < 0  # the clipped corner lies before the sequence
+    assert (seen | ~live).all() and steps == live.sum(1).max()
+    assert rule.grid_counts(S, bq, bk, True) == (int(live.sum()), S // bq * steps)
+    steps = rule.band_steps(S, bq, bk, False)
+    seen = np.zeros_like(live)
+    for ki in range(S // bk):
+        for step in range(steps):
+            qi = rule.query_tile(ki, bq, bk, step)
+            if qi < S // bq:
+                assert not seen[qi, ki]
+                seen[qi, ki] = True
+    assert (seen | ~live).all() and steps == live.sum(0).max()
+    assert rule.grid_counts(S, bq, bk, False) == (int(live.sum()), S // bk * steps)
+
+
+def test_the_cell_shape_walks_31_live_tiles_in_32_steps():
+    """S 16,384 under a window of 1024 at (1024, 1024) tiles: 31 live tiles a
+    head of the square's 256 (7.3 dead steps a live one on the full grid), a
+    band of 2 steps a tile with one clipped; 12.1 % of causal's pairs."""
+    rule = fa.SlidingWindow(1024)
+    assert rule.tile_counts(16384, 1024, 1024) == (31, 256, 16253440)
+    assert rule.grid_counts(16384, 1024, 1024, True) == rule.grid_counts(16384, 1024, 1024, False) == (31, 32)
+    assert round((256 - 31) / 31, 1) == 7.3
+    assert round(100 * 16253440 / (16384 * 16385 // 2), 1) == 12.1
+    units, _, unit = fa.visited_units(rule, 16384, 1024, 1024, 128)
+    assert round(100 * 16253440 / (units * unit), 1) == 64.6  # the *lower* body on the diagonal
+
+
+@pytest.mark.parametrize("rate", [0.0, 0.1], ids=["no-dropout", "dropout"])
+@pytest.mark.parametrize("case", sorted(WINDOWS))
+def test_window_forward_kernel_matches_materialized_attention(case, rate):
+    S, window, (bq, bk) = WINDOWS[case]
+    rule = fa.SlidingWindow(window)
+    q, k, v, _, seed, bhv = window_operands(S)
+    out, lse = fa._flash_forward(q, k, v, rule, True, bq, bk, rate, seed, bhv)
+    ref_out, ref_lse = fa._jnp_reference_forward(q, k, v, rule, rate, seed, bhv)
+    np.testing.assert_allclose(out, ref_out, atol=2e-5, rtol=2e-5)
+    np.testing.assert_allclose(lse, ref_lse, atol=2e-5, rtol=2e-5)
+    if rate == 0.0:
+        want, want_lse = materialized(q, k, v, jnp.asarray(window_mask(S, window)))
+        np.testing.assert_allclose(out, want, atol=2e-5, rtol=2e-5)
+        np.testing.assert_allclose(lse, want_lse, atol=2e-5, rtol=2e-5)
+
+
+@pytest.mark.parametrize("path", ["fused", "pair", "jnp"])
+@pytest.mark.parametrize("case", sorted(WINDOWS))
+def test_window_backward_paths_match_the_gradient_of_materialized_attention(case, path):
+    S, window, (bq, bk) = WINDOWS[case]
+    rule, mask = fa.SlidingWindow(window), jnp.asarray(window_mask(S, window))
+    q, k, v, do, seed, bhv = window_operands(S)
+    out, lse = materialized(q, k, v, mask)
+    want = jax.grad(lambda q, k, v: jnp.sum(materialized(q, k, v, mask)[0] * do), (0, 1, 2))(q, k, v)
+    if path == "jnp":
+        got = fa._jnp_blockwise_bwd(rule, bk, 0.0, (q, k, v, out, lse, seed, bhv), do)
+    else:
+        lse3 = jnp.broadcast_to(lse[:, None, :], (BH, 8, S))
+        delta3 = jnp.broadcast_to(jnp.sum(do * out, -1)[:, None, :], (BH, 8, S))
+        backward = fa._fused_backward if path == "fused" else fa._pair_backward
+        got = backward(q, k, v, do, lse3, delta3, seed, bhv, rule, 0.0, bq, bk, True)
+    for g, w in zip(got, want):
+        np.testing.assert_allclose(g, w, atol=3e-5, rtol=3e-5)
+
+
+@pytest.mark.parametrize("window", [96, 256], ids=["pieces-cut", "a-tile"])
+def test_window_bodies_in_pieces_give_the_whole_tile_walks_results(window, monkeypatch):
+    """Square tiles of two pieces: the diagonal tile runs the *lower* body,
+    forward and backward, with dropout; against the walk with every tile *full*."""
+    S, rule = 1024, fa.SlidingWindow(window)
+    q, k, v, do, seed, bhv = window_operands(S)
+
+    def both():
+        out, lse = fa._flash_forward(q, k, v, rule, True, TILE, TILE, 0.1, seed, bhv, sub_k=PIECE)
+        lse3 = jnp.broadcast_to(lse[:, None, :], (BH, 8, S))
+        delta3 = jnp.broadcast_to(jnp.sum(do * out, -1)[:, None, :], (BH, 8, S))
+        return (out, lse) + tuple(fa._fused_backward(
+            q, k, v, do, lse3, delta3, seed, bhv, rule, 0.1, TILE, TILE, True, sub=PIECE))
+
+    assert fa.tiles_by_shape(rule, S, TILE, TILE, PIECE)[fa.LOWER].sum() == S // TILE
+    shaped = both()
+    shapes_off(monkeypatch)
+    for got, want in zip(shaped, both()):
+        np.testing.assert_allclose(got, want, atol=1e-6, rtol=1e-6)
+
+
+def test_flash_attention_under_a_window_differentiates_to_the_reference():
+    """The public call: (B, S, H, D) operands, the rule as ``causal``."""
+    S, rule = 256, fa.SlidingWindow(80)
+    keys = jax.random.split(jax.random.key(9), 3)
+    q, k, v = (jax.random.normal(key, (1, S, 2, D), jnp.float32) for key in keys)
+
+    def loss(attention):
+        return lambda q, k, v: jnp.sum(jnp.square(attention(q, k, v, causal=rule)))
+
+    flash = functools.partial(fa.flash_attention, block_q=64, block_k=64, block_k_bwd=64)
+    np.testing.assert_allclose(flash(q, k, v, causal=rule), fa.reference_attention(q, k, v, causal=rule),
+                               atol=2e-5, rtol=2e-5)
+    got = jax.grad(loss(flash), (0, 1, 2))(q, k, v)
+    want = jax.grad(loss(fa.reference_attention), (0, 1, 2))(q, k, v)
+    for g, w in zip(got, want):
+        np.testing.assert_allclose(g, w, atol=1e-4, rtol=1e-4)
+
+
+def pallas_calls(fn, *args):
+    """[(name, grid)] of the Pallas calls ``fn`` traces, in order."""
+    def calls(jaxpr):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "pallas_call":
+                yield eqn.params["name"], tuple(eqn.params["grid_mapping"].grid)
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                yield from calls(sub)
+
+    return list(calls(jax.make_jaxpr(fn)(*args).jaxpr))
+
+
+# (heads, S, Dqk, Dv, rule, dropout) of the accepted cells' flash calls on the
+# Mosaic path, and the window layer's: what each differentiated call hands the chip.
+CELL_CALLS = {
+    "tinygpt-a.seq8192": (16, 8192, 64, 64, False, 0.1, (8, 8)),
+    "mistral-7b.d2": (32, 4096, 128, 128, True, 0.0, (4, 4)),
+    "deepseek-v2-lite.share8-seq8192": (16, 8192, 192, 128, True, 0.0, (8, 8)),
+    "sdar-30b-a3b.share8-bd8192": (32, 16384, 128, 128, fa.BlockDiffusion(8192, 4), 0.0, (16, 16)),
+    "mellum2-12b-a2.5b.global": (32, 16384, 128, 128, True, 0.0, (16, 16)),
+    "mellum2-12b-a2.5b.window": (32, 16384, 128, 128, fa.SlidingWindow(1024), 0.0, (16, 2)),
+}
+
+
+@pytest.mark.parametrize("cell", sorted(CELL_CALLS))
+def test_a_cells_call_is_two_kernels_on_the_grid_it_had(cell):
+    """Causal, no mask and block diffusion keep the square's grid, (heads, S /
+    1024, S / 1024), forward and backward; only a window's grid is its band.
+    One forward and one fused backward call a differentiated call."""
+    heads, S, d_qk, d_v, rule, rate, grid = CELL_CALLS[cell]
+    q = jax.ShapeDtypeStruct((1, S, heads, d_qk), jnp.bfloat16)
+    v = jax.ShapeDtypeStruct((1, S, heads, d_v), jnp.bfloat16)
+
+    def loss(q, k, v):
+        out = fa.flash_attention.__wrapped__(
+            q, k, v, causal=rule, interpret=False, dropout_rate=rate,
+            dropout_seed=jnp.uint32(1) if rate else None)
+        return jnp.sum(out.astype(jnp.float32))
+
+    calls = pallas_calls(jax.grad(loss, argnums=(0, 1, 2)), q, q, v)
+    assert calls == [("flash_fwd", (heads,) + grid), ("flash_bwd_fused", (heads,) + grid)]

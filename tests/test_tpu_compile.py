@@ -116,6 +116,26 @@ def test_block_diffusion_kernels_compile(v5e_devices, block):
     assert "flash_fwd" in text and "flash_bwd_fused" in text
 
 
+@pytest.mark.parametrize("window", [1024, 1536, 300], ids=["a-tile", "not-a-multiple", "inside-a-tile"])
+def test_sliding_window_kernels_compile_on_their_band(v5e_devices, window):
+    """Forward and fused backward under the window rule at the Mellum cell's
+    shape (S 16,384, head width 128): the band is the grid, so the operands'
+    block indices are computed from the step (clamped in the clipped corner),
+    and a q tile's slice of the resident dq row is zeroed and written out by
+    conditions on scalars of the grid."""
+    one = SingleDeviceSharding(v5e_devices[0])
+    rule = fa.SlidingWindow(window)
+
+    def loss(q, k, v):
+        out = fa.flash_attention(q, k, v, causal=rule, interpret=False)
+        return jnp.sum(out.astype(jnp.float32))
+
+    q, k, v, _ = _qkv(one, one, 1, 16384, 4, 128)
+    text = _compile(jax.grad(loss, argnums=(0, 1, 2)), q, k, v)
+    assert "flash_fwd" in text and "flash_bwd_fused" in text
+    assert rule.band_steps(16384, 1024, 1024, True) == -(-(window - 1) // 1024) + 1
+
+
 @pytest.mark.parametrize("kernel", ["fwd", "bwd"])
 def test_the_bodies_change_the_mosaic_module_and_a_retrace_does_not(v5e_devices, kernel, monkeypatch):
     """``scripts/flash_mosaic_modules.py``: what a kernel hands the compiler,
