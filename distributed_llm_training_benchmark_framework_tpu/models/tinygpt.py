@@ -900,13 +900,11 @@ def _rope(
     ``apply_rotary_pos_emb`` exactly so the transformers parity test can
     load identical weights. fp32 rotation math, cast back to x.dtype.
     """
+    from ..ops.rotary import rope_angles
+
     Dh = x.shape[-1]
     half = Dh // 2
-    if scaling is None:
-        inv_freq = 1.0 / (theta ** (jnp.arange(0, half, dtype=jnp.float32) * 2.0 / Dh))
-    else:
-        inv_freq = jnp.asarray(scaling.inv_freq(Dh, theta), dtype=jnp.float32)
-    freqs = positions.astype(jnp.float32)[:, None] * inv_freq[None, :]  # (S, Dh/2)
+    freqs = rope_angles(positions, Dh, theta, scaling)  # (S, Dh/2)
     cos = jnp.cos(freqs)[None, :, None, :]  # (1, S, 1, Dh/2)
     sin = jnp.sin(freqs)[None, :, None, :]
     if scaling is not None and scaling.cos_sin_factor != 1.0:
@@ -1103,11 +1101,13 @@ def _block(
     dropout_key: Optional[jax.Array],
     deterministic: bool,
     kind: Optional[str] = None,
+    qk_tables: Optional[Dict] = None,
 ) -> Tuple[jax.Array, jax.Array]:
     """Pre-LN transformer block -> (x, aux) where aux is the MoE load-balance
     loss contribution (0 for dense blocks). ``kind`` is the layer's, of
     ``config.layer_types``, static: its attention runs under a scope of that
-    name, with that kind's mask rule and rotary table.
+    name, with that kind's mask rule and rotary table. ``qk_tables``:
+    ``qk_prologue_tables``, made once for the stack.
 
     Parity: reference train_harness.py:108-131 for the dense path."""
     c = config
@@ -1139,12 +1139,86 @@ def _block(
         )
     with jax.named_scope(scopes.ATTENTION):
         if kind is None:
-            x = _attention_sublayer(c, x, layer, keys[0], deterministic)
+            x = _attention_sublayer(c, x, layer, keys[0], deterministic, None, qk_tables)
         else:
             with jax.named_scope(kind):
-                x = _attention_sublayer(c, x, layer, keys[0], deterministic, kind)
+                x = _attention_sublayer(c, x, layer, keys[0], deterministic, kind, qk_tables)
     with jax.named_scope(scopes.MLP):
         return _mlp_sublayer(c, x, layer, keys[1], deterministic)
+
+
+def _rotary_positions(c: TinyGPTConfig, S: int) -> jax.Array:
+    """(S,) int32: the positions the S rows of a layer's q and k are rotated
+    at. Global token positions; under a sequence-manual pipeline this shard
+    holds positions [shard*S, shard*S + S) (same offset rule as the learned
+    table's dynamic slice in embed()). The zigzag ring redistribution happens
+    INSIDE ring_attention, after rotation, so the rotated rows travel with
+    their tokens. Under block diffusion both copies of the document are at
+    0..L-1."""
+    pos = jnp.arange(S, dtype=jnp.int32)
+    if c.seq_manual_axis is not None:
+        pos = pos + S * lax.axis_index(c.seq_manual_axis)
+    if c.block_diffusion is not None:
+        pos = pos % (S // 2)
+    return pos
+
+
+def _takes_qk_prologue(c: TinyGPTConfig, S: int) -> bool:
+    """Whether the stack's q and k, S rows of them, are ``ops.rotary``'s
+    operand: rotary over whole heads of 128-lane vregs. The per-head norm is
+    the pass's first stage; a norm over all of a layer's features (OLMoE)
+    stays in ``jnp`` before it. Not latent attention's, which rotates 64 of
+    192 lanes of q and a one-head key."""
+    from ..ops import rotary as rotary_ops
+
+    return (c.pos_embed == "rope" and not c.latent_attention
+            and rotary_ops.fits(c.head_dim, S))
+
+
+def qk_prologue_tables(c: TinyGPTConfig, S: int) -> Dict:
+    """{kind of layer: the (S, head_dim) f32 table of cos and sin
+    ``ops.rotary.qk_prologue`` rotates by} (``layer_types``; the one key None
+    for a stack of one kind), made once for the whole stack and handed down
+    to its layers. Empty where the stack's layers keep the
+    ``jnp`` chain: another operand (``_takes_qk_prologue``), or a backend
+    without the kernels."""
+    from ..ops import rotary as rotary_ops
+
+    if not _takes_qk_prologue(c, S) or rotary_ops.kernel_mode() is None:
+        return {}
+    pos = _rotary_positions(c, S)
+    kinds = sorted(set(c.layer_types)) if c.layer_types else (None,)
+    return {
+        kind: rotary_ops.table(pos, c.head_dim, c.rotary(kind).theta, c.rotary(kind).scaling)
+        for kind in kinds
+    }
+
+
+def qk_prologue_stats(config: TinyGPTConfig, seq_len: int) -> Dict[str, int]:
+    """Counters of the pass between the projections and the flash kernels
+    over sequences of ``seq_len`` tokens (a block-diffusion stream is twice
+    that), from the config and the backend at trace time: ``rotary_layers``
+    that rotate q and k at all, ``pass_layers`` of them that take
+    ``ops.rotary``'s one pass here (the rest run the ``jnp`` chain: a cell
+    that fell back says so), ``norm_stage_layers`` of those with the per-head
+    norm inside the pass, and the bytes one layer's pass moves a sequence,
+    ``forward_bytes`` and ``backward_bytes``."""
+    from ..ops import rotary as rotary_ops
+
+    c = config
+    S = seq_len * (2 if c.block_diffusion is not None else 1)
+    rotary_layers = c.n_layer if c.pos_embed == "rope" else 0
+    taken = _takes_qk_prologue(c, S) and rotary_ops.kernel_mode() is not None
+    head = c.qk_norm == "head"
+    moved = rotary_ops.pass_bytes(
+        S, c.n_head * c.head_dim, c.kv_heads * c.head_dim,
+        jnp.dtype(c.compute_dtype).itemsize, head) if taken else {"forward": 0, "backward": 0}
+    return {
+        "rotary_layers": rotary_layers,
+        "pass_layers": rotary_layers if taken else 0,
+        "norm_stage_layers": rotary_layers if taken and head else 0,
+        "forward_bytes": moved["forward"], "backward_bytes": moved["backward"],
+    }
 
 
 def _attention_sublayer(
@@ -1154,9 +1228,14 @@ def _attention_sublayer(
     dropout_key: Optional[jax.Array],
     deterministic: bool,
     kind: Optional[str] = None,
+    qk_tables: Optional[Dict] = None,
 ) -> jax.Array:
     """Norm -> q/k/v projections -> QK-norm -> rope -> attention -> output
-    projection -> residual: the first half of ``_block``."""
+    projection -> residual: the first half of ``_block``. Where the stack's
+    q and k are ``ops.rotary``'s operand and the backend runs its kernels
+    (``qk_prologue_tables`` has this kind's tables), the per-head norm and
+    the rotation are its one pass; else the ``jnp`` chain ``_rms_norm`` ->
+    ``_rope``, which is also what the pass is tested against."""
     B, S, D = x.shape
     cd = c.compute_dtype
     use_cmm = c.tp_collective_matmul
@@ -1199,26 +1278,29 @@ def _attention_sublayer(
     if c.qk_norm and c.qk_norm != "head":
         q = _rms_norm(q, layer["q_norm"], c.norm_eps)
         k = _rms_norm(k, layer["k_norm"], c.norm_eps)
-    q = q.reshape(B, S, c.n_head, c.head_dim)
-    k = k.reshape(B, S, c.kv_heads, c.head_dim)
+    if qk_tables is None:
+        qk_tables = qk_prologue_tables(c, S)
+    head_norm = c.qk_norm == "head"
     v = v.reshape(B, S, c.kv_heads, c.head_dim)
-    if c.qk_norm == "head":
-        q = _rms_norm(q, layer["q_norm"], c.norm_eps)
-        k = _rms_norm(k, layer["k_norm"], c.norm_eps)
-    if c.pos_embed == "rope":
-        # Global token positions; under a sequence-manual pipeline this
-        # shard holds positions [shard*S, shard*S + S) (same offset rule as
-        # the learned table's dynamic slice in embed()). The zigzag ring
-        # redistribution happens INSIDE ring_attention, after rotation, so
-        # the rotated rows travel with their tokens.
-        pos = jnp.arange(S, dtype=jnp.int32)
-        if c.seq_manual_axis is not None:
-            pos = pos + S * lax.axis_index(c.seq_manual_axis)
-        if c.block_diffusion is not None:
-            pos = pos % (S // 2)  # both copies of the document at 0..L-1
-        rotary = c.rotary(kind)
-        q = _rope(q, pos, rotary.theta, rotary.scaling)
-        k = _rope(k, pos, rotary.theta, rotary.scaling)
+    if kind in qk_tables:
+        from ..ops import rotary as rotary_ops
+
+        scales = (layer["q_norm"], layer["k_norm"]) if head_norm else (None, None)
+        with jax.named_scope(scopes.QK_PROLOGUE):
+            q, k = rotary_ops.qk_prologue(  # -> (B, S, heads, head_dim)
+                q, k, *scales, qk_tables[kind], c.norm_eps,
+                interpret=rotary_ops.kernel_mode())
+    else:  # the jnp chain
+        q = q.reshape(B, S, c.n_head, c.head_dim)
+        k = k.reshape(B, S, c.kv_heads, c.head_dim)
+        if head_norm:
+            q = _rms_norm(q, layer["q_norm"], c.norm_eps)
+            k = _rms_norm(k, layer["k_norm"], c.norm_eps)
+        if c.pos_embed == "rope":
+            rotary = c.rotary(kind)
+            pos = _rotary_positions(c, S)
+            q = _rope(q, pos, rotary.theta, rotary.scaling)
+            k = _rope(k, pos, rotary.theta, rotary.scaling)
     if c.kv_heads != c.n_head:
         # Broadcast each K/V head to its query group. Consecutive-block
         # repetition matches the TP layout: query-head shard j needs exactly
@@ -1496,6 +1578,7 @@ def apply_blocks(
     base_key: Optional[jax.Array] = None,
     deterministic: bool = True,
     layer_offset: int = 0,
+    qk_tables: Optional[Dict] = None,
 ) -> jax.Array:
     """Scan the given stacked blocks over x.
 
@@ -1510,16 +1593,22 @@ def apply_blocks(
     loop by the layer's index, the scanned loop by scanning whole periods of
     the pattern (the stack viewed as (periods, period), the body a period's
     layers in a row), so the given stack starts and ends on a period.
+
+    ``qk_tables`` are ``qk_prologue_tables``'s, made here where the caller
+    has none: once for the stack, outside the loop and its remat.
     """
     c = config
     pol = normalize_remat(c.remat)
     wrapped = {}
+    if qk_tables is None:
+        qk_tables = qk_prologue_tables(c, x.shape[1])
 
     def block_of(kind):
         """``_block`` for layers of ``kind`` under the remat policy, made once."""
         if kind not in wrapped:
             wrapped[kind] = _under_remat(
-                pol, functools.partial(_block, c, deterministic=deterministic, kind=kind))
+                pol, lambda x, layer, key, tables: _block(
+                    c, x, layer, key, deterministic, kind, tables))
         return wrapped[kind]
 
     def kind_at(i):  # of the i-th layer of the given stack
@@ -1542,7 +1631,7 @@ def apply_blocks(
             ki = (
                 jax.random.fold_in(base_key, layer_offset + i) if live else None
             )
-            x, a = block_of(kind_at(i))(x, _constrain_layer(c, layer), ki)
+            x, a = block_of(kind_at(i))(x, _constrain_layer(c, layer), ki, qk_tables)
             aux = aux + a
         return x, aux
 
@@ -1574,7 +1663,8 @@ def apply_blocks(
         for j in range(period):
             layer = layers if period == 1 else jax.tree_util.tree_map(lambda t: t[j], layers)
             key = jax.random.fold_in(base_key, idx[j]) if live else None
-            x, a = block_of(kind_at(j))(_pin_carry(x), _constrain_layer(c, layer), key)
+            x, a = block_of(kind_at(j))(
+                _pin_carry(x), _constrain_layer(c, layer), key, qk_tables)
             aux = aux + a
         return (x, aux), None
 
@@ -1674,11 +1764,14 @@ def apply_layers(
     'blocks', each through ``apply_blocks`` (the same loop, remat policy and
     per-layer placement hooks) -> (x, aux_sum)."""
     c = config
+    tables = qk_prologue_tables(c, x.shape[1])  # one set for both stacks
     if not c.first_k_dense:
-        return apply_blocks(c, params["blocks"], x, base_key, deterministic)
-    x, aux_dense = apply_blocks(c, params["dense_blocks"], x, base_key, deterministic)
+        return apply_blocks(c, params["blocks"], x, base_key, deterministic, qk_tables=tables)
+    x, aux_dense = apply_blocks(
+        c, params["dense_blocks"], x, base_key, deterministic, qk_tables=tables)
     x, aux = apply_blocks(
-        c, params["blocks"], x, base_key, deterministic, layer_offset=c.first_k_dense
+        c, params["blocks"], x, base_key, deterministic, layer_offset=c.first_k_dense,
+        qk_tables=tables,
     )
     return x, aux_dense + aux
 

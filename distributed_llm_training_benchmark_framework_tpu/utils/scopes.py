@@ -44,6 +44,12 @@ MLA_PROJ, MLA_CORE, MLA_OUT = MLA_SCOPES = ("mla_proj", "mla_core", "mla_out")
 # its kind's name, a sliding-window layer or a global one.
 WINDOW, GLOBAL = LAYER_KIND_SCOPES = ("window", "global")
 
+# Inside ``attention`` (below the kind's scope where there is one), where a
+# layer's QK-norm and rotary are ``ops/rotary.py``'s one pass: the two Mosaic
+# calls ``qk_prologue_fwd`` / ``qk_prologue_bwd`` and the sum of the scale
+# gradients' partial sums. A layer on the ``jnp`` chain has no such scope.
+QK_PROLOGUE = "qk_prologue"
+
 # Inside ``embed``, under block diffusion (models/tinygpt.py ``bd_stream``):
 # drawing a noise level a block, masking, and joining the noisy copy to the
 # clean one.

@@ -3,16 +3,18 @@
 instructions a scope's milliseconds are, forward, backward and remat's second
 run apart. Reads what a traced run of a benchmark cell leaves behind
 (``perfbench/.trace/<cell>/``: the newest ``.xplane.pb`` and ``step_hlo.txt``)
-with the benchmark's own join (``perfbench/harness/scopes.py``,
-``mla_scopes.py``), so its sums are the readers' sums.
+with the benchmark's own join (``perfbench/harness/scopes.py``), so its sums
+are the readers' sums.
 
     python3 scripts/scope_ops.py perfbench/.trace/<cell> <steps traced> mlp dispatch combine
+    python3 scripts/scope_ops.py perfbench/.trace/<cell> 5 attention qk_prologue -
 
-Prints one line an (part, phase, instruction name without its number): ms a
-step, events a step and the largest result shape; the 30 largest single
-instructions; then, for every instruction name that shows under the parts, how
-its step total splits between the parts and the rest of the step (whose
-``other:reshape`` is it).
+A part is any scope below the module (``utils/scopes.py``); ``-`` stands for
+the module's ops under none of the parts named beside it. Prints one line an
+(part, phase, instruction name without its number): ms a step, events a step
+and the largest result shape; the 30 largest single instructions; then, for
+every instruction name that shows under the parts, how its step total splits
+between the parts and the rest of the step (whose ``other:reshape`` is it).
 """
 
 import collections
@@ -23,9 +25,26 @@ import sys
 
 sys.path.insert(0, os.path.join(os.path.dirname(os.path.abspath(__file__)), ".."))
 
-from perfbench.harness import mla_scopes, scopes, trace_reduce  # noqa: E402
+from perfbench.harness import scopes, trace_reduce  # noqa: E402
 
 SHAPE = re.compile(r" = (\S+)")
+REST = "-"
+
+
+def part_of(op_name, module, parts):
+    """The first of ``parts`` below ``module`` in one of the ``;``-joined paths
+    of an ``op_name`` (the join of ``perfbench/harness/mla_scopes.part``, for
+    any scope's name), ``REST`` where the module is there, none of them is and
+    ``REST`` is asked for, else None."""
+    for path in op_name.split(";"):
+        plain = [scopes._unwrap(c) for c in path.split("/")]
+        if module in plain:
+            below = plain[plain.index(module) + 1:]
+            if found := next((c for c in below if c in parts), None):
+                return found
+            if REST in parts:
+                return REST
+    return None
 
 
 def phase_of(op_name):
@@ -46,10 +65,10 @@ def main(argv):
         op_name = names.get(scopes.instruction_name(event), "")
         base = trace_reduce.base_name(event)
         whole[base] += self_s
-        found = mla_scopes.part(op_name)
-        if not found or found[0] != module or found[1] not in parts:
+        part = part_of(op_name, module, parts)
+        if part is None:
             continue
-        part, phase = found[1], phase_of(op_name)
+        phase = phase_of(op_name)
         inside[base, part] += self_s
         shape = SHAPE.search(event.name)
         shape = shape.group(1) if shape else ""

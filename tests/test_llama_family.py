@@ -440,3 +440,27 @@ def test_flash_matches_reference_impl_llama():
     fl, _ = forward(flash_cfg, params, idx)
     np.testing.assert_allclose(np.asarray(ref), np.asarray(fl),
                                atol=5e-3, rtol=5e-3)
+
+
+def test_through_the_one_pass_prologue_the_loss_and_gradients_are_the_chains(monkeypatch):
+    """On a chip a llama-family layer with heads of 128 lanes rotates q and k
+    in ``ops/rotary.py``'s one pass (rotary alone: no QK-norm, Mistral's
+    case). One small layer, GQA, batch 2, with the kernels interpreted,
+    against the ``jnp`` chain (``_rope``) the cases above pin."""
+    from distributed_llm_training_benchmark_framework_tpu.models import tinygpt
+    from distributed_llm_training_benchmark_framework_tpu.ops import rotary
+
+    cfg = llama_cfg(n_embd=512, n_layer=1, block_size=64, attention_impl="flash")
+    params = init_params(cfg, jax.random.key(0))
+    idx = jax.random.randint(jax.random.key(1), (2, 64), 0, cfg.vocab_size)
+    run = lambda: jax.value_and_grad(lambda p: tinygpt.loss_fn(cfg, p, idx, idx))(params)
+    want_loss, want = run()
+    monkeypatch.setattr(rotary, "kernel_mode", lambda: True)  # as a chip, interpreted
+    stats = tinygpt.qk_prologue_stats(cfg, 64)
+    assert (stats["rotary_layers"], stats["pass_layers"], stats["norm_stage_layers"]) == (1, 1, 0)
+    got_loss, got = run()
+    np.testing.assert_allclose(float(got_loss), float(want_loss), rtol=1e-6)
+    for (path, g), w in zip(jax.tree_util.tree_leaves_with_path(got), jax.tree.leaves(want)):
+        np.testing.assert_allclose(
+            np.asarray(g), np.asarray(w), atol=5e-3 * float(jnp.abs(w).max()), rtol=5e-3,
+            err_msg=jax.tree_util.keystr(path))

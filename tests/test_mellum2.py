@@ -398,3 +398,34 @@ def test_mask_stats_count_tiles_steps_and_pairs_by_kind():
     assert round(100 * fill, 1) == 63.3
     plain = dataclasses.replace(config, layer_types=None, sliding_window=None, layer_rotary=None)
     assert tinygpt.attn_mask_stats(plain, 16384) == {GLOBAL: {**whole, "layers": 4}}
+
+
+def test_through_the_one_pass_prologue_the_loss_and_gradients_are_the_chains(batch, monkeypatch):
+    """On a chip each layer's per-head QK-norm and rotation are
+    ``ops/rotary.py``'s one pass against its kind's table (plain theta on the
+    window layers, YaRN with its factor on the global one). A window layer and
+    a global one at the cell's head width with the kernels interpreted, scanned
+    under the cell's remat, against the ``jnp`` chain the cases above hold to
+    the reference."""
+    from distributed_llm_training_benchmark_framework_tpu.ops import rotary
+
+    file = {**FILE, "head_dim": 128, "num_hidden_layers": 2,
+            "layer_types": ["sliding_attention", "full_attention"] * 14}
+    config = dataclasses.replace(
+        build_mellum.mellum_config(JOB, file), compute_dtype=jnp.float32, remat="dots",
+        scan_layers=True, attention_impl="reference")  # whichever attention follows the pass
+    weights = seeded_weights(config)
+    run = lambda: jax.jit(jax.value_and_grad(
+        lambda p: tinygpt.loss_fn(config, p, batch, batch)))(weights)
+    want_loss, want = run()
+    monkeypatch.setattr(rotary, "kernel_mode", lambda: True)  # as a chip, interpreted
+    stats = tinygpt.qk_prologue_stats(config, SEQ)
+    assert (stats["rotary_layers"], stats["pass_layers"], stats["norm_stage_layers"]) == (2, 2, 2)
+    assert set(tinygpt.qk_prologue_tables(config, SEQ)) == {WINDOW, GLOBAL}
+    got_loss, got = run()
+    assert abs(float(got_loss) - float(want_loss)) / float(want_loss) < TOLERANCE["loss"]
+    got["blocks"].pop("router"), want["blocks"].pop("router")  # not trained: zero on both
+    errors = jax.tree.map(
+        lambda g, w: float(jnp.linalg.norm(g - w) / jnp.linalg.norm(w)), got, want)
+    for path, error in jax.tree_util.tree_leaves_with_path(errors):
+        assert error < TOLERANCE["grad_leaf"], (jax.tree_util.keystr(path), error)

@@ -362,3 +362,31 @@ def test_the_norm_scales_are_replicated_over_the_model_axis():
     specs = strategies.param_partition_specs(shapes, mesh, shard=False, kv_heads=2)
     assert tuple(specs["blocks"]["q_norm"]) == tuple(specs["blocks"]["k_norm"]) == (None, None)
     assert "model" in tuple(specs["blocks"]["wq"])
+
+
+def test_through_the_one_pass_prologue_the_loss_and_gradients_are_the_chains(batch, monkeypatch):
+    """On a chip the per-head QK-norm and the rotation at i mod L are
+    ``ops/rotary.py``'s one pass. One layer at the cell's head width with the
+    kernels interpreted, under the cell's remat, against the ``jnp`` chain the
+    cases above hold to the reference: the same loss, every leaf's gradient."""
+    from distributed_llm_training_benchmark_framework_tpu.ops import rotary
+
+    file = {**FILE, "head_dim": 128, "num_hidden_layers": 1}
+    config = dataclasses.replace(
+        build_bd.sdar_config(JOB, file), compute_dtype=jnp.float32, remat="dots",
+        attention_impl="reference")  # the pass does not care which attention follows it
+    weights = seeded_weights(config)
+    run = lambda: jax.jit(jax.value_and_grad(
+        lambda p: tinygpt.loss_fn(config, p, batch, batch, dropout_key=KEY)))(weights)
+    assert tinygpt.qk_prologue_stats(config, SEQ)["pass_layers"] == 0
+    want_loss, want = run()
+    monkeypatch.setattr(rotary, "kernel_mode", lambda: True)  # as a chip, interpreted
+    stats = tinygpt.qk_prologue_stats(config, SEQ)
+    assert (stats["rotary_layers"], stats["pass_layers"], stats["norm_stage_layers"]) == (1, 1, 1)
+    got_loss, got = run()
+    assert abs(float(got_loss) - float(want_loss)) / float(want_loss) < TOLERANCE["loss"]
+    got["blocks"].pop("router"), want["blocks"].pop("router")  # not trained: zero on both
+    errors = jax.tree.map(
+        lambda g, w: float(jnp.linalg.norm(g - w) / jnp.linalg.norm(w)), got, want)
+    for path, error in jax.tree_util.tree_leaves_with_path(errors):
+        assert error < TOLERANCE["grad_leaf"], (jax.tree_util.keystr(path), error)

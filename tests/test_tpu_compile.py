@@ -13,6 +13,7 @@ described chip cannot be read back without one. One process at a time —
 libtpu's lock file refuses a second.
 """
 
+import functools
 import os
 
 os.environ.setdefault("TPU_LOG_DIR", "disabled")  # else libtpu logs under /tmp
@@ -567,3 +568,52 @@ def test_flash_compiles_at_latent_attention_widths(v5e_devices):
     assert text.count("tpu_custom_call") == 2
     assert "bf16[16,8192,192]" in text and "bf16[16,8192,128]" in text
     assert "bf16[16,8192,256]" not in text  # nothing padded to a lane multiple in HBM
+
+
+# --- ops/rotary.py: QK-norm and rotary in one pass (about 5 s a compile) ---
+
+def _prologue_loss(norm, q, k, q_scale, k_scale, positions):
+    from distributed_llm_training_benchmark_framework_tpu.ops import rotary
+
+    table = rotary.table(positions, 128, 1e6)
+    q, k = rotary.qk_prologue(
+        q, k, q_scale if norm else None, k_scale if norm else None, table, 1e-6)
+    return jnp.sum(q.astype(jnp.float32)) + jnp.sum(k.astype(jnp.float32))
+
+
+@pytest.mark.parametrize(
+    "batch,seq,heads,kv,norm", [(1, 16384, 32, 4, True), (2, 4096, 32, 8, False)],
+    ids=["sdar-and-mellum-16384x32/4-head-norm", "mistral-2x4096x32/8-rotary-alone"])
+def test_qk_prologue_compiles_at_the_cells_widths(v5e_devices, batch, seq, heads, kv, norm):
+    """Forward and backward of the pass at the claimed cells' operand (16,384
+    rows of a 4096-wide q, 4 KV heads, the per-head norm) and at
+    ``mistral-7b.d2``'s (rotary alone): one Mosaic call a direction, under
+    the scoped VMEM its row block asks for."""
+    one = SingleDeviceSharding(v5e_devices[0])
+    aval = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+    args = (aval((batch, seq, heads * 128), jnp.bfloat16), aval((batch, seq, kv * 128), jnp.bfloat16),
+            aval((128,), jnp.float32), aval((128,), jnp.float32), aval((seq,), jnp.int32))
+    loss = functools.partial(_prologue_loss, norm)
+    text = _compile(jax.value_and_grad(loss, argnums=(0, 1, 2, 3)), *args)
+    assert text.count('custom_call_target="tpu_custom_call"') == 2
+    assert "qk_prologue_fwd" in text and "qk_prologue_bwd" in text
+    assert "flash_" not in text
+
+
+def test_qk_prologue_partitions_over_a_four_device_data_mesh(v5e_devices):
+    """``mistral-7b.fsdp4``'s case: the batch over four chips, one example a
+    chip. The call shard_maps itself, rows are independent, and the only
+    thing exchanged is the sum of the scale gradients."""
+    import numpy as np
+
+    mesh = Mesh(np.asarray(v5e_devices).reshape(4), ("data",))
+    rows, whole = NamedSharding(mesh, P("data")), NamedSharding(mesh, P())
+    aval = lambda shape, dtype, s: jax.ShapeDtypeStruct(shape, dtype, sharding=s)
+    args = (aval((4, 4096, 32 * 128), jnp.bfloat16, rows), aval((4, 4096, 8 * 128), jnp.bfloat16, rows),
+            aval((128,), jnp.float32, whole), aval((128,), jnp.float32, whole),
+            aval((4096,), jnp.int32, whole))
+    with jax.set_mesh(mesh):
+        text = _compile(
+            jax.grad(functools.partial(_prologue_loss, True), argnums=(0, 1, 2, 3)), *args)
+    assert "all-gather" not in text and "all-to-all" not in text
+    assert "bf16[1,4096,4096]" in text  # a chip's own example
