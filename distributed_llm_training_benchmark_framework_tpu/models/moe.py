@@ -11,7 +11,12 @@ by expert, the rows gathered into expert order, the experts run as grouped
 matmuls over the per-expert row counts (the Pallas ``gmm`` / ``tgmm`` that
 ship with jax, ``jax.experimental.pallas.ops.tpu.megablox``; they show in a
 trace under those names), and the rows go back to token order weighted by
-their gates. Experts are SwiGLU without bias (``moe_wgu`` (E, D, 2F), gate
+their gates. Every grouped matmul takes its tiles from its own widths
+(``gmm_tiling``: the contraction and column tiles are multiples of 128 that
+divide what they multiply, the pair nearest the (1024, 1024) PR 26 swept at
+OLMoE's sizes that fits the kernel's VMEM; megablox pads a width to whole tiles
+and multiplies the padding, which at Mellum's 2304 x 1792 was a third of the
+work: PERF.md, PR 42). Experts are SwiGLU without bias (``moe_wgu`` (E, D, 2F), gate
 columns then up columns, and ``moe_wd`` (E, F, D); the config pairs
 ``mlp_act='swiglu'`` with this path); gates are renormalised only if
 ``norm_topk_prob``; the auxiliary channel carries the load-balance term over
@@ -353,9 +358,64 @@ def _route_dropless(c, xt: jax.Array, router: jax.Array, sequences: int = 1):
     return gates, expert_idx, counts, aux
 
 
-# (rows, contraction, columns) tile of the grouped matmuls, from a sweep on the
-# v5e at OLMoE's sizes (scripts/microbench_moe_experts.py; PERF.md, PR 26).
-_GMM_TILING = (512, 1024, 1024)
+# (contraction, columns) tile the grouped matmuls aim at and their row tile: the
+# best of a sweep on the v5e at OLMoE's sizes, whose widths it divides
+# (scripts/microbench_moe_experts.py; PERF.md, PR 26). At other widths
+# ``gmm_tiling`` takes the dividing tiles nearest to it: the rule was read from a
+# sweep of each kernel alone at the DeepSeek, SDAR and Mellum cells' widths (the
+# same script with ``--calls``; PERF.md, PR 42).
+_GMM_TILE = (1024, 1024)
+_ROW_TILE = 512
+# What a grouped matmul's tiles may take of the kernel's 16 MiB of VMEM, counted
+# as ``_tiles_vmem`` counts: on a described v5e the compiler's own allocation read
+# up to 1.1 MiB over that count (PR 42; tests/test_tpu_compile.py compiles the
+# cells' stacks).
+_GMM_VMEM = 14 * 2**20
+
+
+def _dividing_tiles(width: int) -> list[int]:
+    """The multiples of 128 that divide ``width``."""
+    return [t for t in range(128, width + 1, 128) if width % t == 0]
+
+
+def _tiles_vmem(tm: int, tk: int, tn: int) -> int:
+    """Bytes of VMEM a (tm, tk, tn) tiling asks for in bf16: both operand tiles
+    and the result tile twice each (the pipeline's double buffers) and the f32
+    accumulator, in whichever of megablox's kernels asks for more: ``gmm``
+    accumulates (tm, tn), ``tgmm`` (tk, tn). A tiling serves both: megablox
+    looks it up by (m, k, n), which the forward and ``tgmm`` share."""
+    return 4 * (tm * tk + tk * tn + tm * tn) + 4 * max(tm, tk) * tn
+
+
+def _nearest_fitting(tm: int, k: int, n: int, near: Tuple[int, int]) -> Tuple[int, int]:
+    """(tk, tn) that **divide** k and n, so that no tile is part padding
+    (megablox rounds a width up to whole tiles, multiplies them whole and masks
+    afterwards): of the pairs that fit ``_GMM_VMEM`` the nearest to ``near`` by
+    ratio. A width no multiple of 128 divides, and both where no pair fits, keep
+    what the kernel pads: ``near`` or the whole width."""
+    padded = (min(near[0], k), min(near[1], n))
+    distance = lambda pair: sum(abs(math.log(t / to)) for t, to in zip(pair, near))
+    pairs = [(tk, tn) for tk in _dividing_tiles(k) or padded[:1]
+             for tn in _dividing_tiles(n) or padded[1:] if _tiles_vmem(tm, tk, tn) <= _GMM_VMEM]
+    return min(pairs, key=distance, default=padded)
+
+
+def gmm_tiling(m: int, k: int, n: int) -> Tuple[int, int, int]:
+    """(tm, tk, tn) for a grouped matmul of (m, k) rows by (k, n) weights, from
+    the call's own widths: megablox looks it up for the forward ``gmm``, for the
+    rows' gradient (k and n changed places) and for the weights' ``tgmm``. One
+    function, named here: it is a static argument of megablox's jits."""
+    tm = math.gcd(m, _ROW_TILE)
+    return (tm,) + _nearest_fitting(tm, k, n, _GMM_TILE)
+
+
+def gmm_tile_fill(m: int, k: int, n: int, tiling=gmm_tiling) -> float:
+    """Share of what a grouped matmul's tiles cover of its (k, n) that is
+    operand, under ``tiling`` (a tuple, or a function of (m, k, n) as megablox
+    takes one): 1.0 where the tiles divide the widths; the rest the MXU
+    multiplies and the kernel masks away."""
+    _, tk, tn = tiling(m, k, n) if callable(tiling) else tiling
+    return k * n / (-(-k // tk) * tk * -(-n // tn) * tn)
 
 
 def _grouped_matmul(c, rows: jax.Array, weights: jax.Array, counts: jax.Array) -> jax.Array:
@@ -363,10 +423,8 @@ def _grouped_matmul(c, rows: jax.Array, weights: jax.Array, counts: jax.Array) -
     times ``weights[e]``, bf16 MXU / fp32 accumulation. The Pallas grouped
     matmul that ships with jax; its backward is one more ``gmm`` (the rows'
     gradient) and a ``tgmm`` (the weights'). Off a TPU it runs interpreted."""
-    tm, tk, tn = _GMM_TILING
-    tiling = (math.gcd(rows.shape[0], tm), min(tk, rows.shape[1]), min(tn, weights.shape[2]))
     return megablox.gmm(
-        rows, weights.astype(c.compute_dtype), counts, c.compute_dtype, tiling,
+        rows, weights.astype(c.compute_dtype), counts, c.compute_dtype, gmm_tiling,
         interpret=jax.default_backend() != "tpu",
     )
 
@@ -394,6 +452,11 @@ def _shared_experts(c, layer, x: jax.Array) -> jax.Array:
     ).astype(cd)
 
 
+# What the held experts' buffer is rounded up to: whole row tiles of the grouped
+# matmuls as swept. Its own number: the buffers' sizes are part of the cells.
+_HELD_ROWS_MULTIPLE = 512
+
+
 def held_buffer_rows(c, n_tokens: int) -> int:
     """Rows of the held experts' static buffer for ``n_tokens`` tokens: all N x
     K assignments, or ``held_rows_factor`` times the N x K x count / E expected
@@ -402,7 +465,7 @@ def held_buffer_rows(c, n_tokens: int) -> int:
     if c.held_rows_factor is None:
         return assignments
     expected = assignments * c.experts_held[1] / c.n_experts
-    tile = math.gcd(assignments, _GMM_TILING[0])
+    tile = math.gcd(assignments, _HELD_ROWS_MULTIPLE)
     return min(assignments, -(-math.ceil(c.held_rows_factor * expected) // tile) * tile)
 
 
@@ -454,14 +517,22 @@ def routing_rows(config, layer: dict, x: jax.Array):
     return counts, jnp.stack([rows, overflow]).astype(jnp.int32)
 
 
-# Tokens a group and (buffer rows, columns) tile of the sum back to tokens, from
-# a sweep on the v5e at DeepSeek-V2-Lite's sizes (scripts/microbench_moe_rows.py;
-# PERF.md, PR 32).
+# Tokens a group and (buffer rows, columns) tile the sum back to tokens aims at,
+# from a sweep on the v5e at DeepSeek-V2-Lite's sizes, whose 2048 columns it
+# divides (scripts/microbench_moe_rows.py; PERF.md, PR 32).
 _SUM_TOKENS, _SUM_TILING = 128, (256, 2048)
 
 
 def _token_tile(n_tokens: int) -> int:
     return math.gcd(n_tokens, _SUM_TOKENS)
+
+
+def _sum_tiling(rows: int, n_tokens: int, columns: int) -> Tuple[int, int, int]:
+    """``tgmm``'s tiling for the sum of ``rows`` buffer rows back to tokens:
+    the column tile divides the columns as the experts' tiles do (at 2304 two
+    2048-wide tiles would read the rows twice for 2304 columns)."""
+    tm, tile = math.gcd(rows, _SUM_TILING[0]), _token_tile(n_tokens)
+    return (tm,) + _nearest_fitting(tm, tile, columns, (tile, _SUM_TILING[1]))
 
 
 @jax.custom_vjp
@@ -501,10 +572,9 @@ def _tokens_from_rows(rows, rows_of, n_tokens: int):
     _, by_token, sorted_token, spans = rows_of
     (M, D), tile = rows.shape, _token_tile(n_tokens)
     inside = (sorted_token % tile)[None, :] == jnp.arange(tile)[:, None]  # (T, M)
-    tm, tn = _SUM_TILING
     out = tgmm(
         inside.astype(rows.dtype), rows[by_token], spans, rows.dtype,
-        (math.gcd(M, tm), tile, min(tn, D)), interpret=jax.default_backend() != "tpu")
+        _sum_tiling(M, n_tokens, D), interpret=jax.default_backend() != "tpu")
     return out.reshape(n_tokens, D)
 
 

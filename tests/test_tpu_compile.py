@@ -222,20 +222,30 @@ def test_ring_block_kernels_compile(v5e_devices):
     )
 
 
-def test_dropless_expert_matmuls_compile_at_olmoe_widths(v5e_devices, monkeypatch):
-    """The routed MLP's expert stack at OLMoE-1B-7B's sizes (8192 tokens x 8
-    experts a token = 65536 rows, hidden 2048, 64 experts of width 1024),
-    forward and backward: six Pallas grouped matmuls (gate+up and down; the
-    rows' gradient of each is one more ``gmm``, the weights' a ``tgmm``) at
-    the tile ``models/moe.py`` picked on the chip."""
+# (rows a layer's grouped matmuls see, hidden, expert width, experts on the chip)
+# of the benchmark's routed cells: olmoe-1b-7b.d1 and the held experts' buffers
+# of the DeepSeek, SDAR and Mellum cells.
+EXPERT_STACKS = {"olmoe": (65536, 2048, 1024, 64), "deepseek": (18432, 2048, 1408, 8),
+                 "sdar": (40960, 2048, 768, 16), "mellum": (49152, 2304, 896, 16)}
+
+
+@pytest.mark.parametrize("cell", sorted(EXPERT_STACKS))
+def test_dropless_expert_matmuls_compile_at_the_cells_widths(v5e_devices, monkeypatch, cell):
+    """The routed MLP's expert stack at the sizes of each routed cell (OLMoE-1B-7B:
+    8192 tokens x 8 experts a token = 65536 rows, hidden 2048, 64 experts of
+    width 1024), forward and backward: six Pallas grouped matmuls (gate+up and
+    down; the rows' gradient of each is one more ``gmm``, the weights' a
+    ``tgmm``), each at the tiles ``moe.gmm_tiling`` makes of its own widths:
+    what the rule says fits the kernel's VMEM, the chip's compiler takes."""
     from distributed_llm_training_benchmark_framework_tpu.models import moe
     from distributed_llm_training_benchmark_framework_tpu.models.tinygpt import TinyGPTConfig
 
     # The program asks the backend whether to run its kernels or interpret
     # them; the target here is the described chip.
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    config = TinyGPTConfig(n_embd=2048, n_head=16, mlp_act="swiglu", mlp_hidden=1024,
-                           bias=False, n_experts=64, expert_top_k=8, capacity_factor=None)
+    rows, hidden, width, experts = EXPERT_STACKS[cell]
+    config = TinyGPTConfig(n_embd=hidden, n_head=16, mlp_act="swiglu", mlp_hidden=width,
+                           bias=False, n_experts=experts, expert_top_k=8, capacity_factor=None)
     one = SingleDeviceSharding(v5e_devices[0])
     aval = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype, sharding=one)
 
@@ -245,29 +255,35 @@ def test_dropless_expert_matmuls_compile_at_olmoe_widths(v5e_devices, monkeypatc
 
     text = _compile(
         jax.grad(loss, argnums=(0, 1, 2)),
-        aval((65536, 2048), jnp.bfloat16), aval((64, 2048, 2048), jnp.float32),
-        aval((64, 1024, 2048), jnp.float32), aval((64,), jnp.int32),
+        aval((rows, hidden), jnp.bfloat16), aval((experts, hidden, 2 * width), jnp.float32),
+        aval((experts, width, hidden), jnp.float32), aval((experts,), jnp.int32),
     )
     assert text.count('custom_call_target="tpu_custom_call"') == 6
     for scope in ("jit(gmm)", "jit(tgmm)"):  # how a trace tells them from the flash kernels
         assert scope in text
 
 
-def test_the_held_rows_passes_compile_at_deepseek_sizes(v5e_devices, monkeypatch):
+@pytest.mark.parametrize("hidden, width, top_k, held, factor, buffer", [
+    (2048, 1408, 6, 8, 1.5, 18432), (2304, 896, 8, 16, 1.5, 49152),
+], ids=["deepseek", "mellum"])
+def test_the_held_rows_passes_compile_at_the_cells_sizes(
+        v5e_devices, monkeypatch, hidden, width, top_k, held, factor, buffer):
     """A chip's share of DeepSeek-V2-Lite's routed layer at the benchmark
     cell's sizes (16,384 tokens x 6 choices over 64 experts, 8 held, a buffer
-    of 18,432 rows of 2048): tokens to rows, rows back to tokens, and the
-    transposes of both. The way back is a ``tgmm`` over the spans of rows that
-    tiles of tokens own: one in the forward pass, one in the backward."""
+    of 18,432 rows of 2048) and of Mellum's (8 choices, 16 held, 49,152 rows of
+    2304: one column tile, where 2048 would not divide): tokens to rows, rows
+    back to tokens, and the transposes of both. The way back is a ``tgmm`` over
+    the spans of rows that tiles of tokens own: one in the forward pass, one in
+    the backward."""
     from distributed_llm_training_benchmark_framework_tpu.models import moe
     from distributed_llm_training_benchmark_framework_tpu.models.tinygpt import TinyGPTConfig
 
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    config = TinyGPTConfig(n_embd=2048, n_head=16, mlp_act="swiglu", mlp_hidden=1408,
-                           bias=False, n_experts=64, expert_top_k=6, capacity_factor=None,
-                           experts_held=(0, 8), held_rows_factor=1.5)
+    config = TinyGPTConfig(n_embd=hidden, n_head=16, mlp_act="swiglu", mlp_hidden=width,
+                           bias=False, n_experts=64, expert_top_k=top_k, capacity_factor=None,
+                           experts_held=(0, held), held_rows_factor=factor)
     tokens = 16384
-    assert moe.held_buffer_rows(config, tokens) == 18432
+    assert moe.held_buffer_rows(config, tokens) == buffer
     one = SingleDeviceSharding(v5e_devices[0])
     aval = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype, sharding=one)
 
@@ -278,7 +294,7 @@ def test_the_held_rows_passes_compile_at_deepseek_sizes(v5e_devices, monkeypatch
         return jnp.sum(jnp.square(back.astype(jnp.float32)))  # its gradient needs ``back``
 
     text = _compile(
-        jax.grad(loss), aval((tokens, 2048), jnp.bfloat16), aval((tokens, 6), jnp.int32),
+        jax.grad(loss), aval((tokens, hidden), jnp.bfloat16), aval((tokens, top_k), jnp.int32),
         aval((64,), jnp.int32),
     )
     assert text.count('custom_call_target="tpu_custom_call"') == 2
