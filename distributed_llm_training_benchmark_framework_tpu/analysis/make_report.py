@@ -87,8 +87,6 @@ def _composition_label(r) -> str:
         bits.append(f"ep{max(val('expert_parallel', 1), 1)}x{val('n_experts', 0)}e")
     if r.get("param_dtype") == "bf16":
         bits.append("bf16-params")
-    if str(r.get("offload_opt_state")).lower() == "true":
-        bits.append("opt-offload")
     return "+".join(bits) if bits else "-"
 
 

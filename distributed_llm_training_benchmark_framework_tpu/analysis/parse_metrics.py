@@ -189,8 +189,7 @@ def load_results(results_dir: str) -> pd.DataFrame:
             # a baseline suite would dedupe one of them away.
             "tensor_parallel", "sequence_parallel", "pipeline_parallel",
             "pipeline_schedule", "virtual_stages", "expert_parallel",
-            "n_experts", "remat_policy", "param_dtype", "offload_opt_state",
-            "offload_delayed_update", "offload_dpu_start_step", "causal",
+            "n_experts", "remat_policy", "param_dtype", "causal",
             "ring_zigzag", "tp_collective_matmul",
             # Stitched-run identity (scaling suite): a reshard-on-restore
             # continuation shares every config axis with the fresh point
@@ -217,8 +216,7 @@ def add_scaling_efficiency(df: pd.DataFrame) -> pd.DataFrame:
             "attention_impl",
             "tensor_parallel", "sequence_parallel", "pipeline_parallel",
             "pipeline_schedule", "virtual_stages", "expert_parallel",
-            "n_experts", "param_dtype", "offload_opt_state",
-            "offload_delayed_update", "offload_dpu_start_step", "causal",
+            "n_experts", "param_dtype", "causal",
             "ring_zigzag", "tp_collective_matmul",
         )
         if c in df.columns

@@ -65,8 +65,7 @@ def _seq_key_cols(df: pd.DataFrame) -> List[str]:
             "attention_impl", "world_size", "tier", "model_family",
             "causal", "ring_zigzag", "tp_collective_matmul",
             "n_experts", "param_dtype",
-            "offload_opt_state", "offload_delayed_update",
-            "offload_dpu_start_step", "tensor_parallel", "sequence_parallel",
+            "tensor_parallel", "sequence_parallel",
             "pipeline_parallel", "pipeline_schedule", "virtual_stages",
             "expert_parallel",
         )

@@ -165,18 +165,12 @@ def main(argv=None) -> int:
     p.add_argument("--json", action="store_true",
                    help="emit the audit reports as JSON on stdout")
     p.add_argument("--inject", default=None,
-                   choices=["bad-kv-spec", "bad-fsdp-axis",
-                            "bad-pipeline-spec", "bad-forward-gather",
-                            "bad-cmm-ring"],
+                   choices=["bad-kv-spec", "bad-fsdp-axis", "bad-cmm-ring"],
                    help="self-test: deliberately reintroduce a known-bad "
                         "configuration (bad-kv-spec = the PR 1 GQA kv "
                         "full-replicate fallback; bad-fsdp-axis = the "
                         "pre-round-8 composed dp x tp fsdp placement; "
-                        "bad-pipeline-spec = the seed-old typed-key "
-                        "shard_map boundary that broke the interleaved "
-                        "arm's compile; bad-forward-gather = the round-15 "
-                        "fsdp/zero3 per-block forward param placement "
-                        "reverted; bad-cmm-ring = the collective-matmul "
+                        "bad-cmm-ring = the collective-matmul "
                         "ppermute decomposition reverted to bulk "
                         "collectives) — the audit MUST then fail")
     args = p.parse_args(argv)

@@ -40,10 +40,7 @@ COLLECTIVE_OPS = (
     "all-to-all",
 )
 
-_INJECTIONS = (
-    "bad-kv-spec", "bad-fsdp-axis", "bad-pipeline-spec",
-    "bad-forward-gather", "bad-cmm-ring",
-)
+_INJECTIONS = ("bad-kv-spec", "bad-fsdp-axis", "bad-cmm-ring")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -55,9 +52,7 @@ class ArmSpec:
     ``inject`` deliberately reintroduces a known-bad configuration for
     self-tests — 'bad-kv-spec' disables the kv-head-aligned PartitionSpec
     rule, bringing back the GQA full-replicate resharding fallback PR 1
-    fixed (the auditor must flag it); 'bad-pipeline-spec' reverts the
-    typed-key/shard_map boundary fix, bringing back the seed-old u32
-    tile-assignment compile failure on the pipeline arms.
+    fixed (the auditor must flag it).
 
     ``pipeline_schedule``/``virtual_stages`` only matter when the mesh
     carries a >1 'pipe' axis (the schedule-auditor roster below); they
@@ -255,13 +250,23 @@ def lower_arm(spec: ArmSpec, devices=None):
         return _with_bad_kv_spec(compile_)
     if spec.inject == "bad-fsdp-axis":
         return _with_bad_fsdp_axis(compile_)
-    if spec.inject == "bad-pipeline-spec":
-        return _with_bad_pipeline_spec(compile_)
-    if spec.inject == "bad-forward-gather":
-        return _with_bad_forward_gather(compile_)
     if spec.inject == "bad-cmm-ring":
         return _with_bad_cmm_ring(compile_)
     return compile_()
+
+
+def _swapped(fn, module, **bodies):
+    """Run ``fn`` with ``module``'s named functions swapped for ``bodies``,
+    and the originals back whatever ``fn`` does: how every injection breaks
+    the module it names, which carries no switch for it."""
+    real = {name: getattr(module, name) for name in bodies}
+    for name, body in bodies.items():
+        setattr(module, name, body)
+    try:
+        return fn()
+    finally:
+        for name, body in real.items():
+            setattr(module, name, body)
 
 
 def _with_bad_kv_spec(fn):
@@ -283,17 +288,13 @@ def _with_bad_kv_spec(fn):
         return real(params, mesh, shard=shard, kv_heads=None,
                     scan_stacked=scan_stacked)
 
-    strat.param_partition_specs = misaligned
-    try:
-        return fn()
-    finally:
-        strat.param_partition_specs = real
+    return _swapped(fn, strat, param_partition_specs=misaligned)
 
 
 def _with_bad_fsdp_axis(fn):
     """Run ``fn`` with the composed dp x tp fsdp-axis hygiene disabled.
 
-    Reverts ``strategies._shard_largest_free_axis`` to the pre-round-8
+    Swaps ``strategies._shard_largest_free_axis`` for the pre-round-8
     unrestricted largest-free-axis placement: fsdp 'data' lands AFTER the
     leaf's 'model' axis on row-parallel/vocab leaves (wo/wproj/wte/
     lm_head), producing the transposed device-order tilings whose reshard
@@ -303,73 +304,46 @@ def _with_bad_fsdp_axis(fn):
     """
     from ...parallel import strategies as strat
 
-    strat._COMPOSED_FSDP_HYGIENE = False
-    try:
-        return fn()
-    finally:
-        strat._COMPOSED_FSDP_HYGIENE = True
+    def unrestricted(spec, shape, n_shards, is_block_leaf, composed=False):
+        axes = list(range(len(shape)))
+        if is_block_leaf and len(shape) > 1:
+            axes = axes[1:] + axes[:1]
+        free = [ax for ax in axes
+                if spec[ax] is None and shape[ax] % n_shards == 0
+                and shape[ax] >= n_shards]
+        if free:
+            spec[max(free, key=lambda ax: shape[ax])] = "data"
 
-
-def _with_bad_forward_gather(fn):
-    """Run ``fn`` with the round-15 forward-side per-block param placement
-    reverted.
-
-    ``train.step._FORWARD_GATHER_OVERLAP = False`` makes
-    ``fsdp_block_param_spec`` return None, so the sharded-param arms'
-    weight slices lose their in-loop placement pins — the scanned
-    fsdp/zero3 lowerings regrow the full-stack activation gather (+1
-    all-gather, +1 all-to-all per arm on this jaxlib) the constraint
-    removed, and the audit must name the arms and the deltas.
-    """
-    from ...train import step as step_mod
-
-    step_mod._FORWARD_GATHER_OVERLAP = False
-    try:
-        return fn()
-    finally:
-        step_mod._FORWARD_GATHER_OVERLAP = True
+    return _swapped(fn, strat, _shard_largest_free_axis=unrestricted)
 
 
 def _with_bad_cmm_ring(fn):
     """Run ``fn`` with the collective-matmul ppermute decomposition broken.
 
-    ``ops.collective_matmul._CMM_RING = False`` reverts the ring bodies to
-    their unfused all_gather / psum_scatter forms — mathematically equal,
+    The two ring bodies of ``ops.collective_matmul`` swapped for their
+    unfused all_gather / psum_scatter forms — mathematically equal,
     structurally the bulk collectives the fusion exists to remove. The
     llama-tp2-gqa-cmm frozen budget (projection all-gathers gone, ring
     permutes in their place) must flag the arm by name with the
     all-gather/reduce-scatter growth and the vanished permutes.
     """
+    from jax import lax
+
     from ...ops import collective_matmul as cm
 
-    cm._CMM_RING = False
-    try:
-        return fn()
-    finally:
-        cm._CMM_RING = True
+    def ag_unfused(x, w, axis_name="model"):
+        xg = lax.all_gather(x, axis_name, axis=1, tiled=True)
+        return cm._proj_einsum(xg, w).astype(x.dtype)
 
+    def rs_unfused(y, w, axis_name="model"):
+        full = cm._proj_einsum(y, w)
+        return lax.psum_scatter(
+            full, axis_name, scatter_dimension=1, tiled=True
+        ).astype(y.dtype)
 
-def _with_bad_pipeline_spec(fn):
-    """Run ``fn`` with the pipeline typed-key boundary fix reverted.
-
-    ``parallel.pipeline._key_data_or_none`` exists because a typed PRNG
-    key must cross the pipeline shard_map boundary as raw u32 key data —
-    passing the key itself resurrects the seed-old interleaved compile
-    failure (the partial-auto boundary builds a rank-0 sharding for the
-    key aval and XLA rejects it against the rank-1 physical u32 data:
-    "Number of tile assignment dimensions ... is different than the input
-    rank ... u32[...]"). The pipeline roster arms audit with live dropout
-    keys precisely so this injection makes them fail to compile, and the
-    schedule auditor must then exit 1 naming the arm and the
-    schedule-compiles law.
-    """
-    from ...parallel import pipeline as pl
-
-    pl._TYPED_KEY_BOUNDARY_FIX = False
-    try:
-        return fn()
-    finally:
-        pl._TYPED_KEY_BOUNDARY_FIX = True
+    return _swapped(
+        fn, cm, ag_proj_sharded=ag_unfused, rs_proj_sharded=rs_unfused
+    )
 
 
 # One instruction definition per line: "%name = <shape> <opcode>(...". The
@@ -381,7 +355,25 @@ _COLLECTIVE_DEF = re.compile(
     r"= .*?\b(" + "|".join(re.escape(op) for op in COLLECTIVE_OPS)
     + r")(?:-start)?\("
 )
-_BF16_TO_F32_CONVERT = re.compile(r"= f32\[[^\]]*\]\S* convert\(bf16\[")
+# One definition per line, "%name = dtype[dims]..."; an f32 convert names its
+# operand, and only some XLA printers repeat the operand's shape beside it.
+_INSTRUCTION_DTYPE = re.compile(r"^\s*(?:ROOT )?%(\S+) = (\w+)\[", re.M)
+_F32_CONVERT_OPERAND = re.compile(
+    r"= f32\[[^\]]*\]\S* convert\((?:(\w+)\[[^\]]*\]\S* )?%([^\s,)]+)\)"
+)
+
+
+def count_bf16_to_f32_converts(hlo_text: str) -> int:
+    """f32 ``convert`` instructions whose operand is bf16. The operand's
+    dtype is read beside it where the printer gives it (jax 0.4) and from
+    the operand's own definition where it does not (jax 0.9 prints
+    ``convert(%name)``: a pattern that wants ``convert(bf16[`` counts 0 in
+    every module)."""
+    dtype_of = dict(_INSTRUCTION_DTYPE.findall(hlo_text))
+    return sum(
+        (inline or dtype_of.get(name)) == "bf16"
+        for inline, name in _F32_CONVERT_OPERAND.findall(hlo_text)
+    )
 
 
 def count_collectives(hlo_text: str) -> Dict[str, int]:
@@ -432,7 +424,7 @@ def audit_arm(spec: ArmSpec, devices=None) -> ArmReport:
         ),
         donated_inputs=txt.count("may-alias") + txt.count("must-alias"),
         donatable_inputs=_donatable_leaves(spec),
-        bf16_to_f32_converts=len(_BF16_TO_F32_CONVERT.findall(txt)),
+        bf16_to_f32_converts=count_bf16_to_f32_converts(txt),
     )
 
 
@@ -448,9 +440,8 @@ def audit_arm(spec: ArmSpec, devices=None) -> ArmReport:
 #: lower WITH live dropout keys (``dropout`` pinned to the family
 #: default instead of the roster's dropout-free choice): the typed-key
 #: shard_map boundary was the seed-old interleaved compile failure, and
-#: an audit that DCEs the keys away could never catch its return —
-#: ``--inject bad-pipeline-spec`` reverts exactly that fix. Dropout adds
-#: RNG ops but no collectives, so the pinned schedule stays
+#: an audit that DCEs the keys away could never catch its return.
+#: Dropout adds RNG ops but no collectives, so the pinned schedule stays
 #: deterministic. The interleaved arm runs V=2 real virtual chunks
 #: (n_layer=4) so the audit covers actual interleaving, not the V=1
 #: degenerate shape.
@@ -651,8 +642,7 @@ def pipeline_law_findings(result: PipelineAuditResult) -> List[str]:
     """The schedule laws, each named per arm + law when broken.
 
     - **schedule-compiles**: the arm must lower at all (the seed-old
-      interleaved bug class; what ``--inject bad-pipeline-spec``
-      resurrects).
+      interleaved bug class).
     - **permute-law**: collective-permute instructions must equal the
       closed form at BOTH audited M values — the excess is the pipeline
       analogue of a replication-reshard suspect (GSPMD resharding the

@@ -24,12 +24,8 @@ module turns those prose envelopes into a suite step that fails loudly:
 - **MFU floors** (round 5): published single-chip tier-A rows must not
   silently regress — per-seq-len floors a few points under the measured
   table (docs/PERFORMANCE.md §9/§12), applied only to the published-arm
-  geometry (tier A, ws=1, v5e, dense, no offload) so experimental configs
-  aren't blocked;
-- **offload CV allowance**: ZeRO-Offload rows run the optimizer on the
-  host CPU, whose load jitter legitimately exceeds the 10% device
-  envelope (PERFORMANCE.md §13) — they get their own, looser CV limit
-  instead of silently skipping the check.
+  geometry (tier A, ws=1, v5e, dense) so experimental configs aren't
+  blocked.
 
 Exit code 0 = all envelopes hold; 1 = any violation (listed on stdout).
 """
@@ -93,10 +89,6 @@ MFU_FLOORS_LLAMA = {2048: 42.0, 8192: 50.0, 16384: 38.0}
 # assignments (cf 1.25 < top-k worst case), but beyond this bound routing
 # has collapsed onto a few experts (or capacity accounting broke).
 EXPERT_OVERFLOW_MAX_PCT = 60.0
-# Host-CPU AdamW step-time jitter under host load (PERFORMANCE.md §13
-# documents p50 varying 3.6-6.2 s run-to-run; within-run CV stays well
-# under this).
-OFFLOAD_STEP_CV_LIMIT_PCT = 25.0
 # Loss-descent envelope: rows long enough to have visibly trained
 # (>= this many steps) must show loss_last_window <= loss_first_window -
 # delta(family, steps). The mean-loss band alone cannot catch a FROZEN run
@@ -230,14 +222,9 @@ def validate_result(r: dict, name: str) -> List[str]:
         and not r.get("resumed")
     ):
         cv = r["step_time_cv_pct"]
-        cv_limit = (
-            OFFLOAD_STEP_CV_LIMIT_PCT if r.get("offload_opt_state")
-            else STEP_CV_LIMIT_PCT
-        )
         _check(
-            cv < cv_limit, name,
-            f"step-time cv {cv:.1f}% >= {cv_limit}% envelope"
-            + (" (offload allowance)" if r.get("offload_opt_state") else ""), f,
+            cv < STEP_CV_LIMIT_PCT, name,
+            f"step-time cv {cv:.1f}% >= {STEP_CV_LIMIT_PCT}% envelope", f,
         )
 
     # Stitched-run honesty (chaos round): a row claiming resumed=true must
@@ -320,12 +307,12 @@ def validate_result(r: dict, name: str) -> List[str]:
             )
 
     # MFU floors for the published-arm geometry only: tier A, single chip,
-    # v5e, flash attention, dense model, device-resident optimizer, and
+    # v5e, flash attention, dense model, and
     # windowed timing (sync_every > 1 — the per-step block_until_ready
     # diagnostic runs legitimately sit ~11 points lower). Any other
     # geometry is exploratory and gets no floor.
-    # Shared base: the published-arm geometry minus the causal/offload
-    # axes (each floor below adds its own) — one predicate to update when
+    # Shared base: the published-arm geometry minus the causal axis
+    # (each floor below adds its own) — one predicate to update when
     # e.g. a v6 device kind joins the published set.
     family_geometry = (
         r.get("tier") == "A"
@@ -333,7 +320,6 @@ def validate_result(r: dict, name: str) -> List[str]:
         and "v5" in str(r.get("device_kind", ""))
         and r.get("attention_impl") == "flash"
         and r.get("sync_every", 1) > 1
-        and not r.get("offload_opt_state")
         and r.get("mfu_pct", 0) > 0
     )
     base_geometry = (

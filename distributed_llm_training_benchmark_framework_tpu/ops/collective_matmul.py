@@ -54,14 +54,6 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import PartitionSpec as P
 
-#: Self-test escape hatch (graftcheck `--inject bad-cmm-ring`): False
-#: breaks the ppermute decomposition — the shard_map bodies fall back to
-#: the unfused all_gather / psum_scatter forms (same math, bulk
-#: collectives back in the module) so CI can prove the cmm arm's frozen
-#: budget catches a silently-reverted ring.
-_CMM_RING = True
-
-
 def _tp_mesh(axis_name: str, mesh) -> Optional[jax.sharding.Mesh]:
     """The mesh in scope when ``axis_name`` is a >1 axis, else None."""
     if mesh is None:
@@ -109,12 +101,6 @@ def ag_proj_sharded(
     n = lax.axis_size(axis_name)
     if n == 1:
         return _proj_einsum(x, w).astype(x.dtype)
-    if not _CMM_RING:
-        # Injection fallback (`--inject bad-cmm-ring`): the unfused form —
-        # same math, but the bulk all-gather is back and the frozen cmm
-        # budget must flag it.
-        xg = lax.all_gather(x, axis_name, axis=1, tiled=True)
-        return _proj_einsum(xg, w).astype(x.dtype)
     idx = lax.axis_index(axis_name)
     perm = [(j, (j + 1) % n) for j in range(n)]
     s_local = x.shape[1]
@@ -156,17 +142,11 @@ def rs_proj_sharded(
         # A non-dividing sequence would silently drop the trailing rows
         # from the ring's partial sums (the rs_proj wrapper guards this;
         # the sharded entry point must be loud too — it is documented
-        # public API, and the injection fallback's psum_scatter would
-        # only error with an opaque tiling message).
+        # public API).
         raise ValueError(
             f"rs_proj_sharded: sequence length {y.shape[1]} does not "
             f"divide the '{axis_name}' ring size {n}"
         )
-    if not _CMM_RING:
-        full = _proj_einsum(y, w)
-        return lax.psum_scatter(
-            full, axis_name, scatter_dimension=1, tiled=True
-        ).astype(y.dtype)
     idx = lax.axis_index(axis_name)
     perm = [(j, (j + 1) % n) for j in range(n)]
     s_local = y.shape[1] // n

@@ -45,15 +45,6 @@ from ..utils.vma import pcast_missing
 AXIS = "pipe"
 
 
-#: The pipeline compile-fix switch: the typed-key boundary crossing that
-#: repaired the seed-old pipeline compile failures. graftcheck's ``--inject
-#: bad-pipeline-spec`` flips this off to resurrect the original lowering
-#: (typed key closed over a partial-auto shard_map beside a REAL auto
-#: 'data' axis -> the u32 tile-assignment XLA rejection) and prove the
-#: schedule auditor catches it; nothing else may touch it.
-_TYPED_KEY_BOUNDARY_FIX = True
-
-
 def _key_data_or_none(base_key):
     """Raw uint32 key data for a typed PRNG key (None passes through).
 
@@ -64,9 +55,12 @@ def _key_data_or_none(base_key):
     ... is different than the input rank", the seed-old interleaved compile
     failure). Raw key data is an ordinary u32 array whose rank the boundary
     always handles; the body rebuilds the key with :func:`_rebuild_key`.
+
+    Seen on jax 0.4.37. On jax 0.9.0 a typed key crosses and every pipeline
+    arm compiles (PR 43's audit, which is why no injection reverts this any
+    more); the crossing stays while ``pyproject.toml`` admits a jax (0.8)
+    nobody has compiled the schedules on.
     """
-    if not _TYPED_KEY_BOUNDARY_FIX:
-        return base_key
     return None if base_key is None else jax.random.key_data(base_key)
 
 
@@ -74,8 +68,6 @@ def _rebuild_key(key_data):
     """The body-side half of the key boundary crossing (see above)."""
     if key_data is None:
         return None
-    if not _TYPED_KEY_BOUNDARY_FIX:
-        return key_data  # the typed key itself crossed — the old bug
     return jax.random.wrap_key_data(key_data)
 
 
@@ -108,6 +100,36 @@ def _seq_setup(config: tinygpt.TinyGPTConfig, mesh: Mesh):
         return config, None, sp, frozenset({AXIS}), P()
     config = dataclasses.replace(config, seq_manual_axis="seq")
     return config, "seq", sp, frozenset({AXIS, "seq"}), P(None, None, "seq")
+
+
+def embed(config: tinygpt.TinyGPTConfig, mesh: Mesh, params, idx, dropout_key,
+          deterministic: bool) -> jax.Array:
+    """``tinygpt.embed`` of one microbatch inside a schedule's manual region,
+    with the lookup manual over 'data' as well.
+
+    The lookup's backward is a scatter-add of a data-sharded cotangent into a
+    table every data replica holds. Left to the partitioner inside the partly
+    manual region, the TPU compiler all-gathers the indices and the updates
+    over 'data' (traffic and buffers that grow with the data degree: the
+    topology audit's growth laws refuse it). Manual over 'data', each replica
+    scatters its own rows and the transpose of the replicated table's
+    broadcast is one psum over 'data': what data parallelism pays for any
+    other gradient. Dropout stays outside, on the whole microbatch, so the
+    mask is the one ``tinygpt.embed`` draws. A sequence-manual schedule keeps
+    the plain lookup: ``tinygpt.embed`` asks ``lax.axis_index`` for its
+    sequence shard, which does not lower inside a second manual region
+    (jax 0.9.0: "axis 'seq' is already bound by a parent").
+    """
+    if mesh.shape.get("data", 1) == 1 or config.seq_manual_axis is not None:
+        return tinygpt.embed(config, params, idx, dropout_key, deterministic)
+    ep = {k: params[k] for k in tinygpt.embed_param_names(config)}
+    x = jax.shard_map(
+        lambda ep, idx: tinygpt.embed(config, ep, idx),
+        in_specs=(P(), P("data")),
+        out_specs=P("data"),
+        axis_names={"data"},
+    )(ep, idx)
+    return tinygpt._dropout(x, config.dropout, dropout_key, deterministic)
 
 
 def pipeline_param_specs(params, mesh: Mesh):
@@ -170,7 +192,7 @@ def pipeline_loss_fn(
                     if emb_key is not None and not deterministic
                     else None
                 )
-                inject = tinygpt.embed(config, params, batch[t], ek, deterministic)
+                inject = embed(config, mesh, params, batch[t], ek, deterministic)
                 state_in = jnp.where(stage == 0, inject, state)
             else:
                 state_in = state
@@ -365,7 +387,7 @@ def pipeline_loss_and_grads_1f1b(
             # ---- forward unit: stage s runs microbatch t - s (as GPipe) ----
             if t < n_micro:
                 ek = jax.random.fold_in(emb_key, t) if live_keys else None
-                inject = tinygpt.embed(config, params, batch[t], ek, deterministic)
+                inject = embed(config, mesh, params, batch[t], ek, deterministic)
                 state_in = jnp.where(stage == 0, inject, state)
             else:
                 state_in = state
@@ -469,7 +491,7 @@ def pipeline_loss_and_grads_1f1b(
                     # pipe-uniform) and 'seq' is handled implicitly.
                     _, vjp_emb = jax.vjp(
                         lambda ep: pcast_missing(
-                            tinygpt.embed(config, ep, batch[bi0], ek0, deterministic),
+                            embed(config, mesh, ep, batch[bi0], ek0, deterministic),
                             (AXIS,),
                         ),
                         ep,

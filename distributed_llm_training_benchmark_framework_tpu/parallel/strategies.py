@@ -79,27 +79,6 @@ class StrategyConfig:
     # bf16-rounded Adam updates (a stress-tier trade, documented in
     # docs/TROUBLESHOOTING.md).
     param_dtype: str = "f32"
-    # Host-offloaded optimizer (TPU-native analogue of DeepSpeed's
-    # ZeRO-Offload, reference configs/deepspeed/zero3.json offload_optimizer):
-    # fp32 MASTER params + Adam moments live permanently in pinned host
-    # memory and the full update runs ON THE HOST CPU
-    # (jax.experimental.compute_on inside the jitted step); the device holds
-    # only a bf16 compute copy of the params, whose grads stream down and
-    # whose refresh streams back each step. The quality-preserving
-    # alternative to param_dtype='bf16' for models whose fp32 state exceeds
-    # HBM: Adam runs in full fp32 against master weights. Costs per-step
-    # PCIe traffic (~2 x bf16-param bytes); see docs/PERFORMANCE.md.
-    offload_opt_state: bool = False
-    # Delayed parameter update for the offload arm (DeepSpeed's
-    # delayed_param_update analogue, opt-in): the host consumes the
-    # PREVIOUS step's gradients (parked in pinned host memory) while the
-    # device runs the CURRENT step's forward/backward — the two have no
-    # data dependency inside one program, so XLA's scheduler overlaps the
-    # multi-second host Adam with device compute instead of serializing
-    # behind it. Params are one step stale (training-semantics change —
-    # hence opt-in); step 0 performs no update (its grads become step 1's).
-    offload_delayed_update: bool = False
-
     def describe(self) -> str:
         bits = [
             f"params={'sharded' if self.shard_params else 'replicated'}",
@@ -110,10 +89,6 @@ class StrategyConfig:
             bits.append(f"remat={self.remat}")
         if self.param_dtype != "f32":
             bits.append(f"param_dtype={self.param_dtype}")
-        if self.offload_opt_state:
-            bits.append("opt_offload=pinned_host")
-        if self.offload_delayed_update:
-            bits.append("delayed_update")
         return f"{self.name}: " + ", ".join(bits)
 
 
@@ -186,6 +161,14 @@ def load_strategy_config(path: str) -> StrategyConfig:
     """
     with open(path) as f:
         raw = json.load(f)
+    offload_keys = sorted(k for k in raw if k.startswith("offload_"))
+    if offload_keys:
+        raise ValueError(
+            f"strategy config {path}: key {offload_keys[0]!r} is not supported: "
+            "the optimizer state lives in HBM here (there is no host offload). "
+            f"Drop {', '.join(repr(k) for k in offload_keys)} from the file; "
+            "\"param_dtype\": \"bf16\" is the memory relief."
+        )
     name = raw.get("strategy")
     base = get_strategy(name) if name in STRATEGIES else StrategyConfig(name=name or os.path.basename(path))
     opt = raw.get("optimizer", {})
@@ -211,9 +194,6 @@ def load_strategy_config(path: str) -> StrategyConfig:
         shard_grads=bool(shard.get("grads", base.shard_grads)),
         shard_opt_state=bool(shard.get("opt_state", base.shard_opt_state)),
         remat=_normalize_remat_field(raw.get("remat", base.remat)),
-        offload_opt_state=bool(
-            raw.get("offload_opt_state", base.offload_opt_state)
-        ),
     )
 
 
@@ -332,16 +312,15 @@ def from_deepspeed_config(raw: Dict[str, Any], strategy_name: str) -> StrategyCo
         # DeepSpeed semantics: gradient_clipping 0 means *disabled*, not
         # "clip everything to zero norm".
         grad_clip = None
-    # ZeRO-Offload: zero_optimization.offload_optimizer.device cpu/nvme
-    # maps onto the pinned-host optimizer offload (reference
-    # configs/deepspeed/zero3.json:12-14 ships the section with "none").
-    # An explicit device (incl. "none") overrides the base strategy in
-    # both directions, like gradient_clipping=0 disables clipping above.
-    ds_off = section("zero_optimization").get("offload_optimizer")
-    if isinstance(ds_off, dict) and "device" in ds_off:
-        offload = ds_off["device"] not in (None, "none")
-    else:
-        offload = base.offload_opt_state
+    offload = zero.get("offload_optimizer")
+    device = offload.get("device") if isinstance(offload, dict) else offload
+    if device not in (None, "none"):
+        raise ValueError(
+            "DeepSpeed config sets zero_optimization.offload_optimizer.device="
+            f"{device!r}: the optimizer state lives in HBM here (there is no "
+            "host offload). Drop 'zero_optimization.offload_optimizer' (or set "
+            "its device to \"none\"); --param-dtype bf16 is the memory relief."
+        )
     return dataclasses.replace(
         base,
         learning_rate=num(opt, "lr", base.learning_rate),
@@ -351,35 +330,7 @@ def from_deepspeed_config(raw: Dict[str, Any], strategy_name: str) -> StrategyCo
         warmup_steps=warmup,
         grad_clip=grad_clip,
         precision=precision,
-        offload_opt_state=offload,
     )
-
-
-def _adamw_only(strategy: StrategyConfig) -> optax.GradientTransformation:
-    """AdamW with the arm's warmup schedule, WITHOUT the clip stage."""
-    if strategy.warmup_steps > 0:
-        lr = optax.linear_schedule(
-            init_value=0.0,
-            end_value=strategy.learning_rate,
-            transition_steps=strategy.warmup_steps,
-        )
-    else:
-        lr = strategy.learning_rate
-    return optax.adamw(
-        learning_rate=lr,
-        b1=strategy.betas[0],
-        b2=strategy.betas[1],
-        eps=strategy.eps,
-        weight_decay=strategy.weight_decay,
-    )
-
-
-def _base_optimizer(strategy: StrategyConfig) -> optax.GradientTransformation:
-    """The plain AdamW chain (+ optional clip + warmup) for one arm."""
-    tx = _adamw_only(strategy)
-    if strategy.grad_clip is not None:
-        tx = optax.chain(optax.clip_by_global_norm(float(strategy.grad_clip)), tx)
-    return tx
 
 
 def make_optimizer(strategy: StrategyConfig) -> optax.GradientTransformation:
@@ -388,177 +339,32 @@ def make_optimizer(strategy: StrategyConfig) -> optax.GradientTransformation:
     Mirrors the reference recipes: bare AdamW(1e-4, wd=0.01) for ddp/fsdp
     (train_harness.py:328-331); AdamW + WarmupLR(5) + clip 1.0 for the ZeRO
     arms (configs/deepspeed/zero2.json:2,27-44).
-
-    For ``offload_opt_state`` arms the returned transformation's state is
-    ``(fp32_master_params, adamw_state)`` — the ZeRO-Offload layout: the
-    fp32 master weights live WITH the moments in pinned host memory
-    (``opt_state_shardings``), the device keeps only a bf16 compute copy of
-    the params, and the whole update executes on the host
-    (``offload_update_and_apply``). Its ``update`` is deliberately not
-    callable — the step must use ``offload_update_and_apply``.
     """
-    tx = _base_optimizer(strategy)
-    if not strategy.offload_opt_state:
-        return tx
-
-    def init(params):
-        # Masters are upcast from the bf16 device init, so they START
-        # bf16-rounded (immaterial: the init is random noise); the arm's
-        # quality edge is that every subsequent Adam update ACCUMULATES in
-        # fp32, where the bf16-state arm rounds each step's small update.
-        master = jax.tree.map(
-            lambda p: p.astype(jnp.float32), params
+    if strategy.warmup_steps > 0:
+        lr = optax.linear_schedule(
+            init_value=0.0,
+            end_value=strategy.learning_rate,
+            transition_steps=strategy.warmup_steps,
         )
-        state = (master, tx.init(master))
-        if strategy.offload_delayed_update:
-            # Delayed update: the state additionally parks last step's
-            # (pre-scaled) gradients in pinned host memory, plus their clip
-            # scale. Step 0 consumes these zeros: with warmup (the ZeRO
-            # arms' schedule starts at lr=0) that is an exact no-op on the
-            # masters; without warmup it applies one weight-decay-only
-            # micro-step (documented DPU semantics).
-            pending = jax.tree.map(
-                lambda p: jnp.zeros(p.shape, p.dtype), params
-            )
-            state = state + ((pending, jnp.zeros((), jnp.float32)),)
-        return state
-
-    def update(grads, state, params=None):
-        raise ValueError(
-            "offload_opt_state optimizer state updates on the host — call "
-            "strategies.offload_update_and_apply, not optimizer.update"
-        )
-
-    return optax.GradientTransformation(init, update)
+    else:
+        lr = strategy.learning_rate
+    tx = optax.adamw(
+        learning_rate=lr,
+        b1=strategy.betas[0],
+        b2=strategy.betas[1],
+        eps=strategy.eps,
+        weight_decay=strategy.weight_decay,
+    )
+    if strategy.grad_clip is not None:
+        tx = optax.chain(optax.clip_by_global_norm(float(strategy.grad_clip)), tx)
+    return tx
 
 
 def opt_state_shardings(mesh: Mesh, opt_specs, strategy: StrategyConfig):
-    """NamedShardings for the optimizer state, honoring the offload layout:
-    with ``offload_opt_state`` the WHOLE state (clip state, Adam moments,
-    schedule count) lives in pinned host memory; otherwise device HBM."""
-    shardings = named(mesh, opt_specs)
-    if not strategy.offload_opt_state:
-        return shardings
-    if jax.default_backend() != "tpu":
-        # XLA:CPU's SPMD partitioner RET_CHECKs on the pinned_host
-        # placement annotation ("Side-effect HLO must have sharding" on
-        # annotate_device_placement), so the offload arm is TPU-only —
-        # fail with the remedy instead of a partitioner crash.
-        raise ValueError(
-            "offload_opt_state requires a TPU runtime (pinned_host memory "
-            "space + host compute); this backend "
-            f"({jax.default_backend()!r}) cannot partition host-placed "
-            "state. Drop --offload-opt-state, or use --param-dtype bf16 "
-            "for the memory relief."
-        )
-    return jax.tree.map(lambda s: s.with_memory_kind("pinned_host"), shardings)
-
-
-def offload_update_and_apply(
-    strategy: StrategyConfig,
-    grads,
-    opt_state,
-    params,
-    mesh: Mesh,
-    grad_specs,
-    param_specs,
-):
-    """Optimizer update + apply for ``offload_opt_state`` arms: the
-    ZeRO-Offload architecture (reference ``configs/deepspeed/zero3.json``
-    offload_optimizer analogue), TPU-native.
-
-    The fp32 master params and the Adam moments live permanently in pinned
-    host memory; the device holds a bf16 compute copy of the params (the
-    memory win) whose gradients stream down once per step. AdamW (+warmup
-    schedule) and ``apply_updates`` run on the host CPU via
-    ``compute_on("device_host")`` in fp32 against the master weights —
-    full-precision Adam, unlike ``--param-dtype bf16`` whose moments and
-    updates round to bf16 — and only the refreshed bf16 compute copy
-    streams back. Per-step PCIe traffic: ~2x bf16-params (grads down +
-    compute copy up). Device HBM never holds moments, masters, or update
-    tensors.
-
-    Round-5 changes (PERFORMANCE.md §13):
-    - global-norm CLIPPING moved to the device: the norm is a cheap fused
-      reduction over grads that are already in HBM; only the resulting
-      scale scalar crosses to the host, where it folds into the fp32
-      upcast pass the host math does anyway. The checkpointed state keeps
-      the full optax chain structure (clip state is ``EmptyState``).
-    - ``offload_delayed_update``: the host consumes LAST step's grads
-      (parked in pinned host memory with their own clip scale) while this
-      step's fresh grads stream down beside it — inside one program the
-      host call has no dependency on this step's forward/backward, so
-      XLA's latency-hiding scheduler overlaps the multi-second host Adam
-      with device compute. Params lag one step (DeepSpeed
-      delayed_param_update semantics, opt-in via --offload-delayed-update).
-    """
-    from jax.experimental.compute_on import compute_on
-
-    adamw = _adamw_only(strategy)
-    is_spec = lambda x: isinstance(x, P)
-    host = lambda specs: jax.tree.map(
-        lambda spec: NamedSharding(mesh, spec).with_memory_kind("pinned_host"),
-        specs, is_leaf=is_spec,
-    )
-    dev = lambda specs: jax.tree.map(
-        lambda spec: NamedSharding(mesh, spec), specs, is_leaf=is_spec
-    )
-
-    # Device-side clip: exact optax.clip_by_global_norm semantics
-    # (scale = 1 when the norm is under the limit, limit/norm otherwise).
-    if strategy.grad_clip is not None:
-        gnorm = optax.global_norm(
-            jax.tree.map(lambda g: g.astype(jnp.float32), grads)
-        )
-        limit = jnp.float32(strategy.grad_clip)
-        # = optax.clip_by_global_norm's scale: 1 under the limit,
-        # limit/gnorm above it; no inf in either where-branch.
-        scale = limit / jnp.maximum(gnorm, limit)
-    else:
-        scale = jnp.float32(1.0)
-
-    delayed = strategy.offload_delayed_update
-    if delayed:
-        master, inner, (g_use, scale_use) = opt_state
-    else:
-        master, inner = opt_state
-        g_use = jax.device_put(grads, host(grad_specs))
-        scale_use = scale
-    if strategy.grad_clip is not None:
-        clip_state, adamw_state = inner
-    else:
-        clip_state, adamw_state = None, inner
-
-    # The compute-copy dtype is the device params' dtype — static trace-time
-    # metadata, so no param data crosses to the host for this.
-    param_dtypes = jax.tree.map(lambda p: p.dtype, params)
-
-    def host_math(g, s, master, adamw_state):
-        # Clip scale folds into the fp32 upcast the update needs anyway —
-        # zero extra passes over the gradient tree.
-        g32 = jax.tree.map(lambda x: x.astype(jnp.float32) * s, g)
-        u, adamw_state2 = adamw.update(g32, adamw_state, master)
-        master2 = optax.apply_updates(master, u)
-        compute = jax.tree.map(
-            lambda m, dt: m.astype(dt), master2, param_dtypes
-        )
-        return compute, master2, adamw_state2
-
-    compute, master2, adamw_state2 = compute_on("device_host")(
-        jax.jit(host_math)
-    )(g_use, scale_use, master, adamw_state)
-    inner2 = (
-        (clip_state, adamw_state2) if strategy.grad_clip is not None
-        else adamw_state2
-    )
-    new_state = (master2, inner2)
-    if delayed:
-        # Park this step's (unscaled) grads + their clip scale for the next
-        # step's host update.
-        new_state = new_state + (
-            (jax.device_put(grads, host(grad_specs)), scale),
-        )
-    return jax.device_put(compute, dev(param_specs)), new_state
+    """NamedShardings for the optimizer state: the whole of it (clip state,
+    Adam moments, schedule count) lives in device HBM under every strategy
+    (``strategy`` decides nothing here; the benchmark's builders pass it)."""
+    return named(mesh, opt_specs)
 
 
 # ---------------------------------------------------------------------------
@@ -624,12 +430,6 @@ def _leaf_name(path) -> str:
     return name.removeprefix("dense_")
 
 
-#: Self-test escape hatch (graftcheck `--inject bad-fsdp-axis`): False
-#: reverts to the pre-round-8 unrestricted largest-free-axis placement,
-#: reintroducing the llama-fsdp-dp4-tp2 transposed-tiling reshard fallback
-#: so CI can prove the HLO auditor catches it.
-_COMPOSED_FSDP_HYGIENE = True
-
 #: Leaves smaller than this (total elements) are not worth FSDP-sharding in
 #: a composed dp x tp mesh: norm scales and biases are a few hundred
 #: elements per layer, and 'data'-sharding them buys ~nothing in HBM while
@@ -684,7 +484,7 @@ def _shard_largest_free_axis(
     - vector-like leaves (< _COMPOSED_MIN_SHARD_ELEMENTS elements) stay
       replicated over 'data' (see the constant's comment).
     """
-    if composed and _COMPOSED_FSDP_HYGIENE:
+    if composed:
         # Vector-likeness is a PER-LAYER property: block leaves are
         # stacked (L, ...), and counting the layers axis would let a
         # deep model's norm scales (L x D elements) dodge the rule the
@@ -697,7 +497,7 @@ def _shard_largest_free_axis(
             return
     axes = list(range(len(shape)))
     candidates = axes[1:] + axes[:1] if is_block_leaf and len(shape) > 1 else axes
-    if composed and _COMPOSED_FSDP_HYGIENE and "model" in spec:
+    if composed and "model" in spec:
         model_ax = spec.index("model")
         candidates = [ax for ax in candidates if ax < model_ax]
     best = None
@@ -788,7 +588,6 @@ def param_partition_specs(
             if (
                 scan_stacked
                 and n_model > 1
-                and _COMPOSED_FSDP_HYGIENE
                 and name in _COMPOSED_CONTRACTION_DATA_SKIP
             ):
                 # Round-15 scan-carry rule: keep the leaf model-only (see

@@ -152,25 +152,6 @@ def build_parser() -> argparse.ArgumentParser:
                         "reference's per-step discipline, N>1 keeps host RPC "
                         "latency out of the timed loop on slow host links")
     # Configs
-    p.add_argument("--offload-opt-state", action="store_true",
-                   help="Host-offload the Adam moments to pinned host memory "
-                        "and run the Adam math on the host CPU (ZeRO-Offload "
-                        "analogue): the fp32-master-weight path for models "
-                        "whose optimizer state exceeds HBM")
-    p.add_argument("--offload-delayed-update", action="store_true",
-                   help="With --offload-opt-state: overlap the host Adam "
-                        "with the next step's forward/backward by consuming "
-                        "the previous step's gradients (DeepSpeed "
-                        "delayed_param_update semantics — params lag one "
-                        "step; step 0 performs no update)")
-    p.add_argument("--offload-dpu-start-step", type=int, default=0,
-                   help="With --offload-delayed-update: run exact serial "
-                        "host updates until this step, then switch to the "
-                        "overlapped schedule — gradient staleness "
-                        "measurably slows the steep early-descent phase "
-                        "(PERFORMANCE.md §13; DeepSpeed gates its DPU "
-                        "behind warmup for the same reason). 0 = delayed "
-                        "from the start. Incompatible with --resume")
     p.add_argument("--param-dtype", choices=["f32", "bf16"], default=None,
                    help="Parameter/Adam-state storage dtype (default: the "
                         "arm's config, normally f32 master weights). bf16 "
@@ -334,25 +315,10 @@ def main(argv=None) -> int:
         enable_debug()
 
     strategy = resolve_strategy(args)
-    if (
-        args.param_dtype is not None
-        or args.offload_opt_state
-        or args.offload_delayed_update
-    ):
+    if args.param_dtype is not None:
         import dataclasses as _dc
 
-        if args.param_dtype is not None:
-            strategy = _dc.replace(strategy, param_dtype=args.param_dtype)
-        if args.offload_opt_state:
-            strategy = _dc.replace(strategy, offload_opt_state=True)
-        if args.offload_delayed_update:
-            if not strategy.offload_opt_state:
-                raise SystemExit(
-                    "--offload-delayed-update requires --offload-opt-state "
-                    "(it schedules the HOST optimizer update; there is "
-                    "nothing to delay on a device-resident optimizer)"
-                )
-            strategy = _dc.replace(strategy, offload_delayed_update=True)
+        strategy = _dc.replace(strategy, param_dtype=args.param_dtype)
     dist.setup_distributed(
         master_addr=args.master_addr,
         master_port=args.master_port,
@@ -402,7 +368,6 @@ def main(argv=None) -> int:
             ring_zigzag={"auto": None, "on": True, "off": False}[args.ring_zigzag],
             layer_loop=args.layer_loop,
             tp_collective_matmul=args.tp_collective_matmul,
-            offload_dpu_start_step=args.offload_dpu_start_step,
             prng_impl=args.prng_impl,
             dataset_size=args.dataset_size,
             sync_every=args.sync_every,

@@ -339,7 +339,6 @@ def _run_benchmark_impl(
     ring_zigzag: Optional[bool] = None,
     layer_loop: str = "scan",
     tp_collective_matmul: bool = False,
-    offload_dpu_start_step: int = 0,
     dataset_size: int = 1000,
     sync_every: int = 1,
     skip_memory_check: bool = False,
@@ -642,49 +641,6 @@ def _run_benchmark_impl(
                 f"{refusal}\nPass --skip-memory-check to attempt the run anyway."
             )
 
-    if offload_dpu_start_step < 0:
-        # A negative value would skip every refusal below (the block gates
-        # on > 0) while still being recorded as run identity in the result
-        # row — the silent-A/B-corruption class those refusals exist for.
-        raise ValueError(
-            f"--offload-dpu-start-step must be >= 0, got {offload_dpu_start_step}"
-        )
-    if offload_dpu_start_step > 0:
-        # Delayed-update staleness measurably slows the STEEP early-descent
-        # phase (PERFORMANCE.md §13 — DeepSpeed gates its DPU behind warmup
-        # for the same reason), so this knob runs exact serial host updates
-        # until the given step, then switches to the overlapped schedule at
-        # a sync boundary. Resume is refused with it: the two phases
-        # checkpoint different optimizer-state layouts.
-        if not strategy.offload_delayed_update:
-            raise ValueError(
-                "--offload-dpu-start-step requires --offload-delayed-update"
-            )
-        if resume:
-            raise ValueError(
-                "--offload-dpu-start-step is incompatible with --resume "
-                "(the serial and delayed phases checkpoint different "
-                "optimizer-state layouts); restart the run, or drop the "
-                "start-step knob"
-            )
-        if offload_dpu_start_step >= steps:
-            # An out-of-range start step would run the WHOLE benchmark
-            # serial while the result row records the delayed identity —
-            # the same silent-A/B-corruption class the --ring-zigzag
-            # refusal exists for.
-            raise ValueError(
-                f"--offload-dpu-start-step {offload_dpu_start_step} >= "
-                f"--steps {steps}: the delayed phase would never begin "
-                "(drop the knob for a fully-serial run)"
-            )
-        if offload_dpu_start_step > warmup_steps and is_main:
-            print(
-                f"WARNING: --offload-dpu-start-step {offload_dpu_start_step} "
-                f"> --warmup-steps {warmup_steps}: timed windows will mix "
-                "serial and delayed step times into one result row; set the "
-                "start step inside the untimed warmup for clean timing"
-            )
-
     t_init = time.perf_counter()
     # Snapshot the allocator's process-lifetime high-water mark BEFORE this
     # arm allocates anything: when several arms share one process (bench.py
@@ -692,12 +648,6 @@ def _run_benchmark_impl(
     # publish an earlier arm's peak as its own (metrics.measure_peak_hbm
     # falls to the per-executable rung when the run didn't raise the mark).
     prior_peak_bytes = metrics_mod.peak_hbm_bytes()
-    dpu_serial_phase = strategy.offload_delayed_update and offload_dpu_start_step > 0
-    # With a serial pre-phase, the DPU state is created ABSTRACT (zero
-    # allocation): only its step_fn and the pending slot's layout are
-    # needed until the serial->delayed transition — the memory-tight
-    # offload arm never holds two copies of params/masters/moments, and
-    # startup skips one full init compile.
     state = create_train_state(
         model_config, strategy, mesh, seed=seed, grad_accum=grad_accum,
         # Streaming runs feed per-step batches from the host prefetcher;
@@ -705,30 +655,12 @@ def _run_benchmark_impl(
         # host->device transfers), byte-identical to every prior round.
         from_table=not use_stream, global_micro=global_micro, seq_len=seq_len,
         pipeline_schedule=pipeline_schedule, virtual_stages=virtual_stages,
-        abstract_init=dpu_serial_phase, sentinel=sentinel_in_step,
+        sentinel=sentinel_in_step,
     )
     if numerics is not None and not sentinel_in_step and is_main:
         print("SENTINEL: grad-norm guard unavailable on pipelined arms "
               "(shard_map lowering); loss-envelope and checksum guards "
               "remain active")
-    serial_state = None
-    pending_template = None
-    if dpu_serial_phase:
-        import dataclasses as _dc
-
-        pending_template = jax.tree.map(
-            lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=x.sharding),
-            state.opt_state[2],
-        )
-        serial_state = create_train_state(
-            model_config,
-            _dc.replace(strategy, offload_delayed_update=False),
-            mesh, seed=seed, grad_accum=grad_accum,
-            from_table=not use_stream, global_micro=global_micro,
-            seq_len=seq_len,
-            pipeline_schedule=pipeline_schedule,
-            virtual_stages=virtual_stages, sentinel=sentinel_in_step,
-        )
     if is_main:
         print(f"Model initialized: {state.n_params/1e6:.2f}M parameters")
         print(f"Init time: {time.perf_counter() - t_init:.1f}s")
@@ -780,8 +712,7 @@ def _run_benchmark_impl(
             )
         else:
             table = jax.device_put(ds.data, replicated)
-    active_state = serial_state if serial_state is not None else state
-    params, opt_state = active_state.params, active_state.opt_state
+    params, opt_state = state.params, state.opt_state
     # Timed stats keyed by step so the sentinel's rollback can truncate
     # a poisoned tail and the replay can re-measure honestly (replayed
     # step TIMES stay excluded — their windows fold the restore; the
@@ -917,14 +848,11 @@ def _run_benchmark_impl(
     # path), and _prepare_rollback falls back to it when no durable
     # checkpoint exists. Single-process only (device_get needs every
     # shard addressable; a one-host-only rollback on a multi-host run
-    # would diverge the replicas) and never under the offload-DPU serial
-    # phase (its opt-state layout changes mid-run, so a pre-transition
-    # snapshot could not be restored after it). Accounting is unchanged:
-    # the heal flows through the same note_rollback ledger.
+    # would diverge the replicas). Accounting is unchanged: the heal flows
+    # through the same note_rollback ledger.
     mem_snapshot = None
     if (
         numerics is not None
-        and serial_state is None
         and jax.process_count() == 1
         and (ckpt is None or checkpoint_every <= 0)
     ):
@@ -1349,33 +1277,6 @@ def _run_benchmark_impl(
                 sync_window(t_window)
             recorder.begin_phase("timed")
             t_window = time.perf_counter()
-        if serial_state is not None and step == offload_dpu_start_step:
-            # Serial -> delayed transition at a sync boundary: extend the
-            # optimizer state with an empty pending-grads slot (pinned
-            # host). The first delayed step applies one zero-grad
-            # "momentum-ghost" update while its own grads prime the
-            # pipeline — the price of entering the overlap, far below the
-            # steep-phase staleness it avoids (PERFORMANCE.md §13).
-            sync_window(t_window)
-
-            def zeros_like_tpl(s):
-                if jax.process_count() > 1:
-                    # device_put of a host array cannot target
-                    # non-addressable devices; assemble per-shard instead
-                    # (same pattern as the dataset table above).
-                    return jax.make_array_from_callback(
-                        s.shape, s.sharding,
-                        lambda idx: np.zeros(s.shape, s.dtype)[idx],
-                    )
-                return jax.device_put(jnp.zeros(s.shape, s.dtype), s.sharding)
-
-            opt_state = opt_state + (
-                jax.tree.map(zeros_like_tpl, pending_template),
-            )
-            active_state = state
-            if is_main:
-                print(f"[Step {step:04d}] delayed-update phase begins")
-            t_window = time.perf_counter()
         # Chaos param corruption (bitflip/grad-explode): poisons the
         # pre-dispatch handle exactly once at its armed step — the
         # sentinel-proof injection point. Inert (one attribute check)
@@ -1407,16 +1308,16 @@ def _run_benchmark_impl(
                 # — a rollback replay re-consumes the same records (the
                 # stream rewind in _roll_back_if_tripped repositions the
                 # cursor), so the step index must address the same rows.
-                params, opt_state, loss, gnorm = active_state.step_fn(
+                params, opt_state, loss, gnorm = state.step_fn(
                     params, opt_state, stream_batch, step
                 )
             else:
-                params, opt_state, loss = active_state.step_fn(
+                params, opt_state, loss = state.step_fn(
                     params, opt_state, stream_batch, step
                 )
                 gnorm = None
         elif numerics is None:
-            params, opt_state, loss = active_state.step_fn(
+            params, opt_state, loss = state.step_fn(
                 params, opt_state, table, step
             )
             gnorm = None
@@ -1426,14 +1327,14 @@ def _run_benchmark_impl(
             # per rollback (data_reseeds) so a replay draws fresh batch
             # rows and dropout keys instead of re-consuming the poisoned
             # sequence.
-            params, opt_state, loss, gnorm = active_state.step_fn(
+            params, opt_state, loss, gnorm = state.step_fn(
                 params, opt_state, table,
                 step + numerics.data_reseeds * steps,
             )
         else:
             # Pipelined sentinel arm: no in-step grad-norm (see the
             # sentinel_in_step note above) — same reseeded step fold.
-            params, opt_state, loss = active_state.step_fn(
+            params, opt_state, loss = state.step_fn(
                 params, opt_state, table,
                 step + numerics.data_reseeds * steps,
             )
@@ -1463,15 +1364,8 @@ def _run_benchmark_impl(
                 recorder.begin_phase("timed")
             t_window = time.perf_counter()
         # Checkpointing happens at a sync boundary, outside the next timed
-        # window, so benchmark step times stay honest. The serial phase of
-        # a --offload-dpu-start-step run is NOT checkpointed: its 2-tuple
-        # opt-state layout could not be restored by either arm's resume
-        # template (and resume is refused with the knob anyway).
-        if (
-            ckpt is not None
-            and ckpt.should_save(step)
-            and (serial_state is None or step >= offload_dpu_start_step)
-        ):
+        # window, so benchmark step times stay honest.
+        if ckpt is not None and ckpt.should_save(step):
             sync_window(t_window)
             if numerics is not None and numerics.trip is None:
                 # Pre-save checksum, unconditional under the sentinel
@@ -1658,7 +1552,7 @@ def _run_benchmark_impl(
             (grad_accum, global_micro, seq_len), jnp.int32,
             sharding=batch_sharding,
         )
-    compiled_step = active_state.aot_compile(params, opt_state, aot_batch, 0)
+    compiled_step = state.aot_compile(params, opt_state, aot_batch, 0)
 
     # Step-anatomy attribution (analysis/step_anatomy.py, docs/
     # OBSERVABILITY.md): when this run captured a profiler trace, decompose
@@ -1837,9 +1731,6 @@ def _run_benchmark_impl(
         n_experts=n_experts,
         remat_policy=state.model_config.remat,
         param_dtype=strategy.param_dtype,
-        offload_opt_state=strategy.offload_opt_state,
-        offload_delayed_update=strategy.offload_delayed_update,
-        offload_dpu_start_step=offload_dpu_start_step,
         causal=model_config.causal,
         ring_zigzag=(
             "auto" if model_config.ring_zigzag is None

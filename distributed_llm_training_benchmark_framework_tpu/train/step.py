@@ -86,30 +86,16 @@ def _resolve_model_config(
     if mesh is not None and mesh.shape.get("pipe", 1) > 1:
         model_config.refuse_pipeline()
     # bf16 parameter storage halves params+grads+Adam state — the knob that
-    # fits tier B on one chip (see StrategyConfig.param_dtype). The
-    # ZeRO-Offload arm also runs bf16 DEVICE params — its fp32 master
-    # weights live on the host inside the optimizer state, so device params
-    # are a compute copy by construction.
+    # fits tier B on one chip (see StrategyConfig.param_dtype).
     param_dtype = (
         jnp.bfloat16
-        if (
-            getattr(strategy, "param_dtype", "f32") == "bf16"
-            or getattr(strategy, "offload_opt_state", False)
-        )
+        if getattr(strategy, "param_dtype", "f32") == "bf16"
         else jnp.float32
     )
     return dataclasses.replace(
         model_config, remat=remat, compute_dtype=compute_dtype,
         param_dtype=param_dtype,
     )
-
-
-
-#: Self-test escape hatch (graftcheck `--inject bad-forward-gather`): False
-#: reverts the round-15 forward-side per-block param placement, letting the
-#: sharded-param arms' weight all-gathers float free of the layer loop again
-#: so CI can prove the HLO auditor catches the regression.
-_FORWARD_GATHER_OVERLAP = True
 
 
 def _per_block_slice_specs(stacked_specs: Params):
@@ -162,8 +148,6 @@ def fsdp_block_param_spec(
     replicated, and pinning it would add a per-layer round-trip. Composed
     dp x tp meshes arm too — the slice spec keeps both axes.
     """
-    if not _FORWARD_GATHER_OVERLAP:
-        return None
     if not (strategy.shard_params and not pipelined):
         return None
     return _per_block_slice_specs(param_specs)
@@ -405,9 +389,8 @@ def make_train_step(
     FOURTH output: the global grad-norm (f32, replicated — see
     :func:`global_norm_f32`), computed inside the jitted step so the
     sentinel's explosion guard costs one fused reduction instead of a
-    second device round-trip. Off by default: the extra all-reduce would
-    shift every arm's frozen collective budget, so only sentinel-armed
-    runs compile it (the HLO auditor compiles with the default).
+    second device round-trip. Off by default: only sentinel-armed runs
+    compile the extra all-reduce.
     """
     cfg = _resolve_model_config(model_config, strategy, mesh)
     grad_sharded_specs = strat.param_partition_specs(
@@ -567,24 +550,6 @@ def make_train_step(
             # replication-reshard suspects from this line alone).
             grads = lax.with_sharding_constraint(grads, strat.named(mesh, grad_sharded_specs))
 
-        if strategy.offload_opt_state:
-            # ZeRO-Offload: fp32 master params + moments live in pinned
-            # host memory, the full update + apply run on the host CPU, and
-            # the device's bf16 compute params are refreshed from the
-            # masters (see strategies.offload_update_and_apply).
-            with jax.named_scope(scopes.OPTIMIZER):
-                new_params, new_opt_state = strat.offload_update_and_apply(
-                    strategy, grads, opt_state, params, mesh,
-                    grad_sharded_specs if (
-                        strategy.shard_grads and not strategy.shard_params
-                    ) else param_specs,
-                    param_specs,
-                )
-            if sentinel:
-                return new_params, new_opt_state, loss, gnorm
-            return new_params, new_opt_state, loss
-
-        # (after the offloaded step's return: its moments live on the host)
         grads = _in_the_layouts_the_state_lives_in(grads, strat.named(
             mesh, grad_sharded_specs if strategy.shard_grads else param_specs))
         with jax.named_scope(scopes.OPTIMIZER):
@@ -707,17 +672,6 @@ def abstract_compile_step(
 
     params_abs = abstract(params_shape, param_specs)
     opt_abs = abstract(opt_shape, opt_specs)
-    if strategy.offload_opt_state:
-        # The state's host subtree must carry its pinned_host memory kind
-        # abstractly too, or the lowered update mixes memory spaces.
-        opt_shardings = strat.opt_state_shardings(mesh, opt_specs, strategy)
-        opt_abs = jax.tree.map(
-            lambda s_abs, sh: jax.ShapeDtypeStruct(
-                s_abs.shape, s_abs.dtype, sharding=sh
-            ),
-            opt_abs, opt_shardings,
-            is_leaf=lambda x: isinstance(x, jax.ShapeDtypeStruct),
-        )
     if from_table:
         batch_abs = jax.ShapeDtypeStruct(
             (dataset_size, seq_len), jnp.int32,
@@ -793,7 +747,6 @@ def create_train_state(
     seq_len: int = 0,
     pipeline_schedule: str = "gpipe",
     virtual_stages: int = 2,
-    abstract_init: bool = False,
     sentinel: bool = False,
 ) -> TrainState:
     """Initialize params + optimizer state directly into their target shardings.
@@ -801,13 +754,6 @@ def create_train_state(
     Init is jitted with ``out_shardings`` so tier-B params materialize sharded
     across HBM — no single host/device ever holds the full replicated tree
     (the TPU analogue of FSDP's deferred/sharded init).
-
-    ``abstract_init=True`` allocates NOTHING: params/opt_state come back as
-    ``ShapeDtypeStruct``s carrying their target shardings. Used by the
-    ``--offload-dpu-start-step`` serial phase, which only needs the delayed
-    state's step_fn and the pending slot's layout until the transition —
-    materializing the multi-GB host master/moment tree twice (once to read
-    its shapes, once for real) would double the startup bill for nothing.
     """
     cfg = _resolve_model_config(model_config, strategy, mesh)
     optimizer = strat.make_optimizer(strategy)
@@ -843,30 +789,17 @@ def create_train_state(
         scan_stacked=cfg.scan_layers,
     )
 
-    if abstract_init:
-        def _abstract(shapes, shardings):
-            return jax.tree.map(
-                lambda s, sh: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=sh),
-                shapes, shardings,
-            )
-
-        params = _abstract(params_shape, strat.named(mesh, param_specs))
-        opt_state = _abstract(
-            jax.eval_shape(optimizer.init, params_shape),
-            strat.opt_state_shardings(mesh, opt_specs, strategy),
-        )
-    else:
-        with mesh:
-            with scopes.host_span(scopes.INIT_PARAMS):
-                params = jax.jit(
-                    init_fn,
-                    out_shardings=strat.named(mesh, param_specs),
-                )(jax.random.key(seed))
-            with scopes.host_span(scopes.INIT_OPT_STATE):
-                opt_state = jax.jit(
-                    optimizer.init,
-                    out_shardings=strat.opt_state_shardings(mesh, opt_specs, strategy),
-                )(params)
+    with mesh:
+        with scopes.host_span(scopes.INIT_PARAMS):
+            params = jax.jit(
+                init_fn,
+                out_shardings=strat.named(mesh, param_specs),
+            )(jax.random.key(seed))
+        with scopes.host_span(scopes.INIT_OPT_STATE):
+            opt_state = jax.jit(
+                optimizer.init,
+                out_shardings=strat.opt_state_shardings(mesh, opt_specs, strategy),
+            )(params)
 
     step_fn, aot_compile = make_train_step(
         model_config,

@@ -139,13 +139,7 @@ def estimate_hbm(
 
     params_b = _sharded_bytes(params_shape, param_specs, mesh)
     grads_b = _sharded_bytes(params_shape, grad_specs, mesh)
-    if getattr(strategy, "offload_opt_state", False):
-        # The WHOLE optimizer state lives in pinned HOST memory and the
-        # update + apply run on the host (strategies.offload_update_and_
-        # apply) — nothing of it occupies HBM.
-        opt_b = 0
-    else:
-        opt_b = _sharded_bytes(opt_shape, opt_specs, mesh)
+    opt_b = _sharded_bytes(opt_shape, opt_specs, mesh)
 
     # --- analytic activations for one microbatch's fwd+bwd on this chip ---
     B = per_device_batch  # per-data-parallel-shard batch

@@ -125,8 +125,7 @@ def buffer_assignment_peak_bytes(ma) -> int:
 
 
 def measure_peak_hbm(
-    compiled_step=None, host_offload: bool = False,
-    prior_peak_bytes: Optional[int] = None,
+    compiled_step=None, prior_peak_bytes: Optional[int] = None,
 ) -> tuple[float, str]:
     """Measured per-device peak memory in GB, with provenance.
 
@@ -164,43 +163,6 @@ def measure_peak_hbm(
         try:
             ma = compiled_step.memory_analysis()
             peak_bytes = buffer_assignment_peak_bytes(ma)
-            # Host-offload arms only (``host_offload``): the
-            # buffer-assignment peak sums ALL memory spaces, so pinned-host
-            # buffers (fp32 masters + Adam moments) would masquerade as
-            # HBM. Report the device space only — and only when the
-            # subtraction leaves a device-plausible remainder, so an XLA
-            # version whose peak already excludes host space can't be
-            # clamped to a bogus ~0 under an authoritative-sounding tag.
-            # (Host outputs alias the donated host arguments, so only
-            # arguments + temps are subtracted — outputs would
-            # double-count.)
-            host_bytes = sum(
-                int(getattr(ma, f, 0) or 0)
-                for f in (
-                    "host_argument_size_in_bytes",
-                    "host_temp_size_in_bytes",
-                )
-            )
-            # The remainder must still be device-plausible: at minimum the
-            # device-resident arguments (compute params, dataset, grads)
-            # live in HBM at peak. If an XLA version's peak already
-            # excludes host space, peak - host falls BELOW that floor and
-            # we fall through to the raw value instead of underreporting.
-            dev_arg_floor = max(
-                0,
-                int(getattr(ma, "argument_size_in_bytes", 0) or 0)
-                - int(getattr(ma, "host_argument_size_in_bytes", 0) or 0),
-            )
-            if (
-                host_offload
-                and peak_bytes > 0
-                and 0 < host_bytes < peak_bytes
-                and peak_bytes - host_bytes >= dev_arg_floor
-            ):
-                return (
-                    (peak_bytes - host_bytes) / 1e9,
-                    "xla_buffer_assignment_minus_host",
-                )
             if peak_bytes > 0:
                 return peak_bytes / 1e9, "xla_buffer_assignment"
         except Exception:
@@ -282,16 +244,9 @@ class BenchmarkResult:
     # The remat policy the run actually executed with ("none"/"dots"/"full")
     # — provenance for strategies whose "auto" resolves per-geometry.
     remat_policy: str = "none"
-    # Parameter storage dtype ('f32'/'bf16') and host optimizer offload —
-    # run identity for arms sharing (strategy, tier, seq) geometry.
+    # Parameter storage dtype ('f32'/'bf16') — run identity for arms
+    # sharing (strategy, tier, seq) geometry.
     param_dtype: str = "f32"
-    offload_opt_state: bool = False
-    # Delayed (one-step-stale) host update — changes training semantics,
-    # so it is run identity (an overlapped arm is not the serial arm).
-    offload_delayed_update: bool = False
-    # First delayed step when the serial->delayed transition knob is used
-    # (0 = delayed from the start); also run identity.
-    offload_dpu_start_step: int = 0
     # Causal (autoregressive) masking — False is reference parity
     # (train_harness.py:127 applies no mask); True halves attention FLOPs
     # and, on causal rings, turns on the zigzag load-balanced layout.
@@ -489,9 +444,6 @@ def compute_result(
     n_experts: int = 0,
     remat_policy: str = "none",
     param_dtype: str = "f32",
-    offload_opt_state: bool = False,
-    offload_delayed_update: bool = False,
-    offload_dpu_start_step: int = 0,
     causal: bool = False,
     ring_zigzag: str = "auto",
     tp_collective_matmul: bool = False,
@@ -551,8 +503,7 @@ def compute_result(
     bytes_per_step = per_device_batch * grad_accum * seq_len * 4
     h2d = (bytes_per_step / mean_step) / 1e9 if mean_step > 0 else 0.0
     peak_gb, peak_method = measure_peak_hbm(
-        compiled_step, host_offload=offload_opt_state,
-        prior_peak_bytes=prior_peak_bytes,
+        compiled_step, prior_peak_bytes=prior_peak_bytes,
     )
     from . import flops as flops_mod
 
@@ -654,9 +605,6 @@ def compute_result(
         n_experts=n_experts,
         remat_policy=remat_policy,
         param_dtype=param_dtype,
-        offload_opt_state=offload_opt_state,
-        offload_delayed_update=offload_delayed_update,
-        offload_dpu_start_step=offload_dpu_start_step,
         causal=causal,
         ring_zigzag=ring_zigzag,
         tp_collective_matmul=tp_collective_matmul,

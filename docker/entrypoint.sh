@@ -55,9 +55,6 @@ export VIRTUAL_STAGES="${VIRTUAL_STAGES:-2}"
 export EXPERT_PARALLEL="${EXPERT_PARALLEL:-1}"
 export NUM_EXPERTS="${NUM_EXPERTS:-0}"
 export PARAM_DTYPE="${PARAM_DTYPE:-}"
-export OFFLOAD_OPT_STATE="${OFFLOAD_OPT_STATE:-0}"
-export OFFLOAD_DELAYED_UPDATE="${OFFLOAD_DELAYED_UPDATE:-0}"
-export OFFLOAD_DPU_START_STEP="${OFFLOAD_DPU_START_STEP:-0}"
 export CAUSAL="${CAUSAL:-0}"
 export MODEL_FAMILY="${MODEL_FAMILY:-tinygpt}"
 export RING_ZIGZAG="${RING_ZIGZAG:-auto}"
@@ -182,12 +179,6 @@ if [ -n "${PARAM_DTYPE}" ]; then
   ARGS="${ARGS} --param-dtype ${PARAM_DTYPE}"; fi
 if [ "${MODEL_FAMILY}" != "tinygpt" ]; then
   ARGS="${ARGS} --model-family ${MODEL_FAMILY}"; fi
-if [ "${OFFLOAD_OPT_STATE}" = "1" ]; then
-  ARGS="${ARGS} --offload-opt-state"; fi
-if [ "${OFFLOAD_DELAYED_UPDATE}" = "1" ]; then
-  ARGS="${ARGS} --offload-delayed-update"; fi
-if [ "${OFFLOAD_DPU_START_STEP}" != "0" ]; then
-  ARGS="${ARGS} --offload-dpu-start-step ${OFFLOAD_DPU_START_STEP}"; fi
 if [ "${CAUSAL}" = "1" ]; then
   ARGS="${ARGS} --causal"; fi
 if [ "${RING_ZIGZAG}" != "auto" ]; then

@@ -28,9 +28,6 @@ EXPERT_PARALLEL=1
 NUM_EXPERTS=0
 PARAM_DTYPE=""
 MODEL_FAMILY="tinygpt"
-OFFLOAD_OPT_STATE=0
-OFFLOAD_DELAYED_UPDATE=0
-OFFLOAD_DPU_START_STEP=0
 CAUSAL=0
 RING_ZIGZAG="auto"
 # Overlap round 3: 1 = collective-matmul tp fusion (ppermute-ring
@@ -98,9 +95,6 @@ while [ $# -gt 0 ]; do
     --num-experts) NUM_EXPERTS="$2"; shift 2 ;;
     --param-dtype) PARAM_DTYPE="$2"; shift 2 ;;
     --model-family) MODEL_FAMILY="$2"; shift 2 ;;
-    --offload-opt-state) OFFLOAD_OPT_STATE=1; shift 1 ;;
-    --offload-delayed-update) OFFLOAD_DELAYED_UPDATE=1; shift 1 ;;
-    --offload-dpu-start-step) OFFLOAD_DPU_START_STEP="$2"; shift 2 ;;
     --causal) CAUSAL=1; shift 1 ;;
     --tp-collective-matmul) TP_COLLECTIVE_MATMUL=1; shift 1 ;;
     --ring-zigzag) RING_ZIGZAG="$2"; shift 2 ;;
@@ -187,9 +181,6 @@ sed -e "s|{{JOB_NAME}}|$JOB_NAME|g" \
     -e "s|{{NUM_EXPERTS}}|$NUM_EXPERTS|g" \
     -e "s|{{PARAM_DTYPE}}|$PARAM_DTYPE|g" \
     -e "s|{{MODEL_FAMILY}}|$MODEL_FAMILY|g" \
-    -e "s|{{OFFLOAD_OPT_STATE}}|$OFFLOAD_OPT_STATE|g" \
-    -e "s|{{OFFLOAD_DELAYED_UPDATE}}|$OFFLOAD_DELAYED_UPDATE|g" \
-    -e "s|{{OFFLOAD_DPU_START_STEP}}|$OFFLOAD_DPU_START_STEP|g" \
     -e "s|{{CAUSAL}}|$CAUSAL|g" \
     -e "s|{{RING_ZIGZAG}}|$RING_ZIGZAG|g" \
     -e "s|{{TP_COLLECTIVE_MATMUL}}|$TP_COLLECTIVE_MATMUL|g" \

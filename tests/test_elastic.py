@@ -528,38 +528,58 @@ def test_elastic_resume_telemetry_and_ledger_record_stitch(elastic_round_trip):
 def multihost_preemption(tmp_path_factory):
     """The multihost dryrun: two ranks rendezvous for real over
     jax.distributed on localhost (each driving its own local mesh);
-    SIGTERM is injected on rank 1 ONLY."""
+    SIGTERM is injected on rank 1 ONLY.
+
+    Each rank steps its own mesh, so nothing keeps the two in step the way
+    a pod's collectives do, and the protocol assumes that (``coordinate``:
+    "boundary-aligned across hosts"). Fourteen steps take a third of a
+    second: beside other jobs one rank compiled two seconds later than the
+    other, rank 0 had finished before rank 1 reached step 9, and it timed
+    out alone at the end-of-run barrier (exit 1). So the run is made longer
+    than the fixture's own limit: whichever rank is ahead is still stepping
+    when rank 1's SIGTERM fires, the agreed stop is the boundary of the rank
+    ahead, and the other walks up to it. Only this fixture's limit decides."""
     base = tmp_path_factory.mktemp("mh_preempt")
+    limit_sec = 180
     with socket.socket() as s:
         s.bind(("127.0.0.1", 0))
         port = s.getsockname()[1]
-    procs = []
+    env = _env()
+    env["PREEMPT_ACK_TIMEOUT_SEC"] = str(limit_sec)
+    procs, logs = [], []
     for rank in (0, 1):
         results = base / f"results{rank}"
         ckpt = base / f"ckpt{rank}"
+        # To a file: a rank that waits for its peer must never also wait
+        # for this fixture to drain a pipe.
+        logs.append(open(base / f"rank{rank}.log", "w"))
         procs.append(subprocess.Popen(
             _harness(results, ckpt, strategy="ddp", world_size=1, extra=(
                 "--rank", str(rank), "--num-processes", "2",
                 "--master-addr", "127.0.0.1", "--master-port", str(port),
                 "--inject-fault", "sigterm-rank@9:1",
+                "--steps", "40000", "--checkpoint-every", "10000",
+                "--heartbeat-sec", "30",
             )),
-            stdout=subprocess.PIPE, stderr=subprocess.STDOUT, text=True,
-            env=_env(),
+            stdout=logs[-1], stderr=subprocess.STDOUT, env=env,
         ))
-    # Alone, both ranks exit 75 inside a minute. One shared limit, and no
-    # rank left running when it expires: a bad run costs the suite two
-    # minutes, not five per rank.
-    deadline = time.monotonic() + 120
-    outs = []
+    # Alone, both ranks exit 75 inside half a minute. One shared limit, and
+    # no rank left running when it expires.
+    deadline = time.monotonic() + limit_sec
     try:
         for p in procs:
-            out, _ = p.communicate(timeout=max(deadline - time.monotonic(), 1))
-            outs.append(out)
+            try:
+                p.wait(timeout=max(deadline - time.monotonic(), 1))
+            except subprocess.TimeoutExpired:
+                pass
     finally:
         for p in procs:
             if p.poll() is None:
                 p.kill()
                 p.wait()
+        for f in logs:
+            f.close()
+    outs = [(base / f"rank{rank}.log").read_text() for rank in (0, 1)]
     return {"base": base, "rcs": [p.returncode for p in procs], "outs": outs}
 
 

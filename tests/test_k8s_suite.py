@@ -308,7 +308,6 @@ def test_roster_job_names_and_manifest_env(roster_run):
     assert 'name: LAYER_LOOP\n              value: "unrolled"' in fl
     assert 'name: ATTENTION\n              value: "flash"' in fl
     moe = (tmp / "manifest_tpu-bench-zero2-ws4-moe-ep2.yaml").read_text()
-    assert 'name: OFFLOAD_OPT_STATE\n              value: "0"' in moe
     assert 'name: NUM_EXPERTS\n              value: "4"' in moe
     assert 'name: EXPERT_PARALLEL\n              value: "2"' in moe
     for f in manifests:

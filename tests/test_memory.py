@@ -262,84 +262,3 @@ def test_tier_b_single_chip_paths():
     assert memory.check_fits(est_bf16, "TPU v5 lite") is None  # fits
     # the bf16 option must actually halve the state, not just relabel it
     assert est_bf16.total < 0.62 * est_f32.total
-
-
-def test_offload_opt_state_excluded_from_hbm_estimate():
-    """ZeRO-Offload arm: the optimizer state (fp32 masters + moments) lives
-    on the host, so the HBM estimate must drop it — that's what makes tier B
-    with fp32-quality Adam fit a 16 GiB chip."""
-    strat = dataclasses.replace(get_strategy("zero3"), offload_opt_state=True)
-    cfg = get_model_config("B", 1024, attention_impl="flash")
-    import dataclasses as _dc
-
-    from distributed_llm_training_benchmark_framework_tpu.train.step import (
-        _resolve_model_config,
-    )
-
-    rcfg = _resolve_model_config(cfg, _dc.replace(strat, remat="full"))
-    est = mem.estimate_hbm(rcfg, strat, _mesh(), 1, 1024, dataset_size=128)
-    assert est.opt_state == 0
-    # bf16 device params + bf16 grads + activations fit comfortably.
-    assert est.total < 12 * 1024**3, est.total / 1024**3
-    # The non-offload f32 arm does NOT fit (the reason the knob exists).
-    plain = dataclasses.replace(get_strategy("zero3"), remat="full")
-    rplain = _resolve_model_config(cfg, plain)
-    est2 = mem.estimate_hbm(rplain, plain, _mesh(), 1, 1024, dataset_size=128)
-    assert est2.total > 16 * 1024**3
-
-
-def test_offload_requires_tpu_backend():
-    """On non-TPU backends the offload arm fails loudly with the remedy
-    (XLA:CPU cannot partition host-placed state)."""
-    import pytest as _pytest
-
-    from distributed_llm_training_benchmark_framework_tpu.parallel.strategies import (
-        make_optimizer,
-        opt_state_shardings,
-    )
-    from distributed_llm_training_benchmark_framework_tpu.parallel import (
-        strategies as strat_mod,
-    )
-
-    strat = dataclasses.replace(get_strategy("zero2"), offload_opt_state=True)
-    optimizer = make_optimizer(strat)
-    cfg = get_model_config("S", 64, dropout=0.0)
-    params_shape = jax.eval_shape(
-        lambda k: __import__(
-            "distributed_llm_training_benchmark_framework_tpu.models.tinygpt",
-            fromlist=["init_params"],
-        ).init_params(cfg, k),
-        jax.random.key(0),
-    )
-    mesh = _mesh()
-    param_specs = strat_mod.param_partition_specs(params_shape, mesh, shard=False)
-    opt_specs = strat_mod.opt_state_partition_specs(
-        optimizer, params_shape, param_specs, mesh, shard=False
-    )
-    with _pytest.raises(ValueError, match="TPU runtime"):
-        opt_state_shardings(mesh, opt_specs, strat)
-
-
-def test_offload_optimizer_state_layout():
-    """Offload optimizer state = (fp32 master params, adamw state); its
-    update is not directly callable (the step uses
-    offload_update_and_apply)."""
-    import numpy as _np
-    import pytest as _pytest
-
-    from distributed_llm_training_benchmark_framework_tpu.parallel.strategies import (
-        make_optimizer,
-    )
-    import jax.numpy as jnp
-
-    strat = dataclasses.replace(get_strategy("zero2"), offload_opt_state=True)
-    tx = make_optimizer(strat)
-    params = {"w": jnp.ones((4, 4), jnp.bfloat16)}
-    state = tx.init(params)
-    master, inner = state
-    assert master["w"].dtype == jnp.float32
-    _np.testing.assert_allclose(
-        _np.asarray(master["w"]), _np.asarray(params["w"], dtype=_np.float32)
-    )
-    with _pytest.raises(ValueError, match="offload_update_and_apply"):
-        tx.update(params, state, params)

@@ -240,14 +240,6 @@ def test_fsdp_shape_arms_block_param_spec(eight_devices):
     assert "wqkv" not in dict(
         step_mod.fsdp_block_param_spec(get_strategy("fsdp"), forced, False)
     )
-    # The injection escape hatch reverts the arming — and self-restores.
-    step_mod._FORWARD_GATHER_OVERLAP = False
-    try:
-        assert step_mod.fsdp_block_param_spec(
-            get_strategy("fsdp"), specs, False
-        ) is None
-    finally:
-        step_mod._FORWARD_GATHER_OVERLAP = True
 
 
 # The forward-overlap shape itself (weight movement interleaved with the
@@ -519,15 +511,17 @@ def test_cmm_ring_replaces_projection_gathers(eight_devices):
 
 
 def test_cmm_arm_budget_is_frozen_with_ring_signature():
-    """The committed budget IS the fusion claim: projection all-gathers
-    collapsed (21 on the plain gqa arm -> 5 boundary gathers), the
-    ppermute ring in their place, reshard suspects 0 — and the plain arm's
-    budget is untouched, so the A/B pair stays auditable."""
+    """The committed budget holds the ring's signature: ppermutes on the
+    cmm arm and none on the plain one, reshard suspects 0. (Under jax 0.4
+    the plain arm also held 21 all-gathers against the cmm arm's 5; this
+    jax's CPU partitioner gives the plain arm no projection all-gather to
+    collapse, so the pair no longer differs there; what the ring removes
+    is asserted against its own unfused form in
+    ``test_cmm_injection_registry_and_flag_restore``.)"""
     budgets = hlo_audit.load_budgets()
     cmm = budgets["arms"]["llama-tp2-gqa-cmm"]
     plain = budgets["arms"]["llama-tp2-gqa"]
     assert cmm["collectives"]["collective-permute"] > 0
-    assert cmm["collectives"]["all-gather"] < plain["collectives"]["all-gather"]
     assert cmm["replication_reshard_suspects"] == 0
     assert plain["collectives"]["collective-permute"] == 0
 
@@ -557,30 +551,31 @@ def test_cmm_refuses_incompatible_compositions(eight_devices):
 
 
 def test_cmm_injection_registry_and_flag_restore(eight_devices):
-    """bad-forward-gather and bad-cmm-ring are registered injections; each
-    reverts its flag for the duration of the lowering and self-restores."""
+    """bad-cmm-ring is a registered injection: it swaps the ring bodies for
+    the duration of the lowering and puts the originals back."""
     import dataclasses as _dc
 
     from distributed_llm_training_benchmark_framework_tpu.ops import (
         collective_matmul as cm,
     )
-    from distributed_llm_training_benchmark_framework_tpu.train import (
-        step as step_mod,
-    )
-
-    assert "bad-forward-gather" in hlo_audit._INJECTIONS
     assert "bad-cmm-ring" in hlo_audit._INJECTIONS
+    real = cm.ag_proj_sharded, cm.rs_proj_sharded
     rep = hlo_audit.audit_arm(
         _dc.replace(CMM_ARM, inject="bad-cmm-ring")
     )
-    assert cm._CMM_RING is True  # restored
+    assert (cm.ag_proj_sharded, cm.rs_proj_sharded) == real  # restored
     # The unfused lowering: bulk collectives back, ring gone.
     assert rep.collectives["collective-permute"] == 0
     assert rep.collectives["reduce-scatter"] > 0
     budgets = hlo_audit.load_budgets()
     deltas = hlo_audit.diff_against_budget(rep, budgets)
     assert any("all-gather" in d and "REGRESSED" in d for d in deltas), deltas
-    assert step_mod._FORWARD_GATHER_OVERLAP is True
+    # The fusion claim, on this pair: the ring removes bulk collectives the
+    # unfused form of the same projections pays (the plain tp arm no longer
+    # shows it: this jax gives it no projection all-gather to collapse).
+    bulk = lambda c: c["all-gather"] + c["reduce-scatter"]
+    fused = budgets["arms"]["llama-tp2-gqa-cmm"]["collectives"]
+    assert bulk(fused) < bulk(rep.collectives), (fused, rep.collectives)
 
 
 def test_cmm_arm_joins_topology_roster_with_flat_ring():

@@ -204,6 +204,48 @@ def test_moe_pp_loss_and_grads_match(eight_devices):
         )
 
 
+@pytest.mark.parametrize("schedule", ["gpipe", "1f1b"])
+def test_embedding_lookup_manual_over_data_matches_dp1(eight_devices, schedule):
+    """On a mesh with a data degree ``pipeline.embed`` runs the lookup manual
+    over 'data' (a local scatter and one psum for the table's gradient); at
+    dp=1 it is ``tinygpt.embed`` itself. Same loss and the same gradients,
+    with the embedding's dropout live: the mask is drawn on the whole
+    microbatch either way."""
+    import jax.numpy as jnp
+
+    from distributed_llm_training_benchmark_framework_tpu.parallel.pipeline import (
+        pipeline_loss_and_grads_1f1b,
+    )
+
+    cfg = get_model_config("S", 64, dropout=0.2, compute_dtype=jnp.float32)
+    params = init_params(cfg, jax.random.key(0))
+    ds = SyntheticDataset(vocab_size=512, seq_len=64, size=16)
+    batch = ds.batch_for_step(0, 2 * 4).reshape(2, 4, 64)
+    key = jax.random.key(7)
+
+    def loss_and_grads(dp):
+        mesh = make_mesh((dp, 1, 1, 2), ("data", "seq", "model", "pipe"),
+                         devices=jax.devices()[:2 * dp])
+        if schedule == "1f1b":
+            fn = lambda p: pipeline_loss_and_grads_1f1b(
+                cfg, mesh, p, batch, base_key=key, deterministic=False)
+        else:
+            fn = jax.value_and_grad(lambda p: pipeline_loss_fn(
+                cfg, mesh, p, batch, base_key=key, deterministic=False))
+        with jax.set_mesh(mesh):
+            return jax.jit(fn)(params)
+
+    loss1, grads1 = loss_and_grads(1)
+    loss2, grads2 = loss_and_grads(2)
+    np.testing.assert_allclose(float(loss2), float(loss1), rtol=1e-5)
+    flat2 = dict(jax.tree_util.tree_leaves_with_path(grads2))
+    for path, g in jax.tree_util.tree_leaves_with_path(grads1):
+        np.testing.assert_allclose(
+            np.asarray(flat2[path]), np.asarray(g), rtol=1e-4, atol=1e-5,
+            err_msg=jax.tree_util.keystr(path),
+        )
+
+
 def make_state(strategy, mesh_shape, grad_accum, **kw):
     cfg = get_model_config("S", 64, dropout=0.0)
     n = int(np.prod(mesh_shape))

@@ -161,24 +161,30 @@ def test_strategy_config_files_load():
     assert {"ddp", "fsdp", "zero2", "zero3"} <= names
 
 
-def test_abstract_init_allocates_nothing(eight_devices):
-    """create_train_state(abstract_init=True) returns ShapeDtypeStructs
-    carrying the same shardings the materialized state would have — the
-    zero-allocation template path --offload-dpu-start-step's serial phase
-    uses to learn the delayed layout without paying two full inits."""
-    cfg = get_model_config("S", 64, dropout=0.0)
-    mesh = make_mesh((8,), ("data",), devices=jax.devices()[:8])
-    abstract = create_train_state(
-        cfg, get_strategy("zero2"), mesh, seed=42, abstract_init=True
+@pytest.mark.parametrize("name", ["ddp", "fsdp", "zero2", "zero3"])
+def test_one_optimizer_callable_and_on_device(eight_devices, name):
+    """Every strategy has the same way through the optimizer: ``make_optimizer``
+    returns a transformation whose ``update`` runs, and the whole of its state
+    is placed in the devices' own memory (no host memory kind anywhere)."""
+    from distributed_llm_training_benchmark_framework_tpu.parallel import (
+        strategies as strat,
     )
-    leaves = jax.tree.leaves((abstract.params, abstract.opt_state))
-    assert leaves and all(isinstance(l, jax.ShapeDtypeStruct) for l in leaves)
 
-    real = create_train_state(cfg, get_strategy("zero2"), mesh, seed=42)
-    a_flat = jax.tree.leaves((abstract.params, abstract.opt_state))
-    r_flat = jax.tree.leaves((real.params, real.opt_state))
-    assert len(a_flat) == len(r_flat)
-    for a, r in zip(a_flat, r_flat):
-        assert a.shape == r.shape and a.dtype == r.dtype
-        assert a.sharding.spec == r.sharding.spec, (a, r.sharding)
-    assert abstract.n_params == real.n_params
+    strategy = get_strategy(name)
+    mesh = make_mesh((8,), ("data",), devices=jax.devices()[:8])
+    tx = strat.make_optimizer(strategy)
+    params = {"w": jnp.ones((16, 8), jnp.float32), "b": jnp.ones((8,), jnp.float32)}
+    state = tx.init(params)
+    updates, state2 = tx.update(params, state, params)
+    assert jax.tree.structure(updates) == jax.tree.structure(params)
+    assert jax.tree.structure(state2) == jax.tree.structure(state)
+
+    param_specs = strat.param_partition_specs(params, mesh, shard=strategy.shard_params)
+    opt_specs = strat.opt_state_partition_specs(
+        tx, params, param_specs, mesh, shard=strategy.shard_opt_state)
+    shardings = jax.tree.leaves(strat.opt_state_shardings(mesh, opt_specs, strategy))
+    assert len(shardings) == len(jax.tree.leaves(state))
+    device_kind = jax.devices()[0].default_memory().kind
+    assert {s.memory_kind for s in shardings} <= {None, device_kind}
+
+

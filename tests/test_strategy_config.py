@@ -17,6 +17,7 @@ from distributed_llm_training_benchmark_framework_tpu.parallel.strategies import
     from_deepspeed_config,
     is_deepspeed_config,
     get_strategy,
+    load_strategy_config,
 )
 from distributed_llm_training_benchmark_framework_tpu.train.harness import (
     resolve_strategy,
@@ -180,117 +181,29 @@ def test_resolve_strategy_unknown_format_falls_back(tmp_path, capsys):
     assert "not a recognized" in capsys.readouterr().out
 
 
-def test_deepspeed_offload_optimizer_maps_to_offload_opt_state():
-    """zero_optimization.offload_optimizer.device cpu -> pinned-host offload;
-    the reference's shipped "none" stays off (its configs carry the section
-    disabled)."""
-    from distributed_llm_training_benchmark_framework_tpu.parallel.strategies import (
-        from_deepspeed_config,
-    )
-
-    on = from_deepspeed_config(
-        {"zero_optimization": {"stage": 3, "offload_optimizer": {"device": "cpu"}}},
-        "zero3",
-    )
-    assert on.offload_opt_state
-    off = from_deepspeed_config(
-        {"zero_optimization": {"stage": 3, "offload_optimizer": {"device": "none"}}},
-        "zero3",
-    )
-    assert not off.offload_opt_state
-    absent = from_deepspeed_config({"zero_optimization": {"stage": 3}}, "zero3")
-    assert not absent.offload_opt_state
-
-
-def test_delayed_update_state_structure_and_specs():
-    """--offload-delayed-update extends the optimizer state with (pending
-    grads, clip scale) parked alongside the masters; partition-spec
-    derivation must give the pending tree param specs (pinned-host on TPU)
-    and the scalar P() — the layout checkpoints and resumes through orbax."""
-    import dataclasses
-
-    import jax
-    import jax.numpy as jnp
-    from jax.sharding import PartitionSpec as P
-
-    from distributed_llm_training_benchmark_framework_tpu.parallel import (
-        get_strategy,
-        make_mesh,
-    )
-    from distributed_llm_training_benchmark_framework_tpu.parallel import (
-        strategies as strat,
-    )
-
-    s = dataclasses.replace(
-        get_strategy("zero3"), offload_opt_state=True,
-        offload_delayed_update=True,
-    )
-    opt = strat.make_optimizer(s)
-    params = {"w": jnp.zeros((8, 4), jnp.bfloat16), "b": jnp.zeros((4,), jnp.bfloat16)}
-    state = opt.init(params)
-    assert len(state) == 3
-    master, inner, (pending, scale) = state
-    assert jax.tree.structure(pending) == jax.tree.structure(params)
-    assert pending["w"].dtype == jnp.bfloat16  # device grad dtype, not fp32
-    assert scale.shape == ()
-    # Spec derivation covers the extended tree: pending leaves get real
-    # specs, the scale scalar replicates.
-    mesh = make_mesh((1,), ("data",), devices=jax.devices()[:1])
-    pspecs = strat.param_partition_specs(params, mesh, shard=True)
-    ospecs = strat.opt_state_partition_specs(opt, params, pspecs, mesh, shard=True)
-    assert ospecs[2][1] == P()
-    assert jax.tree.structure(ospecs[2][0]) == jax.tree.structure(params)
-
-
-def test_delayed_update_requires_offload(tmp_path):
-    """--offload-delayed-update without --offload-opt-state is a config
-    error, not a silent no-op."""
-    import subprocess
-    import sys
-
-    proc = subprocess.run(
-        [
-            sys.executable, "-m",
-            "distributed_llm_training_benchmark_framework_tpu.train.harness",
-            "--strategy", "ddp", "--world-size", "1", "--tier", "S",
-            "--seq-len", "64", "--steps", "1", "--per-device-batch", "1",
-            "--grad-accum", "1", "--offload-delayed-update",
-            "--results-dir", str(tmp_path),
-        ],
-        capture_output=True, text=True, timeout=120,
-        env={**__import__("os").environ, "JAX_PLATFORMS": "cpu"},
-    )
-    assert proc.returncode != 0
-    assert "requires --offload-opt-state" in proc.stderr + proc.stdout
-
-
-def test_dpu_start_step_validation(tmp_path):
-    """--offload-dpu-start-step demands the delayed-update arm, and refuses
-    --resume (the two phases checkpoint different optimizer-state
-    layouts). Both refusals fire before any device work."""
-    import os
-    import subprocess
-    import sys
-
-    def run(*extra):
-        return subprocess.run(
-            [
-                sys.executable, "-m",
-                "distributed_llm_training_benchmark_framework_tpu.train.harness",
-                "--strategy", "zero3", "--world-size", "1", "--tier", "S",
-                "--seq-len", "64", "--steps", "1", "--per-device-batch", "1",
-                "--grad-accum", "1", "--results-dir", str(tmp_path), *extra,
-            ],
-            capture_output=True, text=True, timeout=120,
-            env={**os.environ, "JAX_PLATFORMS": "cpu"},
-        )
-
-    p = run("--offload-dpu-start-step", "5")
-    assert p.returncode != 0
-    assert "requires --offload-delayed-update" in p.stderr + p.stdout
-
-    p = run("--offload-opt-state", "--offload-delayed-update",
-            "--offload-dpu-start-step", "5", "--resume",
-            "--checkpoint-dir", str(tmp_path / "ck"))
-    assert p.returncode != 0
-    assert "incompatible with --resume" in p.stderr + p.stdout
+@pytest.mark.parametrize("where, key, value", [
+    ("deepspeed", "offload_optimizer", "cpu"),
+    ("deepspeed", "offload_optimizer", "nvme"),
+    ("strategy", "offload_opt_state", True),
+    ("strategy", "offload_delayed_update", True),
+])
+def test_offload_requests_are_refused_by_name(tmp_path, where, key, value):
+    """The optimizer state lives in HBM: a config that asks for a host
+    offload is refused with the key to drop, not silently run on the device.
+    The reference's shipped ``"device": "none"`` (and no section) still load."""
+    if where == "deepspeed":
+        def load(device):
+            return from_deepspeed_config(
+                {"zero_optimization": {"stage": 3, "offload_optimizer": {"device": device}}},
+                "zero3",
+            )
+        assert load("none") == from_deepspeed_config(
+            {"zero_optimization": {"stage": 3}}, "zero3")
+    else:
+        def load(v):
+            path = tmp_path / "arm.json"
+            path.write_text(json.dumps({"strategy": "zero3", key: v}))
+            return load_strategy_config(str(path))
+    with pytest.raises(ValueError, match="lives in HBM") as e:
+        load(value)
+    assert key in str(e.value) and "Drop" in str(e.value)
