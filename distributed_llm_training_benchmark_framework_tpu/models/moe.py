@@ -317,10 +317,18 @@ def _permute_rows_bwd(inverse, g):
 _permute_rows.defvjp(_permute_rows_fwd, _permute_rows_bwd)
 
 
-def _route_dropless(c, xt: jax.Array, router: jax.Array, sequences: int = 1):
+def _route_dropless(c, xt: jax.Array, router: jax.Array, sequences: int = 1, bias=None):
     """Token-choice routing with nothing dropped -> (gates (N, K) fp32,
     expert_idx (N, K), counts (E,) int32, aux). ``sequences`` is how many
     equal sequences the N tokens are, read only under ``seq_aux``.
+
+    The scoring rule is the config's (``router_score``): a softmax over the
+    experts, or each logit's sigmoid, where the K are chosen by score +
+    ``bias`` (the layer's (E,) ``router_bias``, which moves the choice and no
+    gate) and the load-balance term is 0: the bias is that family's balancer,
+    and its update between steps is not part of the step. Either way the gates
+    are renormalised under ``norm_topk_prob`` and then multiplied by
+    ``routed_scaling_factor``.
 
     fp32 throughout, the logits at the highest matmul precision (a TPU's
     default would round the router to bfloat16, and near-ties in the top-k
@@ -337,12 +345,22 @@ def _route_dropless(c, xt: jax.Array, router: jax.Array, sequences: int = 1):
         "nd,de->ne", xt.astype(jnp.float32), router.astype(jnp.float32),
         precision=lax.Precision.HIGHEST, preferred_element_type=jnp.float32,
     )
-    probs = jax.nn.softmax(logits, axis=-1)
-    gates, expert_idx = lax.top_k(probs, K)
+    sigmoid = c.router_score == "sigmoid"
+    if sigmoid:
+        probs = jax.nn.sigmoid(logits)
+        _, expert_idx = lax.top_k(probs + bias.astype(jnp.float32), K)
+        gates = jnp.take_along_axis(probs, expert_idx, axis=-1)
+    else:
+        probs = jax.nn.softmax(logits, axis=-1)
+        gates, expert_idx = lax.top_k(probs, K)
     if c.norm_topk_prob:
         gates = gates / jnp.maximum(jnp.sum(gates, axis=-1, keepdims=True), 1e-9)
+    if c.routed_scaling_factor != 1.0:
+        gates = gates * c.routed_scaling_factor
     counts = jnp.sum(jax.nn.one_hot(expert_idx, E, dtype=jnp.int32), axis=(0, 1))
-    if c.seq_aux:
+    if sigmoid:
+        aux = jnp.zeros((), jnp.float32)
+    elif c.seq_aux:
         # DeepSeek: the same statistic a sequence (f its share of the S x K
         # assignments, P its mean probability), averaged over sequences.
         S = N // sequences
@@ -510,7 +528,7 @@ def routing_rows(config, layer: dict, x: jax.Array):
     dispatch puts into the held experts' buffer and the held assignments that
     do not fit it)."""
     gates, expert_idx, counts, _ = _route_dropless(
-        config, x.reshape(-1, x.shape[-1]), layer["router"])
+        config, x.reshape(-1, x.shape[-1]), layer["router"], bias=layer.get("router_bias"))
     if config.experts_held is None:
         return counts, None
     *_, rows, overflow = _held_plan(config, expert_idx, counts, gates)
@@ -600,7 +618,8 @@ def _moe_mlp_held(c, layer, x, dropout_key, deterministic):
     N = B * S
     xt = x.reshape(N, D)
     with jax.named_scope(scopes.ROUTER):
-        gates, expert_idx, counts, aux = _route_dropless(c, xt, layer["router"], B)
+        gates, expert_idx, counts, aux = _route_dropless(
+            c, xt, layer["router"], B, layer.get("router_bias"))
         if not c.trains_routing:
             gates, aux = lax.stop_gradient((gates, aux))
     with jax.named_scope(scopes.DISPATCH):
@@ -629,7 +648,8 @@ def _moe_mlp_dropless(c, layer, x, dropout_key, deterministic):
     N, K = B * S, c.expert_top_k
     xt = x.reshape(N, D)
     with jax.named_scope(scopes.ROUTER):
-        gates, expert_idx, counts, aux = _route_dropless(c, xt, layer["router"], B)
+        gates, expert_idx, counts, aux = _route_dropless(
+            c, xt, layer["router"], B, layer.get("router_bias"))
     with jax.named_scope(scopes.DISPATCH):
         # Assignment n*K + k is token n's k-th choice; ``order`` lists the
         # assignments expert by expert, ``inverse`` is where each one went.

@@ -48,11 +48,11 @@ from ..utils import scopes
 
 Params = Dict[str, Any]
 
-REMAT_POLICIES = ("none", "dots", "full")
+REMAT_POLICIES = ("none", "dots", "full_keep_kernels", "full")
 
 
 def normalize_remat(value: Any) -> str:
-    """Normalize a remat policy: accepts "none"/"dots"/"full" or a legacy
+    """Normalize a remat policy: accepts one of ``REMAT_POLICIES`` or a legacy
     bool (True = "full"). "auto" must be resolved (utils.memory
     .resolve_auto_remat) before it reaches the model."""
     if isinstance(value, bool):
@@ -114,10 +114,19 @@ class YarnScaling:
         return self._mscale(self.factor, self.mscale_all_dim) ** 2
 
 
-#: The kinds of attention layer a stack can mix (``TinyGPTConfig.layer_types``):
-#: ``global`` sees every earlier position, ``window`` the last
-#: ``sliding_window`` of them. Also the names of their scopes under ``attention``.
-LAYER_KINDS = (scopes.GLOBAL, scopes.WINDOW)
+#: The kinds of layer a stack can mix (``TinyGPTConfig.layer_types``); a kind
+#: chooses the layer's mixer: ``global`` is softmax attention over every
+#: earlier position (latent attention where the config has it), ``window``
+#: over the last ``sliding_window`` of them, ``kda`` the gated delta-rule
+#: recurrence (``_kda_sublayer``), which has leaves of its own. Also the names
+#: of their scopes under ``attention``.
+LAYER_KINDS = (scopes.GLOBAL, scopes.WINDOW, scopes.KDA)
+
+#: The stacks of the parameter tree, by (the mixer is KDA, the MLP is a
+#: leading dense one): layers of one stack have equal leaves
+#: (``TinyGPTConfig.layer_groups``). Every name ends in ``blocks``.
+_STACK_NAMES = {(False, False): "blocks", (False, True): "dense_blocks",
+                (True, False): "kda_blocks", (True, True): "kda_dense_blocks"}
 
 
 @dataclasses.dataclass(frozen=True)
@@ -171,6 +180,10 @@ class TinyGPTConfig:
     #            elementwise/norm work (and reference attention's batched
     #            products) is recomputed in backward (the low-tax middle
     #            ground);
+    #   "full_keep_kernels" — jax.checkpoint keeping only the mixer's
+    #            forward kernel's named results (the flash kernel's out and
+    #            lse, a kda layer's output and chunk states: a few (B, S, H x
+    #            Dv) arrays a layer, and the dearest thing to run again);
     #   "full" — all-or-nothing jax.checkpoint per layer (least memory,
     #            ~full forward recompute in backward).
     # Booleans are accepted for backward compatibility (True="full").
@@ -318,6 +331,25 @@ class TinyGPTConfig:
     sliding_window: Optional[int] = None
     # ((kind, Rotary), ...) for the kinds whose table is not plain rope_theta.
     layer_rotary: Optional[Tuple[Tuple[str, Rotary], ...]] = None
+    # A ``kda`` layer's sizes (Kimi Delta Attention, Kimi Linear): heads of
+    # kda_head_dim keys and as many values, a depthwise causal convolution of
+    # kda_conv positions after each of the q, k, v projections, the recurrence
+    # in chunks of kda_chunk positions (``ops/kda.py``). The two low-rank maps
+    # (the decay's and the output gate's) have rank kda_head_dim.
+    kda_heads: int = 0
+    kda_head_dim: int = 0
+    kda_conv: int = 4
+    kda_chunk: int = 128  # ops.kda.DEFAULT_CHUNK: measured there
+    # Latent attention without rotary on its qk_rope_head_dim columns (Kimi
+    # Linear's mla_use_nope): the columns stay, nothing rotates them.
+    mla_nope: bool = False
+    # How the dropless router scores: 'softmax' over the experts, or 'sigmoid'
+    # of each logit (DeepSeek-V3-class: the choice is by score + the leaf
+    # router_bias, a buffer that gets no gradient; the gates are the scores at
+    # the chosen, renormalised under norm_topk_prob; no load-balance term).
+    router_score: str = "softmax"
+    # What multiplies the gates after renormalisation.
+    routed_scaling_factor: float = 1.0
     # Linear/LayerNorm biases (Llama ships none anywhere).
     bias: bool = True
     # Weight-tied LM head (reference train_harness.py:61-62). False adds a
@@ -445,6 +477,20 @@ class TinyGPTConfig:
                     if len(kinds) % p == 0 and kinds == kinds[:p] * (len(kinds) // p))
 
     @property
+    def has_kda(self) -> bool:
+        return scopes.KDA in (self.layer_types or ())
+
+    @property
+    def layer_groups(self) -> Tuple[Tuple[str, Tuple[int, ...]], ...]:
+        """((a stack's name in the parameter tree, its layers' indices in the
+        published order), ...): the layers of equal leaves, by (mixer, MLP)."""
+        groups: Dict[str, list] = {}
+        for i in range(self.n_layer):
+            kda = self.layer_types is not None and self.layer_types[i] == scopes.KDA
+            groups.setdefault(_STACK_NAMES[kda, i < self.first_k_dense], []).append(i)
+        return tuple((name, tuple(layers)) for name, layers in groups.items())
+
+    @property
     def aux_shape(self) -> Tuple[int, ...]:
         """The layer loop's aux carry: the load-balance scalar, or with it the
         held experts' rows and the held assignments that did not fit."""
@@ -504,8 +550,8 @@ class TinyGPTConfig:
         if self.layer_types is not None:
             raise ValueError(
                 "the pipeline schedules slice one homogeneous stack; layer_types "
-                "gives each layer a kind of its own (sliding_window layers beside "
-                "global ones). Run this config with pipe=1"
+                "gives each layer a kind of its own (sliding_window or kda layers "
+                "beside global ones). Run this config with pipe=1"
             )
 
     def __post_init__(self):
@@ -597,20 +643,44 @@ class TinyGPTConfig:
             if self.attention_impl not in ("flash", "reference") or (
                     self.seq_manual_axis is not None):
                 raise ValueError(
-                    "layer_types (sliding_window layers beside global ones) runs "
+                    "layer_types (sliding_window or kda layers beside global ones) runs "
                     "attention_impl 'flash' or 'reference' on whole sequences: ring "
                     "attention, Ulysses and the sequence-parallel pipeline cut the "
                     "sequence, and their bodies take causal or no mask only; got "
                     f"attention_impl={self.attention_impl!r}, "
                     f"seq_manual_axis={self.seq_manual_axis!r}"
                 )
-            if (not self.causal or self.latent_attention or self.first_k_dense
-                    or self.block_diffusion is not None):
+            if not self.causal or self.block_diffusion is not None or (
+                    self.latent_attention and scopes.WINDOW in kinds) or (
+                    self.first_k_dense and scopes.KDA not in kinds):
                 raise ValueError(
-                    "layer_types mixes causal layers of ordinary attention in one "
-                    "stack: causal=True, no kv_lora_rank, no first_k_dense, no "
-                    "block_diffusion"
+                    "layer_types mixes causal layers in one stack: causal=True, no "
+                    "block_diffusion; latent attention (kv_lora_rank) is its 'global' "
+                    "layers' and has no 'window' ones; first_k_dense leading layers "
+                    "go with 'kda' layers (the stacks by mixer and MLP)"
                 )
+            if scopes.KDA in kinds and not (
+                    self.kda_heads > 0 and self.kda_head_dim > 0 and self.kda_conv >= 1
+                    and self.kda_chunk >= 2 and self.norm == "rmsnorm" and not self.bias
+                    and not self.scan_layers and not self.tp_collective_matmul
+                    and not self.dropout):
+                raise ValueError(
+                    "a 'kda' layer needs kda_heads, kda_head_dim, kda_conv >= 1, "
+                    "kda_chunk >= 2, norm='rmsnorm', bias=False, no dropout, no "
+                    "tp_collective_matmul and scan_layers=False: stacks of unequal "
+                    "leaves run unrolled, in the published order, and the scanned "
+                    "loop is refused"
+                )
+        if self.router_score not in ("softmax", "sigmoid") or (
+                self.router_score == "sigmoid" or self.routed_scaling_factor != 1.0
+        ) and not (self.n_experts > 0 and dropless):
+            raise ValueError(
+                "router_score is 'softmax' or 'sigmoid'; 'sigmoid' and "
+                "routed_scaling_factor belong to dropless routing; got "
+                f"{self.router_score!r}, {self.routed_scaling_factor}"
+            )
+        if self.mla_nope and not self.latent_attention:
+            raise ValueError("mla_nope is latent attention's (kv_lora_rank)")
         if (scopes.WINDOW in (kinds or ())) != (self.sliding_window is not None) or (
                 self.sliding_window is not None and self.sliding_window < 1):
             raise ValueError(
@@ -733,6 +803,25 @@ PARAM_AXIS_RULES: Dict[str, Tuple[Optional[str], ...]] = {
     # n_shared_experts): one SwiGLU, gate columns then up columns.
     "blocks/shared_wgu": ("layers", "embed", "gate_up"),
     "blocks/shared_wd": ("layers", "mlp", "embed"),
+    # The sigmoid router's selection bias (present when router_score='sigmoid'):
+    # a buffer, added to the scores for the choice only; no gradient reaches it.
+    "blocks/router_bias": ("layers", "experts"),
+    # A 'kda' layer's mixer (the stacks 'kda_blocks' / 'kda_dense_blocks', which
+    # take these rules as 'blocks' does; present instead of the attention
+    # leaves): q, k, v projections on a 'qkv3' axis, their depthwise causal
+    # convolutions (filter taps on 'conv'), the decay's low-rank map with its
+    # per-head rate and per-channel bias, beta, the output gate's low-rank map,
+    # the head norm's (kda_head_dim,) scale; wo as above.
+    "blocks/kda_wqkv": ("layers", "embed", "qkv3", "heads"),
+    "blocks/kda_conv": ("layers", "qkv3", "conv", "heads"),
+    "blocks/kda_wfa": ("layers", "embed", "kda_rank"),
+    "blocks/kda_wfb": ("layers", "kda_rank", "heads"),
+    "blocks/kda_a_log": ("layers", "kda_heads"),
+    "blocks/kda_dt_bias": ("layers", "heads"),
+    "blocks/kda_wb": ("layers", "embed", "kda_heads"),
+    "blocks/kda_wga": ("layers", "embed", "kda_rank"),
+    "blocks/kda_wgb": ("layers", "kda_rank", "heads"),
+    "blocks/kda_norm": ("layers", "head_dim"),
     "lnf_scale": ("embed",),
     "lnf_bias": ("embed",),
     # Untied LM head (present when tie_embeddings=False): same logical axes
@@ -759,7 +848,8 @@ def init_params(config: TinyGPTConfig, key: jax.Array) -> Params:
     # wider split; they are new surface with no reproduction constraint.
     legacy = Hkv == H and c.tie_embeddings and c.pos_embed == "learned"
     wide = c.latent_attention or c.first_k_dense or c.n_shared_experts
-    k = iter(jax.random.split(key, 8 if legacy else 24 if wide else 12))
+    k = iter(jax.random.split(
+        key, 64 if c.has_kda else 8 if legacy else 24 if wide else 12))
 
     def normal(key, shape):
         return (0.02 * jax.random.normal(key, shape)).astype(c.param_dtype)
@@ -799,60 +889,101 @@ def init_params(config: TinyGPTConfig, key: jax.Array) -> Params:
             blocks["bo"] = zeros((L, D))
         return blocks
 
-    L -= c.first_k_dense  # 'blocks' holds the layers after the leading dense ones
-    blocks = norms_and_attention(L)
-    if c.n_experts > 0:
-        E = c.n_experts
-        blocks["router"] = normal(next(k), (L, D, E))
-        if c.capacity_factor is None:  # dropless SwiGLU experts, no bias
-            held = c.n_experts_held  # the router scores E; this chip's leaves hold these
-            blocks.update(
-                moe_wgu=normal(next(k), (L, held, D, 2 * F)),
-                moe_wd=normal(next(k), (L, held, F, D)),
-            )
-            if c.n_shared_experts:
-                Fs = c.n_shared_experts * F
+    def norms_and_kda(L):
+        """One stack's norm scales and KDA leaves, L layers. The filters start
+        as a depthwise Conv1d's do (uniform within 1 / sqrt(taps)), the decay's
+        rate exp(A_log) uniform on [1, 16] a head, and its bias the inverse
+        softplus of a step log-uniform on [0.001, 0.1] a channel: the
+        family's published initialisation."""
+        Hk, Dk, taps = c.kda_heads, c.kda_head_dim, c.kda_conv
+        uniform = lambda key, shape, lo, hi: jax.random.uniform(
+            key, shape, jnp.float32, minval=lo, maxval=hi)
+        step = jnp.exp(uniform(next(k), (L, Hk * Dk), math.log(1e-3), math.log(0.1)))
+        bound = taps ** -0.5
+        return dict(
+            ln1_scale=ones((L, D)), ln2_scale=ones((L, D)),
+            kda_wqkv=normal(next(k), (L, D, 3, Hk * Dk)),
+            kda_conv=uniform(next(k), (L, 3, taps, Hk * Dk), -bound, bound).astype(c.param_dtype),
+            kda_wfa=normal(next(k), (L, D, Dk)),
+            kda_wfb=normal(next(k), (L, Dk, Hk * Dk)),
+            kda_a_log=jnp.log(uniform(next(k), (L, Hk), 1.0, 16.0)).astype(c.param_dtype),
+            kda_dt_bias=(step + jnp.log(-jnp.expm1(-step))).astype(c.param_dtype),
+            kda_wb=normal(next(k), (L, D, Hk)),
+            kda_wga=normal(next(k), (L, D, Dk)),
+            kda_wgb=normal(next(k), (L, Dk, Hk * Dk)),
+            kda_norm=ones((L, Dk)),
+            wo=normal(next(k), (L, Hk * Dk, D)),
+        )
+
+    def mlp_leaves(L):
+        """One stack's MLP as the config has it (routed where n_experts), L layers."""
+        blocks = {}
+        if c.n_experts > 0:
+            E = c.n_experts
+            blocks["router"] = normal(next(k), (L, D, E))
+            if c.router_score == "sigmoid":
+                blocks["router_bias"] = zeros((L, E))
+            if c.capacity_factor is None:  # dropless SwiGLU experts, no bias
+                held = c.n_experts_held  # the router scores E; this chip's leaves hold these
                 blocks.update(
-                    shared_wgu=normal(next(k), (L, D, 2 * Fs)),
-                    shared_wd=normal(next(k), (L, Fs, D)),
+                    moe_wgu=normal(next(k), (L, held, D, 2 * F)),
+                    moe_wd=normal(next(k), (L, held, F, D)),
                 )
+                if c.n_shared_experts:
+                    Fs = c.n_shared_experts * F
+                    blocks.update(
+                        shared_wgu=normal(next(k), (L, D, 2 * Fs)),
+                        shared_wd=normal(next(k), (L, Fs, D)),
+                    )
+            else:
+                blocks.update(
+                    moe_w1=normal(next(k), (L, E, D, F)),
+                    moe_b1=zeros((L, E, F)),
+                    moe_w2=normal(next(k), (L, E, F, D)),
+                    moe_b2=zeros((L, E, D)),
+                )
+        elif c.mlp_act == "swiglu":
+            blocks["wgu"] = normal(next(k), (L, D, 2, F))
+            blocks["wproj"] = normal(next(k), (L, F, D))
+            if c.bias:
+                blocks["bgu"] = zeros((L, 2, F))
+                blocks["bproj"] = zeros((L, D))
         else:
-            blocks.update(
-                moe_w1=normal(next(k), (L, E, D, F)),
-                moe_b1=zeros((L, E, F)),
-                moe_w2=normal(next(k), (L, E, F, D)),
-                moe_b2=zeros((L, E, D)),
-            )
-    elif c.mlp_act == "swiglu":
-        blocks["wgu"] = normal(next(k), (L, D, 2, F))
-        blocks["wproj"] = normal(next(k), (L, F, D))
-        if c.bias:
-            blocks["bgu"] = zeros((L, 2, F))
-            blocks["bproj"] = zeros((L, D))
-    else:
-        blocks["wfc"] = normal(next(k), (L, D, F))
-        blocks["wproj"] = normal(next(k), (L, F, D))
-        if c.bias:
-            blocks["bfc"] = zeros((L, F))
-            blocks["bproj"] = zeros((L, D))
-    params = {
-        "wte": normal(next(k), (V, D)),
-        "blocks": blocks,
-        "lnf_scale": ones((D,)),
-    }
+            blocks["wfc"] = normal(next(k), (L, D, F))
+            blocks["wproj"] = normal(next(k), (L, F, D))
+            if c.bias:
+                blocks["bfc"] = zeros((L, F))
+                blocks["bproj"] = zeros((L, D))
+        return blocks
+
+    def stack(name, L):
+        """The stack ``name`` (``_STACK_NAMES``) of L layers: its mixer's leaves,
+        then its MLP's; the draws in that order."""
+        leaves = (norms_and_kda if name.startswith("kda_") else norms_and_attention)(L)
+        if name.endswith("dense_blocks"):
+            Fd = c.dense_mlp_hidden
+            leaves.update(wgu=normal(next(k), (L, D, 2, Fd)), wproj=normal(next(k), (L, Fd, D)))
+        else:
+            leaves.update(mlp_leaves(L))
+        return leaves
+
+    # The draws' order is the seeds' contract with every published artifact:
+    # 'blocks' (the layers after the leading dense ones), the embedding and the
+    # head, 'dense_blocks', then the KDA stacks.
+    sizes = {name: len(layers) for name, layers in c.layer_groups}
+    params = {}
+    if "blocks" in sizes:
+        params["blocks"] = stack("blocks", sizes["blocks"])
+    params.update(wte=normal(next(k), (V, D)), lnf_scale=ones((D,)))
     if c.pos_embed == "learned":
         params["wpe"] = normal(next(k), (T, D))
     if c.norm == "layernorm":
         params["lnf_bias"] = zeros((D,))
     if not c.tie_embeddings:
         params["lm_head"] = normal(next(k), (V, D))
-    if c.first_k_dense:
-        Ld, Fd = c.first_k_dense, c.dense_mlp_hidden
-        params["dense_blocks"] = dict(
-            norms_and_attention(Ld),
-            wgu=normal(next(k), (Ld, D, 2, Fd)),
-            wproj=normal(next(k), (Ld, Fd, D)),
-        )
+    for name in ("dense_blocks", "kda_blocks", "kda_dense_blocks"):
+        if name in sizes:
+            params[name] = stack(name, sizes[name])
     return params
 
 
@@ -1137,14 +1268,25 @@ def _block(
             "over the manual 'seq' axis; drop --tp-collective-matmul "
             "for pipeline arms)"
         )
+    x = _mixer_half(c, x, layer, keys[0], deterministic, kind, qk_tables)
+    return _mlp_half(c, x, layer, keys[1], deterministic)
+
+
+def _mixer_half(c, x, layer, key, deterministic, kind, qk_tables):
+    """``_block``'s first half under its scope: the kind chooses the mixer."""
     with jax.named_scope(scopes.ATTENTION):
         if kind is None:
-            x = _attention_sublayer(c, x, layer, keys[0], deterministic, None, qk_tables)
-        else:
-            with jax.named_scope(kind):
-                x = _attention_sublayer(c, x, layer, keys[0], deterministic, kind, qk_tables)
+            return _attention_sublayer(c, x, layer, key, deterministic, None, qk_tables)
+        with jax.named_scope(kind):
+            if kind == scopes.KDA:
+                return _kda_sublayer(c, x, layer)
+            return _attention_sublayer(c, x, layer, key, deterministic, kind, qk_tables)
+
+
+def _mlp_half(c, x, layer, key, deterministic):
+    """``_block``'s second half under its scope -> (x, aux)."""
     with jax.named_scope(scopes.MLP):
-        return _mlp_sublayer(c, x, layer, keys[1], deterministic)
+        return _mlp_sublayer(c, x, layer, key, deterministic)
 
 
 def _rotary_positions(c: TinyGPTConfig, S: int) -> jax.Array:
@@ -1187,7 +1329,7 @@ def qk_prologue_tables(c: TinyGPTConfig, S: int) -> Dict:
     if not _takes_qk_prologue(c, S) or rotary_ops.kernel_mode() is None:
         return {}
     pos = _rotary_positions(c, S)
-    kinds = sorted(set(c.layer_types)) if c.layer_types else (None,)
+    kinds = sorted(set(c.layer_types) - {scopes.KDA}) if c.layer_types else (None,)
     return {
         kind: rotary_ops.table(pos, c.head_dim, c.rotary(kind).theta, c.rotary(kind).scaling)
         for kind in kinds
@@ -1350,10 +1492,13 @@ def _latent_attention(
         latent = _rms_norm(kv_a[..., :R], layer["kv_norm"], c.norm_eps)
         kv_b = proj("bsr,re->bse", latent, layer["wkv_b"].astype(cd)).astype(cd)
         kv_b = kv_b.reshape(B, S, H, Dn + Dv)
-        pos = jnp.arange(S, dtype=jnp.int32)
-        q_pe = _rope(q[..., Dn:], pos, c.rope_theta, c.rope_scaling)
-        k_pe = _rope(kv_a[:, :, None, R:], pos, c.rope_theta, c.rope_scaling)
-        q = jnp.concatenate((q[..., :Dn], q_pe), axis=-1)
+        if c.mla_nope:  # the 64 shared columns as they are: nothing rotates q or k
+            k_pe = kv_a[:, :, None, R:]
+        else:
+            pos = jnp.arange(S, dtype=jnp.int32)
+            q_pe = _rope(q[..., Dn:], pos, c.rope_theta, c.rope_scaling)
+            k_pe = _rope(kv_a[:, :, None, R:], pos, c.rope_theta, c.rope_scaling)
+            q = jnp.concatenate((q[..., :Dn], q_pe), axis=-1)
         k = jnp.concatenate(
             (kv_b[..., :Dn], jnp.broadcast_to(k_pe, (B, S, H, Dr))), axis=-1
         )
@@ -1364,6 +1509,100 @@ def _latent_attention(
         return proj(
             "bse,ed->bsd", attn.reshape(B, S, H * Dv), layer["wo"].astype(cd)
         ).astype(cd)
+
+
+def _head_columns(heads: int, d: int) -> jax.Array:
+    """(H x d, H) float32 of 0s and 1s: column c belongs to head c // d."""
+    return (jnp.arange(heads * d)[:, None] // d == jnp.arange(heads)[None, :]).astype(jnp.float32)
+
+
+def _head_sums(x: jax.Array, heads: int) -> jax.Array:
+    """(B, S, H x d) float32 -> (B, S, H): each head's sum over its d columns,
+    as a product with 0s and 1s at full precision. A (.., H, d) view of the
+    operand would be another layout on a TPU, and the reshape a copy of it."""
+    return jnp.einsum("bsc,ch->bsh", x, _head_columns(heads, x.shape[-1] // heads),
+                      precision=lax.Precision.HIGHEST)
+
+
+def _over_heads(t: jax.Array, d: int) -> jax.Array:
+    """(B, S, H) float32 -> (B, S, H x d): a head's value over its d columns
+    (``_head_sums``'s transpose, the same way)."""
+    return jnp.einsum("bsh,ch->bsc", t, _head_columns(t.shape[-1], d),
+                      precision=lax.Precision.HIGHEST)
+
+
+def _kda_sublayer(c: TinyGPTConfig, x: jax.Array, layer: Params) -> jax.Array:
+    """Norm -> Kimi Delta Attention -> residual: a ``kda`` layer's mixer, in
+    three scopes. ``kda_prep``: q = l2norm(silu(conv(h Wq))), k likewise, v =
+    silu(conv(h Wv)), the log-decay a key channel g = -exp(A_log) softplus(Wfb
+    (Wfa h) + dt_bias) and beta = sigmoid(h Wb), g and beta float32.
+    ``kda_core``: the recurrence (``ops/kda.py``: the Mosaic kernels on a TPU
+    at whole 128-lane head widths, its ``jnp`` path elsewhere). ``kda_out``:
+    Wo [RMSNorm over each head's values (one (kda_head_dim,) scale) x
+    sigmoid(Wgb (Wga h))]."""
+    from ..ops import kda as kda_ops
+
+    B, S, _ = x.shape
+    cd = c.compute_dtype
+    H, Dk = c.kda_heads, c.kda_head_dim
+    proj = functools.partial(jnp.einsum, preferred_element_type=jnp.float32)
+    h = _norm(c, x, layer["ln1_scale"], layer.get("ln1_bias"))
+    with jax.named_scope(scopes.KDA_PREP):
+        # one (D, 3 H Dk) product: with q, k, v on an axis of their own XLA lays the
+        # result out (3, S, H Dk) and the flat view the convolution takes is a copy
+        wqkv = layer["kda_wqkv"].reshape(x.shape[-1], 3 * H * Dk).astype(cd)
+        qkv = proj("bsd,de->bse", h, wqkv).astype(cd)
+        taps = jnp.moveaxis(layer["kda_conv"], 0, 1).reshape(c.kda_conv, 3 * H * Dk)
+        fits = Dk % 128 == 0  # the kernels' widths; else XLA's convolution and the jnp scan
+        mode = kda_ops.kernel_mode() if fits else None
+        qkv = jax.nn.silu(kda_ops.causal_conv(qkv, taps, interpret=mode))
+        q, k, v = (qkv[..., i * H * Dk:(i + 1) * H * Dk] for i in range(3))
+
+        def l2norm(t):  # over a head's channels, float32 (the published kernel's eps)
+            tf = t.astype(jnp.float32)
+            return (tf * _over_heads(lax.rsqrt(_head_sums(tf * tf, H) + 1e-6), Dk)).astype(cd)
+
+        q, k = l2norm(q), l2norm(k)
+        low = proj("bsd,dr->bsr", h, layer["kda_wfa"].astype(cd)).astype(cd)
+        rate = proj("bsr,re->bse", low, layer["kda_wfb"].astype(cd))  # float32
+        # every per-channel operand stays (B, S, H x Dk), a head's columns together: on
+        # a TPU a (.., H, Dk) view of it is another layout, and a reshape a copy
+        g = -jnp.repeat(jnp.exp(layer["kda_a_log"].astype(jnp.float32)), Dk) * jax.nn.softplus(
+            rate + layer["kda_dt_bias"].astype(jnp.float32))
+        beta = jax.nn.sigmoid(proj("bsd,dh->bsh", h, layer["kda_wb"].astype(cd)))
+    with jax.named_scope(scopes.KDA_CORE):
+        o = kda_ops.kda_flat(q, k, v, g, beta, H, c.kda_chunk, interpret=mode)
+    with jax.named_scope(scopes.KDA_OUT):
+        low = proj("bsd,dr->bsr", h, layer["kda_wga"].astype(cd)).astype(cd)
+        gate = proj("bsr,re->bse", low, layer["kda_wgb"].astype(cd)).astype(cd)
+        of = o.astype(jnp.float32)  # RMSNorm over each head's values, one (Dk,) scale
+        of = of * _over_heads(lax.rsqrt(_head_sums(of * of, H) / Dk + c.norm_eps), Dk)
+        of = of * jnp.tile(layer["kda_norm"].astype(jnp.float32), H)
+        o = (of * jax.nn.sigmoid(gate.astype(jnp.float32))).astype(cd)
+        return x + proj("bse,ed->bsd", o, layer["wo"].astype(cd)).astype(cd)
+
+
+def kda_stats(config: TinyGPTConfig, seq_len: int) -> Dict[str, Any]:
+    """Counters of the ``kda`` layers over sequences of ``seq_len`` tokens,
+    from the config and the backend at trace time: ``layers`` of the kind,
+    ``chunk`` and ``chunks`` a sequence, ``kernel_calls`` a step by name (one
+    forward and one backward a layer where the kernels run: none on the ``jnp``
+    path; remat's second forward is the policy's, not counted), and
+    ``saved_state_bytes`` a layer a sequence: the states entering the chunks,
+    which the forward keeps for the backward beside its operands."""
+    from ..ops import kda as kda_ops
+
+    c = config
+    layers = (c.layer_types or ()).count(scopes.KDA)
+    chunks = seq_len // c.kda_chunk
+    kernels = layers if (c.kda_head_dim % 128 == 0
+                         and kda_ops.kernel_mode() is not None) else 0
+    return {
+        "layers": layers, "chunk": c.kda_chunk, "chunks": chunks,
+        "kernel_calls": {"kda_fwd": kernels, "kda_bwd": kernels},
+        "saved_state_bytes": (c.kda_heads * chunks * c.kda_head_dim ** 2
+                              * jnp.dtype(c.compute_dtype).itemsize),
+    }
 
 
 def _pin_mlp_hidden(c: TinyGPTConfig, h: jax.Array) -> jax.Array:
@@ -1550,7 +1789,7 @@ def attn_mask_stats(config: TinyGPTConfig, seq_len: int) -> Dict[str, Dict[str, 
 
     kinds = config.layer_types or (scopes.GLOBAL,) * config.n_layer
     stats = {}
-    for kind in sorted(set(kinds)):
+    for kind in sorted(set(kinds) - {scopes.KDA}):  # a kda layer has no mask: kda_stats
         rule = config.mask_rule(seq_len, kind if config.layer_types else None)
         bq, bk, bk_bwd, _ = fa.pick_tiles(
             seq_len, config.qk_dim, config.compute_dtype, causal=rule)
@@ -1674,27 +1913,32 @@ def apply_blocks(
     return x, aux
 
 
-def _under_remat(pol: str, block):
-    """``block`` under the layer loop's remat policy ``pol`` (normalized)."""
+def _under_remat(pol: str, block, kda: bool = False):
+    """``block`` under the layer loop's remat policy ``pol`` (normalized).
+    ``dots`` saves matmul (dot_general without dot-batch dims, i.e. x @ W)
+    outputs and the mixer's forward kernel's named results (no dot_general: the
+    flash kernel's output and row sums, ``FLASH_RESIDUAL_NAMES``, or for a
+    ``kda`` layer the recurrence's output and the states entering its chunks,
+    ``ops.kda.KDA_RESIDUAL_NAMES``) and recomputes only LN/GELU/softmax/dropout
+    in backward: most of full remat's recompute tax gone while the elementwise
+    intermediates still drop from liveness. ``full_keep_kernels`` saves those
+    named results alone, ``full`` nothing."""
+    if pol == "none":
+        return block
     if pol == "full":
-        block = jax.checkpoint(block)
-    elif pol == "dots":
-        # Save matmul (dot_general without dot-batch dims, i.e. x @ W)
-        # outputs and the flash kernel's two results (no dot_general: see
-        # FLASH_RESIDUAL_NAMES); recompute only LN/GELU/softmax/dropout in
-        # backward — removes most of full remat's recompute tax while still
-        # dropping the elementwise intermediates from liveness.
-        from ..ops.flash_attention import FLASH_RESIDUAL_NAMES
-
-        policies = jax.checkpoint_policies
-        block = jax.checkpoint(
-            block,
-            policy=policies.save_from_both_policies(
-                policies.dots_with_no_batch_dims_saveable,
-                policies.save_only_these_names(*FLASH_RESIDUAL_NAMES),
-            ),
-        )
-    return block
+        return jax.checkpoint(block)
+    if kda:
+        from ..ops.kda import KDA_RESIDUAL_NAMES as names
+    else:
+        from ..ops.flash_attention import FLASH_RESIDUAL_NAMES as names
+    policies = jax.checkpoint_policies
+    kernel_results = policies.save_only_these_names(*names)
+    if pol == "full_keep_kernels":
+        return jax.checkpoint(block, policy=kernel_results)
+    return jax.checkpoint(  # dots
+        block,
+        policy=policies.save_from_both_policies(
+            policies.dots_with_no_batch_dims_saveable, kernel_results))
 
 
 def embed_param_names(config: TinyGPTConfig) -> Tuple[str, ...]:
@@ -1765,6 +2009,8 @@ def apply_layers(
     per-layer placement hooks) -> (x, aux_sum)."""
     c = config
     tables = qk_prologue_tables(c, x.shape[1])  # one set for both stacks
+    if c.has_kda:
+        return _apply_stacks(c, params, x, base_key, deterministic, tables)
     if not c.first_k_dense:
         return apply_blocks(c, params["blocks"], x, base_key, deterministic, qk_tables=tables)
     x, aux_dense = apply_blocks(
@@ -1774,6 +2020,47 @@ def apply_layers(
         qk_tables=tables,
     )
     return x, aux_dense + aux
+
+
+def layer_weights(config: TinyGPTConfig, params: Params, i: int) -> Params:
+    """Layer ``i``'s slice of its stack (``TinyGPTConfig.layer_groups``)."""
+    for name, layers in config.layer_groups:
+        if i in layers:
+            at = layers.index(i)
+            return jax.tree_util.tree_map(lambda t: t[at], params[name])
+    raise IndexError(f"no layer {i} among {config.n_layer}")
+
+
+def apply_layer(config: TinyGPTConfig, layer: Params, x: jax.Array, kind: Optional[str],
+                key: Optional[jax.Array] = None, deterministic: bool = True,
+                qk_tables: Optional[Dict] = None) -> Tuple[jax.Array, jax.Array]:
+    """One layer of ``kind`` as the unrolled loop over unequal stacks runs it:
+    ``_block``'s two halves, **each under the config's remat policy on its
+    own** (the backward then holds one sublayer's recomputed activations at a
+    time, not a KDA mixer's beside a 9216-wide MLP's: 0.9 GB at the Kimi
+    cell's sizes, for one more (B, S, D) kept a layer), with the per-layer
+    placement hooks, on the layer's own slice of its stack -> (x, aux). Also
+    what a check calls to run one layer of the timed config alone."""
+    c, pol = config, normalize_remat(config.remat)
+    keys = jax.random.split(key, 2) if key is not None else (None, None)
+    layer = _constrain_layer(c, layer)
+    mixer = _under_remat(pol, lambda x, layer, key, tables: _mixer_half(
+        c, x, layer, key, deterministic, kind, tables), kda=kind == scopes.KDA)
+    mlp = _under_remat(pol, lambda x, layer, key: _mlp_half(c, x, layer, key, deterministic))
+    return mlp(mixer(x, layer, keys[0], qk_tables), layer, keys[1])
+
+
+def _apply_stacks(c, params, x, base_key, deterministic, qk_tables):
+    """The whole depth of a config whose stacks have unequal leaves (``kda``
+    layers beside others): unrolled, the layers in the published order, each
+    from its own stack (``apply_layer``) -> (x, aux_sum)."""
+    live = base_key is not None and not deterministic
+    aux = jnp.zeros(c.aux_shape, jnp.float32)
+    for i, kind in enumerate(c.layer_types):
+        key = jax.random.fold_in(base_key, i) if live else None
+        x, a = apply_layer(c, layer_weights(c, params, i), x, kind, key, deterministic, qk_tables)
+        aux = aux + a
+    return x, aux
 
 
 def _forward(c, params, idx, targets, dropout_key, deterministic):
@@ -1876,14 +2163,16 @@ def _walk_routers(config: TinyGPTConfig, params: Params, idx: jax.Array, read):
     stream)."""
     c = dataclasses.replace(config, dropout=0.0)
     x = embed(c, params, idx, None, True)
-    if c.first_k_dense:
-        x, _ = apply_blocks(c, params["dense_blocks"], x, None, True)
     found = []
-    for i in range(c.n_layer - c.first_k_dense):
-        layer = jax.tree_util.tree_map(lambda t: t[i], params["blocks"])
+    for i in range(c.n_layer):
+        layer = layer_weights(c, params, i)
         kind = None if c.layer_types is None else c.layer_types[i]
-        x = _attention_sublayer(c, x, layer, None, True, kind)
-        found.append(read(c, layer, _norm(c, x, layer["ln2_scale"], layer.get("ln2_bias"))))
+        if kind == scopes.KDA:
+            x = _kda_sublayer(c, x, layer)
+        else:
+            x = _attention_sublayer(c, x, layer, None, True, kind)
+        if "router" in layer:
+            found.append(read(c, layer, _norm(c, x, layer["ln2_scale"], layer.get("ln2_bias"))))
         x, _ = _mlp_sublayer(c, x, layer, None, True)
     return found
 

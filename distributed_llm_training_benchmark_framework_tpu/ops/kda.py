@@ -1,0 +1,590 @@
+"""Kimi Delta Attention's recurrence (the gated delta rule with a decay a key
+channel; Kimi Linear, arXiv:2510.26692), chunkwise, forward and backward.
+
+A head keeps a (dk, dv) state S, zero before the first position:
+
+    S_t = (I - beta_t k_t k_t^T) diag(exp(g_t)) S_{t-1} + beta_t k_t v_t^T
+    o_t = scale * S_t^T q_t
+
+with ``g_t <= 0`` a log-decay for each of the dk key channels and ``beta_t``
+in (0, 1). ``kda`` computes it a chunk of C positions at a time. Inside a
+chunk, with G_r the sum of g over the chunk's positions up to r and
+E_ri = exp(G_r - G_i) (a vector over the key channels, r >= i):
+
+    A_ri = beta_r sum_c k_r k_i E_ri   (i < r)    T = (I + A)^-1
+    B_ri = sum_c q_r k_i E_ri          (i <= r)
+    W = T (beta k exp(G)),  U = T (beta v) - W S_0
+    O = scale ((q exp(G)) S_0 + B U)
+    S_C = exp(G_C) S_0 + (k exp(G_C - G))^T U
+
+**No exponential of a positive sum of decays is formed.** E_ri does not factor
+into a row's part and a column's with both bounded unless a reference point
+lies between the two positions, so the lower triangle is cut into log2(C)
+levels: at the level of half-size s, every block of 2s positions gives its
+lower-left quadrant (rows in its upper half, columns in its lower), with the
+block's middle as the reference: a row's factor is exp(sum of g from the
+middle to the row), a column's exp(sum of g from after the column to the
+middle), both sums of non-positive terms taken directly (a 0/1 matrix times
+g, never a difference of two running sums), and the quadrants of all blocks of
+a level are one masked matrix product. The diagonal has E = 1. The same
+levels invert I + A exactly as block forward substitution does: with T the
+inverse of the block diagonal part at block size s, T - T A_level T is the
+inverse at 2s. There is no clamp on g.
+
+Matrix products take their operands in the inputs' dtype (the compute type)
+and accumulate in float32; the state, the running sums of g and everything
+elementwise are float32. ``beta`` enters through ``beta k`` and ``beta v``,
+which the chunk's body makes from a (C, 1) column (made outside, they are two
+more operands and two more gradients a head's width wide, and two float32
+copies of beta over every channel that XLA keeps as arrays).
+
+Two implementations under one ``custom_vjp``: a ``jnp`` path (any backend: a
+``lax.scan`` over the chunks) and two Pallas kernels, ``kda_fwd`` and
+``kda_bwd``: grid (batch, heads, chunks), the chunk axis sequential, a head's
+state resident in VMEM across it. The forward writes ``o`` and, for the
+backward, the state entering each chunk (in the compute type: the backward
+reads it as a matrix product's operand, which is that type anyway). The
+backward walks the chunks in reverse with dS resident, and differentiates the
+chunk's own body (``jax.vjp`` of ``_chunk``, traced into the kernel): what it
+recomputes of the forward is the chunk's intra-chunk part. Operands are (B, S,
+H x d), a head's columns together (``kda_flat``; ``kda`` takes a heads axis
+and reshapes): on a TPU the last two axes of an array are tiled, so a (.., H,
+d) view of such an operand is another layout and every reshape a copy.
+
+Beside it the depthwise causal convolutions that stand in front of the
+recurrence in a KDA layer (``causal_conv``): two more Mosaic calls,
+``kda_conv_fwd`` / ``kda_conv_bwd``, and XLA's grouped convolution as their
+``jnp`` path.
+"""
+
+from __future__ import annotations
+
+import functools
+import math
+from typing import NamedTuple, Optional
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+from jax import lax
+from jax.ad_checkpoint import checkpoint_name
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+#: What the forward keeps for the backward beside its operands, by the names a
+#: remat policy may save (models/tinygpt.py's ``dots`` does): the output and
+#: the states entering the chunks.
+KDA_RESIDUAL_NAMES = ("kda_out", "kda_states")
+
+#: Measured on a v5e at heads of 128 keys and values, 16,384 positions
+#: (scripts/microbench_kda.py, forward + backward a layer: 64.7 ms at 64, 50.7
+#: at 128): fewer sequential steps, and half the states kept.
+DEFAULT_CHUNK = 128
+#: Heads a grid step of the kernels walks (scripts/microbench_kda.py's sweep).
+HEADS_PER_STEP = 4
+
+
+def kernel_mode() -> Optional[bool]:
+    """``kda``'s ``interpret`` where the caller has no wish of its own: False,
+    the Mosaic kernels, on a TPU; None, the ``jnp`` path, elsewhere."""
+    return False if jax.default_backend() == "tpu" else None
+
+
+class _Levels(NamedTuple):
+    """The 0/1 matrices of a chunk of C positions, float32. ``sums`` ((2 + L)
+    C + 8, C): times g, C rows at a time, they give the inclusive running sum,
+    the sum over the later positions, and each level's row sums (from the
+    block's middle to an upper row; from after a lower row to the middle); the
+    last 8 rows (a whole sublane tile) are 1s: the sum over the chunk.
+    ``quadrant`` (L, C, C): the level's lower-left quadrants. ``upper`` /
+    ``lower`` (L, C, 1): the rows in their block's upper / lower half."""
+
+    sums: np.ndarray
+    quadrant: np.ndarray
+    upper: np.ndarray
+    lower: np.ndarray
+
+
+@functools.lru_cache(maxsize=None)
+def _levels(C: int) -> _Levels:
+    L = int(math.log2(C))
+    if C < 2 or 2 ** L != C:
+        raise ValueError(f"kda: the chunk is a power of two of at least 2 positions; got {C}")
+    i, t = np.arange(C)[:, None], np.arange(C)[None, :]
+    sums = [t <= i, t > i]
+    quadrant, upper, lower = [], [], []
+    for level in range(L):
+        s = 2 ** level
+        middle = (i // (2 * s)) * 2 * s + s  # the first row of the block's upper half
+        up = (i % (2 * s)) >= s
+        sums.append(np.where(up, (t >= middle) & (t <= i), (t > i) & (t < middle)))
+        quadrant.append((i // (2 * s) == t // (2 * s)) & up & ~up.T)
+        upper.append(up)
+        lower.append(~up)
+    f32 = lambda rows: np.stack(rows).astype(np.float32)
+    sums.append(np.ones((8, C)))
+    return _Levels(np.concatenate(sums).astype(np.float32), f32(quadrant), f32(upper), f32(lower))
+
+
+def _mm(a, b, dims, dtype, precision=None):
+    """``a`` x ``b`` contracting ``dims`` = (a's, b's), operands in ``dtype``,
+    float32 out."""
+    return lax.dot_general(
+        a.astype(dtype), b.astype(dtype), (((dims[0],), (dims[1],)), ((), ())),
+        precision=precision, preferred_element_type=jnp.float32)
+
+
+def _exact_mm(m, x, dims):
+    """``m`` x ``x`` contracting ``dims``, ``m`` of 0s and 1s and ``x`` float32,
+    as exact as a float32 sum: x is three bfloat16 pieces (8 + 8 + 8 bits of
+    its 24), each multiplied in one pass of the MXU and accumulated in
+    float32. (``Precision.HIGHEST`` would split the 0s and 1s too: six passes.)"""
+    if x.dtype != jnp.float32:
+        raise TypeError(f"_exact_mm splits float32; got {x.dtype}")
+    out = None
+    for _ in range(3):
+        piece = x.astype(jnp.bfloat16)
+        part = _mm(m, piece, dims, jnp.bfloat16)
+        out = part if out is None else out + part
+        x = x - piece.astype(jnp.float32)
+    return out
+
+
+@jax.custom_vjp
+def _segment_sums(sums, g):
+    """``_Levels.sums`` x g (C, dk) float32 -> the list of 2 + L (C, dk) sums of
+    g, each row's over its own segment of the chunk's positions, taken
+    directly, and last the (1, dk) sum over the chunk. Its transpose is the
+    same product the other way round (written out: the transposes jax would
+    make of the split and of the slices are pads, which a kernel cannot
+    hold)."""
+    C = g.shape[0]
+    out = _exact_mm(sums, g, (1, 0))
+    blocks = sums.shape[0] // C
+    return [out[i * C:(i + 1) * C] for i in range(blocks)] + [out[blocks * C:blocks * C + 1]]
+
+
+def _segment_sums_fwd(sums, g):
+    return _segment_sums(sums, g), sums
+
+
+def _segment_sums_bwd(sums, cotangents):
+    *blocks, total = cotangents  # the 8 rows of 1s share the total's cotangent
+    total = jnp.broadcast_to(total * 0.125, (8, total.shape[1]))
+    return None, _exact_mm(sums, jnp.concatenate(blocks + [total], 0), (0, 0))
+
+
+_segment_sums.defvjp(_segment_sums_fwd, _segment_sums_bwd)
+
+
+def _chunk(levels, scale, q, k, v, g, beta, S0):
+    """One head's chunk: q, k (C, dk), v (C, dv), g (C, dk) float32, beta (1,
+    C) float32 (a row: lane-dense where it is stored; its column is taken
+    here, a masked sum over the lanes), S0 (dv, dk) float32, the state **transposed** (its decay is
+    then a row over the lanes) -> (o (C, dv) in q's dtype, S_C^T float32): the
+    module docstring's equations."""
+    cd = q.dtype
+    C = q.shape[0]
+    sums, quadrant, upper, lower = levels
+    eye = (lax.broadcasted_iota(jnp.int32, (C, C), 0)
+           == lax.broadcasted_iota(jnp.int32, (C, C), 1)).astype(jnp.float32)
+    beta = jnp.sum(eye * beta, axis=1, keepdims=True)  # (C, 1)
+    qf, kf = q.astype(jnp.float32), k.astype(jnp.float32)
+    kbf, vb = kf * beta, v.astype(jnp.float32) * beta
+    # exp(G_r), exp(G_C - G_r), then a level's factors: all of non-positive sums
+    decay, later, *factors, decay_all = (jnp.exp(x) for x in _segment_sums(sums, g))
+    B = _mm(q, k, (1, 1), cd) * eye
+    T = eye
+    for level, e in enumerate(factors):
+        rows, columns = e * upper[level], kf * (e * lower[level])
+        B_level = _mm(qf * rows, columns, (1, 1), cd) * quadrant[level]
+        A_level = _mm(kbf * rows, columns, (1, 1), cd) * quadrant[level]
+        B = B + B_level
+        T = T - (A_level if level == 0 else _mm(_mm(T, A_level, (1, 0), cd), T, (1, 0), cd))
+    W = _mm(T, kbf * decay, (1, 0), cd)
+    U = _mm(T, vb, (1, 0), cd) - _mm(W, S0, (1, 1), cd)
+    o = scale * (_mm(qf * decay, S0, (1, 1), cd) + _mm(B, U, (1, 0), cd))
+    S1 = decay_all * S0 + _mm(U, kf * later, (0, 0), cd)
+    return o.astype(cd), S1
+
+
+# ---------------------------------------------------------------------------
+# The jnp path: a scan over the chunks, every (batch, head) at once.
+# ---------------------------------------------------------------------------
+
+def _by_chunk(x, C, H):  # (B, S, H * d) -> (N, B, H, C, d)
+    B, S, _ = x.shape
+    return x.reshape(B, S // C, C, H, -1).transpose(1, 0, 3, 2, 4)
+
+
+def _from_chunks(x):  # (N, B, H, C, d) -> (B, S, H * d)
+    N, B, H, C, d = x.shape
+    return x.transpose(1, 0, 3, 2, 4).reshape(B, N * C, H * d)
+
+
+def _beta_by_chunk(beta, C):  # (B, S, H) -> (N, B, H, 1, C)
+    B, S, H = beta.shape
+    return beta.astype(jnp.float32).reshape(B, S // C, C, H).transpose(1, 0, 3, 2)[..., None, :]
+
+
+def _chunk_of_every_head(opts):
+    """``_chunk`` over (batch, heads), the levels' matrices as arrays."""
+    C, scale, *_ = opts
+    levels = jax.tree.map(jnp.asarray, _levels(C))
+    return jax.vmap(jax.vmap(functools.partial(_chunk, levels, scale)))
+
+
+def _jnp_forward(opts, q, k, v, g, beta):
+    C, H, body = opts[0], opts[-1], _chunk_of_every_head(opts)
+    B, dk, dv = q.shape[0], q.shape[-1] // H, v.shape[-1] // H
+
+    def step(S, xs):
+        o, S1 = body(*xs, S)
+        return S1, (o, S.astype(q.dtype))
+
+    S0 = jnp.zeros((B, H, dv, dk), jnp.float32)
+    xs = tuple(_by_chunk(x, C, H) for x in (q, k, v, g)) + (_beta_by_chunk(beta, C),)
+    _, (o, states) = lax.scan(step, S0, xs)
+    return _from_chunks(o), states.transpose(1, 2, 0, 3, 4)  # states: (B, H, N, dv, dk)
+
+
+def _jnp_backward(opts, q, k, v, g, beta, states, do):
+    C, H, body = opts[0], opts[-1], _chunk_of_every_head(opts)
+
+    def step(dS, xs):
+        *operands, S0, d_o = xs
+        _, pull_back = jax.vjp(body, *operands, S0.astype(jnp.float32))
+        *grads, dS0 = pull_back((d_o, dS))
+        return dS0, tuple(grads)
+
+    B, dk, dv = q.shape[0], q.shape[-1] // H, v.shape[-1] // H
+    xs = tuple(_by_chunk(x, C, H) for x in (q, k, v, g)) + (
+        _beta_by_chunk(beta, C), states.transpose(2, 0, 1, 3, 4), _by_chunk(do, C, H))
+    _, grads = lax.scan(step, jnp.zeros((B, H, dv, dk), jnp.float32), xs, reverse=True)
+    *wide, dbeta = grads  # dbeta: (N, B, H, 1, C)
+    dbeta = dbeta[..., 0, :].transpose(1, 0, 3, 2).reshape(beta.shape)
+    return tuple(_from_chunks(x) for x in wide) + (dbeta,)
+
+
+# ---------------------------------------------------------------------------
+# The Pallas kernels. A grid step is one chunk of ``heads`` heads in a row: a
+# head's chain of small products leaves the MXU waiting, and the next head's
+# fills it.
+# ---------------------------------------------------------------------------
+
+def _level_specs(levels):
+    return [pl.BlockSpec(a.shape, lambda b, h, n, nd=a.ndim: (0,) * nd) for a in levels]
+
+
+def _head(ref, j, d):
+    """Head j's (C, d) columns of a (C, heads x d) block."""
+    return ref[:, j * d:(j + 1) * d]
+
+
+def _fwd_kernel(scale, heads, *refs):
+    level_refs, (q, k, v, g, beta, o, states, S) = refs[:4], refs[4:]
+
+    @pl.when(pl.program_id(2) == 0)
+    def _():
+        S[...] = jnp.zeros_like(S)
+
+    levels = tuple(r[...] for r in level_refs)
+    dv, dk = S.shape[1:]
+    for j in range(heads):
+        S0 = S[j]
+        states[j] = S0.astype(states.dtype)
+        out, S1 = _chunk(levels, scale, _head(q, j, dk), _head(k, j, dk), _head(v, j, dv),
+                         _head(g, j, dk), beta[j:j + 1, :], S0)
+        o[:, j * dv:(j + 1) * dv] = out
+        S[j] = S1
+
+
+def _bwd_kernel(scale, heads, *refs):
+    level_refs, (q, k, v, g, beta, states, do, dq, dk_, dv_, dg, dbeta, dS) = refs[:4], refs[4:]
+
+    @pl.when(pl.program_id(2) == 0)
+    def _():
+        dS[...] = jnp.zeros_like(dS)
+
+    body = functools.partial(_chunk, tuple(r[...] for r in level_refs), scale)
+    dv, dk = dS.shape[1:]
+    for j in range(heads):
+        _, pull_back = jax.vjp(
+            body, _head(q, j, dk), _head(k, j, dk), _head(v, j, dv), _head(g, j, dk),
+            beta[j:j + 1, :], states[j].astype(jnp.float32))
+        *grads, dbeta[j:j + 1, :], dS[j] = pull_back((_head(do, j, dv), dS[j]))
+        for ref, grad, d in zip((dq, dk_, dv_, dg), grads, (dk, dk, dv, dk)):
+            ref[:, j * d:(j + 1) * d] = grad
+
+
+def _pallas_call(kernel, name, interpret, grid, in_specs, out_specs, out_shape, scratch):
+    return pl.pallas_call(
+        kernel, grid=grid, in_specs=in_specs, out_specs=out_specs, out_shape=out_shape,
+        scratch_shapes=scratch, interpret=interpret, name=name,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
+    )
+
+
+def _geometry(opts, q, v):
+    C, _, _, heads, H = opts
+    B, S, _ = q.shape
+    return B, S, H, q.shape[-1] // H, v.shape[-1] // H, S // C, math.gcd(H, heads)
+
+
+def _beta_by_step(beta, G, C):
+    """(B, S, H) -> (B, H / G, N, G, C) float32: a grid step's block is the
+    array's whole last two axes, its G heads' rows over the chunk's positions
+    (G of H columns would be neither a block's whole last axis nor whole
+    128-lane tiles, and G lanes of 128 a layout XLA takes milliseconds to
+    make). A small array; the kernels make beta k and beta v themselves."""
+    B, S, H = beta.shape
+    return beta.astype(jnp.float32).reshape(B, S // C, C, H // G, G).transpose(0, 3, 1, 4, 2)
+
+
+def _beta_from_steps(x, shape):  # (B, H / G, N, G, C) -> (B, S, H)
+    return x.transpose(0, 2, 4, 1, 3).reshape(shape)
+
+
+def _pallas_forward(opts, q, k, v, g, beta):
+    C, scale, interpret, *_ = opts
+    B, S, H, dk, dv, N, G = _geometry(opts, q, v)
+    levels = _levels(C)
+    wide = lambda d: pl.BlockSpec((None, C, G * d), lambda b, h, n: (b, n, h))
+    narrow = pl.BlockSpec((None, None, None, G, C), lambda b, h, n: (b, h, n, 0, 0))
+    o, states = _pallas_call(
+        functools.partial(_fwd_kernel, scale, G), "kda_fwd", interpret,
+        (B, H // G, N),
+        _level_specs(levels) + [wide(dk), wide(dk), wide(dv), wide(dk), narrow],
+        [wide(dv), pl.BlockSpec((None, G, None, dv, dk), lambda b, h, n: (b, h, n, 0, 0))],
+        [jax.ShapeDtypeStruct((B, S, H * dv), q.dtype),
+         jax.ShapeDtypeStruct((B, H, N, dv, dk), q.dtype)],
+        [pltpu.VMEM((G, dv, dk), jnp.float32)],
+    )(*levels, q, k, v, g, _beta_by_step(beta, G, C))
+    return o, states
+
+
+def _pallas_backward(opts, q, k, v, g, beta, states, do):
+    C, scale, interpret, *_ = opts
+    B, S, H, dk, dv, N, G = _geometry(opts, q, v)
+    levels = _levels(C)
+    # the chunks in reverse
+    wide = lambda d: pl.BlockSpec((None, C, G * d), lambda b, h, n: (b, N - 1 - n, h))
+    narrow = pl.BlockSpec((None, None, None, G, C), lambda b, h, n: (b, h, N - 1 - n, 0, 0))
+    shape = lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype)
+    by_step = _beta_by_step(beta, G, C)
+    *wide_grads, dbeta = _pallas_call(
+        functools.partial(_bwd_kernel, scale, G), "kda_bwd", interpret,
+        (B, H // G, N),
+        _level_specs(levels) + [wide(dk), wide(dk), wide(dv), wide(dk), narrow] + [
+            pl.BlockSpec((None, G, None, dv, dk), lambda b, h, n: (b, h, N - 1 - n, 0, 0)),
+            wide(dv)],
+        [wide(dk), wide(dk), wide(dv), wide(dk), narrow],
+        [shape(x) for x in (q, k, v, g, by_step)],
+        [pltpu.VMEM((G, dv, dk), jnp.float32)],
+    )(*levels, q, k, v, g, by_step, states, do)
+    return (*wide_grads, _beta_from_steps(dbeta, beta.shape))
+
+
+# ---------------------------------------------------------------------------
+# The op.
+# ---------------------------------------------------------------------------
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def _kda(opts, q, k, v, g, beta):
+    return _kda_fwd(opts, q, k, v, g, beta)[0]
+
+
+def _kda_fwd(opts, q, k, v, g, beta):
+    forward = _jnp_forward if opts[2] is None else _pallas_forward
+    o, states = forward(opts, q, k, v, g, beta)
+    # The results feed nothing but the two names, so where a policy saves them
+    # the recompute's copy of the call is dead code (as the flash kernel's).
+    o = checkpoint_name(o, KDA_RESIDUAL_NAMES[0])
+    states = checkpoint_name(states, KDA_RESIDUAL_NAMES[1])
+    return o, (q, k, v, g, beta, states)
+
+
+def _kda_bwd(opts, residuals, do):
+    q, k, v, g, beta, states = residuals
+    backward = _jnp_backward if opts[2] is None else _pallas_backward
+    dq, dk, dv, dg, dbeta = backward(opts, q, k, v, g, beta, states, do)
+    return dq, dk, dv, dg, dbeta.astype(beta.dtype)
+
+
+_kda.defvjp(_kda_fwd, _kda_bwd)
+
+
+def kda(q, k, v, g, beta, chunk: int = DEFAULT_CHUNK, **options):
+    """``kda_flat`` for operands with a heads axis: q, k, g (B, S, H, dk), v (B,
+    S, H, dv) -> o (B, S, H, dv). (On a TPU an array's last two axes are tiled,
+    so (B, S, H, d) and (B, S, H x d) are two layouts and a reshape between
+    them a copy: a caller that has its operands flat calls ``kda_flat``.)"""
+    B, S, H, _ = q.shape
+    flat = lambda x: x.reshape(B, S, -1)
+    o = kda_flat(flat(q), flat(k), flat(v), flat(g), beta, H, chunk, **options)
+    return o.reshape(B, S, H, -1)
+
+
+def kda_flat(q, k, v, g, beta, heads: int, chunk: int = DEFAULT_CHUNK, *,
+             scale: Optional[float] = None, interpret: Optional[bool] = None,
+             heads_per_step: int = HEADS_PER_STEP):
+    """The recurrence of the module docstring over whole sequences.
+
+    q, k (B, S, H x dk) and v (B, S, H x dv) in the compute type, a head's
+    columns together; g (B, S, H x dk) float32, the log-decays, <= 0; beta (B,
+    S, H). -> o (B, S, H x dv) in q's dtype. ``scale`` defaults to dk^-0.5. ``interpret``: None the ``jnp`` path,
+    False the Mosaic kernels, True the kernels interpreted. ``heads_per_step``: the heads a grid
+    step of the kernels walks (the gcd with H is taken). S must be whole chunks: a sequence
+    that is not is refused, not padded."""
+    S, dk, dv = q.shape[1], q.shape[-1] // heads, v.shape[-1] // heads
+    if S % chunk:
+        raise ValueError(
+            f"kda: a sequence of {S} positions is not whole chunks of {chunk}; pad it "
+            "(g = 0, beta = 0 add nothing) or choose a chunk that divides it")
+    if g.dtype != jnp.float32:
+        raise ValueError(f"kda: the log-decays are float32; got {g.dtype}")
+    if interpret is not None and (dk % 128 or dv % 128):
+        raise ValueError(
+            f"kda: the kernels take head widths that are whole 128-lane tiles; got dk={dk}, "
+            f"dv={dv} (the jnp path, interpret=None, takes any)")
+    opts = (chunk, float(dk ** -0.5 if scale is None else scale), interpret, heads_per_step,
+            heads)
+    return _kda(opts, q, k, v, g, beta)
+
+
+# ---------------------------------------------------------------------------
+# The short convolutions in front of the recurrence.
+# ---------------------------------------------------------------------------
+
+_CONV_ROWS, _CONV_COLUMNS = 512, 512  # a tile of the convolution's kernels
+
+
+def _conv_tile(S, C):
+    return math.gcd(S, _CONV_ROWS), math.gcd(C, _CONV_COLUMNS)
+
+
+def _conv_fwd_kernel(K, x, tail, taps, y, ext):
+    """y_t = sum_i taps_i x_{t-K+1+i} over a (rows, columns) tile: the tile
+    behind its 8 preceding rows (zeros before the sequence) in ``ext``, read
+    back at the K row offsets."""
+    rows = x.shape[0]
+    ext[0:8, :] = jnp.where(pl.program_id(2) == 0, 0.0, tail[...].astype(jnp.float32))
+    ext[8:, :] = x[...].astype(jnp.float32)
+    acc = ext[8:, :] * taps[K - 1:K, :]
+    for i in range(K - 1):
+        acc = acc + ext[pl.ds(8 - (K - 1 - i), rows), :] * taps[i:i + 1, :]
+    y[...] = acc.astype(y.dtype)
+
+
+def _conv_bwd_kernel(K, x, tail, dy, head, taps, dx, dtaps, ext, dext):
+    """The transposes: dx_t = sum_i taps_i dy_{t+K-1-i} (the tile before its 8
+    following rows, zeros after the sequence), and dtaps_i = sum over the
+    positions of x_{t-K+1+i} dy_t, summed into a block that stays resident
+    over the batch and the tiles of rows."""
+    rows = x.shape[0]
+    first = (pl.program_id(1) == 0) & (pl.program_id(2) == 0)
+    last = pl.program_id(2) == pl.num_programs(2) - 1
+    ext[0:8, :] = jnp.where(pl.program_id(2) == 0, 0.0, tail[...].astype(jnp.float32))
+    ext[8:, :] = x[...].astype(jnp.float32)
+    dyf = dy[...].astype(jnp.float32)
+    dext[0:rows, :] = dyf
+    dext[rows:, :] = jnp.where(last, 0.0, head[...].astype(jnp.float32))
+
+    @pl.when(first)
+    def _():
+        dtaps[...] = jnp.zeros_like(dtaps)
+
+    acc = dyf * taps[K - 1:K, :]
+    for i in range(K):
+        d = K - 1 - i
+        if d:
+            acc = acc + dext[pl.ds(d, rows), :] * taps[i:i + 1, :]
+        shifted = ext[pl.ds(8 - d, rows), :]
+        dtaps[i:i + 1, :] += jnp.sum(shifted * dyf, axis=0, keepdims=True)
+    dx[...] = acc.astype(dx.dtype)
+
+
+def _conv_specs(S, K, rows, columns):
+    """Blocks over a grid of (tiles of columns, batch, tiles of rows): a tile, the
+    8 rows before it and the 8 after it (clamped at the sequence's ends, where
+    the kernels put zeros), and the taps of the tile's columns."""
+    tile = pl.BlockSpec((None, rows, columns), lambda c, b, n: (b, n, c))
+    before = pl.BlockSpec((None, 8, columns),
+                          lambda c, b, n: (b, jnp.maximum(n * (rows // 8) - 1, 0), c))
+    after = pl.BlockSpec((None, 8, columns),
+                         lambda c, b, n: (b, jnp.minimum((n + 1) * (rows // 8), S // 8 - 1), c))
+    taps = pl.BlockSpec((K, columns), lambda c, b, n: (0, c))
+    return tile, before, after, taps
+
+
+def _conv_forward(x, taps, interpret):
+    (B, S, C), K = x.shape, taps.shape[0]
+    rows, columns = _conv_tile(S, C)
+    tile, before, _, taps_spec = _conv_specs(S, K, rows, columns)
+    return pl.pallas_call(
+        functools.partial(_conv_fwd_kernel, K), grid=(C // columns, B, S // rows),
+        in_specs=[tile, before, taps_spec], out_specs=tile,
+        out_shape=jax.ShapeDtypeStruct(x.shape, x.dtype),
+        scratch_shapes=[pltpu.VMEM((rows + 8, columns), jnp.float32)],
+        interpret=interpret, name="kda_conv_fwd",
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "parallel")),
+    )(x, x, taps.astype(jnp.float32))
+
+
+def _conv_backward(x, taps, dy, interpret):
+    (B, S, C), K = x.shape, taps.shape[0]
+    rows, columns = _conv_tile(S, C)
+    tile, before, after, taps_spec = _conv_specs(S, K, rows, columns)
+    return pl.pallas_call(
+        functools.partial(_conv_bwd_kernel, K), grid=(C // columns, B, S // rows),
+        in_specs=[tile, before, tile, after, taps_spec], out_specs=[tile, taps_spec],
+        out_shape=[jax.ShapeDtypeStruct(x.shape, x.dtype),
+                   jax.ShapeDtypeStruct(taps.shape, jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((rows + 8, columns), jnp.float32)] * 2,
+        interpret=interpret, name="kda_conv_bwd",
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary", "arbitrary")),
+    )(x, x, dy, dy, taps.astype(jnp.float32))
+
+
+def _conv_reference(x, taps):
+    K, C = taps.shape
+    return lax.conv_general_dilated(
+        x, taps[:, None, :].astype(x.dtype), window_strides=(1,), padding=[(K - 1, 0)],
+        dimension_numbers=("NWC", "WIO", "NWC"), feature_group_count=C)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2,))
+def _causal_conv(x, taps, interpret):
+    return _conv_forward(x, taps, interpret)
+
+
+def _causal_conv_fwd(x, taps, interpret):
+    return _conv_forward(x, taps, interpret), (x, taps)
+
+
+def _causal_conv_bwd(interpret, residuals, dy):
+    x, taps = residuals
+    dx, dtaps = _conv_backward(x, taps, dy, interpret)
+    return dx, dtaps.astype(taps.dtype)
+
+
+_causal_conv.defvjp(_causal_conv_fwd, _causal_conv_bwd)
+
+
+def causal_conv(x, taps, *, interpret: Optional[bool] = None):
+    """The depthwise causal convolution over positions in front of the
+    recurrence: x (B, S, C) in the compute type, taps (K, C), y_t = sum_i
+    taps_i x_{t-K+1+i} with zeros before the sequence; float32 products and
+    sums, x's dtype out. ``interpret`` as ``kda_flat``'s: None is XLA's grouped
+    convolution (any backend, any width; on a TPU it takes minutes to compile
+    at 12,288 groups, which is why the kernels exist), False the two Mosaic
+    calls ``kda_conv_fwd`` / ``kda_conv_bwd``, which take K <= 8, S in whole
+    8-row tiles and C in whole 128-lane tiles."""
+    (_, S, C), K = x.shape, taps.shape[0]
+    if interpret is None or K > 8 or S % 8 or C % 128:
+        return _conv_reference(x, taps)
+    return _causal_conv(x, taps, interpret)
+

@@ -64,10 +64,10 @@ class StrategyConfig:
     shard_grads: bool = False
     shard_opt_state: bool = False
     # per-layer rematerialization policy inside the block scan:
-    # "none" | "dots" (save matmul outputs) | "full" | "auto" (pick the
-    # cheapest policy whose memory estimate fits the device — resolved by
-    # utils.memory.resolve_auto_remat before training). Legacy bools accepted
-    # in JSON configs (True = "full").
+    # "none" | "dots" (save matmul outputs) | "full_keep_kernels" | "full" |
+    # "auto" (pick the cheapest of none / dots / full whose memory estimate
+    # fits the device — resolved by utils.memory.resolve_auto_remat before
+    # training). Legacy bools accepted in JSON configs (True = "full").
     remat: str = "none"
     # compute precision for matmuls ('bf16' | 'f32')
     precision: str = "bf16"
@@ -139,7 +139,7 @@ def _normalize_remat_field(value: Any) -> str:
     except ValueError:
         raise ValueError(
             f"invalid remat value {value!r} in strategy config "
-            "(expected bool or one of 'none'/'dots'/'full'/'auto')"
+            "(expected bool or one of 'none'/'dots'/'full_keep_kernels'/'full'/'auto')"
         )
 
 
@@ -423,11 +423,15 @@ _EP_RULES = {
 
 
 def _leaf_name(path) -> str:
-    """'blocks/<leaf>' for a leaf of either stack of layers: the leading dense
-    stack ('dense_blocks', models/tinygpt.py first_k_dense) holds leaves of
-    the same names, shapes and roles as 'blocks' and takes the same rules."""
+    """'blocks/<leaf>' for a leaf of any stack of layers (models/tinygpt.py
+    ``_STACK_NAMES``: 'blocks', the leading dense 'dense_blocks', the KDA
+    layers' 'kda_blocks' / 'kda_dense_blocks'): a leaf of one name has the same
+    shape but for its widths and the same role in every stack, and takes the
+    same rules. A KDA mixer's own leaves ('kda_*') have no tensor-parallel
+    rule: under a 'model' axis they stay whole."""
     name = "/".join(str(getattr(p, "key", p)) for p in path)
-    return name.removeprefix("dense_")
+    stack, _, leaf = name.partition("/")
+    return f"blocks/{leaf}" if leaf and stack.endswith("blocks") else name
 
 
 #: Leaves smaller than this (total elements) are not worth FSDP-sharding in

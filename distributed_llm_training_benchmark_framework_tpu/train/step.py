@@ -110,12 +110,17 @@ def _per_block_slice_specs(stacked_specs: Params):
     instead of hiding one. Returns None when nothing is armable.
     """
     # A leading dense stack holds leaves of the same names and shapes as
-    # 'blocks' (same slice spec) plus its own MLP's: one table serves both.
-    stacks = {**stacked_specs.get("dense_blocks", {}), **stacked_specs["blocks"]}
+    # 'blocks' (same slice spec) plus its own MLP's: one table serves both. So
+    # it does the KDA layers' stacks; a leaf two stacks place differently (one
+    # name at two widths) is left out, as a leaf on the layers axis is.
+    stacks = {}
+    for stack in sorted(k for k in stacked_specs if k.endswith("blocks")):
+        for name, spec in stacked_specs[stack].items():
+            stacks.setdefault(name, set()).add(tuple(spec))
     per_block = tuple(sorted(
-        (name, P(*list(spec)[1:]))
-        for name, spec in stacks.items()
-        if list(spec)[0] is None
+        (name, P(*spec[1:]))
+        for name, (spec, *others) in stacks.items()
+        if spec[0] is None and not others
     ))
     return per_block or None
 
@@ -217,7 +222,7 @@ def mlp_hidden_spec(
     """
     if not strategy.shard_params or pipelined or cfg.tp_collective_matmul:
         return None
-    spec = param_specs["blocks"].get("wgu" if cfg.mlp_act == "swiglu" else "wfc")
+    spec = param_specs.get("blocks", {}).get("wgu" if cfg.mlp_act == "swiglu" else "wfc")
     hidden = None if spec is None else list(spec)[-1]
     if "data" not in _axes(hidden):
         return None

@@ -151,11 +151,29 @@ def _deepseek_forward_flops_per_token(c) -> float:
         mlp = (6 if c.mlp_act == "swiglu" else 4) * D * F
     dense = 6 * D * (c.dense_mlp_hidden or 0)
     routed_layers = c.n_layer - c.first_k_dense
+    kda_layers = (c.layer_types or ()).count("kda")
     return float(
-        c.n_layer * (copies * projections + scores)
+        (c.n_layer - kda_layers) * (copies * projections + scores)
+        + kda_layers * kda_forward_flops_per_token(c)
         + copies * (c.first_k_dense * dense + routed_layers * mlp)
         + 2 * D * c.vocab_size
     )
+
+
+def kda_forward_flops_per_token(c) -> float:
+    """One ``kda`` layer's mixer, a token: the projections (q, k, v; the
+    decay's and the gate's low-rank maps of rank kda_head_dim; beta; the
+    output), the three convolutions' taps, and the recurrence counted as the
+    chunkwise form's work at the config's chunk C with d = kda_head_dim, a
+    head: five products of 2 C d (K K^T, Q K^T, the two applications of the
+    inverse, the intra-chunk output), three of 2 d^2 through the state, and
+    2 C^2 / 3 for the triangular inverse. What a kernel multiplies beyond
+    that (masked halves, its own way to the inverse) is its choice."""
+    D, H, d, C = c.n_embd, c.kda_heads, c.kda_head_dim, c.kda_chunk
+    projections = 2 * D * 3 * H * d + 2 * (2 * D * d + 2 * d * H * d) + 2 * D * H + 2 * H * d * D
+    convolutions = 2 * c.kda_conv * 3 * H * d
+    recurrence = H * (5 * 2 * C * d + 3 * 2 * d * d + 2 * C * C / 3)
+    return float(projections + convolutions + recurrence)
 
 
 def train_flops_per_token(config) -> float:

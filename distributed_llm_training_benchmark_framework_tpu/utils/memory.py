@@ -170,10 +170,24 @@ def estimate_hbm(
     from ..models.tinygpt import normalize_remat
 
     pol = normalize_remat("full" if cfg.remat == "auto" else cfg.remat)
-    if pol == "full":
-        # Only the layer-boundary residual (+grad) survives; one layer's
-        # working set is live during its backward recompute.
-        act_b = layers_here * 2 * B * layer_S * D * cbytes + dense_per_layer
+    # A KDA layer's recurrence keeps for its backward the states entering its
+    # chunks and its output (by name under 'dots' and 'full_keep_kernels' too)
+    # and, without remat, its five operands (g in float32); under 'full' it
+    # runs again.
+    kda_layers = (getattr(cfg, "layer_types", None) or ()).count("kda")
+    kda_b = 0
+    if kda_layers and pol != "full":
+        width = cfg.kda_heads * cfg.kda_head_dim
+        kept = tinygpt.kda_stats(cfg, S)["saved_state_bytes"] + S * width * cbytes
+        if pol == "none":
+            kept += S * width * (3 * cbytes + 4) + S * cfg.kda_heads * 4
+        kda_b = kda_layers * B * kept
+    if pol in ("full", "full_keep_kernels"):
+        # Only the layer-boundary residual (+grad) survives (and, kept by
+        # name, the attention kernel's output); one layer's working set is
+        # live during its backward recompute.
+        kept = 3 if pol == "full_keep_kernels" else 2
+        act_b = layers_here * kept * B * layer_S * D * cbytes + dense_per_layer
     elif pol == "dots":
         # Matmul outputs are saved (~qkv 3BSD + attn-out BSD + mlp 5BSD +
         # boundary 2BSD ≈ 11·BSD per layer; the attention output is in
@@ -190,7 +204,7 @@ def estimate_hbm(
 
     return HBMEstimate(
         params=params_b, grads=grads_b, opt_state=opt_b,
-        activations=act_b, logits=logits_b, dataset=dataset_b,
+        activations=act_b + kda_b, logits=logits_b, dataset=dataset_b,
     )
 
 

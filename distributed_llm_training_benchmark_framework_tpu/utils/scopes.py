@@ -40,9 +40,17 @@ SHARED = "shared"
 MLA_PROJ, MLA_CORE, MLA_OUT = MLA_SCOPES = ("mla_proj", "mla_core", "mla_out")
 
 # Inside ``attention``, where the stack mixes kinds of layer
-# (``TinyGPTConfig.layer_types``): a layer's whole attention sublayer under
-# its kind's name, a sliding-window layer or a global one.
-WINDOW, GLOBAL = LAYER_KIND_SCOPES = ("window", "global")
+# (``TinyGPTConfig.layer_types``): a layer's whole mixer sublayer under its
+# kind's name, a sliding-window layer, a global one (softmax attention over
+# every earlier position, latent attention too) or a KDA one (the gated
+# delta-rule recurrence, ``ops/kda.py``).
+WINDOW, GLOBAL, KDA = LAYER_KIND_SCOPES = ("window", "global", "kda")
+
+# Inside ``attention`` / ``kda`` (``models/tinygpt.py::_kda_sublayer``): the
+# projections with their convolutions, SiLU, l2norm, the decay and beta; the
+# recurrence itself (the Mosaic calls ``kda_fwd`` / ``kda_bwd``); the head
+# norm, the gate and the output projection.
+KDA_PREP, KDA_CORE, KDA_OUT = KDA_SCOPES = ("kda_prep", "kda_core", "kda_out")
 
 # Inside ``attention`` (below the kind's scope where there is one), where a
 # layer's QK-norm and rotary are ``ops/rotary.py``'s one pass: the two Mosaic

@@ -1,0 +1,93 @@
+#!/usr/bin/env python
+"""The KDA recurrence (``ops/kda.py``) alone on the chip: the forward kernel
+and the forward + backward at a cell's operand, a line a chunk size, and the
+kernels' distance from the position-by-position recurrence at a short length.
+
+    chiprun -- python scripts/microbench_kda.py [--rows 16384] [--heads 32]
+        [--chunks 32,64,128] [--heads-per-step 1,2,4] [--iters 5]
+        [--out chiprun_out/kda.jsonl]
+
+ms a layer's call (the mean of ``--iters`` after a warm-up), the least time
+``perfbench/harness/flops_kda.kda_kernel_cost`` gives for the work where that
+file is there, and |kernel - recurrence| / |recurrence| of the output and the
+five gradients at ``--check-rows`` positions with bfloat16 operands.
+"""
+
+import argparse
+import json
+import os
+import sys
+import time
+
+os.environ.setdefault("TPU_LOG_DIR", "disabled")
+sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+
+
+def main():
+    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    ap.add_argument("--rows", type=int, default=16384)
+    ap.add_argument("--heads", type=int, default=32)
+    ap.add_argument("--chunks", default="32,64,128")
+    ap.add_argument("--heads-per-step", default="1,2,4")
+    ap.add_argument("--iters", type=int, default=5)
+    ap.add_argument("--check-rows", type=int, default=1024)
+    ap.add_argument("--out")
+    args = ap.parse_args()
+
+    import jax
+    import jax.numpy as jnp
+
+    from distributed_llm_training_benchmark_framework_tpu.ops import kda
+    from perfbench.harness import reference_kda
+
+    def recurrent(q, k, v, g, beta):  # the benchmark's scan over the positions, float32
+        f32 = lambda x: x.astype(jnp.float32)
+        rule = lambda *a: reference_kda.delta_rule({"state_dtype": "float32"}, *a)
+        with jax.default_matmul_precision("highest"):
+            return jax.vmap(rule)(f32(q), f32(k), f32(v), g, beta)
+
+    def operands(S, H, d=128):
+        ks = jax.random.split(jax.random.PRNGKey(0), 6)
+        unit = lambda x: x / jnp.linalg.norm(x, axis=-1, keepdims=True)
+        q, k = (unit(jax.random.normal(key, (1, S, H, d))) for key in ks[:2])
+        v = jax.random.normal(ks[2], (1, S, H, d))
+        a = jnp.exp(jax.random.uniform(ks[3], (H, 1), maxval=jnp.log(16.0)))
+        g = -a * jax.nn.softplus(jax.random.normal(ks[4], (1, S, H, d)) - 3.0)
+        beta = jax.nn.sigmoid(jax.random.normal(ks[5], (1, S, H)))
+        bf = lambda x: x.astype(jnp.bfloat16)
+        return bf(q), bf(k), bf(v), g, beta
+
+    def timed(f, *a):
+        jax.block_until_ready(f(*a))
+        t = time.perf_counter()
+        for _ in range(args.iters):
+            out = f(*a)
+        jax.block_until_ready(out)
+        return 1e3 * (time.perf_counter() - t) / args.iters
+
+    lines = []
+    rel = lambda a, b: float(jnp.linalg.norm(a.astype(jnp.float32) - b) / jnp.linalg.norm(b))
+    ints = lambda text: [int(c) for c in text.split(",")]
+    for chunk, per_step in ((c, h) for c in ints(args.chunks) for h in ints(args.heads_per_step)):
+        op = lambda *a: kda.kda(*a, chunk=chunk, interpret=False, heads_per_step=per_step)
+        loss = lambda f: (lambda *a: jnp.sum(f(*a).astype(jnp.float32) ** 2))
+        small = operands(args.check_rows, 4)
+        want = recurrent(*small)
+        want_grads = jax.grad(loss(recurrent), argnums=(0, 1, 2, 3, 4))(*small)
+        got_grads = jax.jit(jax.grad(loss(op), argnums=(0, 1, 2, 3, 4)))(*small)
+        line = {"chunk": chunk, "heads_per_step": per_step, "rows": args.rows, "heads": args.heads,
+                "out_err": rel(jax.jit(op)(*small), want),
+                "grad_err": [rel(a, b) for a, b in zip(got_grads, want_grads)]}
+        big = operands(args.rows, args.heads)
+        line["fwd_ms"] = timed(jax.jit(op), *big)
+        line["fwd_bwd_ms"] = timed(jax.jit(jax.grad(loss(op), argnums=(0, 1, 2, 3, 4))), *big)
+        print(json.dumps(line), flush=True)
+        lines.append(line)
+    if args.out:
+        os.makedirs(os.path.dirname(args.out), exist_ok=True)
+        with open(args.out, "w") as f:
+            f.writelines(json.dumps(line) + "\n" for line in lines)
+
+
+if __name__ == "__main__":
+    main()

@@ -86,13 +86,15 @@ def _kernel_runs(jaxpr, name, times=1):
     return runs
 
 
-@pytest.mark.parametrize("remat, runs_a_layer", [("none", 1), ("dots", 1), ("full", 2)])
+@pytest.mark.parametrize("remat, runs_a_layer", [
+    ("none", 1), ("dots", 1), ("full_keep_kernels", 1), ("full", 2)])
 @pytest.mark.parametrize("loop", sorted(LOOPS))
 @pytest.mark.parametrize("case", CASES)
 def test_flash_forward_runs_a_layer_in_the_gradient(case, loop, remat, runs_a_layer):
     """``dots`` runs the forward kernel once a layer, as no remat does: its
-    two results are saved by name. ``full`` has no policy, so nothing is
-    kept by name and the kernel still runs again in the backward pass."""
+    two results are saved by name; ``full_keep_kernels`` saves them and
+    nothing else. ``full`` has no policy, so nothing is kept by name and the
+    kernel still runs again in the backward pass."""
     config = _config(case, loop, remat)
     params, batch = _operands(config)
     jaxpr = jax.make_jaxpr(jax.grad(_loss(config)))(params, batch).jaxpr

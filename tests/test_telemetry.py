@@ -198,7 +198,9 @@ def test_step_time_spike_says_where_the_window_went(tmp_path, planted):
         window(2 * w)
         rec.step_window(last_step=2 * w + 1, losses=[5.0, 5.0],
                         window_mean_step_time_sec=0.1)
-    window(8, **{"dispatch": {"inside": 0.05}, "wait": {"after": 0.05}}[planted])
+    # 80 ms against the limit of 50: the wait is read over the median wait of the
+    # process's earlier windows, which a loaded host puts at several ms
+    window(8, **{"dispatch": {"inside": 0.08}, "wait": {"after": 0.08}}[planted])
     rec.step_window(last_step=9, losses=[5.0, 5.0],
                     window_mean_step_time_sec=1.0)
     rec.close()

@@ -226,7 +226,8 @@ def test_ring_block_kernels_compile(v5e_devices):
 # of the benchmark's routed cells: olmoe-1b-7b.d1 and the held experts' buffers
 # of the DeepSeek, SDAR and Mellum cells.
 EXPERT_STACKS = {"olmoe": (65536, 2048, 1024, 64), "deepseek": (18432, 2048, 1408, 8),
-                 "sdar": (40960, 2048, 768, 16), "mellum": (49152, 2304, 896, 16)}
+                 "sdar": (40960, 2048, 768, 16), "mellum": (49152, 2304, 896, 16),
+                 "kimi": (6144, 2304, 1024, 8)}
 
 
 @pytest.mark.parametrize("cell", sorted(EXPERT_STACKS))
@@ -633,3 +634,74 @@ def test_qk_prologue_partitions_over_a_four_device_data_mesh(v5e_devices):
             jax.grad(functools.partial(_prologue_loss, True), argnums=(0, 1, 2, 3)), *args)
     assert "all-gather" not in text and "all-to-all" not in text
     assert "bf16[1,4096,4096]" in text  # a chip's own example
+
+
+@pytest.mark.parametrize("chunk", [64, 128])
+def test_the_kda_kernels_compile_at_the_cells_operand(v5e_devices, chunk):
+    """``ops/kda.py`` at the Kimi-Linear cell's operand (one sequence of 16,384
+    positions, 32 heads of 128 keys and values): ``kda_fwd`` and ``kda_bwd``,
+    whose body is ``jax.vjp`` of the chunk's own body traced into the kernel,
+    so what Mosaic has to take is also every transpose jax makes of it."""
+    from distributed_llm_training_benchmark_framework_tpu.ops import kda
+
+    one = SingleDeviceSharding(v5e_devices[0])
+    aval = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+    wide = aval((1, 16384, 32, 128), jnp.bfloat16)
+
+    def loss(q, k, v, g, beta):
+        return jnp.sum(kda.kda(q, k, v, g, beta, chunk, interpret=False).astype(jnp.float32))
+
+    text = _compile(jax.grad(loss, argnums=(0, 1, 2, 3, 4)), wide, wide, wide,
+                    aval((1, 16384, 32, 128), jnp.float32), aval((1, 16384, 32), jnp.float32))
+    assert text.count('custom_call_target="tpu_custom_call"') == 2
+    assert "kda_fwd" in text and "kda_bwd" in text
+
+
+def test_the_kda_convolutions_kernels_compile_at_the_cells_operand(v5e_devices):
+    """The three depthwise convolutions of a KDA layer as one call over the
+    12,288 columns of q, k and v, forward and backward (XLA's grouped
+    convolution at that many groups takes the chip's compiler minutes)."""
+    from distributed_llm_training_benchmark_framework_tpu.ops import kda
+
+    one = SingleDeviceSharding(v5e_devices[0])
+    aval = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+
+    def loss(x, taps):
+        return jnp.sum(jnp.square(kda.causal_conv(x, taps, interpret=False).astype(jnp.float32)))
+
+    text = _compile(jax.grad(loss, argnums=(0, 1)),
+                    aval((1, 16384, 12288), jnp.bfloat16), aval((4, 12288), jnp.float32))
+    assert "kda_conv_fwd" in text and "kda_conv_bwd" in text
+
+
+def test_the_kimi_cells_stack_compiles_a_layer_of_each_kind(v5e_devices, monkeypatch):
+    """A KDA layer with a routed MLP and the NoPE latent-attention layer at the
+    cell's widths and its 16,384 positions, forward and backward under the
+    cell's remat policy: the recurrence's two kernels, the flash pair at
+    192 / 128, the held experts' grouped matmuls."""
+    from distributed_llm_training_benchmark_framework_tpu.models import tinygpt
+    from distributed_llm_training_benchmark_framework_tpu.models.tinygpt import TinyGPTConfig
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    config = TinyGPTConfig(
+        vocab_size=20480, n_embd=2304, n_head=32, n_layer=2, block_size=16384, dropout=0.0,
+        causal=True, attention_impl="flash", scan_layers=False, norm="rmsnorm", pos_embed="rope",
+        mlp_act="swiglu", mlp_hidden=1024, bias=False, tie_embeddings=False, kv_lora_rank=512,
+        qk_nope_head_dim=128, qk_rope_head_dim=64, v_head_dim=128, mla_nope=True, n_experts=256,
+        expert_top_k=8, capacity_factor=None, router_score="sigmoid", routed_scaling_factor=2.446,
+        n_shared_experts=1, experts_held=(0, 8), held_rows_factor=1.5,
+        remat="full_keep_kernels", layer_types=("kda", "global"), kda_heads=32, kda_head_dim=128)
+    one = SingleDeviceSharding(v5e_devices[0])
+    shapes = jax.eval_shape(lambda k: tinygpt.init_params(config, k), jax.random.key(0))
+    params = jax.tree.map(lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one), shapes)
+    x = jax.ShapeDtypeStruct((1, 16384, 2304), jnp.bfloat16, sharding=one)
+
+    def loss(params, x):
+        y, _ = tinygpt.apply_layers(config, params, x)
+        return jnp.sum(y.astype(jnp.float32))
+
+    text = _compile(jax.grad(loss), params, x)
+    for name in ("kda_fwd", "kda_bwd", "kda_conv_fwd", "kda_conv_bwd", "flash_fwd",
+                 "flash_bwd_fused", "jit(gmm)", "jit(tgmm)"):
+        assert name in text, name
+    assert text.count("kda_fwd") >= 1 and "attention/kda/kda_core" in text
