@@ -24,9 +24,13 @@ levels: at the level of half-size s, every block of 2s positions gives its
 lower-left quadrant (rows in its upper half, columns in its lower), with the
 block's middle as the reference: a row's factor is exp(sum of g from the
 middle to the row), a column's exp(sum of g from after the column to the
-middle), both sums of non-positive terms taken directly (a 0/1 matrix times
-g, never a difference of two running sums), and the quadrants of all blocks of
-a level are one masked matrix product. The diagonal has E = 1. The same
+middle), both sums of non-positive terms taken directly: one segmented scan
+over the chunk's rows (``_decay_sums``: log2 C stages of float32 additions on
+the vector units, each of numbers of one sign, a stage's sums the level's
+exponents; never a difference of two running sums, and no matrix product),
+and the quadrants of all blocks of a level are one masked matrix product (a
+factor is at most 1 on every row, so the quadrant's mask is the only one).
+The diagonal has E = 1. The same
 levels invert I + A exactly as block forward substitution does: with T the
 inverse of the block diagonal part at block size s, T - T A_level T is the
 inverse at 2s. There is no clamp on g.
@@ -61,7 +65,7 @@ from __future__ import annotations
 
 import functools
 import math
-from typing import NamedTuple, Optional
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -76,11 +80,16 @@ from jax.experimental.pallas import tpu as pltpu
 #: the states entering the chunks.
 KDA_RESIDUAL_NAMES = ("kda_out", "kda_states")
 
-#: Measured on a v5e at heads of 128 keys and values, 16,384 positions
-#: (scripts/microbench_kda.py, forward + backward a layer: 64.7 ms at 64, 50.7
-#: at 128): fewer sequential steps, and half the states kept.
+#: Measured on a v5e at (1, 16384, 32, 128), four heads a grid step
+#: (scripts/microbench_kda.py, ms a layer forward | forward + backward: 14.65 |
+#: 50.83 at 64, 9.67 | 34.55 at 128): fewer sequential steps, and half the
+#: states kept.
 DEFAULT_CHUNK = 128
-#: Heads a grid step of the kernels walks (scripts/microbench_kda.py's sweep).
+#: Heads a grid step of the kernels walks (the same sweep at chunk 128: 11.08 |
+#: 36.37 at 1, 10.12 | 34.83 at 2, 9.67 | 34.55 at 4; at 8 the forward reads
+#: 9.45 and the backward's spilled registers pass the kernel's 16 MiB of VMEM,
+#: 28.31 MB: a head more in a step adds its whole schedule, so the step's
+#: fixed cost is all there is to win).
 HEADS_PER_STEP = 4
 
 
@@ -90,115 +99,133 @@ def kernel_mode() -> Optional[bool]:
     return False if jax.default_backend() == "tpu" else None
 
 
-class _Levels(NamedTuple):
-    """The 0/1 matrices of a chunk of C positions, float32. ``sums`` ((2 + L)
-    C + 8, C): times g, C rows at a time, they give the inclusive running sum,
-    the sum over the later positions, and each level's row sums (from the
-    block's middle to an upper row; from after a lower row to the middle); the
-    last 8 rows (a whole sublane tile) are 1s: the sum over the chunk.
-    ``quadrant`` (L, C, C): the level's lower-left quadrants. ``upper`` /
-    ``lower`` (L, C, 1): the rows in their block's upper / lower half."""
-
-    sums: np.ndarray
-    quadrant: np.ndarray
-    upper: np.ndarray
-    lower: np.ndarray
-
-
 @functools.lru_cache(maxsize=None)
-def _levels(C: int) -> _Levels:
+def _quadrants(C: int) -> np.ndarray:
+    """The 0/1 masks of a chunk of C positions, (log2 C, C, C) float32: at the
+    level of half-size s = 2^level, the lower-left quadrant of every aligned
+    block of 2s positions (rows in its upper half, columns in its lower)."""
     L = int(math.log2(C))
     if C < 2 or 2 ** L != C:
         raise ValueError(f"kda: the chunk is a power of two of at least 2 positions; got {C}")
     i, t = np.arange(C)[:, None], np.arange(C)[None, :]
-    sums = [t <= i, t > i]
-    quadrant, upper, lower = [], [], []
+    quadrant = []
     for level in range(L):
         s = 2 ** level
-        middle = (i // (2 * s)) * 2 * s + s  # the first row of the block's upper half
         up = (i % (2 * s)) >= s
-        sums.append(np.where(up, (t >= middle) & (t <= i), (t > i) & (t < middle)))
         quadrant.append((i // (2 * s) == t // (2 * s)) & up & ~up.T)
-        upper.append(up)
-        lower.append(~up)
-    f32 = lambda rows: np.stack(rows).astype(np.float32)
-    sums.append(np.ones((8, C)))
-    return _Levels(np.concatenate(sums).astype(np.float32), f32(quadrant), f32(upper), f32(lower))
+    return np.stack(quadrant).astype(np.float32)
 
 
-def _mm(a, b, dims, dtype, precision=None):
+def _mm(a, b, dims, dtype):
     """``a`` x ``b`` contracting ``dims`` = (a's, b's), operands in ``dtype``,
     float32 out."""
     return lax.dot_general(
         a.astype(dtype), b.astype(dtype), (((dims[0],), (dims[1],)), ((), ())),
-        precision=precision, preferred_element_type=jnp.float32)
+        preferred_element_type=jnp.float32)
 
 
-def _exact_mm(m, x, dims):
-    """``m`` x ``x`` contracting ``dims``, ``m`` of 0s and 1s and ``x`` float32,
-    as exact as a float32 sum: x is three bfloat16 pieces (8 + 8 + 8 bits of
-    its 24), each multiplied in one pass of the MXU and accumulated in
-    float32. (``Precision.HIGHEST`` would split the 0s and 1s too: six passes.)"""
-    if x.dtype != jnp.float32:
-        raise TypeError(f"_exact_mm splits float32; got {x.dtype}")
-    out = None
-    for _ in range(3):
-        piece = x.astype(jnp.bfloat16)
-        part = _mm(m, piece, dims, jnp.bfloat16)
-        out = part if out is None else out + part
-        x = x - piece.astype(jnp.float32)
-    return out
+def _in_upper_half(shape, s):
+    """Whether a row lies in the upper half of its aligned block of 2s rows."""
+    rows = lax.broadcasted_iota(jnp.int32, shape, 0)
+    return lax.ne(lax.bitwise_and(rows, jnp.int32(s)), jnp.int32(0))
+
+
+def _sibling(x, s):
+    """x (C, d) -> x[r ^ s]: every aligned block of s rows changes places with
+    the other half of its block of 2s. Halves of whole 8-row sublane tiles
+    move as tiles (a reshape of the row axis); below that, two rolls of the
+    rows under the mask of the half. A permutation that is its own inverse,
+    so its transpose is itself."""
+    C, d = x.shape
+    if s % 8 == 0:
+        halves = lax.reshape(x, (C // (2 * s), 2, s, d))
+        half = lambda i: lax.slice_in_dim(halves, i, i + 1, axis=1)
+        return lax.reshape(lax.concatenate([half(1), half(0)], 1), (C, d))
+    roll = lambda n: lax.concatenate(  # jnp.roll(x, n, 0), 0 < n < C
+        [lax.slice_in_dim(x, C - n, C, axis=0), lax.slice_in_dim(x, 0, C - n, axis=0)], 0)
+    return lax.select(_in_upper_half(x.shape, s), roll(s), roll(C - s))
 
 
 @jax.custom_vjp
-def _segment_sums(sums, g):
-    """``_Levels.sums`` x g (C, dk) float32 -> the list of 2 + L (C, dk) sums of
-    g, each row's over its own segment of the chunk's positions, taken
-    directly, and last the (1, dk) sum over the chunk. Its transpose is the
-    same product the other way round (written out: the transposes jax would
-    make of the split and of the slices are pads, which a kernel cannot
-    hold)."""
+def _decay_sums(g):
+    """g (C, dk) float32 -> (P_C, X_C, [a level's (C, dk) sums], the (1, dk)
+    sum over the chunk): one segmented scan over the rows, log2 C stages. With
+    P_s[r] the sum of g from the first row of r's aligned block of s rows to r
+    and X_s[r] the sum over the rows after r to that block's end (P_1 = g,
+    X_1 = 0), a stage doubles the block: a row of the upper half adds the
+    lower half's total to its P, a row of the lower half the upper half's
+    total to its X; a block's total (kept on every row of the block) reaches
+    the other half's rows by ``_sibling``. Every addition is of numbers of
+    one sign; none is a difference of two running sums. The level of
+    half-size s reads P_s on its upper rows (from the block's middle to the
+    row) and X_s on its lower ones (from after the row to the middle)."""
     C = g.shape[0]
-    out = _exact_mm(sums, g, (1, 0))
-    blocks = sums.shape[0] // C
-    return [out[i * C:(i + 1) * C] for i in range(blocks)] + [out[blocks * C:blocks * C + 1]]
+    P, X, total = g, lax.full_like(g, 0.0), g
+    levels = []
+    for level in range(int(math.log2(C))):
+        s = 2 ** level
+        up = _in_upper_half(g.shape, s)
+        levels.append(lax.select(up, P, X))
+        other = _sibling(total, s)
+        P, X = lax.select(up, lax.add(P, other), P), lax.select(up, X, lax.add(X, other))
+        total = lax.add(total, other)
+    return P, X, levels, lax.slice_in_dim(total, 0, 1, axis=0)
 
 
-def _segment_sums_fwd(sums, g):
-    return _segment_sums(sums, g), sums
+def _decay_sums_bwd(shape, cotangents):
+    """The same chain run backwards (written out: jax would transpose the
+    slices of ``_sibling`` and of the total's row into pads, which a kernel
+    cannot hold, and would trace three passes where this is one): a stage's
+    cotangents add to P and X on the rows that read them, and what its rows
+    hand to ``other`` goes back through ``_sibling`` to the block's total.
+    The chunk's total is the sum of every row, so its cotangent is every
+    row's."""
+    dP, dX, dlevels, dtotal = cotangents
+    zero = lax.full_like(dP, 0.0)
+    dblock = zero  # of a block's total, on every row of the block
+    for level in reversed(range(len(dlevels))):
+        s = 2 ** level
+        up = _in_upper_half(shape, s)
+        dblock = lax.add(dblock, _sibling(lax.add(dblock, lax.select(up, dP, dX)), s))
+        dP = lax.add(dP, lax.select(up, dlevels[level], zero))
+        dX = lax.add(dX, lax.select(up, zero, dlevels[level]))
+    return (lax.add(lax.add(dP, dblock), lax.broadcast_in_dim(dtotal, shape, (0, 1))),)
 
 
-def _segment_sums_bwd(sums, cotangents):
-    *blocks, total = cotangents  # the 8 rows of 1s share the total's cotangent
-    total = jnp.broadcast_to(total * 0.125, (8, total.shape[1]))
-    return None, _exact_mm(sums, jnp.concatenate(blocks + [total], 0), (0, 0))
+_decay_sums.defvjp(lambda g: (_decay_sums(g), g.shape), _decay_sums_bwd)
 
 
-_segment_sums.defvjp(_segment_sums_fwd, _segment_sums_bwd)
-
-
-def _chunk(levels, scale, q, k, v, g, beta, S0):
-    """One head's chunk: q, k (C, dk), v (C, dv), g (C, dk) float32, beta (1,
-    C) float32 (a row: lane-dense where it is stored; its column is taken
-    here, a masked sum over the lanes), S0 (dv, dk) float32, the state **transposed** (its decay is
-    then a row over the lanes) -> (o (C, dv) in q's dtype, S_C^T float32): the
-    module docstring's equations."""
+# A jit of its own: a kernel walks ``heads`` heads a grid step and a step's
+# program holds the kernels several times over (the layers' stacks, remat, the
+# benchmark's check), and every one after the first finds the body's jaxpr,
+# its linearization and its transpose traced. Mosaic inlines the calls.
+@functools.partial(jax.jit, static_argnums=(1,))
+def _chunk(quadrant, scale, q, k, v, g, beta, S0):
+    """One head's chunk: ``quadrant`` (``_quadrants``'s masks), q, k (C, dk), v
+    (C, dv), g (C, dk) float32, beta (1, C) float32 (a row: lane-dense where
+    it is stored; its column is taken here, a masked sum over the lanes), S0
+    (dv, dk) float32, the state **transposed** (its decay is then a row over
+    the lanes) -> (o (C, dv) in q's dtype, S_C^T float32): the module
+    docstring's equations."""
     cd = q.dtype
     C = q.shape[0]
-    sums, quadrant, upper, lower = levels
     eye = (lax.broadcasted_iota(jnp.int32, (C, C), 0)
            == lax.broadcasted_iota(jnp.int32, (C, C), 1)).astype(jnp.float32)
     beta = jnp.sum(eye * beta, axis=1, keepdims=True)  # (C, 1)
     qf, kf = q.astype(jnp.float32), k.astype(jnp.float32)
     kbf, vb = kf * beta, v.astype(jnp.float32) * beta
-    # exp(G_r), exp(G_C - G_r), then a level's factors: all of non-positive sums
-    decay, later, *factors, decay_all = (jnp.exp(x) for x in _segment_sums(sums, g))
+    # exp(G_r), exp(G_C - G_r), exp(G_C), a level's factors: all of non-positive sums
+    running, after, sums, total = _decay_sums(g)
+    decay, later, decay_all = jnp.exp(running), jnp.exp(after), jnp.exp(total)
     B = _mm(q, k, (1, 1), cd) * eye
     T = eye
-    for level, e in enumerate(factors):
-        rows, columns = e * upper[level], kf * (e * lower[level])
-        B_level = _mm(qf * rows, columns, (1, 1), cd) * quadrant[level]
-        A_level = _mm(kbf * rows, columns, (1, 1), cd) * quadrant[level]
+    for level, x in enumerate(sums):
+        # e is a row's factor on a block's upper rows and a column's on its lower
+        # ones, and at most 1 on both: the quadrant's mask takes the rest away
+        e = jnp.exp(x)
+        columns = kf * e
+        B_level = _mm(qf * e, columns, (1, 1), cd) * quadrant[level]
+        A_level = _mm(kbf * e, columns, (1, 1), cd) * quadrant[level]
         B = B + B_level
         T = T - (A_level if level == 0 else _mm(_mm(T, A_level, (1, 0), cd), T, (1, 0), cd))
     W = _mm(T, kbf * decay, (1, 0), cd)
@@ -228,10 +255,9 @@ def _beta_by_chunk(beta, C):  # (B, S, H) -> (N, B, H, 1, C)
 
 
 def _chunk_of_every_head(opts):
-    """``_chunk`` over (batch, heads), the levels' matrices as arrays."""
+    """``_chunk`` over (batch, heads)."""
     C, scale, *_ = opts
-    levels = jax.tree.map(jnp.asarray, _levels(C))
-    return jax.vmap(jax.vmap(functools.partial(_chunk, levels, scale)))
+    return jax.vmap(jax.vmap(functools.partial(_chunk, jnp.asarray(_quadrants(C)), scale)))
 
 
 def _jnp_forward(opts, q, k, v, g, beta):
@@ -272,8 +298,9 @@ def _jnp_backward(opts, q, k, v, g, beta, states, do):
 # fills it.
 # ---------------------------------------------------------------------------
 
-def _level_specs(levels):
-    return [pl.BlockSpec(a.shape, lambda b, h, n, nd=a.ndim: (0,) * nd) for a in levels]
+def _whole(a):
+    """The block that is all of ``a``, the same at every grid step."""
+    return pl.BlockSpec(a.shape, lambda b, h, n: (0,) * a.ndim)
 
 
 def _head(ref, j, d):
@@ -282,31 +309,30 @@ def _head(ref, j, d):
 
 
 def _fwd_kernel(scale, heads, *refs):
-    level_refs, (q, k, v, g, beta, o, states, S) = refs[:4], refs[4:]
+    quadrant, q, k, v, g, beta, o, states, S = refs
 
     @pl.when(pl.program_id(2) == 0)
     def _():
         S[...] = jnp.zeros_like(S)
 
-    levels = tuple(r[...] for r in level_refs)
     dv, dk = S.shape[1:]
     for j in range(heads):
         S0 = S[j]
         states[j] = S0.astype(states.dtype)
-        out, S1 = _chunk(levels, scale, _head(q, j, dk), _head(k, j, dk), _head(v, j, dv),
+        out, S1 = _chunk(quadrant[...], scale, _head(q, j, dk), _head(k, j, dk), _head(v, j, dv),
                          _head(g, j, dk), beta[j:j + 1, :], S0)
         o[:, j * dv:(j + 1) * dv] = out
         S[j] = S1
 
 
 def _bwd_kernel(scale, heads, *refs):
-    level_refs, (q, k, v, g, beta, states, do, dq, dk_, dv_, dg, dbeta, dS) = refs[:4], refs[4:]
+    quadrant, q, k, v, g, beta, states, do, dq, dk_, dv_, dg, dbeta, dS = refs
 
     @pl.when(pl.program_id(2) == 0)
     def _():
         dS[...] = jnp.zeros_like(dS)
 
-    body = functools.partial(_chunk, tuple(r[...] for r in level_refs), scale)
+    body = functools.partial(_chunk, quadrant[...], scale)
     dv, dk = dS.shape[1:]
     for j in range(heads):
         _, pull_back = jax.vjp(
@@ -349,25 +375,25 @@ def _beta_from_steps(x, shape):  # (B, H / G, N, G, C) -> (B, S, H)
 def _pallas_forward(opts, q, k, v, g, beta):
     C, scale, interpret, *_ = opts
     B, S, H, dk, dv, N, G = _geometry(opts, q, v)
-    levels = _levels(C)
+    quadrant = _quadrants(C)
     wide = lambda d: pl.BlockSpec((None, C, G * d), lambda b, h, n: (b, n, h))
     narrow = pl.BlockSpec((None, None, None, G, C), lambda b, h, n: (b, h, n, 0, 0))
     o, states = _pallas_call(
         functools.partial(_fwd_kernel, scale, G), "kda_fwd", interpret,
         (B, H // G, N),
-        _level_specs(levels) + [wide(dk), wide(dk), wide(dv), wide(dk), narrow],
+        [_whole(quadrant), wide(dk), wide(dk), wide(dv), wide(dk), narrow],
         [wide(dv), pl.BlockSpec((None, G, None, dv, dk), lambda b, h, n: (b, h, n, 0, 0))],
         [jax.ShapeDtypeStruct((B, S, H * dv), q.dtype),
          jax.ShapeDtypeStruct((B, H, N, dv, dk), q.dtype)],
         [pltpu.VMEM((G, dv, dk), jnp.float32)],
-    )(*levels, q, k, v, g, _beta_by_step(beta, G, C))
+    )(quadrant, q, k, v, g, _beta_by_step(beta, G, C))
     return o, states
 
 
 def _pallas_backward(opts, q, k, v, g, beta, states, do):
     C, scale, interpret, *_ = opts
     B, S, H, dk, dv, N, G = _geometry(opts, q, v)
-    levels = _levels(C)
+    quadrant = _quadrants(C)
     # the chunks in reverse
     wide = lambda d: pl.BlockSpec((None, C, G * d), lambda b, h, n: (b, N - 1 - n, h))
     narrow = pl.BlockSpec((None, None, None, G, C), lambda b, h, n: (b, h, N - 1 - n, 0, 0))
@@ -376,13 +402,13 @@ def _pallas_backward(opts, q, k, v, g, beta, states, do):
     *wide_grads, dbeta = _pallas_call(
         functools.partial(_bwd_kernel, scale, G), "kda_bwd", interpret,
         (B, H // G, N),
-        _level_specs(levels) + [wide(dk), wide(dk), wide(dv), wide(dk), narrow] + [
+        [_whole(quadrant), wide(dk), wide(dk), wide(dv), wide(dk), narrow] + [
             pl.BlockSpec((None, G, None, dv, dk), lambda b, h, n: (b, h, N - 1 - n, 0, 0)),
             wide(dv)],
         [wide(dk), wide(dk), wide(dv), wide(dk), narrow],
         [shape(x) for x in (q, k, v, g, by_step)],
         [pltpu.VMEM((G, dv, dk), jnp.float32)],
-    )(*levels, q, k, v, g, by_step, states, do)
+    )(quadrant, q, k, v, g, by_step, states, do)
     return (*wide_grads, _beta_from_steps(dbeta, beta.shape))
 
 

@@ -7,10 +7,11 @@ kernels' distance from the position-by-position recurrence at a short length.
         [--chunks 32,64,128] [--heads-per-step 1,2,4] [--iters 5]
         [--out chiprun_out/kda.jsonl]
 
-ms a layer's call (the mean of ``--iters`` after a warm-up), the least time
-``perfbench/harness/flops_kda.kda_kernel_cost`` gives for the work where that
-file is there, and |kernel - recurrence| / |recurrence| of the output and the
-five gradients at ``--check-rows`` positions with bfloat16 operands.
+ms a layer's call (the mean of ``--iters`` after a warm-up) and |kernel -
+recurrence| / |recurrence| of the output and the five gradients at
+``--check-rows`` positions with bfloat16 operands. A pair the chip's compiler
+refuses (a backward whose spilled registers pass the kernel's 16 MiB of VMEM)
+gives a line with its ``error`` and the sweep goes on.
 """
 
 import argparse
@@ -68,19 +69,22 @@ def main():
     lines = []
     rel = lambda a, b: float(jnp.linalg.norm(a.astype(jnp.float32) - b) / jnp.linalg.norm(b))
     ints = lambda text: [int(c) for c in text.split(",")]
+    loss = lambda f: (lambda *a: jnp.sum(f(*a).astype(jnp.float32) ** 2))
+    small, big = operands(args.check_rows, 4), operands(args.rows, args.heads)
+    want = recurrent(*small)
+    want_grads = jax.grad(loss(recurrent), argnums=(0, 1, 2, 3, 4))(*small)
     for chunk, per_step in ((c, h) for c in ints(args.chunks) for h in ints(args.heads_per_step)):
         op = lambda *a: kda.kda(*a, chunk=chunk, interpret=False, heads_per_step=per_step)
-        loss = lambda f: (lambda *a: jnp.sum(f(*a).astype(jnp.float32) ** 2))
-        small = operands(args.check_rows, 4)
-        want = recurrent(*small)
-        want_grads = jax.grad(loss(recurrent), argnums=(0, 1, 2, 3, 4))(*small)
-        got_grads = jax.jit(jax.grad(loss(op), argnums=(0, 1, 2, 3, 4)))(*small)
-        line = {"chunk": chunk, "heads_per_step": per_step, "rows": args.rows, "heads": args.heads,
-                "out_err": rel(jax.jit(op)(*small), want),
-                "grad_err": [rel(a, b) for a, b in zip(got_grads, want_grads)]}
-        big = operands(args.rows, args.heads)
-        line["fwd_ms"] = timed(jax.jit(op), *big)
-        line["fwd_bwd_ms"] = timed(jax.jit(jax.grad(loss(op), argnums=(0, 1, 2, 3, 4))), *big)
+        line = {"chunk": chunk, "heads_per_step": per_step, "rows": args.rows, "heads": args.heads}
+        try:
+            got_grads = jax.jit(jax.grad(loss(op), argnums=(0, 1, 2, 3, 4)))(*small)
+            line["out_err"] = rel(jax.jit(op)(*small), want)
+            line["grad_err"] = [rel(a, b) for a, b in zip(got_grads, want_grads)]
+            line["fwd_ms"] = timed(jax.jit(op), *big)
+            line["fwd_bwd_ms"] = timed(jax.jit(jax.grad(loss(op), argnums=(0, 1, 2, 3, 4))), *big)
+        except jax.errors.JaxRuntimeError as e:  # the compiler's refusal: say it, go on
+            text = " ".join(str(e).split())
+            line["error"] = text if len(text) < 600 else text[:200] + " ... " + text[-350:]
         print(json.dumps(line), flush=True)
         lines.append(line)
     if args.out:

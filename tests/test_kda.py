@@ -12,6 +12,8 @@ chunkwise form that takes its decays relative to the chunk's start overflows
 there, this one forms no exponential of a positive sum.
 """
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -147,36 +149,142 @@ def test_what_the_op_refuses_by_name(change, match):
                 chunk=change.get("chunk", 64), interpret=change.get("interpret"))
 
 
+def decays(chunk, decay, width=8):
+    """g (chunk, width) float32 as ``operands`` makes it, and in float64."""
+    g = operands(seq=chunk, heads=1, width=width, decay=decay)[3][0, :, 0, :]
+    return g, np.asarray(g, np.float64)
+
+
+def sums_directly(g, level=None):
+    """float64: what ``_decay_sums`` sums, a row at a time from g itself. A
+    level (half-size s = 2^level): from its block's middle to an upper row,
+    from after a lower row to the middle; ``None``: the inclusive running sum
+    and the sum over the later rows."""
+    C = g.shape[0]
+    if level is None:
+        return (np.stack([g[:r + 1].sum(0) for r in range(C)]),
+                np.stack([g[r + 1:].sum(0) for r in range(C)]))
+    s = 2 ** level
+    rows = []
+    for r in range(C):
+        middle = (r // (2 * s)) * 2 * s + s
+        rows.append(g[middle:r + 1].sum(0) if r >= middle else g[r + 1:middle].sum(0))
+    return np.stack(rows)
+
+
 @pytest.mark.parametrize("chunk", [2, 16, 64, 128])
-def test_the_levels_cut_the_lower_triangle_once_and_sum_only_decays(chunk):
+def test_the_levels_cut_the_lower_triangle_once_and_halves_change_places(chunk):
     """The quadrants of all levels are the strict lower triangle, each pair
-    once; a row of ``sums`` holds 0s and 1s only, so every exponent the chunk
-    forms is a sum of log-decays, which are <= 0: none is positive. And a
-    pair's two factors cover exactly the positions between them."""
-    levels = kda._levels(chunk)
-    assert set(np.unique(levels.sums)) <= {0.0, 1.0}
-    covered = levels.quadrant.sum(0)
-    np.testing.assert_array_equal(covered, np.tril(np.ones((chunk, chunk)), -1))
-    assert levels.sums.shape == ((2 + int(np.log2(chunk))) * chunk + 8, chunk)
-    for level, quadrant in enumerate(levels.quadrant):
-        rows = levels.sums[(2 + level) * chunk:(3 + level) * chunk]
-        for r, i in zip(*np.nonzero(quadrant)):
-            between = np.zeros(chunk)
-            between[i + 1:r + 1] = 1  # exp(G_r - G_i) is over positions i+1..r
-            np.testing.assert_array_equal(rows[r] + rows[i], between)
-            assert levels.upper[level][r] == 1 and levels.lower[level][i] == 1
+    once, a pair's row in its block's upper half and its column in the lower;
+    and ``_sibling`` hands a row the row of the block's other half (r ^ s),
+    by tiles (s >= 8) and by rolls (below) alike, and undoes itself."""
+    quadrants = kda._quadrants(chunk)
+    np.testing.assert_array_equal(quadrants.sum(0), np.tril(np.ones((chunk, chunk)), -1))
+    rows = jnp.broadcast_to(jnp.arange(chunk, dtype=jnp.float32)[:, None], (chunk, 4))
+    for level, quadrant in enumerate(quadrants):
+        s = 2 ** level
+        upper = np.asarray(kda._in_upper_half((chunk, 1), s))[:, 0]
+        r, i = np.nonzero(quadrant)
+        assert upper[r].all() and not upper[i].any() and (r // (2 * s) == i // (2 * s)).all()
+        np.testing.assert_array_equal(kda._sibling(rows, s)[:, 0], np.arange(chunk) ^ s)
+        np.testing.assert_array_equal(kda._sibling(kda._sibling(rows, s), s), rows)
 
 
-def test_the_sums_of_decays_are_exact_in_three_passes():
-    """``_exact_mm``: 0s and 1s times float32 as three bfloat16 pieces is the
-    float32 sum, where one bfloat16 pass loses 16 of the 24 bits."""
-    g = -jnp.exp(jax.random.normal(jax.random.key(1), (64, 8)) * 2.0)
-    m = jnp.asarray(kda._levels(64).sums)
-    want = np.asarray(m, np.float64) @ np.asarray(g, np.float64)
-    got = kda._exact_mm(m, g, (1, 0))
-    np.testing.assert_allclose(got, want, rtol=2e-6)
-    one_pass = kda._mm(m, g, (1, 0), jnp.bfloat16)
-    assert float(jnp.max(jnp.abs(one_pass - want) / jnp.abs(want).clip(1e-6))) > 1e-3
+@pytest.mark.parametrize("decay", ["seeded", "strongest"])
+@pytest.mark.parametrize("chunk", [2, 16, 64, 128])
+def test_the_sums_of_decays_are_the_float64_sums_and_never_positive(chunk, decay):
+    """Every stage of the scan against float64 sums taken from g a row at a
+    time: the running sum, the sum over the later rows, each level's sums and
+    the chunk's total; none is positive, so no exponent the chunk forms is;
+    and a pair's two factors cover exactly the positions between them."""
+    g, g64 = decays(chunk, decay)
+    running, after, levels, total = kda._decay_sums(g)
+    want_running, want_after = sums_directly(g64)
+    np.testing.assert_allclose(running, want_running, rtol=2e-6)
+    np.testing.assert_allclose(after, want_after, rtol=2e-6)
+    np.testing.assert_allclose(total, g64.sum(0, keepdims=True), rtol=2e-6)
+    assert len(levels) == int(np.log2(chunk))
+    for x in (running, after, total, *levels):
+        assert float(jnp.max(x)) <= 0.0
+    for level, (got, quadrant) in enumerate(zip(levels, kda._quadrants(chunk))):
+        np.testing.assert_allclose(got, sums_directly(g64, level), rtol=2e-6)
+        got = np.asarray(got, np.float64)
+        for r, i in zip(*np.nonzero(quadrant)):  # exp(G_r - G_i) is over positions i+1..r
+            np.testing.assert_allclose(got[r] + got[i], g64[i + 1:r + 1].sum(0), rtol=2e-6)
+
+
+@pytest.mark.parametrize("chunk", [16, 128])
+def test_the_sums_transpose_is_that_of_the_plain_sums(chunk):
+    """``jax.vjp`` of the scan (the halves' exchange and the total's row have
+    their transposes written out) against ``jax.vjp`` of the same sums as 0/1
+    matrices times g."""
+    g, _ = decays(chunk, "seeded")
+    i, t = np.arange(chunk)[:, None], np.arange(chunk)[None, :]
+    matrices = [t <= i, t > i]
+    for level in range(int(np.log2(chunk))):
+        s = 2 ** level
+        middle, up = (i // (2 * s)) * 2 * s + s, (i % (2 * s)) >= s
+        matrices.append(np.where(up, (t >= middle) & (t <= i), (t > i) & (t < middle)))
+    matrices = jnp.asarray(np.stack(matrices), jnp.float32)
+
+    def plain(g):
+        running, after, *levels = jnp.einsum("lrt,td->lrd", matrices, g, precision="highest")
+        return running, after, levels, jnp.sum(g, 0, keepdims=True)
+
+    out, pull_back = jax.vjp(kda._decay_sums, g)
+    want, want_pull_back = jax.vjp(plain, g)
+    keys = iter(jax.random.split(jax.random.key(3), 64))
+    cotangents = jax.tree.map(lambda x: jax.random.normal(next(keys), x.shape), want)
+    assert jax.tree.structure(out) == jax.tree.structure(want)
+    np.testing.assert_allclose(pull_back(cotangents)[0], want_pull_back(cotangents)[0],
+                               rtol=1e-5, atol=1e-5)
+
+
+def _dots(jaxpr):
+    """Every ``dot_general`` of a jaxpr and of the jaxprs inside its equations."""
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "dot_general":
+            yield eqn
+        for param in eqn.params.values():
+            for inner in param if isinstance(param, (tuple, list)) else (param,):
+                inner = getattr(inner, "jaxpr", inner)
+                if hasattr(inner, "eqns"):
+                    yield from _dots(inner)
+
+
+def test_the_chunks_body_forms_no_product_for_its_sums_of_decays():
+    """At the cell's chunk the body's matrix products are q k^T, a level's two
+    (seven levels), the six T - T A T updates' two each, and W, U's two, o's
+    two and S1's: 33, each of operands no taller than the chunk; its ``vjp``
+    adds two transposes a product and no other. (The sums of decays were a
+    1,160-row 0/1 product in three passes, and the same again transposed.)"""
+    C, d = 128, 128
+    q, k, v, g = (x[0, :, 0] for x in operands(seq=C, heads=1, width=d)[:4])
+    body = functools.partial(kda._chunk, jnp.asarray(kda._quadrants(C)), d ** -0.5)
+    args = (q, k, v, g, jnp.full((1, C), 0.5), jnp.zeros((d, d)))
+    forward = list(_dots(jax.make_jaxpr(body)(*args).jaxpr))
+    both = list(_dots(jax.make_jaxpr(
+        lambda *a: jax.vjp(body, *a)[1]((jnp.ones((C, d)), jnp.ones((d, d)))))(*args).jaxpr))
+    assert len(forward) == 33 and len(both) == 3 * 33
+    for eqn in forward + both:
+        assert max(x.aval.shape[0] for x in eqn.invars) <= C, eqn
+
+
+def test_a_kernels_heads_share_one_trace_of_the_chunks_body(monkeypatch):
+    """``_chunk`` is a ``jit``: a kernel's heads find one jaxpr of it (the
+    backward's, under ``jax.vjp``'s trace, a second), and a second program
+    with the same operand traces it no more (a cell's set-up holds the
+    kernels dozens of times: traced a head at a time the scan cost more
+    set-up than the product it replaced)."""
+    products, mm = [], kda._mm  # the body's 33 at chunk 128, 25 at chunk 32: five levels
+    monkeypatch.setattr(kda, "_mm", lambda *a: (products.append(1), mm(*a))[1])
+    args = operands(seq=64, heads=4, width=128)
+    op = lambda *a: kda.kda(*a, chunk=32, interpret=True, heads_per_step=4, scale=0.12345)
+    loss = lambda *a: jnp.sum(op(*a))  # a scale no other test has: the body is traced anew
+    jax.make_jaxpr(jax.grad(loss, argnums=(0, 1, 2, 3, 4)))(*args)
+    assert len(products) == 2 * 25  # not 2 x 4 heads x 25
+    jax.make_jaxpr(jax.value_and_grad(loss, argnums=(0, 3)))(*args)
+    assert len(products) == 2 * 25
 
 
 def test_the_kernels_are_two_calls_by_their_names():

@@ -636,20 +636,25 @@ def test_qk_prologue_partitions_over_a_four_device_data_mesh(v5e_devices):
     assert "bf16[1,4096,4096]" in text  # a chip's own example
 
 
-@pytest.mark.parametrize("chunk", [64, 128])
-def test_the_kda_kernels_compile_at_the_cells_operand(v5e_devices, chunk):
+@pytest.mark.parametrize("chunk,heads_per_step", [(64, 4), (128, 4), (64, 8)])
+def test_the_kda_kernels_compile_at_the_cells_operand(v5e_devices, chunk, heads_per_step):
     """``ops/kda.py`` at the Kimi-Linear cell's operand (one sequence of 16,384
     positions, 32 heads of 128 keys and values): ``kda_fwd`` and ``kda_bwd``,
     whose body is ``jax.vjp`` of the chunk's own body traced into the kernel,
-    so what Mosaic has to take is also every transpose jax makes of it."""
+    so what Mosaic has to take is also every transpose jax makes of it: the
+    scan's sublane rolls and tile moves among them. The pairs are those
+    ``scripts/microbench_kda.py``'s sweep times ((128, 8) is not one: its
+    backward's spilled registers pass the kernel's 16 MiB of VMEM)."""
     from distributed_llm_training_benchmark_framework_tpu.ops import kda
 
+    assert (kda.DEFAULT_CHUNK, kda.HEADS_PER_STEP) == (128, 4)  # the cell's pair is a case
     one = SingleDeviceSharding(v5e_devices[0])
     aval = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype, sharding=one)
     wide = aval((1, 16384, 32, 128), jnp.bfloat16)
 
     def loss(q, k, v, g, beta):
-        return jnp.sum(kda.kda(q, k, v, g, beta, chunk, interpret=False).astype(jnp.float32))
+        out = kda.kda(q, k, v, g, beta, chunk, interpret=False, heads_per_step=heads_per_step)
+        return jnp.sum(out.astype(jnp.float32))
 
     text = _compile(jax.grad(loss, argnums=(0, 1, 2, 3, 4)), wide, wide, wide,
                     aval((1, 16384, 32, 128), jnp.float32), aval((1, 16384, 32), jnp.float32))
