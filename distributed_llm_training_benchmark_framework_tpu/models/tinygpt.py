@@ -1511,31 +1511,15 @@ def _latent_attention(
         ).astype(cd)
 
 
-def _head_columns(heads: int, d: int) -> jax.Array:
-    """(H x d, H) float32 of 0s and 1s: column c belongs to head c // d."""
-    return (jnp.arange(heads * d)[:, None] // d == jnp.arange(heads)[None, :]).astype(jnp.float32)
-
-
-def _head_sums(x: jax.Array, heads: int) -> jax.Array:
-    """(B, S, H x d) float32 -> (B, S, H): each head's sum over its d columns,
-    as a product with 0s and 1s at full precision. A (.., H, d) view of the
-    operand would be another layout on a TPU, and the reshape a copy of it."""
-    return jnp.einsum("bsc,ch->bsh", x, _head_columns(heads, x.shape[-1] // heads),
-                      precision=lax.Precision.HIGHEST)
-
-
-def _over_heads(t: jax.Array, d: int) -> jax.Array:
-    """(B, S, H) float32 -> (B, S, H x d): a head's value over its d columns
-    (``_head_sums``'s transpose, the same way)."""
-    return jnp.einsum("bsh,ch->bsc", t, _head_columns(t.shape[-1], d),
-                      precision=lax.Precision.HIGHEST)
-
-
 def _kda_sublayer(c: TinyGPTConfig, x: jax.Array, layer: Params) -> jax.Array:
     """Norm -> Kimi Delta Attention -> residual: a ``kda`` layer's mixer, in
     three scopes. ``kda_prep``: q = l2norm(silu(conv(h Wq))), k likewise, v =
-    silu(conv(h Wv)), the log-decay a key channel g = -exp(A_log) softplus(Wfb
-    (Wfa h) + dt_bias) and beta = sigmoid(h Wb), g and beta float32.
+    silu(conv(h Wv)) (one projection, then ``ops.kda.qkv_prologue``: on a TPU
+    at whole 128-lane head widths the convolution, SiLU and the l2norms are
+    one Mosaic call a third of the columns, ``kda_conv_fwd``, and one back,
+    ``kda_conv_bwd``; elsewhere XLA's convolution and the ``jnp`` chain), the
+    log-decay a key channel g = -exp(A_log) softplus(Wfb (Wfa h) + dt_bias)
+    and beta = sigmoid(h Wb), g and beta float32.
     ``kda_core``: the recurrence (``ops/kda.py``: the Mosaic kernels on a TPU
     at whole 128-lane head widths, its ``jnp`` path elsewhere). ``kda_out``:
     Wo [RMSNorm over each head's values (one (kda_head_dim,) scale) x
@@ -1555,14 +1539,7 @@ def _kda_sublayer(c: TinyGPTConfig, x: jax.Array, layer: Params) -> jax.Array:
         taps = jnp.moveaxis(layer["kda_conv"], 0, 1).reshape(c.kda_conv, 3 * H * Dk)
         fits = Dk % 128 == 0  # the kernels' widths; else XLA's convolution and the jnp scan
         mode = kda_ops.kernel_mode() if fits else None
-        qkv = jax.nn.silu(kda_ops.causal_conv(qkv, taps, interpret=mode))
-        q, k, v = (qkv[..., i * H * Dk:(i + 1) * H * Dk] for i in range(3))
-
-        def l2norm(t):  # over a head's channels, float32 (the published kernel's eps)
-            tf = t.astype(jnp.float32)
-            return (tf * _over_heads(lax.rsqrt(_head_sums(tf * tf, H) + 1e-6), Dk)).astype(cd)
-
-        q, k = l2norm(q), l2norm(k)
+        q, k, v = kda_ops.qkv_prologue(qkv, taps, H, interpret=mode)
         low = proj("bsd,dr->bsr", h, layer["kda_wfa"].astype(cd)).astype(cd)
         rate = proj("bsr,re->bse", low, layer["kda_wfb"].astype(cd))  # float32
         # every per-channel operand stays (B, S, H x Dk), a head's columns together: on
@@ -1576,7 +1553,8 @@ def _kda_sublayer(c: TinyGPTConfig, x: jax.Array, layer: Params) -> jax.Array:
         low = proj("bsd,dr->bsr", h, layer["kda_wga"].astype(cd)).astype(cd)
         gate = proj("bsr,re->bse", low, layer["kda_wgb"].astype(cd)).astype(cd)
         of = o.astype(jnp.float32)  # RMSNorm over each head's values, one (Dk,) scale
-        of = of * _over_heads(lax.rsqrt(_head_sums(of * of, H) / Dk + c.norm_eps), Dk)
+        of = of * kda_ops.over_heads(
+            lax.rsqrt(kda_ops.head_sums(of * of, H) / Dk + c.norm_eps), Dk)
         of = of * jnp.tile(layer["kda_norm"].astype(jnp.float32), H)
         o = (of * jax.nn.sigmoid(gate.astype(jnp.float32))).astype(cd)
         return x + proj("bse,ed->bsd", o, layer["wo"].astype(cd)).astype(cd)
@@ -1587,7 +1565,10 @@ def kda_stats(config: TinyGPTConfig, seq_len: int) -> Dict[str, Any]:
     from the config and the backend at trace time: ``layers`` of the kind,
     ``chunk`` and ``chunks`` a sequence, ``kernel_calls`` a step by name (one
     forward and one backward a layer where the kernels run: none on the ``jnp``
-    path; remat's second forward is the policy's, not counted), and
+    path; remat's second forward is the policy's, not counted),
+    ``prep_kernel_calls`` the same of ``qkv_prologue``'s two (a call each for
+    q, k and v a layer a direction where the convolution, SiLU and the
+    l2norms are the kernels'; none where they are the ``jnp`` chain), and
     ``saved_state_bytes`` a layer a sequence: the states entering the chunks,
     which the forward keeps for the backward beside its operands."""
     from ..ops import kda as kda_ops
@@ -1597,9 +1578,11 @@ def kda_stats(config: TinyGPTConfig, seq_len: int) -> Dict[str, Any]:
     chunks = seq_len // c.kda_chunk
     kernels = layers if (c.kda_head_dim % 128 == 0
                          and kda_ops.kernel_mode() is not None) else 0
+    prologues = 3 * kernels if kda_ops.conv_fits(seq_len, c.kda_conv, c.kda_head_dim) else 0
     return {
         "layers": layers, "chunk": c.kda_chunk, "chunks": chunks,
         "kernel_calls": {"kda_fwd": kernels, "kda_bwd": kernels},
+        "prep_kernel_calls": {"kda_conv_fwd": prologues, "kda_conv_bwd": prologues},
         "saved_state_bytes": (c.kda_heads * chunks * c.kda_head_dim ** 2
                               * jnp.dtype(c.compute_dtype).itemsize),
     }

@@ -55,10 +55,19 @@ H x d), a head's columns together (``kda_flat``; ``kda`` takes a heads axis
 and reshapes): on a TPU the last two axes of an array are tiled, so a (.., H,
 d) view of such an operand is another layout and every reshape a copy.
 
-Beside it the depthwise causal convolutions that stand in front of the
-recurrence in a KDA layer (``causal_conv``): two more Mosaic calls,
-``kda_conv_fwd`` / ``kda_conv_bwd``, and XLA's grouped convolution as their
-``jnp`` path.
+Beside it what stands between a KDA layer's q, k, v projection and the
+recurrence (``qkv_prologue``): the depthwise causal convolutions over
+positions, SiLU, and each head of q and k over its l2 norm, in two more Mosaic
+calls, ``kda_conv_fwd`` / ``kda_conv_bwd``, a call a third of the columns
+each way. The forward makes all of it on the float32 slab (a head's lanes of
+a tile) it holds after the taps and writes q, k and v once, as three arrays; the backward takes
+their three cotangents, computes the convolution's output, the sigmoid and the
+inverse norms again from x, and writes one dx and the taps' gradient: each
+element of q, k, v is read once and written once a direction, and nothing is
+kept but x. ``causal_conv`` is the same kernels without the epilogue. Their
+``jnp`` path (another backend, or heads that are not whole 128-lane tiles) is
+XLA's grouped convolution and ``silu_l2norm``, which is also what the tests
+hold the kernels to.
 """
 
 from __future__ import annotations
@@ -480,99 +489,301 @@ def kda_flat(q, k, v, g, beta, heads: int, chunk: int = DEFAULT_CHUNK, *,
 
 
 # ---------------------------------------------------------------------------
-# The short convolutions in front of the recurrence.
+# The short convolutions in front of the recurrence, and what stands between
+# them and it: SiLU, and the l2norm of each head of q and k.
 # ---------------------------------------------------------------------------
 
 _CONV_ROWS, _CONV_COLUMNS = 512, 512  # a tile of the convolution's kernels
+L2NORM_EPS = 1e-6  # the published kernel's
 
 
-def _conv_tile(S, C):
-    return math.gcd(S, _CONV_ROWS), math.gcd(C, _CONV_COLUMNS)
+def _head_columns(heads: int, d: int) -> jax.Array:
+    """(H x d, H) float32 of 0s and 1s: column c belongs to head c // d."""
+    return (jnp.arange(heads * d)[:, None] // d == jnp.arange(heads)[None, :]).astype(jnp.float32)
 
 
-def _conv_fwd_kernel(K, x, tail, taps, y, ext):
-    """y_t = sum_i taps_i x_{t-K+1+i} over a (rows, columns) tile: the tile
+def head_sums(x: jax.Array, heads: int) -> jax.Array:
+    """(B, S, H x d) float32 -> (B, S, H): each head's sum over its d columns,
+    as a product with 0s and 1s at full precision. A (.., H, d) view of the
+    operand would be another layout on a TPU, and the reshape a copy of it."""
+    return jnp.einsum("bsc,ch->bsh", x, _head_columns(heads, x.shape[-1] // heads),
+                      precision=lax.Precision.HIGHEST)
+
+
+def over_heads(t: jax.Array, d: int) -> jax.Array:
+    """(B, S, H) float32 -> (B, S, H x d): a head's value over its d columns
+    (``head_sums``'s transpose, the same way)."""
+    return jnp.einsum("bsh,ch->bsc", t, _head_columns(t.shape[-1], d),
+                      precision=lax.Precision.HIGHEST)
+
+
+def silu_l2norm(y, heads: int):
+    """The ``jnp`` chain behind the convolution: y (B, S, 3 x H x d), q's, k's
+    and v's columns in that order -> (q, k, v) (B, S, H x d) each: SiLU on
+    all three, then q and k divided a head by the head's l2 norm in float32
+    (``L2NORM_EPS`` under the root). What ``qkv_prologue``'s kernels are held
+    to, and its path where they do not run."""
+    a = jax.nn.silu(y)
+    width = y.shape[-1] // 3
+    q, k, v = (a[..., i * width:(i + 1) * width] for i in range(3))
+
+    def l2norm(t):
+        tf = t.astype(jnp.float32)
+        inverse = lax.rsqrt(head_sums(tf * tf, heads) + L2NORM_EPS)
+        return (tf * over_heads(inverse, width // heads)).astype(t.dtype)
+
+    return l2norm(q), l2norm(k), v
+
+
+def _conv_tile(S, width, head_dim):
+    """(rows, columns) of a tile over ``width`` columns: whole heads where
+    there are heads."""
+    if head_dim is None:
+        return math.gcd(S, _CONV_ROWS), math.gcd(width, _CONV_COLUMNS)
+    heads = math.gcd(width // head_dim, max(1, _CONV_COLUMNS // head_dim))
+    return math.gcd(S, _CONV_ROWS), heads * head_dim
+
+
+def _window(ext, taps, K, start, rows, lanes):
+    """x and y = sum_i taps_i x_{t-K+1+i}, float32, over ``rows`` rows from
+    ``start`` (a multiple of 8) and the lanes ``lanes`` of the tile that
+    ``ext`` holds behind 8 rows: one aligned read of the rows and the 8 before
+    them, the K offsets taken of the value (there is no read at an offset that
+    is not whole 8-row tiles from a start known only at run time)."""
+    window = ext[pl.ds(start, rows + 8), lanes]
+    x = window[8:]
+    y = x * taps[K - 1:K, lanes]
+    for i in range(K - 1):
+        y = y + window[8 - (K - 1 - i):8 - (K - 1 - i) + rows] * taps[i:i + 1, lanes]
+    return x, y
+
+
+def _sigmoid(y):
+    """1 / (1 + exp(-y)) in float32: the reciprocal as the EUP's approximation
+    and two Newton steps, each of which squares its error (whatever the
+    approximation's bits: 8 would do). The denominator lies in [1, 1 + e^80],
+    so the infinities and NaNs that Mosaic's own division spends a dozen more
+    vector operations an element on cannot come."""
+    d = 1.0 + jnp.exp(-jnp.maximum(y, -80.0))
+    r = pl.reciprocal(d, approx=True)
+    r = r * (2.0 - d * r)
+    return r * (2.0 - d * r)
+
+
+def _lane_sum(x):
+    """The sum over the lanes of a float32 (rows, d), as a column that
+    multiplies back over the lanes: the XLU's lane reduction, float32 adds.
+    (As three exact bfloat16 terms against a matrix of ones on the idle MXU,
+    which is what ``ops/rotary.py`` found faster in its pass, these kernels
+    read 1.99 | 6.90 ms a layer forward | forward + backward where this reads
+    1.67 | 5.94, on a v5e at (1, 16384, 12288): the terms' splits are vector
+    work, and vector work is these kernels' bound.)"""
+    return jnp.sum(x, axis=-1, keepdims=True)
+
+
+def _over_slabs(columns, head_dim, body):
+    """``body(lanes)`` for a tile's columns a head at a time (128 lanes where
+    there are no heads): a loop, so that a kernel holds one head's code."""
+    d = head_dim or 128
+    lax.fori_loop(0, columns // d, lambda j, _: body(pl.ds(pl.multiple_of(j * d, d), d)), None)
+
+
+def _pass_rows(rows, norm):
+    """Rows of a head's slab a pass of the kernels takes from its read to its
+    write. Under the norm all of the tile's: the lane reduction in the middle
+    of the chain makes the compiler walk it a vreg at a time. Without it a
+    (512, 128) value is 64 vregs that every elementwise operation stores and
+    loads again (by its schedule for a v5e 3,616 bundles a tile forward where
+    128 rows a pass read 2,613, and q's and k's tiles 3,037 whole where they
+    read 4,187 in passes of 128)."""
+    return rows if norm else math.gcd(rows, 128)
+
+
+def _conv_fwd_kernel(K, head_dim, norm, x, tail, taps, y, ext):
+    """A (rows, columns) tile of y_t = sum_i taps_i x_{t-K+1+i}: the tile
     behind its 8 preceding rows (zeros before the sequence) in ``ext``, read
-    back at the K row offsets."""
-    rows = x.shape[0]
+    back a head's lanes at a time. With ``head_dim`` the epilogue on the
+    float32 slab: a = silu(y), and under ``norm`` the head's a (sum of a^2
+    over its lanes + eps)^-1/2; one cast, one write."""
+    rows, columns = x.shape
     ext[0:8, :] = jnp.where(pl.program_id(2) == 0, 0.0, tail[...].astype(jnp.float32))
     ext[8:, :] = x[...].astype(jnp.float32)
-    acc = ext[8:, :] * taps[K - 1:K, :]
-    for i in range(K - 1):
-        acc = acc + ext[pl.ds(8 - (K - 1 - i), rows), :] * taps[i:i + 1, :]
-    y[...] = acc.astype(y.dtype)
+    n = _pass_rows(rows, norm)
+
+    def slab(lanes):
+        def rows_from(i, _):
+            start = pl.multiple_of(i * n, n)
+            _, a = _window(ext, taps, K, start, n, lanes)
+            if head_dim is not None:
+                a = a * _sigmoid(a)
+                if norm:
+                    a = a * lax.rsqrt(_lane_sum(a * a) + L2NORM_EPS)
+            y[pl.ds(start, n), lanes] = a.astype(y.dtype)
+
+        lax.fori_loop(0, rows // n, rows_from, None)
+
+    _over_slabs(columns, head_dim, slab)
 
 
-def _conv_bwd_kernel(K, x, tail, dy, head, taps, dx, dtaps, ext, dext):
-    """The transposes: dx_t = sum_i taps_i dy_{t+K-1-i} (the tile before its 8
-    following rows, zeros after the sequence), and dtaps_i = sum over the
-    positions of x_{t-K+1+i} dy_t, summed into a block that stays resident
-    over the batch and the tiles of rows."""
-    rows = x.shape[0]
+def _conv_bwd_kernel(K, head_dim, norm, *refs):
+    """The transposes over a tile: dx_t = sum_i taps_i dy_{t+K-1-i} (the tile
+    before its 8 following rows, zeros after the sequence), and dtaps_i = sum
+    over the positions of x_{t-K+1+i} dy_t, summed into a block that stays
+    resident over the batch and the tiles of rows. A head's slab is walked
+    from its last rows to its first, each pass handing the next its first 8
+    rows of dy. With ``head_dim`` the cotangent that comes in is the
+    epilogue's output's, and dy is made of it here, on the tile's rows and the
+    8 after them: y, the sigmoid s and a head's inverse norm r are computed
+    again from x (its 8 rows after the tile come in too), da = r (dn - a r^2
+    sum_head(dn a)) under ``norm`` (dn otherwise), dy = da (s + a (1 - s))."""
+    if head_dim is None:
+        (x, tail, taps, dy, head), (dx, dtaps, ext) = refs[:5], refs[-3:]
+    else:
+        (x, tail, taps, after, dy, head), (dx, dtaps, ext) = refs[:6], refs[-3:]
+    rows, columns = x.shape
     first = (pl.program_id(1) == 0) & (pl.program_id(2) == 0)
     last = pl.program_id(2) == pl.num_programs(2) - 1
     ext[0:8, :] = jnp.where(pl.program_id(2) == 0, 0.0, tail[...].astype(jnp.float32))
-    ext[8:, :] = x[...].astype(jnp.float32)
-    dyf = dy[...].astype(jnp.float32)
-    dext[0:rows, :] = dyf
-    dext[rows:, :] = jnp.where(last, 0.0, head[...].astype(jnp.float32))
+    ext[8:rows + 8, :] = x[...].astype(jnp.float32)
+    if head_dim is not None:  # any finite rows do after the sequence's end: their dy is 0
+        ext[rows + 8:, :] = after[...].astype(jnp.float32)
 
     @pl.when(first)
     def _():
         dtaps[...] = jnp.zeros_like(dtaps)
 
-    acc = dyf * taps[K - 1:K, :]
-    for i in range(K):
-        d = K - 1 - i
-        if d:
-            acc = acc + dext[pl.ds(d, rows), :] * taps[i:i + 1, :]
-        shifted = ext[pl.ds(8 - d, rows), :]
-        dtaps[i:i + 1, :] += jnp.sum(shifted * dyf, axis=0, keepdims=True)
-    dx[...] = acc.astype(dx.dtype)
+    n = _pass_rows(rows, norm)
+
+    def slab(lanes):
+        def pulled_back(start, count, d):
+            """x and dy over ``count`` rows from ``start``, of the cotangent that came in."""
+            if head_dim is None:
+                return ext[pl.ds(start + 8, count), lanes], d
+            x, y = _window(ext, taps, K, start, count, lanes)
+            s = _sigmoid(y)
+            a = y * s
+            if norm:
+                r = lax.rsqrt(_lane_sum(a * a) + L2NORM_EPS)
+                d = r * (d - a * (r * r * _lane_sum(d * a)))
+            return x, d * (s + a * (1.0 - s))
+
+        def rows_from(i, after_d):
+            start = pl.multiple_of((rows // n - 1 - i) * n, n)
+            x, d = pulled_back(start, n, dy[pl.ds(start, n), lanes].astype(jnp.float32))
+            # dy_{t+K-1-j} for the K taps j: the pass's dy before the 8 rows after it
+            window = jnp.concatenate([d, after_d], axis=0)
+            later = [window[K - 1 - j:K - 1 - j + n] for j in range(K)]
+            acc = later[K - 1] * taps[K - 1:K, lanes]
+            for j in range(K - 1):
+                acc = acc + later[j] * taps[j:j + 1, lanes]
+            dx[pl.ds(start, n), lanes] = acc.astype(dx.dtype)
+            # x_u dy_{u+K-1-j} over the tile's u: over all tiles, every pair of dtaps_j once
+            for j in range(K):
+                dtaps[j:j + 1, lanes] += jnp.sum(x * later[j], axis=0, keepdims=True)
+            return d[0:8]
+
+        after_d = jnp.where(last, 0.0, head[:, lanes].astype(jnp.float32))
+        if head_dim is not None:
+            _, after_d = pulled_back(rows, 8, after_d)
+        lax.fori_loop(0, rows // n, rows_from, after_d)
+
+    _over_slabs(columns, head_dim, slab)
 
 
-def _conv_specs(S, K, rows, columns):
-    """Blocks over a grid of (tiles of columns, batch, tiles of rows): a tile, the
-    8 rows before it and the 8 after it (clamped at the sequence's ends, where
-    the kernels put zeros), and the taps of the tile's columns."""
-    tile = pl.BlockSpec((None, rows, columns), lambda c, b, n: (b, n, c))
-    before = pl.BlockSpec((None, 8, columns),
-                          lambda c, b, n: (b, jnp.maximum(n * (rows // 8) - 1, 0), c))
-    after = pl.BlockSpec((None, 8, columns),
-                         lambda c, b, n: (b, jnp.minimum((n + 1) * (rows // 8), S // 8 - 1), c))
-    taps = pl.BlockSpec((K, columns), lambda c, b, n: (0, c))
-    return tile, before, after, taps
-
-
-def _conv_forward(x, taps, interpret):
-    (B, S, C), K = x.shape, taps.shape[0]
-    rows, columns = _conv_tile(S, C)
-    tile, before, _, taps_spec = _conv_specs(S, K, rows, columns)
+@functools.lru_cache(maxsize=64)
+def _conv_call(backward, B, S, width, part, parts, K, dtype, head_dim, norm, interpret):
+    """One direction's ``pallas_call`` over ``width`` columns from column
+    ``part`` x ``width`` of an x of ``parts`` x ``width``, made once a process
+    (as ``flash_attention._forward_call``, and for its reason). A grid of
+    (tiles of columns, batch, tiles of rows); a tile of x comes with the 8
+    rows before it and the backward's cotangent with the 8 after it (clamped
+    at the sequence's ends, where the kernels put zeros). Operands: forward
+    (x, x, taps) -> y (B, S, width); backward (x, x, taps, [x,] dy, dy[, dx so
+    far]) -> (dx (B, S, parts x width), of which this call writes its part's
+    columns and keeps the rest of ``dx so far``, aliased; dtaps (K, width))."""
+    rows, columns = _conv_tile(S, width, head_dim)
+    offset = part * (width // columns)
+    tile = lambda at: pl.BlockSpec((None, rows, columns), lambda c, b, n: (b, n, c + at))
+    eight = lambda row, at: pl.BlockSpec((None, 8, columns), lambda c, b, n: (b, row(n), c + at))
+    before = lambda n: jnp.maximum(n * (rows // 8) - 1, 0)
+    after = lambda n: jnp.minimum((n + 1) * (rows // 8), S // 8 - 1)
+    taps = lambda at: pl.BlockSpec((K, columns), lambda c, b, n: (0, c + at))
+    grid = (width // columns, B, S // rows)
+    fused = head_dim is not None
+    if not backward:
+        return pl.pallas_call(
+            functools.partial(_conv_fwd_kernel, K, head_dim, norm), grid=grid,
+            in_specs=[tile(offset), eight(before, offset), taps(offset)], out_specs=tile(0),
+            out_shape=jax.ShapeDtypeStruct((B, S, width), dtype),
+            scratch_shapes=[pltpu.VMEM((rows + 8, columns), jnp.float32)],
+            interpret=interpret, name="kda_conv_fwd",
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("parallel", "parallel", "parallel")),
+        )
+    in_specs = [tile(offset), eight(before, offset), taps(offset)] + (
+        [eight(after, offset)] if fused else []) + [tile(0), eight(after, 0)] + (
+        [pl.BlockSpec(memory_space=pl.ANY)] if part else [])
     return pl.pallas_call(
-        functools.partial(_conv_fwd_kernel, K), grid=(C // columns, B, S // rows),
-        in_specs=[tile, before, taps_spec], out_specs=tile,
-        out_shape=jax.ShapeDtypeStruct(x.shape, x.dtype),
-        scratch_shapes=[pltpu.VMEM((rows + 8, columns), jnp.float32)],
-        interpret=interpret, name="kda_conv_fwd",
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "parallel")),
-    )(x, x, taps.astype(jnp.float32))
-
-
-def _conv_backward(x, taps, dy, interpret):
-    (B, S, C), K = x.shape, taps.shape[0]
-    rows, columns = _conv_tile(S, C)
-    tile, before, after, taps_spec = _conv_specs(S, K, rows, columns)
-    return pl.pallas_call(
-        functools.partial(_conv_bwd_kernel, K), grid=(C // columns, B, S // rows),
-        in_specs=[tile, before, tile, after, taps_spec], out_specs=[tile, taps_spec],
-        out_shape=[jax.ShapeDtypeStruct(x.shape, x.dtype),
-                   jax.ShapeDtypeStruct(taps.shape, jnp.float32)],
-        scratch_shapes=[pltpu.VMEM((rows + 8, columns), jnp.float32)] * 2,
+        functools.partial(_conv_bwd_kernel, K, head_dim, norm), grid=grid,
+        in_specs=in_specs, out_specs=[tile(offset), taps(0)],
+        out_shape=[jax.ShapeDtypeStruct((B, S, parts * width), dtype),
+                   jax.ShapeDtypeStruct((K, width), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((rows + 8 + 8 * fused, columns), jnp.float32)],
+        input_output_aliases={len(in_specs) - 1: 0} if part else {},
         interpret=interpret, name="kda_conv_bwd",
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary", "arbitrary")),
-    )(x, x, dy, dy, taps.astype(jnp.float32))
+    )
+
+
+def _conv_parts(head_dim):
+    """The calls a direction over x's columns, as each one's ``norm``: one
+    over all of them for the bare convolution; q's, k's and v's thirds apart
+    under the epilogue, which normalises the first two."""
+    return (False,) if head_dim is None else (True, True, False)
+
+
+def _conv_run(backward, part, head_dim, interpret, x, taps, *cotangent):
+    norms = _conv_parts(head_dim)
+    (B, S, C), K = x.shape, taps.shape[0]
+    call = _conv_call(backward, B, S, C // len(norms), part, len(norms), K, x.dtype,
+                      head_dim, norms[part], interpret)
+    # one trace for the primal and the forward rule: see flash_attention._flash_forward
+    with jax.sharding.use_abstract_mesh(jax.sharding.get_abstract_mesh()):
+        return call(x, x, taps, *cotangent)
+
+
+def _conv_forward(x, taps, head_dim, interpret):
+    return [_conv_run(False, part, head_dim, interpret, x, taps)
+            for part in range(len(_conv_parts(head_dim)))]
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3))
+def _conv(x, taps, head_dim, interpret):
+    """x (B, S, C), taps (K, C) float32 -> [a part's (B, S, C / parts)]."""
+    return _conv_forward(x, taps, head_dim, interpret)
+
+
+def _conv_fwd(x, taps, head_dim, interpret):
+    return _conv_forward(x, taps, head_dim, interpret), (x, taps)
+
+
+def _conv_bwd(head_dim, interpret, residuals, cotangents):
+    """A call a part: each writes its columns of the one dx, which the next
+    takes aliased, so that neither the cotangents nor the parts of dx are
+    ever set side by side in a copy."""
+    x, taps = residuals
+    after = () if head_dim is None else (x,)
+    so_far, dtaps = (), []
+    for part, dy in enumerate(cotangents):
+        dx, dtaps_part = _conv_run(
+            True, part, head_dim, interpret, x, taps, *after, dy, dy, *so_far)
+        so_far = (dx,)
+        dtaps.append(dtaps_part)
+    return dx, jnp.concatenate(dtaps, axis=-1)
+
+
+_conv.defvjp(_conv_fwd, _conv_bwd)
 
 
 def _conv_reference(x, taps):
@@ -582,35 +793,48 @@ def _conv_reference(x, taps):
         dimension_numbers=("NWC", "WIO", "NWC"), feature_group_count=C)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(2,))
-def _causal_conv(x, taps, interpret):
-    return _conv_forward(x, taps, interpret)
-
-
-def _causal_conv_fwd(x, taps, interpret):
-    return _conv_forward(x, taps, interpret), (x, taps)
-
-
-def _causal_conv_bwd(interpret, residuals, dy):
-    x, taps = residuals
-    dx, dtaps = _conv_backward(x, taps, dy, interpret)
-    return dx, dtaps.astype(taps.dtype)
-
-
-_causal_conv.defvjp(_causal_conv_fwd, _causal_conv_bwd)
+def conv_fits(seq_len: int, taps: int, width: int) -> bool:
+    """Whether the convolution's kernels take the operand: K <= 8 taps, S in
+    whole 8-row tiles, and ``width`` (the columns, or a head's where the
+    epilogue runs) in whole 128-lane tiles."""
+    return taps <= 8 and seq_len % 8 == 0 and width % 128 == 0
 
 
 def causal_conv(x, taps, *, interpret: Optional[bool] = None):
-    """The depthwise causal convolution over positions in front of the
-    recurrence: x (B, S, C) in the compute type, taps (K, C), y_t = sum_i
-    taps_i x_{t-K+1+i} with zeros before the sequence; float32 products and
-    sums, x's dtype out. ``interpret`` as ``kda_flat``'s: None is XLA's grouped
-    convolution (any backend, any width; on a TPU it takes minutes to compile
-    at 12,288 groups, which is why the kernels exist), False the two Mosaic
-    calls ``kda_conv_fwd`` / ``kda_conv_bwd``, which take K <= 8, S in whole
-    8-row tiles and C in whole 128-lane tiles."""
-    (_, S, C), K = x.shape, taps.shape[0]
-    if interpret is None or K > 8 or S % 8 or C % 128:
+    """The depthwise causal convolution over positions, bare: x (B, S, C) in
+    the compute type, taps (K, C), y_t = sum_i taps_i x_{t-K+1+i} with zeros
+    before the sequence; float32 products and sums, x's dtype out.
+    ``interpret`` as ``kda_flat``'s: None is XLA's grouped convolution (any
+    backend, any width; on a TPU it takes minutes to compile at 12,288 groups,
+    which is why the kernels exist), False the two Mosaic calls
+    ``kda_conv_fwd`` / ``kda_conv_bwd`` without their epilogue, where
+    ``conv_fits``. A KDA layer calls ``qkv_prologue``; this is the same
+    kernels' first half, kept for the tests and ``scripts/microbench_kda.py
+    --prep``, whose baseline is this and the ``jnp`` chain behind it."""
+    if interpret is None or not conv_fits(x.shape[1], taps.shape[0], x.shape[2]):
         return _conv_reference(x, taps)
-    return _causal_conv(x, taps, interpret)
+    return _conv(x, taps.astype(jnp.float32), None, interpret)[0]
 
+
+def qkv_prologue(x, taps, heads: int, *, interpret: Optional[bool] = None):
+    """What stands between a KDA layer's q, k, v projection and the
+    recurrence, in one pass a direction: x (B, S, 3 x H x d) in the compute
+    type, q's, k's and v's columns in that order, taps (K, 3 x H x d) ->
+    (q, k, v), (B, S, H x d) each: the depthwise causal convolution
+    (``causal_conv``), SiLU, and on q and k each head divided by its l2 norm
+    (``L2NORM_EPS`` under the root). ``interpret`` False (True: interpreted)
+    where ``conv_fits`` at the head's width: ``kda_conv_fwd`` computes all of
+    it on the float32 slab it holds after the taps (the sum of squares a
+    float32 lane reduction; nothing is rounded to the compute type before the
+    one cast at the end) and ``kda_conv_bwd`` takes the three cotangents back
+    to dx and dtaps, computing y, the sigmoid and the inverse norms again
+    from x: nothing is kept but x, and each element of q, k, v is read once
+    and written once a direction. A call a third of the columns (q, k, v):
+    three arrays out, three cotangents in, no slice and no concatenation.
+    None, or an operand the kernels do not take: XLA's grouped convolution
+    and ``silu_l2norm``, the ``jnp`` chain, which is also what the tests hold
+    the kernels to."""
+    head_dim = x.shape[-1] // (3 * heads)
+    if interpret is None or not conv_fits(x.shape[1], taps.shape[0], head_dim):
+        return silu_l2norm(_conv_reference(x, taps), heads)
+    return tuple(_conv(x, taps.astype(jnp.float32), head_dim, interpret))

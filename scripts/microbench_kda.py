@@ -7,6 +7,14 @@ kernels' distance from the position-by-position recurrence at a short length.
         [--chunks 32,64,128] [--heads-per-step 1,2,4] [--iters 5]
         [--out chiprun_out/kda.jsonl]
 
+``--prep``: what stands between a layer's q, k, v projection and the recurrence
+instead, at (1, rows, 3 x heads x 128): the chain as it ran before the
+convolution's kernels had an epilogue (``causal_conv``'s two calls, then
+``silu_l2norm``, XLA's ``jnp`` chain) against ``qkv_prologue``'s calls, a line
+each: ms a layer forward and forward + backward, the GB/s of the bytes one pass
+needs (x in and q, k, v out; x and three cotangents in and dx out), and
+``out_err`` / ``grad_err`` against the chain in float32 on the same operands.
+
 ms a layer's call (the mean of ``--iters`` after a warm-up) and |kernel -
 recurrence| / |recurrence| of the output and the five gradients at
 ``--check-rows`` positions with bfloat16 operands. A pair the chip's compiler
@@ -32,6 +40,7 @@ def main():
     ap.add_argument("--heads-per-step", default="1,2,4")
     ap.add_argument("--iters", type=int, default=5)
     ap.add_argument("--check-rows", type=int, default=1024)
+    ap.add_argument("--prep", action="store_true", help="the chain in front of the recurrence")
     ap.add_argument("--out")
     args = ap.parse_args()
 
@@ -69,6 +78,9 @@ def main():
     lines = []
     rel = lambda a, b: float(jnp.linalg.norm(a.astype(jnp.float32) - b) / jnp.linalg.norm(b))
     ints = lambda text: [int(c) for c in text.split(",")]
+    if args.prep:
+        lines = prep_lines(args, timed, rel)
+        return write(args.out, lines)
     loss = lambda f: (lambda *a: jnp.sum(f(*a).astype(jnp.float32) ** 2))
     small, big = operands(args.check_rows, 4), operands(args.rows, args.heads)
     want = recurrent(*small)
@@ -87,9 +99,60 @@ def main():
             line["error"] = text if len(text) < 600 else text[:200] + " ... " + text[-350:]
         print(json.dumps(line), flush=True)
         lines.append(line)
-    if args.out:
-        os.makedirs(os.path.dirname(args.out), exist_ok=True)
-        with open(args.out, "w") as f:
+    write(args.out, lines)
+
+
+def prep_lines(args, timed, rel):
+    import jax
+    import jax.numpy as jnp
+
+    from distributed_llm_training_benchmark_framework_tpu.ops import kda
+
+    H, d, K = args.heads, 128, 4
+
+    def operands(S, heads, dtype=jnp.bfloat16):
+        ks = jax.random.split(jax.random.PRNGKey(0), 2)
+        x = jax.random.normal(ks[0], (1, S, 3 * heads * d)).astype(dtype)
+        bound = K ** -0.5  # the model's initialisation
+        return x, jax.random.uniform(ks[1], (K, 3 * heads * d), minval=-bound, maxval=bound)
+
+    def float32_chain(heads):  # tap by tap, SiLU, the l2norms: no kernel, nothing rounded
+        def f(x, taps):
+            S = x.shape[1]
+            xf = jnp.pad(x.astype(jnp.float32), ((0, 0), (K - 1, 0), (0, 0)))
+            return kda.silu_l2norm(sum(xf[:, i:i + S] * taps[i] for i in range(K)), heads)
+        return f
+
+    paths = {
+        "parent_chain": lambda heads: lambda x, taps: kda.silu_l2norm(
+            kda.causal_conv(x, taps, interpret=False), heads),
+        "qkv_prologue": lambda heads: lambda x, taps: kda.qkv_prologue(
+            x, taps, heads, interpret=False),
+    }
+    loss = lambda f: lambda x, taps: sum(jnp.sum(o.astype(jnp.float32) ** 2) for o in f(x, taps))
+    small, big = operands(args.check_rows, 4), operands(args.rows, H)
+    want = float32_chain(4)(*small)
+    want_grads = jax.grad(loss(float32_chain(4)), argnums=(0, 1))(*small)
+    third = args.rows * H * d * 2  # bytes of one of q, k, v in bfloat16
+    lines = []
+    for name, path in paths.items():
+        line = {"path": name, "rows": args.rows, "heads": H}
+        grads = jax.jit(jax.grad(loss(path(4)), argnums=(0, 1)))(*small)
+        line["out_err"] = [rel(a, b) for a, b in zip(jax.jit(path(4))(*small), want)]
+        line["grad_err"] = [rel(a, b) for a, b in zip(grads, want_grads)]
+        line["fwd_ms"] = timed(jax.jit(path(H)), *big)
+        line["fwd_bwd_ms"] = timed(jax.jit(jax.grad(loss(path(H)), argnums=(0, 1))), *big)
+        line["fwd_gb_s"] = 6 * third / line["fwd_ms"] / 1e6
+        line["bwd_gb_s"] = 9 * third / (line["fwd_bwd_ms"] - line["fwd_ms"]) / 1e6
+        print(json.dumps(line), flush=True)
+        lines.append(line)
+    return lines
+
+
+def write(out, lines):
+    if out:
+        os.makedirs(os.path.dirname(out), exist_ok=True)
+        with open(out, "w") as f:
             f.writelines(json.dumps(line) + "\n" for line in lines)
 
 

@@ -18,6 +18,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax import lax
 
 from distributed_llm_training_benchmark_framework_tpu.ops import kda
 from perfbench.harness import reference_kda
@@ -240,16 +241,25 @@ def test_the_sums_transpose_is_that_of_the_plain_sums(chunk):
                                rtol=1e-5, atol=1e-5)
 
 
-def _dots(jaxpr):
-    """Every ``dot_general`` of a jaxpr and of the jaxprs inside its equations."""
+def _equations(jaxpr, scope=""):
+    """(name stack, equation) for every equation of a jaxpr and of the jaxprs
+    inside its equations (whose name stacks are relative to it), a
+    ``pallas_call``'s body aside."""
     for eqn in jaxpr.eqns:
-        if eqn.primitive.name == "dot_general":
-            yield eqn
+        path = f"{scope}/{eqn.source_info.name_stack}"
+        yield path, eqn
+        if eqn.primitive.name == "pallas_call":
+            continue
         for param in eqn.params.values():
             for inner in param if isinstance(param, (tuple, list)) else (param,):
                 inner = getattr(inner, "jaxpr", inner)
                 if hasattr(inner, "eqns"):
-                    yield from _dots(inner)
+                    yield from _equations(inner, path)
+
+
+def _dots(jaxpr):
+    """Every ``dot_general`` of a jaxpr and of the jaxprs inside its equations."""
+    return (eqn for _, eqn in _equations(jaxpr) if eqn.primitive.name == "dot_general")
 
 
 def test_the_chunks_body_forms_no_product_for_its_sums_of_decays():
@@ -325,3 +335,157 @@ def test_the_convolution_takes_any_width_on_its_jnp_path():
     assert "pallas_call" not in str(jax.make_jaxpr(
         lambda x, t: kda.causal_conv(x, t, interpret=True))(x, taps))
     assert float(jnp.abs(got[:, 0] - x[:, 0] * taps[3]).max()) < 1e-6  # zeros before the sequence
+
+
+# ``qkv_prologue``: the convolution's kernels with their epilogue.
+
+def prologue_operands(seq, dtype, heads=4, width=128, batch=2):
+    """x as a projection's output with q's, k's and v's columns in that order,
+    every head at a scale of its own (1 to 100: a head's sum that took in a
+    neighbour's lanes would be another number by orders), taps, and a weight a
+    result for the loss."""
+    ks = jax.random.split(jax.random.key(3), 5)
+    scale = jnp.repeat(10.0 ** jax.random.uniform(ks[0], (3 * heads,), maxval=2.0), width)
+    x = (jax.random.normal(ks[1], (batch, seq, 3 * heads * width)) * scale).astype(dtype)
+    taps = jax.random.uniform(ks[2], (4, 3 * heads * width), minval=-0.5, maxval=0.5)
+    shape = (batch, seq, heads * width)
+    return x, taps, [jax.random.normal(k, shape) for k in jax.random.split(ks[3], 3)]
+
+
+def chain(x, taps, heads):
+    """What stands between the projection and the recurrence, written out in
+    float32: y_t = sum_i taps_i x_{t-3+i} tap by tap with zeros before the
+    sequence, SiLU, and on q's and k's thirds each head over its l2 norm with
+    1e-6 under the root; v's third SiLU alone."""
+    (B, S, C), K = x.shape, taps.shape[0]
+    xf = jnp.pad(x.astype(jnp.float32), ((0, 0), (K - 1, 0), (0, 0)))
+    y = sum(xf[:, i:i + S] * taps[i] for i in range(K))
+    a = (y * jax.nn.sigmoid(y)).reshape(B, S, 3, heads, -1)
+    unit = a * lax.rsqrt(jnp.sum(a * a, axis=-1, keepdims=True) + 1e-6)
+    return tuple(t.reshape(B, S, -1) for t in (unit[:, :, 0], unit[:, :, 1], a[:, :, 2]))
+
+
+def prologue_results(f, x, taps, weights):
+    """(q, k, v, dx, dtaps) of ``f`` under a loss that weighs every element."""
+    loss = lambda x, t: sum(jnp.sum(o.astype(jnp.float32) * w) for o, w in zip(f(x, t), weights))
+    return (*f(x, taps), *jax.grad(loss, (0, 1))(x, taps))
+
+
+@pytest.mark.parametrize("dtype,tolerance", [(jnp.float32, TOLERANCE), (jnp.bfloat16, 6e-3)],
+                         ids=["float32", "bfloat16"])
+@pytest.mark.parametrize("seq", [512, 1536])
+def test_the_prologues_kernels_are_the_chain_and_its_gradients(seq, dtype, tolerance):
+    """``kda_conv_fwd`` / ``kda_conv_bwd`` with their epilogue (interpreted)
+    against the chain written out, over one tile of rows and over three (the
+    first tile's zero history; the backward's 8 rows after a tile, computed
+    again from x, and zeros after the last one), four heads of 128 lanes a
+    tile of columns at scales of their own, a batch of two: q, k, v, and the
+    gradients by x and by the taps. With bfloat16 operands the kernels round
+    once, at the end, and the gradient by x once: within that rounding of the
+    float32 chain on the same operands."""
+    x, taps, weights = prologue_operands(seq, dtype)
+    want = prologue_results(lambda x, t: chain(x, t, 4), x.astype(jnp.float32), taps, weights)
+    got = prologue_results(lambda x, t: kda.qkv_prologue(x, t, 4, interpret=True), x, taps, weights)
+    for name, a, b in zip(("q", "k", "v", "dx", "dtaps"), got, want):
+        assert a.shape == b.shape and a.dtype == (taps.dtype if name == "dtaps" else dtype), name
+        assert relative(a, b) < tolerance, (name, relative(a, b))
+    # a head of q and of k is a unit vector, the largest head (100 x) as the least
+    norms = jnp.linalg.norm(got[0].astype(jnp.float32).reshape(2, seq, 4, 128), axis=-1)
+    assert float(jnp.abs(norms - 1.0).max()) < (1e-5 if dtype == jnp.float32 else 4e-3)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16], ids=["float32", "bfloat16"])
+def test_the_prologues_jnp_path_is_the_same_chain(dtype):
+    """``interpret=None``: XLA's convolution, ``jax.nn.silu`` and the l2norm
+    as products with 0s and 1s at full precision, each step rounded to the
+    operands' type as a model's ``jnp`` chain rounds."""
+    x, taps, weights = prologue_operands(256, dtype)
+    want = prologue_results(lambda x, t: chain(x, t, 4), x.astype(jnp.float32), taps, weights)
+    got = prologue_results(lambda x, t: kda.qkv_prologue(x, t, 4), x, taps, weights)
+    for name, a, b in zip(("q", "k", "v", "dx", "dtaps"), got, want):
+        assert relative(a, b) < (TOLERANCE if dtype == jnp.float32 else 2e-2), name
+
+
+def test_the_prologue_is_a_call_a_third_each_way_and_keeps_x_alone():
+    """q, k and v leave ``kda_conv_fwd`` as three arrays and their cotangents
+    enter ``kda_conv_bwd`` as three: a call a third, the three backward calls
+    writing one dx (each takes the one before it aliased); no slice and no
+    concatenation of anything as wide as a third, and nothing kept for the
+    backward but the operands."""
+    x, taps, weights = prologue_operands(512, jnp.bfloat16, batch=1)
+    f = lambda x, t: kda.qkv_prologue(x, t, 4, interpret=True)
+    loss = lambda x, t: sum(jnp.sum(o.astype(jnp.float32) * w) for o, w in zip(f(x, t), weights))
+    text = str(jax.make_jaxpr(jax.grad(loss, (0, 1)))(x, taps))
+    assert text.count("name=kda_conv_fwd") == 3 and text.count("name=kda_conv_bwd") == 3
+    assert text.count("input_output_aliases=((6, 0),)") == 2
+    wide = [eqn for eqn in jax.make_jaxpr(jax.grad(loss, (0, 1)))(x, taps).jaxpr.eqns
+            if eqn.primitive.name in ("slice", "concatenate", "dynamic_slice", "pad")
+            and max(v.aval.size for v in eqn.outvars) >= x.size // 3]
+    assert not wide, wide
+    _, residuals = jax.vjp(f, x, taps)
+    kept = {leaf.shape for leaf in jax.tree.leaves(residuals)}
+    assert kept == {x.shape, taps.shape}, kept
+
+
+def test_the_prologue_falls_back_where_a_head_is_not_whole_lanes():
+    x, taps, weights = prologue_operands(64, jnp.float32, heads=2, width=48)
+    f = lambda x, t: kda.qkv_prologue(x, t, 2, interpret=True)
+    assert "pallas_call" not in str(jax.make_jaxpr(f)(x, taps))
+    want = chain(x, taps, 2)
+    for a, b in zip(f(x, taps), want):
+        assert relative(a, b) < TOLERANCE
+
+
+@pytest.mark.parametrize("backend", ["tpu", "cpu"])
+def test_on_the_kernel_path_kda_prep_holds_no_sigmoid_and_no_full_precision_product(
+        monkeypatch, backend):
+    """A KDA layer's mixer traced for a TPU at a 128-lane head, forward and
+    backward: under ``kda_prep`` the only ``logistic`` left outside the
+    convolution's calls is beta's (a column a head) and no product asks for
+    ``Precision.HIGHEST`` (the l2norms' 0/1 products went into the calls; the
+    two of ``kda_out``'s head norm stay, in their scope). Traced for another
+    backend the chain is the ``jnp`` one: SiLU's ``logistic`` over q, k, v
+    and q's and k's products, and no call."""
+    from distributed_llm_training_benchmark_framework_tpu.models import tinygpt
+
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    config = tinygpt.TinyGPTConfig(
+        vocab_size=64, n_embd=64, n_head=2, n_layer=1, block_size=128, dropout=0.0, causal=True,
+        norm="rmsnorm", pos_embed="rope", mlp_act="swiglu", mlp_hidden=64, bias=False,
+        tie_embeddings=False, scan_layers=False, layer_types=("kda",), kda_heads=2,
+        kda_head_dim=128, kda_chunk=64)
+    params = jax.eval_shape(lambda k: tinygpt.init_params(config, k), jax.random.key(0))
+    layer = jax.tree.map(lambda leaf: jnp.zeros(leaf.shape[1:], leaf.dtype), params["kda_blocks"])
+    x = jnp.zeros((1, 128, 64), config.compute_dtype)
+    loss = lambda layer, x: jnp.sum(tinygpt._kda_sublayer(config, x, layer).astype(jnp.float32))
+    eqns = list(_equations(jax.make_jaxpr(jax.grad(loss, (0, 1)))(layer, x).jaxpr))
+    prep = [e for path, e in eqns if "kda_prep" in path]
+    assert prep and len(prep) < len(eqns)
+    calls = [e.params["name"] for e in prep if e.primitive.name == "pallas_call"]
+    sigmoids = [e for e in prep if e.primitive.name == "logistic"
+                and e.invars[0].aval.shape[-1] > config.kda_heads]
+    exact = [e for e in prep if e.primitive.name == "dot_general"
+             and e.params["precision"] is not None
+             and lax.Precision.HIGHEST in tuple(e.params["precision"])]
+    if backend == "tpu":
+        assert sorted(calls) == ["kda_conv_bwd"] * 3 + ["kda_conv_fwd"] * 3
+        assert not sigmoids and not exact
+    else:
+        assert not calls and sigmoids and exact
+    # kda_out's head norm keeps its two products (and their transposes) either way
+    out = [e for path, e in eqns if "kda_out" in path
+           and e.primitive.name == "dot_general" and e.params["precision"] is not None
+           and lax.Precision.HIGHEST in tuple(e.params["precision"])]
+    assert len(out) >= 2
+
+
+def test_the_prologues_calls_are_made_once_a_shape():
+    """A layer, remat's copy and the benchmark's check call the same
+    ``pallas_call`` object again (``_conv_call`` is cached by shape and static
+    choices), so the kernels' bodies are traced once a program."""
+    x, taps, _ = prologue_operands(512, jnp.bfloat16, batch=1)
+    kda._conv_call.cache_clear()
+    for _ in range(3):
+        jax.make_jaxpr(lambda x, t: kda.qkv_prologue(x, t, 4, interpret=True))(x, taps)
+    info = kda._conv_call.cache_info()
+    assert info.misses == 3 and info.hits == 6

@@ -429,8 +429,23 @@ def test_kda_stats_count_chunks_calls_and_what_the_forward_keeps():
     stats = tinygpt.kda_stats(CONFIG, SEQ)
     assert stats["layers"] == 4 and stats["chunk"] == 16 and stats["chunks"] == 4
     assert stats["kernel_calls"] == {"kda_fwd": 0, "kda_bwd": 0}  # the jnp path: no kernel here
+    assert stats["prep_kernel_calls"] == {"kda_conv_fwd": 0, "kda_conv_bwd": 0}
     assert stats["saved_state_bytes"] == 4 * 4 * 16 * 16 * 4  # heads x chunks x d^2 x float32
     cell = dataclasses.replace(CONFIG, kda_heads=32, kda_head_dim=128, kda_chunk=128,
                                compute_dtype=jnp.bfloat16)
     assert tinygpt.kda_stats(cell, 16384)["saved_state_bytes"] == 32 * 128 * 128 * 128 * 2
     assert set(tinygpt.attn_mask_stats(CONFIG, SEQ)) == {GLOBAL}  # a kda layer has no mask
+
+
+def test_kda_stats_count_the_prologues_calls_where_its_kernels_run(monkeypatch):
+    """On a TPU at a 128-lane head the convolution, SiLU and the l2norms are
+    ``kda_conv_fwd`` / ``kda_conv_bwd``, a call each for q, k and v a layer;
+    at the test's 16-wide heads, or on another backend, the ``jnp`` chain."""
+    cell = dataclasses.replace(CONFIG, kda_heads=32, kda_head_dim=128, kda_chunk=128)
+    assert tinygpt.kda_stats(cell, 16384)["prep_kernel_calls"] == {"kda_conv_fwd": 0, "kda_conv_bwd": 0}
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    stats = tinygpt.kda_stats(cell, 16384)
+    assert stats["kernel_calls"] == {"kda_fwd": 4, "kda_bwd": 4}
+    assert stats["prep_kernel_calls"] == {"kda_conv_fwd": 12, "kda_conv_bwd": 12}
+    assert tinygpt.kda_stats(cell, 16380)["prep_kernel_calls"]["kda_conv_fwd"] == 0  # not whole 8-row tiles
+    assert tinygpt.kda_stats(CONFIG, SEQ)["prep_kernel_calls"] == {"kda_conv_fwd": 0, "kda_conv_bwd": 0}

@@ -662,21 +662,29 @@ def test_the_kda_kernels_compile_at_the_cells_operand(v5e_devices, chunk, heads_
     assert "kda_fwd" in text and "kda_bwd" in text
 
 
-def test_the_kda_convolutions_kernels_compile_at_the_cells_operand(v5e_devices):
-    """The three depthwise convolutions of a KDA layer as one call over the
-    12,288 columns of q, k and v, forward and backward (XLA's grouped
-    convolution at that many groups takes the chip's compiler minutes)."""
+@pytest.mark.parametrize("epilogue", [False, True], ids=["bare", "qkv_prologue"])
+def test_the_kda_convolutions_kernels_compile_at_the_cells_operand(v5e_devices, epilogue):
+    """The three depthwise convolutions of a KDA layer over the 12,288 columns
+    of q, k and v, forward and backward (XLA's grouped convolution at that many
+    groups takes the chip's compiler minutes): bare as one call each way, and
+    as a layer runs them, with SiLU and q's and k's l2norms inside, a call a
+    third each way, the backward's three writing one dx with no copy."""
     from distributed_llm_training_benchmark_framework_tpu.ops import kda
 
     one = SingleDeviceSharding(v5e_devices[0])
     aval = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype, sharding=one)
 
     def loss(x, taps):
-        return jnp.sum(jnp.square(kda.causal_conv(x, taps, interpret=False).astype(jnp.float32)))
+        out = (kda.qkv_prologue(x, taps, 32, interpret=False) if epilogue
+               else (kda.causal_conv(x, taps, interpret=False),))
+        return sum(jnp.sum(jnp.square(o.astype(jnp.float32))) for o in out)
 
     text = _compile(jax.grad(loss, argnums=(0, 1)),
                     aval((1, 16384, 12288), jnp.bfloat16), aval((4, 12288), jnp.float32))
+    calls = 3 if epilogue else 1
+    assert text.count('custom_call_target="tpu_custom_call"') == 2 * calls
     assert "kda_conv_fwd" in text and "kda_conv_bwd" in text
+    assert " copy(" not in text and " concatenate(" not in text.replace("f32[4,", "")
 
 
 def test_the_kimi_cells_stack_compiles_a_layer_of_each_kind(v5e_devices, monkeypatch):
