@@ -124,7 +124,9 @@ LAYER_KINDS = (scopes.GLOBAL, scopes.WINDOW, scopes.KDA)
 
 #: The stacks of the parameter tree, by (the mixer is KDA, the MLP is a
 #: leading dense one): layers of one stack have equal leaves
-#: (``TinyGPTConfig.layer_groups``). Every name ends in ``blocks``.
+#: (``TinyGPTConfig.layer_groups``). Every name ends in ``blocks``. Where the
+#: attention kinds differ in head count (``layer_heads``) a stack's name takes
+#: its kind in front: ``global_dense_blocks``, ``window_blocks``.
 _STACK_NAMES = {(False, False): "blocks", (False, True): "dense_blocks",
                 (True, False): "kda_blocks", (True, True): "kda_dense_blocks"}
 
@@ -133,10 +135,15 @@ _STACK_NAMES = {(False, False): "blocks", (False, True): "dense_blocks",
 class Rotary:
     """One kind of layer's rotary table: plain ``theta``, or YaRN's
     frequencies over it with cos and sin times ``scaling.cos_sin_factor``
-    (the Hugging Face ``attention_factor``; the softmax scale is untouched)."""
+    (the Hugging Face ``attention_factor``; the softmax scale is untouched).
+    ``rotary_dim``: the leading lanes of a head that rotate (``partial_rotary_
+    factor`` x head_dim, rotate-half inside them: lane j with j + rotary_dim /
+    2; the frequencies are over ``rotary_dim``), the rest pass unrotated. None:
+    the whole head."""
 
     theta: float
     scaling: Optional[YarnScaling] = None
+    rotary_dim: Optional[int] = None
 
 
 @dataclasses.dataclass(frozen=True)
@@ -331,6 +338,16 @@ class TinyGPTConfig:
     sliding_window: Optional[int] = None
     # ((kind, Rotary), ...) for the kinds whose table is not plain rope_theta.
     layer_rotary: Optional[Tuple[Tuple[str, Rotary], ...]] = None
+    # ((kind, query heads), ...) for the attention kinds whose head count is
+    # not n_head (Laguna: 48 on the full layers, 64 on the sliding ones, the
+    # same KV heads and head width). The count decides the shapes of wq, wg
+    # and wo, so kinds of unequal counts are stacks of their own
+    # (``layer_groups``) and run unrolled in the published order.
+    layer_heads: Optional[Tuple[Tuple[str, int], ...]] = None
+    # A learned gate on the attention's output: sigmoid(h wg), one scalar a
+    # head a token from the sublayer's normed input, times that head's output
+    # before wo (leaf wg (D, heads), no bias; scope 'attn_gate').
+    attn_gate: bool = False
     # A ``kda`` layer's sizes (Kimi Delta Attention, Kimi Linear): heads of
     # kda_head_dim keys and as many values, a depthwise causal convolution of
     # kda_conv positions after each of the q, k, v projections, the recurrence
@@ -476,18 +493,39 @@ class TinyGPTConfig:
         return next(p for p in range(1, len(kinds) + 1)
                     if len(kinds) % p == 0 and kinds == kinds[:p] * (len(kinds) // p))
 
+    def heads(self, kind: Optional[str] = None) -> int:
+        """Query heads of an attention layer of ``kind``."""
+        return dict(self.layer_heads or ()).get(kind, self.n_head)
+
     @property
     def has_kda(self) -> bool:
         return scopes.KDA in (self.layer_types or ())
 
     @property
+    def heads_by_kind(self) -> bool:
+        """Whether the stack's attention kinds differ in head count."""
+        kinds = set(self.layer_types or ()) - {scopes.KDA}
+        return len({self.heads(kind) for kind in kinds}) > 1
+
+    @property
+    def stacks_unequal(self) -> bool:
+        """Whether the layers' leaves differ by kind (another mixer, another
+        head count): such stacks run unrolled through ``_apply_stacks``."""
+        return self.has_kda or self.heads_by_kind
+
+    @property
     def layer_groups(self) -> Tuple[Tuple[str, Tuple[int, ...]], ...]:
         """((a stack's name in the parameter tree, its layers' indices in the
-        published order), ...): the layers of equal leaves, by (mixer, MLP)."""
+        published order), ...): the layers of equal leaves, by what decides
+        the leaves' shapes: the mixer, the MLP and, where the attention kinds
+        differ in head count, the kind."""
         groups: Dict[str, list] = {}
+        by_kind = self.heads_by_kind
         for i in range(self.n_layer):
-            kda = self.layer_types is not None and self.layer_types[i] == scopes.KDA
-            groups.setdefault(_STACK_NAMES[kda, i < self.first_k_dense], []).append(i)
+            kind = None if self.layer_types is None else self.layer_types[i]
+            kda = kind == scopes.KDA
+            name = _STACK_NAMES[kda, i < self.first_k_dense]
+            groups.setdefault(f"{kind}_{name}" if by_kind and not kda else name, []).append(i)
         return tuple((name, tuple(layers)) for name, layers in groups.items())
 
     @property
@@ -551,7 +589,8 @@ class TinyGPTConfig:
             raise ValueError(
                 "the pipeline schedules slice one homogeneous stack; layer_types "
                 "gives each layer a kind of its own (sliding_window or kda layers "
-                "beside global ones). Run this config with pipe=1"
+                "beside global ones, stacks of unequal leaves under layer_heads). Run "
+                "this config with pipe=1"
             )
 
     def __post_init__(self):
@@ -643,7 +682,8 @@ class TinyGPTConfig:
             if self.attention_impl not in ("flash", "reference") or (
                     self.seq_manual_axis is not None):
                 raise ValueError(
-                    "layer_types (sliding_window or kda layers beside global ones) runs "
+                    "layer_types (sliding_window or kda layers beside global ones, head "
+                    "counts by kind) runs "
                     "attention_impl 'flash' or 'reference' on whole sequences: ring "
                     "attention, Ulysses and the sequence-parallel pipeline cut the "
                     "sequence, and their bodies take causal or no mask only; got "
@@ -652,12 +692,13 @@ class TinyGPTConfig:
                 )
             if not self.causal or self.block_diffusion is not None or (
                     self.latent_attention and scopes.WINDOW in kinds) or (
-                    self.first_k_dense and scopes.KDA not in kinds):
+                    self.first_k_dense and not self.stacks_unequal):
                 raise ValueError(
                     "layer_types mixes causal layers in one stack: causal=True, no "
                     "block_diffusion; latent attention (kv_lora_rank) is its 'global' "
                     "layers' and has no 'window' ones; first_k_dense leading layers "
-                    "go with 'kda' layers (the stacks by mixer and MLP)"
+                    "go with 'kda' layers or head counts by kind (layer_heads): the "
+                    "stacks of unequal leaves"
                 )
             if scopes.KDA in kinds and not (
                     self.kda_heads > 0 and self.kda_head_dim > 0 and self.kda_conv >= 1
@@ -671,6 +712,29 @@ class TinyGPTConfig:
                     "leaves run unrolled, in the published order, and the scanned "
                     "loop is refused"
                 )
+        if self.layer_heads is not None:
+            if kinds is None or self.latent_attention or self.n_kv_head is None or (
+                    self.tp_collective_matmul) or any(
+                    k not in kinds or k == scopes.KDA or n < 1 or n % self.kv_heads
+                    for k, n in self.layer_heads):
+                raise ValueError(
+                    "layer_heads gives ((kind, query heads), ...) for attention kinds of "
+                    "layer_types, each a multiple of n_kv_head, over the split q and k/v "
+                    "projections (n_kv_head), without latent attention or "
+                    f"tp_collective_matmul; got {self.layer_heads}, layer_types={kinds}"
+                )
+            if self.heads_by_kind and self.scan_layers:
+                raise ValueError(
+                    "layer_heads gives the attention kinds unequal head counts, so wq, wg "
+                    "and wo differ in shape by kind: stacks of unequal leaves run unrolled, "
+                    "in the published order (scan_layers=False), and the scanned loop is "
+                    "refused"
+                )
+        if self.attn_gate and (self.latent_attention or self.tp_collective_matmul):
+            raise ValueError(
+                "attn_gate (the per-head sigmoid gate on the attention's output) is "
+                "wired for ordinary attention without tp_collective_matmul"
+            )
         if self.router_score not in ("softmax", "sigmoid") or (
                 self.router_score == "sigmoid" or self.routed_scaling_factor != 1.0
         ) and not (self.n_experts > 0 and dropless):
@@ -696,6 +760,14 @@ class TinyGPTConfig:
                 "layer_rotary gives ((kind, Rotary), ...) for kinds of layer_types "
                 f"under pos_embed='rope'; got {self.layer_rotary}"
             )
+        for _, r in self.layer_rotary or ():
+            if r.rotary_dim is not None and not (
+                    0 < r.rotary_dim <= self.head_dim and r.rotary_dim % 2 == 0
+                    and not self.latent_attention):
+                raise ValueError(
+                    f"rotary_dim (the leading lanes of a head that rotate) is even and at "
+                    f"most head_dim={self.head_dim}, for ordinary attention; got {r.rotary_dim}"
+                )
         bd = self.block_diffusion
         if bd is not None:
             if self.attention_impl not in ("flash", "reference") or (
@@ -822,6 +894,9 @@ PARAM_AXIS_RULES: Dict[str, Tuple[Optional[str], ...]] = {
     "blocks/kda_wga": ("layers", "embed", "kda_rank"),
     "blocks/kda_wgb": ("layers", "kda_rank", "heads"),
     "blocks/kda_norm": ("layers", "head_dim"),
+    # The attention's per-head output gate (present when attn_gate): one
+    # column a query head, so it splits over 'model' as wq's columns do.
+    "blocks/wg": ("layers", "embed", "gate_heads"),
     "lnf_scale": ("embed",),
     "lnf_bias": ("embed",),
     # Untied LM head (present when tie_embeddings=False): same logical axes
@@ -849,7 +924,7 @@ def init_params(config: TinyGPTConfig, key: jax.Array) -> Params:
     legacy = Hkv == H and c.tie_embeddings and c.pos_embed == "learned"
     wide = c.latent_attention or c.first_k_dense or c.n_shared_experts
     k = iter(jax.random.split(
-        key, 64 if c.has_kda else 8 if legacy else 24 if wide else 12))
+        key, 64 if c.stacks_unequal else 8 if legacy else 24 if wide else 12))
 
     def normal(key, shape):
         return (0.02 * jax.random.normal(key, shape)).astype(c.param_dtype)
@@ -857,8 +932,8 @@ def init_params(config: TinyGPTConfig, key: jax.Array) -> Params:
     zeros = lambda shape: jnp.zeros(shape, c.param_dtype)
     ones = lambda shape: jnp.ones(shape, c.param_dtype)
 
-    def norms_and_attention(L):
-        """One stack's norm scales and attention leaves, L layers."""
+    def norms_and_attention(L, H):
+        """One stack's norm scales and attention leaves, L layers of H heads."""
         blocks = {"ln1_scale": ones((L, D)), "ln2_scale": ones((L, D))}
         if c.norm == "layernorm":
             blocks.update(ln1_bias=zeros((L, D)), ln2_bias=zeros((L, D)))
@@ -887,6 +962,8 @@ def init_params(config: TinyGPTConfig, key: jax.Array) -> Params:
         blocks["wo"] = normal(next(k), (L, H * c.v_dim, D))
         if c.bias:
             blocks["bo"] = zeros((L, D))
+        if c.attn_gate:
+            blocks["wg"] = normal(next(k), (L, D, H))
         return blocks
 
     def norms_and_kda(L):
@@ -956,10 +1033,15 @@ def init_params(config: TinyGPTConfig, key: jax.Array) -> Params:
                 blocks["bproj"] = zeros((L, D))
         return blocks
 
-    def stack(name, L):
-        """The stack ``name`` (``_STACK_NAMES``) of L layers: its mixer's leaves,
-        then its MLP's; the draws in that order."""
-        leaves = (norms_and_kda if name.startswith("kda_") else norms_and_attention)(L)
+    def stack(name, layers):
+        """The stack ``name`` (``layer_groups``) of these layers: its mixer's
+        leaves, then its MLP's; the draws in that order."""
+        L = len(layers)
+        if name.startswith("kda_"):
+            leaves = norms_and_kda(L)
+        else:
+            kind = c.layer_types[layers[0]] if c.layer_types else None
+            leaves = norms_and_attention(L, c.heads(kind))
         if name.endswith("dense_blocks"):
             Fd = c.dense_mlp_hidden
             leaves.update(wgu=normal(next(k), (L, D, 2, Fd)), wproj=normal(next(k), (L, Fd, D)))
@@ -969,11 +1051,12 @@ def init_params(config: TinyGPTConfig, key: jax.Array) -> Params:
 
     # The draws' order is the seeds' contract with every published artifact:
     # 'blocks' (the layers after the leading dense ones), the embedding and the
-    # head, 'dense_blocks', then the KDA stacks.
-    sizes = {name: len(layers) for name, layers in c.layer_groups}
+    # head, 'dense_blocks', the KDA stacks, then the stacks named by kind
+    # (``layer_heads``) in the published order of their first layers.
+    groups = dict(c.layer_groups)
     params = {}
-    if "blocks" in sizes:
-        params["blocks"] = stack("blocks", sizes["blocks"])
+    if "blocks" in groups:
+        params["blocks"] = stack("blocks", groups["blocks"])
     params.update(wte=normal(next(k), (V, D)), lnf_scale=ones((D,)))
     if c.pos_embed == "learned":
         params["wpe"] = normal(next(k), (T, D))
@@ -981,9 +1064,10 @@ def init_params(config: TinyGPTConfig, key: jax.Array) -> Params:
         params["lnf_bias"] = zeros((D,))
     if not c.tie_embeddings:
         params["lm_head"] = normal(next(k), (V, D))
-    for name in ("dense_blocks", "kda_blocks", "kda_dense_blocks"):
-        if name in sizes:
-            params[name] = stack(name, sizes[name])
+    for name in ("dense_blocks", "kda_blocks", "kda_dense_blocks",
+                 *(n for n in groups if n not in _STACK_NAMES.values())):
+        if name in groups:
+            params[name] = stack(name, groups[name])
     return params
 
 
@@ -1023,6 +1107,7 @@ def _rope(
     positions: jax.Array,  # (S,) int32 global token positions
     theta: float,
     scaling: Optional[YarnScaling] = None,
+    rotary_dim: Optional[int] = None,
 ) -> jax.Array:
     """Rotary position embedding, HF-Llama rotate-half convention.
 
@@ -1030,9 +1115,14 @@ def _rope(
     x2 = second half, x' = x*cos + cat(-x2, x1)*sin — matching HF
     ``apply_rotary_pos_emb`` exactly so the transformers parity test can
     load identical weights. fp32 rotation math, cast back to x.dtype.
+    ``rotary_dim``: only the leading lanes rotate (rotate-half inside them,
+    the frequencies over ``rotary_dim``); the rest pass as they are.
     """
     from ..ops.rotary import rope_angles
 
+    if rotary_dim is not None and rotary_dim != x.shape[-1]:
+        turned = _rope(x[..., :rotary_dim], positions, theta, scaling)
+        return jnp.concatenate((turned, x[..., rotary_dim:]), axis=-1)
     Dh = x.shape[-1]
     half = Dh // 2
     freqs = rope_angles(positions, Dh, theta, scaling)  # (S, Dh/2)
@@ -1305,16 +1395,17 @@ def _rotary_positions(c: TinyGPTConfig, S: int) -> jax.Array:
     return pos
 
 
-def _takes_qk_prologue(c: TinyGPTConfig, S: int) -> bool:
-    """Whether the stack's q and k, S rows of them, are ``ops.rotary``'s
-    operand: rotary over whole heads of 128-lane vregs. The per-head norm is
-    the pass's first stage; a norm over all of a layer's features (OLMoE)
-    stays in ``jnp`` before it. Not latent attention's, which rotates 64 of
-    192 lanes of q and a one-head key."""
+def _takes_qk_prologue(c: TinyGPTConfig, S: int, kind: Optional[str] = None) -> bool:
+    """Whether q and k of the stack's layers of ``kind``, S rows of them, are
+    ``ops.rotary``'s operand: rotary over heads of whole 128-lane vregs, all of
+    a head's lanes or its leading ``rotary_dim``. The per-head norm is the
+    pass's first stage; a norm over all of a layer's features (OLMoE) stays in
+    ``jnp`` before it. Not latent attention's, which rotates 64 of 192 lanes of
+    q and a one-head key."""
     from ..ops import rotary as rotary_ops
 
     return (c.pos_embed == "rope" and not c.latent_attention
-            and rotary_ops.fits(c.head_dim, S))
+            and rotary_ops.fits(c.head_dim, S, c.rotary(kind).rotary_dim))
 
 
 def qk_prologue_tables(c: TinyGPTConfig, S: int) -> Dict:
@@ -1326,17 +1417,18 @@ def qk_prologue_tables(c: TinyGPTConfig, S: int) -> Dict:
     without the kernels."""
     from ..ops import rotary as rotary_ops
 
-    if not _takes_qk_prologue(c, S) or rotary_ops.kernel_mode() is None:
+    if rotary_ops.kernel_mode() is None:
         return {}
     pos = _rotary_positions(c, S)
     kinds = sorted(set(c.layer_types) - {scopes.KDA}) if c.layer_types else (None,)
     return {
-        kind: rotary_ops.table(pos, c.head_dim, c.rotary(kind).theta, c.rotary(kind).scaling)
-        for kind in kinds
+        kind: rotary_ops.table(pos, c.head_dim, c.rotary(kind).theta, c.rotary(kind).scaling,
+                               c.rotary(kind).rotary_dim)
+        for kind in kinds if _takes_qk_prologue(c, S, kind)
     }
 
 
-def qk_prologue_stats(config: TinyGPTConfig, seq_len: int) -> Dict[str, int]:
+def qk_prologue_stats(config: TinyGPTConfig, seq_len: int) -> Dict[str, Any]:
     """Counters of the pass between the projections and the flash kernels
     over sequences of ``seq_len`` tokens (a block-diffusion stream is twice
     that), from the config and the backend at trace time: ``rotary_layers``
@@ -1344,22 +1436,41 @@ def qk_prologue_stats(config: TinyGPTConfig, seq_len: int) -> Dict[str, int]:
     ``ops.rotary``'s one pass here (the rest run the ``jnp`` chain: a cell
     that fell back says so), ``norm_stage_layers`` of those with the per-head
     norm inside the pass, and the bytes one layer's pass moves a sequence,
-    ``forward_bytes`` and ``backward_bytes``."""
+    ``forward_bytes`` and ``backward_bytes`` (of the first kind that takes
+    it). ``by_kind`` has the same a kind of attention layer (the one key
+    ``global`` for a stack of one kind), with its ``heads`` and the
+    ``rotary_lanes`` of a head that rotate."""
     from ..ops import rotary as rotary_ops
 
     c = config
     S = seq_len * (2 if c.block_diffusion is not None else 1)
-    rotary_layers = c.n_layer if c.pos_embed == "rope" else 0
-    taken = _takes_qk_prologue(c, S) and rotary_ops.kernel_mode() is not None
     head = c.qk_norm == "head"
-    moved = rotary_ops.pass_bytes(
-        S, c.n_head * c.head_dim, c.kv_heads * c.head_dim,
-        jnp.dtype(c.compute_dtype).itemsize, head) if taken else {"forward": 0, "backward": 0}
+    kinds = c.layer_types or (None,) * c.n_layer
+    by_kind = {}
+    for kind in sorted(set(kinds) - {scopes.KDA}, key=str):
+        layers = kinds.count(kind) if c.pos_embed == "rope" else 0
+        taken = (layers > 0 and _takes_qk_prologue(c, S, kind)
+                 and rotary_ops.kernel_mode() is not None)
+        moved = rotary_ops.pass_bytes(
+            S, c.heads(kind) * c.head_dim, c.kv_heads * c.head_dim,
+            jnp.dtype(c.compute_dtype).itemsize, head) if taken else {"forward": 0, "backward": 0}
+        by_kind[kind or scopes.GLOBAL] = {
+            "heads": c.heads(kind),
+            "rotary_lanes": (c.rotary(kind).rotary_dim or c.head_dim) if layers else 0,
+            "rotary_layers": layers,
+            "pass_layers": layers if taken else 0,
+            "norm_stage_layers": layers if taken and head else 0,
+            "forward_bytes": moved["forward"], "backward_bytes": moved["backward"],
+        }
+    total = lambda key: sum(entry[key] for entry in by_kind.values())
+    first = next((e for e in by_kind.values() if e["pass_layers"]), None) or {}
     return {
-        "rotary_layers": rotary_layers,
-        "pass_layers": rotary_layers if taken else 0,
-        "norm_stage_layers": rotary_layers if taken and head else 0,
-        "forward_bytes": moved["forward"], "backward_bytes": moved["backward"],
+        "rotary_layers": total("rotary_layers"),
+        "pass_layers": total("pass_layers"),
+        "norm_stage_layers": total("norm_stage_layers"),
+        "forward_bytes": first.get("forward_bytes", 0),
+        "backward_bytes": first.get("backward_bytes", 0),
+        "by_kind": by_kind,
     }
 
 
@@ -1372,14 +1483,16 @@ def _attention_sublayer(
     kind: Optional[str] = None,
     qk_tables: Optional[Dict] = None,
 ) -> jax.Array:
-    """Norm -> q/k/v projections -> QK-norm -> rope -> attention -> output
-    projection -> residual: the first half of ``_block``. Where the stack's
+    """Norm -> q/k/v projections -> QK-norm -> rope -> attention -> (the
+    per-head output gate) -> output projection -> residual: the first half of
+    ``_block``, at the kind's head count (``TinyGPTConfig.heads``). Where the stack's
     q and k are ``ops.rotary``'s operand and the backend runs its kernels
     (``qk_prologue_tables`` has this kind's tables), the per-head norm and
     the rotation are its one pass; else the ``jnp`` chain ``_rms_norm`` ->
     ``_rope``, which is also what the pass is tested against."""
     B, S, D = x.shape
     cd = c.compute_dtype
+    H = c.heads(kind)
     use_cmm = c.tp_collective_matmul
     if use_cmm:
         from ..ops import collective_matmul as _cm
@@ -1431,9 +1544,9 @@ def _attention_sublayer(
         with jax.named_scope(scopes.QK_PROLOGUE):
             q, k = rotary_ops.qk_prologue(  # -> (B, S, heads, head_dim)
                 q, k, *scales, qk_tables[kind], c.norm_eps,
-                interpret=rotary_ops.kernel_mode())
+                interpret=rotary_ops.kernel_mode(), rotary_dim=c.rotary(kind).rotary_dim)
     else:  # the jnp chain
-        q = q.reshape(B, S, c.n_head, c.head_dim)
+        q = q.reshape(B, S, H, c.head_dim)
         k = k.reshape(B, S, c.kv_heads, c.head_dim)
         if head_norm:
             q = _rms_norm(q, layer["q_norm"], c.norm_eps)
@@ -1441,20 +1554,25 @@ def _attention_sublayer(
         if c.pos_embed == "rope":
             rotary = c.rotary(kind)
             pos = _rotary_positions(c, S)
-            q = _rope(q, pos, rotary.theta, rotary.scaling)
-            k = _rope(k, pos, rotary.theta, rotary.scaling)
-    if c.kv_heads != c.n_head:
+            q = _rope(q, pos, rotary.theta, rotary.scaling, rotary.rotary_dim)
+            k = _rope(k, pos, rotary.theta, rotary.scaling, rotary.rotary_dim)
+    if c.kv_heads != H:
         # Broadcast each K/V head to its query group. Consecutive-block
         # repetition matches the TP layout: query-head shard j needs exactly
         # kv-head shard j when the 'model' degree divides kv_heads; when it
         # does not, the kv-head-aligned spec rule keeps wkv replicated over
         # 'model' (strategies.param_partition_specs) so this reshape never
         # needs the partitioner's full-replicate resharding fallback.
-        rep = c.n_head // c.kv_heads
+        rep = H // c.kv_heads
         k = jnp.repeat(k, rep, axis=2)
         v = jnp.repeat(v, rep, axis=2)
     attn = _attention(c, q, k, v, dropout_key, deterministic, kind)
-    attn = attn.reshape(B, S, c.n_head * c.head_dim)
+    if "wg" in layer:
+        with jax.named_scope(scopes.ATTN_GATE):
+            gate = jax.nn.sigmoid(jnp.einsum(  # (B, S, H) f32: one scalar a head a token
+                "bsd,dh->bsh", h, layer["wg"].astype(cd), preferred_element_type=jnp.float32))
+            attn = (attn.astype(jnp.float32) * gate[..., None]).astype(cd)
+    attn = attn.reshape(B, S, H * c.head_dim)
     if use_cmm:
         attn = _cm.rs_proj(attn, layer["wo"].astype(cd)).astype(cd)
     else:
@@ -1762,7 +1880,8 @@ def attn_mask_stats(config: TinyGPTConfig, seq_len: int) -> Dict[str, Dict[str, 
     layer (``layer_types``; one entry, ``global``, for a stack of one kind),
     from each kind's mask rule at the tiles and pieces ``ops.flash_attention``
     picks (no array is made): ``layers`` of the kind, ``true_pairs`` the rule
-    allows, and for the forward and the fused backward kernel ``*_live_tiles``
+    allows, the kind's query ``heads``, the (queries, keys) ``fwd_tile`` and
+    ``bwd_tile`` taken, and for the forward and the fused backward kernel ``*_live_tiles``
     (tiles that hold a pair), ``*_grid_steps`` (steps a head's grid makes: the
     square's under causal, the band's under a window; the difference brings a
     tile, or holds the last one, and multiplies nothing) and
@@ -1778,6 +1897,8 @@ def attn_mask_stats(config: TinyGPTConfig, seq_len: int) -> Dict[str, Dict[str, 
             seq_len, config.qk_dim, config.compute_dtype, causal=rule)
         window = isinstance(rule, fa.SlidingWindow)
         entry = {"layers": kinds.count(kind),
+                 "heads": config.heads(kind if config.layer_types else None),
+                 "fwd_tile": (bq, bk), "bwd_tile": (bq, bk_bwd),
                  "true_pairs": (rule.true_pairs(seq_len) if window
                                 else seq_len * (seq_len + 1) // 2 if rule else seq_len ** 2)}
         for name, keys, piece in (("fwd", bk, fa._fwd_sub_k(bk)),
@@ -1992,7 +2113,7 @@ def apply_layers(
     per-layer placement hooks) -> (x, aux_sum)."""
     c = config
     tables = qk_prologue_tables(c, x.shape[1])  # one set for both stacks
-    if c.has_kda:
+    if c.stacks_unequal:
         return _apply_stacks(c, params, x, base_key, deterministic, tables)
     if not c.first_k_dense:
         return apply_blocks(c, params["blocks"], x, base_key, deterministic, qk_tables=tables)
@@ -2035,7 +2156,7 @@ def apply_layer(config: TinyGPTConfig, layer: Params, x: jax.Array, kind: Option
 
 def _apply_stacks(c, params, x, base_key, deterministic, qk_tables):
     """The whole depth of a config whose stacks have unequal leaves (``kda``
-    layers beside others): unrolled, the layers in the published order, each
+    layers beside others, attention kinds of unequal head counts): unrolled, the layers in the published order, each
     from its own stack (``apply_layer``) -> (x, aux_sum)."""
     live = base_key is not None and not deterministic
     aux = jnp.zeros(c.aux_shape, jnp.float32)

@@ -1658,6 +1658,23 @@ def _kernel_mesh_axes():
     return frozenset(m.axis_names), batch, heads
 
 
+#: The least tile a narrow window's band takes (``_window_tile``).
+_MIN_WINDOW_TILE = 256
+
+
+def _window_tile(mask: MaskRule) -> Optional[int]:
+    """The (queries, keys) tile of the forward and the fused backward under a
+    ``SlidingWindow`` narrower than the default tile: the largest power of two
+    that the window holds, and no less than ``_MIN_WINDOW_TILE``. None for any
+    other rule and for a window of the default tile or more (1024: the choice
+    there stays the default's). At the default tile a window of 512 multiplies
+    the area of a window of 1024: every query tile meets a whole trailing tile
+    and a diagonal one, 33 % of it live; at its own width 61 %."""
+    if not isinstance(mask, SlidingWindow) or mask.window >= _FWD_BLOCK_Q:
+        return None
+    return max(_MIN_WINDOW_TILE, 1 << (mask.window.bit_length() - 1))
+
+
 def pick_tiles(
     S: int, D: int, dtype, interpret: bool = False,
     pallas_backward: Optional[bool] = None, block_q: Optional[int] = None,
@@ -1668,18 +1685,21 @@ def pick_tiles(
     ``flash_attention`` call over S positions at q / k width D: the caller's
     where given, else the measured defaults (the table above ``_FWD_BLOCK_Q``;
     the Mosaic path's with ``interpret`` False), which divide S, and under a
-    ``BlockDiffusion`` rule each copy of the document (S / 2)."""
+    ``BlockDiffusion`` rule each copy of the document (S / 2). Under a
+    ``SlidingWindow`` narrower than the default tile the forward's and the
+    fused backward's tiles follow the window (``_window_tile``)."""
     if pallas_backward is None:
         # Auto: the measured S-dependent crossover (_PALLAS_BWD_MIN_SEQ).
         # Interpret mode keeps the einsum backward — the Pallas bwd kernels
         # would run under the slow HLO interpreter for no fidelity gain.
         pallas_backward = (not interpret) and S >= _PALLAS_BWD_MIN_SEQ
     whole = causal.seq_len if isinstance(causal, BlockDiffusion) else S
-    bq = block_q or _pick_block(whole, _FWD_BLOCK_Q)
-    bk = block_k or _pick_block(whole, _FWD_BLOCK_K)
+    narrow = _window_tile(causal)  # the band's tile, or None: the defaults
+    bq = block_q or _pick_block(whole, narrow or _FWD_BLOCK_Q)
+    bk = block_k or _pick_block(whole, narrow or _FWD_BLOCK_K)
     fused = pallas_backward and _fused_fits(S, D, dtype)
     bk_bwd = block_k_bwd or _pick_block(
-        whole, _FUSED_BWD_BLOCK_K if fused else _BWD_BLOCK_K
+        whole, (narrow or _FUSED_BWD_BLOCK_K) if fused else _BWD_BLOCK_K
     )
     return bq, bk, bk_bwd, pallas_backward
 

@@ -21,6 +21,13 @@ computed again from the input (nothing is saved but what the caller already
 holds), the gradient written once; the two scale gradients leave as one
 (8, D) partial sum a grid step and are added outside.
 
+Where only the leading ``rotary_dim`` lanes of a head rotate (a partial rotary
+factor: lane j pairs with j + rotary_dim / 2 inside them, the rest pass), the
+head still moves as whole vregs and the pairing is two lane rotations, by
+rotary_dim / 2 up and down, each against a sin that is zero off its half; the
+table holds cos and sin in its first ``rotary_dim`` lanes. Whole heads lower as
+they did.
+
 f32 inside, the operands' dtype at both ends. The norm's result is not rounded
 to the operands' dtype before the rotation, as the ``jnp`` chain does: one
 rounding fewer. cos and sin come in as one (S, D) f32 array (``table``), built
@@ -60,11 +67,20 @@ _CHUNK_ROWS = 32
 _VMEM_HEADROOM = 16 * 2**20
 
 
-def fits(head_dim: int, seq_len: int) -> bool:
-    """Whether q and k of ``seq_len`` rows and heads of ``head_dim`` are the
-    pass's operand: heads of whole 128-lane vregs, rows that cut into chunks.
-    A 64-wide head is not, nor the 64 of 192 lanes latent attention rotates."""
-    return head_dim % 128 == 0 and seq_len % _CHUNK_ROWS == 0
+def fits(head_dim: int, seq_len: int, rotary_dim: Optional[int] = None) -> bool:
+    """Whether q and k of ``seq_len`` rows and heads of ``head_dim``, of which
+    the leading ``rotary_dim`` lanes rotate (None: all), are the pass's
+    operand: heads of whole 128-lane vregs, rows that cut into chunks, an even
+    part of a head. A 64-wide head is not, nor the 64 of 192 lanes latent
+    attention rotates."""
+    part = head_dim if rotary_dim is None else rotary_dim
+    return (head_dim % 128 == 0 and seq_len % _CHUNK_ROWS == 0
+            and 0 < part <= head_dim and part % 2 == 0)
+
+
+def _part(dim: int, rotary_dim: Optional[int]) -> Optional[int]:
+    """``rotary_dim`` where it is a proper part of the head, else None."""
+    return None if rotary_dim in (None, dim) else rotary_dim
 
 
 def kernel_mode() -> Optional[bool]:
@@ -98,22 +114,29 @@ def rope_angles(
     return positions.astype(jnp.float32)[:, None] * inv_freq[None, :]
 
 
-def table(positions: jax.Array, dim: int, theta: float, scaling=None) -> jax.Array:
+def table(positions: jax.Array, dim: int, theta: float, scaling=None,
+          rotary_dim: Optional[int] = None) -> jax.Array:
     """(S, dim) f32: a row's cos in the first dim / 2 lanes, its sin in the
     rest (``_rope``'s numbers: YaRN's frequencies and its factor on cos and
     sin folded in). One array a kind of layer is what a step holds from its
-    first layer's forward to its backward."""
-    freqs = rope_angles(positions, dim, theta, scaling)
+    first layer's forward to its backward. Under a ``rotary_dim`` short of
+    ``dim`` the angles are over ``rotary_dim``: cos in the first rotary_dim / 2
+    lanes, sin in the next, zeros in the lanes that do not rotate."""
+    part = _part(dim, rotary_dim) or dim
+    freqs = rope_angles(positions, part, theta, scaling)
     out = jnp.concatenate((jnp.cos(freqs), jnp.sin(freqs)), axis=-1)
     if scaling is not None and scaling.cos_sin_factor != 1.0:
         out = out * scaling.cos_sin_factor
+    if part != dim:
+        out = jnp.pad(out, ((0, 0), (0, dim - part)))
     return out
 
 
 def pass_bytes(rows: int, q_width: int, k_width: int, itemsize: int, norm: bool) -> dict:
     """The bytes one layer's pass moves over ``rows`` rows, from its shapes:
     forward each operand in and out once; backward the cotangent in and the
-    gradient out, and under the norm the projection's output in as well."""
+    gradient out, and under the norm the projection's output in as well. The
+    same whatever part of a head rotates: a head moves whole."""
     operand = rows * (q_width + k_width) * itemsize
     return {"forward": 2 * operand, "backward": (3 if norm else 2) * operand}
 
@@ -167,12 +190,50 @@ def _cos_sin(packed: jax.Array) -> Tuple[jax.Array, jax.Array]:
     return lax.select(first, packed, swapped), lax.select(first, lax.neg(swapped), packed)
 
 
+def _cos_sin_part(packed: jax.Array, part: int) -> Tuple[jax.Array, jax.Array, jax.Array]:
+    """``_cos_sin`` where the leading ``part`` lanes rotate -> (cos, sin down,
+    sin up): cos is 1 and both sins 0 on the lanes that pass; ``sin down`` is
+    -sin on the part's first half (against the lane part / 2 above), ``sin
+    up`` +sin on its second (against the lane part / 2 below)."""
+    dim, half = packed.shape[-1], part // 2
+    lane = lax.broadcasted_iota(jnp.int32, packed.shape, 1)
+    first, turning = lax.lt(lane, half), lax.lt(lane, part)
+    above = pltpu.roll(packed, dim - half, 1)  # lane j: the table's j + half, sin for j < half
+    below = pltpu.roll(packed, half, 1)  # lane j: the table's j - half, cos for half <= j < part
+    zero, one = jnp.zeros_like(packed), jnp.ones_like(packed)
+    return (lax.select(first, packed, lax.select(turning, below, one)),
+            lax.select(first, lax.neg(above), zero),
+            lax.select(first, zero, lax.select(turning, packed, zero)))
+
+
 def _rotate(x: jax.Array, cos: jax.Array, sin: jax.Array, sign: float) -> jax.Array:
     """x * cos + sign * roll(x, D / 2) * sin: the rotation at +1, its
     transpose at -1 (``sin`` carries the half's sign)."""
     rolled = lax.mul(pltpu.roll(x, x.shape[-1] // 2, 1), sin)
     straight = lax.mul(x, cos)
     return lax.add(straight, rolled) if sign > 0 else lax.sub(straight, rolled)
+
+
+def _rotate_part(x: jax.Array, cos, sin_down, sin_up, sign: float, part: int) -> jax.Array:
+    """``_rotate`` where the leading ``part`` lanes rotate: a lane of the
+    part's first half meets the lane part / 2 above it, one of its second the
+    lane part / 2 below, and the sins are zero elsewhere."""
+    dim, half = x.shape[-1], part // 2
+    rolled = lax.add(lax.mul(pltpu.roll(x, dim - half, 1), sin_down),
+                     lax.mul(pltpu.roll(x, half, 1), sin_up))
+    straight = lax.mul(x, cos)
+    return lax.add(straight, rolled) if sign > 0 else lax.sub(straight, rolled)
+
+
+def _coefficients(packed: jax.Array, part: Optional[int]) -> Tuple[jax.Array, ...]:
+    """A chunk of ``table`` -> what ``_turn`` multiplies by: whole heads
+    (``part`` None) or their leading ``part`` lanes."""
+    return _cos_sin(packed) if part is None else _cos_sin_part(packed, part)
+
+
+def _turn(x: jax.Array, by: Tuple[jax.Array, ...], sign: float, part: Optional[int]) -> jax.Array:
+    """x rotated (+1) or un-rotated (-1) by ``_coefficients``' result."""
+    return _rotate(x, *by, sign) if part is None else _rotate_part(x, *by, sign, part)
 
 
 def _chunks(rows: int):
@@ -188,7 +249,7 @@ def _chunks(rows: int):
     return walk
 
 
-def _fwd_kernel(table_ref, *refs, dim: int, eps: float, norm: bool):
+def _fwd_kernel(table_ref, *refs, dim: int, eps: float, norm: bool, part: Optional[int] = None):
     if norm:
         qs_ref, ks_ref, q_ref, k_ref, qo_ref, ko_ref = refs
     else:
@@ -196,7 +257,7 @@ def _fwd_kernel(table_ref, *refs, dim: int, eps: float, norm: bool):
         qs_ref = ks_ref = None
 
     def body(rows, carry):
-        cos, sin = _cos_sin(table_ref[rows, :])
+        by = _coefficients(table_ref[rows, :], part)
         for x_ref, o_ref, s_ref in ((q_ref, qo_ref, qs_ref), (k_ref, ko_ref, ks_ref)):
             scale = s_ref[...] if norm else None  # (1, dim) f32
             for h in range(x_ref.shape[-1] // dim):
@@ -205,7 +266,7 @@ def _fwd_kernel(table_ref, *refs, dim: int, eps: float, norm: bool):
                 if norm:
                     (mean_sq,) = _lane_means(lax.mul(x, x))
                     x = lax.mul(lax.mul(x, lax.rsqrt(lax.add(mean_sq, eps))), scale)
-                o_ref[h, rows, :] = _rotate(x, cos, sin, +1).astype(o_ref.dtype)
+                o_ref[h, rows, :] = _turn(x, by, +1, part).astype(o_ref.dtype)
         return carry
 
     _chunks(q_ref.shape[0])(body, None)
@@ -219,7 +280,7 @@ def _fold8(x: jax.Array) -> jax.Array:
     return out
 
 
-def _bwd_kernel(table_ref, *refs, dim: int, eps: float, norm: bool):
+def _bwd_kernel(table_ref, *refs, dim: int, eps: float, norm: bool, part: Optional[int] = None):
     if norm:
         (qs_ref, ks_ref, q_ref, k_ref, dqo_ref, dko_ref,
          dq_ref, dk_ref, dqs_ref, dks_ref) = refs
@@ -229,13 +290,13 @@ def _bwd_kernel(table_ref, *refs, dim: int, eps: float, norm: bool):
         streams = ((None, dqo_ref, dq_ref, None), (None, dko_ref, dk_ref, None))
 
     def body(rows, sums):
-        cos, sin = _cos_sin(table_ref[rows, :])
+        by = _coefficients(table_ref[rows, :], part)
         out = []
         for (x_ref, do_ref, dx_ref, s_ref), acc in zip(streams, sums):
             scale = s_ref[...] if norm else None
             for h in range(do_ref.shape[0]):
                 lanes = slice(h * dim, (h + 1) * dim)
-                dy = _rotate(do_ref[h, rows, :].astype(jnp.float32), cos, sin, -1)
+                dy = _turn(do_ref[h, rows, :].astype(jnp.float32), by, -1, part)
                 if norm:
                     # y = x r s with r = rsqrt(mean(x^2) + eps) a row, g = dy s:
                     # ds = sum dy x r; dx = r g - x r^3 mean(g x). Both means
@@ -258,7 +319,7 @@ def _bwd_kernel(table_ref, *refs, dim: int, eps: float, norm: bool):
 
 
 @functools.lru_cache(maxsize=64)
-def _call(backward: bool, B, S, Eq, Ek, dim, dtype, vma, eps, norm, interpret):
+def _call(backward: bool, B, S, Eq, Ek, dim, dtype, vma, eps, norm, interpret, part=None):
     """One direction's ``pallas_call``, made once a process for a shape (as
     ``flash_attention._forward_call``, and for its reason). Operands, in
     order: the table, [q scale, k scale, q, k,] [q's and k's cotangents]."""
@@ -292,7 +353,7 @@ def _call(backward: bool, B, S, Eq, Ek, dim, dtype, vma, eps, norm, interpret):
     streams = (3 if norm else 2) if backward else 2
     return pl.pallas_call(
         functools.partial(_bwd_kernel if backward else _fwd_kernel,
-                          dim=dim, eps=eps, norm=norm),
+                          dim=dim, eps=eps, norm=norm, part=part),
         out_shape=results,
         grid=grid,
         in_specs=in_specs,
@@ -307,9 +368,9 @@ def _call(backward: bool, B, S, Eq, Ek, dim, dtype, vma, eps, norm, interpret):
 
 
 def _run(backward, opts, shape, table, *operands):
-    dim, eps, norm, interpret = opts
+    dim, eps, norm, interpret, part = opts
     call = _call(backward, *shape, dim, operands[-1].dtype,
-                 vma_of(table, *operands), eps, norm, interpret)
+                 vma_of(table, *operands), eps, norm, interpret, part)
     # one trace for the primal and the forward rule: see _flash_forward
     with jax.sharding.use_abstract_mesh(jax.sharding.get_abstract_mesh()):
         return call(table, *operands)
@@ -354,9 +415,11 @@ def qk_prologue(
     table: jax.Array,  # (S, D) f32, ``table``
     eps: float,
     interpret: bool = False,
+    rotary_dim: Optional[int] = None,
 ) -> Tuple[jax.Array, jax.Array]:
     """-> q (B, S, H, D) and k (B, S, KV, D), each head normalised (where
-    scales are given) and rotated, in one pass; differentiable in q, k and the
+    scales are given) and rotated (its leading ``rotary_dim`` lanes, by a
+    ``table`` made for them; None: all of it), in one pass; differentiable in q, k and the
     scales. The kernels write a (S, D) slab a head, (B, H, S, D), which is
     what ``flash_attention`` transposes its operands to: handed back as the
     transpose of that, so that the two cancel and no relayout runs between
@@ -369,10 +432,10 @@ def qk_prologue(
             "kernels only (interpret mode is for CPU tests)")
     dim = table.shape[-1]
     norm = q_scale is not None
-    if not fits(dim, q.shape[1]) or q.shape[-1] % dim or k.shape[-1] % dim:
+    if not fits(dim, q.shape[1], rotary_dim) or q.shape[-1] % dim or k.shape[-1] % dim:
         raise ValueError(
             f"q {q.shape} / k {k.shape} at heads of {dim} are not the pass's operand (fits)")
-    opts = (dim, float(eps), norm, bool(interpret))
+    opts = (dim, float(eps), norm, bool(interpret), _part(dim, rotary_dim))
     manual, batch_axes, heads_axis = _kernel_mesh_axes()
 
     def local(q, k, q_scale, k_scale, table):

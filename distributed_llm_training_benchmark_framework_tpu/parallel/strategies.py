@@ -390,6 +390,7 @@ _TP_RULES = {
     "blocks/wkv": (3,),
     "blocks/bkv": (2,),
     "blocks/wo": (1,),  # row-parallel input (merged heads)
+    "blocks/wg": (2,),  # the output gate: one column a query head, as wq's
     "blocks/wfc": (2,),  # column-parallel output
     "blocks/bfc": (1,),
     # SwiGLU gate/up stack: column-parallel output features

@@ -123,7 +123,10 @@ def _deepseek_forward_flops_per_token(c) -> float:
     Or a stack of more than one kind of layer (``layer_types``): a ``window``
     layer's scores are counted over its true pairs, W (W + 1) / 2 + (S - W) W
     a head a sequence of S with W = min(sliding_window, S), the exact count
-    (causal's S / 2 keys a token is the convention for the global ones)."""
+    (causal's S / 2 keys a token is the convention for the global ones); each
+    kind at its own head count (``layer_heads``), with the output gate's
+    projection where the config has one (``attn_gate``: 2 D H a token). Rotary
+    over a part of a head is elementwise, as whole heads' is: not counted."""
     D, H, S = c.n_embd, c.n_head, c.block_size
     attn_tokens = S / 2 if c.causal else S
     copies = 1
@@ -134,30 +137,59 @@ def _deepseek_forward_flops_per_token(c) -> float:
         projections = (2 * D * H * (Dn + Dr) + 2 * D * (R + Dr) + 2 * R * H * (Dn + Dv)
                        + 2 * H * Dv * D)
         scores = 2 * attn_tokens * H * (Dn + Dr + Dv)
+    elif getattr(c, "layer_heads", None) or getattr(c, "attn_gate", False):
+        return _by_kind_forward_flops_per_token(c, attn_tokens, copies)
     else:
         Dh = c.head_dim
         projections = 2 * D * (H + 2 * c.kv_heads) * Dh + 2 * H * Dh * D
         scores = 4 * attn_tokens * H * Dh
         windows = (c.layer_types or ()).count("window")
         if windows:  # the mean over the stack: window layers by their true pairs
-            W = min(c.sliding_window, S)
-            window_tokens = (W * (W + 1) / 2 + (S - W) * W) / S
-            scores *= (windows * window_tokens / attn_tokens + c.n_layer - windows) / c.n_layer
-    F = c.mlp_dim
+            scores *= (windows * _window_tokens(c) / attn_tokens + c.n_layer - windows) / c.n_layer
+    kda_layers = (c.layer_types or ()).count("kda")
+    return float(
+        (c.n_layer - kda_layers) * (copies * projections + scores)
+        + kda_layers * kda_forward_flops_per_token(c)
+        + copies * _mlp_forward_flops_per_token(c)
+        + 2 * D * c.vocab_size
+    )
+
+
+def _window_tokens(c) -> float:
+    """Keys a token of a ``window`` layer meets, the mean over a sequence."""
+    S = c.block_size
+    W = min(c.sliding_window, S)
+    return (W * (W + 1) / 2 + (S - W) * W) / S
+
+
+def _mlp_forward_flops_per_token(c) -> float:
+    """The MLPs of the whole depth a token: the leading dense layers', and the
+    routed layers' by their active parameters on this chip."""
+    D, F = c.n_embd, c.mlp_dim
     if c.n_experts > 0:
         routed_rows = c.expert_top_k * c.n_experts_held / c.n_experts
         mlp = 2 * D * c.n_experts + 6 * D * F * (routed_rows + c.n_shared_experts)
     else:
         mlp = (6 if c.mlp_act == "swiglu" else 4) * D * F
     dense = 6 * D * (c.dense_mlp_hidden or 0)
-    routed_layers = c.n_layer - c.first_k_dense
-    kda_layers = (c.layer_types or ()).count("kda")
-    return float(
-        (c.n_layer - kda_layers) * (copies * projections + scores)
-        + kda_layers * kda_forward_flops_per_token(c)
-        + copies * (c.first_k_dense * dense + routed_layers * mlp)
-        + 2 * D * c.vocab_size
-    )
+    return c.first_k_dense * dense + (c.n_layer - c.first_k_dense) * mlp
+
+
+def _by_kind_forward_flops_per_token(c, attn_tokens: float, copies: int) -> float:
+    """A stack whose attention kinds have head counts of their own and, maybe,
+    the per-head output gate: every attention layer at its kind's count
+    (``copies`` of the matmuls a data token, as the caller counts them)."""
+    D, Dh = c.n_embd, c.head_dim
+    attention = 0.0
+    for kind in c.layer_types or (None,) * c.n_layer:
+        if kind == "kda":
+            attention += kda_forward_flops_per_token(c)
+            continue
+        H = c.heads(kind)
+        tokens = _window_tokens(c) if kind == "window" else attn_tokens
+        attention += copies * (2 * D * (H + 2 * c.kv_heads) * Dh + 2 * H * Dh * D
+                               + (2 * D * H if c.attn_gate else 0)) + 4 * tokens * H * Dh
+    return float(attention + copies * _mlp_forward_flops_per_token(c) + 2 * D * c.vocab_size)
 
 
 def kda_forward_flops_per_token(c) -> float:

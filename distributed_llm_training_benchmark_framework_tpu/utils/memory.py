@@ -143,7 +143,10 @@ def estimate_hbm(
 
     # --- analytic activations for one microbatch's fwd+bwd on this chip ---
     B = per_device_batch  # per-data-parallel-shard batch
-    S, D, L, H, V = seq_len, cfg.n_embd, cfg.n_layer, cfg.n_head, cfg.vocab_size
+    S, D, L, V = seq_len, cfg.n_embd, cfg.n_layer, cfg.vocab_size
+    # the widest kind's query heads where a kind has its own (layer_heads); the
+    # output gate adds one (B, S, H) f32 a layer, which the coefficients below hold
+    H = max([cfg.n_head] + [n for _, n in getattr(cfg, "layer_heads", None) or ()])
     # Block diffusion runs its layers over the stream of two copies of each
     # document; the head sees the noisy copy only (the logits below stay S).
     layer_S = 2 * S if getattr(cfg, "block_diffusion", None) is not None else S

@@ -58,6 +58,11 @@ KDA_PREP, KDA_CORE, KDA_OUT = KDA_SCOPES = ("kda_prep", "kda_core", "kda_out")
 # gradients' partial sums. A layer on the ``jnp`` chain has no such scope.
 QK_PROLOGUE = "qk_prologue"
 
+# Inside ``attention`` (below the kind's scope where there is one), where the
+# config has ``attn_gate``: the gate's projection, its sigmoid and the product
+# with the kernel's (B, S, H, head_dim) result, before ``wo``.
+ATTN_GATE = "attn_gate"
+
 # Inside ``embed``, under block diffusion (models/tinygpt.py ``bd_stream``):
 # drawing a noise level a block, masking, and joining the noisy copy to the
 # clean one.

@@ -5,7 +5,7 @@ for (``models/tinygpt.py``: ``_rms_norm`` -> ``_rope``), the forward and the
 backward apart, at a cell's operand.
 
     chiprun -- python scripts/microbench_qk_prologue.py [--rows 16384] [--batch 1]
-        [--heads 32] [--kv-heads 4] [--norm 1] [--iters 20] [--copies 8]
+        [--heads 32] [--kv-heads 4] [--norm 1] [--rotary-dim 64] [--iters 20] [--copies 8]
         [--out chiprun_out/x.jsonl]
 
 A line a variant: ms a layer's call, the bytes the pass needs from its shapes
@@ -41,6 +41,8 @@ def main():
     ap.add_argument("--heads", type=int, default=32)
     ap.add_argument("--kv-heads", type=int, default=4)
     ap.add_argument("--norm", type=int, default=1)
+    ap.add_argument("--rotary-dim", type=int, default=None,
+                    help="the leading lanes of a head that rotate (default: all 128)")
     ap.add_argument("--iters", type=int, default=20)
     ap.add_argument("--copies", type=int, default=8)
     ap.add_argument("--describe", action="store_true")
@@ -54,18 +56,20 @@ def main():
     from distributed_llm_training_benchmark_framework_tpu.ops import rotary
 
     B, S, H, KV, D, norm = args.batch, args.rows, args.heads, args.kv_heads, 128, bool(args.norm)
-    eps, theta = 1e-6, 1e6
+    eps, theta, part = 1e-6, 1e6, args.rotary_dim
 
     def the_pass(q, k, qs, ks):
-        table = rotary.table(jnp.arange(S, dtype=jnp.int32), D, theta)
-        return rotary.qk_prologue(q, k, qs if norm else None, ks if norm else None, table, eps)
+        table = rotary.table(jnp.arange(S, dtype=jnp.int32), D, theta, rotary_dim=part)
+        return rotary.qk_prologue(q, k, qs if norm else None, ks if norm else None, table, eps,
+                                  rotary_dim=part)
 
     def the_chain(q, k, qs, ks):
         pos = jnp.arange(S, dtype=jnp.int32)
         q, k = q.reshape(B, S, H, D), k.reshape(B, S, KV, D)
         if norm:
             q, k = tinygpt._rms_norm(q, qs, eps), tinygpt._rms_norm(k, ks, eps)
-        return tinygpt._rope(q, pos, theta), tinygpt._rope(k, pos, theta)
+        return (tinygpt._rope(q, pos, theta, rotary_dim=part),
+                tinygpt._rope(k, pos, theta, rotary_dim=part))
 
     def forward(fn, q, k, qs, ks, wq, wk):
         a, b = fn(q, k, qs, ks)  # (B, S, heads, D) -> head-major, as flash reads them
@@ -135,7 +139,7 @@ def main():
                 variant=name, ms=ms, pass_needs_gb=need / 1e9,
                 of_hbm_rate_pct=100 * need / hbm_bytes_per_s / (ms / 1e3),
                 device=kind))
-    shape = dict(batch=B, rows=S, heads=H, kv_heads=KV, norm=norm)
+    shape = dict(batch=B, rows=S, heads=H, kv_heads=KV, norm=norm, rotary_dim=part or D)
     for row in rows:
         print(json.dumps({**shape, **row}), flush=True)
     if args.out:

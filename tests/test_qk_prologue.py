@@ -300,6 +300,8 @@ CELLS = {
     "deepseek-v2-lite.share8-seq8192": (6, 0, 0, 0),
     "sdar-30b-a3b.share8-bd8192": (6, 6, 6, 4096 + 512),
     "mellum2-12b-a2.5b.share4-seq16384": (4, 4, 4, 4096 + 512),
+    # head counts by kind: the bytes are the full layers' (48 + 8 heads; ``by_kind`` has both)
+    "laguna-xs.2.share16-seq16384": (5, 5, 0, 6144 + 1024),
 }
 
 
