@@ -1145,10 +1145,24 @@ def _dropout(x: jax.Array, rate: float, key: Optional[jax.Array], deterministic:
     return jnp.where(mask, x / keep, jnp.zeros((), x.dtype)).astype(x.dtype)
 
 
+def _whole_heads(q: jax.Array, k: jax.Array, v: jax.Array) -> Tuple[jax.Array, jax.Array]:
+    """k and v (B, S, KV, .) broadcast to q's head count, each kv head to its
+    query group, for the attention bodies that take a k and a v a query head.
+    Consecutive-block repetition matches the TP layout: query-head shard j
+    needs exactly kv-head shard j when the 'model' degree divides kv_heads;
+    when it does not, the kv-head-aligned spec rule keeps wkv replicated over
+    'model' (strategies.param_partition_specs) so this never needs the
+    partitioner's full-replicate resharding fallback."""
+    rep = q.shape[2] // k.shape[2]
+    if rep == 1:
+        return k, v
+    return jnp.repeat(k, rep, axis=2), jnp.repeat(v, rep, axis=2)
+
+
 def _attention(
     config: TinyGPTConfig,
     q: jax.Array,  # (B, S, H, Dh)
-    k: jax.Array,
+    k: jax.Array,  # (B, S, KV, Dh): the model's kv heads, H a multiple
     v: jax.Array,
     dropout_key: Optional[jax.Array],
     deterministic: bool,
@@ -1156,6 +1170,10 @@ def _attention(
 ) -> jax.Array:
     """Dispatch to the configured attention implementation. Returns (B,S,H,Dh).
     ``kind`` is the layer's (``TinyGPTConfig.layer_types``): its mask rule.
+
+    'flash' takes k and v at their own head count (its kernels' index maps
+    find a query head's kv head); every other body takes them broadcast to
+    the query heads (``_whole_heads``).
 
     Attention-probability dropout (reference train_harness.py:116) applies in
     ALL THREE impls: materialized bernoulli in 'reference', and the shared
@@ -1177,6 +1195,8 @@ def _attention(
         dropout_seed=seed,
     )
     rule = config.mask_rule(q.shape[1], kind)
+    if config.attention_impl != "flash":
+        k, v = _whole_heads(q, k, v)
     if config.latent_attention and (
         config.seq_manual_axis is not None
         or config.attention_impl not in ("flash", "reference")
@@ -1556,16 +1576,6 @@ def _attention_sublayer(
             pos = _rotary_positions(c, S)
             q = _rope(q, pos, rotary.theta, rotary.scaling, rotary.rotary_dim)
             k = _rope(k, pos, rotary.theta, rotary.scaling, rotary.rotary_dim)
-    if c.kv_heads != H:
-        # Broadcast each K/V head to its query group. Consecutive-block
-        # repetition matches the TP layout: query-head shard j needs exactly
-        # kv-head shard j when the 'model' degree divides kv_heads; when it
-        # does not, the kv-head-aligned spec rule keeps wkv replicated over
-        # 'model' (strategies.param_partition_specs) so this reshape never
-        # needs the partitioner's full-replicate resharding fallback.
-        rep = H // c.kv_heads
-        k = jnp.repeat(k, rep, axis=2)
-        v = jnp.repeat(v, rep, axis=2)
     attn = _attention(c, q, k, v, dropout_key, deterministic, kind)
     if "wg" in layer:
         with jax.named_scope(scopes.ATTN_GATE):
@@ -1851,6 +1861,20 @@ def bd_stream(
     return jnp.concatenate([noisy, idx], axis=1), weights, masked
 
 
+def _kv_heads_in_kernel(config: TinyGPTConfig, kind: Optional[str] = None) -> int:
+    """The head count k and v enter a ``kind`` layer's attention body with:
+    the model's ``kv_heads`` where the flash kernels read them as they are
+    (``ops.flash_attention.kv_heads_in_kernel``: under the mesh this is called
+    in), the query heads' where a body takes whole heads (``_whole_heads``)
+    or the layer makes a k and a v a head itself (latent attention)."""
+    from ..ops import flash_attention as fa
+
+    heads = config.heads(kind)
+    if config.attention_impl != "flash" or config.latent_attention:
+        return heads
+    return fa.kv_heads_in_kernel(heads, config.kv_heads)
+
+
 def bd_mask_stats(config: TinyGPTConfig, seq_len: int) -> Dict[str, int]:
     """Counters of one head's attention over documents of ``seq_len`` tokens
     under ``block_diffusion``, from the mask rule (no array is made): the true
@@ -1859,7 +1883,8 @@ def bd_mask_stats(config: TinyGPTConfig, seq_len: int) -> Dict[str, int]:
     and in the unit each kernel skips by (``visited_units``): the (piece,
     piece) piece where the rule gives its tiles shapes (``*_live_tiles``
     pieces visited of ``*_tiles``, ``*_tile_pairs`` pairs a piece), the
-    whole tile where it does not."""
+    whole tile where it does not; and ``kv_heads_in_kernel``, the heads of k
+    and v the kernels were handed (``_kv_heads_in_kernel``)."""
     from ..ops import flash_attention as fa
 
     S = 2 * seq_len
@@ -1872,6 +1897,7 @@ def bd_mask_stats(config: TinyGPTConfig, seq_len: int) -> Dict[str, int]:
         "true_pairs": rule.tile_counts(bq, bk)[2],
         "fwd_live_tiles": live_fwd, "fwd_tiles": all_fwd, "fwd_tile_pairs": unit_fwd,
         "bwd_live_tiles": live_bwd, "bwd_tiles": all_bwd, "bwd_tile_pairs": unit_bwd,
+        "kv_heads_in_kernel": _kv_heads_in_kernel(config),
     }
 
 
@@ -1880,7 +1906,10 @@ def attn_mask_stats(config: TinyGPTConfig, seq_len: int) -> Dict[str, Dict[str, 
     layer (``layer_types``; one entry, ``global``, for a stack of one kind),
     from each kind's mask rule at the tiles and pieces ``ops.flash_attention``
     picks (no array is made): ``layers`` of the kind, ``true_pairs`` the rule
-    allows, the kind's query ``heads``, the (queries, keys) ``fwd_tile`` and
+    allows, the kind's query ``heads`` and the ``kv_heads_in_kernel`` its k and v
+    entered the kernels with (``_kv_heads_in_kernel``: the model's kv heads
+    where the index maps do the sharing, ``heads`` where k and v were
+    repeated or nothing is shared), the (queries, keys) ``fwd_tile`` and
     ``bwd_tile`` taken, and for the forward and the fused backward kernel ``*_live_tiles``
     (tiles that hold a pair), ``*_grid_steps`` (steps a head's grid makes: the
     square's under causal, the band's under a window; the difference brings a
@@ -1898,6 +1927,8 @@ def attn_mask_stats(config: TinyGPTConfig, seq_len: int) -> Dict[str, Dict[str, 
         window = isinstance(rule, fa.SlidingWindow)
         entry = {"layers": kinds.count(kind),
                  "heads": config.heads(kind if config.layer_types else None),
+                 "kv_heads_in_kernel": _kv_heads_in_kernel(
+                     config, kind if config.layer_types else None),
                  "fwd_tile": (bq, bk), "bwd_tile": (bq, bk_bwd),
                  "true_pairs": (rule.true_pairs(seq_len) if window
                                 else seq_len * (seq_len + 1) // 2 if rule else seq_len ** 2)}

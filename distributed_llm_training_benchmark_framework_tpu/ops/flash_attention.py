@@ -48,6 +48,11 @@ Partitioning: a Mosaic kernel is a custom call GSPMD cannot split, so under a
 mesh of more than one device ``flash_attention`` shard_maps itself, batch and
 heads split over the axes that shard them (see ``_kernel_mesh_axes``). The dropout hash is keyed by GLOBAL
 (batch, head) ids fed in as sharded data, so masks do not depend on the mesh.
+
+Grouped-query attention: k and v come at the model's kv head count, H // KV
+consecutive query heads to a kv head, and every BlockSpec that addresses k or
+v sends the grid's (batch, query head) row b to row b // rep (``_kv_row``):
+no repeated copy of k or v exists in front of the kernels or behind them.
 """
 
 from __future__ import annotations
@@ -560,6 +565,27 @@ def _softmax_scale(scale: Optional[float], d_qk: int) -> float:
     return 1.0 / (d_qk ** 0.5) if scale is None else scale
 
 
+def _kv_row(rep: int):
+    """Grid row (a batch x query head pair) -> the row of k and v it reads
+    where ``rep`` query heads share a kv head, in consecutive groups
+    (``jnp.repeat``'s order, and the tensor-parallel layout's): b // rep; the
+    identity, the index map there was, where none is shared."""
+    return (lambda b: b) if rep == 1 else (lambda b: b // rep)
+
+
+def _repeat_groups(t: jax.Array, rep: int) -> jax.Array:
+    """(rows, ...) kv rows -> (rows * rep, ...), each once a query head of
+    its group: for the paths that take whole heads (the ``jnp`` ones and the
+    kernel pair, off the timed path)."""
+    return t if rep == 1 else jnp.repeat(t, rep, axis=0)
+
+
+def _sum_groups(t: jax.Array, rep: int) -> jax.Array:
+    """(rows * rep, ...) gradients a query head -> (rows, ...) a kv head:
+    ``_repeat_groups``'s transpose."""
+    return t if rep == 1 else t.reshape(-1, rep, *t.shape[1:]).sum(1)
+
+
 def _fwd_sub_k(bk: int) -> int:
     """Keys of the compute piece the forward kernel walks a (bq, bk) DMA tile
     in: 128, one MXU weight tile of P. A tile no wider than that, or one it
@@ -843,7 +869,7 @@ def _jnp_reference_forward(
 @functools.lru_cache(maxsize=64)
 def _forward_call(
     BH, S, D, Dv, dtype, vma, mask, interpret, bq, bk, sub_k, scale,
-    dropout_rate,
+    dropout_rate, rep=1,
 ):
     """The forward's ``pallas_call`` on (seed, bhv, q, k, v) for one shape and
     one set of static choices, made once a process. What ``pl.pallas_call``
@@ -860,13 +886,18 @@ def _forward_call(
     Under a ``SlidingWindow`` the grid's last axis is the band and not the
     square's row: ``band_steps`` steps a query tile, the key tile's block
     index computed from the step (held at 0 in the clipped corner, where the
-    block repeats and nothing is fetched anew)."""
+    block repeats and nothing is fetched anew).
+
+    k and v hold BH // ``rep`` rows, a kv head each: the grid's row ``b``, a
+    (batch, query head) pair, reads row b // rep of them (``_kv_row``); q,
+    out and lse keep ``b``."""
     band = mask.band_steps(S, bq, bk, True) if isinstance(mask, SlidingWindow) else None
+    kv = _kv_row(rep)
     if band is None:
-        key_spec = lambda b, qi, ki: (b, ki, 0)
+        key_spec = lambda b, qi, ki: (kv(b), ki, 0)
     else:
         key_spec = lambda b, qi, step: (
-            b, jnp.maximum(mask.key_tile(qi, bq, bk, step, band), 0), 0)
+            kv(b), jnp.maximum(mask.key_tile(qi, bq, bk, step, band), 0), 0)
     return pl.pallas_call(
         functools.partial(
             _flash_fwd_kernel, bq=bq, bk=bk, sub_k=sub_k, scale=scale,
@@ -907,25 +938,29 @@ def _flash_forward(
     dropout_rate: float, seed: jax.Array, bhv: jax.Array,
     sub_k: Optional[int] = None, scale: Optional[float] = None,
 ) -> Tuple[jax.Array, jax.Array]:
-    """Run the Pallas kernel on (BH, S, D) q and k and (BH, S, Dv) v ->
-    (out (BH, S, Dv), lse); Dv is D everywhere but latent attention, whose
+    """Run the Pallas kernel on (BH, S, D) q, (BKV, S, D) k and (BKV, S, Dv)
+    v -> (out (BH, S, Dv), lse); BH a multiple of BKV, query row b reading kv
+    row b // (BH // BKV) (grouped-query attention: the kernel's index maps,
+    no repeated copy); Dv is D everywhere but latent attention, whose
     keys carry a rotary part the values lack. ``bhv`` is
     the (BH,) int32 vector of GLOBAL batch*head ids keying the dropout hash
     (arange(BH) on one device; mesh-global ids under a shard_map).
     ``sub_k`` forces the compute piece (tests and the microbench; no flag or
     config field reaches it); ``_fwd_sub_k`` chooses it otherwise."""
     BH, S, D = q.shape
+    rep = BH // k.shape[0]
     scale = _softmax_scale(scale, D)
     from ..utils.vma import vma_of
 
     vma = vma_of(q, k, v)
     if interpret and vma:
         return _jnp_reference_forward(
-            q, k, v, mask, dropout_rate, seed, bhv, scale
+            q, _repeat_groups(k, rep), _repeat_groups(v, rep), mask,
+            dropout_rate, seed, bhv, scale,
         )
     call = _forward_call(
         BH, S, D, v.shape[-1], q.dtype, vma, mask, interpret, bq, bk,
-        sub_k or _fwd_sub_k(bk), scale, dropout_rate,
+        sub_k or _fwd_sub_k(bk), scale, dropout_rate, rep,
     )
     # jit keys a trace by its context too, and with no mesh set the primal
     # is traced under none and the forward rule under an empty one: name the
@@ -1214,7 +1249,7 @@ def _bwd_fused_kernel(
     seed_ref, bhv_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     dq_ref, dk_ref, dv_ref, dq_acc, dk_acc, dv_acc,
     *, bq: int, bk: int, sub_q: int, scale: float, mask: MaskRule,
-    dropout_rate: float, band: Optional[int] = None,
+    dropout_rate: float, band: Optional[int] = None, group: int = 1,
 ):
     """dq, dk and dv from ONE visit of each live (k tile, q tile): s, p, dp,
     the keep mask and ds are computed once and feed all three products
@@ -1259,11 +1294,15 @@ def _bwd_fused_kernel(
     last tile in the band's clipped corner, where the step multiplies
     nothing): a q tile's slice of ``dq_acc`` is zeroed at the first key tile
     of its band and written out at the last, the diagonal's."""
-    bh = pl.program_id(0)
-    ki = pl.program_id(1)
-    step = pl.program_id(2)
-    nk = pl.num_programs(1)
-    steps = pl.num_programs(2)
+    if group == 1:
+        bh, axis = pl.program_id(0), 1
+    else:
+        member, axis = pl.program_id(1), 2
+        bh = pl.program_id(0) * group + member
+    ki = pl.program_id(axis)
+    step = pl.program_id(axis + 1)
+    nk = pl.num_programs(axis)
+    steps = pl.num_programs(axis + 1)
     if band is None:
         qi = step
     else:
@@ -1276,10 +1315,15 @@ def _bwd_fused_kernel(
     c = scale * _LOG2_E
     keep_prob = 1.0 - dropout_rate
 
-    @pl.when(step == 0)
+    # Where the k tile lies in ``dk_acc`` / ``dv_acc``: they are the tile, or,
+    # under a group, the kv head's whole rows. A head opens and closes its own
+    # tile; a group's first and last head open and close the kv head's.
+    tile_rows = slice(None) if group == 1 else pl.ds(pl.multiple_of(k_off, bk), bk)
+
+    @pl.when(step == 0 if group == 1 else (step == 0) & (member == 0))
     def _init_kv():
-        dk_acc[:] = jnp.zeros_like(dk_acc)
-        dv_acc[:] = jnp.zeros_like(dv_acc)
+        dk_acc[tile_rows, :] = jnp.zeros((bk, dk_acc.shape[1]), dk_acc.dtype)
+        dv_acc[tile_rows, :] = jnp.zeros((bk, dv_acc.shape[1]), dv_acc.dtype)
 
     if band is None:
         first_pass = ki == 0
@@ -1306,6 +1350,7 @@ def _bwd_fused_kernel(
                 keys = slice(None) if shape == FULL else pl.ds(0, hi * sub_q)
                 k = k_ref[0, keys, :]
                 v = v_ref[0, keys, :]
+                acc_rows = keys if group == 1 else pl.ds(tile_rows.start, k.shape[0])
                 # Narrow coordinate operands, as in the other kernels; key
                 # positions ("cols" of the hash) run down the sublanes here.
                 cols = lax.add(
@@ -1348,11 +1393,11 @@ def _bwd_fused_kernel(
                 dp = _fill_where(keep, dp, 0.0)
                 delta = lax.mul(delta, keep_prob)
             ds = lax.mul(p, lax.sub(dp, delta)).astype(q.dtype)  # ds / scale
-            dv_acc[keys, :] = lax.add(dv_acc[keys, :], lax.dot_general(
+            dv_acc[acc_rows, :] = lax.add(dv_acc[acc_rows, :], lax.dot_general(
                 pd.astype(q.dtype), do, (((1,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32,
             ))
-            dk_acc[keys, :] = lax.add(dk_acc[keys, :], lax.dot_general(
+            dk_acc[acc_rows, :] = lax.add(dk_acc[acc_rows, :], lax.dot_general(
                 ds, q, (((1,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32,
             ))
@@ -1369,10 +1414,10 @@ def _bwd_fused_kernel(
         pl.when(live & lower)(functools.partial(_accumulate, LOWER))
         pl.when(live & ~lower)(functools.partial(_accumulate, FULL))
 
-    @pl.when(step == steps - 1)
+    @pl.when(step == steps - 1 if group == 1 else (step == steps - 1) & (member == group - 1))
     def _finalize_kv():
-        dk_ref[0] = (dk_acc[:] * scale).astype(dk_ref.dtype)
-        dv_ref[0] = dv_acc[:].astype(dv_ref.dtype)
+        dk_ref[0, tile_rows, :] = (dk_acc[tile_rows, :] * scale).astype(dk_ref.dtype)
+        dv_ref[0, tile_rows, :] = dv_acc[tile_rows, :].astype(dv_ref.dtype)
 
     if band is None:
         last_pass = ki == nk - 1
@@ -1396,60 +1441,96 @@ _FUSED_TILE_VMEM = 32 * 2**20
 _FUSED_MAX_VMEM = 96 * 2**20
 
 
-def _fused_vmem_bytes(S: int, D: int, dtype) -> int:
+def _fused_vmem_bytes(S: int, D: int, dtype, grouped_dv: Optional[int] = None) -> int:
+    """``grouped_dv``: v's width where dk and dv are resident rows too (the
+    grouped form: ``_grouped_fits``)."""
+    row = S * (4 + 2 * jnp.dtype(dtype).itemsize)
     lanes = -(-D // 128) * 128
-    return S * lanes * (4 + 2 * jnp.dtype(dtype).itemsize) + _FUSED_TILE_VMEM
+    rows = lanes if grouped_dv is None else 2 * lanes + -(-grouped_dv // 128) * 128
+    return row * rows + _FUSED_TILE_VMEM
 
 
 def _fused_fits(S: int, D: int, dtype) -> bool:
     return _fused_vmem_bytes(S, D, dtype) <= _FUSED_MAX_VMEM
 
 
+def _grouped_fits(S: int, D: int, Dv: int, dtype) -> bool:
+    """Whether a kv head's dk and dv rows fit VMEM beside the dq row: three
+    resident rows where ``_fused_fits`` counts one (S 16,384 at head dims to
+    128 in bf16: 80 MiB; S 32,768 does not fit)."""
+    return _fused_vmem_bytes(S, D, dtype, Dv) <= _FUSED_MAX_VMEM
+
+
 @functools.lru_cache(maxsize=64)
 def _fused_call(
     BH, S, D, Dv, dtypes, vma, mask, interpret, bq, bk, sub_q, scale, rate,
+    rep=1, grouped=False,
 ):
     """The fused backward's ``pallas_call`` on (seed, bhv, q, k, v, do, lse3,
     delta3), made once a process for a shape and its static choices, as
     ``_forward_call`` and for its reason; under a ``SlidingWindow`` its
     grid's last axis is the band, as the forward's (the query tile's block
-    index held at the last tile in the clipped corner)."""
+    index held at the last tile in the clipped corner).
+
+    k and v hold BH // ``rep`` rows and are read at row b // rep, as the
+    forward reads them. dk and dv leave in one of two forms. ``grouped``: the
+    grid is (kv rows, a group's ``rep`` heads, k tiles, q tiles), ``dk_acc``
+    and ``dv_acc`` hold the kv head's whole rows and take every head of the
+    group, and dk / dv are written once a kv head, (BH // rep, S, .): no sum
+    behind the kernel and 1 / rep of the writes (my chip run, PR 48: 0.9 ms
+    of a 22.5 ms call at the SDAR cell's shape, 1.1 of 9.5 at Laguna's
+    window layer's). Else a query head, (BH, S, .), as they always have,
+    for ``_fused_backward`` to sum (what a sequence too long for three
+    resident rows takes)."""
     band = mask.band_steps(S, bq, bk, False) if isinstance(mask, SlidingWindow) else None
+    kv = _kv_row(rep)
     if band is None:
         q_tile = lambda ki, qi: qi
     else:
         q_tile = lambda ki, step: jnp.minimum(mask.query_tile(ki, bq, bk, step), S // bq - 1)
-    q_spec = pl.BlockSpec((1, bq, D), lambda b, ki, qi: (b, q_tile(ki, qi), 0))
-    k_spec = pl.BlockSpec((1, bk, D), lambda b, ki, qi: (b, ki, 0))
-    do_spec = pl.BlockSpec((1, bq, Dv), lambda b, ki, qi: (b, q_tile(ki, qi), 0))
-    v_spec = pl.BlockSpec((1, bk, Dv), lambda b, ki, qi: (b, ki, 0))
-    stat_spec = pl.BlockSpec((1, 8, bq), lambda b, ki, qi: (b, 0, q_tile(ki, qi)))
+    if grouped:
+        # grid (kv rows, the group's heads, k tiles, q tiles): dk / dv rows
+        # of a kv head stay in VMEM over its group and leave once
+        def rows(index):  # (g, r, ki, qi) -> (q row, kv row, ki, qi)
+            return lambda g, r, ki, qi: index(g * rep + r, g, ki, qi)
+        acc_rows, dkv_block = S, lambda b, g, ki, qi: (g, 0, 0)
+    else:
+        def rows(index):
+            return lambda b, ki, qi: index(b, kv(b), ki, qi)
+        acc_rows, dkv_block = bk, lambda b, g, ki, qi: (b, ki, 0)
+    q_spec = pl.BlockSpec((1, bq, D), rows(lambda b, g, ki, qi: (b, q_tile(ki, qi), 0)))
+    k_spec = pl.BlockSpec((1, bk, D), rows(lambda b, g, ki, qi: (g, ki, 0)))
+    do_spec = pl.BlockSpec((1, bq, Dv), rows(lambda b, g, ki, qi: (b, q_tile(ki, qi), 0)))
+    v_spec = pl.BlockSpec((1, bk, Dv), rows(lambda b, g, ki, qi: (g, ki, 0)))
+    dk_spec = pl.BlockSpec((1, acc_rows, D), rows(dkv_block))
+    dv_spec = pl.BlockSpec((1, acc_rows, Dv), rows(dkv_block))
+    stat_spec = pl.BlockSpec((1, 8, bq), rows(lambda b, g, ki, qi: (b, 0, q_tile(ki, qi))))
     smem = pl.BlockSpec(memory_space=pltpu.SMEM)
     return pl.pallas_call(
         functools.partial(
             _bwd_fused_kernel, bq=bq, bk=bk, sub_q=sub_q, scale=scale,
-            mask=mask, dropout_rate=rate, band=band,
+            mask=mask, dropout_rate=rate, band=band, group=rep if grouped else 1,
         ),
         out_shape=[
             _struct((BH, S, D), dtypes[0], vma),
-            _struct((BH, S, D), dtypes[1], vma),
-            _struct((BH, S, Dv), dtypes[2], vma),
+            _struct((BH // rep if grouped else BH, S, D), dtypes[1], vma),
+            _struct((BH // rep if grouped else BH, S, Dv), dtypes[2], vma),
         ],
-        grid=(BH, S // bk, S // bq if band is None else band),
+        grid=((BH // rep, rep) if grouped else (BH,)) + (S // bk, S // bq if band is None else band),
         in_specs=[smem, smem, q_spec, k_spec, v_spec, do_spec,
                   stat_spec, stat_spec],
         out_specs=[
-            pl.BlockSpec((1, S, D), lambda b, ki, qi: (b, 0, 0)),
-            k_spec, v_spec,
+            pl.BlockSpec((1, S, D), rows(lambda b, g, ki, qi: (b, 0, 0))),
+            dk_spec, dv_spec,
         ],
         scratch_shapes=[
             pltpu.VMEM((S, D), jnp.float32),   # dq, the whole row
-            pltpu.VMEM((bk, D), jnp.float32),  # dk, one k tile
-            pltpu.VMEM((bk, Dv), jnp.float32),  # dv
+            pltpu.VMEM((acc_rows, D), jnp.float32),  # dk, one k tile or the kv head's rows
+            pltpu.VMEM((acc_rows, Dv), jnp.float32),  # dv
         ],
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary", "arbitrary"),
-            vmem_limit_bytes=_fused_vmem_bytes(S, D, dtypes[0]),
+            dimension_semantics=("parallel",) + ("arbitrary",) * (3 if grouped else 2),
+            vmem_limit_bytes=_fused_vmem_bytes(S, D, dtypes[0], Dv if grouped else None),
         ),
         name="flash_bwd_fused",
         interpret=interpret,
@@ -1466,19 +1547,31 @@ def forget_kernel_calls() -> None:
 
 def _fused_backward(
     q, k, v, do, lse3, delta3, seed, bhv, mask, rate, bq, bk, interpret,
-    scale=None, sub=None,
+    scale=None, sub=None, grouped=None,
 ):
-    """The one-kernel Pallas backward on (BH, S, D) q and k and (BH, S, Dv)
-    v and do. ``sub`` forces the compute piece (tests and the microbench; no
-    flag or config field reaches it); ``_bwd_sub_q`` chooses it otherwise."""
+    """The one-kernel Pallas backward on (BH, S, D) q, (BKV, S, D) k,
+    (BKV, S, Dv) v and (BH, S, Dv) do -> dq (BH, S, D), dk (BKV, S, D), dv
+    (BKV, S, Dv). Where query heads share kv heads the kernel sums a group's
+    dk and dv itself if their rows fit VMEM (``_grouped_fits``: decided from
+    S, the widths and the dtype); else they leave it a query head and the sum
+    runs behind it. ``sub`` forces the compute piece and ``grouped`` the form
+    (tests and the microbench; no flag or config field reaches them);
+    ``_bwd_sub_q`` chooses the piece otherwise."""
     from ..utils.vma import vma_of
 
     BH, S, D = q.shape
-    return _fused_call(
+    rep = BH // k.shape[0]
+    if grouped is None:
+        grouped = rep > 1 and _grouped_fits(S, D, v.shape[-1], q.dtype)
+    dq, dk, dv = _fused_call(
         BH, S, D, v.shape[-1], (q.dtype, k.dtype, v.dtype),
         vma_of(q, k, v, do), mask, interpret, bq, bk,
         sub or _bwd_sub_q(bq, rate), _softmax_scale(scale, D), rate,
+        rep, grouped,
     )(seed, bhv, q, k, v, do, lse3, delta3)
+    if not grouped:
+        dk, dv = _sum_groups(dk, rep), _sum_groups(dv, rep)
+    return dq, dk, dv
 
 
 def _pair_backward(
@@ -1584,10 +1677,16 @@ def _flash_bwd_rule(opts, res, do):
         # around: the Pallas HLO interpreter cannot run on vma-carrying
         # operands (manual regions in interpret mode) — take the jnp backward.
         pallas_bwd = False
-    if not pallas_bwd:
-        return (*_jnp_blockwise_bwd(mask, bk, rate, res, do, scale), *int_cts)
     q, k, v, out, lse, seed, bhv = res
     BH, S, D = q.shape
+    rep = BH // k.shape[0]
+    fused = pallas_bwd and _fused_fits(S, D, q.dtype)
+    if not fused:  # whole heads in: off the grouped-query cells' timed path
+        k, v = _repeat_groups(k, rep), _repeat_groups(v, rep)
+    if not pallas_bwd:
+        dq, dk, dv = _jnp_blockwise_bwd(
+            mask, bk, rate, (q, k, v, out, lse, seed, bhv), do, scale)
+        return dq, _sum_groups(dk, rep), _sum_groups(dv, rep), *int_cts
 
     delta = jnp.sum(
         do.astype(jnp.float32) * out.astype(jnp.float32), axis=-1
@@ -1596,12 +1695,11 @@ def _flash_bwd_rule(opts, res, do):
     # the (8, 128) input-tile constraint (same trick as the forward's output).
     lse3 = jnp.broadcast_to(lse[:, None, :], (BH, 8, S))
     delta3 = jnp.broadcast_to(delta[:, None, :], (BH, 8, S))
-    backward = _fused_backward if _fused_fits(S, D, q.dtype) else _pair_backward
-    dq, dk, dv = backward(
-        q, k, v, do, lse3, delta3, seed, bhv, mask, rate, bq, bk, interpret,
-        scale,
-    )
-    return dq, dk, dv, *int_cts
+    args = (q, k, v, do, lse3, delta3, seed, bhv, mask, rate, bq, bk, interpret, scale)
+    if fused:
+        return *_fused_backward(*args), *int_cts
+    dq, dk, dv = _pair_backward(*args)
+    return dq, _sum_groups(dk, rep), _sum_groups(dv, rep), *int_cts
 
 
 _flash.defvjp(_flash_fwd_rule, _flash_bwd_rule)
@@ -1656,6 +1754,20 @@ def _kernel_mesh_axes():
     batch = tuple(a for a in _BATCH_AXES if a in split)
     heads = _HEADS_AXIS if _HEADS_AXIS in split else None
     return frozenset(m.axis_names), batch, heads
+
+
+def kv_heads_in_kernel(heads: int, kv_heads: int) -> int:
+    """The head count k and v enter the kernels with under the mesh this is
+    called in: the model's own ``kv_heads``, unless a heads axis splits the
+    call into more shards than there are kv heads to hand out whole (its
+    degree does not divide ``kv_heads``): then ``heads``, k and v repeated in
+    front of the ``shard_map``. Decided from the shapes and the mesh, as
+    ``flash_attention`` decides it; the models' counters read it."""
+    _, _, heads_axis = _kernel_mesh_axes()
+    if heads_axis is None:
+        return kv_heads
+    degree = jax.sharding.get_abstract_mesh().shape[heads_axis]
+    return kv_heads if kv_heads % degree == 0 else heads
 
 
 #: The least tile a narrow window's band takes (``_window_tile``).
@@ -1713,8 +1825,8 @@ def pick_tiles(
 )
 def flash_attention(
     q: jax.Array,  # (B, S, H, D)
-    k: jax.Array,
-    v: jax.Array,  # (B, S, H, Dv); Dv == D everywhere but latent attention
+    k: jax.Array,  # (B, S, KV, D); H a multiple of KV
+    v: jax.Array,  # (B, S, KV, Dv); Dv == D everywhere but latent attention
     causal: MaskRule = False,
     interpret: Optional[bool] = None,
     block_q: Optional[int] = None,
@@ -1736,6 +1848,14 @@ def flash_attention(
     192-wide keys over 128-wide values, no padding of either); ``scale``
     multiplies q k^T and defaults to 1 / sqrt(width of q).
 
+    k and v come at the model's own kv head count (grouped-query attention:
+    query heads [j * rep, (j + 1) * rep) read kv head j, rep = H // KV, the
+    grouping ``jnp.repeat(axis=2)`` gives): the kernels' index maps find a
+    query head's kv head and no repeated copy of k or v is made; dk and dv
+    come back at KV heads. Only under a mesh whose heads axis does not
+    divide KV are they repeated, in front of the ``shard_map``
+    (``kv_heads_in_kernel``).
+
     Forward and backward take separate K-block sizes because their optima
     differ on v5e (see _FWD_BLOCK_* notes above).
 
@@ -1753,6 +1873,12 @@ def flash_attention(
     GLOBAL ids and the loss matches a one-device run of the same batch.
     """
     B, S, H, D = q.shape
+    KV = k.shape[2]
+    if H % KV or v.shape[2] != KV:
+        raise ValueError(
+            f"{H} query heads over k {k.shape} / v {v.shape}: k and v share a "
+            "head count that divides the query heads'"
+        )
     interpret = _resolve_interpret(interpret)
     bq, bk, bk_bwd, pallas_backward = pick_tiles(
         S, D, q.dtype, interpret, pallas_backward, block_q, block_k, block_k_bwd, causal
@@ -1775,12 +1901,13 @@ def flash_attention(
     opts = (causal, interpret, bq, bk, bk_bwd, pallas_backward, dropout_rate, scale)
 
     def local(ql, kl, vl, seed_l, b_ids, h_ids):
-        # (Bl, S, Hl, D) -> (Bl*Hl, S, D): one grid row per (batch, head)
-        # pair, keyed for dropout by its global id b*H + h.
+        # (Bl, S, Hl, D) -> (Bl*Hl, S, D): one grid row per (batch, query
+        # head) pair, keyed for dropout by its global id b*H + h; k and v
+        # (Bl*KVl, S, .), row b // rep of them that grid row's.
         Bl, Hl = ql.shape[0], ql.shape[2]
 
         def to_bhsd(t):
-            return t.transpose(0, 2, 1, 3).reshape(Bl * Hl, S, t.shape[-1])
+            return t.transpose(0, 2, 1, 3).reshape(Bl * t.shape[2], S, t.shape[-1])
 
         bhv = (b_ids[:, None] * H + h_ids[None, :]).reshape(Bl * Hl)
         out = _flash(opts, to_bhsd(ql), to_bhsd(kl), to_bhsd(vl), seed_l, bhv)
@@ -1791,6 +1918,10 @@ def flash_attention(
     manual, batch_axes, heads_axis = _kernel_mesh_axes()
     if not manual:
         return local(q, k, v, seed, b_ids, h_ids)
+    if kv_heads_in_kernel(H, KV) != KV:
+        # the heads axis cuts a kv head's group: every shard needs a part of
+        # a kv head's queries, so k and v enter a query head each
+        k, v = jnp.repeat(k, H // KV, axis=2), jnp.repeat(v, H // KV, axis=2)
     # The id vectors ride in as P(axis)-sharded iotas: each shard's slice IS
     # its global ids, whatever the order of the axes that split the dim.
     spec = P(batch_axes or None, None, heads_axis, None)

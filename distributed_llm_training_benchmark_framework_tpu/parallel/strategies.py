@@ -382,9 +382,10 @@ _TP_RULES = {
     "lm_head": (0,),    # untied head: vocab-sharded like wte
     "blocks/wqkv": (3,),  # per-head output features
     "blocks/bqkv": (2,),
-    # GQA split projections: column-parallel q and k/v (the consecutive-block
-    # kv repeat in the model keeps each query-head shard paired with its own
-    # kv-head shard as long as the 'model' degree divides kv_heads)
+    # GQA split projections: column-parallel q and k/v (query heads share kv
+    # heads in consecutive blocks, in the flash kernels' index maps and in
+    # the other bodies' repeat, so each query-head shard is paired with its
+    # own kv-head shard as long as the 'model' degree divides kv_heads)
     "blocks/wq": (2,),
     "blocks/bq": (1,),
     "blocks/wkv": (3,),
@@ -529,7 +530,8 @@ def param_partition_specs(
     callers) gates the GQA kv projections' 'model' sharding: the column
     split is only head-aligned when the 'model' degree divides ``kv_heads``.
     A misaligned split shards WITHIN each kv head's feature block, and the
-    consecutive-block kv repeat in the model then needs a layout the
+    consecutive-block kv repeat (``tinygpt._whole_heads``; in front of
+    ``flash_attention``'s shard_map at such a degree) then needs a layout the
     partitioner cannot produce in place — it falls back to
     full-replicate-then-repartition of every per-layer k/v tensor (measured:
     +10 all-gathers and +6 collective-permutes per step on a tp=2 llama-S

@@ -12,6 +12,22 @@ S 2048) the einsum backward, at the shapes the benchmark's cells run.
   tinygpt-a.seq2048                 BH 16, S 2048, D 64: below _PALLAS_BWD_MIN_SEQ
                                     the model runs the einsum backward; the
                                     kernel against it is a number for PERF.md
+  mellum2.global / .window          BH 32 over 4 kv heads, S 16,384, causal / a
+                                    window of 1024
+  laguna.global / .window           BH 48 / 64 over 8 kv heads, S 16,384, causal /
+                                    a window of 512 (its tile 512)
+
+A shape's ``KV`` is the rows of k and v (batch x kv heads; ``--kv-heads``
+overrides it): the mistral, sdar, mellum and laguna shapes share a kv head
+among 4, 8, 8 and 6 / 8 query heads, and the kernel reads row b // rep of k
+and v for query row b. ``prod`` is then what the model runs: the grid walks
+(kv rows, the group's heads, k tiles, q tiles) and dk / dv stay in VMEM, the kv
+head's whole rows, until the group's last head. ``ungrouped`` is the form a
+sequence too long for those rows takes, and the one PR 48 measured ``prod``
+against: dk and dv a query head out of the kernel and the group's sum behind
+it. ``repeated`` is what the model ran until PR 48: k and v repeated a query
+head in front of the kernel, the same sum behind (PERF.md section 6, PR 48,
+has the table).
 
 Under a mask rule a live tile has a shape (``fa._tile_shape``: *full* or
 *lower*) and the kernel a body a shape. ``prod`` rows take a fourth field, the
@@ -94,15 +110,23 @@ CLOCK_HZ = 1.5e9     # v5e: 197e12 / (4 MXUs x 128 x 128 x 2)
 
 SHAPES = {
     "tinygpt-a.seq8192": dict(BH=16, S=8192, D=64, Dv=64, causal=False, rate=0.1, scale=None),
-    "mistral-7b.d2": dict(BH=64, S=4096, D=128, Dv=128, causal=True, rate=0.0, scale=None),
+    "mistral-7b.d2": dict(BH=64, KV=16, S=4096, D=128, Dv=128, causal=True, rate=0.0, scale=None),
     "deepseek-v2-lite.share8-seq8192": dict(
         BH=32, S=8192, D=192, Dv=128, causal=True, rate=0.0, scale=0.114721
     ),
     "sdar-30b-a3b.share8-bd8192": dict(
-        BH=32, S=16384, D=128, Dv=128, causal=fa.BlockDiffusion(8192, 4), rate=0.0, scale=None
+        BH=32, KV=4, S=16384, D=128, Dv=128, causal=fa.BlockDiffusion(8192, 4), rate=0.0, scale=None
     ),
     "tinygpt-a.seq2048": dict(BH=16, S=2048, D=64, Dv=64, causal=False, rate=0.1, scale=None),
+    "mellum2.global": dict(BH=32, KV=4, S=16384, D=128, Dv=128, causal=True, rate=0.0, scale=None),
+    "mellum2.window": dict(
+        BH=32, KV=4, S=16384, D=128, Dv=128, causal=fa.SlidingWindow(1024), rate=0.0, scale=None),
+    "laguna.global": dict(BH=48, KV=8, S=16384, D=128, Dv=128, causal=True, rate=0.0, scale=None),
+    "laguna.window": dict(
+        BH=64, KV=8, S=16384, D=128, Dv=128, causal=fa.SlidingWindow(512), rate=0.0, scale=None,
+        tile=512),
 }
+GROUPED_SHAPES = [name for name, shape in SHAPES.items() if shape.get("KV", shape["BH"]) < shape["BH"]]
 BODIES = ("all", "none")
 DEFAULT_ROWS = [
     "pair", "proto:1024:1024:1024:1024:0:0", "prod:1024:1024:0",
@@ -353,17 +377,28 @@ def backward_fn(row, shape):
     kind, *nums = row.split(":")
     which = nums.pop() if nums and nums[-1] in BODIES else "all"
     nums = [int(n) for n in nums]
+    rep = shape["BH"] // shape["KV"]
+
+    def whole_heads(fn):
+        """``fn`` on whole heads, between the repeat of k and v a query head
+        and the sum of dk and dv a kv head: both inside the timed call."""
+        def run(q, k, v, *rest):
+            dq, dk, dv = fn(q, fa._repeat_groups(k, rep), fa._repeat_groups(v, rep), *rest)
+            return dq, fa._sum_groups(dk, rep), fa._sum_groups(dv, rep)
+        return run
+
     if kind == "pair":
-        def run(*a):
-            return fa._pair_backward(*a, causal, rate, 1024, 512, False, scale)
-    elif kind == "prod":
+        run = whole_heads(lambda *a: fa._pair_backward(*a, causal, rate, 1024, 512, False, scale))
+    elif kind in ("prod", "repeated", "ungrouped"):
         bq, bk, sub = nums
 
-        def run(*a):
+        def fused(*a):
             with bodies(which):  # entered when the call is traced
                 return fa._fused_backward(
-                    *a, causal, rate, bq, bk, False, scale, sub=sub or None
+                    *a, causal, rate, bq, bk, False, scale, sub=sub or None,
+                    grouped=None if kind == "prod" else False,
                 )
+        run = whole_heads(fused) if kind == "repeated" else fused
     elif kind == "proto":
         bq, bk, sub_q, sub_k, lookahead, trim, *late = nums
         run = functools.partial(
@@ -372,32 +407,36 @@ def backward_fn(row, shape):
             trim=trim, late_dq=bool(late and late[0]),
         )
     elif kind == "einsum":
-        def run(q, k, v, do, lse3, delta3, seed, bhv):
+        def einsum(q, k, v, do, lse3, delta3, seed, bhv):
             # _jnp_blockwise_bwd makes delta from out itself; do stands in
             # for out (a time only: this row's gradients are not compared).
             res = (q, k, v, do, lse3[:, 0, :], seed, bhv)
             return fa._jnp_blockwise_bwd(causal, 512, rate, res, do, scale)
+        run = whole_heads(einsum)
     else:
         raise SystemExit(f"unknown row {row!r}")
     return jax.jit(run)
 
 
 def avals(shape, sharding):
-    BH, S, D, Dv = shape["BH"], shape["S"], shape["D"], shape["Dv"]
-    qk = jax.ShapeDtypeStruct((BH, S, D), jnp.bfloat16, sharding=sharding)
-    vo = jax.ShapeDtypeStruct((BH, S, Dv), jnp.bfloat16, sharding=sharding)
-    stat = jax.ShapeDtypeStruct((BH, 8, S), jnp.float32, sharding=sharding)
-    seed = jax.ShapeDtypeStruct((1,), jnp.uint32, sharding=sharding)
-    bhv = jax.ShapeDtypeStruct((BH,), jnp.int32, sharding=sharding)
-    return qk, qk, vo, vo, stat, stat, seed, bhv
+    BH, KV, S, D, Dv = shape["BH"], shape["KV"], shape["S"], shape["D"], shape["Dv"]
+
+    def array(*dims, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=sharding)
+
+    stat = array(BH, 8, S, dtype=jnp.float32)
+    return (array(BH, S, D), array(KV, S, D), array(KV, S, Dv), array(BH, S, Dv), stat, stat,
+            array(1, dtype=jnp.uint32), array(BH, dtype=jnp.int32))
 
 
 def residuals(shape):
     """q, k, v, do and the forward kernel's lse / delta, on the device."""
-    BH, S, D, Dv = shape["BH"], shape["S"], shape["D"], shape["Dv"]
+    BH, KV, S, D, Dv = shape["BH"], shape["KV"], shape["S"], shape["D"], shape["Dv"]
     keys = jax.random.split(jax.random.key(0), 4)
-    q, k = (jax.random.normal(key, (BH, S, D), jnp.bfloat16) for key in keys[:2])
-    v, do = (jax.random.normal(key, (BH, S, Dv), jnp.bfloat16) for key in keys[2:])
+    q = jax.random.normal(keys[0], (BH, S, D), jnp.bfloat16)
+    k = jax.random.normal(keys[1], (KV, S, D), jnp.bfloat16)
+    v = jax.random.normal(keys[2], (KV, S, Dv), jnp.bfloat16)
+    do = jax.random.normal(keys[3], (BH, S, Dv), jnp.bfloat16)
     seed = jnp.asarray([1234], jnp.uint32)
     bhv = jnp.arange(BH, dtype=jnp.int32)
     out, lse = jax.jit(
@@ -498,14 +537,19 @@ def main():
                     help="summarise the LLO dump in DIR and exit")
     ap.add_argument("--iters", type=int, default=10)
     ap.add_argument("--shapes", nargs="*", default=[
-        s for s in SHAPES if s != "tinygpt-a.seq2048"])
+        s for s in SHAPES if s != "tinygpt-a.seq2048" and not s.endswith((".global", ".window"))],
+        help=f"of {sorted(SHAPES)}; 'grouped' = {GROUPED_SHAPES}")
+    ap.add_argument("--kv-heads", type=int, default=None,
+                    help="override the shapes' rows of k and v (batch x kv heads); "
+                         "a shape's BH is a multiple")
     ap.add_argument("--dropout", type=float, default=None,
                     help="override the shapes' dropout rate")
     ap.add_argument("--causal", type=int, default=None, choices=(0, 1),
                     help="override the shapes' masking")
     ap.add_argument("--rows", nargs="*", default=None,
-                    help="pair | einsum | prod:bq:bk:sub | "
-                         "proto:bq:bk:sub_q:sub_k:lookahead:trim[:late_dq]")
+                    help="pair | einsum | prod:bq:bk:sub | repeated:bq:bk:sub | ungrouped:bq:bk:sub | "
+                         "proto:bq:bk:sub_q:sub_k:lookahead:trim[:late_dq] "
+                         "(default under shared kv heads: repeated, ungrouped and prod at the shape's tile)")
     ap.add_argument("--out", default="chiprun_out/flash_bwd_sweep.jsonl")
     args = ap.parse_args()
 
@@ -526,8 +570,12 @@ def main():
         sys.exit("no TPU: --describe compiles without one, timing needs one")
 
     os.makedirs(os.path.dirname(args.out), exist_ok=True)
-    for name in args.shapes:
+    shapes = GROUPED_SHAPES if args.shapes == ["grouped"] else args.shapes
+    for name in shapes:
         shape = dict(SHAPES[name])
+        shape["KV"] = args.kv_heads or shape.get("KV", shape["BH"])
+        if shape["BH"] % shape["KV"]:
+            sys.exit(f"{name}: {shape['BH']} query rows over {shape['KV']} of k and v")
         if args.dropout is not None:
             shape["rate"] = args.dropout
         if args.causal is not None:
@@ -535,19 +583,23 @@ def main():
         BH, S, D, Dv = shape["BH"], shape["S"], shape["D"], shape["Dv"]
         mask = shape["causal"]
         ruled = isinstance(mask, fa.BlockDiffusion)
+        tile = shape.get("tile", 1024)
         pairs = (mask.tile_counts(1024, 1024)[2] if ruled
+                 else mask.true_pairs(S) if isinstance(mask, fa.SlidingWindow)
                  else S * S / (2 if mask else 1))
         # FlashAttention-2's count of the fused pass: five tile products.
         flops = 2 * BH * pairs * (3 * D + 2 * Dv)
         least_ms = flops / PEAK_FLOPS * 1e3
-        counts = shape_counts(mask, S, 1024, fa._bwd_sub_q(1024, shape["rate"]))
+        counts = shape_counts(mask, S, tile, fa._bwd_sub_q(tile, shape["rate"]))
         tiles = BH * (counts["full"] + counts["lower"])
         print(f"{name}: {shape}; least time for one fused pass at full-width "
-              f"peak {least_ms:.2f} ms; {tiles} live (1024, 1024) tiles, a "
+              f"peak {least_ms:.2f} ms; {tiles} live ({tile}, {tile}) tiles, a "
               f"head {counts}", flush=True)
         by_bodies_rows = [f"prod:1024:1024:0:{which}" for which in BODIES]
         rows = args.rows or (
-            ["einsum", "pair", "prod:1024:1024:0"] if S < fa._PALLAS_BWD_MIN_SEQ
+            [f"{kind}:{tile}:{tile}:0" for kind in ("repeated", "ungrouped", "prod")]
+            if shape["KV"] < BH
+            else ["einsum", "pair", "prod:1024:1024:0"] if S < fa._PALLAS_BWD_MIN_SEQ
             else ["pair"] + by_bodies_rows if ruled
             else DEFAULT_ROWS + by_bodies_rows[1:] * bool(mask)
         )
@@ -576,8 +628,8 @@ def main():
                                 )))
                                 for g, w in zip(got, with_all)
                             )
-                    if spec == "pair":
-                        want = got
+                    if spec == "pair" or (want is None and spec.startswith("repeated")):
+                        want = got  # what the others are held to
                     elif want is not None and spec != "einsum":
                         row["max_abs_diff_vs_pair"] = max(
                             float(jnp.max(jnp.abs(

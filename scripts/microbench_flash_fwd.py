@@ -9,6 +9,14 @@ against the whole-tile body, at the shapes the benchmark's cells run.
                       twice), D 128, ``BlockDiffusion(8192, 4)``, no dropout:
                       the production kernel only (the prototype below and the
                       floors know no rule)
+  mellum2.global / laguna.global   BH 32 / 48, S 16,384, D 128, causal
+
+A shape's ``KV`` is the rows of k and v (batch x kv heads; ``--kv-heads``
+overrides it): where it is less than BH the kernel reads row b // rep of k and
+v for query row b, ``flash_production`` is handed them as they are, and a
+``flash_repeated`` row times what ran until PR 48, k and v repeated a query
+head in front of the same kernel; the floors and the prototype take whole
+heads, repeated outside what is timed.
 
 docs/PERFORMANCE.md section 15 measured that the two products of a score tile
 alone took twice the MXU's time, and refuted three operand layouts that keep
@@ -87,9 +95,11 @@ PEAK_FLOPS = 197e12  # v5e bf16
 SHAPES = {
     "tinygpt-a.seq8192": dict(BH=16, S=8192, D=64, causal=False, rate=0.1),
     "tinygpt-a.seq2048": dict(BH=16, S=2048, D=64, causal=False, rate=0.1),
-    "mistral-7b.d2": dict(BH=64, S=4096, D=128, causal=True, rate=0.0),
+    "mistral-7b.d2": dict(BH=64, KV=16, S=4096, D=128, causal=True, rate=0.0),
     "sdar-30b-a3b.share8-bd8192": dict(
-        BH=32, S=16384, D=128, causal=fa.BlockDiffusion(8192, 4), rate=0.0),
+        BH=32, KV=4, S=16384, D=128, causal=fa.BlockDiffusion(8192, 4), rate=0.0),
+    "mellum2.global": dict(BH=32, KV=4, S=16384, D=128, causal=True, rate=0.0),
+    "laguna.global": dict(BH=48, KV=8, S=16384, D=128, causal=True, rate=0.0),
 }
 DMA_TILES = [(1024, 1024), (2048, 1024), (1024, 2048), (2048, 2048)]
 SUB_K = [128, 256, 512, 1024]
@@ -378,6 +388,9 @@ def main():
     ap.add_argument("--bodies", nargs="*", default=list(BODIES), choices=BODIES,
                     help="under a mask rule, the bodies flash_production may run")
     ap.add_argument("--shapes", nargs="*", default=list(SHAPES))
+    ap.add_argument("--kv-heads", type=int, default=None,
+                    help="override the shapes' rows of k and v (batch x kv heads); "
+                         "a shape's BH is a multiple")
     ap.add_argument("--dropout", type=float, default=None,
                     help="override the shapes' dropout rate")
     ap.add_argument("--causal", type=int, default=None, choices=(0, 1),
@@ -421,6 +434,10 @@ def main():
         if args.causal is not None:
             shape["causal"] = bool(args.causal)
         BH, S, D = shape["BH"], shape["S"], shape["D"]
+        KV = shape["KV"] = args.kv_heads or shape.get("KV", BH)
+        if BH % KV:
+            sys.exit(f"{name}: {BH} query rows over {KV} of k and v")
+        rep = BH // KV
         causal, rate = shape["causal"], shape["rate"]
         ruled = isinstance(causal, fa.BlockDiffusion)
         pairs = (causal.tile_counts(1024, 1024)[2] if ruled
@@ -437,13 +454,14 @@ def main():
               f"{counts}", flush=True)
 
         x = jax.ShapeDtypeStruct((BH, S, D), jnp.bfloat16, sharding=sharding)
+        kv_a = jax.ShapeDtypeStruct((KV, S, D), jnp.bfloat16, sharding=sharding)
         seed_a = jax.ShapeDtypeStruct((1,), jnp.uint32, sharding=sharding)
         bhv_a = jax.ShapeDtypeStruct((BH,), jnp.int32, sharding=sharding)
         n_a = jax.ShapeDtypeStruct((), jnp.int32, sharding=sharding)
         if not args.describe:
             keys = jax.random.split(jax.random.key(0), 3)
-            q, k, v = (jax.random.normal(key, (BH, S, D), jnp.bfloat16)
-                       for key in keys)
+            q, k, v = (jax.random.normal(key, (rows, S, D), jnp.bfloat16)
+                       for key, rows in zip(keys, (BH, KV, KV)))
             seed = jnp.asarray([1234], jnp.uint32)
             bhv = jnp.arange(BH, dtype=jnp.int32)
         chain = max(4, int(args.target_ms / max(2.0 * least_ms, 0.05)))
@@ -466,6 +484,9 @@ def main():
         if counts["lower"]:
             variants = [("flash_production", dict(bodies=which), with_bodies(which))
                         for which in args.bodies]
+        if rep > 1:  # the repeat in front of the kernel, timed with it
+            variants.append(("flash_repeated", {}, lambda q, k, v, *ids: production(
+                q, fa._repeat_groups(k, rep), fa._repeat_groups(v, rep), *ids)))
         if not ruled:
             variants.insert(0, ("matmul_floor", {}, lambda q, k, v, seed, bhv: (matmul_floor(q, k, v), None)))
         if not ruled and BH * S * S * 4 <= 2 * 2**30:
@@ -496,21 +517,27 @@ def main():
             ))
 
         want, by_bodies = None, {}
+        if rep > 1 and not args.describe:
+            whole_k, whole_v = fa._repeat_groups(k, rep), fa._repeat_groups(v, rep)
         for variant, cfg, fn in variants:
             row = dict(shape=name, variant=variant, **cfg)
             n = chain if variant != "xla_sdpa" else max(chain // 8, 2)
             many = chained(fn)
+            # the kernel as the model runs it finds a query head's kv head
+            # itself; every other row takes a k and a v a query head
+            grouped = rep > 1 and variant in ("flash_production", "flash_repeated")
             try:
                 if args.describe:
-                    many.lower(n_a, x, x, x, seed_a, bhv_a).compile()
+                    many.lower(n_a, x, *((kv_a, kv_a) if grouped else (x, x)), seed_a, bhv_a).compile()
                     row["compiles"] = True
                 else:
-                    row["ms"] = time_ms(many, (q, k, v, seed, bhv), n, args.reps)
+                    operands = (q, k, v, seed, bhv) if grouped or rep == 1 else (q, whole_k, whole_v, seed, bhv)
+                    row["ms"] = time_ms(many, operands, n, args.reps)
                     row["pct_of_peak"] = 100 * least_ms / row["ms"]
                     row["us_a_tile"] = row["ms"] * 1e3 / tiles
                     if list(cfg) == ["bodies"]:
                         by_bodies[cfg["bodies"]] = row["ms"]
-                    _, out, lse = many(1, q, k, v, seed, bhv)
+                    _, out, lse = many(1, *operands)
                     if want is None and variant == "flash_production":
                         want = (out, lse)
                     elif variant.startswith("flash_"):

@@ -784,3 +784,148 @@ def test_a_cells_call_is_two_kernels_on_the_grid_it_had(cell):
 
     calls = pallas_calls(jax.grad(loss, argnums=(0, 1, 2)), q, q, v)
     assert calls == [("flash_fwd", (heads,) + grid), ("flash_bwd_fused", (heads,) + grid)]
+
+
+# Grouped-query attention: k and v enter ``flash_attention`` at their own head
+# count and the kernels' index maps find a query head's kv head. Tiles of 64
+# over 256 positions, 8 query heads.
+GQA_S, GQA_H, GQA_TILE = 256, 8, 64
+GQA_RULES = {"causal": True, "window": fa.SlidingWindow(80), "block-diffusion": fa.BlockDiffusion(GQA_S // 2, 4)}
+GQA_CASES = [(rule, rep, (D, D)) for rule in sorted(GQA_RULES) for rep in (1, 4, 8)] + [("causal", 4, (24, D))]
+
+
+def gqa_operands(rep, widths=(D, D), batch=2, dtype=jnp.float32):
+    keys = jax.random.split(jax.random.key(11), 3)
+    q = jax.random.normal(keys[0], (batch, GQA_S, GQA_H, widths[0]), dtype)
+    k = jax.random.normal(keys[1], (batch, GQA_S, GQA_H // rep, widths[0]), dtype)
+    v = jax.random.normal(keys[2], (batch, GQA_S, GQA_H // rep, widths[1]), dtype)
+    return q, k, v
+
+
+def on_repeated_heads(attention, rep):
+    """``attention`` on k and v repeated a query head: what the model ran in
+    front of the kernels, and what differentiating through it sums back."""
+    return lambda q, k, v, **kw: attention(q, jnp.repeat(k, rep, axis=2), jnp.repeat(v, rep, axis=2), **kw)
+
+
+@pytest.mark.parametrize("path", ["fused", "pair", "einsum"])
+@pytest.mark.parametrize("rule,rep,widths", GQA_CASES,
+                         ids=[f"{rule}-rep{rep}-{w[0]}over{w[1]}" for rule, rep, w in GQA_CASES])
+def test_kv_heads_enter_as_they_are_and_match_the_reference_on_repeated_heads(rule, rep, widths, path, monkeypatch):
+    """Forward, dq, and dk / dv against the group sums of the reference's, at
+    every backward there is: the fused kernel (k and v read at row b // rep),
+    the kernel pair and the einsum scan (both on heads repeated inside)."""
+    mask = GQA_RULES[rule]
+    q, k, v = gqa_operands(rep, widths)
+    if path == "pair":
+        monkeypatch.setattr(fa, "_fused_fits", lambda *a: False)
+    flash = functools.partial(
+        fa.flash_attention, causal=mask, block_q=GQA_TILE, block_k=GQA_TILE, block_k_bwd=GQA_TILE,
+        pallas_backward=path != "einsum")
+    reference = on_repeated_heads(functools.partial(fa.reference_attention, causal=mask), rep)
+    out = flash(q, k, v)
+    assert out.shape == (*q.shape[:3], widths[1])
+    np.testing.assert_allclose(out, reference(q, k, v), atol=2e-5, rtol=2e-5)
+
+    def loss(attention):
+        return lambda q, k, v: jnp.sum(jnp.square(attention(q, k, v)))
+
+    got = jax.grad(loss(flash), (0, 1, 2))(q, k, v)
+    want = jax.grad(loss(reference), (0, 1, 2))(q, k, v)
+    assert [g.shape for g in got] == [q.shape, k.shape, v.shape]
+    for g, w in zip(got, want):
+        np.testing.assert_allclose(g, w, atol=2e-4, rtol=2e-4)
+
+
+@pytest.mark.parametrize("rep", [4, 8])
+def test_dropout_draws_the_bits_of_the_call_on_repeated_heads(rep):
+    """The hash is keyed by the (batch, query head) id: a call on 8 / rep kv
+    heads keeps the pairs the call on 8 repeated heads keeps. Each query row
+    runs the same products on the same operands, so out and dq are the same
+    bits; dk and dv are the group sums."""
+    q, k, v = gqa_operands(rep)
+    flash = functools.partial(
+        fa.flash_attention, causal=True, block_q=GQA_TILE, block_k=GQA_TILE, block_k_bwd=GQA_TILE,
+        pallas_backward=True, dropout_rate=0.5, dropout_seed=jnp.uint32(5))
+    repeated = on_repeated_heads(flash, rep)
+    np.testing.assert_array_equal(flash(q, k, v), repeated(q, k, v))
+    assert not np.allclose(flash(q, k, v), flash(q, k, v, dropout_seed=jnp.uint32(6)), atol=1e-3)
+
+    def loss(attention):
+        return lambda q, k, v: jnp.sum(jnp.square(attention(q, k, v)))
+
+    got = jax.grad(loss(flash), (0, 1, 2))(q, k, v)
+    want = jax.grad(loss(repeated), (0, 1, 2))(q, k, v)
+    np.testing.assert_array_equal(got[0], want[0])
+    for g, w in zip(got[1:], want[1:]):
+        np.testing.assert_allclose(g, w, atol=1e-4, rtol=1e-4)
+
+
+@pytest.mark.parametrize("rule", sorted(GQA_RULES))
+def test_a_grouped_call_is_the_two_kernels_with_k_and_v_at_their_own_rows(rule):
+    """32 query heads over 4: the forward's grid is the whole-head call's,
+    the backward's walks (kv rows, a group's heads, k tiles, q tiles), k and v
+    enter the kernels as (4, S, D) and dk / dv leave at 4 heads."""
+    mask = GQA_RULES[rule]
+    q = jax.ShapeDtypeStruct((1, GQA_S, 32, D), jnp.float32)
+    kv = jax.ShapeDtypeStruct((1, GQA_S, 4, D), jnp.float32)
+
+    def loss(q, k, v):
+        return jnp.sum(fa.flash_attention.__wrapped__(
+            q, k, v, causal=mask, interpret=True, block_q=GQA_TILE, block_k=GQA_TILE,
+            block_k_bwd=GQA_TILE, pallas_backward=True))
+
+    (fwd, whole_fwd), (bwd, whole_bwd) = pallas_calls(jax.grad(loss, argnums=(0, 1, 2)), q, q, q)
+    assert (fwd, bwd) == ("flash_fwd", "flash_bwd_fused") and whole_fwd[0] == whole_bwd[0] == 32
+    assert pallas_calls(jax.grad(loss, argnums=(0, 1, 2)), q, kv, kv) == [
+        (fwd, whole_fwd), (bwd, (4, 8) + whole_bwd[1:])]
+
+    def kernel_operands(jaxpr):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "pallas_call":
+                yield eqn.params["name"], [tuple(x.aval.shape) for x in eqn.invars]
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                yield from kernel_operands(sub)
+
+    grads = jax.make_jaxpr(jax.grad(loss, argnums=(0, 1, 2)))(q, kv, kv)
+    assert [tuple(x.aval.shape) for x in grads.jaxpr.outvars] == [q.shape, kv.shape, kv.shape]
+    for name, shapes in kernel_operands(grads.jaxpr):
+        # (seed, ids, q, k, v, ...): k and v a kv head a row
+        assert shapes[2:5] == [(32, GQA_S, D), (4, GQA_S, D), (4, GQA_S, D)], name
+
+
+@pytest.mark.parametrize("rule", sorted(GQA_RULES))
+def test_the_two_forms_of_the_grouped_backward_agree(rule):
+    """dk and dv summed over a group in the kernel's whole-row accumulators
+    (the form taken where the rows fit VMEM) against a query head out of the
+    kernel and summed behind it (the form for longer sequences), with pieces
+    cut and a *lower* body on the diagonal; dq is the same bits."""
+    mask, rep = GQA_RULES[rule], 4
+    q, k, v = (t.transpose(0, 2, 1, 3).reshape(-1, GQA_S, D) for t in gqa_operands(rep, batch=1))
+    seed, bhv = jnp.asarray([7], jnp.uint32), jnp.arange(GQA_H, dtype=jnp.int32)
+    do = jax.random.normal(jax.random.key(12), q.shape, jnp.float32)
+    out, lse = fa._flash_forward(q, k, v, mask, True, GQA_TILE, GQA_TILE, 0.1, seed, bhv)
+    stats = [jnp.broadcast_to(x[:, None, :], (GQA_H, 8, GQA_S)) for x in (lse, jnp.sum(do * out, -1))]
+    args = (q, k, v, do, *stats, seed, bhv, mask, 0.1, GQA_TILE, GQA_TILE, True)
+    grouped = fa._fused_backward(*args, sub=32, grouped=True)
+    behind = fa._fused_backward(*args, sub=32, grouped=False)
+    assert [g.shape for g in grouped] == [q.shape, k.shape, v.shape]
+    np.testing.assert_array_equal(grouped[0], behind[0])
+    for g, w in zip(grouped[1:], behind[1:]):
+        np.testing.assert_allclose(g, w, atol=1e-5, rtol=1e-5)
+
+
+def test_the_grouped_form_is_taken_where_three_rows_fit_vmem():
+    """The cells' S 16,384 at head width 128 fits (80 MiB of the 96 allowed);
+    twice that keeps the fused kernel (one row) and sums behind it."""
+    assert fa._fused_vmem_bytes(16384, 128, jnp.bfloat16, 128) == 80 * 2**20
+    assert fa._grouped_fits(16384, 128, 128, jnp.bfloat16)
+    assert fa._fused_fits(32768, 128, jnp.bfloat16) and not fa._grouped_fits(32768, 128, 128, jnp.bfloat16)
+
+
+def test_head_counts_that_do_not_divide_are_refused():
+    q, k, v = gqa_operands(4)
+    with pytest.raises(ValueError, match="head count that divides"):
+        fa.flash_attention(q, k[:, :, :1], v)  # one head of k under two of v
+    with pytest.raises(ValueError, match="head count that divides"):
+        fa.flash_attention(q[:, :, :3], k, v)  # 3 query heads over 2

@@ -464,3 +464,56 @@ def test_through_the_one_pass_prologue_the_loss_and_gradients_are_the_chains(mon
         np.testing.assert_allclose(
             np.asarray(g), np.asarray(w), atol=5e-3 * float(jnp.abs(w).max()), rtol=5e-3,
             err_msg=jax.tree_util.keystr(path))
+
+
+def _derived_shapes(jaxpr, tainted, stop):
+    """(shapes of every array in ``jaxpr`` computed from the ``tainted``
+    variables, which of its results are), walked into the calls it holds and
+    not past one of the primitives named in ``stop`` (the attention itself:
+    what leaves it is the layer's output, not k or v)."""
+    from jax.extend.core import Literal
+
+    tainted, shapes = set(tainted), []
+    for eqn in jaxpr.eqns:
+        hit = [not isinstance(x, Literal) and x in tainted for x in eqn.invars]
+        if not any(hit) or eqn.primitive.name in stop:
+            continue
+        made = [True] * len(eqn.outvars)
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            if len(sub.invars) == len(eqn.invars) and len(sub.outvars) == len(eqn.outvars):
+                inner, made = _derived_shapes(sub, [x for x, h in zip(sub.invars, hit) if h], stop)
+                shapes += inner
+        tainted.update(x for x, m in zip(eqn.outvars, made) if m)
+        shapes += [tuple(x.aval.shape) for x, m in zip(eqn.outvars, made) if m]
+    return shapes, [not isinstance(x, Literal) and x in tainted for x in jaxpr.outvars]
+
+
+@pytest.mark.parametrize("impl", ["flash", "reference"])
+def test_k_and_v_reach_the_flash_kernels_at_the_models_kv_heads(impl):
+    """32 query heads over 4: under 'flash' nothing made from the kv
+    projection has a query head's worth of heads in front of the kernels, in
+    the layer or inside ``flash_attention``, and the counter says 4; the
+    'reference' body still takes k and v broadcast to 32."""
+    from distributed_llm_training_benchmark_framework_tpu.models import tinygpt
+
+    B, S, H, KV, Dh = 2, 64, 32, 4, 8
+    cfg = llama_cfg(n_embd=H * Dh, n_head=H, n_kv_head=KV, n_layer=1, block_size=S, attention_impl=impl)
+    layer = jax.tree.map(lambda leaf: leaf[0], init_params(cfg, jax.random.key(0))["blocks"])
+    x = jnp.zeros((B, S, cfg.n_embd), jnp.float32)
+    names = sorted(layer)
+    closed = jax.make_jaxpr(
+        lambda x, *leaves: tinygpt._attention_sublayer(cfg, x, dict(zip(names, leaves)), None, True)
+    )(x, *(layer[n] for n in names))
+    wkv = closed.jaxpr.invars[1 + names.index("wkv")]
+    shapes, _ = _derived_shapes(closed.jaxpr, [wkv], stop=("custom_vjp_call", "custom_vjp_call_jaxpr"))
+    assert (B, S, KV, Dh) in shapes
+    whole = {(B, S, H, Dh), (B, H, S, Dh), (B * H, S, Dh)}
+    stats = tinygpt.attn_mask_stats(cfg, S)["global"]
+    assert stats["heads"] == H
+    if impl == "flash":
+        assert not whole & set(shapes), sorted(whole & set(shapes))
+        assert (B * KV, S, Dh) in shapes  # a (S, D) slab a kv head, the kernels' operand
+        assert stats["kv_heads_in_kernel"] == KV
+    else:
+        assert (B, S, H, Dh) in shapes
+        assert stats["kv_heads_in_kernel"] == H

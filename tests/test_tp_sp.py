@@ -108,6 +108,41 @@ def test_dp_sp_ulysses_tp(eight_devices):
     np.testing.assert_allclose(full, base, rtol=5e-3)
 
 
+@pytest.mark.parametrize("degree,kv_in_kernel", [(2, 2), (4, 8)], ids=["divides-kv-heads", "cuts-a-kv-heads-group"])
+def test_flash_under_a_heads_axis_takes_kv_heads_whole_or_repeats_them(eight_devices, degree, kv_in_kernel):
+    """8 query heads over 2 under 'model' = 2 and 4: where the degree divides
+    the kv heads k and v ride the heads axis at their own count and a shard's
+    kernels read its own kv head; where it does not they are repeated in front
+    of the ``shard_map``, as the model repeated them. Either way the loss and
+    the gradients are one device's."""
+    import jax.numpy as jnp
+    from jax.sharding import NamedSharding
+
+    from distributed_llm_training_benchmark_framework_tpu.models import tinygpt
+
+    cfg = tinygpt.TinyGPTConfig(
+        vocab_size=64, n_embd=128, n_head=8, n_kv_head=2, n_layer=1, block_size=64, dropout=0.0,
+        causal=True, norm="rmsnorm", pos_embed="rope", mlp_act="swiglu", mlp_hidden=96, bias=False,
+        tie_embeddings=False, param_dtype=jnp.float32, compute_dtype=jnp.float32, attention_impl="flash")
+    params = tinygpt.init_params(cfg, jax.random.key(0))
+    idx = jax.random.randint(jax.random.key(1), (2, 64), 0, cfg.vocab_size)
+    step = jax.jit(jax.value_and_grad(lambda p: tinygpt.loss_fn(cfg, p, idx, idx)))
+    assert tinygpt.attn_mask_stats(cfg, 64)["global"]["kv_heads_in_kernel"] == 2
+    want_loss, want = step(params)
+    mesh = make_mesh((1, 1, degree), ("data", "seq", "model"), devices=eight_devices[:degree])
+    specs = param_partition_specs(params, mesh, shard=False, kv_heads=cfg.kv_heads)
+    assert ("model" in tuple(specs["blocks"]["wkv"])) == (degree == 2)
+    placed = jax.tree.map(lambda leaf, spec: jax.device_put(leaf, NamedSharding(mesh, spec)), params, specs)
+    with jax.set_mesh(mesh):
+        assert tinygpt.attn_mask_stats(cfg, 64)["global"]["kv_heads_in_kernel"] == kv_in_kernel
+        got_loss, got = step(placed)
+    np.testing.assert_allclose(float(got_loss), float(want_loss), rtol=1e-5)
+    for (path, g), w in zip(jax.tree_util.tree_leaves_with_path(got), jax.tree.leaves(want)):
+        np.testing.assert_allclose(
+            np.asarray(g), np.asarray(w), atol=1e-4 * float(jnp.abs(w).max()), rtol=1e-3,
+            err_msg=jax.tree_util.keystr(path))
+
+
 def test_world_size_not_divisible_raises():
     from distributed_llm_training_benchmark_framework_tpu.train.loop import run_benchmark
     from distributed_llm_training_benchmark_framework_tpu.parallel import get_strategy

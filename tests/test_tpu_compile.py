@@ -137,6 +137,37 @@ def test_sliding_window_kernels_compile_on_their_band(v5e_devices, window):
     assert rule.band_steps(16384, 1024, 1024, True) == -(-(window - 1) // 1024) + 1
 
 
+GROUPED_CALLS = {
+    # (query heads, kv heads, S, rule) of the cells whose query heads share kv heads
+    "mistral-32-over-8": (32, 8, 4096, True),
+    "sdar-32-over-4": (32, 4, 16384, fa.BlockDiffusion(8192, 4)),
+    "mellum-window-32-over-4": (32, 4, 16384, fa.SlidingWindow(1024)),
+    "laguna-global-48-over-8": (48, 8, 16384, True),
+    "laguna-window-64-over-8": (64, 8, 16384, fa.SlidingWindow(512)),
+}
+
+
+@pytest.mark.parametrize("call", sorted(GROUPED_CALLS))
+def test_grouped_query_kernels_compile_at_the_cells_head_counts(v5e_devices, call):
+    """k and v at the model's kv heads: the forward's and the fused backward's
+    index maps divide the grid's row by the group (a scalar op in the index
+    computation, in the square's grid and in the band's), and dk / dv leave
+    at the kv heads' shape."""
+    heads, kv_heads, seq, rule = GROUPED_CALLS[call]
+    one = SingleDeviceSharding(v5e_devices[0])
+
+    def loss(q, k, v):
+        out = fa.flash_attention(q, k, v, causal=rule, interpret=False)
+        return jnp.sum(out.astype(jnp.float32))
+
+    q, _, _, _ = _qkv(one, one, 1, seq, heads, 128)
+    kv, _, _, _ = _qkv(one, one, 1, seq, kv_heads, 128)
+    lowered = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(q, kv, kv)
+    assert [tuple(leaf.shape) for leaf in jax.tree.leaves(lowered.out_info)] == [q.shape, kv.shape, kv.shape]
+    text = lowered.compile().as_text()
+    assert "flash_fwd" in text and "flash_bwd_fused" in text
+
+
 @pytest.mark.parametrize("kernel", ["fwd", "bwd"])
 def test_the_bodies_change_the_mosaic_module_and_a_retrace_does_not(v5e_devices, kernel, monkeypatch):
     """``scripts/flash_mosaic_modules.py``: what a kernel hands the compiler,
