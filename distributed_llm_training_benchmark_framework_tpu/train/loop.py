@@ -1597,16 +1597,18 @@ def _run_benchmark_impl(
     # Memory-anatomy reconciliation (analysis/memory_anatomy.py, docs/
     # OBSERVABILITY.md): fold the three memory sources this run already
     # produced — the pre-flight analytic estimate, XLA's compile-time
-    # buffer accounting off the (cache-hit) step executable, and the
-    # allocator's measured peak (explicitly null-with-reason on backends
-    # without memory_stats) — into the per-class attribution + the
-    # hbm_model_drift_frac secondary metric.
+    # buffer accounting of the step executable (what ``aot_compile`` above
+    # put into the process's record: ``scopes.step_memory()``, the one the
+    # benchmark's reader prints), and the allocator's measured peak
+    # (explicitly null-with-reason on backends without memory_stats) — into
+    # the per-class attribution + the hbm_model_drift_frac secondary metric.
     from ..analysis import memory_anatomy as memano
+    from ..utils import scopes
 
     measured_b, measured_reason = memano.measured_peak_bytes(prior_peak_bytes)
     mem_report = memano.reconcile(
         est,
-        compile_mem=memano.compile_memory_fields(compiled_step),
+        compile_mem=scopes.step_memory()["compiled"],
         measured_bytes=measured_b,
         measured_reason=measured_reason,
     )

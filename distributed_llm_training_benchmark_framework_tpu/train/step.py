@@ -360,6 +360,17 @@ def _in_the_layouts_the_state_lives_in(grads, shardings):
     return jax.tree.map(pin, grads, shardings)
 
 
+def _bytes_limit(mesh: Mesh) -> Optional[int]:
+    """The smallest ``bytes_limit`` the allocators of this process's devices of
+    ``mesh`` report; None where one of them keeps no statistics (the CPU) or
+    is described and not attached (a compile for a chip that is not there)."""
+    try:
+        limits = [(d.memory_stats() or {}).get("bytes_limit") for d in mesh.local_devices]
+    except jax.errors.JaxRuntimeError:
+        return None
+    return None if not limits or None in limits else min(limits)
+
+
 def make_train_step(
     model_config: tinygpt.TinyGPTConfig,
     strategy: strat.StrategyConfig,
@@ -398,8 +409,9 @@ def make_train_step(
     compile the extra all-reduce.
     """
     cfg = _resolve_model_config(model_config, strategy, mesh)
+    params_shape = jax.eval_shape(functools.partial(tinygpt.init_params, cfg), jax.random.key(0))
     grad_sharded_specs = strat.param_partition_specs(
-        jax.eval_shape(functools.partial(tinygpt.init_params, cfg), jax.random.key(0)),
+        params_shape,
         mesh,
         shard=True,
         kv_heads=cfg.kv_heads,
@@ -422,15 +434,18 @@ def make_train_step(
             f"a config that reports {cfg.step_report} does not compose with sentinel "
             "or pipe > 1")
 
-    def micro_loss(params: Params, micro: jax.Array, key: jax.Array) -> jax.Array:
+    def loss_under(c, params: Params, micro: jax.Array, key: jax.Array) -> jax.Array:
         return (tinygpt.loss_and_report_fn if reports else tinygpt.loss_fn)(
-            cfg,
+            c,
             params,
             micro,
             micro,  # targets = inputs, unshifted (reference parity)
             dropout_key=key,
             deterministic=deterministic_dropout,
         )
+
+    def micro_loss(params: Params, micro: jax.Array, key: jax.Array) -> jax.Array:
+        return loss_under(cfg, params, micro, key)
 
     pipelined = mesh.shape.get("pipe", 1) > 1
     if pipelined:
@@ -604,6 +619,46 @@ def make_train_step(
         with scopes.host_span(scopes.STEP_DISPATCH, **args), jax.set_mesh(mesh):
             return jitted(params, opt_state, batch, step)
 
+    data_only = all(size == 1 for axis, size in mesh.shape.items() if axis != "data")
+
+    @functools.lru_cache(maxsize=None)
+    def saved_for_backward(micro_shape):
+        """What one micro-batch's forward keeps for its backward on one chip:
+        ``{"kept": [...], "all": [...], "left_out": {...}}``, entries
+        ``(scope_path, name, shape, dtype, bytes)``, largest first. ``kept`` is
+        under the step's own remat policy, ``all`` the same closure under
+        ``remat="none"``; what is in ``all`` and not in ``kept`` is what the
+        policy drops and remat runs again to have. Traced from shapes alone
+        (``utils/residuals.py``), under the step's mesh, when asked and once.
+
+        One chip's share: the trace is of the GLOBAL micro-batch
+        (``global_micro`` x ``seq_len``; ``shape`` is the traced one) and
+        ``bytes`` is that value's bytes over the ``data`` axis's size, since
+        everything listed was computed from the batch and so carries it. What
+        was computed from the parameters alone (``weights``) or from nothing
+        (``constants``) is no activation and is summed in ``left_out``, whole.
+        Only for a data-only mesh: with a ``pipe``, ``model``, ``seq`` or
+        ``expert`` axis over 1 a chip's activations are not the trace's
+        shapes over anything, and the record holds no callable."""
+        from ..utils import residuals
+
+        args = (jax.ShapeDtypeStruct(micro_shape, jnp.int32),
+                jax.eval_shape(jax.random.key, jax.ShapeDtypeStruct((), jnp.uint32)))
+        chips = mesh.shape.get("data", 1)
+
+        def listed(remat):
+            found = residuals.residuals(
+                functools.partial(loss_under, dataclasses.replace(cfg, remat=remat)),
+                params_shape, args, has_aux=bool(reports))
+            return ([(*e[:4], e[4] // chips) for e in found["entries"]],
+                    {k: found[k] for k in ("constants", "weights")})
+
+        with jax.set_mesh(mesh):
+            kept = listed(cfg.remat)
+            everything = kept if tinygpt.normalize_remat(cfg.remat) == "none" else listed("none")
+        return {"kept": kept[0], "all": everything[0],
+                "left_out": {"kept": kept[1], "all": everything[1]}}
+
     def aot_compile(params, opt_state, batch, step=0):
         """AOT-compile for the given args and return the jax.stages.Compiled.
 
@@ -611,12 +666,26 @@ def make_train_step(
         path shares the jit executable cache — so it is the free way to get
         ``compiled.memory_analysis()`` (XLA's measured buffer-assignment
         peak) on runtimes whose allocator exposes no ``memory_stats()``.
+
+        What the compiled step holds goes into the process's record
+        (``scopes.step_memory()``): buffer assignment's classes, the smallest
+        limit the mesh's devices' allocators report, and the callable that
+        lists the micro-batch's residuals when someone asks.
         """
+        from ..analysis.memory_anatomy import compile_memory_fields
+
         with jax.set_mesh(mesh):
             with scopes.host_span(scopes.STEP_LOWER):
                 lowered = jitted.lower(params, opt_state, batch, step)
             with scopes.host_span(scopes.STEP_COMPILE):
-                return lowered.compile()
+                compiled = lowered.compile()
+        micro_shape = (global_micro, seq_len) if from_table else tuple(batch.shape[1:])
+        scopes.record_step_memory(
+            compiled=compile_memory_fields(compiled),
+            bytes_limit=_bytes_limit(mesh),
+            saved=functools.partial(saved_for_backward, micro_shape) if data_only else None,
+        )
+        return compiled
 
     return step_with_mesh, aot_compile
 

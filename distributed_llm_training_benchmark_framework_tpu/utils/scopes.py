@@ -9,7 +9,11 @@ docs/OBSERVABILITY.md ("Names in a profile").
 The host's half, below it: five spans round the work ``train/step.py`` does on
 the host, jax's own compile and cache events, and the collector's pauses, kept
 in the process for whoever asks (``host_records``, ``compile_events``).
-Nothing here prints or writes a file.
+
+The step's memory, last: what ``aot_compile`` read off the step it compiled,
+the devices' limit, and a callable that lists what the forward keeps for the
+backward when someone asks (``step_memory``; docs/OBSERVABILITY.md, "The
+step's memory"). Nothing here prints or writes a file.
 """
 
 import collections
@@ -68,6 +72,11 @@ ATTN_GATE = "attn_gate"
 # clean one.
 NOISE = "noise"
 
+# Every name above: what a scope path read back from a name stack is held to
+# (``utils/residuals.py::scope_path``).
+NAMES = frozenset((*SCOPES, *MOE_SCOPES, SHARED, *MLA_SCOPES, *LAYER_KIND_SCOPES, *KDA_SCOPES,
+                   QK_PROLOGUE, ATTN_GATE, NOISE))
+
 
 # ---------------------------------------------------------------------------
 # The host's half: what the Python side of a run was doing, kept in the
@@ -112,6 +121,7 @@ _backend_compiles = collections.deque(maxlen=_MAXLEN)  # (fun_name, end_ns, seco
 _jit_busy = collections.deque(maxlen=_MAXLEN)  # [start_ns, end_ns], disjoint, in order
 _cache = {"hits": 0, "misses": collections.deque(maxlen=_MAXLEN)}  # a miss: its time, ns
 _gc_started = [0]
+_step_memory = {"compiled": None, "bytes_limit": None, "saved": None}
 
 
 def wall_ns(perf_ns):
@@ -167,6 +177,26 @@ def compile_events():
         "cache_hits": _cache["hits"],
         "cache_misses": list(_cache["misses"]),
     }
+
+
+def record_step_memory(compiled, bytes_limit, saved):
+    """``train/step.py::aot_compile`` calls this once a compilation."""
+    _step_memory.update(compiled=compiled, bytes_limit=bytes_limit, saved=saved)
+
+
+def step_memory():
+    """The memory of the newest step ``aot_compile`` compiled in the process:
+    ``compiled`` (``analysis.memory_anatomy.compile_memory_fields`` of it:
+    ``argument_bytes``, ``output_bytes``, ``temp_bytes``, ``alias_bytes``,
+    ``peak_bytes`` of buffer assignment; None where the backend has no
+    analysis), ``bytes_limit`` (the smallest the mesh's devices' allocators
+    report; None where they keep none) and ``saved``, which traces when called
+    and not before: ``saved()`` -> ``{"kept": [...], "all": [...], "left_out":
+    ...}``, what a micro-batch's forward keeps for its backward on one chip
+    under the step's remat policy and under none
+    (``train/step.py::saved_for_backward``); None on a mesh that is not
+    data-only. All three are None before any step was compiled."""
+    return dict(_step_memory)
 
 
 def _on_duration(event, seconds, fun_name="", **_):
