@@ -21,9 +21,12 @@ columns then up columns, and ``moe_wd`` (E, F, D); the config pairs
 ``mlp_act='swiglu'`` with this path); gates are renormalised only if
 ``norm_topk_prob``; the auxiliary channel carries the load-balance term over
 all K choices plus the router z-loss. Its parts carry the scopes ``router`` /
-``dispatch`` / ``experts`` / ``combine`` (``utils/scopes.MOE_SCOPES``). It
-runs on one chip's tokens: the 'expert' axis all-to-all around it is not
-written yet.
+``dispatch`` / ``experts`` / ``combine`` (``utils/scopes.MOE_SCOPES``). What
+of it is dear to make again and cheap to hold carries a ``checkpoint_name``
+(``MOE_RESIDUAL_NAMES``: the gate+up grouped matmul's result, the router's
+logits and choice, the sorts' plan), which remat ``dots`` and
+``full_keep_kernels`` keep. It runs on one chip's tokens: the 'expert' axis
+all-to-all around it is not written yet.
 
 **A chip's share of the experts** (``experts_held = (first, count)``;
 ``_moe_mlp_held``) is the dropless layer told which experts it holds, as
@@ -106,11 +109,23 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental.pallas.ops.tpu.megablox import ops as megablox
 from jax.experimental.pallas.ops.tpu.megablox.gmm import tgmm
 from jax.sharding import PartitionSpec as P
 
 from ..utils import scopes
+
+
+#: ``jax.ad_checkpoint.checkpoint_name``s of what the dropless routed layer makes
+#: that is dear to make again and cheap to hold (``tinygpt._under_remat`` has the
+#: rule; remat ``dots`` and ``full_keep_kernels`` keep them): the experts' gate+up
+#: grouped matmul's result (a Mosaic call, which no ``dot_general`` rule sees);
+#: the router's float32 logits (at ``Precision.HIGHEST``); its choice (``top_k``'s
+#: results and the counts an expert: a sort); and the plan that moves rows (the
+#: sorts' integer arrays and the gate a row, kilobytes to a MB a layer).
+MOE_GU, ROUTER_LOGITS, ROUTER_CHOICE, MOE_PLAN = MOE_RESIDUAL_NAMES = (
+    "moe_gu", "router_logits", "router_choice", "moe_plan")
 
 
 def capacity(n_tokens: int, n_experts: int, top_k: int, factor: float) -> int:
@@ -341,23 +356,25 @@ def _route_dropless(c, xt: jax.Array, router: jax.Array, sequences: int = 1, bia
     """
     N = xt.shape[0]
     E, K = c.n_experts, c.expert_top_k
-    logits = jnp.einsum(
+    logits = checkpoint_name(jnp.einsum(
         "nd,de->ne", xt.astype(jnp.float32), router.astype(jnp.float32),
         precision=lax.Precision.HIGHEST, preferred_element_type=jnp.float32,
-    )
+    ), ROUTER_LOGITS)
     sigmoid = c.router_score == "sigmoid"
     if sigmoid:
         probs = jax.nn.sigmoid(logits)
-        _, expert_idx = lax.top_k(probs + bias.astype(jnp.float32), K)
+        expert_idx = checkpoint_name(
+            lax.top_k(probs + bias.astype(jnp.float32), K)[1], ROUTER_CHOICE)
         gates = jnp.take_along_axis(probs, expert_idx, axis=-1)
     else:
         probs = jax.nn.softmax(logits, axis=-1)
-        gates, expert_idx = lax.top_k(probs, K)
+        gates, expert_idx = checkpoint_name(lax.top_k(probs, K), ROUTER_CHOICE)
     if c.norm_topk_prob:
         gates = gates / jnp.maximum(jnp.sum(gates, axis=-1, keepdims=True), 1e-9)
     if c.routed_scaling_factor != 1.0:
         gates = gates * c.routed_scaling_factor
-    counts = jnp.sum(jax.nn.one_hot(expert_idx, E, dtype=jnp.int32), axis=(0, 1))
+    counts = checkpoint_name(
+        jnp.sum(jax.nn.one_hot(expert_idx, E, dtype=jnp.int32), axis=(0, 1)), ROUTER_CHOICE)
     if sigmoid:
         aux = jnp.zeros((), jnp.float32)
     elif c.seq_aux:
@@ -450,7 +467,8 @@ def _grouped_matmul(c, rows: jax.Array, weights: jax.Array, counts: jax.Array) -
 def _experts_dropless(c, layer, rows: jax.Array, counts: jax.Array) -> jax.Array:
     """SwiGLU experts over rows in expert order, (M, D) -> (M, D)."""
     F = c.mlp_dim
-    gu = _grouped_matmul(c, rows, layer["moe_wgu"], counts)  # gate and up in one matmul
+    # gate and up in one matmul; kept through remat by name: its re-run is a ``gmm``
+    gu = checkpoint_name(_grouped_matmul(c, rows, layer["moe_wgu"], counts), MOE_GU)
     h = jax.nn.silu(gu[:, :F]) * gu[:, F:]
     return _grouped_matmul(c, h, layer["moe_wd"], counts)
 
@@ -519,7 +537,10 @@ def _held_plan(c, expert_idx: jax.Array, counts: jax.Array, gates: jax.Array):
     sorted_token, by_token = lax.sort_key_val(token, jnp.arange(M, dtype=jnp.int32))
     tile = _token_tile(N)
     spans = jnp.sum(jax.nn.one_hot(token // tile, N // tile, dtype=jnp.int32), axis=0)
-    return gate[:M], live, (token, by_token, sorted_token, spans), sizes, rows, overflow
+    # what the backward reads of the plan, by name: remat then re-runs neither sort
+    gate, live, rows_of, sizes = checkpoint_name(
+        (gate[:M], live, (token, by_token, sorted_token, spans), sizes), MOE_PLAN)
+    return gate, live, rows_of, sizes, rows, overflow
 
 
 def routing_rows(config, layer: dict, x: jax.Array):
@@ -654,7 +675,7 @@ def _moe_mlp_dropless(c, layer, x, dropout_key, deterministic):
         # Assignment n*K + k is token n's k-th choice; ``order`` lists the
         # assignments expert by expert, ``inverse`` is where each one went.
         order = jnp.argsort(expert_idx.reshape(N * K), stable=True)
-        inverse = jnp.argsort(order)
+        order, inverse = checkpoint_name((order, jnp.argsort(order)), MOE_PLAN)
         rows = _permute_rows(jnp.repeat(xt, K, axis=0), order, inverse)
     with jax.named_scope(scopes.EXPERTS):
         out = _experts_dropless(c, layer, rows, counts)

@@ -42,6 +42,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
+from jax.ad_checkpoint import checkpoint_name
 from jax.sharding import PartitionSpec as P
 
 from ..utils import scopes
@@ -49,6 +50,12 @@ from ..utils import scopes
 Params = Dict[str, Any]
 
 REMAT_POLICIES = ("none", "dots", "full_keep_kernels", "full")
+
+#: ``checkpoint_name``s of two matmul results in their compute-dtype form, which
+#: ``full_keep_kernels`` keeps (``_under_remat``): a KDA layer's q, k, v
+#: projection (``_kda_sublayer``) and a dense SwiGLU layer's gate+up
+#: (``_mlp_sublayer``).
+KDA_QKV, MLP_GU = MATMUL_CAST_NAMES = ("kda_qkv", "mlp_gu")
 
 
 def normalize_remat(value: Any) -> str:
@@ -183,14 +190,17 @@ class TinyGPTConfig:
     #   "none" — save every intermediate (fastest, most memory);
     #   "dots" — jax.checkpoint keeping what is dear to redo and cheap to
     #            keep: matmul outputs (dot_general without batch dims) and
-    #            the flash kernel's two results (out, lse); only cheap
-    #            elementwise/norm work (and reference attention's batched
-    #            products) is recomputed in backward (the low-tax middle
-    #            ground);
-    #   "full_keep_kernels" — jax.checkpoint keeping only the mixer's
-    #            forward kernel's named results (the flash kernel's out and
-    #            lse, a kda layer's output and chunk states: a few (B, S, H x
-    #            Dv) arrays a layer, and the dearest thing to run again);
+    #            the values `remat_kept_names` lists (the flash kernel's out
+    #            and lse, a kda layer's output and chunk states, the routed
+    #            experts' gate+up grouped matmul's result, the router's
+    #            logits, choice and plan); only cheap elementwise/norm work
+    #            (and reference attention's batched products) is recomputed
+    #            in backward (the low-tax middle ground);
+    #   "full_keep_kernels" — jax.checkpoint keeping only the named values:
+    #            the list above and, by name where "dots" has the matmul's
+    #            own result, a kda layer's q, k, v projection and a dense
+    #            SwiGLU layer's gate+up (`_under_remat` has the rule for the
+    #            list: dear to run again, cheap to hold);
     #   "full" — all-or-nothing jax.checkpoint per layer (least memory,
     #            ~full forward recompute in backward).
     # Booleans are accepted for backward compatibility (True="full").
@@ -1663,7 +1673,7 @@ def _kda_sublayer(c: TinyGPTConfig, x: jax.Array, layer: Params) -> jax.Array:
         # one (D, 3 H Dk) product: with q, k, v on an axis of their own XLA lays the
         # result out (3, S, H Dk) and the flat view the convolution takes is a copy
         wqkv = layer["kda_wqkv"].reshape(x.shape[-1], 3 * H * Dk).astype(cd)
-        qkv = proj("bsd,de->bse", h, wqkv).astype(cd)
+        qkv = checkpoint_name(proj("bsd,de->bse", h, wqkv).astype(cd), KDA_QKV)
         taps = jnp.moveaxis(layer["kda_conv"], 0, 1).reshape(c.kda_conv, 3 * H * Dk)
         fits = Dk % 128 == 0  # the kernels' widths; else XLA's convolution and the jnp scan
         mode = kda_ops.kernel_mode() if fits else None
@@ -1758,6 +1768,7 @@ def _mlp_sublayer(
         gu = _pin_mlp_hidden(c, gu)
         if "bgu" in layer:
             gu = gu + layer["bgu"].astype(cd)
+        gu = checkpoint_name(gu, MLP_GU)
         h = jax.nn.silu(gu[:, :, 0]) * gu[:, :, 1]
     else:
         if use_cmm:
@@ -2048,32 +2059,58 @@ def apply_blocks(
     return x, aux
 
 
-def _under_remat(pol: str, block, kda: bool = False):
+def remat_kept_names() -> Tuple[str, ...]:
+    """The ``checkpoint_name``s ``dots`` and ``full_keep_kernels`` keep through
+    remat: one list for every layer kind (``_under_remat`` has the rule)."""
+    from ..ops.flash_attention import FLASH_RESIDUAL_NAMES
+    from ..ops.kda import KDA_RESIDUAL_NAMES
+    from .moe import MOE_RESIDUAL_NAMES
+
+    return (*FLASH_RESIDUAL_NAMES, *KDA_RESIDUAL_NAMES, *MOE_RESIDUAL_NAMES, *MATMUL_CAST_NAMES)
+
+
+def _under_remat(pol: str, block):
     """``block`` under the layer loop's remat policy ``pol`` (normalized).
-    ``dots`` saves matmul (dot_general without dot-batch dims, i.e. x @ W)
-    outputs and the mixer's forward kernel's named results (no dot_general: the
-    flash kernel's output and row sums, ``FLASH_RESIDUAL_NAMES``, or for a
-    ``kda`` layer the recurrence's output and the states entering its chunks,
-    ``ops.kda.KDA_RESIDUAL_NAMES``) and recomputes only LN/GELU/softmax/dropout
-    in backward: most of full remat's recompute tax gone while the elementwise
-    intermediates still drop from liveness. ``full_keep_kernels`` saves those
-    named results alone, ``full`` nothing."""
+
+    ``full`` keeps nothing and ``none`` everything. The two between keep **the
+    values that are dear to make again and cheap to hold**, by name
+    (``remat_kept_names``: one list, whatever the layer's kind; a name a block
+    does not produce costs nothing): ``full_keep_kernels`` those alone, ``dots``
+    those beside every matmul result (a ``dot_general`` without batch dims, x @
+    W), so that its backward recomputes only LN/GELU/softmax/dropout and the
+    cheap elementwise chains. The list: the mixer's forward kernel's results (no
+    ``dot_general``: the flash kernel's output and row sums,
+    ``FLASH_RESIDUAL_NAMES``; a ``kda`` layer's output and the states entering
+    its chunks, ``KDA_RESIDUAL_NAMES``), the routed experts' gate+up grouped
+    matmul's result (a Mosaic call too), the router's ``HIGHEST``-precision
+    logits, its choice and the plan that moves rows (sorts; ``moe.
+    MOE_RESIDUAL_NAMES``), a ``kda`` layer's q, k, v projection after its cast
+    (``KDA_QKV``) and a dense SwiGLU layer's gate+up (``MLP_GU``). ``dots`` holds
+    those last two as their ``dot_general``'s results already and leaves their
+    names out: with them the policy would trade each product for its cast, and
+    no second run would go.
+
+    **The rule for the list** (``tests/test_remat_flash.py`` holds it; PERF.md,
+    PR 50, has the readings): a value is named only if, in the benchmark cell
+    where it is largest, its second run costs at least 5 ms a step per GB it
+    holds, and every cell keeps 1.5 GB of HBM free with it. A name goes to the
+    value in its compute-dtype or integer form, never to the float32 in front
+    of a cast. By that rule the KDA convolutions' q, k, v (4.1 ms a GB), the
+    shared experts' gate+up (4.0) and ``dispatch``'s gathered rows (1.5) stay
+    dropped."""
     if pol == "none":
         return block
     if pol == "full":
         return jax.checkpoint(block)
-    if kda:
-        from ..ops.kda import KDA_RESIDUAL_NAMES as names
-    else:
-        from ..ops.flash_attention import FLASH_RESIDUAL_NAMES as names
     policies = jax.checkpoint_policies
-    kernel_results = policies.save_only_these_names(*names)
+    names = remat_kept_names()
     if pol == "full_keep_kernels":
-        return jax.checkpoint(block, policy=kernel_results)
+        return jax.checkpoint(block, policy=policies.save_only_these_names(*names))
     return jax.checkpoint(  # dots
         block,
         policy=policies.save_from_both_policies(
-            policies.dots_with_no_batch_dims_saveable, kernel_results))
+            policies.dots_with_no_batch_dims_saveable,
+            policies.save_only_these_names(*(n for n in names if n not in MATMUL_CAST_NAMES))))
 
 
 def embed_param_names(config: TinyGPTConfig) -> Tuple[str, ...]:
@@ -2173,14 +2210,15 @@ def apply_layer(config: TinyGPTConfig, layer: Params, x: jax.Array, kind: Option
     ``_block``'s two halves, **each under the config's remat policy on its
     own** (the backward then holds one sublayer's recomputed activations at a
     time, not a KDA mixer's beside a 9216-wide MLP's: 0.9 GB at the Kimi
-    cell's sizes, for one more (B, S, D) kept a layer), with the per-layer
+    cell's sizes, for one more (B, S, D) kept a layer; both halves keep the
+    one list of names, ``remat_kept_names``), with the per-layer
     placement hooks, on the layer's own slice of its stack -> (x, aux). Also
     what a check calls to run one layer of the timed config alone."""
     c, pol = config, normalize_remat(config.remat)
     keys = jax.random.split(key, 2) if key is not None else (None, None)
     layer = _constrain_layer(c, layer)
     mixer = _under_remat(pol, lambda x, layer, key, tables: _mixer_half(
-        c, x, layer, key, deterministic, kind, tables), kda=kind == scopes.KDA)
+        c, x, layer, key, deterministic, kind, tables))
     mlp = _under_remat(pol, lambda x, layer, key: _mlp_half(c, x, layer, key, deterministic))
     return mlp(mixer(x, layer, keys[0], qk_tables), layer, keys[1])
 

@@ -64,7 +64,9 @@ class StrategyConfig:
     shard_grads: bool = False
     shard_opt_state: bool = False
     # per-layer rematerialization policy inside the block scan:
-    # "none" | "dots" (save matmul outputs) | "full_keep_kernels" | "full" |
+    # "none" | "dots" (save matmul outputs and the named values of
+    # models.tinygpt.remat_kept_names) | "full_keep_kernels" (the named
+    # values alone) | "full" |
     # "auto" (pick the cheapest of none / dots / full whose memory estimate
     # fits the device — resolved by utils.memory.resolve_auto_remat before
     # training). Legacy bools accepted in JSON configs (True = "full").

@@ -185,6 +185,32 @@ def estimate_hbm(
         if pol == "none":
             kept += S * width * (3 * cbytes + 4) + S * cfg.kda_heads * 4
         kda_b = kda_layers * B * kept
+    # What 'dots' and 'full_keep_kernels' keep by name beside the mixer
+    # kernels' results (tinygpt._under_remat has the list): a routed layer's
+    # gate+up over the rows its experts take, its router's float32 logits
+    # where the routing trains (the plan's integer arrays are kilobytes) and,
+    # under 'full_keep_kernels' alone ('dots' counts its matmul results
+    # below), a KDA layer's q, k, v projection and a dense SwiGLU layer's
+    # gate+up.
+    named_b = 0
+    if pol in ("dots", "full_keep_kernels"):
+        tokens = B * layer_S
+        moe_layers = cfg.n_moe_layers if cfg.capacity_factor is None else 0
+        if moe_layers:
+            from ..models.moe import held_buffer_rows
+
+            rows = (tokens * cfg.expert_top_k if cfg.experts_held is None
+                    else held_buffer_rows(cfg, tokens))
+            named_b += moe_layers * rows * 2 * F * cbytes
+            if cfg.trains_routing:
+                named_b += moe_layers * tokens * cfg.n_experts * 4
+        if pol == "full_keep_kernels":
+            named_b += kda_layers * tokens * 3 * cfg.kda_heads * cfg.kda_head_dim * cbytes
+            if cfg.mlp_act == "swiglu":
+                dense_layers = L - cfg.n_moe_layers
+                named_b += (dense_layers * tokens * 2 * (cfg.dense_mlp_hidden or F) * cbytes
+                            // max(tp, 1))
+        named_b //= max(pp, 1)
     if pol in ("full", "full_keep_kernels"):
         # Only the layer-boundary residual (+grad) survives (and, kept by
         # name, the attention kernel's output); one layer's working set is
@@ -207,7 +233,7 @@ def estimate_hbm(
 
     return HBMEstimate(
         params=params_b, grads=grads_b, opt_state=opt_b,
-        activations=act_b + kda_b, logits=logits_b, dataset=dataset_b,
+        activations=act_b + kda_b + named_b, logits=logits_b, dataset=dataset_b,
     )
 
 

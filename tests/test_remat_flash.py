@@ -1,16 +1,21 @@
-"""Remat ``dots`` keeps the flash forward kernel's two results.
+"""Remat ``dots`` and ``full_keep_kernels`` keep what is dear to make again and
+cheap to hold, by name.
 
 A Mosaic call is no ``dot_general``: under the plain dots-class policy the
 kernel's ``out`` and ``lse`` (residuals of ``ops.flash_attention
 ._flash_fwd_rule``) were dropped and the whole O(S^2) kernel ran a second time
-in the backward pass. They carry ``checkpoint_name``s now
-(``FLASH_RESIDUAL_NAMES``) and ``models.tinygpt.apply_blocks`` saves those
-names beside the matmul results. These tests count the kernel's calls in the
-gradient's jaxpr (walking it: shared sub-jaxprs print once in its text) and
-hold ``dots`` to ``none``'s loss and gradients.
+in the backward pass. They carry ``checkpoint_name``s
+(``FLASH_RESIDUAL_NAMES``) and ``models.tinygpt._under_remat`` saves those
+names beside the matmul results; since PR 50 the same list
+(``tinygpt.remat_kept_names``) holds the routed experts' gate+up grouped
+matmul's result, the router's logits, choice and plan, a KDA layer's q, k, v
+projection and a dense SwiGLU layer's gate+up. These tests count calls in the
+gradient's jaxpr (walking it: shared sub-jaxprs print once in its text), hold
+both policies to ``none``'s loss and gradients, and hold the list to its rule.
 """
 
 import dataclasses
+import functools
 import re
 
 import jax
@@ -24,16 +29,25 @@ from distributed_llm_training_benchmark_framework_tpu.models import (
     get_llama_config,
     get_model_config,
 )
+from distributed_llm_training_benchmark_framework_tpu.models import moe, tinygpt
 from distributed_llm_training_benchmark_framework_tpu.models.tinygpt import (
+    TinyGPTConfig,
     init_params,
     loss_fn,
 )
+from distributed_llm_training_benchmark_framework_tpu.ops.flash_attention import (
+    FLASH_RESIDUAL_NAMES,
+)
+from distributed_llm_training_benchmark_framework_tpu.ops.kda import KDA_RESIDUAL_NAMES
 from distributed_llm_training_benchmark_framework_tpu.parallel import (
     get_strategy,
     make_mesh,
 )
 from distributed_llm_training_benchmark_framework_tpu.train import create_train_state
+from distributed_llm_training_benchmark_framework_tpu.utils import scopes
+from perfbench.harness import build_kda
 from test_deepseek import CONFIG as MLA_CONFIG
+from test_kimi_linear import FILE as KIMI_FILE
 
 SEQ, BATCH = 64, 2
 
@@ -49,9 +63,27 @@ CONFIGS = {
     # latent attention: keys 16 + 8 rotary over values of 16 (the cell's
     # 192 / 128 in small), a leading dense layer, held routed experts.
     "mla": MLA_CONFIG,
+    # three KDA layers to a latent-attention one, a leading dense SwiGLU layer,
+    # sigmoid routing over held experts: unequal stacks, run unrolled whatever
+    # ``scan_layers`` says.
+    "kda": dataclasses.replace(
+        build_kda.kimi_config(dict(seq_len=SEQ, held_rows_factor=4.0, attention="flash",
+                                   layer_loop="unrolled", kda_chunk=16), KIMI_FILE),
+        compute_dtype=jnp.float32),
+    # every expert on the chip, routing trained: the sort by expert and back.
+    "dropless": TinyGPTConfig(
+        vocab_size=128, n_embd=64, n_head=4, n_layer=2, block_size=SEQ, mlp_hidden=32,
+        n_experts=8, expert_top_k=2, capacity_factor=None, norm_topk_prob=False,
+        norm="rmsnorm", mlp_act="swiglu", pos_embed="rope", tie_embeddings=False, bias=False,
+        dropout=0.0, router_aux_coef=0.01, router_z_coef=0.001, attention_impl="flash",
+        compute_dtype=jnp.float32),
 }
 CASES = sorted(CONFIGS)
 LOOPS = {"unrolled": False, "scan": True}
+# (case, loop) a test runs: the ``kda`` stacks have one loop
+RUNS = [(case, loop) for case in CASES for loop in sorted(LOOPS)
+        if (case, loop) != ("kda", "scan")]
+KEEPING = ("dots", "full_keep_kernels")
 
 
 def _config(case, loop, remat):
@@ -73,23 +105,29 @@ def _operands(config):
     return params, batch
 
 
-def _kernel_runs(jaxpr, name, times=1):
-    """How often the ``pallas_call`` called ``name`` runs in ``jaxpr``: every
-    sub-jaxpr is walked, a scan's body counted ``length`` times."""
-    runs = 0
+def _equations(jaxpr, rematted=False, times=1):
+    """(equation, whether a ``jax.checkpoint``'s second run holds it, how often it
+    runs: a scan's body ``length`` times) of ``jaxpr`` and every jaxpr inside it."""
     for eqn in jaxpr.eqns:
-        if eqn.primitive.name == "pallas_call" and eqn.params["name"] == name:
-            runs += times
+        yield eqn, rematted, times
         inner = times * eqn.params["length"] if eqn.primitive.name == "scan" else times
         for sub in jax.core.jaxprs_in_params(eqn.params):
-            runs += _kernel_runs(sub, name, inner)
-    return runs
+            yield from _equations(sub, rematted or eqn.primitive.name == "remat2", inner)
+
+
+def _kernel_runs(jaxpr, name):
+    """How often the ``pallas_call`` called ``name`` runs in ``jaxpr``."""
+    return sum(times for eqn, _, times in _equations(jaxpr)
+               if eqn.primitive.name == "pallas_call" and eqn.params["name"] == name)
+
+
+def _flash_layers(config):
+    return config.n_layer - (config.layer_types or ()).count(scopes.KDA)
 
 
 @pytest.mark.parametrize("remat, runs_a_layer", [
     ("none", 1), ("dots", 1), ("full_keep_kernels", 1), ("full", 2)])
-@pytest.mark.parametrize("loop", sorted(LOOPS))
-@pytest.mark.parametrize("case", CASES)
+@pytest.mark.parametrize("case, loop", RUNS)
 def test_flash_forward_runs_a_layer_in_the_gradient(case, loop, remat, runs_a_layer):
     """``dots`` runs the forward kernel once a layer, as no remat does: its
     two results are saved by name; ``full_keep_kernels`` saves them and
@@ -98,22 +136,98 @@ def test_flash_forward_runs_a_layer_in_the_gradient(case, loop, remat, runs_a_la
     config = _config(case, loop, remat)
     params, batch = _operands(config)
     jaxpr = jax.make_jaxpr(jax.grad(_loss(config)))(params, batch).jaxpr
-    assert _kernel_runs(jaxpr, "flash_fwd") == runs_a_layer * config.n_layer
+    assert _kernel_runs(jaxpr, "flash_fwd") == runs_a_layer * _flash_layers(config)
 
 
-@pytest.mark.parametrize("loop", sorted(LOOPS))
-@pytest.mark.parametrize("case", CASES)
-def test_dots_matches_no_remat_through_the_saved_results(case, loop):
+@functools.lru_cache(maxsize=None)
+def _loss_and_gradients(case, loop, remat):
+    config = _config(case, loop, remat)
+    return jax.value_and_grad(_loss(config))(*_operands(config))
+
+
+@pytest.mark.parametrize("remat", KEEPING)
+@pytest.mark.parametrize("case, loop", RUNS)
+def test_a_keeping_policy_matches_no_remat_through_the_saved_results(case, loop, remat):
     """Same limits as ``test_model.py::test_remat_matches_no_remat``: the
-    backward reads the kernel's results where it used to recompute them."""
-    plain, dots = _config(case, loop, "none"), _config(case, loop, "dots")
-    params, batch = _operands(plain)
-    l_plain, g_plain = jax.value_and_grad(_loss(plain))(params, batch)
-    l_dots, g_dots = jax.value_and_grad(_loss(dots))(params, batch)
-    assert np.allclose(float(l_plain), float(l_dots), rtol=1e-5)
-    for a, b in zip(jax.tree.leaves(g_plain), jax.tree.leaves(g_dots)):
+    backward reads the named values where it used to make them again."""
+    l_plain, g_plain = _loss_and_gradients(case, loop, "none")
+    l_kept, g_kept = _loss_and_gradients(case, loop, remat)
+    assert np.allclose(float(l_plain), float(l_kept), rtol=1e-5)
+    assert jax.tree.structure(g_plain) == jax.tree.structure(g_kept)
+    for a, b in zip(jax.tree.leaves(g_plain), jax.tree.leaves(g_kept)):
         a, b = np.asarray(a, np.float64), np.asarray(b, np.float64)
         assert np.linalg.norm(a - b) / (np.linalg.norm(a) + 1e-12) < 1e-2
+
+
+def _gradient_equations(case, loop, remat):
+    config = _config(case, loop, remat)
+    jaxpr = jax.make_jaxpr(jax.grad(_loss(config)))(*_operands(config)).jaxpr
+    return config, list(_equations(jaxpr))
+
+
+@functools.lru_cache(maxsize=None)
+def _routed_counts(case, loop, remat):
+    """(megablox's ``gmm`` jits in the gradient, forward and backward alike; the
+    ``sort``s and the ``top_k``s a ``jax.checkpoint``'s second run holds)."""
+    _, equations = _gradient_equations(case, loop, remat)
+    gmms = sum(times for eqn, _, times in equations
+               if eqn.primitive.name == "jit" and eqn.params["name"] == "gmm")
+    again = {name: sum(times for eqn, rematted, times in equations
+                       if rematted and eqn.primitive.name == name) for name in ("sort", "top_k")}
+    return gmms, again
+
+
+# grouped matmuls a routed layer's second run adds to what no remat runs (until PR 50
+# gate+up's, one more): where the routing trains, the gates' gradient reads the experts'
+# output, which stays dropped, so the down matmul runs again
+GMMS_AGAIN = {"mla": 0, "kda": 0, "dropless": 1}
+
+
+@pytest.mark.parametrize("remat", KEEPING + ("full",))
+@pytest.mark.parametrize("case, loop", [run for run in RUNS if run[0] in GMMS_AGAIN])
+def test_the_routed_layers_second_run_holds_no_gate_up_matmul_and_no_sort(case, loop, remat):
+    """``gu`` by name: the gradient runs gate+up's ``gmm`` once, as no remat does.
+    The router's choice and the plan by name: no ``sort`` and no ``top_k`` runs
+    twice (but the one ``top_k`` whose own JVP reads its indices, where the gates
+    are its values and the routing trains: no cell's). ``full`` names nothing
+    and shows what the names took away."""
+    layers = _config(case, loop, remat).n_moe_layers
+    gmms, again = _routed_counts(case, loop, remat)
+    kept = _routed_counts(case, loop, "none")[0] + GMMS_AGAIN[case] * layers
+    if remat == "full":
+        assert gmms > kept and again["sort"] > 0 and again["top_k"] > 0
+    else:
+        assert gmms == kept
+        assert again == {"sort": 0, "top_k": layers if case == "dropless" else 0}
+
+
+@pytest.mark.parametrize("remat, reads", [("dots", 1), ("full_keep_kernels", 1), ("full", 2)])
+def test_the_kda_projection_runs_once(remat, reads):
+    """A KDA layer's second run multiplies by the (D, 3 H Dk) projection once, for
+    its input's gradient: the product itself is kept (``dots``: as the
+    ``dot_general``'s result; ``full_keep_kernels``: its cast by name)."""
+    config, equations = _gradient_equations("kda", "unrolled", remat)
+    weight = (config.n_embd, 3 * config.kda_heads * config.kda_head_dim)
+    products = sum(times for eqn, rematted, times in equations
+                   if rematted and eqn.primitive.name == "dot_general"
+                   and weight in [tuple(v.aval.shape) for v in eqn.invars])
+    assert products == reads * config.layer_types.count(scopes.KDA)
+
+
+def test_the_list_is_one_and_names_what_the_rule_allows():
+    """``_under_remat``'s rule: a value is named only if its second run costs at
+    least 5 ms a step per GB it holds in the benchmark cell where it is largest.
+    The readings are PERF.md's (section 5, "Memory by scope", my chip runs, PR
+    50): a new name comes with its own, and one that reads under 5 goes."""
+    ms_a_gb = {moe.MOE_GU: 6.6,  # sdar-30b-a3b.share8-bd8192: 4.95 ms for 0.755 GB
+               moe.ROUTER_LOGITS: 1000.0, moe.ROUTER_CHOICE: 1000.0,  # kimi: 8.78 ms, 17 MB
+               moe.MOE_PLAN: 1000.0,  # mellum2: 7.5 ms with combine's backward, 3 MB
+               tinygpt.KDA_QKV: 12.3,  # kimi: 19.86 ms for 1.611 GB
+               tinygpt.MLP_GU: 11.1}  # kimi: 6.73 ms for 0.604 GB
+    names = tinygpt.remat_kept_names()
+    assert len(set(names)) == len(names)
+    assert set(names) == {*FLASH_RESIDUAL_NAMES, *KDA_RESIDUAL_NAMES, *ms_a_gb}
+    assert min(ms_a_gb.values()) >= 5.0
 
 
 def test_dots_saves_the_shard_mapped_calls_results_on_their_shards(eight_devices, capsys):

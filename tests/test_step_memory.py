@@ -10,14 +10,16 @@ unless a case runs it; each case reads the record right after the
 """
 
 import dataclasses
+import functools
 import math
 
 import jax
 import jax.numpy as jnp
 import pytest
+from jax.ad_checkpoint import checkpoint_name
 
 from distributed_llm_training_benchmark_framework_tpu.analysis import memory_anatomy
-from distributed_llm_training_benchmark_framework_tpu.models import tinygpt
+from distributed_llm_training_benchmark_framework_tpu.models import moe, tinygpt
 from distributed_llm_training_benchmark_framework_tpu.models.tinygpt import TinyGPTConfig
 from distributed_llm_training_benchmark_framework_tpu.ops.flash_attention import (
     FLASH_RESIDUAL_NAMES,
@@ -134,12 +136,18 @@ def test_a_routed_config_shows_the_experts():
     assert total(saved["all"]) > total(saved["kept"])
 
 
+@functools.lru_cache(maxsize=None)
+def saved_of(which, policy):
+    """``saved()`` of the routed or the Kimi-shaped config's step under ``policy``."""
+    config, batch = {"routed": (ROUTED, BATCH), "kimi": (KIMI, 1)}[which]
+    return compiled_step(config, policy, batch=batch)[0]["saved"]()
+
+
 @pytest.mark.parametrize("policy", ("full_keep_kernels", "none"))
 def test_the_kda_states_are_the_counters_bytes(policy):
     """Under the recurrence's second name the account holds what
     ``tinygpt.kda_stats`` counts a layer, times the KDA layers."""
-    record, _ = compiled_step(KIMI, policy, batch=1)
-    saved = record["saved"]()
+    saved = saved_of("kimi", policy)
     stats = tinygpt.kda_stats(dataclasses.replace(KIMI, compute_dtype=jnp.bfloat16), SEQ)
     states = named(saved["kept"], KDA_RESIDUAL_NAMES[1])
     assert len(states) == stats["layers"] == 4
@@ -148,6 +156,99 @@ def test_the_kda_states_are_the_counters_bytes(policy):
     paths = {e[0] for e in saved["all"]}
     assert (scopes.ATTENTION, scopes.KDA, scopes.KDA_PREP) in paths
     assert (scopes.ATTENTION, scopes.GLOBAL, scopes.MLA_CORE) in paths
+
+
+@pytest.mark.parametrize("policy", ("dots", "full_keep_kernels", "full"))
+def test_the_routed_layers_named_values_by_scope_and_bytes(policy):
+    """The experts' gate+up (N x K rows of 2F at the compute dtype), the router's
+    float32 logits and its choice, the two permutations of the plan: a layer
+    each under the policies that keep names, none of them under ``full``."""
+    kept = saved_of("routed", policy)["kept"]
+    by_name = {name: named(kept, name) for name in moe.MOE_RESIDUAL_NAMES}
+    if policy == "full":
+        assert not any(by_name.values())
+        return
+    layers, tokens, K = ROUTED.n_layer, BATCH * SEQ, ROUTED.expert_top_k
+    assert total(by_name[moe.MOE_GU]) == layers * tokens * K * 2 * ROUTED.mlp_dim * 2
+    assert {e[3] for e in by_name[moe.MOE_GU]} == {"bfloat16"}
+    assert total(by_name[moe.ROUTER_LOGITS]) == layers * tokens * ROUTED.n_experts * 4
+    assert total(by_name[moe.MOE_PLAN]) == layers * 2 * tokens * K * 4
+    assert by_name[moe.ROUTER_CHOICE]
+    for name, path in ((moe.MOE_GU, scopes.EXPERTS), (moe.ROUTER_LOGITS, scopes.ROUTER),
+                       (moe.ROUTER_CHOICE, scopes.ROUTER), (moe.MOE_PLAN, scopes.DISPATCH)):
+        assert {e[0] for e in by_name[name]} == {(scopes.MLP, path)}
+
+
+@pytest.mark.parametrize("policy", ("dots", "full_keep_kernels", "full"))
+def test_the_kimi_layers_named_values_by_scope_and_bytes(policy):
+    """A KDA layer's q, k, v projection and the dense layer's gate+up, in their
+    compute-dtype form: by name under ``full_keep_kernels``; under ``dots`` as
+    their ``dot_general``'s float32 result and under no name; nowhere under
+    ``full``. The held experts' gate+up by name under both."""
+    kept = saved_of("kimi", policy)["kept"]
+    kda_layers = KIMI.layer_types.count(scopes.KDA)
+    width = 3 * KIMI.kda_heads * KIMI.kda_head_dim
+    qkv, dense, gu = (named(kept, name) for name in (tinygpt.KDA_QKV, tinygpt.MLP_GU, moe.MOE_GU))
+    prep = (scopes.ATTENTION, scopes.KDA, scopes.KDA_PREP)
+    products = [e for e in kept if e[:3] == (prep, "dot_general", (1, SEQ, width))]
+    if policy == "full_keep_kernels":
+        assert total(qkv) == kda_layers * SEQ * width * 2 and {e[0] for e in qkv} == {prep}
+        assert total(dense) == KIMI.first_k_dense * SEQ * 2 * KIMI.dense_mlp_hidden * 2
+        assert {e[0] for e in dense} == {(scopes.MLP,)}
+        assert {e[3] for e in qkv + dense} == {"bfloat16"} and not products
+    else:
+        assert not qkv and not dense
+        assert len(products) == (kda_layers if policy == "dots" else 0)
+    rows = moe.held_buffer_rows(KIMI, SEQ)
+    assert total(gu) == (0 if policy == "full" else KIMI.n_moe_layers * rows * 2 * KIMI.mlp_dim * 2)
+    assert {e[0] for e in gu} <= {(scopes.MLP, scopes.EXPERTS)}
+
+
+def _jaxprs(jaxpr):
+    """``jaxpr`` and every jaxpr inside it."""
+    yield jaxpr
+    for eqn in jaxpr.eqns:
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _jaxprs(sub)
+
+
+def _names_in_front_of_a_cast(jaxpr, names):
+    """The ``checkpoint_name``s of ``names`` in ``jaxpr`` (and every jaxpr inside
+    it) given to a float32 value that a cast to a narrower float follows."""
+    found = []
+    for inner in _jaxprs(jaxpr):
+        for eqn in inner.eqns:
+            if eqn.primitive.name != "name" or eqn.params["name"] not in names:
+                continue
+            value = eqn.outvars[0]
+            if any(value in e.invars and e.primitive.name == "convert_element_type"
+                   and jnp.issubdtype(e.params["new_dtype"], jnp.floating)
+                   and jnp.dtype(e.params["new_dtype"]).itemsize < value.aval.dtype.itemsize
+                   for e in inner.eqns):
+                found.append(eqn.params["name"])
+    return found
+
+
+@pytest.mark.parametrize("which", ("routed", "kimi", "a name in front of its cast"))
+def test_no_name_is_given_to_a_float32_in_front_of_its_cast(which):
+    """A name keeps the value it is given: on the float32 product in front of
+    ``.astype(bfloat16)`` it would hold twice the bytes the backward reads."""
+    names = tinygpt.remat_kept_names()
+    if which == "a name in front of its cast":  # what the walk is there to catch
+        wrong = lambda x, w: checkpoint_name(x @ w, names[-1]).astype(jnp.bfloat16).sum()
+        x = jnp.ones((4, 4), jnp.float32)
+        assert _names_in_front_of_a_cast(jax.make_jaxpr(wrong)(x, x).jaxpr, names) == [names[-1]]
+        return
+    config = dataclasses.replace(
+        {"routed": ROUTED, "kimi": KIMI}[which], compute_dtype=jnp.bfloat16, remat="none")
+    params = jax.eval_shape(lambda: tinygpt.init_params(config, jax.random.key(0)))
+    batch = jax.ShapeDtypeStruct((1, SEQ), jnp.int32)
+    jaxpr = jax.make_jaxpr(lambda p, b: tinygpt.loss_fn(config, p, b, b))(params, batch).jaxpr
+    seen = {e.params["name"] for inner in _jaxprs(jaxpr) for e in inner.eqns
+            if e.primitive.name == "name"}
+    assert seen >= ({moe.MOE_GU, moe.ROUTER_LOGITS} | ({tinygpt.KDA_QKV, tinygpt.MLP_GU}
+                                                       if which == "kimi" else set()))
+    assert _names_in_front_of_a_cast(jaxpr, names) == []
 
 
 def test_it_works_from_shapes_and_once():
