@@ -18,7 +18,10 @@ OLMoE's sizes that fits the kernel's VMEM; megablox pads a width to whole tiles
 and multiplies the padding, which at Mellum's 2304 x 1792 was a third of the
 work: PERF.md, PR 42). Experts are SwiGLU without bias (``moe_wgu`` (E, D, 2F), gate
 columns then up columns, and ``moe_wd`` (E, F, D); the config pairs
-``mlp_act='swiglu'`` with this path); gates are renormalised only if
+``mlp_act='swiglu'`` with this path) or, under ``mlp_act='relu2'``, not gated:
+``W_down relu(W_up h)^2`` (``moe_wu`` (E, D, F) in ``moe_wgu``'s place, the shared
+expert's ``shared_wu`` at its own width, ``shared_expert_hidden``; Nemotron-H);
+gates are renormalised only if
 ``norm_topk_prob``; the auxiliary channel carries the load-balance term over
 all K choices plus the router z-loss. Its parts carry the scopes ``router`` /
 ``dispatch`` / ``experts`` / ``combine`` (``utils/scopes.MOE_SCOPES``). What
@@ -464,9 +467,19 @@ def _grouped_matmul(c, rows: jax.Array, weights: jax.Array, counts: jax.Array) -
     )
 
 
+def _relu2(u: jax.Array) -> jax.Array:
+    """relu(u)^2, squared in float32, in u's dtype: the activation of experts
+    that are not gated (``mlp_act='relu2'``)."""
+    return jnp.square(jax.nn.relu(u.astype(jnp.float32))).astype(u.dtype)
+
+
 def _experts_dropless(c, layer, rows: jax.Array, counts: jax.Array) -> jax.Array:
-    """SwiGLU experts over rows in expert order, (M, D) -> (M, D)."""
+    """The experts over rows in expert order, (M, D) -> (M, D): SwiGLU (leaf
+    ``moe_wgu``), or not gated, W_down relu(W_up h)^2 (leaf ``moe_wu``)."""
     F = c.mlp_dim
+    if "moe_wu" in layer:  # the first grouped matmul's result keeps its name
+        u = checkpoint_name(_grouped_matmul(c, rows, layer["moe_wu"], counts), MOE_GU)
+        return _grouped_matmul(c, _relu2(u), layer["moe_wd"], counts)
     # gate and up in one matmul; kept through remat by name: its re-run is a ``gmm``
     gu = checkpoint_name(_grouped_matmul(c, rows, layer["moe_wgu"], counts), MOE_GU)
     h = jax.nn.silu(gu[:, :F]) * gu[:, F:]
@@ -479,6 +492,13 @@ def _shared_experts(c, layer, x: jax.Array) -> jax.Array:
     then up columns in one matrix, as the routed experts store theirs."""
     cd = c.compute_dtype
     Fs = layer["shared_wd"].shape[0]
+    if "shared_wu" in layer:  # not gated: W_down relu(W_up h)^2
+        u = jnp.einsum(
+            "bsd,df->bsf", x, layer["shared_wu"].astype(cd), preferred_element_type=jnp.float32
+        ).astype(cd)
+        return jnp.einsum(
+            "bsf,fd->bsd", _relu2(u), layer["shared_wd"].astype(cd),
+            preferred_element_type=jnp.float32).astype(cd)
     gu = jnp.einsum(
         "bsd,df->bsf", x, layer["shared_wgu"].astype(cd), preferred_element_type=jnp.float32
     ).astype(cd)

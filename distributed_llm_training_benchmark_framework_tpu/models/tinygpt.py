@@ -57,6 +57,12 @@ REMAT_POLICIES = ("none", "dots", "full_keep_kernels", "full")
 #: (``_mlp_sublayer``).
 KDA_QKV, MLP_GU = MATMUL_CAST_NAMES = ("kda_qkv", "mlp_gu")
 
+#: An SSD (Mamba-2) layer's x | B | C projection after its cast
+#: (``_ssd_sublayer``): named, so that ``saved_for_backward`` lists it apart, and
+#: **not** on the list ``full_keep_kernels`` keeps (a 6144-wide product a layer:
+#: by ``_under_remat``'s rule its second run is cheaper than its room).
+SSD_XBC = "ssd_xbc"
+
 
 def normalize_remat(value: Any) -> str:
     """Normalize a remat policy: accepts one of ``REMAT_POLICIES`` or a legacy
@@ -125,9 +131,12 @@ class YarnScaling:
 #: chooses the layer's mixer: ``global`` is softmax attention over every
 #: earlier position (latent attention where the config has it), ``window``
 #: over the last ``sliding_window`` of them, ``kda`` the gated delta-rule
-#: recurrence (``_kda_sublayer``), which has leaves of its own. Also the names
-#: of their scopes under ``attention``.
-LAYER_KINDS = (scopes.GLOBAL, scopes.WINDOW, scopes.KDA)
+#: recurrence (``_kda_sublayer``), ``ssd`` a Mamba-2 mixer (the scalar-decay
+#: state-space scan, ``_ssd_sublayer``), both with leaves of their own. Also the
+#: names of their scopes under ``attention``. ``mlp`` is no mixer: under
+#: ``block_halves`` a block of that kind is the feed-forward part alone (scope
+#: ``mlp``), and a block of a mixer's kind the mixer alone.
+LAYER_KINDS = (scopes.GLOBAL, scopes.WINDOW, scopes.KDA, scopes.SSD, scopes.MLP)
 
 #: The stacks of the parameter tree, by (the mixer is KDA, the MLP is a
 #: leading dense one): layers of one stack have equal leaves
@@ -136,6 +145,10 @@ LAYER_KINDS = (scopes.GLOBAL, scopes.WINDOW, scopes.KDA)
 #: its kind in front: ``global_dense_blocks``, ``window_blocks``.
 _STACK_NAMES = {(False, False): "blocks", (False, True): "dense_blocks",
                 (True, False): "kda_blocks", (True, True): "kda_dense_blocks"}
+
+#: The kinds of layer that have no softmax attention: no mask rule, no rotary
+#: table, no flash kernel.
+_NO_ATTENTION = frozenset((scopes.KDA, scopes.SSD, scopes.MLP))
 
 
 @dataclasses.dataclass(frozen=True)
@@ -263,13 +276,16 @@ class TinyGPTConfig:
     norm: str = "layernorm"
     norm_eps: float = 1e-5
     # Position information: 'learned' (additive wpe table, the reference
-    # design) or 'rope' (rotary embedding applied to q/k per head — no
+    # design), 'rope' (rotary embedding applied to q/k per head — no
     # positional parameters at all, and block_size no longer bounds the
-    # table, only the benchmark geometry).
+    # table, only the benchmark geometry) or 'none' (no table and no rotation:
+    # attention layers beside ``ssd`` ones, whose scan carries the order).
     pos_embed: str = "learned"
     rope_theta: float = 10000.0
-    # MLP: 'gelu' (D -> mlp_dim -> exact-erf GELU -> D, the reference MLP)
-    # or 'swiglu' (gate/up pair, silu(gate)*up -> down — Llama).
+    # MLP: 'gelu' (D -> mlp_dim -> exact-erf GELU -> D, the reference MLP),
+    # 'swiglu' (gate/up pair, silu(gate)*up -> down — Llama) or 'relu2' (not
+    # gated: D -> mlp_dim -> relu(.)^2 -> D, no bias — Nemotron-H; the dropless
+    # routed experts' and their shared expert's only, leaves moe_wu / shared_wu).
     mlp_act: str = "gelu"
     # Hidden width of the MLP. None = 4*n_embd (the reference ratio). The
     # Llama family passes an explicit width (~8/3*D rounded for SwiGLU's
@@ -367,6 +383,28 @@ class TinyGPTConfig:
     kda_head_dim: int = 0
     kda_conv: int = 4
     kda_chunk: int = 128  # ops.kda.DEFAULT_CHUNK: measured there
+    # An ``ssd`` layer's sizes (a Mamba-2 mixer, Nemotron-H): ssd_heads heads of
+    # ssd_head_dim channels (d_inner = their product), B and C of ssd_state
+    # columns shared by the heads of each of ssd_groups groups, a depthwise
+    # causal convolution of ssd_conv taps with a bias over x | B | C, the scan
+    # in chunks of ssd_chunk positions (``ops/ssd.py``), a gated RMSNorm over
+    # each group's d_inner / ssd_groups channels.
+    ssd_heads: int = 0
+    ssd_head_dim: int = 0
+    ssd_groups: int = 1
+    ssd_state: int = 0
+    ssd_conv: int = 4
+    ssd_chunk: int = 128  # the family's published chunk_size
+    # Each block of the stack is one sublayer alone behind its own norm and
+    # residual (Nemotron-H's hybrid_override_pattern): a block of a mixer's kind
+    # has no feed-forward part, and a block of kind ``mlp`` no mixer. The
+    # stacks of the parameter tree are then by kind (``layer_groups``:
+    # 'ssd_blocks', 'global_blocks', 'mlp_blocks'), each with the one norm its
+    # half reads (ln1_scale a mixer's, ln2_scale the feed-forward part's).
+    block_halves: bool = False
+    # Width of the one shared expert where it is not n_shared_experts x mlp_dim
+    # (Nemotron-H: moe_shared_expert_intermediate_size).
+    shared_expert_hidden: Optional[int] = None
     # Latent attention without rotary on its qk_rope_head_dim columns (Kimi
     # Linear's mla_use_nope): the columns stay, nothing rotates them.
     mla_nope: bool = False
@@ -512,16 +550,38 @@ class TinyGPTConfig:
         return scopes.KDA in (self.layer_types or ())
 
     @property
+    def ssd_inner(self) -> int:
+        """d_inner of an ``ssd`` layer: heads x head width."""
+        return self.ssd_heads * self.ssd_head_dim
+
+    @property
+    def ssd_xbc(self) -> int:
+        """Columns of an ``ssd`` layer's x | B | C, what its convolution spans."""
+        return self.ssd_inner + 2 * self.ssd_groups * self.ssd_state
+
+    def halves(self, kind: Optional[str]) -> Tuple[bool, bool]:
+        """(a layer of ``kind`` has a mixer, it has a feed-forward part)."""
+        if not self.block_halves:
+            return True, True
+        return kind != scopes.MLP, kind == scopes.MLP
+
+    @property
+    def shared_dim(self) -> int:
+        """Width of the shared experts' one MLP."""
+        return self.shared_expert_hidden or self.n_shared_experts * self.mlp_dim
+
+    @property
     def heads_by_kind(self) -> bool:
         """Whether the stack's attention kinds differ in head count."""
-        kinds = set(self.layer_types or ()) - {scopes.KDA}
+        kinds = set(self.layer_types or ()) - _NO_ATTENTION
         return len({self.heads(kind) for kind in kinds}) > 1
 
     @property
     def stacks_unequal(self) -> bool:
         """Whether the layers' leaves differ by kind (another mixer, another
-        head count): such stacks run unrolled through ``_apply_stacks``."""
-        return self.has_kda or self.heads_by_kind
+        head count, a half alone): such stacks run unrolled through
+        ``_apply_stacks``."""
+        return self.has_kda or self.heads_by_kind or self.block_halves
 
     @property
     def layer_groups(self) -> Tuple[Tuple[str, Tuple[int, ...]], ...]:
@@ -531,6 +591,10 @@ class TinyGPTConfig:
         differ in head count, the kind."""
         groups: Dict[str, list] = {}
         by_kind = self.heads_by_kind
+        if self.block_halves:  # a block is one half alone: its kind decides its leaves
+            for i, kind in enumerate(self.layer_types):
+                groups.setdefault(f"{kind}_blocks", []).append(i)
+            return tuple((name, tuple(layers)) for name, layers in groups.items())
         for i in range(self.n_layer):
             kind = None if self.layer_types is None else self.layer_types[i]
             kda = kind == scopes.KDA
@@ -545,8 +609,14 @@ class TinyGPTConfig:
         return (3,) if self.reports_held_overflow else ()
 
     @property
+    def n_mlp_layers(self) -> int:
+        """Layers with a feed-forward part: all of them, or under
+        ``block_halves`` the blocks of kind ``mlp``."""
+        return self.layer_types.count(scopes.MLP) if self.block_halves else self.n_layer
+
+    @property
     def n_moe_layers(self) -> int:
-        return self.n_layer - self.first_k_dense if self.n_experts > 0 else 0
+        return self.n_mlp_layers - self.first_k_dense if self.n_experts > 0 else 0
 
     @property
     def n_experts_held(self) -> int:
@@ -599,28 +669,45 @@ class TinyGPTConfig:
             raise ValueError(
                 "the pipeline schedules slice one homogeneous stack; layer_types "
                 "gives each layer a kind of its own (sliding_window or kda layers "
-                "beside global ones, stacks of unequal leaves under layer_heads). Run "
+                "beside global ones, ssd layers, stacks of unequal leaves under "
+                "layer_heads or block_halves). Run "
                 "this config with pipe=1"
             )
 
     def __post_init__(self):
         if self.norm not in ("layernorm", "rmsnorm"):
             raise ValueError(f"norm must be 'layernorm'|'rmsnorm', got {self.norm!r}")
-        if self.pos_embed not in ("learned", "rope"):
+        if self.pos_embed not in ("learned", "rope", "none"):
             raise ValueError(
-                f"pos_embed must be 'learned'|'rope', got {self.pos_embed!r}"
+                f"pos_embed must be 'learned'|'rope'|'none', got {self.pos_embed!r}"
             )
-        if self.mlp_act not in ("gelu", "swiglu"):
-            raise ValueError(f"mlp_act must be 'gelu'|'swiglu', got {self.mlp_act!r}")
+        if self.mlp_act not in ("gelu", "swiglu", "relu2"):
+            raise ValueError(
+                f"mlp_act must be 'gelu'|'swiglu'|'relu2', got {self.mlp_act!r}")
+        if self.mlp_act == "relu2" and (
+                self.bias or self.tp_collective_matmul or self.n_experts == 0
+                or self.capacity_factor is not None):
+            raise ValueError(
+                "mlp_act='relu2' (W_down relu(W_up h)^2, no bias) is the dropless routed "
+                "experts' and their shared expert's (n_experts > 0, capacity_factor=None), "
+                "without tp_collective_matmul; a dense MLP of it is not built")
+        if self.pos_embed == "none" and (
+                self.latent_attention or self.layer_rotary is not None
+                or self.seq_manual_axis is not None):
+            raise ValueError(
+                "pos_embed='none' (attention without a table or a rotation: the layers "
+                "beside it carry the order) is ordinary attention's, without layer_rotary "
+                "(latent attention has mla_nope) and outside the sequence-parallel pipeline")
         if self.n_kv_head is not None and self.n_head % self.n_kv_head != 0:
             raise ValueError(
                 f"n_kv_head={self.n_kv_head} must divide n_head={self.n_head}"
             )
         dropless = self.capacity_factor is None
-        if self.n_experts > 0 and dropless != (self.mlp_act == "swiglu" and not self.bias):
+        if self.n_experts > 0 and dropless != (
+                self.mlp_act in ("swiglu", "relu2") and not self.bias):
             raise ValueError(
                 "MoE blocks come in two kinds: GELU experts with biases under a "
-                "capacity_factor, and SwiGLU experts without bias under dropless "
+                "capacity_factor, and SwiGLU or relu2 experts without bias under dropless "
                 f"routing (capacity_factor=None); got mlp_act={self.mlp_act!r}, "
                 f"bias={self.bias}, capacity_factor={self.capacity_factor!r}"
             )
@@ -663,6 +750,8 @@ class TinyGPTConfig:
             raise ValueError(
                 "n_shared_experts, experts_held and seq_aux belong to dropless routing"
             )
+        if self.shared_expert_hidden is not None and not self.n_shared_experts:
+            raise ValueError("shared_expert_hidden is the width of n_shared_experts' one MLP")
         if self.experts_held is not None:
             first, count = self.experts_held
             if not (0 <= first and 0 < count and first + count <= self.n_experts):
@@ -692,8 +781,8 @@ class TinyGPTConfig:
             if self.attention_impl not in ("flash", "reference") or (
                     self.seq_manual_axis is not None):
                 raise ValueError(
-                    "layer_types (sliding_window or kda layers beside global ones, head "
-                    "counts by kind) runs "
+                    "layer_types (sliding_window or kda layers beside global ones, ssd "
+                    "layers, head counts by kind, blocks of one half) runs "
                     "attention_impl 'flash' or 'reference' on whole sequences: ring "
                     "attention, Ulysses and the sequence-parallel pipeline cut the "
                     "sequence, and their bodies take causal or no mask only; got "
@@ -722,10 +811,38 @@ class TinyGPTConfig:
                     "leaves run unrolled, in the published order, and the scanned "
                     "loop is refused"
                 )
+            if scopes.SSD in kinds and not (
+                    self.ssd_heads > 0 and self.ssd_head_dim > 0 and self.ssd_state > 0
+                    and self.ssd_groups > 0 and self.ssd_heads % self.ssd_groups == 0
+                    and self.ssd_conv >= 1 and self.ssd_chunk >= 1
+                    and self.norm == "rmsnorm" and not self.bias
+                    and not self.scan_layers and not self.tp_collective_matmul
+                    and not self.dropout and self.block_halves):
+                raise ValueError(
+                    "an 'ssd' layer needs ssd_heads, ssd_head_dim, ssd_state, ssd_groups "
+                    "dividing ssd_heads, ssd_conv >= 1, ssd_chunk >= 1, norm='rmsnorm', "
+                    "bias=False, no dropout, no tp_collective_matmul, block_halves=True "
+                    "(a Mamba-2 block is the mixer alone) and scan_layers=False: stacks of "
+                    "unequal leaves run unrolled, in the published order, and the scanned "
+                    "loop is refused"
+                )
+            if (scopes.MLP in kinds) != self.block_halves or (self.block_halves and (
+                    self.scan_layers or self.first_k_dense or self.layer_heads is not None
+                    or scopes.MLP not in kinds or self.tp_collective_matmul)):
+                raise ValueError(
+                    "block_halves (each block a mixer or a feed-forward part alone) goes "
+                    "with layer_types that name the 'mlp' blocks, and a kind 'mlp' with "
+                    "block_halves: stacks by kind, run unrolled in the published order "
+                    "(scan_layers=False: the scanned loop is refused), without first_k_dense, "
+                    "layer_heads or tp_collective_matmul; got "
+                    f"block_halves={self.block_halves}, layer_types={kinds}"
+                )
+        elif self.block_halves:
+            raise ValueError("block_halves needs layer_types: a kind for each block")
         if self.layer_heads is not None:
             if kinds is None or self.latent_attention or self.n_kv_head is None or (
                     self.tp_collective_matmul) or any(
-                    k not in kinds or k == scopes.KDA or n < 1 or n % self.kv_heads
+                    k not in kinds or k in _NO_ATTENTION or n < 1 or n % self.kv_heads
                     for k, n in self.layer_heads):
                 raise ValueError(
                     "layer_heads gives ((kind, query heads), ...) for attention kinds of "
@@ -907,6 +1024,22 @@ PARAM_AXIS_RULES: Dict[str, Tuple[Optional[str], ...]] = {
     # The attention's per-head output gate (present when attn_gate): one
     # column a query head, so it splits over 'model' as wq's columns do.
     "blocks/wg": ("layers", "embed", "gate_heads"),
+    # An 'ssd' layer's mixer (the stack 'ssd_blocks'; present instead of the
+    # attention leaves): in_proj's columns [z | x B C | dt], the depthwise
+    # convolution's taps and bias over x | B | C, a head's dt bias, decay rate
+    # and skip, the gated norm's (d_inner,) scale; wo (out_proj) as above. No
+    # tensor-parallel rule: under a 'model' axis they stay whole.
+    "blocks/ssd_win": ("layers", "embed", "ssd_in"),
+    "blocks/ssd_conv": ("layers", "conv", "ssd_xbc"),
+    "blocks/ssd_conv_bias": ("layers", "ssd_xbc"),
+    "blocks/ssd_dt_bias": ("layers", "ssd_heads"),
+    "blocks/ssd_a_log": ("layers", "ssd_heads"),
+    "blocks/ssd_d": ("layers", "ssd_heads"),
+    "blocks/ssd_norm": ("layers", "ssd_inner"),
+    # Experts that are not gated (mlp_act='relu2', present instead of moe_wgu /
+    # shared_wgu): the up projection alone.
+    "blocks/moe_wu": ("layers", "experts", "embed", "mlp"),
+    "blocks/shared_wu": ("layers", "embed", "mlp"),
     "lnf_scale": ("embed",),
     "lnf_bias": ("embed",),
     # Untied LM head (present when tie_embeddings=False): same logical axes
@@ -943,10 +1076,13 @@ def init_params(config: TinyGPTConfig, key: jax.Array) -> Params:
     ones = lambda shape: jnp.ones(shape, c.param_dtype)
 
     def norms_and_attention(L, H):
-        """One stack's norm scales and attention leaves, L layers of H heads."""
-        blocks = {"ln1_scale": ones((L, D)), "ln2_scale": ones((L, D))}
+        """One stack's norm scales and attention leaves, L layers of H heads
+        (under ``block_halves`` the mixer's one norm)."""
+        blocks = {"ln1_scale": ones((L, D))}
+        if not c.block_halves:
+            blocks["ln2_scale"] = ones((L, D))
         if c.norm == "layernorm":
-            blocks.update(ln1_bias=zeros((L, D)), ln2_bias=zeros((L, D)))
+            blocks.update({f"{name[:3]}_bias": zeros((L, D)) for name in list(blocks)})
         if c.latent_attention:
             R, Dr = c.kv_lora_rank, c.qk_rope_head_dim
             blocks.update(
@@ -1002,9 +1138,34 @@ def init_params(config: TinyGPTConfig, key: jax.Array) -> Params:
             wo=normal(next(k), (L, Hk * Dk, D)),
         )
 
+    def norm_and_ssd(L):
+        """A stack of ``ssd`` blocks: the norm's scale and the Mamba-2 mixer's
+        leaves, L layers. The family's published initialisation: the filters
+        as a depthwise Conv1d's (uniform within 1 / sqrt(taps), the bias too),
+        the decay's rate exp(A_log) uniform on [1, 16] a head, dt's bias the
+        inverse softplus of a step log-uniform on [0.001, 0.1] a head (floor
+        1e-4), the skip D at ones, the gated norm's scale at ones."""
+        Hs, taps, W = c.ssd_heads, c.ssd_conv, c.ssd_xbc
+        uniform = lambda key, shape, lo, hi: jax.random.uniform(
+            key, shape, jnp.float32, minval=lo, maxval=hi)
+        step = jnp.maximum(
+            jnp.exp(uniform(next(k), (L, Hs), math.log(1e-3), math.log(0.1))), 1e-4)
+        bound = taps ** -0.5
+        return dict(
+            ln1_scale=ones((L, D)),
+            ssd_win=normal(next(k), (L, D, c.ssd_inner + W + Hs)),
+            ssd_conv=uniform(next(k), (L, taps, W), -bound, bound).astype(c.param_dtype),
+            ssd_conv_bias=uniform(next(k), (L, W), -bound, bound).astype(c.param_dtype),
+            ssd_dt_bias=(step + jnp.log(-jnp.expm1(-step))).astype(c.param_dtype),
+            ssd_a_log=jnp.log(uniform(next(k), (L, Hs), 1.0, 16.0)).astype(c.param_dtype),
+            ssd_d=ones((L, Hs)),
+            ssd_norm=ones((L, c.ssd_inner)),
+            wo=normal(next(k), (L, c.ssd_inner, D)),
+        )
+
     def mlp_leaves(L):
         """One stack's MLP as the config has it (routed where n_experts), L layers."""
-        blocks = {}
+        blocks = {"ln2_scale": ones((L, D))} if c.block_halves else {}
         if c.n_experts > 0:
             E = c.n_experts
             blocks["router"] = normal(next(k), (L, D, E))
@@ -1012,16 +1173,18 @@ def init_params(config: TinyGPTConfig, key: jax.Array) -> Params:
                 blocks["router_bias"] = zeros((L, E))
             if c.capacity_factor is None:  # dropless SwiGLU experts, no bias
                 held = c.n_experts_held  # the router scores E; this chip's leaves hold these
-                blocks.update(
-                    moe_wgu=normal(next(k), (L, held, D, 2 * F)),
-                    moe_wd=normal(next(k), (L, held, F, D)),
-                )
+                gated = c.mlp_act == "swiglu"  # else relu2: the up projection alone
+                up, up_width = ("wgu", 2) if gated else ("wu", 1)
+                blocks.update({
+                    f"moe_{up}": normal(next(k), (L, held, D, up_width * F)),
+                    "moe_wd": normal(next(k), (L, held, F, D)),
+                })
                 if c.n_shared_experts:
-                    Fs = c.n_shared_experts * F
-                    blocks.update(
-                        shared_wgu=normal(next(k), (L, D, 2 * Fs)),
-                        shared_wd=normal(next(k), (L, Fs, D)),
-                    )
+                    Fs = c.shared_dim
+                    blocks.update({
+                        f"shared_{up}": normal(next(k), (L, D, up_width * Fs)),
+                        "shared_wd": normal(next(k), (L, Fs, D)),
+                    })
             else:
                 blocks.update(
                     moe_w1=normal(next(k), (L, E, D, F)),
@@ -1047,6 +1210,11 @@ def init_params(config: TinyGPTConfig, key: jax.Array) -> Params:
         """The stack ``name`` (``layer_groups``) of these layers: its mixer's
         leaves, then its MLP's; the draws in that order."""
         L = len(layers)
+        if c.block_halves:  # the one half the blocks of this kind are
+            kind = c.layer_types[layers[0]]
+            if kind == scopes.MLP:
+                return mlp_leaves(L)
+            return norm_and_ssd(L) if kind == scopes.SSD else norms_and_attention(L, c.heads(kind))
         if name.startswith("kda_"):
             leaves = norms_and_kda(L)
         else:
@@ -1400,6 +1568,8 @@ def _mixer_half(c, x, layer, key, deterministic, kind, qk_tables):
         with jax.named_scope(kind):
             if kind == scopes.KDA:
                 return _kda_sublayer(c, x, layer)
+            if kind == scopes.SSD:
+                return _ssd_sublayer(c, x, layer)
             return _attention_sublayer(c, x, layer, key, deterministic, kind, qk_tables)
 
 
@@ -1450,7 +1620,7 @@ def qk_prologue_tables(c: TinyGPTConfig, S: int) -> Dict:
     if rotary_ops.kernel_mode() is None:
         return {}
     pos = _rotary_positions(c, S)
-    kinds = sorted(set(c.layer_types) - {scopes.KDA}) if c.layer_types else (None,)
+    kinds = sorted(set(c.layer_types) - _NO_ATTENTION) if c.layer_types else (None,)
     return {
         kind: rotary_ops.table(pos, c.head_dim, c.rotary(kind).theta, c.rotary(kind).scaling,
                                c.rotary(kind).rotary_dim)
@@ -1477,7 +1647,7 @@ def qk_prologue_stats(config: TinyGPTConfig, seq_len: int) -> Dict[str, Any]:
     head = c.qk_norm == "head"
     kinds = c.layer_types or (None,) * c.n_layer
     by_kind = {}
-    for kind in sorted(set(kinds) - {scopes.KDA}, key=str):
+    for kind in sorted(set(kinds) - _NO_ATTENTION, key=str):
         layers = kinds.count(kind) if c.pos_embed == "rope" else 0
         taken = (layers > 0 and _takes_qk_prologue(c, S, kind)
                  and rotary_ops.kernel_mode() is not None)
@@ -1726,6 +1896,83 @@ def kda_stats(config: TinyGPTConfig, seq_len: int) -> Dict[str, Any]:
     }
 
 
+def _ssd_sublayer(c: TinyGPTConfig, x: jax.Array, layer: Params) -> jax.Array:
+    """Norm -> Mamba-2 mixer -> residual: an ``ssd`` block, in three scopes.
+    ``ssd_prep``: [z | xBC | dt] = h W_in as three products of the weight's
+    column blocks (slices of the weight, not of a (B, S, 10304) result), xBC
+    = silu(conv(xBC) + bias) (``ops.kda.conv_silu``: on a TPU the convolution,
+    its bias and SiLU are one Mosaic call over the 6144 columns,
+    ``kda_conv_fwd``, and one back; elsewhere XLA's convolution), dt =
+    softplus(dt + dt_bias) and the log-decay g = -exp(A_log) dt a head, both
+    float32 (no clamp beyond softplus). ``ssd_core``: the scan (``ops/ssd.py``:
+    the Mosaic kernels on a TPU where ``ops.ssd.fits``, its ``jnp`` path
+    elsewhere) over xBC as it stands: x's, B's and C's columns are found by
+    the kernels' block specs. ``ssd_out``: the skip D x, the gate u = y
+    silu(z), the RMS over each group's d_inner / ssd_groups channels times
+    the (d_inner,) scale (gate first, then the norm), and W_out."""
+    from ..ops import kda as kda_ops
+    from ..ops import ssd as ssd_ops
+
+    cd = c.compute_dtype
+    H, P, groups = c.ssd_heads, c.ssd_head_dim, c.ssd_groups
+    inner, W = c.ssd_inner, c.ssd_xbc
+    proj = functools.partial(jnp.einsum, preferred_element_type=jnp.float32)
+    h = _norm(c, x, layer["ln1_scale"], layer.get("ln1_bias"))
+    with jax.named_scope(scopes.SSD_PREP):
+        win = layer["ssd_win"].astype(cd)
+        z = proj("bsd,de->bse", h, win[:, :inner]).astype(cd)
+        xbc = checkpoint_name(proj("bsd,de->bse", h, win[:, inner:inner + W]).astype(cd), SSD_XBC)
+        dt = proj("bsd,dh->bsh", h, win[:, inner + W:])  # float32
+        xbc = kda_ops.conv_silu(xbc, layer["ssd_conv"], layer["ssd_conv_bias"],
+                                interpret=kda_ops.kernel_mode())
+        dt = jax.nn.softplus(dt + layer["ssd_dt_bias"].astype(jnp.float32))
+        g = -jnp.exp(layer["ssd_a_log"].astype(jnp.float32)) * dt
+    with jax.named_scope(scopes.SSD_CORE):
+        fits = ssd_ops.fits(P, c.ssd_state, H, groups)
+        y = ssd_ops.ssd_flat(xbc, dt, g, H, groups, P, c.ssd_chunk,
+                             interpret=ssd_ops.kernel_mode() if fits else None)
+    with jax.named_scope(scopes.SSD_OUT):
+        # every per-channel operand stays (B, S, d_inner): see _kda_sublayer
+        skip = jnp.repeat(layer["ssd_d"].astype(jnp.float32), P)
+        u = y.astype(jnp.float32) + skip * xbc[..., :inner].astype(jnp.float32)
+        u = u * jax.nn.silu(z.astype(jnp.float32))
+        u = u * kda_ops.over_heads(
+            lax.rsqrt(kda_ops.head_sums(u * u, groups) / (inner // groups) + c.norm_eps),
+            inner // groups)
+        u = (u * layer["ssd_norm"].astype(jnp.float32)).astype(cd)
+        return x + proj("bse,ed->bsd", u, layer["wo"].astype(cd)).astype(cd)
+
+
+def ssd_stats(config: TinyGPTConfig, seq_len: int) -> Dict[str, Any]:
+    """Counters of the ``ssd`` layers over sequences of ``seq_len`` tokens,
+    from the config and the backend at trace time: ``layers`` of the kind,
+    ``chunk`` and ``chunks`` a sequence, ``chunk_steps`` the grid steps one
+    kernel call makes a sequence (a chunk of a group each), ``kernel_calls`` a
+    step by name (one forward and one backward a layer where the kernels run:
+    none on the ``jnp`` path; remat's second forward is the policy's, not
+    counted), ``conv_kernel_calls`` the same of the convolution's two, and
+    ``saved_state_bytes`` a layer a sequence: the states entering the chunks,
+    which the forward keeps for the backward beside its operands."""
+    from ..ops import kda as kda_ops
+    from ..ops import ssd as ssd_ops
+
+    c = config
+    layers = (c.layer_types or ()).count(scopes.SSD)
+    chunks = seq_len // c.ssd_chunk if layers else 0
+    on = layers > 0 and ssd_ops.kernel_mode() is not None
+    kernels = layers if on and ssd_ops.fits(
+        c.ssd_head_dim, c.ssd_state, c.ssd_heads, c.ssd_groups) else 0
+    convs = layers if on and kda_ops.conv_fits(seq_len, c.ssd_conv, c.ssd_xbc) else 0
+    return {
+        "layers": layers, "chunk": c.ssd_chunk, "chunks": chunks,
+        "chunk_steps": chunks * c.ssd_groups,
+        "kernel_calls": {"ssd_fwd": kernels, "ssd_bwd": kernels},
+        "conv_kernel_calls": {"kda_conv_fwd": convs, "kda_conv_bwd": convs},
+        "saved_state_bytes": (chunks * c.ssd_inner * c.ssd_state
+                              * jnp.dtype(c.compute_dtype).itemsize),
+    }
+
+
 def _pin_mlp_hidden(c: TinyGPTConfig, h: jax.Array) -> jax.Array:
     """Pin an F-wide MLP intermediate, (B, S, F) or (B, S, 2, F), to
     ``config.mlp_hidden_spec``; an unset spec is an exact no-op."""
@@ -1816,9 +2063,10 @@ def embed(
         shard = lax.axis_index(c.seq_manual_axis)
         if dropout_key is not None:
             dropout_key = jax.random.fold_in(dropout_key, shard)
-    if c.pos_embed == "rope":
+    if c.pos_embed != "learned":
         # Rotary positions are applied to q/k inside each block (_rope in
-        # _block); the residual stream carries no additive position signal.
+        # _block), or none at all ('none'); the residual stream carries no
+        # additive position signal.
         x = tok.astype(c.compute_dtype)
     else:
         if c.seq_manual_axis is not None:
@@ -1931,7 +2179,7 @@ def attn_mask_stats(config: TinyGPTConfig, seq_len: int) -> Dict[str, Dict[str, 
 
     kinds = config.layer_types or (scopes.GLOBAL,) * config.n_layer
     stats = {}
-    for kind in sorted(set(kinds) - {scopes.KDA}):  # a kda layer has no mask: kda_stats
+    for kind in sorted(set(kinds) - _NO_ATTENTION):  # no mask there: kda_stats, ssd_stats
         rule = config.mask_rule(seq_len, kind if config.layer_types else None)
         bq, bk, bk_bwd, _ = fa.pick_tiles(
             seq_len, config.qk_dim, config.compute_dtype, causal=rule)
@@ -2064,9 +2312,11 @@ def remat_kept_names() -> Tuple[str, ...]:
     remat: one list for every layer kind (``_under_remat`` has the rule)."""
     from ..ops.flash_attention import FLASH_RESIDUAL_NAMES
     from ..ops.kda import KDA_RESIDUAL_NAMES
+    from ..ops.ssd import SSD_RESIDUAL_NAMES
     from .moe import MOE_RESIDUAL_NAMES
 
-    return (*FLASH_RESIDUAL_NAMES, *KDA_RESIDUAL_NAMES, *MOE_RESIDUAL_NAMES, *MATMUL_CAST_NAMES)
+    return (*FLASH_RESIDUAL_NAMES, *KDA_RESIDUAL_NAMES, *SSD_RESIDUAL_NAMES,
+            *MOE_RESIDUAL_NAMES, *MATMUL_CAST_NAMES)
 
 
 def _under_remat(pol: str, block):
@@ -2081,7 +2331,8 @@ def _under_remat(pol: str, block):
     cheap elementwise chains. The list: the mixer's forward kernel's results (no
     ``dot_general``: the flash kernel's output and row sums,
     ``FLASH_RESIDUAL_NAMES``; a ``kda`` layer's output and the states entering
-    its chunks, ``KDA_RESIDUAL_NAMES``), the routed experts' gate+up grouped
+    its chunks, ``KDA_RESIDUAL_NAMES``; an ``ssd`` layer's the same,
+    ``SSD_RESIDUAL_NAMES``), the routed experts' gate+up (or up) grouped
     matmul's result (a Mosaic call too), the router's ``HIGHEST``-precision
     logits, its choice and the plan that moves rows (sorts; ``moe.
     MOE_RESIDUAL_NAMES``), a ``kda`` layer's q, k, v projection after its cast
@@ -2220,13 +2471,19 @@ def apply_layer(config: TinyGPTConfig, layer: Params, x: jax.Array, kind: Option
     mixer = _under_remat(pol, lambda x, layer, key, tables: _mixer_half(
         c, x, layer, key, deterministic, kind, tables))
     mlp = _under_remat(pol, lambda x, layer, key: _mlp_half(c, x, layer, key, deterministic))
-    return mlp(mixer(x, layer, keys[0], qk_tables), layer, keys[1])
+    has_mixer, has_mlp = c.halves(kind)  # under block_halves a block is one of the two
+    if has_mixer:
+        x = mixer(x, layer, keys[0], qk_tables)
+    if not has_mlp:
+        return x, jnp.zeros(c.aux_shape, jnp.float32)
+    return mlp(x, layer, keys[1])
 
 
 def _apply_stacks(c, params, x, base_key, deterministic, qk_tables):
     """The whole depth of a config whose stacks have unequal leaves (``kda``
-    layers beside others, attention kinds of unequal head counts): unrolled, the layers in the published order, each
-    from its own stack (``apply_layer``) -> (x, aux_sum)."""
+    or ``ssd`` layers beside others, attention kinds of unequal head counts,
+    blocks that are one half alone): unrolled, the layers in the published
+    order, each from its own stack (``apply_layer``) -> (x, aux_sum)."""
     live = base_key is not None and not deterministic
     aux = jnp.zeros(c.aux_shape, jnp.float32)
     for i, kind in enumerate(c.layer_types):
@@ -2340,10 +2597,17 @@ def _walk_routers(config: TinyGPTConfig, params: Params, idx: jax.Array, read):
     for i in range(c.n_layer):
         layer = layer_weights(c, params, i)
         kind = None if c.layer_types is None else c.layer_types[i]
-        if kind == scopes.KDA:
+        has_mixer, has_mlp = c.halves(kind)
+        if not has_mixer:
+            pass
+        elif kind == scopes.KDA:
             x = _kda_sublayer(c, x, layer)
+        elif kind == scopes.SSD:
+            x = _ssd_sublayer(c, x, layer)
         else:
             x = _attention_sublayer(c, x, layer, None, True, kind)
+        if not has_mlp:
+            continue
         if "router" in layer:
             found.append(read(c, layer, _norm(c, x, layer["ln2_scale"], layer.get("ln2_bias"))))
         x, _ = _mlp_sublayer(c, x, layer, None, True)

@@ -599,10 +599,11 @@ def _pass_rows(rows, norm):
     return rows if norm else math.gcd(rows, 128)
 
 
-def _conv_fwd_kernel(K, head_dim, norm, x, tail, taps, y, ext):
+def _conv_fwd_kernel(K, head_dim, norm, bias, x, tail, taps, y, ext):
     """A (rows, columns) tile of y_t = sum_i taps_i x_{t-K+1+i}: the tile
     behind its 8 preceding rows (zeros before the sequence) in ``ext``, read
-    back a head's lanes at a time. With ``head_dim`` the epilogue on the
+    back a head's lanes at a time. Under ``bias`` the row behind the K taps is
+    a bias a column, added to y. With ``head_dim`` the epilogue on the
     float32 slab: a = silu(y), and under ``norm`` the head's a (sum of a^2
     over its lanes + eps)^-1/2; one cast, one write."""
     rows, columns = x.shape
@@ -614,6 +615,8 @@ def _conv_fwd_kernel(K, head_dim, norm, x, tail, taps, y, ext):
         def rows_from(i, _):
             start = pl.multiple_of(i * n, n)
             _, a = _window(ext, taps, K, start, n, lanes)
+            if bias:
+                a = a + taps[K:K + 1, lanes]
             if head_dim is not None:
                 a = a * _sigmoid(a)
                 if norm:
@@ -625,7 +628,7 @@ def _conv_fwd_kernel(K, head_dim, norm, x, tail, taps, y, ext):
     _over_slabs(columns, head_dim, slab)
 
 
-def _conv_bwd_kernel(K, head_dim, norm, *refs):
+def _conv_bwd_kernel(K, head_dim, norm, bias, *refs):
     """The transposes over a tile: dx_t = sum_i taps_i dy_{t+K-1-i} (the tile
     before its 8 following rows, zeros after the sequence), and dtaps_i = sum
     over the positions of x_{t-K+1+i} dy_t, summed into a block that stays
@@ -635,7 +638,9 @@ def _conv_bwd_kernel(K, head_dim, norm, *refs):
     epilogue's output's, and dy is made of it here, on the tile's rows and the
     8 after them: y, the sigmoid s and a head's inverse norm r are computed
     again from x (its 8 rows after the tile come in too), da = r (dn - a r^2
-    sum_head(dn a)) under ``norm`` (dn otherwise), dy = da (s + a (1 - s))."""
+    sum_head(dn a)) under ``norm`` (dn otherwise), dy = da (s + a (1 - s)).
+    Under ``bias`` y has the row behind the taps added, and that row of dtaps
+    takes the sum of dy over the positions."""
     if head_dim is None:
         (x, tail, taps, dy, head), (dx, dtaps, ext) = refs[:5], refs[-3:]
     else:
@@ -660,6 +665,8 @@ def _conv_bwd_kernel(K, head_dim, norm, *refs):
             if head_dim is None:
                 return ext[pl.ds(start + 8, count), lanes], d
             x, y = _window(ext, taps, K, start, count, lanes)
+            if bias:
+                y = y + taps[K:K + 1, lanes]
             s = _sigmoid(y)
             a = y * s
             if norm:
@@ -680,6 +687,8 @@ def _conv_bwd_kernel(K, head_dim, norm, *refs):
             # x_u dy_{u+K-1-j} over the tile's u: over all tiles, every pair of dtaps_j once
             for j in range(K):
                 dtaps[j:j + 1, lanes] += jnp.sum(x * later[j], axis=0, keepdims=True)
+            if bias:
+                dtaps[K:K + 1, lanes] += jnp.sum(d, axis=0, keepdims=True)
             return d[0:8]
 
         after_d = jnp.where(last, 0.0, head[:, lanes].astype(jnp.float32))
@@ -691,7 +700,8 @@ def _conv_bwd_kernel(K, head_dim, norm, *refs):
 
 
 @functools.lru_cache(maxsize=64)
-def _conv_call(backward, B, S, width, part, parts, K, dtype, head_dim, norm, interpret):
+def _conv_call(backward, B, S, width, part, parts, K, dtype, head_dim, norm, interpret,
+               bias=False):
     """One direction's ``pallas_call`` over ``width`` columns from column
     ``part`` x ``width`` of an x of ``parts`` x ``width``, made once a process
     (as ``flash_attention._forward_call``, and for its reason). A grid of
@@ -700,19 +710,20 @@ def _conv_call(backward, B, S, width, part, parts, K, dtype, head_dim, norm, int
     at the sequence's ends, where the kernels put zeros). Operands: forward
     (x, x, taps) -> y (B, S, width); backward (x, x, taps, [x,] dy, dy[, dx so
     far]) -> (dx (B, S, parts x width), of which this call writes its part's
-    columns and keeps the rest of ``dx so far``, aliased; dtaps (K, width))."""
+    columns and keeps the rest of ``dx so far``, aliased; dtaps (K, width)).
+    Under ``bias`` taps and dtaps have one more row, the bias a column."""
     rows, columns = _conv_tile(S, width, head_dim)
     offset = part * (width // columns)
     tile = lambda at: pl.BlockSpec((None, rows, columns), lambda c, b, n: (b, n, c + at))
     eight = lambda row, at: pl.BlockSpec((None, 8, columns), lambda c, b, n: (b, row(n), c + at))
     before = lambda n: jnp.maximum(n * (rows // 8) - 1, 0)
     after = lambda n: jnp.minimum((n + 1) * (rows // 8), S // 8 - 1)
-    taps = lambda at: pl.BlockSpec((K, columns), lambda c, b, n: (0, c + at))
+    taps = lambda at: pl.BlockSpec((K + bias, columns), lambda c, b, n: (0, c + at))
     grid = (width // columns, B, S // rows)
     fused = head_dim is not None
     if not backward:
         return pl.pallas_call(
-            functools.partial(_conv_fwd_kernel, K, head_dim, norm), grid=grid,
+            functools.partial(_conv_fwd_kernel, K, head_dim, norm, bias), grid=grid,
             in_specs=[tile(offset), eight(before, offset), taps(offset)], out_specs=tile(0),
             out_shape=jax.ShapeDtypeStruct((B, S, width), dtype),
             scratch_shapes=[pltpu.VMEM((rows + 8, columns), jnp.float32)],
@@ -724,10 +735,10 @@ def _conv_call(backward, B, S, width, part, parts, K, dtype, head_dim, norm, int
         [eight(after, offset)] if fused else []) + [tile(0), eight(after, 0)] + (
         [pl.BlockSpec(memory_space=pl.ANY)] if part else [])
     return pl.pallas_call(
-        functools.partial(_conv_bwd_kernel, K, head_dim, norm), grid=grid,
+        functools.partial(_conv_bwd_kernel, K, head_dim, norm, bias), grid=grid,
         in_specs=in_specs, out_specs=[tile(offset), taps(0)],
         out_shape=[jax.ShapeDtypeStruct((B, S, parts * width), dtype),
-                   jax.ShapeDtypeStruct((K, width), jnp.float32)],
+                   jax.ShapeDtypeStruct((K + bias, width), jnp.float32)],
         scratch_shapes=[pltpu.VMEM((rows + 8 + 8 * fused, columns), jnp.float32)],
         input_output_aliases={len(in_specs) - 1: 0} if part else {},
         interpret=interpret, name="kda_conv_bwd",
@@ -736,39 +747,41 @@ def _conv_call(backward, B, S, width, part, parts, K, dtype, head_dim, norm, int
     )
 
 
-def _conv_parts(head_dim):
+def _conv_parts(head_dim, bias=False):
     """The calls a direction over x's columns, as each one's ``norm``: one
-    over all of them for the bare convolution; q's, k's and v's thirds apart
-    under the epilogue, which normalises the first two."""
-    return (False,) if head_dim is None else (True, True, False)
+    over all of them for the bare convolution and for the one with a bias
+    (``conv_silu``: SiLU behind it, no norm); q's, k's and v's thirds apart
+    under the KDA epilogue, which normalises the first two."""
+    return (False,) if head_dim is None or bias else (True, True, False)
 
 
-def _conv_run(backward, part, head_dim, interpret, x, taps, *cotangent):
-    norms = _conv_parts(head_dim)
-    (B, S, C), K = x.shape, taps.shape[0]
+def _conv_run(backward, part, head_dim, interpret, x, taps, *cotangent, bias=False):
+    norms = _conv_parts(head_dim, bias)
+    (B, S, C), K = x.shape, taps.shape[0] - bias
     call = _conv_call(backward, B, S, C // len(norms), part, len(norms), K, x.dtype,
-                      head_dim, norms[part], interpret)
+                      head_dim, norms[part], interpret, bias)
     # one trace for the primal and the forward rule: see flash_attention._flash_forward
     with jax.sharding.use_abstract_mesh(jax.sharding.get_abstract_mesh()):
         return call(x, x, taps, *cotangent)
 
 
-def _conv_forward(x, taps, head_dim, interpret):
-    return [_conv_run(False, part, head_dim, interpret, x, taps)
-            for part in range(len(_conv_parts(head_dim)))]
+def _conv_forward(x, taps, head_dim, interpret, bias):
+    return [_conv_run(False, part, head_dim, interpret, x, taps, bias=bias)
+            for part in range(len(_conv_parts(head_dim, bias)))]
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3))
-def _conv(x, taps, head_dim, interpret):
-    """x (B, S, C), taps (K, C) float32 -> [a part's (B, S, C / parts)]."""
-    return _conv_forward(x, taps, head_dim, interpret)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3, 4))
+def _conv(x, taps, head_dim, interpret, bias=False):
+    """x (B, S, C), taps (K, C) float32 (under ``bias`` (K + 1, C): the bias a
+    column behind the taps) -> [a part's (B, S, C / parts)]."""
+    return _conv_forward(x, taps, head_dim, interpret, bias)
 
 
-def _conv_fwd(x, taps, head_dim, interpret):
-    return _conv_forward(x, taps, head_dim, interpret), (x, taps)
+def _conv_fwd(x, taps, head_dim, interpret, bias=False):
+    return _conv_forward(x, taps, head_dim, interpret, bias), (x, taps)
 
 
-def _conv_bwd(head_dim, interpret, residuals, cotangents):
+def _conv_bwd(head_dim, interpret, bias, residuals, cotangents):
     """A call a part: each writes its columns of the one dx, which the next
     takes aliased, so that neither the cotangents nor the parts of dx are
     ever set side by side in a copy."""
@@ -777,7 +790,7 @@ def _conv_bwd(head_dim, interpret, residuals, cotangents):
     so_far, dtaps = (), []
     for part, dy in enumerate(cotangents):
         dx, dtaps_part = _conv_run(
-            True, part, head_dim, interpret, x, taps, *after, dy, dy, *so_far)
+            True, part, head_dim, interpret, x, taps, *after, dy, dy, *so_far, bias=bias)
         so_far = (dx,)
         dtaps.append(dtaps_part)
     return dx, jnp.concatenate(dtaps, axis=-1)
@@ -813,7 +826,26 @@ def causal_conv(x, taps, *, interpret: Optional[bool] = None):
     --prep``, whose baseline is this and the ``jnp`` chain behind it."""
     if interpret is None or not conv_fits(x.shape[1], taps.shape[0], x.shape[2]):
         return _conv_reference(x, taps)
-    return _conv(x, taps.astype(jnp.float32), None, interpret)[0]
+    return _conv(x, taps.astype(jnp.float32), None, interpret, False)[0]
+
+
+def conv_silu(x, taps, bias, *, interpret: Optional[bool] = None):
+    """silu(the depthwise causal convolution of x + a bias a column): what
+    stands between a Mamba-2 mixer's projection and its scan (``ops/ssd.py``).
+    x (B, S, C) in the compute type, taps (K, C), bias (C,) -> (B, S, C) in
+    x's dtype. ``interpret`` False (True: interpreted) where ``conv_fits`` at
+    128 lanes: ``causal_conv``'s two Mosaic calls with the bias as the row
+    behind the taps and SiLU as their epilogue on the float32 slab, one call
+    over all the columns each way (``kda_conv_fwd`` / ``kda_conv_bwd``: the
+    backward computes y and the sigmoid again from x and gives the bias's
+    gradient in the taps' last row). None, or an operand the kernels do not
+    take: XLA's grouped convolution and ``jax.nn.silu`` in float32, which is
+    also what the tests hold the kernels to."""
+    if interpret is None or not conv_fits(x.shape[1], taps.shape[0], x.shape[2]):
+        y = _conv_reference(x.astype(jnp.float32), taps.astype(jnp.float32))
+        return jax.nn.silu(y + bias.astype(jnp.float32)).astype(x.dtype)
+    both = jnp.concatenate([taps.astype(jnp.float32), bias.astype(jnp.float32)[None]], axis=0)
+    return _conv(x, both, 128, interpret, True)[0]
 
 
 def qkv_prologue(x, taps, heads: int, *, interpret: Optional[bool] = None):
@@ -837,4 +869,4 @@ def qkv_prologue(x, taps, heads: int, *, interpret: Optional[bool] = None):
     head_dim = x.shape[-1] // (3 * heads)
     if interpret is None or not conv_fits(x.shape[1], taps.shape[0], head_dim):
         return silu_l2norm(_conv_reference(x, taps), heads)
-    return tuple(_conv(x, taps.astype(jnp.float32), head_dim, interpret))
+    return tuple(_conv(x, taps.astype(jnp.float32), head_dim, interpret, False))

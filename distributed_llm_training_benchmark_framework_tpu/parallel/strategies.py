@@ -422,6 +422,7 @@ _EP_RULES = {
     "blocks/moe_w2": 1,
     "blocks/moe_b2": 1,
     "blocks/moe_wgu": 1,
+    "blocks/moe_wu": 1,  # experts that are not gated: the up projection alone
     "blocks/moe_wd": 1,
 }
 
@@ -429,10 +430,13 @@ _EP_RULES = {
 def _leaf_name(path) -> str:
     """'blocks/<leaf>' for a leaf of any stack of layers (models/tinygpt.py
     ``_STACK_NAMES``: 'blocks', the leading dense 'dense_blocks', the KDA
-    layers' 'kda_blocks' / 'kda_dense_blocks'): a leaf of one name has the same
-    shape but for its widths and the same role in every stack, and takes the
-    same rules. A KDA mixer's own leaves ('kda_*') have no tensor-parallel
-    rule: under a 'model' axis they stay whole."""
+    layers' 'kda_blocks' / 'kda_dense_blocks', the stacks by kind of
+    ``layer_heads`` and of ``block_halves``: 'global_blocks', 'ssd_blocks',
+    'mlp_blocks'): a leaf of one name has the same shape but for its widths
+    and the same role in every stack, and takes the same rules. A KDA or SSD
+    mixer's own leaves ('kda_*', 'ssd_*') and the relu2 experts' up projections
+    ('moe_wu', 'shared_wu') have no tensor-parallel rule: under a 'model' axis
+    they stay whole."""
     name = "/".join(str(getattr(p, "key", p)) for p in path)
     stack, _, leaf = name.partition("/")
     return f"blocks/{leaf}" if leaf and stack.endswith("blocks") else name

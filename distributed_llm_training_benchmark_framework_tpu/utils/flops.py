@@ -78,6 +78,8 @@ def forward_flops_per_token(config) -> float:
             getattr(config, "block_diffusion", None) is not None
             or getattr(config, "head_width", None) is not None
             or getattr(config, "layer_types", None) is not None):
+        if getattr(config, "block_halves", False):
+            return _halves_forward_flops_per_token(config)
         return _deepseek_forward_flops_per_token(config)
     D, L, V, S = config.n_embd, config.n_layer, config.vocab_size, config.block_size
     H = config.n_head
@@ -166,13 +168,16 @@ def _mlp_forward_flops_per_token(c) -> float:
     """The MLPs of the whole depth a token: the leading dense layers', and the
     routed layers' by their active parameters on this chip."""
     D, F = c.n_embd, c.mlp_dim
+    matrices = 6 if c.mlp_act == "swiglu" else 4  # gated: three matrices; gelu, relu2: two
     if c.n_experts > 0:
         routed_rows = c.expert_top_k * c.n_experts_held / c.n_experts
-        mlp = 2 * D * c.n_experts + 6 * D * F * (routed_rows + c.n_shared_experts)
+        shared = getattr(c, "shared_dim", c.n_shared_experts * F)
+        mlp = 2 * D * c.n_experts + matrices * D * (F * routed_rows + shared)
     else:
-        mlp = (6 if c.mlp_act == "swiglu" else 4) * D * F
+        mlp = matrices * D * F
     dense = 6 * D * (c.dense_mlp_hidden or 0)
-    return c.first_k_dense * dense + (c.n_layer - c.first_k_dense) * mlp
+    layers = getattr(c, "n_mlp_layers", c.n_layer)  # under block_halves the 'mlp' blocks
+    return c.first_k_dense * dense + (layers - c.first_k_dense) * mlp
 
 
 def _by_kind_forward_flops_per_token(c, attn_tokens: float, copies: int) -> float:
@@ -190,6 +195,38 @@ def _by_kind_forward_flops_per_token(c, attn_tokens: float, copies: int) -> floa
         attention += copies * (2 * D * (H + 2 * c.kv_heads) * Dh + 2 * H * Dh * D
                                + (2 * D * H if c.attn_gate else 0)) + 4 * tokens * H * Dh
     return float(attention + copies * _mlp_forward_flops_per_token(c) + 2 * D * c.vocab_size)
+
+
+def _halves_forward_flops_per_token(c) -> float:
+    """A stack whose blocks are one sublayer alone (``block_halves``): every
+    mixer block by its kind (an ``ssd`` block by ``ssd_forward_flops_per_token``,
+    an attention block's projections and its scores over causal's S / 2 keys a
+    token, or a window's true pairs), the ``mlp`` blocks by
+    ``_mlp_forward_flops_per_token``, and the head."""
+    D, Dh, S = c.n_embd, c.head_dim, c.block_size
+    mixers = 0.0
+    for kind in c.layer_types:
+        if kind == "ssd":
+            mixers += ssd_forward_flops_per_token(c)
+        elif kind != "mlp":
+            H = c.heads(kind)
+            tokens = _window_tokens(c) if kind == "window" else S / 2
+            mixers += 2 * D * (H + 2 * c.kv_heads) * Dh + 2 * H * Dh * D + 4 * tokens * H * Dh
+    return float(mixers + _mlp_forward_flops_per_token(c) + 2 * D * c.vocab_size)
+
+
+def ssd_forward_flops_per_token(c) -> float:
+    """One ``ssd`` block's mixer, a token: in_proj ([z | x B C | dt]), the
+    convolution's taps, out_proj, and the scan counted as the chunkwise form's
+    work at the config's chunk C, with P = ssd_head_dim and N = ssd_state: C
+    B^T once a group (2 C N), and a head's (L o C B^T)(dt x) (2 C P), C S_0^T
+    and the state's update (2 N P each). What a kernel multiplies beyond that
+    (a slab's masked half) is its choice."""
+    D, H, P, N, C = c.n_embd, c.ssd_heads, c.ssd_head_dim, c.ssd_state, c.ssd_chunk
+    projections = 2 * D * (c.ssd_inner + c.ssd_xbc + H) + 2 * c.ssd_inner * D
+    convolution = 2 * c.ssd_conv * c.ssd_xbc
+    scan = H * (2 * C * P + 4 * N * P) + c.ssd_groups * 2 * C * N
+    return float(projections + convolution + scan)
 
 
 def kda_forward_flops_per_token(c) -> float:

@@ -185,6 +185,15 @@ def estimate_hbm(
         if pol == "none":
             kept += S * width * (3 * cbytes + 4) + S * cfg.kda_heads * 4
         kda_b = kda_layers * B * kept
+    # An SSD (Mamba-2) layer's scan likewise: the states entering its chunks and
+    # its output by name; without remat also x | B | C before and after the
+    # convolution, z, and dt and the sums of the log-decay in float32.
+    ssd_layers = (getattr(cfg, "layer_types", None) or ()).count("ssd")
+    if ssd_layers and pol != "full":
+        kept = tinygpt.ssd_stats(cfg, S)["saved_state_bytes"] + S * cfg.ssd_inner * cbytes
+        if pol == "none":
+            kept += S * ((2 * cfg.ssd_xbc + cfg.ssd_inner) * cbytes + 2 * cfg.ssd_heads * 4)
+        kda_b += ssd_layers * B * kept
     # What 'dots' and 'full_keep_kernels' keep by name beside the mixer
     # kernels' results (tinygpt._under_remat has the list): a routed layer's
     # gate+up over the rows its experts take, its router's float32 logits
@@ -201,7 +210,7 @@ def estimate_hbm(
 
             rows = (tokens * cfg.expert_top_k if cfg.experts_held is None
                     else held_buffer_rows(cfg, tokens))
-            named_b += moe_layers * rows * 2 * F * cbytes
+            named_b += moe_layers * rows * (2 if cfg.mlp_act == "swiglu" else 1) * F * cbytes
             if cfg.trains_routing:
                 named_b += moe_layers * tokens * cfg.n_experts * 4
         if pol == "full_keep_kernels":

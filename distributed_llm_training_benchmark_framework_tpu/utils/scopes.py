@@ -46,15 +46,24 @@ MLA_PROJ, MLA_CORE, MLA_OUT = MLA_SCOPES = ("mla_proj", "mla_core", "mla_out")
 # Inside ``attention``, where the stack mixes kinds of layer
 # (``TinyGPTConfig.layer_types``): a layer's whole mixer sublayer under its
 # kind's name, a sliding-window layer, a global one (softmax attention over
-# every earlier position, latent attention too) or a KDA one (the gated
-# delta-rule recurrence, ``ops/kda.py``).
-WINDOW, GLOBAL, KDA = LAYER_KIND_SCOPES = ("window", "global", "kda")
+# every earlier position, latent attention too), a KDA one (the gated
+# delta-rule recurrence, ``ops/kda.py``) or an SSD one (a Mamba-2 mixer: the
+# scalar-decay state-space scan, ``ops/ssd.py``).
+WINDOW, GLOBAL, KDA, SSD = LAYER_KIND_SCOPES = ("window", "global", "kda", "ssd")
 
 # Inside ``attention`` / ``kda`` (``models/tinygpt.py::_kda_sublayer``): the
 # projections with their convolutions, SiLU, l2norm, the decay and beta; the
 # recurrence itself (the Mosaic calls ``kda_fwd`` / ``kda_bwd``); the head
 # norm, the gate and the output projection.
 KDA_PREP, KDA_CORE, KDA_OUT = KDA_SCOPES = ("kda_prep", "kda_core", "kda_out")
+
+# Inside ``attention`` / ``ssd`` (``models/tinygpt.py::_ssd_sublayer``): what
+# stands before the scan (``in_proj``'s three products, the convolution with
+# its bias and SiLU, the Mosaic calls ``kda_conv_fwd`` / ``kda_conv_bwd``, and
+# dt's softplus with the log-decay); the scan itself (``ssd_fwd`` / ``ssd_bwd``
+# and the running sums in front of them); the skip, the gated grouped norm and
+# ``out_proj``.
+SSD_PREP, SSD_CORE, SSD_OUT = SSD_SCOPES = ("ssd_prep", "ssd_core", "ssd_out")
 
 # Inside ``attention`` (below the kind's scope where there is one), where a
 # layer's QK-norm and rotary are ``ops/rotary.py``'s one pass: the two Mosaic
@@ -75,6 +84,7 @@ NOISE = "noise"
 # Every name above: what a scope path read back from a name stack is held to
 # (``utils/residuals.py::scope_path``).
 NAMES = frozenset((*SCOPES, *MOE_SCOPES, SHARED, *MLA_SCOPES, *LAYER_KIND_SCOPES, *KDA_SCOPES,
+                   *SSD_SCOPES,
                    QK_PROLOGUE, ATTN_GATE, NOISE))
 
 

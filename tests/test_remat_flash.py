@@ -39,6 +39,7 @@ from distributed_llm_training_benchmark_framework_tpu.ops.flash_attention import
     FLASH_RESIDUAL_NAMES,
 )
 from distributed_llm_training_benchmark_framework_tpu.ops.kda import KDA_RESIDUAL_NAMES
+from distributed_llm_training_benchmark_framework_tpu.ops.ssd import SSD_RESIDUAL_NAMES
 from distributed_llm_training_benchmark_framework_tpu.parallel import (
     get_strategy,
     make_mesh,
@@ -226,7 +227,7 @@ def test_the_list_is_one_and_names_what_the_rule_allows():
                tinygpt.MLP_GU: 11.1}  # kimi: 6.73 ms for 0.604 GB
     names = tinygpt.remat_kept_names()
     assert len(set(names)) == len(names)
-    assert set(names) == {*FLASH_RESIDUAL_NAMES, *KDA_RESIDUAL_NAMES, *ms_a_gb}
+    assert set(names) == {*FLASH_RESIDUAL_NAMES, *KDA_RESIDUAL_NAMES, *SSD_RESIDUAL_NAMES, *ms_a_gb}
     assert min(ms_a_gb.values()) >= 5.0
 
 

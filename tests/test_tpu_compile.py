@@ -749,3 +749,77 @@ def test_the_kimi_cells_stack_compiles_a_layer_of_each_kind(v5e_devices, monkeyp
                  "flash_bwd_fused", "jit(gmm)", "jit(tgmm)"):
         assert name in text, name
     assert text.count("kda_fwd") >= 1 and "attention/kda/kda_core" in text
+
+
+def test_the_ssd_kernels_compile_at_the_cells_operand(v5e_devices):
+    """``ops/ssd.py`` at the Nemotron cell's operand (one sequence of 16,384
+    positions, 64 heads of 64 channels in 8 groups over a state of 128, x | B |
+    C as one array of 6144 columns): ``ssd_fwd`` and ``ssd_bwd``, whose body is
+    ``jax.vjp`` of the slab's own body traced into the kernel, a group of four
+    128-lane slabs a grid step; the backward's three gradients joined once."""
+    from distributed_llm_training_benchmark_framework_tpu.ops import ssd
+
+    one = SingleDeviceSharding(v5e_devices[0])
+    aval = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+
+    def loss(xbc, dt, g):
+        out = ssd.ssd_flat(xbc, dt, g, 64, 8, 64, 128, interpret=False)
+        return jnp.sum(jnp.square(out.astype(jnp.float32)))
+
+    text = _compile(jax.grad(loss, argnums=(0, 1, 2)), aval((1, 16384, 6144), jnp.bfloat16),
+                    aval((1, 16384, 64), jnp.float32), aval((1, 16384, 64), jnp.float32))
+    assert text.count('custom_call_target="tpu_custom_call"') == 2
+    assert "ssd_fwd" in text and "ssd_bwd" in text
+
+
+def test_the_convolution_with_bias_and_silu_compiles_at_the_cells_operand(v5e_devices):
+    """``ops.kda.conv_silu`` over the 6144 columns of a Mamba-2 block's x | B |
+    C: the convolution's two kernels with the bias as the row behind the taps
+    and SiLU as their epilogue, one call each way."""
+    from distributed_llm_training_benchmark_framework_tpu.ops import kda
+
+    one = SingleDeviceSharding(v5e_devices[0])
+    aval = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+
+    def loss(x, taps, bias):
+        return jnp.sum(jnp.square(kda.conv_silu(x, taps, bias, interpret=False).astype(jnp.float32)))
+
+    text = _compile(jax.grad(loss, argnums=(0, 1, 2)), aval((1, 16384, 6144), jnp.bfloat16),
+                    aval((4, 6144), jnp.float32), aval((6144,), jnp.float32))
+    assert text.count('custom_call_target="tpu_custom_call"') == 2
+    assert "kda_conv_fwd" in text and "kda_conv_bwd" in text
+
+
+def test_the_nemotron_cells_stacks_compile_a_block_of_each_kind(v5e_devices, monkeypatch):
+    """A Mamba-2 block, the attention block (32 query heads over 2 KV heads, no
+    positions) and a routed block of relu2 experts at the cell's widths and its
+    16,384 positions, forward and backward under the cell's remat policy: the
+    scan's two kernels, the convolution's, the flash pair, the held experts'
+    grouped matmuls at 2688 x 1856."""
+    from distributed_llm_training_benchmark_framework_tpu.models import tinygpt
+    from distributed_llm_training_benchmark_framework_tpu.models.tinygpt import TinyGPTConfig
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    config = TinyGPTConfig(
+        vocab_size=16384, n_embd=2688, n_head=32, n_kv_head=2, head_width=128, n_layer=3,
+        block_size=16384, dropout=0.0, causal=True, attention_impl="flash", scan_layers=False,
+        norm="rmsnorm", pos_embed="none", mlp_act="relu2", mlp_hidden=1856, bias=False,
+        tie_embeddings=False, n_experts=128, expert_top_k=6, capacity_factor=None,
+        router_score="sigmoid", routed_scaling_factor=2.5, router_aux_coef=0.0,
+        n_shared_experts=1, shared_expert_hidden=3712, experts_held=(0, 8), held_rows_factor=3.0,
+        remat="full_keep_kernels", layer_types=("ssd", "global", "mlp"), block_halves=True,
+        ssd_heads=64, ssd_head_dim=64, ssd_groups=8, ssd_state=128)
+    one = SingleDeviceSharding(v5e_devices[0])
+    shapes = jax.eval_shape(lambda k: tinygpt.init_params(config, k), jax.random.key(0))
+    params = jax.tree.map(lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one), shapes)
+    x = jax.ShapeDtypeStruct((1, 16384, 2688), jnp.bfloat16, sharding=one)
+
+    def loss(params, x):
+        y, _ = tinygpt.apply_layers(config, params, x)
+        return jnp.sum(y.astype(jnp.float32))
+
+    text = _compile(jax.grad(loss), params, x)
+    for name in ("ssd_fwd", "ssd_bwd", "kda_conv_fwd", "kda_conv_bwd", "flash_fwd",
+                 "flash_bwd_fused", "jit(gmm)", "jit(tgmm)"):
+        assert name in text, name
+    assert "attention/ssd/ssd_core" in text and "attention/global" in text
