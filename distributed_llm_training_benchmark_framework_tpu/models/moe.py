@@ -489,13 +489,18 @@ def _experts_dropless(c, layer, rows: jax.Array, counts: jax.Array) -> jax.Array
 @jax.named_scope(scopes.SHARED)
 def _shared_experts(c, layer, x: jax.Array) -> jax.Array:
     """The shared experts as one SwiGLU, (B, S, D) -> (B, S, D): gate columns
-    then up columns in one matrix, as the routed experts store theirs."""
+    then up columns in one matrix, as the routed experts store theirs; or one
+    expert that is not gated, whose up product keeps a name (``tinygpt.SHARED_U``:
+    remat ``full_keep_kernels`` keeps it; the gated one's gate+up stays dropped,
+    ``tinygpt._under_remat`` has both readings)."""
     cd = c.compute_dtype
     Fs = layer["shared_wd"].shape[0]
     if "shared_wu" in layer:  # not gated: W_down relu(W_up h)^2
-        u = jnp.einsum(
+        from .tinygpt import SHARED_U
+
+        u = checkpoint_name(jnp.einsum(
             "bsd,df->bsf", x, layer["shared_wu"].astype(cd), preferred_element_type=jnp.float32
-        ).astype(cd)
+        ).astype(cd), SHARED_U)
         return jnp.einsum(
             "bsf,fd->bsd", _relu2(u), layer["shared_wd"].astype(cd),
             preferred_element_type=jnp.float32).astype(cd)

@@ -51,17 +51,14 @@ Params = Dict[str, Any]
 
 REMAT_POLICIES = ("none", "dots", "full_keep_kernels", "full")
 
-#: ``checkpoint_name``s of two matmul results in their compute-dtype form, which
+#: ``checkpoint_name``s of matmul results in their compute-dtype form, which
 #: ``full_keep_kernels`` keeps (``_under_remat``): a KDA layer's q, k, v
-#: projection (``_kda_sublayer``) and a dense SwiGLU layer's gate+up
-#: (``_mlp_sublayer``).
-KDA_QKV, MLP_GU = MATMUL_CAST_NAMES = ("kda_qkv", "mlp_gu")
-
-#: An SSD (Mamba-2) layer's x | B | C projection after its cast
-#: (``_ssd_sublayer``): named, so that ``saved_for_backward`` lists it apart, and
-#: **not** on the list ``full_keep_kernels`` keeps (a 6144-wide product a layer:
-#: by ``_under_remat``'s rule its second run is cheaper than its room).
-SSD_XBC = "ssd_xbc"
+#: projection (``_kda_sublayer``), a dense SwiGLU layer's gate+up
+#: (``_mlp_sublayer``), an SSD (Mamba-2) layer's x | B | C and z products of
+#: ``in_proj`` (``_ssd_sublayer``) and a shared expert's up product where it is
+#: not gated (``moe._shared_experts``).
+KDA_QKV, MLP_GU, SSD_XBC, SSD_Z, SHARED_U = MATMUL_CAST_NAMES = (
+    "kda_qkv", "mlp_gu", "ssd_xbc", "ssd_z", "shared_u")
 
 
 def normalize_remat(value: Any) -> str:
@@ -1920,7 +1917,7 @@ def _ssd_sublayer(c: TinyGPTConfig, x: jax.Array, layer: Params) -> jax.Array:
     h = _norm(c, x, layer["ln1_scale"], layer.get("ln1_bias"))
     with jax.named_scope(scopes.SSD_PREP):
         win = layer["ssd_win"].astype(cd)
-        z = proj("bsd,de->bse", h, win[:, :inner]).astype(cd)
+        z = checkpoint_name(proj("bsd,de->bse", h, win[:, :inner]).astype(cd), SSD_Z)
         xbc = checkpoint_name(proj("bsd,de->bse", h, win[:, inner:inner + W]).astype(cd), SSD_XBC)
         dt = proj("bsd,dh->bsh", h, win[:, inner + W:])  # float32
         xbc = kda_ops.conv_silu(xbc, layer["ssd_conv"], layer["ssd_conv_bias"],
@@ -2335,20 +2332,26 @@ def _under_remat(pol: str, block):
     ``SSD_RESIDUAL_NAMES``), the routed experts' gate+up (or up) grouped
     matmul's result (a Mosaic call too), the router's ``HIGHEST``-precision
     logits, its choice and the plan that moves rows (sorts; ``moe.
-    MOE_RESIDUAL_NAMES``), a ``kda`` layer's q, k, v projection after its cast
-    (``KDA_QKV``) and a dense SwiGLU layer's gate+up (``MLP_GU``). ``dots`` holds
-    those last two as their ``dot_general``'s results already and leaves their
-    names out: with them the policy would trade each product for its cast, and
-    no second run would go.
+    MOE_RESIDUAL_NAMES``) and, after their casts, the wide products of
+    ``MATMUL_CAST_NAMES``: a ``kda`` layer's q, k, v projection (``KDA_QKV``), a
+    dense SwiGLU layer's gate+up (``MLP_GU``), an ``ssd`` layer's x | B | C and z
+    products of ``in_proj`` (``SSD_XBC``, ``SSD_Z``; dt is 64 columns of float32
+    and has no name) and the up product of a shared expert that is not gated
+    (``SHARED_U``). ``dots`` holds those as their ``dot_general``'s results
+    already and leaves their names out: with them the policy would trade each
+    product for its cast, and no second run would go.
 
     **The rule for the list** (``tests/test_remat_flash.py`` holds it; PERF.md,
-    PR 50, has the readings): a value is named only if, in the benchmark cell
-    where it is largest, its second run costs at least 5 ms a step per GB it
-    holds, and every cell keeps 1.5 GB of HBM free with it. A name goes to the
-    value in its compute-dtype or integer form, never to the float32 in front
-    of a cast. By that rule the KDA convolutions' q, k, v (4.1 ms a GB), the
-    shared experts' gate+up (4.0) and ``dispatch``'s gathered rows (1.5) stay
-    dropped."""
+    PRs 50 and 52, has the readings): a value is named only if, in the
+    benchmark cell where it is largest, its second run costs at least 5 ms a
+    step per GB it holds, and every cell keeps 1.5 GB of HBM free with it. A
+    name goes to the value in its compute-dtype or integer form, never to the
+    float32 in front of a cast. A product 2688 deep costs 15 ms a GB of its
+    bfloat16 result (the Nemotron cell's ``in_proj`` and shared up product),
+    three times the rule. By the same rule what a convolution makes of a
+    named product (a ``kda`` layer's q, k, v 4.1 ms a GB, an ``ssd`` layer's
+    x | B | C 3.9), the gated shared experts' gate+up (4.0 in the Kimi cell) and
+    ``dispatch``'s gathered rows (1.5) stay dropped."""
     if pol == "none":
         return block
     if pol == "full":

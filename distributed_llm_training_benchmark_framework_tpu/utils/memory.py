@@ -187,7 +187,9 @@ def estimate_hbm(
         kda_b = kda_layers * B * kept
     # An SSD (Mamba-2) layer's scan likewise: the states entering its chunks and
     # its output by name; without remat also x | B | C before and after the
-    # convolution, z, and dt and the sums of the log-decay in float32.
+    # convolution, z, and dt and the sums of the log-decay in float32 (under
+    # 'full_keep_kernels' x | B | C before the convolution and z are named:
+    # counted with named_b below).
     ssd_layers = (getattr(cfg, "layer_types", None) or ()).count("ssd")
     if ssd_layers and pol != "full":
         kept = tinygpt.ssd_stats(cfg, S)["saved_state_bytes"] + S * cfg.ssd_inner * cbytes
@@ -199,8 +201,9 @@ def estimate_hbm(
     # gate+up over the rows its experts take, its router's float32 logits
     # where the routing trains (the plan's integer arrays are kilobytes) and,
     # under 'full_keep_kernels' alone ('dots' counts its matmul results
-    # below), a KDA layer's q, k, v projection and a dense SwiGLU layer's
-    # gate+up.
+    # below), the wide products of tinygpt.MATMUL_CAST_NAMES: a KDA layer's q,
+    # k, v projection, a dense SwiGLU layer's gate+up, an SSD layer's x | B | C
+    # and z, and the up product of a shared expert that is not gated.
     named_b = 0
     if pol in ("dots", "full_keep_kernels"):
         tokens = B * layer_S
@@ -215,6 +218,9 @@ def estimate_hbm(
                 named_b += moe_layers * tokens * cfg.n_experts * 4
         if pol == "full_keep_kernels":
             named_b += kda_layers * tokens * 3 * cfg.kda_heads * cfg.kda_head_dim * cbytes
+            named_b += ssd_layers * tokens * (cfg.ssd_xbc + cfg.ssd_inner) * cbytes
+            if cfg.mlp_act == "relu2":  # a shared expert that is not gated (shared_dim 0: none)
+                named_b += moe_layers * tokens * cfg.shared_dim * cbytes
             if cfg.mlp_act == "swiglu":
                 dense_layers = L - cfg.n_moe_layers
                 named_b += (dense_layers * tokens * 2 * (cfg.dense_mlp_hidden or F) * cbytes

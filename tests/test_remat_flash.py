@@ -9,7 +9,9 @@ in the backward pass. They carry ``checkpoint_name``s
 names beside the matmul results; since PR 50 the same list
 (``tinygpt.remat_kept_names``) holds the routed experts' gate+up grouped
 matmul's result, the router's logits, choice and plan, a KDA layer's q, k, v
-projection and a dense SwiGLU layer's gate+up. These tests count calls in the
+projection and a dense SwiGLU layer's gate+up, and since PR 52 an SSD layer's
+x | B | C and z products and the up product of a shared expert that is not
+gated. These tests count calls in the
 gradient's jaxpr (walking it: shared sub-jaxprs print once in its text), hold
 both policies to ``none``'s loss and gradients, and hold the list to its rule.
 """
@@ -46,9 +48,10 @@ from distributed_llm_training_benchmark_framework_tpu.parallel import (
 )
 from distributed_llm_training_benchmark_framework_tpu.train import create_train_state
 from distributed_llm_training_benchmark_framework_tpu.utils import scopes
-from perfbench.harness import build_kda
+from perfbench.harness import build_kda, build_nemotron
 from test_deepseek import CONFIG as MLA_CONFIG
 from test_kimi_linear import FILE as KIMI_FILE
+from test_nemotron import FILE as NEMOTRON_FILE
 
 SEQ, BATCH = 64, 2
 
@@ -71,6 +74,16 @@ CONFIGS = {
         build_kda.kimi_config(dict(seq_len=SEQ, held_rows_factor=4.0, attention="flash",
                                    layer_loop="unrolled", kda_chunk=16), KIMI_FILE),
         compute_dtype=jnp.float32),
+    # the Nemotron cell's nine blocks, each one sublayer alone: four Mamba-2 mixers,
+    # four routed blocks of relu2 experts with a shared expert that is not gated,
+    # one attention block; unrolled as ``kda``. Six scan heads (``tiny_nemotron``
+    # has four), so that no two weight blocks of a mixer share a shape: z's is
+    # (64, 96), x | B | C's (64, 160), out_proj's (96, 64).
+    "ssd": dataclasses.replace(
+        build_nemotron.nemotron_config(
+            dict(seq_len=SEQ, held_rows_factor=4.0, attention="flash", layer_loop="unrolled"),
+            {**NEMOTRON_FILE, "mamba_num_heads": 6}),
+        compute_dtype=jnp.float32),
     # every expert on the chip, routing trained: the sort by expert and back.
     "dropless": TinyGPTConfig(
         vocab_size=128, n_embd=64, n_head=4, n_layer=2, block_size=SEQ, mlp_hidden=32,
@@ -81,9 +94,9 @@ CONFIGS = {
 }
 CASES = sorted(CONFIGS)
 LOOPS = {"unrolled": False, "scan": True}
-# (case, loop) a test runs: the ``kda`` stacks have one loop
+# (case, loop) a test runs: the ``kda`` and ``ssd`` stacks have one loop
 RUNS = [(case, loop) for case in CASES for loop in sorted(LOOPS)
-        if (case, loop) != ("kda", "scan")]
+        if (case, loop) not in (("kda", "scan"), ("ssd", "scan"))]
 KEEPING = ("dots", "full_keep_kernels")
 
 
@@ -123,7 +136,9 @@ def _kernel_runs(jaxpr, name):
 
 
 def _flash_layers(config):
-    return config.n_layer - (config.layer_types or ()).count(scopes.KDA)
+    kinds = config.layer_types or ()
+    return sum(config.halves(kind)[0] and kind not in (scopes.KDA, scopes.SSD)
+               for kind in kinds) if kinds else config.n_layer
 
 
 @pytest.mark.parametrize("remat, runs_a_layer", [
@@ -160,6 +175,7 @@ def test_a_keeping_policy_matches_no_remat_through_the_saved_results(case, loop,
         assert np.linalg.norm(a - b) / (np.linalg.norm(a) + 1e-12) < 1e-2
 
 
+@functools.lru_cache(maxsize=None)  # three products of one case read one trace
 def _gradient_equations(case, loop, remat):
     config = _config(case, loop, remat)
     jaxpr = jax.make_jaxpr(jax.grad(_loss(config)))(*_operands(config)).jaxpr
@@ -181,7 +197,7 @@ def _routed_counts(case, loop, remat):
 # grouped matmuls a routed layer's second run adds to what no remat runs (until PR 50
 # gate+up's, one more): where the routing trains, the gates' gradient reads the experts'
 # output, which stays dropped, so the down matmul runs again
-GMMS_AGAIN = {"mla": 0, "kda": 0, "dropless": 1}
+GMMS_AGAIN = {"mla": 0, "kda": 0, "ssd": 0, "dropless": 1}
 
 
 @pytest.mark.parametrize("remat", KEEPING + ("full",))
@@ -202,29 +218,47 @@ def test_the_routed_layers_second_run_holds_no_gate_up_matmul_and_no_sort(case, 
         assert again == {"sort": 0, "top_k": layers if case == "dropless" else 0}
 
 
+# a named product: (the case that makes it, the kind of layer that multiplies by its
+# weight block, the block's shape from the case's config)
+PRODUCTS = {
+    "kda_q_k_v": ("kda", scopes.KDA, lambda c: (c.n_embd, 3 * c.kda_heads * c.kda_head_dim)),
+    "ssd_x_b_c": ("ssd", scopes.SSD, lambda c: (c.n_embd, c.ssd_xbc)),
+    "ssd_z": ("ssd", scopes.SSD, lambda c: (c.n_embd, c.ssd_inner)),
+    "shared_up": ("ssd", scopes.MLP, lambda c: (c.n_embd, c.shared_dim)),
+}
+
+
 @pytest.mark.parametrize("remat, reads", [("dots", 1), ("full_keep_kernels", 1), ("full", 2)])
-def test_the_kda_projection_runs_once(remat, reads):
-    """A KDA layer's second run multiplies by the (D, 3 H Dk) projection once, for
+@pytest.mark.parametrize("product", sorted(PRODUCTS))
+def test_a_named_product_runs_once(product, remat, reads):
+    """A layer's second run multiplies by a named product's weight block once, for
     its input's gradient: the product itself is kept (``dots``: as the
-    ``dot_general``'s result; ``full_keep_kernels``: its cast by name)."""
-    config, equations = _gradient_equations("kda", "unrolled", remat)
-    weight = (config.n_embd, 3 * config.kda_heads * config.kda_head_dim)
+    ``dot_general``'s result; ``full_keep_kernels``: its cast by name:
+    ``tinygpt.MATMUL_CAST_NAMES``). ``full`` keeps nothing and multiplies twice."""
+    case, kind, block = PRODUCTS[product]
+    config, equations = _gradient_equations(case, "unrolled", remat)
+    weight = block(config)
     products = sum(times for eqn, rematted, times in equations
                    if rematted and eqn.primitive.name == "dot_general"
                    and weight in [tuple(v.aval.shape) for v in eqn.invars])
-    assert products == reads * config.layer_types.count(scopes.KDA)
+    assert products == reads * config.layer_types.count(kind)
 
 
 def test_the_list_is_one_and_names_what_the_rule_allows():
     """``_under_remat``'s rule: a value is named only if its second run costs at
     least 5 ms a step per GB it holds in the benchmark cell where it is largest.
-    The readings are PERF.md's (section 5, "Memory by scope", my chip runs, PR
-    50): a new name comes with its own, and one that reads under 5 goes."""
+    The readings are PERF.md's (section 5, "Memory by scope", my chip runs, PRs
+    50 and 52): a new name comes with its own, and one that reads under 5 goes."""
     ms_a_gb = {moe.MOE_GU: 6.6,  # sdar-30b-a3b.share8-bd8192: 4.95 ms for 0.755 GB
                moe.ROUTER_LOGITS: 1000.0, moe.ROUTER_CHOICE: 1000.0,  # kimi: 8.78 ms, 17 MB
                moe.MOE_PLAN: 1000.0,  # mellum2: 7.5 ms with combine's backward, 3 MB
                tinygpt.KDA_QKV: 12.3,  # kimi: 19.86 ms for 1.611 GB
-               tinygpt.MLP_GU: 11.1}  # kimi: 6.73 ms for 0.604 GB
+               tinygpt.MLP_GU: 11.1,  # kimi: 6.73 ms for 0.604 GB
+               # nemotron-3-nano-30b-a3b.share16-seq16384, the parent traced (my chip run, PR
+               # 52), four blocks each:
+               tinygpt.SSD_XBC: 14.9,  # 12.02 ms (4 x 3.005) for 0.805 GB
+               tinygpt.SSD_Z: 15.4,  # 8.28 ms (in_proj's 20.33 less x | B | C's and dt's) for 0.537 GB
+               tinygpt.SHARED_U: 14.5}  # 7.05 ms (4 x 1.763) for 0.487 GB
     names = tinygpt.remat_kept_names()
     assert len(set(names)) == len(names)
     assert set(names) == {*FLASH_RESIDUAL_NAMES, *KDA_RESIDUAL_NAMES, *SSD_RESIDUAL_NAMES, *ms_a_gb}

@@ -30,8 +30,9 @@ from distributed_llm_training_benchmark_framework_tpu.train.step import (
     abstract_compile_step, create_train_state,
 )
 from distributed_llm_training_benchmark_framework_tpu.utils import memory, residuals, scopes
-from perfbench.harness import build_kda
+from perfbench.harness import build_kda, build_nemotron
 from tests.test_kimi_linear import FILE as KIMI_FILE
+from tests.test_nemotron import FILE as NEMOTRON_FILE
 
 SEQ, BATCH = 128, 4
 AXES = ("data", "seq", "model", "pipe", "expert")
@@ -48,6 +49,9 @@ ROUTED = TinyGPTConfig(
 KIMI_JOB = dict(seq_len=SEQ, held_rows_factor=4.0, attention="flash", layer_loop="unrolled",
                 kda_chunk=16)
 KIMI = build_kda.kimi_config(KIMI_JOB, KIMI_FILE)
+NEMOTRON = build_nemotron.nemotron_config(
+    dict(seq_len=SEQ, held_rows_factor=4.0, attention="flash", layer_loop="unrolled"),
+    NEMOTRON_FILE)
 
 
 def mesh_of(**sizes):
@@ -229,7 +233,7 @@ def _names_in_front_of_a_cast(jaxpr, names):
     return found
 
 
-@pytest.mark.parametrize("which", ("routed", "kimi", "a name in front of its cast"))
+@pytest.mark.parametrize("which", ("routed", "kimi", "nemotron", "a name in front of its cast"))
 def test_no_name_is_given_to_a_float32_in_front_of_its_cast(which):
     """A name keeps the value it is given: on the float32 product in front of
     ``.astype(bfloat16)`` it would hold twice the bytes the backward reads."""
@@ -240,14 +244,16 @@ def test_no_name_is_given_to_a_float32_in_front_of_its_cast(which):
         assert _names_in_front_of_a_cast(jax.make_jaxpr(wrong)(x, x).jaxpr, names) == [names[-1]]
         return
     config = dataclasses.replace(
-        {"routed": ROUTED, "kimi": KIMI}[which], compute_dtype=jnp.bfloat16, remat="none")
+        {"routed": ROUTED, "kimi": KIMI, "nemotron": NEMOTRON}[which],
+        compute_dtype=jnp.bfloat16, remat="none")
     params = jax.eval_shape(lambda: tinygpt.init_params(config, jax.random.key(0)))
     batch = jax.ShapeDtypeStruct((1, SEQ), jnp.int32)
     jaxpr = jax.make_jaxpr(lambda p, b: tinygpt.loss_fn(config, p, b, b))(params, batch).jaxpr
     seen = {e.params["name"] for inner in _jaxprs(jaxpr) for e in inner.eqns
             if e.primitive.name == "name"}
-    assert seen >= ({moe.MOE_GU, moe.ROUTER_LOGITS} | ({tinygpt.KDA_QKV, tinygpt.MLP_GU}
-                                                       if which == "kimi" else set()))
+    assert seen >= ({moe.MOE_GU, moe.ROUTER_LOGITS} | {
+        "kimi": {tinygpt.KDA_QKV, tinygpt.MLP_GU},
+        "nemotron": {tinygpt.SSD_XBC, tinygpt.SSD_Z, tinygpt.SHARED_U}}.get(which, set()))
     assert _names_in_front_of_a_cast(jaxpr, names) == []
 
 
