@@ -60,6 +60,12 @@ REMAT_POLICIES = ("none", "dots", "full_keep_kernels", "full")
 KDA_QKV, MLP_GU, SSD_XBC, SSD_Z, SHARED_U = MATMUL_CAST_NAMES = (
     "kda_qkv", "mlp_gu", "ssd_xbc", "ssd_z", "shared_u")
 
+#: A ``conv`` layer's B | C | x~ projection after its cast (``_conv_sublayer``):
+#: named, so that ``saved_for_backward`` lists it apart, and **not** on the list
+#: ``full_keep_kernels`` keeps: ``_under_remat``'s rule has two clauses, and the
+#: second one (room) refuses it (its docstring has the readings).
+SCONV_BCX = "sconv_bcx"
+
 
 def normalize_remat(value: Any) -> str:
     """Normalize a remat policy: accepts one of ``REMAT_POLICIES`` or a legacy
@@ -129,23 +135,26 @@ class YarnScaling:
 #: earlier position (latent attention where the config has it), ``window``
 #: over the last ``sliding_window`` of them, ``kda`` the gated delta-rule
 #: recurrence (``_kda_sublayer``), ``ssd`` a Mamba-2 mixer (the scalar-decay
-#: state-space scan, ``_ssd_sublayer``), both with leaves of their own. Also the
+#: state-space scan, ``_ssd_sublayer``), ``conv`` a gated short convolution
+#: (``_conv_sublayer``), each with leaves of its own. Also the
 #: names of their scopes under ``attention``. ``mlp`` is no mixer: under
 #: ``block_halves`` a block of that kind is the feed-forward part alone (scope
 #: ``mlp``), and a block of a mixer's kind the mixer alone.
-LAYER_KINDS = (scopes.GLOBAL, scopes.WINDOW, scopes.KDA, scopes.SSD, scopes.MLP)
+LAYER_KINDS = (scopes.GLOBAL, scopes.WINDOW, scopes.KDA, scopes.SSD, scopes.MLP, scopes.CONV)
 
-#: The stacks of the parameter tree, by (the mixer is KDA, the MLP is a
-#: leading dense one): layers of one stack have equal leaves
-#: (``TinyGPTConfig.layer_groups``). Every name ends in ``blocks``. Where the
-#: attention kinds differ in head count (``layer_heads``) a stack's name takes
-#: its kind in front: ``global_dense_blocks``, ``window_blocks``.
-_STACK_NAMES = {(False, False): "blocks", (False, True): "dense_blocks",
-                (True, False): "kda_blocks", (True, True): "kda_dense_blocks"}
+#: The stacks of the parameter tree, by (the mixer's prefix: ``kda_``, ``conv_``
+#: or none for attention; the MLP is a leading dense one): layers of one stack
+#: have equal leaves (``TinyGPTConfig.layer_groups``). Every name ends in
+#: ``blocks``. Where the attention kinds differ in head count (``layer_heads``)
+#: a stack's name takes its kind in front: ``global_dense_blocks``,
+#: ``window_blocks``.
+_OWN_MIXER = {scopes.KDA: "kda_", scopes.CONV: "conv_"}  # a kind's stacks' prefix
+_STACK_NAMES = {(own, dense): own + ("dense_blocks" if dense else "blocks")
+                for own in ("", *_OWN_MIXER.values()) for dense in (False, True)}
 
 #: The kinds of layer that have no softmax attention: no mask rule, no rotary
 #: table, no flash kernel.
-_NO_ATTENTION = frozenset((scopes.KDA, scopes.SSD, scopes.MLP))
+_NO_ATTENTION = frozenset((scopes.KDA, scopes.SSD, scopes.MLP, scopes.CONV))
 
 
 @dataclasses.dataclass(frozen=True)
@@ -392,6 +401,11 @@ class TinyGPTConfig:
     ssd_state: int = 0
     ssd_conv: int = 4
     ssd_chunk: int = 128  # the family's published chunk_size
+    # A ``conv`` layer's taps (a gated short convolution, LFM2's conv_L_cache):
+    # [B | C | x~] = h W_in (n_embd -> 3 n_embd), a depthwise causal
+    # convolution of conv_taps positions over B * x~, no bias and no activation,
+    # the result times C, then W_out (``ops/kda.py::gated_conv``).
+    conv_taps: int = 3
     # Each block of the stack is one sublayer alone behind its own norm and
     # residual (Nemotron-H's hybrid_override_pattern): a block of a mixer's kind
     # has no feed-forward part, and a block of kind ``mlp`` no mixer. The
@@ -547,6 +561,10 @@ class TinyGPTConfig:
         return scopes.KDA in (self.layer_types or ())
 
     @property
+    def has_conv(self) -> bool:
+        return scopes.CONV in (self.layer_types or ())
+
+    @property
     def ssd_inner(self) -> int:
         """d_inner of an ``ssd`` layer: heads x head width."""
         return self.ssd_heads * self.ssd_head_dim
@@ -578,7 +596,7 @@ class TinyGPTConfig:
         """Whether the layers' leaves differ by kind (another mixer, another
         head count, a half alone): such stacks run unrolled through
         ``_apply_stacks``."""
-        return self.has_kda or self.heads_by_kind or self.block_halves
+        return self.has_kda or self.has_conv or self.heads_by_kind or self.block_halves
 
     @property
     def layer_groups(self) -> Tuple[Tuple[str, Tuple[int, ...]], ...]:
@@ -594,9 +612,9 @@ class TinyGPTConfig:
             return tuple((name, tuple(layers)) for name, layers in groups.items())
         for i in range(self.n_layer):
             kind = None if self.layer_types is None else self.layer_types[i]
-            kda = kind == scopes.KDA
-            name = _STACK_NAMES[kda, i < self.first_k_dense]
-            groups.setdefault(f"{kind}_{name}" if by_kind and not kda else name, []).append(i)
+            own = _OWN_MIXER.get(kind, "")
+            name = _STACK_NAMES[own, i < self.first_k_dense]
+            groups.setdefault(f"{kind}_{name}" if by_kind and not own else name, []).append(i)
         return tuple((name, tuple(layers)) for name, layers in groups.items())
 
     @property
@@ -666,7 +684,7 @@ class TinyGPTConfig:
             raise ValueError(
                 "the pipeline schedules slice one homogeneous stack; layer_types "
                 "gives each layer a kind of its own (sliding_window or kda layers "
-                "beside global ones, ssd layers, stacks of unequal leaves under "
+                "beside global ones, conv or ssd layers, stacks of unequal leaves under "
                 "layer_heads or block_halves). Run "
                 "this config with pipe=1"
             )
@@ -778,8 +796,8 @@ class TinyGPTConfig:
             if self.attention_impl not in ("flash", "reference") or (
                     self.seq_manual_axis is not None):
                 raise ValueError(
-                    "layer_types (sliding_window or kda layers beside global ones, ssd "
-                    "layers, head counts by kind, blocks of one half) runs "
+                    "layer_types (sliding_window or kda layers beside global ones, conv or "
+                    "ssd layers, head counts by kind, blocks of one half) runs "
                     "attention_impl 'flash' or 'reference' on whole sequences: ring "
                     "attention, Ulysses and the sequence-parallel pipeline cut the "
                     "sequence, and their bodies take causal or no mask only; got "
@@ -793,8 +811,18 @@ class TinyGPTConfig:
                     "layer_types mixes causal layers in one stack: causal=True, no "
                     "block_diffusion; latent attention (kv_lora_rank) is its 'global' "
                     "layers' and has no 'window' ones; first_k_dense leading layers "
-                    "go with 'kda' layers or head counts by kind (layer_heads): the "
-                    "stacks of unequal leaves"
+                    "go with 'kda' layers, 'conv' layers or head counts by kind "
+                    "(layer_heads): the stacks of unequal leaves"
+                )
+            if scopes.CONV in kinds and not (
+                    self.conv_taps >= 1 and self.norm == "rmsnorm" and not self.bias
+                    and not self.scan_layers and not self.tp_collective_matmul
+                    and not self.dropout and not self.block_halves):
+                raise ValueError(
+                    "a 'conv' layer (a gated short convolution) needs conv_taps >= 1, "
+                    "norm='rmsnorm', bias=False, no dropout, no tp_collective_matmul, no "
+                    "block_halves and scan_layers=False: stacks of unequal leaves run "
+                    "unrolled, in the published order, and the scanned loop is refused"
                 )
             if scopes.KDA in kinds and not (
                     self.kda_heads > 0 and self.kda_head_dim > 0 and self.kda_conv >= 1
@@ -1033,6 +1061,12 @@ PARAM_AXIS_RULES: Dict[str, Tuple[Optional[str], ...]] = {
     "blocks/ssd_a_log": ("layers", "ssd_heads"),
     "blocks/ssd_d": ("layers", "ssd_heads"),
     "blocks/ssd_norm": ("layers", "ssd_inner"),
+    # A 'conv' layer's mixer (the stacks 'conv_blocks' / 'conv_dense_blocks';
+    # present instead of the attention leaves): the input projection's columns
+    # [B | C | x~] and the depthwise convolution's taps over the embed channels;
+    # wo as above. No tensor-parallel rule: under a 'model' axis they stay whole.
+    "blocks/sconv_win": ("layers", "embed", "sconv_in"),
+    "blocks/sconv_taps": ("layers", "conv", "sconv_channels"),
     # Experts that are not gated (mlp_act='relu2', present instead of moe_wgu /
     # shared_wgu): the up projection alone.
     "blocks/moe_wu": ("layers", "experts", "embed", "mlp"),
@@ -1160,6 +1194,19 @@ def init_params(config: TinyGPTConfig, key: jax.Array) -> Params:
             wo=normal(next(k), (L, c.ssd_inner, D)),
         )
 
+    def norms_and_conv(L):
+        """One stack's norm scales and gated-convolution leaves, L layers: the
+        taps as a depthwise Conv1d's default (uniform within 1 / sqrt(taps))."""
+        bound = c.conv_taps ** -0.5
+        return dict(
+            ln1_scale=ones((L, D)), ln2_scale=ones((L, D)),
+            sconv_win=normal(next(k), (L, D, 3 * D)),
+            sconv_taps=jax.random.uniform(
+                next(k), (L, c.conv_taps, D), jnp.float32, minval=-bound, maxval=bound
+            ).astype(c.param_dtype),
+            wo=normal(next(k), (L, D, D)),
+        )
+
     def mlp_leaves(L):
         """One stack's MLP as the config has it (routed where n_experts), L layers."""
         blocks = {"ln2_scale": ones((L, D))} if c.block_halves else {}
@@ -1214,6 +1261,8 @@ def init_params(config: TinyGPTConfig, key: jax.Array) -> Params:
             return norm_and_ssd(L) if kind == scopes.SSD else norms_and_attention(L, c.heads(kind))
         if name.startswith("kda_"):
             leaves = norms_and_kda(L)
+        elif name.startswith("conv_"):
+            leaves = norms_and_conv(L)
         else:
             kind = c.layer_types[layers[0]] if c.layer_types else None
             leaves = norms_and_attention(L, c.heads(kind))
@@ -1226,8 +1275,8 @@ def init_params(config: TinyGPTConfig, key: jax.Array) -> Params:
 
     # The draws' order is the seeds' contract with every published artifact:
     # 'blocks' (the layers after the leading dense ones), the embedding and the
-    # head, 'dense_blocks', the KDA stacks, then the stacks named by kind
-    # (``layer_heads``) in the published order of their first layers.
+    # head, 'dense_blocks', the KDA stacks, the conv stacks, then the stacks
+    # named by kind (``layer_heads``) in the published order of their first layers.
     groups = dict(c.layer_groups)
     params = {}
     if "blocks" in groups:
@@ -1239,8 +1288,8 @@ def init_params(config: TinyGPTConfig, key: jax.Array) -> Params:
         params["lnf_bias"] = zeros((D,))
     if not c.tie_embeddings:
         params["lm_head"] = normal(next(k), (V, D))
-    for name in ("dense_blocks", "kda_blocks", "kda_dense_blocks",
-                 *(n for n in groups if n not in _STACK_NAMES.values())):
+    for name in ("dense_blocks", "kda_blocks", "kda_dense_blocks", "conv_dense_blocks",
+                 "conv_blocks", *(n for n in groups if n not in _STACK_NAMES.values())):
         if name in groups:
             params[name] = stack(name, groups[name])
     return params
@@ -1567,6 +1616,8 @@ def _mixer_half(c, x, layer, key, deterministic, kind, qk_tables):
                 return _kda_sublayer(c, x, layer)
             if kind == scopes.SSD:
                 return _ssd_sublayer(c, x, layer)
+            if kind == scopes.CONV:
+                return _conv_sublayer(c, x, layer)
             return _attention_sublayer(c, x, layer, key, deterministic, kind, qk_tables)
 
 
@@ -1970,6 +2021,54 @@ def ssd_stats(config: TinyGPTConfig, seq_len: int) -> Dict[str, Any]:
     }
 
 
+def _conv_sublayer(c: TinyGPTConfig, x: jax.Array, layer: Params) -> jax.Array:
+    """Norm -> gated short convolution -> residual: a ``conv`` layer's mixer, in
+    three scopes. ``sconv_in``: [B | C | x~] = h W_in, one (D, 3 D) product
+    whose result has a name (``SCONV_BCX``: not one a remat policy keeps). ``sconv_core``:
+    C * conv(B * x~), the depthwise causal convolution of ``conv_taps``
+    positions with zeros before the sequence, no bias and no activation
+    (``ops.kda.gated_conv``: on a TPU where ``conv_fits`` one Mosaic call a
+    direction, ``sconv_fwd`` / ``sconv_bwd``, which find the three thirds of the
+    operand by their block specs; elsewhere the ``jnp`` chain). ``sconv_out``:
+    W_out."""
+    from ..ops import kda as kda_ops
+
+    cd = c.compute_dtype
+    proj = functools.partial(jnp.einsum, preferred_element_type=jnp.float32)
+    h = _norm(c, x, layer["ln1_scale"], layer.get("ln1_bias"))
+    with jax.named_scope(scopes.SCONV_IN):
+        bcx = checkpoint_name(
+            proj("bsd,de->bse", h, layer["sconv_win"].astype(cd)).astype(cd), SCONV_BCX)
+    with jax.named_scope(scopes.SCONV_CORE):
+        y = kda_ops.gated_conv(bcx, layer["sconv_taps"], interpret=kda_ops.kernel_mode())
+    with jax.named_scope(scopes.SCONV_OUT):
+        return x + proj("bse,ed->bsd", y, layer["wo"].astype(cd)).astype(cd)
+
+
+def sconv_stats(config: TinyGPTConfig, seq_len: int) -> Dict[str, Any]:
+    """Counters of the ``conv`` layers over sequences of ``seq_len`` tokens,
+    from the config and the backend at trace time: ``layers`` of the kind,
+    ``taps``, ``layers_in_kernel`` of them whose gated convolution the Mosaic
+    calls take (``kernel_calls`` a step by name, one forward and one backward a
+    layer; none on the ``jnp`` chain; remat's second forward is the policy's,
+    not counted), and the bytes one call moves a sequence each way at the
+    stored width: forward the (S, 3 D) operand in and (S, D) out, backward the
+    operand, the cotangent and the (S, 3 D) result."""
+    from ..ops import kda as kda_ops
+
+    c = config
+    layers = (c.layer_types or ()).count(scopes.CONV)
+    taken = layers if (layers and kda_ops.kernel_mode() is not None
+                       and kda_ops.conv_fits(seq_len, c.conv_taps, c.n_embd)) else 0
+    cell = seq_len * c.n_embd * jnp.dtype(c.compute_dtype).itemsize
+    return {
+        "layers": layers, "taps": c.conv_taps, "layers_in_kernel": taken,
+        "kernel_calls": {"sconv_fwd": taken, "sconv_bwd": taken},
+        "forward_bytes": 4 * cell if layers else 0,
+        "backward_bytes": 7 * cell if layers else 0,
+    }
+
+
 def _pin_mlp_hidden(c: TinyGPTConfig, h: jax.Array) -> jax.Array:
     """Pin an F-wide MLP intermediate, (B, S, F) or (B, S, 2, F), to
     ``config.mlp_hidden_spec``; an unset spec is an exact no-op."""
@@ -2351,7 +2450,11 @@ def _under_remat(pol: str, block):
     three times the rule. By the same rule what a convolution makes of a
     named product (a ``kda`` layer's q, k, v 4.1 ms a GB, an ``ssd`` layer's
     x | B | C 3.9), the gated shared experts' gate+up (4.0 in the Kimi cell) and
-    ``dispatch``'s gathered rows (1.5) stay dropped."""
+    ``dispatch``'s gathered rows (1.5) stay dropped. A ``conv`` layer's B | C |
+    x~ projection (``SCONV_BCX``) reads 10.9 ms a GB and stays dropped by the
+    second clause: with it the one cell that produces it is 14.87 GB under
+    ``full_keep_kernels``, over the 14.5 GB line its policy is chosen by, and
+    would fall to ``full`` (PERF.md, PR 54)."""
     if pol == "none":
         return block
     if pol == "full":
@@ -2483,8 +2586,8 @@ def apply_layer(config: TinyGPTConfig, layer: Params, x: jax.Array, kind: Option
 
 
 def _apply_stacks(c, params, x, base_key, deterministic, qk_tables):
-    """The whole depth of a config whose stacks have unequal leaves (``kda``
-    or ``ssd`` layers beside others, attention kinds of unequal head counts,
+    """The whole depth of a config whose stacks have unequal leaves (``kda``,
+    ``conv`` or ``ssd`` layers beside others, attention kinds of unequal head counts,
     blocks that are one half alone): unrolled, the layers in the published
     order, each from its own stack (``apply_layer``) -> (x, aux_sum)."""
     live = base_key is not None and not deterministic
@@ -2607,6 +2710,8 @@ def _walk_routers(config: TinyGPTConfig, params: Params, idx: jax.Array, read):
             x = _kda_sublayer(c, x, layer)
         elif kind == scopes.SSD:
             x = _ssd_sublayer(c, x, layer)
+        elif kind == scopes.CONV:
+            x = _conv_sublayer(c, x, layer)
         else:
             x = _attention_sublayer(c, x, layer, None, True, kind)
         if not has_mlp:

@@ -67,7 +67,10 @@ element of q, k, v is read once and written once a direction, and nothing is
 kept but x. ``causal_conv`` is the same kernels without the epilogue. Their
 ``jnp`` path (another backend, or heads that are not whole 128-lane tiles) is
 XLA's grouped convolution and ``silu_l2norm``, which is also what the tests
-hold the kernels to.
+hold the kernels to. ``gated_conv`` is a mixer of its own on the same
+convolution: c * conv(b * x) over the thirds of one operand, the two gates as
+the prologue and the epilogue of two more Mosaic calls, ``sconv_fwd`` /
+``sconv_bwd``.
 """
 
 from __future__ import annotations
@@ -870,3 +873,152 @@ def qkv_prologue(x, taps, heads: int, *, interpret: Optional[bool] = None):
     if interpret is None or not conv_fits(x.shape[1], taps.shape[0], head_dim):
         return silu_l2norm(_conv_reference(x, taps), heads)
     return tuple(_conv(x, taps.astype(jnp.float32), head_dim, interpret, False))
+
+
+_GATED_ROWS = 128  # rows of a tile of the gated convolution's kernels, all C columns wide
+
+
+def _gated_prologue(b, b_tail, x, x_tail, ext):
+    """v = b x of a tile in float32 behind its 8 preceding rows (zeros before
+    the sequence), into ``ext``."""
+    tail = b_tail[...].astype(jnp.float32) * x_tail[...].astype(jnp.float32)
+    ext[0:8, :] = jnp.where(pl.program_id(1) == 0, 0.0, tail)
+    ext[8:, :] = b[...].astype(jnp.float32) * x[...].astype(jnp.float32)
+
+
+def _gated_fwd_kernel(K, b, b_tail, c, x, x_tail, taps, y, ext):
+    """A (rows, C) tile of y = c * conv(b * x): the prologue v = b x, then a
+    128-lane slab at a time the taps over it and the gate c as the epilogue;
+    one cast, one write."""
+    rows, columns = b.shape
+    _gated_prologue(b, b_tail, x, x_tail, ext)
+
+    def slab(lanes):
+        _, w = _window(ext, taps, K, 0, rows, lanes)
+        y[:, lanes] = (w * c[:, lanes].astype(jnp.float32)).astype(y.dtype)
+
+    _over_slabs(columns, None, slab)
+
+
+def _gated_bwd_kernel(K, b, b_tail, c, c_after, x, x_tail, taps, dy, dy_after, dbcx, dtaps, ext):
+    """The transposes over a (rows, C) tile: v = b x and w = conv(v) computed
+    again from the operand's rows and the 8 before them; dc = dy w; dw = dy c
+    on the tile's rows and the 8 after them (zeros after the sequence); dv_t =
+    sum_i taps_i dw_{t+K-1-i}; db = dv x, dx = dv b, written as the three
+    thirds of one (rows, 3 C) tile; dtaps_i = sum over the positions of
+    v_{t-K+1+i} dw_t, summed into a block that stays resident over the grid."""
+    rows, columns = b.shape
+    first = (pl.program_id(0) == 0) & (pl.program_id(1) == 0)
+    last = pl.program_id(1) == pl.num_programs(1) - 1
+    _gated_prologue(b, b_tail, x, x_tail, ext)
+
+    @pl.when(first)
+    def _():
+        dtaps[...] = jnp.zeros_like(dtaps)
+
+    def slab(j, _):
+        at = lambda third: pl.ds(pl.multiple_of(third * columns + j * 128, 128), 128)
+        lanes = at(0)
+        v, w = _window(ext, taps, K, 0, rows, lanes)
+        d = dy[:, lanes].astype(jnp.float32)
+        dbcx[:, at(1)] = (d * w).astype(dbcx.dtype)
+        after = jnp.where(last, 0.0, dy_after[:, lanes].astype(jnp.float32)
+                          * c_after[:, lanes].astype(jnp.float32))
+        window = jnp.concatenate([d * c[:, lanes].astype(jnp.float32), after], axis=0)
+        later = [window[K - 1 - i:K - 1 - i + rows] for i in range(K)]  # dw_{t+K-1-i}
+        dv = later[K - 1] * taps[K - 1:K, lanes]
+        for i in range(K - 1):
+            dv = dv + later[i] * taps[i:i + 1, lanes]
+        dbcx[:, lanes] = (dv * x[:, lanes].astype(jnp.float32)).astype(dbcx.dtype)
+        dbcx[:, at(2)] = (dv * b[:, lanes].astype(jnp.float32)).astype(dbcx.dtype)
+        for i in range(K):
+            dtaps[i:i + 1, lanes] += jnp.sum(v * later[i], axis=0, keepdims=True)
+
+    lax.fori_loop(0, columns // 128, slab, None)
+
+
+@functools.lru_cache(maxsize=16)
+def _gated_call(backward, B, S, C, K, dtype, interpret):
+    """One direction's ``pallas_call`` of the gated convolution over an operand
+    (B, S, 3 C) whose thirds are b, c and x, in ``_conv_call``'s frame: a grid of
+    (batch, tiles of rows), a tile all C columns wide; b's, c's and x's tiles
+    are three block specs on the one operand (no sliced copy), b's and x's come
+    with the 8 rows before them, and the backward's cotangent and c with the 8
+    after (clamped at the sequence's ends, where the kernels put zeros).
+    Operands: forward (bcx x 5, taps) -> y (B, S, C); backward (bcx x 6, taps,
+    dy, dy) -> (dbcx (B, S, 3 C), one tile a step across its thirds; dtaps (K,
+    C))."""
+    rows = math.gcd(S, _GATED_ROWS)
+    tile = lambda third: pl.BlockSpec((None, rows, C), lambda b, n: (b, n, third))
+    eight = lambda row, third: pl.BlockSpec((None, 8, C), lambda b, n: (b, row(n), third))
+    before = lambda n: jnp.maximum(n * (rows // 8) - 1, 0)
+    after = lambda n: jnp.minimum((n + 1) * (rows // 8), S // 8 - 1)
+    taps = pl.BlockSpec((K, C), lambda b, n: (0, 0))
+    grid = (B, S // rows)
+    scratch = [pltpu.VMEM((rows + 8, C), jnp.float32)]
+    if not backward:
+        return pl.pallas_call(
+            functools.partial(_gated_fwd_kernel, K), grid=grid,
+            in_specs=[tile(0), eight(before, 0), tile(1), tile(2), eight(before, 2), taps],
+            out_specs=tile(0), out_shape=jax.ShapeDtypeStruct((B, S, C), dtype),
+            scratch_shapes=scratch, interpret=interpret, name="sconv_fwd",
+            compiler_params=pltpu.CompilerParams(dimension_semantics=("parallel", "parallel")),
+        )
+    return pl.pallas_call(
+        functools.partial(_gated_bwd_kernel, K), grid=grid,
+        in_specs=[tile(0), eight(before, 0), tile(1), eight(after, 1), tile(2), eight(before, 2),
+                  taps, tile(0), eight(after, 0)],
+        out_specs=[pl.BlockSpec((None, rows, 3 * C), lambda b, n: (b, n, 0)), taps],
+        out_shape=[jax.ShapeDtypeStruct((B, S, 3 * C), dtype),
+                   jax.ShapeDtypeStruct((K, C), jnp.float32)],
+        scratch_shapes=scratch, interpret=interpret, name="sconv_bwd",
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("arbitrary", "arbitrary")),
+    )
+
+
+def _gated_run(backward, interpret, bcx, taps, *cotangent):
+    (B, S, C3), K = bcx.shape, taps.shape[0]
+    call = _gated_call(backward, B, S, C3 // 3, K, bcx.dtype, interpret)
+    operands = (bcx,) * (6 if backward else 5)
+    # one trace for the primal and the forward rule: see flash_attention._flash_forward
+    with jax.sharding.use_abstract_mesh(jax.sharding.get_abstract_mesh()):
+        return call(*operands, taps, *cotangent)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2,))
+def _gated(bcx, taps, interpret):
+    return _gated_run(False, interpret, bcx, taps)
+
+
+def _gated_fwd(bcx, taps, interpret):
+    return _gated_run(False, interpret, bcx, taps), (bcx, taps)
+
+
+def _gated_bwd(interpret, residuals, dy):
+    bcx, taps = residuals
+    return tuple(_gated_run(True, interpret, bcx, taps, dy, dy))  # (dbcx, dtaps)
+
+
+_gated.defvjp(_gated_fwd, _gated_bwd)
+
+
+def gated_conv(bcx, taps, *, interpret: Optional[bool] = None):
+    """A gated short convolution's middle (LFM2's conv mixer between its two
+    projections): bcx (B, S, 3 C) in the compute type, b's, c's and x's columns
+    in that order, taps (K, C) -> c * conv(b * x), (B, S, C) in bcx's dtype: the
+    depthwise causal convolution over positions with zeros before the sequence,
+    no bias and no activation; float32 products and sums. ``interpret`` False
+    (True: interpreted) where ``conv_fits`` at the C columns: the two gates are
+    the prologue and the epilogue of ``causal_conv``'s kernels on the float32
+    slab, one Mosaic call a direction, ``sconv_fwd`` / ``sconv_bwd``; the three
+    thirds of the operand are found by the calls' block specs, the backward
+    computes b x and its convolution again from the operand and gives db, dc and
+    dx as one (B, S, 3 C) result beside the taps' gradient: nothing is kept but
+    the operand. None, or an operand the kernels do not take: the ``jnp`` chain
+    over XLA's grouped convolution, which is also what the tests hold the
+    kernels to."""
+    C = bcx.shape[-1] // 3
+    if interpret is None or not conv_fits(bcx.shape[1], taps.shape[0], C):
+        b, c, x = (bcx[..., i * C:(i + 1) * C].astype(jnp.float32) for i in range(3))
+        return (c * _conv_reference(b * x, taps.astype(jnp.float32))).astype(bcx.dtype)
+    return _gated(bcx, taps.astype(jnp.float32), interpret)

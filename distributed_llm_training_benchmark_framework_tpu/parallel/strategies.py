@@ -430,11 +430,12 @@ _EP_RULES = {
 def _leaf_name(path) -> str:
     """'blocks/<leaf>' for a leaf of any stack of layers (models/tinygpt.py
     ``_STACK_NAMES``: 'blocks', the leading dense 'dense_blocks', the KDA
-    layers' 'kda_blocks' / 'kda_dense_blocks', the stacks by kind of
+    layers' 'kda_blocks' / 'kda_dense_blocks', the gated-convolution layers'
+    'conv_blocks' / 'conv_dense_blocks', the stacks by kind of
     ``layer_heads`` and of ``block_halves``: 'global_blocks', 'ssd_blocks',
     'mlp_blocks'): a leaf of one name has the same shape but for its widths
-    and the same role in every stack, and takes the same rules. A KDA or SSD
-    mixer's own leaves ('kda_*', 'ssd_*') and the relu2 experts' up projections
+    and the same role in every stack, and takes the same rules. A KDA, SSD or
+    conv mixer's own leaves ('kda_*', 'ssd_*', 'sconv_*') and the relu2 experts' up projections
     ('moe_wu', 'shared_wu') have no tensor-parallel rule: under a 'model' axis
     they stay whole."""
     name = "/".join(str(getattr(p, "key", p)) for p in path)

@@ -128,7 +128,8 @@ def _deepseek_forward_flops_per_token(c) -> float:
     (causal's S / 2 keys a token is the convention for the global ones); each
     kind at its own head count (``layer_heads``), with the output gate's
     projection where the config has one (``attn_gate``: 2 D H a token). Rotary
-    over a part of a head is elementwise, as whole heads' is: not counted."""
+    over a part of a head is elementwise, as whole heads' is: not counted. A
+    ``kda`` or ``conv`` layer's mixer by its own count."""
     D, H, S = c.n_embd, c.n_head, c.block_size
     attn_tokens = S / 2 if c.causal else S
     copies = 1
@@ -149,9 +150,11 @@ def _deepseek_forward_flops_per_token(c) -> float:
         if windows:  # the mean over the stack: window layers by their true pairs
             scores *= (windows * _window_tokens(c) / attn_tokens + c.n_layer - windows) / c.n_layer
     kda_layers = (c.layer_types or ()).count("kda")
+    conv_layers = (c.layer_types or ()).count("conv")
     return float(
-        (c.n_layer - kda_layers) * (copies * projections + scores)
+        (c.n_layer - kda_layers - conv_layers) * (copies * projections + scores)
         + kda_layers * kda_forward_flops_per_token(c)
+        + conv_layers * conv_forward_flops_per_token(c)
         + copies * _mlp_forward_flops_per_token(c)
         + 2 * D * c.vocab_size
     )
@@ -227,6 +230,14 @@ def ssd_forward_flops_per_token(c) -> float:
     convolution = 2 * c.ssd_conv * c.ssd_xbc
     scan = H * (2 * C * P + 4 * N * P) + c.ssd_groups * 2 * C * N
     return float(projections + convolution + scan)
+
+
+def conv_forward_flops_per_token(c) -> float:
+    """One ``conv`` layer's mixer, a token: the input projection to B | C | x~
+    (D -> 3 D), the convolution's taps over the D channels and the output
+    projection (D -> D). The two gates are elementwise: not counted."""
+    D = c.n_embd
+    return float(2 * D * 3 * D + 2 * c.conv_taps * D + 2 * D * D)
 
 
 def kda_forward_flops_per_token(c) -> float:

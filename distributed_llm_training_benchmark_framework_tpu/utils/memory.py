@@ -196,6 +196,12 @@ def estimate_hbm(
         if pol == "none":
             kept += S * ((2 * cfg.ssd_xbc + cfg.ssd_inner) * cbytes + 2 * cfg.ssd_heads * 4)
         kda_b += ssd_layers * B * kept
+    # A conv layer (a gated short convolution) keeps nothing of its own but,
+    # without remat, B | C | x~ and the gated result (no policy keeps them by
+    # name: tinygpt.SCONV_BCX is not on the list).
+    conv_layers = (getattr(cfg, "layer_types", None) or ()).count("conv")
+    if conv_layers and pol == "none":
+        kda_b += conv_layers * B * S * 4 * D * cbytes
     # What 'dots' and 'full_keep_kernels' keep by name beside the mixer
     # kernels' results (tinygpt._under_remat has the list): a routed layer's
     # gate+up over the rows its experts take, its router's float32 logits

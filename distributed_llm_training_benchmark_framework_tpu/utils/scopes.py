@@ -47,9 +47,10 @@ MLA_PROJ, MLA_CORE, MLA_OUT = MLA_SCOPES = ("mla_proj", "mla_core", "mla_out")
 # (``TinyGPTConfig.layer_types``): a layer's whole mixer sublayer under its
 # kind's name, a sliding-window layer, a global one (softmax attention over
 # every earlier position, latent attention too), a KDA one (the gated
-# delta-rule recurrence, ``ops/kda.py``) or an SSD one (a Mamba-2 mixer: the
-# scalar-decay state-space scan, ``ops/ssd.py``).
-WINDOW, GLOBAL, KDA, SSD = LAYER_KIND_SCOPES = ("window", "global", "kda", "ssd")
+# delta-rule recurrence, ``ops/kda.py``), an SSD one (a Mamba-2 mixer: the
+# scalar-decay state-space scan, ``ops/ssd.py``) or a conv one (a gated short
+# convolution and nothing else, ``ops/kda.py::gated_conv``).
+WINDOW, GLOBAL, KDA, SSD, CONV = LAYER_KIND_SCOPES = ("window", "global", "kda", "ssd", "conv")
 
 # Inside ``attention`` / ``kda`` (``models/tinygpt.py::_kda_sublayer``): the
 # projections with their convolutions, SiLU, l2norm, the decay and beta; the
@@ -64,6 +65,12 @@ KDA_PREP, KDA_CORE, KDA_OUT = KDA_SCOPES = ("kda_prep", "kda_core", "kda_out")
 # and the running sums in front of them); the skip, the gated grouped norm and
 # ``out_proj``.
 SSD_PREP, SSD_CORE, SSD_OUT = SSD_SCOPES = ("ssd_prep", "ssd_core", "ssd_out")
+
+# Inside ``attention`` / ``conv`` (``models/tinygpt.py::_conv_sublayer``): the
+# input projection to B | C | x~; the gated convolution C * conv(B * x~) (the
+# Mosaic calls ``sconv_fwd`` / ``sconv_bwd``, or the ``jnp`` chain); the output
+# projection.
+SCONV_IN, SCONV_CORE, SCONV_OUT = SCONV_SCOPES = ("sconv_in", "sconv_core", "sconv_out")
 
 # Inside ``attention`` (below the kind's scope where there is one), where a
 # layer's QK-norm and rotary are ``ops/rotary.py``'s one pass: the two Mosaic
@@ -84,7 +91,7 @@ NOISE = "noise"
 # Every name above: what a scope path read back from a name stack is held to
 # (``utils/residuals.py::scope_path``).
 NAMES = frozenset((*SCOPES, *MOE_SCOPES, SHARED, *MLA_SCOPES, *LAYER_KIND_SCOPES, *KDA_SCOPES,
-                   *SSD_SCOPES,
+                   *SSD_SCOPES, *SCONV_SCOPES,
                    QK_PROLOGUE, ATTN_GATE, NOISE))
 
 

@@ -15,6 +15,15 @@ each: ms a layer forward and forward + backward, the GB/s of the bytes one pass
 needs (x in and q, k, v out; x and three cotangents in and dx out), and
 ``out_err`` / ``grad_err`` against the chain in float32 on the same operands.
 
+``--gated``: a gated short-convolution mixer's middle (``ops.kda.gated_conv``)
+at (2, rows, 3 x 2048) and 3 taps: its two Mosaic calls (``sconv_fwd`` /
+``sconv_bwd``, a line a tile height of ``--gated-rows``) against the ``jnp``
+chain (the two gates and the taps as shifted products, XLA's fusions) and
+against the gates in ``jnp`` around ``causal_conv``'s kernels: ms forward and
+forward + backward, the GB/s of the bytes one pass needs (the operand in and
+the result out; the operand and the cotangent in and the operand's gradient
+out), and ``out_err`` / ``grad_err`` against the chain in float32.
+
 ms a layer's call (the mean of ``--iters`` after a warm-up) and |kernel -
 recurrence| / |recurrence| of the output and the five gradients at
 ``--check-rows`` positions with bfloat16 operands. A pair the chip's compiler
@@ -41,6 +50,8 @@ def main():
     ap.add_argument("--iters", type=int, default=5)
     ap.add_argument("--check-rows", type=int, default=1024)
     ap.add_argument("--prep", action="store_true", help="the chain in front of the recurrence")
+    ap.add_argument("--gated", action="store_true", help="a gated short convolution's middle")
+    ap.add_argument("--gated-rows", default="128", help="tile heights of the gated kernels")
     ap.add_argument("--out")
     args = ap.parse_args()
 
@@ -78,8 +89,8 @@ def main():
     lines = []
     rel = lambda a, b: float(jnp.linalg.norm(a.astype(jnp.float32) - b) / jnp.linalg.norm(b))
     ints = lambda text: [int(c) for c in text.split(",")]
-    if args.prep:
-        lines = prep_lines(args, timed, rel)
+    if args.prep or args.gated:
+        lines = (gated_lines if args.gated else prep_lines)(args, timed, rel)
         return write(args.out, lines)
     loss = lambda f: (lambda *a: jnp.sum(f(*a).astype(jnp.float32) ** 2))
     small, big = operands(args.check_rows, 4), operands(args.rows, args.heads)
@@ -144,6 +155,64 @@ def prep_lines(args, timed, rel):
         line["fwd_bwd_ms"] = timed(jax.jit(jax.grad(loss(path(H)), argnums=(0, 1))), *big)
         line["fwd_gb_s"] = 6 * third / line["fwd_ms"] / 1e6
         line["bwd_gb_s"] = 9 * third / (line["fwd_bwd_ms"] - line["fwd_ms"]) / 1e6
+        print(json.dumps(line), flush=True)
+        lines.append(line)
+    return lines
+
+
+def gated_lines(args, timed, rel):
+    import jax
+    import jax.numpy as jnp
+
+    from distributed_llm_training_benchmark_framework_tpu.ops import kda
+
+    C, K, B = 2048, 3, 2
+
+    def operands(batch, S, dtype=jnp.bfloat16):
+        ks = jax.random.split(jax.random.PRNGKey(0), 2)
+        bcx = jax.random.normal(ks[0], (batch, S, 3 * C)).astype(dtype)
+        bound = K ** -0.5  # the model's initialisation
+        return bcx, jax.random.uniform(ks[1], (K, C), minval=-bound, maxval=bound)
+
+    def chain(dtype):  # the two gates and the taps as shifted products: no kernel
+        def f(bcx, taps):
+            S = bcx.shape[1]
+            b, c, x = (bcx[..., i * C:(i + 1) * C].astype(jnp.float32) for i in range(3))
+            v = jnp.pad(b * x, ((0, 0), (K - 1, 0), (0, 0)))
+            return (c * sum(v[:, i:i + S] * taps[i] for i in range(K))).astype(dtype)
+        return f
+
+    def around_causal_conv(bcx, taps):  # the gates in jnp around the bare convolution's kernels
+        b, c, x = (bcx[..., i * C:(i + 1) * C] for i in range(3))
+        return c * kda.causal_conv(b * x, taps, interpret=False)
+
+    kernels = lambda bcx, taps: kda.gated_conv(bcx, taps, interpret=False)
+    # (name, path, the kernels' tile height where it is theirs)
+    paths = [("jnp_chain", chain(jnp.bfloat16), None),
+             ("gates_around_causal_conv", around_causal_conv, None),
+             *((f"gated_conv_rows{r}", kernels, int(r)) for r in args.gated_rows.split(","))]
+    loss = lambda f: lambda bcx, taps: jnp.sum(f(bcx, taps).astype(jnp.float32) ** 2)
+    small, big = operands(1, args.check_rows), operands(B, args.rows)
+    want = chain(jnp.float32)(*small)
+    want_grads = jax.grad(loss(chain(jnp.float32)), argnums=(0, 1))(*small)
+    third = B * args.rows * C * 2  # bytes of one of b, c, x in bfloat16
+    lines = []
+    for name, path, tile_rows in paths:
+        line = {"path": name, "batch": B, "rows": args.rows, "columns": 3 * C}
+        if tile_rows is not None:
+            kda._GATED_ROWS = tile_rows
+            kda._gated_call.cache_clear()
+        try:
+            grads = jax.jit(jax.grad(loss(path), argnums=(0, 1)))(*small)
+            line["out_err"] = rel(jax.jit(path)(*small), want)
+            line["grad_err"] = [rel(a, b) for a, b in zip(grads, want_grads)]
+            line["fwd_ms"] = timed(jax.jit(path), *big)
+            line["fwd_bwd_ms"] = timed(jax.jit(jax.grad(loss(path), argnums=(0, 1))), *big)
+            line["fwd_gb_s"] = 4 * third / line["fwd_ms"] / 1e6
+            line["bwd_gb_s"] = 7 * third / (line["fwd_bwd_ms"] - line["fwd_ms"]) / 1e6
+        except jax.errors.JaxRuntimeError as e:  # the compiler's refusal: say it, go on
+            text = " ".join(str(e).split())
+            line["error"] = text if len(text) < 600 else text[:200] + " ... " + text[-350:]
         print(json.dumps(line), flush=True)
         lines.append(line)
     return lines

@@ -823,3 +823,57 @@ def test_the_nemotron_cells_stacks_compile_a_block_of_each_kind(v5e_devices, mon
                  "flash_bwd_fused", "jit(gmm)", "jit(tgmm)"):
         assert name in text, name
     assert "attention/ssd/ssd_core" in text and "attention/global" in text
+
+
+def test_the_gated_convolution_compiles_at_the_cells_operand(v5e_devices):
+    """``ops.kda.gated_conv`` over the (2, 16384, 6144) operand of a gated
+    short-convolution mixer, B | C | x~ of 2048 columns each and 3 taps: one
+    Mosaic call each way, the three thirds found by the calls' block specs."""
+    from distributed_llm_training_benchmark_framework_tpu.ops import kda
+
+    one = SingleDeviceSharding(v5e_devices[0])
+    aval = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+
+    def loss(bcx, taps):
+        return jnp.sum(jnp.square(kda.gated_conv(bcx, taps, interpret=False).astype(jnp.float32)))
+
+    text = _compile(jax.grad(loss, argnums=(0, 1)), aval((2, 16384, 6144), jnp.bfloat16),
+                    aval((3, 2048), jnp.float32))
+    assert text.count('custom_call_target="tpu_custom_call"') == 2
+    assert "sconv_fwd" in text and "sconv_bwd" in text
+
+
+def test_the_lfm2_cells_stacks_compile_a_layer_of_each_kind(v5e_devices, monkeypatch):
+    """A gated-convolution layer before the dense 7168 MLP, an attention layer
+    (32 query heads over 8 KV heads of 64 under rotary and per-head QK-norm,
+    causal) and a gated-convolution layer before 8 held experts of 32 at 2048 x
+    1792, two sequences of 16,384, forward and backward under the cell's remat
+    policy: the gated convolution's two kernels, the flash pair at head width
+    64 with k and v at their own head count, the held experts' grouped
+    matmuls."""
+    from distributed_llm_training_benchmark_framework_tpu.models import tinygpt
+    from distributed_llm_training_benchmark_framework_tpu.models.tinygpt import TinyGPTConfig
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    config = TinyGPTConfig(
+        vocab_size=16384, n_embd=2048, n_head=32, n_kv_head=8, n_layer=3, block_size=16384,
+        dropout=0.0, causal=True, attention_impl="flash", scan_layers=False, norm="rmsnorm",
+        pos_embed="rope", rope_theta=1e6, qk_norm="head", mlp_act="swiglu", mlp_hidden=1792,
+        bias=False, tie_embeddings=True, n_experts=32, expert_top_k=4, capacity_factor=None,
+        router_score="sigmoid", router_aux_coef=0.0, first_k_dense=1, dense_mlp_hidden=7168,
+        experts_held=(0, 8), held_rows_factor=1.5, remat="full_keep_kernels",
+        layer_types=("conv", "global", "conv"), conv_taps=3)
+    one = SingleDeviceSharding(v5e_devices[0])
+    shapes = jax.eval_shape(lambda k: tinygpt.init_params(config, k), jax.random.key(0))
+    params = jax.tree.map(lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one), shapes)
+    x = jax.ShapeDtypeStruct((2, 16384, 2048), jnp.bfloat16, sharding=one)
+
+    def loss(params, x):
+        y, _ = tinygpt.apply_layers(config, params, x)
+        return jnp.sum(y.astype(jnp.float32))
+
+    text = _compile(jax.grad(loss), params, x)
+    for name in ("sconv_fwd", "sconv_bwd", "flash_fwd", "flash_bwd_fused", "jit(gmm)",
+                 "jit(tgmm)"):
+        assert name in text, name
+    assert "attention/conv/sconv_core" in text and "attention/global" in text
