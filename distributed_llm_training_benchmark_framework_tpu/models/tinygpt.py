@@ -55,16 +55,11 @@ REMAT_POLICIES = ("none", "dots", "full_keep_kernels", "full")
 #: ``full_keep_kernels`` keeps (``_under_remat``): a KDA layer's q, k, v
 #: projection (``_kda_sublayer``), a dense SwiGLU layer's gate+up
 #: (``_mlp_sublayer``), an SSD (Mamba-2) layer's x | B | C and z products of
-#: ``in_proj`` (``_ssd_sublayer``) and a shared expert's up product where it is
-#: not gated (``moe._shared_experts``).
-KDA_QKV, MLP_GU, SSD_XBC, SSD_Z, SHARED_U = MATMUL_CAST_NAMES = (
-    "kda_qkv", "mlp_gu", "ssd_xbc", "ssd_z", "shared_u")
-
-#: A ``conv`` layer's B | C | x~ projection after its cast (``_conv_sublayer``):
-#: named, so that ``saved_for_backward`` lists it apart, and **not** on the list
-#: ``full_keep_kernels`` keeps: ``_under_remat``'s rule has two clauses, and the
-#: second one (room) refuses it (its docstring has the readings).
-SCONV_BCX = "sconv_bcx"
+#: ``in_proj`` (``_ssd_sublayer``), a shared expert's up product where it is
+#: not gated (``moe._shared_experts``) and a ``conv`` layer's B | C | x~
+#: projection (``_conv_sublayer``).
+KDA_QKV, MLP_GU, SSD_XBC, SSD_Z, SHARED_U, SCONV_BCX = MATMUL_CAST_NAMES = (
+    "kda_qkv", "mlp_gu", "ssd_xbc", "ssd_z", "shared_u", "sconv_bcx")
 
 
 def normalize_remat(value: Any) -> str:
@@ -2024,7 +2019,8 @@ def ssd_stats(config: TinyGPTConfig, seq_len: int) -> Dict[str, Any]:
 def _conv_sublayer(c: TinyGPTConfig, x: jax.Array, layer: Params) -> jax.Array:
     """Norm -> gated short convolution -> residual: a ``conv`` layer's mixer, in
     three scopes. ``sconv_in``: [B | C | x~] = h W_in, one (D, 3 D) product
-    whose result has a name (``SCONV_BCX``: not one a remat policy keeps). ``sconv_core``:
+    whose result after its cast has a name (``SCONV_BCX``: ``full_keep_kernels``
+    keeps it, ``dots`` holds the product itself). ``sconv_core``:
     C * conv(B * x~), the depthwise causal convolution of ``conv_taps``
     positions with zeros before the sequence, no bias and no activation
     (``ops.kda.gated_conv``: on a TPU where ``conv_fits`` one Mosaic call a
@@ -2435,26 +2431,33 @@ def _under_remat(pol: str, block):
     ``MATMUL_CAST_NAMES``: a ``kda`` layer's q, k, v projection (``KDA_QKV``), a
     dense SwiGLU layer's gate+up (``MLP_GU``), an ``ssd`` layer's x | B | C and z
     products of ``in_proj`` (``SSD_XBC``, ``SSD_Z``; dt is 64 columns of float32
-    and has no name) and the up product of a shared expert that is not gated
-    (``SHARED_U``). ``dots`` holds those as their ``dot_general``'s results
+    and has no name), the up product of a shared expert that is not gated
+    (``SHARED_U``) and a ``conv`` layer's B | C | x~ projection
+    (``SCONV_BCX``). ``dots`` holds those as their ``dot_general``'s results
     already and leaves their names out: with them the policy would trade each
     product for its cast, and no second run would go.
 
-    **The rule for the list** (``tests/test_remat_flash.py`` holds it; PERF.md,
-    PRs 50 and 52, has the readings): a value is named only if, in the
-    benchmark cell where it is largest, its second run costs at least 5 ms a
-    step per GB it holds, and every cell keeps 1.5 GB of HBM free with it. A
-    name goes to the value in its compute-dtype or integer form, never to the
-    float32 in front of a cast. A product 2688 deep costs 15 ms a GB of its
-    bfloat16 result (the Nemotron cell's ``in_proj`` and shared up product),
-    three times the rule. By the same rule what a convolution makes of a
-    named product (a ``kda`` layer's q, k, v 4.1 ms a GB, an ``ssd`` layer's
+    **The rule for the list has two clauses** (``tests/test_remat_flash.py``
+    holds the first; PERF.md, PRs 50, 52 and 55, has the readings). (1) A value
+    is named only if, in the benchmark cell where it is largest, its second run
+    costs at least 5 ms a step per GB it holds. (2) With it that cell keeps at
+    least 1.5 GB of HBM free: the allocator's limit (16.909 GB on a v5e) less
+    the peak of the step compiled under the cell's own policy
+    (``hbm_headroom_gb``; ``perfbench/tools/describe_cell.py`` reads the same
+    peak without a chip). Nothing else belongs to the rule: not the margin a
+    cell's policy was picked by when the cell was added. With every name on the
+    list the three cells under ``full_keep_kernels`` and the tightest under
+    ``dots`` keep 2.79 (Kimi), 2.04 (LFM2), 1.87 (Nemotron) and 2.34 GB
+    (DeepSeek). A name goes to the value in its compute-dtype or integer form,
+    never to the float32 in front of a cast. A product 2688 deep costs 15 ms a
+    GB of its bfloat16 result (the Nemotron cell's ``in_proj`` and shared up
+    product) and one 2048 deep 10.9 (the LFM2 cell's ``W_in``): three and two
+    times the rule. By the first clause what a convolution makes of a named
+    product (a ``kda`` layer's q, k, v 4.1 ms a GB, an ``ssd`` layer's
     x | B | C 3.9), the gated shared experts' gate+up (4.0 in the Kimi cell) and
-    ``dispatch``'s gathered rows (1.5) stay dropped. A ``conv`` layer's B | C |
-    x~ projection (``SCONV_BCX``) reads 10.9 ms a GB and stays dropped by the
-    second clause: with it the one cell that produces it is 14.87 GB under
-    ``full_keep_kernels``, over the 14.5 GB line its policy is chosen by, and
-    would fall to ``full`` (PERF.md, PR 54)."""
+    ``dispatch``'s gathered rows (1.5) stay dropped; by the second a ``conv``
+    layer's gated result does (6.0 ms a GB, 0.537 GB in the LFM2 cell: with it
+    beside ``SCONV_BCX`` the cell would keep 1.4997 GB), so it has no name."""
     if pol == "none":
         return block
     if pol == "full":

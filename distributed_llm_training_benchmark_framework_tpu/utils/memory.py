@@ -197,8 +197,9 @@ def estimate_hbm(
             kept += S * ((2 * cfg.ssd_xbc + cfg.ssd_inner) * cbytes + 2 * cfg.ssd_heads * 4)
         kda_b += ssd_layers * B * kept
     # A conv layer (a gated short convolution) keeps nothing of its own but,
-    # without remat, B | C | x~ and the gated result (no policy keeps them by
-    # name: tinygpt.SCONV_BCX is not on the list).
+    # without remat, B | C | x~ and the gated result (under 'full_keep_kernels'
+    # B | C | x~ is named, tinygpt.SCONV_BCX: counted with named_b below; the
+    # gated result has no name).
     conv_layers = (getattr(cfg, "layer_types", None) or ()).count("conv")
     if conv_layers and pol == "none":
         kda_b += conv_layers * B * S * 4 * D * cbytes
@@ -209,7 +210,8 @@ def estimate_hbm(
     # under 'full_keep_kernels' alone ('dots' counts its matmul results
     # below), the wide products of tinygpt.MATMUL_CAST_NAMES: a KDA layer's q,
     # k, v projection, a dense SwiGLU layer's gate+up, an SSD layer's x | B | C
-    # and z, and the up product of a shared expert that is not gated.
+    # and z, the up product of a shared expert that is not gated, and a conv
+    # layer's B | C | x~.
     named_b = 0
     if pol in ("dots", "full_keep_kernels"):
         tokens = B * layer_S
@@ -225,6 +227,7 @@ def estimate_hbm(
         if pol == "full_keep_kernels":
             named_b += kda_layers * tokens * 3 * cfg.kda_heads * cfg.kda_head_dim * cbytes
             named_b += ssd_layers * tokens * (cfg.ssd_xbc + cfg.ssd_inner) * cbytes
+            named_b += conv_layers * tokens * 3 * D * cbytes
             if cfg.mlp_act == "relu2":  # a shared expert that is not gated (shared_dim 0: none)
                 named_b += moe_layers * tokens * cfg.shared_dim * cbytes
             if cfg.mlp_act == "swiglu":

@@ -502,9 +502,9 @@ def test_the_kind_has_a_scope_and_its_three_parts(weights, batch):
     for scope in ("router", "dispatch", "experts", "combine"):
         assert f"mlp/{scope}" in text
     assert "mlp/shared" not in text and f"{GLOBAL}/qk_prologue" not in text  # heads of 64: the chain
-    # the projection has a name, and no remat policy keeps it (_under_remat's second clause)
+    # the projection has a name, which full_keep_kernels keeps (_under_remat's rule; PR 55)
     jaxpr = str(jax.make_jaxpr(lambda p, b: tinygpt.loss_fn(CONFIG, p, b, b))(weights, batch))
-    assert "name=sconv_bcx" in jaxpr and tinygpt.SCONV_BCX not in tinygpt.remat_kept_names()
+    assert "name=sconv_bcx" in jaxpr and tinygpt.SCONV_BCX in tinygpt.remat_kept_names()
 
 
 @pytest.mark.parametrize("change, match", [
@@ -536,7 +536,8 @@ def test_the_pipeline_schedules_refuse_the_stack():
 def test_flops_and_memory_count_the_layers_by_kind():
     """The program's count is the benchmark's (``flops_lfm2``) but for the causal
     pairs' half position; a ``conv`` layer's is the hand count; without remat
-    the memory estimate grows by what a conv layer keeps."""
+    the memory estimate grows by what a conv layer keeps, under
+    ``full_keep_kernels`` by the projection it keeps by name."""
     got, want = flops.forward_flops_per_token(CONFIG), flops_lfm2.forward_flops_per_token(SHAPE)
     half_position = 4 * 0.5 * 4 * 16  # (S + 1) / 2 against S / 2 keys, 4 heads of 16
     assert got == pytest.approx(want - half_position)
@@ -546,9 +547,11 @@ def test_flops_and_memory_count_the_layers_by_kind():
     estimate = lambda config, remat: memory.estimate_hbm(
         dataclasses.replace(config, remat=remat), get_strategy("zero2"), mesh,
         per_device_batch=1, seq_len=SEQ).activations
+    fewer = dataclasses.replace(CONFIG, layer_types=(CONV, GLOBAL, GLOBAL, GLOBAL, CONV))
     kept = 2 * SEQ * 4 * D * 4  # two conv layers' B | C | x~ and gated result (float32 here)
-    assert estimate(CONFIG, "none") - estimate(dataclasses.replace(
-        CONFIG, layer_types=(CONV, GLOBAL, GLOBAL, GLOBAL, CONV)), "none") == kept
+    assert estimate(CONFIG, "none") - estimate(fewer, "none") == kept
+    named = 2 * SEQ * 3 * D * 4  # by name under full_keep_kernels: B | C | x~ alone
+    assert estimate(CONFIG, "full_keep_kernels") - estimate(fewer, "full_keep_kernels") == named
 
 
 def test_sconv_stats_count_layers_calls_and_bytes(monkeypatch):

@@ -19,6 +19,7 @@ from distributed_llm_training_benchmark_framework_tpu.parallel import (
 from distributed_llm_training_benchmark_framework_tpu.models.tinygpt import REMAT_POLICIES
 from distributed_llm_training_benchmark_framework_tpu.utils import memory as mem
 from test_kimi_linear import CONFIG as KIMI_CONFIG
+from test_lfm2 import CONFIG as LFM2_CONFIG
 from test_nemotron import CONFIG as NEMOTRON_CONFIG
 
 
@@ -269,24 +270,30 @@ def test_tier_b_single_chip_paths():
 
 
 # (config, tokens of its one sequence, the activation estimate by policy before PR 52 named an
-# ``ssd`` block's x | B | C and z and the up product of a shared expert that is not gated)
+# ``ssd`` block's x | B | C and z and the up product of a shared expert that is not gated, and
+# PR 55 a ``conv`` layer's B | C | x~)
 READ_BEFORE_THE_NAMES = {
-    # KDA mixers, a gated shared expert: produces neither name
+    # KDA mixers, a gated shared expert: produces none of the names
     "kimi": (KIMI_CONFIG, 64, {"none": 1953792, "dots": 1540096, "full_keep_kernels": 1130496,
                                "full": 475136}),
     # four Mamba-2 blocks and four routed blocks with the shared expert that is not gated
     "nemotron": (NEMOTRON_CONFIG, 32, {"none": 1302528, "dots": 1044480,
                                        "full_keep_kernels": 454656, "full": 266240}),
+    # four gated short convolutions, an attention layer, held experts behind no shared one
+    "lfm2": (LFM2_CONFIG, 32, {"none": 745472, "dots": 671744, "full_keep_kernels": 368640,
+                               "full": 204800}),
 }
 
 
 @pytest.mark.parametrize("remat", REMAT_POLICIES)
 @pytest.mark.parametrize("case", sorted(READ_BEFORE_THE_NAMES))
 def test_the_activation_estimate_grows_by_the_named_products_alone(case, remat):
-    """``full_keep_kernels`` keeps an ``ssd`` block's two wide products of ``in_proj`` and
-    the up product of a shared expert that is not gated by name: the estimate grows by
-    exactly those bytes, and no other policy's and no other config's moves (``dots``
-    holds matmul results already; a gated shared expert's gate+up stays dropped)."""
+    """``full_keep_kernels`` keeps an ``ssd`` block's two wide products of ``in_proj``,
+    the up product of a shared expert that is not gated and a ``conv`` layer's B | C | x~
+    by name: the estimate grows by exactly those bytes, and no other policy's and no other
+    config's moves (``dots`` holds matmul results already; under ``none`` a conv layer
+    keeps what it kept; a gated shared expert's gate+up and a conv layer's gated result
+    stay dropped)."""
     config, tokens, before = READ_BEFORE_THE_NAMES[case]
     got = mem.estimate_hbm(dataclasses.replace(config, remat=remat), get_strategy("zero2"),
                            _mesh(), per_device_batch=1, seq_len=tokens).activations
@@ -295,4 +302,6 @@ def test_the_activation_estimate_grows_by_the_named_products_alone(case, remat):
         # four ssd blocks x tokens x (x | B | C 128 + z 64) and four routed blocks x tokens x
         # the shared expert's 48 columns, in float32 (the test configs' compute dtype)
         named = 4 * 32 * (128 + 64) * 4 + 4 * 32 * 48 * 4
+    if case == "lfm2" and remat == "full_keep_kernels":
+        named = 4 * 32 * 3 * 64 * 4  # four conv layers x tokens x 3 D columns, in float32
     assert got == before[remat] + named

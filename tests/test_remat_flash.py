@@ -9,11 +9,12 @@ in the backward pass. They carry ``checkpoint_name``s
 names beside the matmul results; since PR 50 the same list
 (``tinygpt.remat_kept_names``) holds the routed experts' gate+up grouped
 matmul's result, the router's logits, choice and plan, a KDA layer's q, k, v
-projection and a dense SwiGLU layer's gate+up, and since PR 52 an SSD layer's
+projection and a dense SwiGLU layer's gate+up, since PR 52 an SSD layer's
 x | B | C and z products and the up product of a shared expert that is not
-gated. These tests count calls in the
-gradient's jaxpr (walking it: shared sub-jaxprs print once in its text), hold
-both policies to ``none``'s loss and gradients, and hold the list to its rule.
+gated, and since PR 55 a ``conv`` layer's B | C | x~ projection. These tests
+count calls in the gradient's jaxpr (walking it: shared sub-jaxprs print once
+in its text), hold both policies to ``none``'s loss and gradients, and hold the
+list to its rule.
 """
 
 import dataclasses
@@ -48,9 +49,10 @@ from distributed_llm_training_benchmark_framework_tpu.parallel import (
 )
 from distributed_llm_training_benchmark_framework_tpu.train import create_train_state
 from distributed_llm_training_benchmark_framework_tpu.utils import scopes
-from perfbench.harness import build_kda, build_nemotron
+from perfbench.harness import build_kda, build_lfm2, build_nemotron
 from test_deepseek import CONFIG as MLA_CONFIG
 from test_kimi_linear import FILE as KIMI_FILE
+from test_lfm2 import FILE as LFM2_FILE
 from test_nemotron import FILE as NEMOTRON_FILE
 
 SEQ, BATCH = 64, 2
@@ -84,6 +86,15 @@ CONFIGS = {
             dict(seq_len=SEQ, held_rows_factor=4.0, attention="flash", layer_loop="unrolled"),
             {**NEMOTRON_FILE, "mamba_num_heads": 6}),
         compute_dtype=jnp.float32),
+    # the LFM2 cell's five layers: a gated short convolution in front of a dense
+    # SwiGLU layer, an attention layer at heads of 16 under per-head QK-norm and
+    # rotary, three more convolutions, the last four in front of held experts
+    # chosen by sigmoid score; unrolled as ``kda``.
+    "conv": dataclasses.replace(
+        build_lfm2.lfm2_config(
+            dict(seq_len=SEQ, held_rows_factor=4.0, attention="flash", layer_loop="unrolled"),
+            LFM2_FILE),
+        compute_dtype=jnp.float32),
     # every expert on the chip, routing trained: the sort by expert and back.
     "dropless": TinyGPTConfig(
         vocab_size=128, n_embd=64, n_head=4, n_layer=2, block_size=SEQ, mlp_hidden=32,
@@ -94,9 +105,9 @@ CONFIGS = {
 }
 CASES = sorted(CONFIGS)
 LOOPS = {"unrolled": False, "scan": True}
-# (case, loop) a test runs: the ``kda`` and ``ssd`` stacks have one loop
+# (case, loop) a test runs: the ``kda``, ``ssd`` and ``conv`` stacks have one loop
 RUNS = [(case, loop) for case in CASES for loop in sorted(LOOPS)
-        if (case, loop) not in (("kda", "scan"), ("ssd", "scan"))]
+        if (case, loop) not in (("kda", "scan"), ("ssd", "scan"), ("conv", "scan"))]
 KEEPING = ("dots", "full_keep_kernels")
 
 
@@ -137,7 +148,7 @@ def _kernel_runs(jaxpr, name):
 
 def _flash_layers(config):
     kinds = config.layer_types or ()
-    return sum(config.halves(kind)[0] and kind not in (scopes.KDA, scopes.SSD)
+    return sum(config.halves(kind)[0] and kind not in (scopes.KDA, scopes.SSD, scopes.CONV)
                for kind in kinds) if kinds else config.n_layer
 
 
@@ -197,7 +208,7 @@ def _routed_counts(case, loop, remat):
 # grouped matmuls a routed layer's second run adds to what no remat runs (until PR 50
 # gate+up's, one more): where the routing trains, the gates' gradient reads the experts'
 # output, which stays dropped, so the down matmul runs again
-GMMS_AGAIN = {"mla": 0, "kda": 0, "ssd": 0, "dropless": 1}
+GMMS_AGAIN = {"mla": 0, "kda": 0, "ssd": 0, "conv": 0, "dropless": 1}
 
 
 @pytest.mark.parametrize("remat", KEEPING + ("full",))
@@ -225,6 +236,7 @@ PRODUCTS = {
     "ssd_x_b_c": ("ssd", scopes.SSD, lambda c: (c.n_embd, c.ssd_xbc)),
     "ssd_z": ("ssd", scopes.SSD, lambda c: (c.n_embd, c.ssd_inner)),
     "shared_up": ("ssd", scopes.MLP, lambda c: (c.n_embd, c.shared_dim)),
+    "sconv_b_c_x": ("conv", scopes.CONV, lambda c: (c.n_embd, 3 * c.n_embd)),
 }
 
 
@@ -248,7 +260,9 @@ def test_the_list_is_one_and_names_what_the_rule_allows():
     """``_under_remat``'s rule: a value is named only if its second run costs at
     least 5 ms a step per GB it holds in the benchmark cell where it is largest.
     The readings are PERF.md's (section 5, "Memory by scope", my chip runs, PRs
-    50 and 52): a new name comes with its own, and one that reads under 5 goes."""
+    50, 52 and 54): a new name comes with its own, and one that reads under 5 goes.
+    The rule's second clause (1.5 GB of HBM free in that cell with the name kept) is
+    read on the compiled step, ``hbm_headroom_gb``: no test here can hold it."""
     ms_a_gb = {moe.MOE_GU: 6.6,  # sdar-30b-a3b.share8-bd8192: 4.95 ms for 0.755 GB
                moe.ROUTER_LOGITS: 1000.0, moe.ROUTER_CHOICE: 1000.0,  # kimi: 8.78 ms, 17 MB
                moe.MOE_PLAN: 1000.0,  # mellum2: 7.5 ms with combine's backward, 3 MB
@@ -258,7 +272,10 @@ def test_the_list_is_one_and_names_what_the_rule_allows():
                # 52), four blocks each:
                tinygpt.SSD_XBC: 14.9,  # 12.02 ms (4 x 3.005) for 0.805 GB
                tinygpt.SSD_Z: 15.4,  # 8.28 ms (in_proj's 20.33 less x | B | C's and dt's) for 0.537 GB
-               tinygpt.SHARED_U: 14.5}  # 7.05 ms (4 x 1.763) for 0.487 GB
+               tinygpt.SHARED_U: 14.5,  # 7.05 ms (4 x 1.763) for 0.487 GB
+               # lfm2-8b-a1b.share4-seq16384, the parent traced (my chip run, PR 54, seed
+               # 5400000101: scope ``sconv_in`` under remat), four layers:
+               tinygpt.SCONV_BCX: 10.9}  # 17.6 ms (4 x 4.4) for 1.611 GB
     names = tinygpt.remat_kept_names()
     assert len(set(names)) == len(names)
     assert set(names) == {*FLASH_RESIDUAL_NAMES, *KDA_RESIDUAL_NAMES, *SSD_RESIDUAL_NAMES, *ms_a_gb}

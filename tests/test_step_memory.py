@@ -30,8 +30,9 @@ from distributed_llm_training_benchmark_framework_tpu.train.step import (
     abstract_compile_step, create_train_state,
 )
 from distributed_llm_training_benchmark_framework_tpu.utils import memory, residuals, scopes
-from perfbench.harness import build_kda, build_nemotron
+from perfbench.harness import build_kda, build_lfm2, build_nemotron
 from tests.test_kimi_linear import FILE as KIMI_FILE
+from tests.test_lfm2 import FILE as LFM2_FILE
 from tests.test_nemotron import FILE as NEMOTRON_FILE
 
 SEQ, BATCH = 128, 4
@@ -52,6 +53,8 @@ KIMI = build_kda.kimi_config(KIMI_JOB, KIMI_FILE)
 NEMOTRON = build_nemotron.nemotron_config(
     dict(seq_len=SEQ, held_rows_factor=4.0, attention="flash", layer_loop="unrolled"),
     NEMOTRON_FILE)
+LFM2 = build_lfm2.lfm2_config(
+    dict(seq_len=SEQ, held_rows_factor=4.0, attention="flash", layer_loop="unrolled"), LFM2_FILE)
 
 
 def mesh_of(**sizes):
@@ -142,8 +145,8 @@ def test_a_routed_config_shows_the_experts():
 
 @functools.lru_cache(maxsize=None)
 def saved_of(which, policy):
-    """``saved()`` of the routed or the Kimi-shaped config's step under ``policy``."""
-    config, batch = {"routed": (ROUTED, BATCH), "kimi": (KIMI, 1)}[which]
+    """``saved()`` of the routed, the Kimi- or the LFM2-shaped config's step under ``policy``."""
+    config, batch = {"routed": (ROUTED, BATCH), "kimi": (KIMI, 1), "lfm2": (LFM2, 2)}[which]
     return compiled_step(config, policy, batch=batch)[0]["saved"]()
 
 
@@ -208,6 +211,32 @@ def test_the_kimi_layers_named_values_by_scope_and_bytes(policy):
     assert {e[0] for e in gu} <= {(scopes.MLP, scopes.EXPERTS)}
 
 
+@pytest.mark.parametrize("policy", POLICIES)
+def test_a_conv_layers_projection_by_scope_and_bytes(policy):
+    """A ``conv`` layer's B | C | x~ projection, 3 D columns a token in the compute
+    dtype: by name under ``full_keep_kernels``; under ``dots`` as its ``dot_general``'s
+    float32 result and under no name; nowhere under ``full``. The gated convolution's
+    own residuals (off a TPU the ``jnp`` chain's: the thirds and their products, at
+    least the 4 D columns a token the estimate counts) are kept without remat alone."""
+    kept = saved_of("lfm2", policy)["kept"]
+    layers, tokens, D = LFM2.layer_types.count(scopes.CONV), 2 * SEQ, LFM2.n_embd
+    bcx = named(kept, tinygpt.SCONV_BCX)
+    into = (scopes.ATTENTION, scopes.CONV, scopes.SCONV_IN)
+    products = [e for e in kept if e[:3] == (into, "dot_general", (2, SEQ, 3 * D))]
+    core = [e for e in kept if e[0] == (scopes.ATTENTION, scopes.CONV, scopes.SCONV_CORE)]
+    if policy == "full_keep_kernels":
+        assert total(bcx) == layers * tokens * 3 * D * 2 and len(bcx) == layers == 4
+        assert {e[0] for e in bcx} == {into} and {e[3] for e in bcx} == {"bfloat16"}
+    else:
+        assert not bcx
+    assert len(products) == (layers if policy == "dots" else 0)
+    if policy == "none":
+        assert total(core) >= layers * tokens * 4 * D * 2
+    else:
+        assert not core
+    assert saved_of("lfm2", policy)["all"] == saved_of("lfm2", "none")["kept"]
+
+
 def _jaxprs(jaxpr):
     """``jaxpr`` and every jaxpr inside it."""
     yield jaxpr
@@ -233,7 +262,8 @@ def _names_in_front_of_a_cast(jaxpr, names):
     return found
 
 
-@pytest.mark.parametrize("which", ("routed", "kimi", "nemotron", "a name in front of its cast"))
+@pytest.mark.parametrize("which", ("routed", "kimi", "nemotron", "lfm2",
+                                   "a name in front of its cast"))
 def test_no_name_is_given_to_a_float32_in_front_of_its_cast(which):
     """A name keeps the value it is given: on the float32 product in front of
     ``.astype(bfloat16)`` it would hold twice the bytes the backward reads."""
@@ -244,7 +274,7 @@ def test_no_name_is_given_to_a_float32_in_front_of_its_cast(which):
         assert _names_in_front_of_a_cast(jax.make_jaxpr(wrong)(x, x).jaxpr, names) == [names[-1]]
         return
     config = dataclasses.replace(
-        {"routed": ROUTED, "kimi": KIMI, "nemotron": NEMOTRON}[which],
+        {"routed": ROUTED, "kimi": KIMI, "nemotron": NEMOTRON, "lfm2": LFM2}[which],
         compute_dtype=jnp.bfloat16, remat="none")
     params = jax.eval_shape(lambda: tinygpt.init_params(config, jax.random.key(0)))
     batch = jax.ShapeDtypeStruct((1, SEQ), jnp.int32)
@@ -253,7 +283,8 @@ def test_no_name_is_given_to_a_float32_in_front_of_its_cast(which):
             if e.primitive.name == "name"}
     assert seen >= ({moe.MOE_GU, moe.ROUTER_LOGITS} | {
         "kimi": {tinygpt.KDA_QKV, tinygpt.MLP_GU},
-        "nemotron": {tinygpt.SSD_XBC, tinygpt.SSD_Z, tinygpt.SHARED_U}}.get(which, set()))
+        "nemotron": {tinygpt.SSD_XBC, tinygpt.SSD_Z, tinygpt.SHARED_U},
+        "lfm2": {tinygpt.SCONV_BCX, tinygpt.MLP_GU}}.get(which, set()))
     assert _names_in_front_of_a_cast(jaxpr, names) == []
 
 
