@@ -118,6 +118,7 @@ from jax.experimental.pallas.ops.tpu.megablox.gmm import tgmm
 from jax.sharding import PartitionSpec as P
 
 from ..utils import scopes
+from .common import SHARED_U, _dropout
 
 
 #: ``jax.ad_checkpoint.checkpoint_name``s of what the dropless routed layer makes
@@ -196,8 +197,6 @@ def _aux_stats(probs: jax.Array, expert_idx: jax.Array, E: int):
 
 def _moe_mlp_einsum(c, layer, x, dropout_key, deterministic):
     """GSPMD formulation: dense einsums, sharding left to the partitioner."""
-    from .tinygpt import _dropout
-
     B, S, D = x.shape
     N = B * S
     E = c.n_experts
@@ -235,8 +234,6 @@ def _moe_mlp_a2a(c, layer, x, dropout_key, deterministic, mesh, ep, dp):
     local experts per member. Two ``lax.all_to_all`` hops exchange the
     per-source-capacity buffers; the expert FFN runs on (E/ep, ep*C, D).
     """
-    from .tinygpt import _dropout
-
     B, S, D = x.shape
     E, K = c.n_experts, c.expert_top_k
     E_loc = E // ep
@@ -490,14 +487,12 @@ def _experts_dropless(c, layer, rows: jax.Array, counts: jax.Array) -> jax.Array
 def _shared_experts(c, layer, x: jax.Array) -> jax.Array:
     """The shared experts as one SwiGLU, (B, S, D) -> (B, S, D): gate columns
     then up columns in one matrix, as the routed experts store theirs; or one
-    expert that is not gated, whose up product keeps a name (``tinygpt.SHARED_U``:
+    expert that is not gated, whose up product keeps a name (``common.SHARED_U``:
     remat ``full_keep_kernels`` keeps it; the gated one's gate+up stays dropped,
     ``tinygpt._under_remat`` has both readings)."""
     cd = c.compute_dtype
     Fs = layer["shared_wd"].shape[0]
     if "shared_wu" in layer:  # not gated: W_down relu(W_up h)^2
-        from .tinygpt import SHARED_U
-
         u = checkpoint_name(jnp.einsum(
             "bsd,df->bsf", x, layer["shared_wu"].astype(cd), preferred_element_type=jnp.float32
         ).astype(cd), SHARED_U)
@@ -658,8 +653,6 @@ def _moe_mlp_held(c, layer, x, dropout_key, deterministic):
     -> (y, aux): aux is the load-balance scalar, or with ``held_rows_factor``
     (3,): that, the rows the buffer took and the held assignments that did
     not fit."""
-    from .tinygpt import _dropout
-
     B, S, D = x.shape
     N = B * S
     xt = x.reshape(N, D)
@@ -688,8 +681,6 @@ def _moe_mlp_held(c, layer, x, dropout_key, deterministic):
 
 def _moe_mlp_dropless(c, layer, x, dropout_key, deterministic):
     """Sort by expert, grouped matmuls, weighted sum back (module docstring)."""
-    from .tinygpt import _dropout
-
     B, S, D = x.shape
     N, K = B * S, c.expert_top_k
     xt = x.reshape(N, D)

@@ -33,6 +33,7 @@ tier A = 1024d/16h/16L (~236M params with tied embeddings), tier B =
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import functools
 import math
@@ -46,20 +47,28 @@ from jax.ad_checkpoint import checkpoint_name
 from jax.sharding import PartitionSpec as P
 
 from ..utils import scopes
+from . import mixers
+from .common import MLP_GU, Params, _dropout, _norm
+from .common import normal as _normal
+from .mixers.attention import qk_prologue_tables
 
-Params = Dict[str, Any]
+# For ``perfbench/`` alone, which reads these from here (``tinygpt.kda_stats``, ...): the
+# next ``benchmark`` PR can read each from its home in ``models/mixers/`` and drop this block.
+from .mixers.attention import attn_mask_stats, bd_mask_stats, qk_prologue_stats  # noqa: F401
+from .mixers.attention import sublayer as _attention_sublayer  # noqa: F401
+from .mixers.conv import sconv_stats  # noqa: F401
+from .mixers.kda import kda_stats  # noqa: F401
+from .mixers.ssd import ssd_stats  # noqa: F401
 
 REMAT_POLICIES = ("none", "dots", "full_keep_kernels", "full")
 
 #: ``checkpoint_name``s of matmul results in their compute-dtype form, which
-#: ``full_keep_kernels`` keeps (``_under_remat``): a KDA layer's q, k, v
-#: projection (``_kda_sublayer``), a dense SwiGLU layer's gate+up
-#: (``_mlp_sublayer``), an SSD (Mamba-2) layer's x | B | C and z products of
-#: ``in_proj`` (``_ssd_sublayer``), a shared expert's up product where it is
-#: not gated (``moe._shared_experts``) and a ``conv`` layer's B | C | x~
-#: projection (``_conv_sublayer``).
-KDA_QKV, MLP_GU, SSD_XBC, SSD_Z, SHARED_U, SCONV_BCX = MATMUL_CAST_NAMES = (
-    "kda_qkv", "mlp_gu", "ssd_xbc", "ssd_z", "shared_u", "sconv_bcx")
+#: ``full_keep_kernels`` keeps (``_under_remat``): the mixers' wide products
+#: (each module's ``CAST_NAMES``: a KDA layer's q, k, v projection, an SSD
+#: layer's x | B | C and z, a ``conv`` layer's B | C | x~), a dense SwiGLU
+#: layer's gate+up (``MLP_GU``, ``_mlp_sublayer``) and a shared expert's up
+#: product where it is not gated (``common.SHARED_U``, ``moe._shared_experts``).
+MATMUL_CAST_NAMES = mixers.MATMUL_CAST_NAMES
 
 
 def normalize_remat(value: Any) -> str:
@@ -126,30 +135,16 @@ class YarnScaling:
 
 
 #: The kinds of layer a stack can mix (``TinyGPTConfig.layer_types``); a kind
-#: chooses the layer's mixer: ``global`` is softmax attention over every
-#: earlier position (latent attention where the config has it), ``window``
-#: over the last ``sliding_window`` of them, ``kda`` the gated delta-rule
-#: recurrence (``_kda_sublayer``), ``ssd`` a Mamba-2 mixer (the scalar-decay
-#: state-space scan, ``_ssd_sublayer``), ``conv`` a gated short convolution
-#: (``_conv_sublayer``), each with leaves of its own. Also the
-#: names of their scopes under ``attention``. ``mlp`` is no mixer: under
-#: ``block_halves`` a block of that kind is the feed-forward part alone (scope
-#: ``mlp``), and a block of a mixer's kind the mixer alone.
+#: chooses the layer's mixer, a module of ``models/mixers/`` (``mixers.MIXERS``):
+#: ``global`` is softmax attention over every earlier position, ``window`` over
+#: the last ``sliding_window``, ``kda`` the gated delta rule, ``ssd`` a Mamba-2
+#: mixer, ``conv`` a gated short convolution. Also the names of their scopes
+#: under ``attention``. ``mlp`` is no mixer: under ``block_halves`` a block of
+#: that kind is the feed-forward part alone, a mixer's kind the mixer alone.
+#: Layers of one stack of the parameter tree have equal leaves (``layer_groups``: by the
+#: mixer, ``mixers.STACKS``, a leading dense MLP and, under ``layer_heads``, the kind).
 LAYER_KINDS = (scopes.GLOBAL, scopes.WINDOW, scopes.KDA, scopes.SSD, scopes.MLP, scopes.CONV)
 
-#: The stacks of the parameter tree, by (the mixer's prefix: ``kda_``, ``conv_``
-#: or none for attention; the MLP is a leading dense one): layers of one stack
-#: have equal leaves (``TinyGPTConfig.layer_groups``). Every name ends in
-#: ``blocks``. Where the attention kinds differ in head count (``layer_heads``)
-#: a stack's name takes its kind in front: ``global_dense_blocks``,
-#: ``window_blocks``.
-_OWN_MIXER = {scopes.KDA: "kda_", scopes.CONV: "conv_"}  # a kind's stacks' prefix
-_STACK_NAMES = {(own, dense): own + ("dense_blocks" if dense else "blocks")
-                for own in ("", *_OWN_MIXER.values()) for dense in (False, True)}
-
-#: The kinds of layer that have no softmax attention: no mask rule, no rotary
-#: table, no flash kernel.
-_NO_ATTENTION = frozenset((scopes.KDA, scopes.SSD, scopes.MLP, scopes.CONV))
 
 
 @dataclasses.dataclass(frozen=True)
@@ -200,36 +195,21 @@ class TinyGPTConfig:
     attention_impl: str = "reference"
     param_dtype: Any = jnp.float32
     compute_dtype: Any = jnp.bfloat16
-    # Per-layer rematerialization policy inside the scan:
-    #   "none" — save every intermediate (fastest, most memory);
-    #   "dots" — jax.checkpoint keeping what is dear to redo and cheap to
-    #            keep: matmul outputs (dot_general without batch dims) and
-    #            the values `remat_kept_names` lists (the flash kernel's out
-    #            and lse, a kda layer's output and chunk states, the routed
-    #            experts' gate+up grouped matmul's result, the router's
-    #            logits, choice and plan); only cheap elementwise/norm work
-    #            (and reference attention's batched products) is recomputed
-    #            in backward (the low-tax middle ground);
-    #   "full_keep_kernels" — jax.checkpoint keeping only the named values:
-    #            the list above and, by name where "dots" has the matmul's
-    #            own result, a kda layer's q, k, v projection and a dense
-    #            SwiGLU layer's gate+up (`_under_remat` has the rule for the
-    #            list: dear to run again, cheap to hold);
-    #   "full" — all-or-nothing jax.checkpoint per layer (least memory,
-    #            ~full forward recompute in backward).
-    # Booleans are accepted for backward compatibility (True="full").
+    # Per-layer rematerialization policy (`_under_remat` has the rule): "none"
+    # saves every intermediate; "dots" keeps matmul outputs and the values
+    # `remat_kept_names` lists (the kernels' results, the router's); only cheap
+    # elementwise/norm work is recomputed; "full_keep_kernels" keeps the named
+    # values alone, with the wide products of MATMUL_CAST_NAMES; "full" keeps
+    # nothing (~a second forward in backward). A bool is accepted (True="full").
     remat: Any = "none"
-    # lax.scan over stacked layer weights (one compiled block body, fast
-    # compile, what pipeline sharding needs) vs an unrolled Python loop
-    # (16x the HLO, but activations save as distinct buffers instead of
-    # dynamic-update-slice stacking — a tuning surface for single-chip runs).
+    # lax.scan over stacked layer weights (one compiled block body, what
+    # pipeline sharding needs) vs an unrolled Python loop (16x the HLO, but
+    # activations save as distinct buffers, not dynamic-update-slice stacking).
     scan_layers: bool = True
-    # Set (to the mesh axis name, e.g. 'seq') by the pipeline schedules when
-    # they run their shard_map manually over the sequence axis: activations
-    # then carry LOCAL sequence chunks, attention dispatches to the
-    # *_sharded ring/Ulysses bodies (which communicate over this axis), the
-    # positional embedding is offset by the shard index, and per-shard
-    # dropout streams are decorrelated. None = ordinary (auto/GSPMD) mode.
+    # Set (to the mesh axis name, 'seq') by the pipeline schedules when their
+    # shard_map is manual over the sequence axis: activations carry LOCAL
+    # chunks, attention runs the *_sharded ring/Ulysses bodies, positions are
+    # offset by the shard index and dropout streams decorrelated a shard.
     seq_manual_axis: Optional[str] = None
     # Mixture-of-Experts MLP (0 = dense). When > 0 every block's MLP becomes
     # a top-k routed expert layer (models.moe) and the training loss gains
@@ -250,22 +230,17 @@ class TinyGPTConfig:
     # units of router_aux_coef, which must then be > 0.
     router_z_coef: float = 0.0
     # Zigzag causal load balancing on ring attention: None = auto (on for
-    # causal rings with even local shards — ops/ring_attention.py), True =
-    # force (errors when the geometry can't), False = force the contiguous
-    # layout. The off switch exists for the scaling-day A/B microbench
-    # (zigzag's benefit is multi-chip wall-clock, unmeasurable single-chip).
+    # causal rings with even local shards, ops/ring_attention.py), True = force
+    # (errors when the geometry can't), False = the contiguous layout.
     ring_zigzag: Optional[bool] = None
-    # Aux channel content: 'switch' (the load-balance loss term, default)
-    # or 'overflow' (fraction of (token, choice) assignments dropped by the
-    # capacity limit) — the latter powers the moe_overflow_fraction
-    # diagnostic without widening the aux carry through every schedule.
+    # Aux channel content: 'switch' (the load-balance loss term) or 'overflow'
+    # (the fraction of assignments the capacity limit dropped: the
+    # moe_overflow_fraction diagnostic, without a wider aux carry).
     moe_aux_mode: str = "switch"
-    # Expert-parallel dispatch: 'auto' uses the explicit all-to-all
-    # shard_map path whenever an 'expert' mesh axis (>1) is in scope and
-    # the geometry allows it, falling back to the GSPMD einsum formulation
-    # (models.moe module docstring — the partitioner does NOT lower the
-    # dispatch einsums to all-to-all on its own). 'alltoall' forces the
-    # explicit path (raises if the geometry can't), 'einsum' forces GSPMD.
+    # Expert-parallel dispatch (the capacity path; models.moe's docstring):
+    # 'auto' takes the explicit all-to-all shard_map path where an 'expert'
+    # axis (>1) is in scope and the geometry allows, else the GSPMD einsums;
+    # 'alltoall' forces the first (raises if it can't), 'einsum' the second.
     moe_dispatch: str = "auto"
     # ------------------------------------------------------------------
     # Architecture-family knobs (models.llama sets these; the defaults
@@ -375,31 +350,23 @@ class TinyGPTConfig:
     # head a token from the sublayer's normed input, times that head's output
     # before wo (leaf wg (D, heads), no bias; scope 'attn_gate').
     attn_gate: bool = False
-    # A ``kda`` layer's sizes (Kimi Delta Attention, Kimi Linear): heads of
-    # kda_head_dim keys and as many values, a depthwise causal convolution of
-    # kda_conv positions after each of the q, k, v projections, the recurrence
-    # in chunks of kda_chunk positions (``ops/kda.py``). The two low-rank maps
-    # (the decay's and the output gate's) have rank kda_head_dim.
+    # A ``kda`` layer's sizes (``mixers/kda.py``): heads of kda_head_dim keys
+    # and values, kda_conv taps behind each of q, k, v, chunks of kda_chunk
+    # positions; the decay's and the gate's low-rank maps have rank kda_head_dim.
     kda_heads: int = 0
     kda_head_dim: int = 0
     kda_conv: int = 4
     kda_chunk: int = 128  # ops.kda.DEFAULT_CHUNK: measured there
-    # An ``ssd`` layer's sizes (a Mamba-2 mixer, Nemotron-H): ssd_heads heads of
-    # ssd_head_dim channels (d_inner = their product), B and C of ssd_state
-    # columns shared by the heads of each of ssd_groups groups, a depthwise
-    # causal convolution of ssd_conv taps with a bias over x | B | C, the scan
-    # in chunks of ssd_chunk positions (``ops/ssd.py``), a gated RMSNorm over
-    # each group's d_inner / ssd_groups channels.
+    # An ``ssd`` layer's sizes (``mixers/ssd.py``): ssd_heads heads of
+    # ssd_head_dim channels, B and C of ssd_state columns a group of ssd_groups,
+    # ssd_conv taps over x | B | C, chunks of ssd_chunk positions.
     ssd_heads: int = 0
     ssd_head_dim: int = 0
     ssd_groups: int = 1
     ssd_state: int = 0
     ssd_conv: int = 4
     ssd_chunk: int = 128  # the family's published chunk_size
-    # A ``conv`` layer's taps (a gated short convolution, LFM2's conv_L_cache):
-    # [B | C | x~] = h W_in (n_embd -> 3 n_embd), a depthwise causal
-    # convolution of conv_taps positions over B * x~, no bias and no activation,
-    # the result times C, then W_out (``ops/kda.py::gated_conv``).
+    # A ``conv`` layer's taps (``mixers/conv.py``; LFM2's conv_L_cache).
     conv_taps: int = 3
     # Each block of the stack is one sublayer alone behind its own norm and
     # residual (Nemotron-H's hybrid_override_pattern): a block of a mixer's kind
@@ -426,57 +393,28 @@ class TinyGPTConfig:
     # Weight-tied LM head (reference train_harness.py:61-62). False adds a
     # separate 'lm_head' (V, D) leaf (Llama unties).
     tie_embeddings: bool = True
-    # ZeRO-2 per-block gradient placement (round 8): a sorted tuple of
-    # (block leaf name, PartitionSpec-for-one-layer-slice) pairs, set by
-    # the train step for sharded-grad/replicated-param strategies. When
-    # present, apply_blocks wraps each layer's weights in an identity
-    # whose COTANGENT carries the sharding constraint — so every layer's
-    # grad reduce-scatter issues INSIDE the backward layer loop, right
-    # after that layer's backward matmuls, instead of as one tail bundle
-    # after the whole backward. That is what lets XLA's latency-hiding
-    # scheduler overlap grad comms with the next layer's backward compute
-    # (DeepSpeed ZeRO's bucketed overlap, GSPMD-native). A tuple (not a
-    # dict) so the config stays hashable.
+    # The step's placement decisions, set by the train step (train/step.py), not by
+    # a model's author. block_grad_spec / block_param_spec: sorted tuples of
+    # (block leaf name, PartitionSpec of one layer's slice) that the layer loops
+    # apply to each layer's weights, the first to the COTANGENT (zero2: every
+    # layer's grad reduce-scatter issues inside the backward loop), the second to
+    # the weights at their use (fsdp / zero3: the all-gather issues per block); a
+    # tuple so that the config stays hashable (`_constrain_layer`).
     block_grad_spec: Any = None
-    # FSDP/ZeRO-3 per-block parameter placement (round 15) — the forward-side
-    # dual of block_grad_spec: a sorted tuple of (block leaf name,
-    # PartitionSpec-for-one-layer-slice) pairs, set by the train step for
-    # sharded-param strategies (train/step.py::fsdp_block_param_spec). When
-    # present, apply_blocks pins each layer's weight SLICE to its sharded
-    # placement INSIDE the forward layer loop — so the weight all-gather the
-    # matmul needs issues per block, right before that block's dots, instead
-    # of being free to bundle ahead of the whole layer stack (the structure
-    # XLA's latency-hiding scheduler needs to overlap weight gathers with
-    # adjacent blocks' forward compute; FSDP's prefetch-one-block schedule,
-    # GSPMD-native). Transposes to the same per-block constraint on the
-    # cotangent — exactly the fsdp/zero3 per-block grad placement.
     block_param_spec: Any = None
-    # Scan-carry activation placement (round 15): a PartitionSpec for the
-    # (B, S, D) residual stream carried through the layer scan, set by the
-    # train step (train/step.py::scan_carry_spec) for scanned sharded-param
-    # arms on composed dp x tp meshes. Without it XLA picks its own layout
-    # for the scan's stacked activation stash and reconciles per iteration
-    # with collective-permute chains (the banked llama-fsdp-dp4-tp2-scan
-    # replication-reshard residue); pinning the carry at the body boundary
-    # pins the stash layout with it.
+    # A PartitionSpec for the (B, S, D) residual stream the layer scan carries:
+    # pins the backward's stacked activation stash with it (`apply_blocks`).
     scan_carry_spec: Any = None
-    # MLP hidden-activation placement: P(batch, seq, hidden) for the F-wide
-    # intermediates between the MLP's two projections, set by the train step
-    # (train/step.py::mlp_hidden_spec) where the strategy shards the first
-    # projection's weight over 'data' along F. The activations then take the
-    # placement of the weight shards they meet, so wgu / wfc / wproj are used
-    # where they live and only (B, S, D) activations travel. Left to
-    # propagation, the partitioner runs the matmuls in this layout and the
-    # elementwise ops between them in the batch layout, with an all-to-all
-    # at every crossing (mistral-7b.fsdp4: 48 a step, 12 % of the step).
+    # P(batch, seq, hidden) for the F-wide intermediates between the MLP's two
+    # projections where the strategy shards the first projection's weight over
+    # 'data' along F: the activations take the placement of the weight shards they
+    # meet and only (B, S, D) travels (`_pin_mlp_hidden`; left to propagation,
+    # mistral-7b.fsdp4 ran 48 all-to-alls a step at the crossings).
     mlp_hidden_spec: Any = None
-    # Collective-matmul tp fusion (round 15, ops/collective_matmul.py): when
-    # True and a >1 'model' mesh axis is in scope, the tp projections
-    # (attention qkv/out, MLP up/down) run as shard_map-decomposed matmuls —
-    # the activation all-gather/reduce-scatter split into per-shard chunks
-    # rotated by ppermute so the comms hide INSIDE the dot, and the residual
-    # stream between projections rides sequence-sharded over 'model'
-    # (Megatron sequence-parallel layout). Opt-in via --tp-collective-matmul.
+    # Collective-matmul tp fusion (ops/collective_matmul.py): with a >1 'model'
+    # axis in scope the tp projections run as shard_map-decomposed matmuls whose
+    # all-gather / reduce-scatter chunks rotate by ppermute inside the dot, the
+    # residual stream sequence-sharded over 'model'. --tp-collective-matmul.
     tp_collective_matmul: bool = False
 
     @property
@@ -552,14 +490,6 @@ class TinyGPTConfig:
         return dict(self.layer_heads or ()).get(kind, self.n_head)
 
     @property
-    def has_kda(self) -> bool:
-        return scopes.KDA in (self.layer_types or ())
-
-    @property
-    def has_conv(self) -> bool:
-        return scopes.CONV in (self.layer_types or ())
-
-    @property
     def ssd_inner(self) -> int:
         """d_inner of an ``ssd`` layer: heads x head width."""
         return self.ssd_heads * self.ssd_head_dim
@@ -583,7 +513,7 @@ class TinyGPTConfig:
     @property
     def heads_by_kind(self) -> bool:
         """Whether the stack's attention kinds differ in head count."""
-        kinds = set(self.layer_types or ()) - _NO_ATTENTION
+        kinds = mixers.attention.own(self.layer_types or ())
         return len({self.heads(kind) for kind in kinds}) > 1
 
     @property
@@ -591,7 +521,7 @@ class TinyGPTConfig:
         """Whether the layers' leaves differ by kind (another mixer, another
         head count, a half alone): such stacks run unrolled through
         ``_apply_stacks``."""
-        return self.has_kda or self.has_conv or self.heads_by_kind or self.block_halves
+        return mixers.own_leaves(self.layer_types) or self.heads_by_kind or self.block_halves
 
     @property
     def layer_groups(self) -> Tuple[Tuple[str, Tuple[int, ...]], ...]:
@@ -607,9 +537,9 @@ class TinyGPTConfig:
             return tuple((name, tuple(layers)) for name, layers in groups.items())
         for i in range(self.n_layer):
             kind = None if self.layer_types is None else self.layer_types[i]
-            own = _OWN_MIXER.get(kind, "")
-            name = _STACK_NAMES[own, i < self.first_k_dense]
-            groups.setdefault(f"{kind}_{name}" if by_kind and not own else name, []).append(i)
+            name = mixers.stack_name(kind, i < self.first_k_dense)
+            by_its_kind = by_kind and name in mixers.attention.STACKS
+            groups.setdefault(f"{kind}_{name}" if by_its_kind else name, []).append(i)
         return tuple((name, tuple(layers)) for name, layers in groups.items())
 
     @property
@@ -636,16 +566,12 @@ class TinyGPTConfig:
     def trains_routing(self) -> bool:
         """Whether the gates and the load-balance term are differentiated. A
         chip that holds a part of the experts sees the gradient through the
-        gates of its own experts only; the deployment sums it over the chips
-        that share the layer, and that sum belongs to the exchange on the
-        'expert' axis, which is not written. Applied alone, the partial
-        gradient pulls every token onto the held experts within tens of steps
-        (PERF.md, PR 30: held rows 0.97 -> 2.81 of the expected in 35 steps,
-        -> 1.43 with only the router's weights left out). So a part of the
-        experts does not train its routing: gates and the load-balance term
-        are constants of its backward pass (neither the router's weights nor
-        its input get a gradient through them); the experts, and everything
-        else, train."""
+        gates of its own experts only (the sum over the chips that share the
+        layer belongs to the 'expert' axis exchange, which is not written);
+        applied alone it pulls every token onto the held experts within tens
+        of steps (PERF.md, PR 30). So a part of the experts does not train its
+        routing: gates and the load-balance term are constants of its backward
+        pass; the experts, and everything else, train."""
         return self.experts_held is None or self.experts_held[1] == self.n_experts
 
     @property
@@ -677,12 +603,10 @@ class TinyGPTConfig:
             )
         if self.layer_types is not None:
             raise ValueError(
-                "the pipeline schedules slice one homogeneous stack; layer_types "
-                "gives each layer a kind of its own (sliding_window or kda layers "
-                "beside global ones, conv or ssd layers, stacks of unequal leaves under "
-                "layer_heads or block_halves). Run "
-                "this config with pipe=1"
-            )
+                "the pipeline schedules slice one homogeneous stack; layer_types gives each "
+                "layer a kind of its own (sliding_window or kda layers beside global ones, "
+                "conv or ssd layers, stacks of unequal leaves under layer_heads or "
+                "block_halves). Run this config with pipe=1")
 
     def __post_init__(self):
         if self.norm not in ("layernorm", "rmsnorm"):
@@ -809,43 +733,7 @@ class TinyGPTConfig:
                     "go with 'kda' layers, 'conv' layers or head counts by kind "
                     "(layer_heads): the stacks of unequal leaves"
                 )
-            if scopes.CONV in kinds and not (
-                    self.conv_taps >= 1 and self.norm == "rmsnorm" and not self.bias
-                    and not self.scan_layers and not self.tp_collective_matmul
-                    and not self.dropout and not self.block_halves):
-                raise ValueError(
-                    "a 'conv' layer (a gated short convolution) needs conv_taps >= 1, "
-                    "norm='rmsnorm', bias=False, no dropout, no tp_collective_matmul, no "
-                    "block_halves and scan_layers=False: stacks of unequal leaves run "
-                    "unrolled, in the published order, and the scanned loop is refused"
-                )
-            if scopes.KDA in kinds and not (
-                    self.kda_heads > 0 and self.kda_head_dim > 0 and self.kda_conv >= 1
-                    and self.kda_chunk >= 2 and self.norm == "rmsnorm" and not self.bias
-                    and not self.scan_layers and not self.tp_collective_matmul
-                    and not self.dropout):
-                raise ValueError(
-                    "a 'kda' layer needs kda_heads, kda_head_dim, kda_conv >= 1, "
-                    "kda_chunk >= 2, norm='rmsnorm', bias=False, no dropout, no "
-                    "tp_collective_matmul and scan_layers=False: stacks of unequal "
-                    "leaves run unrolled, in the published order, and the scanned "
-                    "loop is refused"
-                )
-            if scopes.SSD in kinds and not (
-                    self.ssd_heads > 0 and self.ssd_head_dim > 0 and self.ssd_state > 0
-                    and self.ssd_groups > 0 and self.ssd_heads % self.ssd_groups == 0
-                    and self.ssd_conv >= 1 and self.ssd_chunk >= 1
-                    and self.norm == "rmsnorm" and not self.bias
-                    and not self.scan_layers and not self.tp_collective_matmul
-                    and not self.dropout and self.block_halves):
-                raise ValueError(
-                    "an 'ssd' layer needs ssd_heads, ssd_head_dim, ssd_state, ssd_groups "
-                    "dividing ssd_heads, ssd_conv >= 1, ssd_chunk >= 1, norm='rmsnorm', "
-                    "bias=False, no dropout, no tp_collective_matmul, block_halves=True "
-                    "(a Mamba-2 block is the mixer alone) and scan_layers=False: stacks of "
-                    "unequal leaves run unrolled, in the published order, and the scanned "
-                    "loop is refused"
-                )
+            mixers.check(self)
             if (scopes.MLP in kinds) != self.block_halves or (self.block_halves and (
                     self.scan_layers or self.first_k_dense or self.layer_heads is not None
                     or scopes.MLP not in kinds or self.tp_collective_matmul)):
@@ -862,7 +750,7 @@ class TinyGPTConfig:
         if self.layer_heads is not None:
             if kinds is None or self.latent_attention or self.n_kv_head is None or (
                     self.tp_collective_matmul) or any(
-                    k not in kinds or k in _NO_ATTENTION or n < 1 or n % self.kv_heads
+                    k not in kinds or k not in mixers.attention.KINDS or n < 1 or n % self.kv_heads
                     for k, n in self.layer_heads):
                 raise ValueError(
                     "layer_heads gives ((kind, query heads), ...) for attention kinds of "
@@ -969,20 +857,8 @@ PARAM_AXIS_RULES: Dict[str, Tuple[Optional[str], ...]] = {
     "wpe": ("pos", "embed"),
     "blocks/ln1_scale": ("layers", "embed"),
     "blocks/ln1_bias": ("layers", "embed"),
-    # qkv is stored (layers, embed, 3, heads*head_dim) — the q/k/v axis is its
-    # own dimension so sharding 'heads' on a tensor-parallel mesh axis never
-    # crosses a q/k/v boundary.
-    "blocks/wqkv": ("layers", "embed", "qkv3", "heads"),
-    "blocks/bqkv": ("layers", "qkv3", "heads"),
-    # GQA split projections (present instead of wqkv/bqkv when kv_heads <
-    # n_head): q keeps its own matrix; k/v stack on a 'kv2' axis so sharding
-    # 'kv_heads' never crosses the k/v boundary (same reasoning as qkv3).
-    "blocks/wq": ("layers", "embed", "heads"),
-    "blocks/bq": ("layers", "heads"),
-    "blocks/wkv": ("layers", "embed", "kv2", "kv_heads"),
-    "blocks/bkv": ("layers", "kv2", "kv_heads"),
-    "blocks/wo": ("layers", "heads_merged", "embed"),
-    "blocks/bo": ("layers", "embed"),
+    # every mixer's leaves, by its module (a stack by any name takes them as 'blocks')
+    **mixers.AXIS_RULES,
     "blocks/ln2_scale": ("layers", "embed"),
     "blocks/ln2_bias": ("layers", "embed"),
     "blocks/wfc": ("layers", "embed", "mlp"),
@@ -1006,18 +882,6 @@ PARAM_AXIS_RULES: Dict[str, Tuple[Optional[str], ...]] = {
     # parameters a step, which a (.., 2, F) pair of minor axes costs on a TPU.
     "blocks/moe_wgu": ("layers", "experts", "embed", "gate_up"),
     "blocks/moe_wd": ("layers", "experts", "mlp", "embed"),
-    # QK-norm scales (present when qk_norm): one per projected q / k feature,
-    # or under qk_norm="head" one (head_dim,) vector that every head shares
-    # (no strategy splits it over 'model': parallel/strategies._TP_RULES).
-    "blocks/q_norm": ("layers", "heads"),
-    "blocks/k_norm": ("layers", "kv_heads"),
-    # Latent attention (present instead of wqkv / wkv when kv_lora_rank): wq
-    # as above with heads of qk_dim; the shared down projection to
-    # [latent | rotary key], the latent's norm scale, and the per-head
-    # expansion to [k_nope | v].
-    "blocks/wkv_a": ("layers", "embed", "latent_rope"),
-    "blocks/kv_norm": ("layers", "latent"),
-    "blocks/wkv_b": ("layers", "latent", "heads"),
     # Shared experts (present beside router / moe_wgu / moe_wd when
     # n_shared_experts): one SwiGLU, gate columns then up columns.
     "blocks/shared_wgu": ("layers", "embed", "gate_up"),
@@ -1025,43 +889,6 @@ PARAM_AXIS_RULES: Dict[str, Tuple[Optional[str], ...]] = {
     # The sigmoid router's selection bias (present when router_score='sigmoid'):
     # a buffer, added to the scores for the choice only; no gradient reaches it.
     "blocks/router_bias": ("layers", "experts"),
-    # A 'kda' layer's mixer (the stacks 'kda_blocks' / 'kda_dense_blocks', which
-    # take these rules as 'blocks' does; present instead of the attention
-    # leaves): q, k, v projections on a 'qkv3' axis, their depthwise causal
-    # convolutions (filter taps on 'conv'), the decay's low-rank map with its
-    # per-head rate and per-channel bias, beta, the output gate's low-rank map,
-    # the head norm's (kda_head_dim,) scale; wo as above.
-    "blocks/kda_wqkv": ("layers", "embed", "qkv3", "heads"),
-    "blocks/kda_conv": ("layers", "qkv3", "conv", "heads"),
-    "blocks/kda_wfa": ("layers", "embed", "kda_rank"),
-    "blocks/kda_wfb": ("layers", "kda_rank", "heads"),
-    "blocks/kda_a_log": ("layers", "kda_heads"),
-    "blocks/kda_dt_bias": ("layers", "heads"),
-    "blocks/kda_wb": ("layers", "embed", "kda_heads"),
-    "blocks/kda_wga": ("layers", "embed", "kda_rank"),
-    "blocks/kda_wgb": ("layers", "kda_rank", "heads"),
-    "blocks/kda_norm": ("layers", "head_dim"),
-    # The attention's per-head output gate (present when attn_gate): one
-    # column a query head, so it splits over 'model' as wq's columns do.
-    "blocks/wg": ("layers", "embed", "gate_heads"),
-    # An 'ssd' layer's mixer (the stack 'ssd_blocks'; present instead of the
-    # attention leaves): in_proj's columns [z | x B C | dt], the depthwise
-    # convolution's taps and bias over x | B | C, a head's dt bias, decay rate
-    # and skip, the gated norm's (d_inner,) scale; wo (out_proj) as above. No
-    # tensor-parallel rule: under a 'model' axis they stay whole.
-    "blocks/ssd_win": ("layers", "embed", "ssd_in"),
-    "blocks/ssd_conv": ("layers", "conv", "ssd_xbc"),
-    "blocks/ssd_conv_bias": ("layers", "ssd_xbc"),
-    "blocks/ssd_dt_bias": ("layers", "ssd_heads"),
-    "blocks/ssd_a_log": ("layers", "ssd_heads"),
-    "blocks/ssd_d": ("layers", "ssd_heads"),
-    "blocks/ssd_norm": ("layers", "ssd_inner"),
-    # A 'conv' layer's mixer (the stacks 'conv_blocks' / 'conv_dense_blocks';
-    # present instead of the attention leaves): the input projection's columns
-    # [B | C | x~] and the depthwise convolution's taps over the embed channels;
-    # wo as above. No tensor-parallel rule: under a 'model' axis they stay whole.
-    "blocks/sconv_win": ("layers", "embed", "sconv_in"),
-    "blocks/sconv_taps": ("layers", "conv", "sconv_channels"),
     # Experts that are not gated (mlp_act='relu2', present instead of moe_wgu /
     # shared_wgu): the up projection alone.
     "blocks/moe_wu": ("layers", "experts", "embed", "mlp"),
@@ -1095,112 +922,9 @@ def init_params(config: TinyGPTConfig, key: jax.Array) -> Params:
     k = iter(jax.random.split(
         key, 64 if c.stacks_unequal else 8 if legacy else 24 if wide else 12))
 
-    def normal(key, shape):
-        return (0.02 * jax.random.normal(key, shape)).astype(c.param_dtype)
-
+    normal = functools.partial(_normal, c)
     zeros = lambda shape: jnp.zeros(shape, c.param_dtype)
     ones = lambda shape: jnp.ones(shape, c.param_dtype)
-
-    def norms_and_attention(L, H):
-        """One stack's norm scales and attention leaves, L layers of H heads
-        (under ``block_halves`` the mixer's one norm)."""
-        blocks = {"ln1_scale": ones((L, D))}
-        if not c.block_halves:
-            blocks["ln2_scale"] = ones((L, D))
-        if c.norm == "layernorm":
-            blocks.update({f"{name[:3]}_bias": zeros((L, D)) for name in list(blocks)})
-        if c.latent_attention:
-            R, Dr = c.kv_lora_rank, c.qk_rope_head_dim
-            blocks.update(
-                wq=normal(next(k), (L, D, H * c.qk_dim)),
-                wkv_a=normal(next(k), (L, D, R + Dr)),
-                kv_norm=ones((L, R)),
-                wkv_b=normal(next(k), (L, R, H * (c.qk_nope_head_dim + c.v_dim))),
-            )
-        elif Hkv == H:
-            blocks["wqkv"] = normal(next(k), (L, D, 3, D))
-            if c.bias:
-                blocks["bqkv"] = zeros((L, 3, D))
-        else:
-            blocks["wq"] = normal(next(k), (L, D, H * Dh))
-            blocks["wkv"] = normal(next(k), (L, D, 2, Hkv * Dh))
-            if c.bias:
-                blocks["bq"] = zeros((L, H * Dh))
-                blocks["bkv"] = zeros((L, 2, Hkv * Dh))
-        if c.qk_norm == "head":
-            blocks.update(q_norm=ones((L, Dh)), k_norm=ones((L, Dh)))
-        elif c.qk_norm:
-            blocks.update(q_norm=ones((L, H * Dh)), k_norm=ones((L, Hkv * Dh)))
-        blocks["wo"] = normal(next(k), (L, H * c.v_dim, D))
-        if c.bias:
-            blocks["bo"] = zeros((L, D))
-        if c.attn_gate:
-            blocks["wg"] = normal(next(k), (L, D, H))
-        return blocks
-
-    def norms_and_kda(L):
-        """One stack's norm scales and KDA leaves, L layers. The filters start
-        as a depthwise Conv1d's do (uniform within 1 / sqrt(taps)), the decay's
-        rate exp(A_log) uniform on [1, 16] a head, and its bias the inverse
-        softplus of a step log-uniform on [0.001, 0.1] a channel: the
-        family's published initialisation."""
-        Hk, Dk, taps = c.kda_heads, c.kda_head_dim, c.kda_conv
-        uniform = lambda key, shape, lo, hi: jax.random.uniform(
-            key, shape, jnp.float32, minval=lo, maxval=hi)
-        step = jnp.exp(uniform(next(k), (L, Hk * Dk), math.log(1e-3), math.log(0.1)))
-        bound = taps ** -0.5
-        return dict(
-            ln1_scale=ones((L, D)), ln2_scale=ones((L, D)),
-            kda_wqkv=normal(next(k), (L, D, 3, Hk * Dk)),
-            kda_conv=uniform(next(k), (L, 3, taps, Hk * Dk), -bound, bound).astype(c.param_dtype),
-            kda_wfa=normal(next(k), (L, D, Dk)),
-            kda_wfb=normal(next(k), (L, Dk, Hk * Dk)),
-            kda_a_log=jnp.log(uniform(next(k), (L, Hk), 1.0, 16.0)).astype(c.param_dtype),
-            kda_dt_bias=(step + jnp.log(-jnp.expm1(-step))).astype(c.param_dtype),
-            kda_wb=normal(next(k), (L, D, Hk)),
-            kda_wga=normal(next(k), (L, D, Dk)),
-            kda_wgb=normal(next(k), (L, Dk, Hk * Dk)),
-            kda_norm=ones((L, Dk)),
-            wo=normal(next(k), (L, Hk * Dk, D)),
-        )
-
-    def norm_and_ssd(L):
-        """A stack of ``ssd`` blocks: the norm's scale and the Mamba-2 mixer's
-        leaves, L layers. The family's published initialisation: the filters
-        as a depthwise Conv1d's (uniform within 1 / sqrt(taps), the bias too),
-        the decay's rate exp(A_log) uniform on [1, 16] a head, dt's bias the
-        inverse softplus of a step log-uniform on [0.001, 0.1] a head (floor
-        1e-4), the skip D at ones, the gated norm's scale at ones."""
-        Hs, taps, W = c.ssd_heads, c.ssd_conv, c.ssd_xbc
-        uniform = lambda key, shape, lo, hi: jax.random.uniform(
-            key, shape, jnp.float32, minval=lo, maxval=hi)
-        step = jnp.maximum(
-            jnp.exp(uniform(next(k), (L, Hs), math.log(1e-3), math.log(0.1))), 1e-4)
-        bound = taps ** -0.5
-        return dict(
-            ln1_scale=ones((L, D)),
-            ssd_win=normal(next(k), (L, D, c.ssd_inner + W + Hs)),
-            ssd_conv=uniform(next(k), (L, taps, W), -bound, bound).astype(c.param_dtype),
-            ssd_conv_bias=uniform(next(k), (L, W), -bound, bound).astype(c.param_dtype),
-            ssd_dt_bias=(step + jnp.log(-jnp.expm1(-step))).astype(c.param_dtype),
-            ssd_a_log=jnp.log(uniform(next(k), (L, Hs), 1.0, 16.0)).astype(c.param_dtype),
-            ssd_d=ones((L, Hs)),
-            ssd_norm=ones((L, c.ssd_inner)),
-            wo=normal(next(k), (L, c.ssd_inner, D)),
-        )
-
-    def norms_and_conv(L):
-        """One stack's norm scales and gated-convolution leaves, L layers: the
-        taps as a depthwise Conv1d's default (uniform within 1 / sqrt(taps))."""
-        bound = c.conv_taps ** -0.5
-        return dict(
-            ln1_scale=ones((L, D)), ln2_scale=ones((L, D)),
-            sconv_win=normal(next(k), (L, D, 3 * D)),
-            sconv_taps=jax.random.uniform(
-                next(k), (L, c.conv_taps, D), jnp.float32, minval=-bound, maxval=bound
-            ).astype(c.param_dtype),
-            wo=normal(next(k), (L, D, D)),
-        )
 
     def mlp_leaves(L):
         """One stack's MLP as the config has it (routed where n_experts), L layers."""
@@ -1249,18 +973,12 @@ def init_params(config: TinyGPTConfig, key: jax.Array) -> Params:
         """The stack ``name`` (``layer_groups``) of these layers: its mixer's
         leaves, then its MLP's; the draws in that order."""
         L = len(layers)
-        if c.block_halves:  # the one half the blocks of this kind are
-            kind = c.layer_types[layers[0]]
-            if kind == scopes.MLP:
-                return mlp_leaves(L)
-            return norm_and_ssd(L) if kind == scopes.SSD else norms_and_attention(L, c.heads(kind))
-        if name.startswith("kda_"):
-            leaves = norms_and_kda(L)
-        elif name.startswith("conv_"):
-            leaves = norms_and_conv(L)
-        else:
-            kind = c.layer_types[layers[0]] if c.layer_types else None
-            leaves = norms_and_attention(L, c.heads(kind))
+        kind = c.layer_types[layers[0]] if c.layer_types else None
+        if kind == scopes.MLP:  # under block_halves: the feed-forward part alone
+            return mlp_leaves(L)
+        leaves = mixers.of(kind).leaves(c, k, L, kind)
+        if c.block_halves:  # the mixer alone
+            return leaves
         if name.endswith("dense_blocks"):
             Fd = c.dense_mlp_hidden
             leaves.update(wgu=normal(next(k), (L, D, 2, Fd)), wproj=normal(next(k), (L, Fd, D)))
@@ -1270,8 +988,8 @@ def init_params(config: TinyGPTConfig, key: jax.Array) -> Params:
 
     # The draws' order is the seeds' contract with every published artifact:
     # 'blocks' (the layers after the leading dense ones), the embedding and the
-    # head, 'dense_blocks', the KDA stacks, the conv stacks, then the stacks
-    # named by kind (``layer_heads``) in the published order of their first layers.
+    # head, 'dense_blocks', the stacks by a mixer's name (``mixers.STACKS``), then the stacks
+    # named by kind (``layer_heads``, ``block_halves``) in the order of their first layers.
     groups = dict(c.layer_groups)
     params = {}
     if "blocks" in groups:
@@ -1283,8 +1001,8 @@ def init_params(config: TinyGPTConfig, key: jax.Array) -> Params:
         params["lnf_bias"] = zeros((D,))
     if not c.tie_embeddings:
         params["lm_head"] = normal(next(k), (V, D))
-    for name in ("dense_blocks", "kda_blocks", "kda_dense_blocks", "conv_dense_blocks",
-                 "conv_blocks", *(n for n in groups if n not in _STACK_NAMES.values())):
+    drawn_here = mixers.STACKS[1:]  # all but 'blocks', drawn above
+    for name in (*drawn_here, *(n for n in groups if n not in mixers.STACKS)):
         if name in groups:
             params[name] = stack(name, groups[name])
     return params
@@ -1292,201 +1010,6 @@ def init_params(config: TinyGPTConfig, key: jax.Array) -> Params:
 
 def count_params(params: Params) -> int:
     return sum(int(x.size) for x in jax.tree_util.tree_leaves(params))
-
-
-def _layer_norm(x: jax.Array, scale: jax.Array, bias: jax.Array, eps: float = 1e-5) -> jax.Array:
-    # fp32 statistics regardless of compute dtype (AMP-style numerics).
-    xf = x.astype(jnp.float32)
-    mean = jnp.mean(xf, axis=-1, keepdims=True)
-    var = jnp.var(xf, axis=-1, keepdims=True)
-    y = (xf - mean) * lax.rsqrt(var + eps)
-    return (y * scale.astype(jnp.float32) + bias.astype(jnp.float32)).astype(x.dtype)
-
-
-def _rms_norm(x: jax.Array, scale: jax.Array, eps: float) -> jax.Array:
-    # Llama RMSNorm: no mean subtraction, no bias; fp32 statistics (HF
-    # LlamaRMSNorm computes the rsqrt in fp32 and multiplies the scale in
-    # the input dtype — we keep the whole product fp32 before the downcast,
-    # which agrees to within bf16 rounding).
-    xf = x.astype(jnp.float32)
-    y = xf * lax.rsqrt(jnp.mean(xf * xf, axis=-1, keepdims=True) + eps)
-    return (y * scale.astype(jnp.float32)).astype(x.dtype)
-
-
-def _norm(
-    config: TinyGPTConfig, x: jax.Array, scale: jax.Array, bias: Optional[jax.Array]
-) -> jax.Array:
-    if config.norm == "rmsnorm":
-        return _rms_norm(x, scale, config.norm_eps)
-    return _layer_norm(x, scale, bias, config.norm_eps)
-
-
-def _rope(
-    x: jax.Array,  # (B, S, H, Dh)
-    positions: jax.Array,  # (S,) int32 global token positions
-    theta: float,
-    scaling: Optional[YarnScaling] = None,
-    rotary_dim: Optional[int] = None,
-) -> jax.Array:
-    """Rotary position embedding, HF-Llama rotate-half convention.
-
-    ``cos``/``sin`` are built over pairs (i, i + Dh/2) — x1 = first half,
-    x2 = second half, x' = x*cos + cat(-x2, x1)*sin — matching HF
-    ``apply_rotary_pos_emb`` exactly so the transformers parity test can
-    load identical weights. fp32 rotation math, cast back to x.dtype.
-    ``rotary_dim``: only the leading lanes rotate (rotate-half inside them,
-    the frequencies over ``rotary_dim``); the rest pass as they are.
-    """
-    from ..ops.rotary import rope_angles
-
-    if rotary_dim is not None and rotary_dim != x.shape[-1]:
-        turned = _rope(x[..., :rotary_dim], positions, theta, scaling)
-        return jnp.concatenate((turned, x[..., rotary_dim:]), axis=-1)
-    Dh = x.shape[-1]
-    half = Dh // 2
-    freqs = rope_angles(positions, Dh, theta, scaling)  # (S, Dh/2)
-    cos = jnp.cos(freqs)[None, :, None, :]  # (1, S, 1, Dh/2)
-    sin = jnp.sin(freqs)[None, :, None, :]
-    if scaling is not None and scaling.cos_sin_factor != 1.0:
-        cos, sin = cos * scaling.cos_sin_factor, sin * scaling.cos_sin_factor
-    xf = x.astype(jnp.float32)
-    x1, x2 = xf[..., :half], xf[..., half:]
-    out = jnp.concatenate((x1 * cos - x2 * sin, x2 * cos + x1 * sin), axis=-1)
-    return out.astype(x.dtype)
-
-
-@jax.named_scope(scopes.DROPOUT)
-def _dropout(x: jax.Array, rate: float, key: Optional[jax.Array], deterministic: bool) -> jax.Array:
-    if deterministic or rate == 0.0 or key is None:
-        return x
-    keep = 1.0 - rate
-    mask = jax.random.bernoulli(key, keep, x.shape)
-    return jnp.where(mask, x / keep, jnp.zeros((), x.dtype)).astype(x.dtype)
-
-
-def _whole_heads(q: jax.Array, k: jax.Array, v: jax.Array) -> Tuple[jax.Array, jax.Array]:
-    """k and v (B, S, KV, .) broadcast to q's head count, each kv head to its
-    query group, for the attention bodies that take a k and a v a query head.
-    Consecutive-block repetition matches the TP layout: query-head shard j
-    needs exactly kv-head shard j when the 'model' degree divides kv_heads;
-    when it does not, the kv-head-aligned spec rule keeps wkv replicated over
-    'model' (strategies.param_partition_specs) so this never needs the
-    partitioner's full-replicate resharding fallback."""
-    rep = q.shape[2] // k.shape[2]
-    if rep == 1:
-        return k, v
-    return jnp.repeat(k, rep, axis=2), jnp.repeat(v, rep, axis=2)
-
-
-def _attention(
-    config: TinyGPTConfig,
-    q: jax.Array,  # (B, S, H, Dh)
-    k: jax.Array,  # (B, S, KV, Dh): the model's kv heads, H a multiple
-    v: jax.Array,
-    dropout_key: Optional[jax.Array],
-    deterministic: bool,
-    kind: Optional[str] = None,
-) -> jax.Array:
-    """Dispatch to the configured attention implementation. Returns (B,S,H,Dh).
-    ``kind`` is the layer's (``TinyGPTConfig.layer_types``): its mask rule.
-
-    'flash' takes k and v at their own head count (its kernels' index maps
-    find a query head's kv head); every other body takes them broadcast to
-    the query heads (``_whole_heads``).
-
-    Attention-probability dropout (reference train_harness.py:116) applies in
-    ALL THREE impls: materialized bernoulli in 'reference', and the shared
-    global-coordinate hash mask in 'flash' (in-kernel) and 'ring' (per
-    rotating K/V block) — the probabilities still never materialize in HBM
-    for the latter two, and flash/ring produce bitwise-identical masks for
-    equal seeds. 'reference' draws from a different RNG stream (bernoulli),
-    so with dropout > 0 its parity vs flash/ring is statistical, not
-    per-step exact; set dropout=0 for exact cross-impl loss comparison.
-    """
-    seed = None
-    if not deterministic and config.dropout > 0.0 and dropout_key is not None:
-        seed = jax.random.bits(dropout_key, (), jnp.uint32)
-    # Which attention, never how: tiles and the backward's choice belong to
-    # ops/flash_attention.py, which picks them from S, D and VMEM.
-    kwargs = dict(
-        causal=config.causal,
-        dropout_rate=config.dropout if seed is not None else 0.0,
-        dropout_seed=seed,
-    )
-    rule = config.mask_rule(q.shape[1], kind)
-    if config.attention_impl != "flash":
-        k, v = _whole_heads(q, k, v)
-    if config.latent_attention and (
-        config.seq_manual_axis is not None
-        or config.attention_impl not in ("flash", "reference")
-    ):
-        raise ValueError(
-            "latent attention runs attention_impl 'flash' or 'reference' outside "
-            "the pipeline schedules; the ring and Ulysses bodies take one head "
-            "width and their own scale"
-        )
-    if config.seq_manual_axis is not None:
-        # Inside a shard_map that is manual over the sequence axis (the
-        # pipeline schedules): q/k/v hold LOCAL sequence chunks, so dispatch
-        # straight to the sharded attention bodies, which communicate over
-        # that axis. The dropout seed is deliberately NOT per-shard here —
-        # ring masks are keyed by global coordinates (all ring participants
-        # must agree on the seed); Ulysses folds its own shard index.
-        ax = config.seq_manual_axis
-        if config.attention_impl == "ring":
-            from ..ops.ring_attention import ring_attention_sharded
-
-            return ring_attention_sharded(
-                q, k, v, axis_name=ax, zigzag=config.ring_zigzag, **kwargs
-            )
-        if config.attention_impl == "ulysses":
-            from ..ops.ulysses_attention import ulysses_attention_sharded
-
-            return ulysses_attention_sharded(q, k, v, axis_name=ax, **kwargs)
-        raise ValueError(
-            "sequence-parallel pipeline needs attention_impl 'ring' or "
-            f"'ulysses' (local '{config.attention_impl}' attention over a "
-            "sequence chunk would silently compute blockwise attention)"
-        )
-    if config.attention_impl == "flash":
-        # Pallas TPU kernel; fp32 online-softmax accumulation internally.
-        from ..ops.flash_attention import flash_attention
-
-        if config.attn_scale is not None:
-            kwargs["scale"] = config.attn_scale
-        kwargs["causal"] = rule
-        return flash_attention(q, k, v, **kwargs)
-    if config.attention_impl == "ring":
-        from ..ops.ring_attention import ring_attention
-
-        return ring_attention(q, k, v, zigzag=config.ring_zigzag, **kwargs)
-    if config.attention_impl == "ulysses":
-        from ..ops.ulysses_attention import ulysses_attention
-
-        return ulysses_attention(q, k, v, **kwargs)
-
-    # Reference jnp implementation: softmax(QK^T/sqrt(d))V with fp32 softmax.
-    scale = config.attn_scale or 1.0 / (q.shape[-1] ** 0.5)
-    scores = jnp.einsum(
-        "bqhd,bkhd->bhqk", q, k, preferred_element_type=jnp.float32
-    ) * scale
-    if not isinstance(rule, bool):  # a rule that is an object says which pairs
-        pos = jnp.arange(q.shape[1], dtype=jnp.int32)
-        scores = jnp.where(
-            rule.allowed(pos[:, None], pos[None, :]), scores, jnp.finfo(jnp.float32).min
-        )
-    elif config.causal:
-        s = q.shape[1]
-        mask = jnp.tril(jnp.ones((s, s), bool))
-        scores = jnp.where(mask, scores, jnp.finfo(jnp.float32).min)
-    probs = jax.nn.softmax(scores, axis=-1)
-    # Parity: nn.MultiheadAttention applies dropout to attention probabilities
-    # (reference train_harness.py:116).
-    probs = _dropout(probs, config.dropout, dropout_key, deterministic)
-    out = jnp.einsum(
-        "bhqk,bkhd->bqhd", probs.astype(q.dtype), v, preferred_element_type=jnp.float32
-    )
-    return out.astype(q.dtype)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
@@ -1580,16 +1103,9 @@ def _block(
         # keys[0] stays shared — ring/Ulysses handle their own coordinates.
         keys = (keys[0], jax.random.fold_in(keys[1], lax.axis_index(c.seq_manual_axis)))
 
-    # Collective-matmul tp fusion (round 15, ops/collective_matmul.py):
-    # route the four projection classes through the ppermute-ring
-    # decomposition — the residual stream between them rides
-    # sequence-sharded over 'model', and the activation all-gather /
-    # partial-sum reduce-scatter hide inside the dots. The helpers fall
-    # back to the plain einsum when no >1 'model' axis is in scope, so
-    # the knob is inert on pure-dp meshes. Incompatible with the
-    # pipeline schedules' manual sequence region (the stream is already
-    # manual over 'seq' there) — refused loudly rather than silently
-    # computing a doubly-sharded projection.
+    # The collective-matmul helpers fall back to the plain einsum without a >1
+    # 'model' axis; inside the pipeline's sequence-manual region the stream is
+    # already manual over 'seq', so the knob is refused there.
     if c.tp_collective_matmul and c.seq_manual_axis is not None:
         raise ValueError(
             "tp_collective_matmul cannot run inside a sequence-manual "
@@ -1602,467 +1118,17 @@ def _block(
 
 
 def _mixer_half(c, x, layer, key, deterministic, kind, qk_tables):
-    """``_block``'s first half under its scope: the kind chooses the mixer."""
-    with jax.named_scope(scopes.ATTENTION):
-        if kind is None:
-            return _attention_sublayer(c, x, layer, key, deterministic, None, qk_tables)
-        with jax.named_scope(kind):
-            if kind == scopes.KDA:
-                return _kda_sublayer(c, x, layer)
-            if kind == scopes.SSD:
-                return _ssd_sublayer(c, x, layer)
-            if kind == scopes.CONV:
-                return _conv_sublayer(c, x, layer)
-            return _attention_sublayer(c, x, layer, key, deterministic, kind, qk_tables)
+    """``_block``'s first half under its scopes: the kind chooses the mixer
+    (``mixers.MIXERS``), and every mixer is handed the same arguments."""
+    own_scope = jax.named_scope(kind) if kind is not None else contextlib.nullcontext()
+    with jax.named_scope(scopes.ATTENTION), own_scope:
+        return mixers.of(kind).sublayer(c, x, layer, key, deterministic, kind, qk_tables)
 
 
 def _mlp_half(c, x, layer, key, deterministic):
     """``_block``'s second half under its scope -> (x, aux)."""
     with jax.named_scope(scopes.MLP):
         return _mlp_sublayer(c, x, layer, key, deterministic)
-
-
-def _rotary_positions(c: TinyGPTConfig, S: int) -> jax.Array:
-    """(S,) int32: the positions the S rows of a layer's q and k are rotated
-    at. Global token positions; under a sequence-manual pipeline this shard
-    holds positions [shard*S, shard*S + S) (same offset rule as the learned
-    table's dynamic slice in embed()). The zigzag ring redistribution happens
-    INSIDE ring_attention, after rotation, so the rotated rows travel with
-    their tokens. Under block diffusion both copies of the document are at
-    0..L-1."""
-    pos = jnp.arange(S, dtype=jnp.int32)
-    if c.seq_manual_axis is not None:
-        pos = pos + S * lax.axis_index(c.seq_manual_axis)
-    if c.block_diffusion is not None:
-        pos = pos % (S // 2)
-    return pos
-
-
-def _takes_qk_prologue(c: TinyGPTConfig, S: int, kind: Optional[str] = None) -> bool:
-    """Whether q and k of the stack's layers of ``kind``, S rows of them, are
-    ``ops.rotary``'s operand: rotary over heads of whole 128-lane vregs, all of
-    a head's lanes or its leading ``rotary_dim``. The per-head norm is the
-    pass's first stage; a norm over all of a layer's features (OLMoE) stays in
-    ``jnp`` before it. Not latent attention's, which rotates 64 of 192 lanes of
-    q and a one-head key."""
-    from ..ops import rotary as rotary_ops
-
-    return (c.pos_embed == "rope" and not c.latent_attention
-            and rotary_ops.fits(c.head_dim, S, c.rotary(kind).rotary_dim))
-
-
-def qk_prologue_tables(c: TinyGPTConfig, S: int) -> Dict:
-    """{kind of layer: the (S, head_dim) f32 table of cos and sin
-    ``ops.rotary.qk_prologue`` rotates by} (``layer_types``; the one key None
-    for a stack of one kind), made once for the whole stack and handed down
-    to its layers. Empty where the stack's layers keep the
-    ``jnp`` chain: another operand (``_takes_qk_prologue``), or a backend
-    without the kernels."""
-    from ..ops import rotary as rotary_ops
-
-    if rotary_ops.kernel_mode() is None:
-        return {}
-    pos = _rotary_positions(c, S)
-    kinds = sorted(set(c.layer_types) - _NO_ATTENTION) if c.layer_types else (None,)
-    return {
-        kind: rotary_ops.table(pos, c.head_dim, c.rotary(kind).theta, c.rotary(kind).scaling,
-                               c.rotary(kind).rotary_dim)
-        for kind in kinds if _takes_qk_prologue(c, S, kind)
-    }
-
-
-def qk_prologue_stats(config: TinyGPTConfig, seq_len: int) -> Dict[str, Any]:
-    """Counters of the pass between the projections and the flash kernels
-    over sequences of ``seq_len`` tokens (a block-diffusion stream is twice
-    that), from the config and the backend at trace time: ``rotary_layers``
-    that rotate q and k at all, ``pass_layers`` of them that take
-    ``ops.rotary``'s one pass here (the rest run the ``jnp`` chain: a cell
-    that fell back says so), ``norm_stage_layers`` of those with the per-head
-    norm inside the pass, and the bytes one layer's pass moves a sequence,
-    ``forward_bytes`` and ``backward_bytes`` (of the first kind that takes
-    it). ``by_kind`` has the same a kind of attention layer (the one key
-    ``global`` for a stack of one kind), with its ``heads`` and the
-    ``rotary_lanes`` of a head that rotate."""
-    from ..ops import rotary as rotary_ops
-
-    c = config
-    S = seq_len * (2 if c.block_diffusion is not None else 1)
-    head = c.qk_norm == "head"
-    kinds = c.layer_types or (None,) * c.n_layer
-    by_kind = {}
-    for kind in sorted(set(kinds) - _NO_ATTENTION, key=str):
-        layers = kinds.count(kind) if c.pos_embed == "rope" else 0
-        taken = (layers > 0 and _takes_qk_prologue(c, S, kind)
-                 and rotary_ops.kernel_mode() is not None)
-        moved = rotary_ops.pass_bytes(
-            S, c.heads(kind) * c.head_dim, c.kv_heads * c.head_dim,
-            jnp.dtype(c.compute_dtype).itemsize, head) if taken else {"forward": 0, "backward": 0}
-        by_kind[kind or scopes.GLOBAL] = {
-            "heads": c.heads(kind),
-            "rotary_lanes": (c.rotary(kind).rotary_dim or c.head_dim) if layers else 0,
-            "rotary_layers": layers,
-            "pass_layers": layers if taken else 0,
-            "norm_stage_layers": layers if taken and head else 0,
-            "forward_bytes": moved["forward"], "backward_bytes": moved["backward"],
-        }
-    total = lambda key: sum(entry[key] for entry in by_kind.values())
-    first = next((e for e in by_kind.values() if e["pass_layers"]), None) or {}
-    return {
-        "rotary_layers": total("rotary_layers"),
-        "pass_layers": total("pass_layers"),
-        "norm_stage_layers": total("norm_stage_layers"),
-        "forward_bytes": first.get("forward_bytes", 0),
-        "backward_bytes": first.get("backward_bytes", 0),
-        "by_kind": by_kind,
-    }
-
-
-def _attention_sublayer(
-    c: TinyGPTConfig,
-    x: jax.Array,
-    layer: Params,
-    dropout_key: Optional[jax.Array],
-    deterministic: bool,
-    kind: Optional[str] = None,
-    qk_tables: Optional[Dict] = None,
-) -> jax.Array:
-    """Norm -> q/k/v projections -> QK-norm -> rope -> attention -> (the
-    per-head output gate) -> output projection -> residual: the first half of
-    ``_block``, at the kind's head count (``TinyGPTConfig.heads``). Where the stack's
-    q and k are ``ops.rotary``'s operand and the backend runs its kernels
-    (``qk_prologue_tables`` has this kind's tables), the per-head norm and
-    the rotation are its one pass; else the ``jnp`` chain ``_rms_norm`` ->
-    ``_rope``, which is also what the pass is tested against."""
-    B, S, D = x.shape
-    cd = c.compute_dtype
-    H = c.heads(kind)
-    use_cmm = c.tp_collective_matmul
-    if use_cmm:
-        from ..ops import collective_matmul as _cm
-
-    h = _norm(c, x, layer["ln1_scale"], layer.get("ln1_bias"))
-    if c.latent_attention:
-        return x + _latent_attention(c, h, layer, dropout_key, deterministic)
-    if "wqkv" in layer:  # fused MHA projection (kv_heads == n_head)
-        if use_cmm:
-            qkv = _cm.ag_proj(h, layer["wqkv"].astype(cd)).astype(cd)
-        else:
-            qkv = jnp.einsum(
-                "bsd,dce->bsce", h, layer["wqkv"].astype(cd), preferred_element_type=jnp.float32
-            ).astype(cd)
-        if "bqkv" in layer:
-            qkv = qkv + layer["bqkv"].astype(cd)
-        q, k, v = (qkv[:, :, i] for i in range(3))
-    else:  # GQA: separate q and stacked k/v projections
-        if use_cmm:
-            q = _cm.ag_proj(h, layer["wq"].astype(cd)).astype(cd)
-            # kv rides the kv-head-aligned rule (aligned_units): with a
-            # misaligned 'model' degree the weight enters replicated and
-            # the ring produces replicated full-kv outputs.
-            kv = _cm.ag_proj(
-                h, layer["wkv"].astype(cd), aligned_units=c.kv_heads
-            ).astype(cd)
-        else:
-            q = jnp.einsum(
-                "bsd,de->bse", h, layer["wq"].astype(cd), preferred_element_type=jnp.float32
-            ).astype(cd)
-            kv = jnp.einsum(
-                "bsd,dce->bsce", h, layer["wkv"].astype(cd), preferred_element_type=jnp.float32
-            ).astype(cd)
-        if "bq" in layer:
-            q = q + layer["bq"].astype(cd)
-            kv = kv + layer["bkv"].astype(cd)
-        k, v = kv[:, :, 0], kv[:, :, 1]
-    if c.qk_norm and c.qk_norm != "head":
-        q = _rms_norm(q, layer["q_norm"], c.norm_eps)
-        k = _rms_norm(k, layer["k_norm"], c.norm_eps)
-    if qk_tables is None:
-        qk_tables = qk_prologue_tables(c, S)
-    head_norm = c.qk_norm == "head"
-    v = v.reshape(B, S, c.kv_heads, c.head_dim)
-    if kind in qk_tables:
-        from ..ops import rotary as rotary_ops
-
-        scales = (layer["q_norm"], layer["k_norm"]) if head_norm else (None, None)
-        with jax.named_scope(scopes.QK_PROLOGUE):
-            q, k = rotary_ops.qk_prologue(  # -> (B, S, heads, head_dim)
-                q, k, *scales, qk_tables[kind], c.norm_eps,
-                interpret=rotary_ops.kernel_mode(), rotary_dim=c.rotary(kind).rotary_dim)
-    else:  # the jnp chain
-        q = q.reshape(B, S, H, c.head_dim)
-        k = k.reshape(B, S, c.kv_heads, c.head_dim)
-        if head_norm:
-            q = _rms_norm(q, layer["q_norm"], c.norm_eps)
-            k = _rms_norm(k, layer["k_norm"], c.norm_eps)
-        if c.pos_embed == "rope":
-            rotary = c.rotary(kind)
-            pos = _rotary_positions(c, S)
-            q = _rope(q, pos, rotary.theta, rotary.scaling, rotary.rotary_dim)
-            k = _rope(k, pos, rotary.theta, rotary.scaling, rotary.rotary_dim)
-    attn = _attention(c, q, k, v, dropout_key, deterministic, kind)
-    if "wg" in layer:
-        with jax.named_scope(scopes.ATTN_GATE):
-            gate = jax.nn.sigmoid(jnp.einsum(  # (B, S, H) f32: one scalar a head a token
-                "bsd,dh->bsh", h, layer["wg"].astype(cd), preferred_element_type=jnp.float32))
-            attn = (attn.astype(jnp.float32) * gate[..., None]).astype(cd)
-    attn = attn.reshape(B, S, H * c.head_dim)
-    if use_cmm:
-        attn = _cm.rs_proj(attn, layer["wo"].astype(cd)).astype(cd)
-    else:
-        attn = jnp.einsum(
-            "bsd,de->bse", attn, layer["wo"].astype(cd), preferred_element_type=jnp.float32
-        ).astype(cd)
-    if "bo" in layer:
-        attn = attn + layer["bo"].astype(cd)
-    return x + attn
-
-
-def _latent_attention(
-    c: TinyGPTConfig,
-    h: jax.Array,  # (B, S, D), the normed input
-    layer: Params,
-    dropout_key: Optional[jax.Array],
-    deterministic: bool,
-) -> jax.Array:
-    """MLA as DeepSeek-V2 computes it in training (no absorbed matrices: k and
-    v are expanded per head), in three scopes: ``mla_proj`` (the three
-    projections, the latent's norm, rotary, assembling k), ``mla_core`` (the
-    attention itself: the flash kernels at qk_dim over v_dim) and
-    ``mla_out``. One departure from the source's ``modeling_deepseek.py``: it
-    de-interleaves q_pe / k_pe before rotate-half; with seeded weights that is
-    one fixed permutation of both and leaves q k^T unchanged."""
-    B, S, _ = h.shape
-    cd = c.compute_dtype
-    H, Dn, Dr, Dv, R = (c.n_head, c.qk_nope_head_dim, c.qk_rope_head_dim,
-                        c.v_dim, c.kv_lora_rank)
-    proj = functools.partial(jnp.einsum, preferred_element_type=jnp.float32)
-    with jax.named_scope(scopes.MLA_PROJ):
-        q = proj("bsd,de->bse", h, layer["wq"].astype(cd)).astype(cd)
-        q = q.reshape(B, S, H, Dn + Dr)
-        kv_a = proj("bsd,de->bse", h, layer["wkv_a"].astype(cd)).astype(cd)
-        latent = _rms_norm(kv_a[..., :R], layer["kv_norm"], c.norm_eps)
-        kv_b = proj("bsr,re->bse", latent, layer["wkv_b"].astype(cd)).astype(cd)
-        kv_b = kv_b.reshape(B, S, H, Dn + Dv)
-        if c.mla_nope:  # the 64 shared columns as they are: nothing rotates q or k
-            k_pe = kv_a[:, :, None, R:]
-        else:
-            pos = jnp.arange(S, dtype=jnp.int32)
-            q_pe = _rope(q[..., Dn:], pos, c.rope_theta, c.rope_scaling)
-            k_pe = _rope(kv_a[:, :, None, R:], pos, c.rope_theta, c.rope_scaling)
-            q = jnp.concatenate((q[..., :Dn], q_pe), axis=-1)
-        k = jnp.concatenate(
-            (kv_b[..., :Dn], jnp.broadcast_to(k_pe, (B, S, H, Dr))), axis=-1
-        )
-        v = kv_b[..., Dn:]
-    with jax.named_scope(scopes.MLA_CORE):
-        attn = _attention(c, q, k, v, dropout_key, deterministic)
-    with jax.named_scope(scopes.MLA_OUT):
-        return proj(
-            "bse,ed->bsd", attn.reshape(B, S, H * Dv), layer["wo"].astype(cd)
-        ).astype(cd)
-
-
-def _kda_sublayer(c: TinyGPTConfig, x: jax.Array, layer: Params) -> jax.Array:
-    """Norm -> Kimi Delta Attention -> residual: a ``kda`` layer's mixer, in
-    three scopes. ``kda_prep``: q = l2norm(silu(conv(h Wq))), k likewise, v =
-    silu(conv(h Wv)) (one projection, then ``ops.kda.qkv_prologue``: on a TPU
-    at whole 128-lane head widths the convolution, SiLU and the l2norms are
-    one Mosaic call a third of the columns, ``kda_conv_fwd``, and one back,
-    ``kda_conv_bwd``; elsewhere XLA's convolution and the ``jnp`` chain), the
-    log-decay a key channel g = -exp(A_log) softplus(Wfb (Wfa h) + dt_bias)
-    and beta = sigmoid(h Wb), g and beta float32.
-    ``kda_core``: the recurrence (``ops/kda.py``: the Mosaic kernels on a TPU
-    at whole 128-lane head widths, its ``jnp`` path elsewhere). ``kda_out``:
-    Wo [RMSNorm over each head's values (one (kda_head_dim,) scale) x
-    sigmoid(Wgb (Wga h))]."""
-    from ..ops import kda as kda_ops
-
-    B, S, _ = x.shape
-    cd = c.compute_dtype
-    H, Dk = c.kda_heads, c.kda_head_dim
-    proj = functools.partial(jnp.einsum, preferred_element_type=jnp.float32)
-    h = _norm(c, x, layer["ln1_scale"], layer.get("ln1_bias"))
-    with jax.named_scope(scopes.KDA_PREP):
-        # one (D, 3 H Dk) product: with q, k, v on an axis of their own XLA lays the
-        # result out (3, S, H Dk) and the flat view the convolution takes is a copy
-        wqkv = layer["kda_wqkv"].reshape(x.shape[-1], 3 * H * Dk).astype(cd)
-        qkv = checkpoint_name(proj("bsd,de->bse", h, wqkv).astype(cd), KDA_QKV)
-        taps = jnp.moveaxis(layer["kda_conv"], 0, 1).reshape(c.kda_conv, 3 * H * Dk)
-        fits = Dk % 128 == 0  # the kernels' widths; else XLA's convolution and the jnp scan
-        mode = kda_ops.kernel_mode() if fits else None
-        q, k, v = kda_ops.qkv_prologue(qkv, taps, H, interpret=mode)
-        low = proj("bsd,dr->bsr", h, layer["kda_wfa"].astype(cd)).astype(cd)
-        rate = proj("bsr,re->bse", low, layer["kda_wfb"].astype(cd))  # float32
-        # every per-channel operand stays (B, S, H x Dk), a head's columns together: on
-        # a TPU a (.., H, Dk) view of it is another layout, and a reshape a copy
-        g = -jnp.repeat(jnp.exp(layer["kda_a_log"].astype(jnp.float32)), Dk) * jax.nn.softplus(
-            rate + layer["kda_dt_bias"].astype(jnp.float32))
-        beta = jax.nn.sigmoid(proj("bsd,dh->bsh", h, layer["kda_wb"].astype(cd)))
-    with jax.named_scope(scopes.KDA_CORE):
-        o = kda_ops.kda_flat(q, k, v, g, beta, H, c.kda_chunk, interpret=mode)
-    with jax.named_scope(scopes.KDA_OUT):
-        low = proj("bsd,dr->bsr", h, layer["kda_wga"].astype(cd)).astype(cd)
-        gate = proj("bsr,re->bse", low, layer["kda_wgb"].astype(cd)).astype(cd)
-        of = o.astype(jnp.float32)  # RMSNorm over each head's values, one (Dk,) scale
-        of = of * kda_ops.over_heads(
-            lax.rsqrt(kda_ops.head_sums(of * of, H) / Dk + c.norm_eps), Dk)
-        of = of * jnp.tile(layer["kda_norm"].astype(jnp.float32), H)
-        o = (of * jax.nn.sigmoid(gate.astype(jnp.float32))).astype(cd)
-        return x + proj("bse,ed->bsd", o, layer["wo"].astype(cd)).astype(cd)
-
-
-def kda_stats(config: TinyGPTConfig, seq_len: int) -> Dict[str, Any]:
-    """Counters of the ``kda`` layers over sequences of ``seq_len`` tokens,
-    from the config and the backend at trace time: ``layers`` of the kind,
-    ``chunk`` and ``chunks`` a sequence, ``kernel_calls`` a step by name (one
-    forward and one backward a layer where the kernels run: none on the ``jnp``
-    path; remat's second forward is the policy's, not counted),
-    ``prep_kernel_calls`` the same of ``qkv_prologue``'s two (a call each for
-    q, k and v a layer a direction where the convolution, SiLU and the
-    l2norms are the kernels'; none where they are the ``jnp`` chain), and
-    ``saved_state_bytes`` a layer a sequence: the states entering the chunks,
-    which the forward keeps for the backward beside its operands."""
-    from ..ops import kda as kda_ops
-
-    c = config
-    layers = (c.layer_types or ()).count(scopes.KDA)
-    chunks = seq_len // c.kda_chunk
-    kernels = layers if (c.kda_head_dim % 128 == 0
-                         and kda_ops.kernel_mode() is not None) else 0
-    prologues = 3 * kernels if kda_ops.conv_fits(seq_len, c.kda_conv, c.kda_head_dim) else 0
-    return {
-        "layers": layers, "chunk": c.kda_chunk, "chunks": chunks,
-        "kernel_calls": {"kda_fwd": kernels, "kda_bwd": kernels},
-        "prep_kernel_calls": {"kda_conv_fwd": prologues, "kda_conv_bwd": prologues},
-        "saved_state_bytes": (c.kda_heads * chunks * c.kda_head_dim ** 2
-                              * jnp.dtype(c.compute_dtype).itemsize),
-    }
-
-
-def _ssd_sublayer(c: TinyGPTConfig, x: jax.Array, layer: Params) -> jax.Array:
-    """Norm -> Mamba-2 mixer -> residual: an ``ssd`` block, in three scopes.
-    ``ssd_prep``: [z | xBC | dt] = h W_in as three products of the weight's
-    column blocks (slices of the weight, not of a (B, S, 10304) result), xBC
-    = silu(conv(xBC) + bias) (``ops.kda.conv_silu``: on a TPU the convolution,
-    its bias and SiLU are one Mosaic call over the 6144 columns,
-    ``kda_conv_fwd``, and one back; elsewhere XLA's convolution), dt =
-    softplus(dt + dt_bias) and the log-decay g = -exp(A_log) dt a head, both
-    float32 (no clamp beyond softplus). ``ssd_core``: the scan (``ops/ssd.py``:
-    the Mosaic kernels on a TPU where ``ops.ssd.fits``, its ``jnp`` path
-    elsewhere) over xBC as it stands: x's, B's and C's columns are found by
-    the kernels' block specs. ``ssd_out``: the skip D x, the gate u = y
-    silu(z), the RMS over each group's d_inner / ssd_groups channels times
-    the (d_inner,) scale (gate first, then the norm), and W_out."""
-    from ..ops import kda as kda_ops
-    from ..ops import ssd as ssd_ops
-
-    cd = c.compute_dtype
-    H, P, groups = c.ssd_heads, c.ssd_head_dim, c.ssd_groups
-    inner, W = c.ssd_inner, c.ssd_xbc
-    proj = functools.partial(jnp.einsum, preferred_element_type=jnp.float32)
-    h = _norm(c, x, layer["ln1_scale"], layer.get("ln1_bias"))
-    with jax.named_scope(scopes.SSD_PREP):
-        win = layer["ssd_win"].astype(cd)
-        z = checkpoint_name(proj("bsd,de->bse", h, win[:, :inner]).astype(cd), SSD_Z)
-        xbc = checkpoint_name(proj("bsd,de->bse", h, win[:, inner:inner + W]).astype(cd), SSD_XBC)
-        dt = proj("bsd,dh->bsh", h, win[:, inner + W:])  # float32
-        xbc = kda_ops.conv_silu(xbc, layer["ssd_conv"], layer["ssd_conv_bias"],
-                                interpret=kda_ops.kernel_mode())
-        dt = jax.nn.softplus(dt + layer["ssd_dt_bias"].astype(jnp.float32))
-        g = -jnp.exp(layer["ssd_a_log"].astype(jnp.float32)) * dt
-    with jax.named_scope(scopes.SSD_CORE):
-        fits = ssd_ops.fits(P, c.ssd_state, H, groups)
-        y = ssd_ops.ssd_flat(xbc, dt, g, H, groups, P, c.ssd_chunk,
-                             interpret=ssd_ops.kernel_mode() if fits else None)
-    with jax.named_scope(scopes.SSD_OUT):
-        # every per-channel operand stays (B, S, d_inner): see _kda_sublayer
-        skip = jnp.repeat(layer["ssd_d"].astype(jnp.float32), P)
-        u = y.astype(jnp.float32) + skip * xbc[..., :inner].astype(jnp.float32)
-        u = u * jax.nn.silu(z.astype(jnp.float32))
-        u = u * kda_ops.over_heads(
-            lax.rsqrt(kda_ops.head_sums(u * u, groups) / (inner // groups) + c.norm_eps),
-            inner // groups)
-        u = (u * layer["ssd_norm"].astype(jnp.float32)).astype(cd)
-        return x + proj("bse,ed->bsd", u, layer["wo"].astype(cd)).astype(cd)
-
-
-def ssd_stats(config: TinyGPTConfig, seq_len: int) -> Dict[str, Any]:
-    """Counters of the ``ssd`` layers over sequences of ``seq_len`` tokens,
-    from the config and the backend at trace time: ``layers`` of the kind,
-    ``chunk`` and ``chunks`` a sequence, ``chunk_steps`` the grid steps one
-    kernel call makes a sequence (a chunk of a group each), ``kernel_calls`` a
-    step by name (one forward and one backward a layer where the kernels run:
-    none on the ``jnp`` path; remat's second forward is the policy's, not
-    counted), ``conv_kernel_calls`` the same of the convolution's two, and
-    ``saved_state_bytes`` a layer a sequence: the states entering the chunks,
-    which the forward keeps for the backward beside its operands."""
-    from ..ops import kda as kda_ops
-    from ..ops import ssd as ssd_ops
-
-    c = config
-    layers = (c.layer_types or ()).count(scopes.SSD)
-    chunks = seq_len // c.ssd_chunk if layers else 0
-    on = layers > 0 and ssd_ops.kernel_mode() is not None
-    kernels = layers if on and ssd_ops.fits(
-        c.ssd_head_dim, c.ssd_state, c.ssd_heads, c.ssd_groups) else 0
-    convs = layers if on and kda_ops.conv_fits(seq_len, c.ssd_conv, c.ssd_xbc) else 0
-    return {
-        "layers": layers, "chunk": c.ssd_chunk, "chunks": chunks,
-        "chunk_steps": chunks * c.ssd_groups,
-        "kernel_calls": {"ssd_fwd": kernels, "ssd_bwd": kernels},
-        "conv_kernel_calls": {"kda_conv_fwd": convs, "kda_conv_bwd": convs},
-        "saved_state_bytes": (chunks * c.ssd_inner * c.ssd_state
-                              * jnp.dtype(c.compute_dtype).itemsize),
-    }
-
-
-def _conv_sublayer(c: TinyGPTConfig, x: jax.Array, layer: Params) -> jax.Array:
-    """Norm -> gated short convolution -> residual: a ``conv`` layer's mixer, in
-    three scopes. ``sconv_in``: [B | C | x~] = h W_in, one (D, 3 D) product
-    whose result after its cast has a name (``SCONV_BCX``: ``full_keep_kernels``
-    keeps it, ``dots`` holds the product itself). ``sconv_core``:
-    C * conv(B * x~), the depthwise causal convolution of ``conv_taps``
-    positions with zeros before the sequence, no bias and no activation
-    (``ops.kda.gated_conv``: on a TPU where ``conv_fits`` one Mosaic call a
-    direction, ``sconv_fwd`` / ``sconv_bwd``, which find the three thirds of the
-    operand by their block specs; elsewhere the ``jnp`` chain). ``sconv_out``:
-    W_out."""
-    from ..ops import kda as kda_ops
-
-    cd = c.compute_dtype
-    proj = functools.partial(jnp.einsum, preferred_element_type=jnp.float32)
-    h = _norm(c, x, layer["ln1_scale"], layer.get("ln1_bias"))
-    with jax.named_scope(scopes.SCONV_IN):
-        bcx = checkpoint_name(
-            proj("bsd,de->bse", h, layer["sconv_win"].astype(cd)).astype(cd), SCONV_BCX)
-    with jax.named_scope(scopes.SCONV_CORE):
-        y = kda_ops.gated_conv(bcx, layer["sconv_taps"], interpret=kda_ops.kernel_mode())
-    with jax.named_scope(scopes.SCONV_OUT):
-        return x + proj("bse,ed->bsd", y, layer["wo"].astype(cd)).astype(cd)
-
-
-def sconv_stats(config: TinyGPTConfig, seq_len: int) -> Dict[str, Any]:
-    """Counters of the ``conv`` layers over sequences of ``seq_len`` tokens,
-    from the config and the backend at trace time: ``layers`` of the kind,
-    ``taps``, ``layers_in_kernel`` of them whose gated convolution the Mosaic
-    calls take (``kernel_calls`` a step by name, one forward and one backward a
-    layer; none on the ``jnp`` chain; remat's second forward is the policy's,
-    not counted), and the bytes one call moves a sequence each way at the
-    stored width: forward the (S, 3 D) operand in and (S, D) out, backward the
-    operand, the cotangent and the (S, 3 D) result."""
-    from ..ops import kda as kda_ops
-
-    c = config
-    layers = (c.layer_types or ()).count(scopes.CONV)
-    taken = layers if (layers and kda_ops.kernel_mode() is not None
-                       and kda_ops.conv_fits(seq_len, c.conv_taps, c.n_embd)) else 0
-    cell = seq_len * c.n_embd * jnp.dtype(c.compute_dtype).itemsize
-    return {
-        "layers": layers, "taps": c.conv_taps, "layers_in_kernel": taken,
-        "kernel_calls": {"sconv_fwd": taken, "sconv_bwd": taken},
-        "forward_bytes": 4 * cell if layers else 0,
-        "backward_bytes": 7 * cell if layers else 0,
-    }
 
 
 def _pin_mlp_hidden(c: TinyGPTConfig, h: jax.Array) -> jax.Array:
@@ -2212,90 +1278,6 @@ def bd_stream(
     return jnp.concatenate([noisy, idx], axis=1), weights, masked
 
 
-def _kv_heads_in_kernel(config: TinyGPTConfig, kind: Optional[str] = None) -> int:
-    """The head count k and v enter a ``kind`` layer's attention body with:
-    the model's ``kv_heads`` where the flash kernels read them as they are
-    (``ops.flash_attention.kv_heads_in_kernel``: under the mesh this is called
-    in), the query heads' where a body takes whole heads (``_whole_heads``)
-    or the layer makes a k and a v a head itself (latent attention)."""
-    from ..ops import flash_attention as fa
-
-    heads = config.heads(kind)
-    if config.attention_impl != "flash" or config.latent_attention:
-        return heads
-    return fa.kv_heads_in_kernel(heads, config.kv_heads)
-
-
-def bd_mask_stats(config: TinyGPTConfig, seq_len: int) -> Dict[str, int]:
-    """Counters of one head's attention over documents of ``seq_len`` tokens
-    under ``block_diffusion``, from the mask rule (no array is made): the true
-    pairs, and what the forward and the backward kernel visit of all there
-    is, at the tiles and pieces ``ops.flash_attention`` picks for the stream
-    and in the unit each kernel skips by (``visited_units``): the (piece,
-    piece) piece where the rule gives its tiles shapes (``*_live_tiles``
-    pieces visited of ``*_tiles``, ``*_tile_pairs`` pairs a piece), the
-    whole tile where it does not; and ``kv_heads_in_kernel``, the heads of k
-    and v the kernels were handed (``_kv_heads_in_kernel``)."""
-    from ..ops import flash_attention as fa
-
-    S = 2 * seq_len
-    rule = config.mask_rule(S)
-    bq, bk, bk_bwd, _ = fa.pick_tiles(S, config.qk_dim, config.compute_dtype, causal=rule)
-    live_fwd, all_fwd, unit_fwd = fa.visited_units(rule, S, bq, bk, fa._fwd_sub_k(bk))
-    live_bwd, all_bwd, unit_bwd = fa.visited_units(
-        rule, S, bq, bk_bwd, fa._bwd_sub_q(bq, config.dropout))
-    return {
-        "true_pairs": rule.tile_counts(bq, bk)[2],
-        "fwd_live_tiles": live_fwd, "fwd_tiles": all_fwd, "fwd_tile_pairs": unit_fwd,
-        "bwd_live_tiles": live_bwd, "bwd_tiles": all_bwd, "bwd_tile_pairs": unit_bwd,
-        "kv_heads_in_kernel": _kv_heads_in_kernel(config),
-    }
-
-
-def attn_mask_stats(config: TinyGPTConfig, seq_len: int) -> Dict[str, Dict[str, int]]:
-    """Counters of one head's attention over ``seq_len`` positions by kind of
-    layer (``layer_types``; one entry, ``global``, for a stack of one kind),
-    from each kind's mask rule at the tiles and pieces ``ops.flash_attention``
-    picks (no array is made): ``layers`` of the kind, ``true_pairs`` the rule
-    allows, the kind's query ``heads`` and the ``kv_heads_in_kernel`` its k and v
-    entered the kernels with (``_kv_heads_in_kernel``: the model's kv heads
-    where the index maps do the sharing, ``heads`` where k and v were
-    repeated or nothing is shared), the (queries, keys) ``fwd_tile`` and
-    ``bwd_tile`` taken, and for the forward and the fused backward kernel ``*_live_tiles``
-    (tiles that hold a pair), ``*_grid_steps`` (steps a head's grid makes: the
-    square's under causal, the band's under a window; the difference brings a
-    tile, or holds the last one, and multiplies nothing) and
-    ``*_pairs_multiplied`` (the area of what the bodies walk: a *lower* tile's
-    pieces on and below its piece diagonal, else whole tiles)."""
-    from ..ops import flash_attention as fa
-
-    kinds = config.layer_types or (scopes.GLOBAL,) * config.n_layer
-    stats = {}
-    for kind in sorted(set(kinds) - _NO_ATTENTION):  # no mask there: kda_stats, ssd_stats
-        rule = config.mask_rule(seq_len, kind if config.layer_types else None)
-        bq, bk, bk_bwd, _ = fa.pick_tiles(
-            seq_len, config.qk_dim, config.compute_dtype, causal=rule)
-        window = isinstance(rule, fa.SlidingWindow)
-        entry = {"layers": kinds.count(kind),
-                 "heads": config.heads(kind if config.layer_types else None),
-                 "kv_heads_in_kernel": _kv_heads_in_kernel(
-                     config, kind if config.layer_types else None),
-                 "fwd_tile": (bq, bk), "bwd_tile": (bq, bk_bwd),
-                 "true_pairs": (rule.true_pairs(seq_len) if window
-                                else seq_len * (seq_len + 1) // 2 if rule else seq_len ** 2)}
-        for name, keys, piece in (("fwd", bk, fa._fwd_sub_k(bk)),
-                                  ("bwd", bk_bwd, fa._bwd_sub_q(bq, config.dropout))):
-            units, _, unit_pairs = fa.visited_units(rule, seq_len, bq, keys, piece)
-            tiles = fa.tiles_by_shape(rule, seq_len, bq, keys, piece)
-            entry[f"{name}_live_tiles"] = int(sum(t.sum() for t in tiles.values()))
-            entry[f"{name}_grid_steps"] = (
-                rule.grid_counts(seq_len, bq, keys, name == "fwd")[1] if window
-                else (seq_len // bq) * (seq_len // keys))
-            entry[f"{name}_pairs_multiplied"] = units * unit_pairs
-        stats[kind] = entry
-    return stats
-
-
 def apply_blocks(
     config: TinyGPTConfig,
     blocks: Params,  # stacked block params, leading 'layers' axis (may be a slice)
@@ -2402,13 +1384,9 @@ def apply_blocks(
 def remat_kept_names() -> Tuple[str, ...]:
     """The ``checkpoint_name``s ``dots`` and ``full_keep_kernels`` keep through
     remat: one list for every layer kind (``_under_remat`` has the rule)."""
-    from ..ops.flash_attention import FLASH_RESIDUAL_NAMES
-    from ..ops.kda import KDA_RESIDUAL_NAMES
-    from ..ops.ssd import SSD_RESIDUAL_NAMES
     from .moe import MOE_RESIDUAL_NAMES
 
-    return (*FLASH_RESIDUAL_NAMES, *KDA_RESIDUAL_NAMES, *SSD_RESIDUAL_NAMES,
-            *MOE_RESIDUAL_NAMES, *MATMUL_CAST_NAMES)
+    return (*mixers.RESIDUAL_NAMES, *MOE_RESIDUAL_NAMES, *MATMUL_CAST_NAMES)
 
 
 def _under_remat(pol: str, block):
@@ -2418,46 +1396,27 @@ def _under_remat(pol: str, block):
     values that are dear to make again and cheap to hold**, by name
     (``remat_kept_names``: one list, whatever the layer's kind; a name a block
     does not produce costs nothing): ``full_keep_kernels`` those alone, ``dots``
-    those beside every matmul result (a ``dot_general`` without batch dims, x @
-    W), so that its backward recomputes only LN/GELU/softmax/dropout and the
-    cheap elementwise chains. The list: the mixer's forward kernel's results (no
-    ``dot_general``: the flash kernel's output and row sums,
-    ``FLASH_RESIDUAL_NAMES``; a ``kda`` layer's output and the states entering
-    its chunks, ``KDA_RESIDUAL_NAMES``; an ``ssd`` layer's the same,
-    ``SSD_RESIDUAL_NAMES``), the routed experts' gate+up (or up) grouped
-    matmul's result (a Mosaic call too), the router's ``HIGHEST``-precision
-    logits, its choice and the plan that moves rows (sorts; ``moe.
-    MOE_RESIDUAL_NAMES``) and, after their casts, the wide products of
-    ``MATMUL_CAST_NAMES``: a ``kda`` layer's q, k, v projection (``KDA_QKV``), a
-    dense SwiGLU layer's gate+up (``MLP_GU``), an ``ssd`` layer's x | B | C and z
-    products of ``in_proj`` (``SSD_XBC``, ``SSD_Z``; dt is 64 columns of float32
-    and has no name), the up product of a shared expert that is not gated
-    (``SHARED_U``) and a ``conv`` layer's B | C | x~ projection
-    (``SCONV_BCX``). ``dots`` holds those as their ``dot_general``'s results
-    already and leaves their names out: with them the policy would trade each
-    product for its cast, and no second run would go.
+    those beside every matmul result (a ``dot_general`` without batch dims), so
+    that its backward recomputes only the cheap elementwise chains. The list:
+    each mixer's forward kernel's results (its module's ``RESIDUAL_NAMES``: the
+    flash kernel's output and row sums, a ``kda`` or ``ssd`` layer's output and
+    chunk states), the routed experts' gate+up (or up) grouped matmul's result,
+    the router's ``HIGHEST``-precision logits, its choice and the plan that
+    moves rows (``moe.MOE_RESIDUAL_NAMES``) and, after their casts, the wide
+    products of ``MATMUL_CAST_NAMES``, which ``dots`` holds as their
+    ``dot_general``'s results already and leaves out (with the names it would
+    trade each product for its cast, and no second run would go).
 
     **The rule for the list has two clauses** (``tests/test_remat_flash.py``
-    holds the first; PERF.md, PRs 50, 52 and 55, has the readings). (1) A value
-    is named only if, in the benchmark cell where it is largest, its second run
-    costs at least 5 ms a step per GB it holds. (2) With it that cell keeps at
-    least 1.5 GB of HBM free: the allocator's limit (16.909 GB on a v5e) less
-    the peak of the step compiled under the cell's own policy
+    holds the first; PERF.md, PRs 50, 52 and 55, has the readings by cell).
+    (1) A value is named only if, in the benchmark cell where it is largest, its
+    second run costs at least 5 ms a step per GB it holds. (2) With it that cell
+    keeps at least 1.5 GB of HBM free: the allocator's limit (16.909 GB on a
+    v5e) less the peak of the step compiled under the cell's own policy
     (``hbm_headroom_gb``; ``perfbench/tools/describe_cell.py`` reads the same
-    peak without a chip). Nothing else belongs to the rule: not the margin a
-    cell's policy was picked by when the cell was added. With every name on the
-    list the three cells under ``full_keep_kernels`` and the tightest under
-    ``dots`` keep 2.79 (Kimi), 2.04 (LFM2), 1.87 (Nemotron) and 2.34 GB
-    (DeepSeek). A name goes to the value in its compute-dtype or integer form,
-    never to the float32 in front of a cast. A product 2688 deep costs 15 ms a
-    GB of its bfloat16 result (the Nemotron cell's ``in_proj`` and shared up
-    product) and one 2048 deep 10.9 (the LFM2 cell's ``W_in``): three and two
-    times the rule. By the first clause what a convolution makes of a named
-    product (a ``kda`` layer's q, k, v 4.1 ms a GB, an ``ssd`` layer's
-    x | B | C 3.9), the gated shared experts' gate+up (4.0 in the Kimi cell) and
-    ``dispatch``'s gathered rows (1.5) stay dropped; by the second a ``conv``
-    layer's gated result does (6.0 ms a GB, 0.537 GB in the LFM2 cell: with it
-    beside ``SCONV_BCX`` the cell would keep 1.4997 GB), so it has no name."""
+    peak without a chip). Nothing else belongs to the rule. A name goes to the
+    value in its compute-dtype or integer form, never to the float32 in front
+    of a cast."""
     if pol == "none":
         return block
     if pol == "full":
@@ -2569,9 +1528,8 @@ def apply_layer(config: TinyGPTConfig, layer: Params, x: jax.Array, kind: Option
     """One layer of ``kind`` as the unrolled loop over unequal stacks runs it:
     ``_block``'s two halves, **each under the config's remat policy on its
     own** (the backward then holds one sublayer's recomputed activations at a
-    time, not a KDA mixer's beside a 9216-wide MLP's: 0.9 GB at the Kimi
-    cell's sizes, for one more (B, S, D) kept a layer; both halves keep the
-    one list of names, ``remat_kept_names``), with the per-layer
+    time, for one more (B, S, D) kept a layer; both halves keep the one list
+    of names, ``remat_kept_names``), with the per-layer
     placement hooks, on the layer's own slice of its stack -> (x, aux). Also
     what a check calls to run one layer of the timed config alone."""
     c, pol = config, normalize_remat(config.remat)
@@ -2707,22 +1665,15 @@ def _walk_routers(config: TinyGPTConfig, params: Params, idx: jax.Array, read):
         layer = layer_weights(c, params, i)
         kind = None if c.layer_types is None else c.layer_types[i]
         has_mixer, has_mlp = c.halves(kind)
-        if not has_mixer:
-            pass
-        elif kind == scopes.KDA:
-            x = _kda_sublayer(c, x, layer)
-        elif kind == scopes.SSD:
-            x = _ssd_sublayer(c, x, layer)
-        elif kind == scopes.CONV:
-            x = _conv_sublayer(c, x, layer)
-        else:
-            x = _attention_sublayer(c, x, layer, None, True, kind)
+        if has_mixer:
+            x = _mixer_half(c, x, layer, None, True, kind, None)
         if not has_mlp:
             continue
         if "router" in layer:
             found.append(read(c, layer, _norm(c, x, layer["ln2_scale"], layer.get("ln2_bias"))))
         x, _ = _mlp_sublayer(c, x, layer, None, True)
     return found
+
 
 
 def _token_nll(logits: jax.Array, targets: jax.Array) -> Tuple[jax.Array, jax.Array]:

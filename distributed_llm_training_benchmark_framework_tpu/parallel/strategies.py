@@ -428,16 +428,14 @@ _EP_RULES = {
 
 
 def _leaf_name(path) -> str:
-    """'blocks/<leaf>' for a leaf of any stack of layers (models/tinygpt.py
-    ``_STACK_NAMES``: 'blocks', the leading dense 'dense_blocks', the KDA
-    layers' 'kda_blocks' / 'kda_dense_blocks', the gated-convolution layers'
-    'conv_blocks' / 'conv_dense_blocks', the stacks by kind of
-    ``layer_heads`` and of ``block_halves``: 'global_blocks', 'ssd_blocks',
-    'mlp_blocks'): a leaf of one name has the same shape but for its widths
-    and the same role in every stack, and takes the same rules. A KDA, SSD or
-    conv mixer's own leaves ('kda_*', 'ssd_*', 'sconv_*') and the relu2 experts' up projections
-    ('moe_wu', 'shared_wu') have no tensor-parallel rule: under a 'model' axis
-    they stay whole."""
+    """'blocks/<leaf>' for a leaf of any stack of layers (every name ends in
+    'blocks': 'blocks', the leading dense 'dense_blocks', the stacks by a
+    mixer's name, ``models/mixers/``'s ``STACKS``, and the stacks by kind of
+    ``layer_heads`` and of ``block_halves``): a leaf of one name has the same
+    shape but for its widths and the same role in every stack, and takes the
+    same rules. Leaves that only ``tinygpt.PARAM_AXIS_RULES`` knows (a mixer's
+    own that is not attention's; the relu2 experts' up projections) have no
+    tensor-parallel rule: under a 'model' axis they stay whole."""
     name = "/".join(str(getattr(p, "key", p)) for p in path)
     stack, _, leaf = name.partition("/")
     return f"blocks/{leaf}" if leaf and stack.endswith("blocks") else name
@@ -537,7 +535,7 @@ def param_partition_specs(
     callers) gates the GQA kv projections' 'model' sharding: the column
     split is only head-aligned when the 'model' degree divides ``kv_heads``.
     A misaligned split shards WITHIN each kv head's feature block, and the
-    consecutive-block kv repeat (``tinygpt._whole_heads``; in front of
+    consecutive-block kv repeat (``mixers.attention._whole_heads``; in front of
     ``flash_attention``'s shard_map at such a degree) then needs a layout the
     partitioner cannot produce in place — it falls back to
     full-replicate-then-repartition of every per-layer k/v tensor (measured:

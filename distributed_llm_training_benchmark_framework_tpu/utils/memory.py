@@ -31,6 +31,7 @@ real peak ±20%); the capacity check applies a safety margin accordingly.
 
 from __future__ import annotations
 
+import collections
 import dataclasses
 import functools
 from typing import Any, Dict, Optional
@@ -111,14 +112,14 @@ def estimate_hbm(
     dataset_size: int = 0,
 ) -> HBMEstimate:
     """Estimate the per-chip HBM footprint of one training arm."""
-    from ..models import tinygpt
+    from ..models import mixers, tinygpt
     from ..parallel import strategies as strat
 
     cfg = model_config
     params_shape = jax.eval_shape(
         functools.partial(tinygpt.init_params, cfg), jax.random.key(0)
     )
-    scan_stacked = bool(getattr(cfg, "scan_layers", True))
+    scan_stacked = cfg.scan_layers
     param_specs = strat.param_partition_specs(
         params_shape, mesh, shard=strategy.shard_params, kv_heads=cfg.kv_heads,
         scan_stacked=scan_stacked,
@@ -146,10 +147,10 @@ def estimate_hbm(
     S, D, L, V = seq_len, cfg.n_embd, cfg.n_layer, cfg.vocab_size
     # the widest kind's query heads where a kind has its own (layer_heads); the
     # output gate adds one (B, S, H) f32 a layer, which the coefficients below hold
-    H = max([cfg.n_head] + [n for _, n in getattr(cfg, "layer_heads", None) or ()])
+    H = max([cfg.n_head] + [n for _, n in cfg.layer_heads or ()])
     # Block diffusion runs its layers over the stream of two copies of each
     # document; the head sees the noisy copy only (the logits below stay S).
-    layer_S = 2 * S if getattr(cfg, "block_diffusion", None) is not None else S
+    layer_S = 2 * S if cfg.block_diffusion is not None else S
     tp = mesh.shape.get("model", 1)
     pp = mesh.shape.get("pipe", 1)
     cbytes = jnp_itemsize(cfg.compute_dtype)
@@ -158,8 +159,8 @@ def estimate_hbm(
     # (F=4D, gelu) reproduces the original 14·BSD coefficient. GQA's k/v
     # are repeated to full H before attention (models.tinygpt), so no
     # activation credit is taken for kv_heads < n_head.
-    F = getattr(cfg, "mlp_dim", 4 * D) or 4 * D
-    mlp_widths = (2 if getattr(cfg, "mlp_act", "gelu") == "swiglu" else 1) * F / D
+    F = cfg.mlp_dim
+    mlp_widths = (2 if cfg.mlp_act == "swiglu" else 1) * F / D
     dense_per_layer = int((10 + mlp_widths) * B * layer_S * D) * cbytes
     # Megatron TP shards the head and MLP activations.
     dense_per_layer = dense_per_layer // max(tp, 1)
@@ -170,48 +171,19 @@ def estimate_hbm(
         # kernels, which keep no score, visit the band alone).
         dense_per_layer += 2 * B * (H // max(tp, 1)) * layer_S * layer_S * 4
     layers_here = L // max(pp, 1)
-    from ..models.tinygpt import normalize_remat
-
-    pol = normalize_remat("full" if cfg.remat == "auto" else cfg.remat)
-    # A KDA layer's recurrence keeps for its backward the states entering its
-    # chunks and its output (by name under 'dots' and 'full_keep_kernels' too)
-    # and, without remat, its five operands (g in float32); under 'full' it
-    # runs again.
-    kda_layers = (getattr(cfg, "layer_types", None) or ()).count("kda")
-    kda_b = 0
-    if kda_layers and pol != "full":
-        width = cfg.kda_heads * cfg.kda_head_dim
-        kept = tinygpt.kda_stats(cfg, S)["saved_state_bytes"] + S * width * cbytes
-        if pol == "none":
-            kept += S * width * (3 * cbytes + 4) + S * cfg.kda_heads * 4
-        kda_b = kda_layers * B * kept
-    # An SSD (Mamba-2) layer's scan likewise: the states entering its chunks and
-    # its output by name; without remat also x | B | C before and after the
-    # convolution, z, and dt and the sums of the log-decay in float32 (under
-    # 'full_keep_kernels' x | B | C before the convolution and z are named:
-    # counted with named_b below).
-    ssd_layers = (getattr(cfg, "layer_types", None) or ()).count("ssd")
-    if ssd_layers and pol != "full":
-        kept = tinygpt.ssd_stats(cfg, S)["saved_state_bytes"] + S * cfg.ssd_inner * cbytes
-        if pol == "none":
-            kept += S * ((2 * cfg.ssd_xbc + cfg.ssd_inner) * cbytes + 2 * cfg.ssd_heads * 4)
-        kda_b += ssd_layers * B * kept
-    # A conv layer (a gated short convolution) keeps nothing of its own but,
-    # without remat, B | C | x~ and the gated result (under 'full_keep_kernels'
-    # B | C | x~ is named, tinygpt.SCONV_BCX: counted with named_b below; the
-    # gated result has no name).
-    conv_layers = (getattr(cfg, "layer_types", None) or ()).count("conv")
-    if conv_layers and pol == "none":
-        kda_b += conv_layers * B * S * 4 * D * cbytes
-    # What 'dots' and 'full_keep_kernels' keep by name beside the mixer
-    # kernels' results (tinygpt._under_remat has the list): a routed layer's
-    # gate+up over the rows its experts take, its router's float32 logits
-    # where the routing trains (the plan's integer arrays are kilobytes) and,
-    # under 'full_keep_kernels' alone ('dots' counts its matmul results
-    # below), the wide products of tinygpt.MATMUL_CAST_NAMES: a KDA layer's q,
-    # k, v projection, a dense SwiGLU layer's gate+up, an SSD layer's x | B | C
-    # and z, the up product of a shared expert that is not gated, and a conv
-    # layer's B | C | x~.
+    pol = tinygpt.normalize_remat("full" if cfg.remat == "auto" else cfg.remat)
+    # What the layers' mixers keep for their backward beyond the coefficients
+    # above (their kernels' residuals; the products 'full_keep_kernels' names
+    # in them), each by its module (models/mixers/: kept_bytes).
+    kinds = collections.Counter(k for k in cfg.layer_types or () if cfg.halves(k)[0])
+    mixer_b = sum(layers * B * mixers.of(kind).kept_bytes(cfg, pol, S, cbytes)
+                  for kind, layers in kinds.items())
+    # What 'dots' and 'full_keep_kernels' keep by name beside those
+    # (tinygpt._under_remat has the list): a routed layer's gate+up over the
+    # rows its experts take, its router's float32 logits where the routing
+    # trains and, under 'full_keep_kernels' alone ('dots' counts its matmul
+    # results below), a dense SwiGLU layer's gate+up and the up product of a
+    # shared expert that is not gated.
     named_b = 0
     if pol in ("dots", "full_keep_kernels"):
         tokens = B * layer_S
@@ -225,9 +197,6 @@ def estimate_hbm(
             if cfg.trains_routing:
                 named_b += moe_layers * tokens * cfg.n_experts * 4
         if pol == "full_keep_kernels":
-            named_b += kda_layers * tokens * 3 * cfg.kda_heads * cfg.kda_head_dim * cbytes
-            named_b += ssd_layers * tokens * (cfg.ssd_xbc + cfg.ssd_inner) * cbytes
-            named_b += conv_layers * tokens * 3 * D * cbytes
             if cfg.mlp_act == "relu2":  # a shared expert that is not gated (shared_dim 0: none)
                 named_b += moe_layers * tokens * cfg.shared_dim * cbytes
             if cfg.mlp_act == "swiglu":
@@ -257,7 +226,7 @@ def estimate_hbm(
 
     return HBMEstimate(
         params=params_b, grads=grads_b, opt_state=opt_b,
-        activations=act_b + kda_b + named_b, logits=logits_b, dataset=dataset_b,
+        activations=act_b + mixer_b + named_b, logits=logits_b, dataset=dataset_b,
     )
 
 
@@ -330,40 +299,26 @@ def resolve_auto_remat(
     device_kind: str = "",
     aot_probe: Optional[Any] = None,
 ) -> Any:
-    """Resolve a strategy's remat="auto" to the cheapest policy that fits.
-
-    Tries "none" -> "dots" -> "full" against :func:`estimate_hbm` +
-    :func:`check_fits` for this arm's actual (batch, seq, mesh) geometry.
-    Remat trades recompute for memory; paying the tax when the arm already
-    fits measured ~20% of zero3's single-chip throughput (docs/PERFORMANCE
-    .md), so the tax is only paid under actual memory pressure. Returns the
-    strategy unchanged unless remat == "auto". Unknown device kinds (CPU)
-    are never refused by check_fits, so they resolve to "none".
-
-    The analytic policy choice uses a STRICTER margin than the go/no-go
-    pre-flight (AUTO_REMAT_MARGIN vs check_fits' 0.95): measured peaks run
-    up to ~13% above the analytic estimate (XLA temp buffers the model
-    ignores — see the est-vs-measured table in docs/PERFORMANCE.md), so a
-    nominal analytic fit near capacity cannot be trusted. But an analytic
-    REJECTION near capacity cannot be trusted either: at 16K the cheapest
-    policy that actually fits ("none", measured buffer-assignment peak
-    15.53e9 of 17.18e9 bytes) is 26% faster than "full", and the analytic
-    margin alone would forfeit that. So when ``aot_probe`` is provided
-    (a callable (remat_policy) -> Optional[peak_bytes] — the harness wires
-    train.step.abstract_step_peak_bytes), policies in the ambiguous band
-    (analytic margin rejects, estimate still <= nominal capacity) are
-    decided by an abstract AOT compile of the real step: accept iff XLA's
-    measured buffer-assignment peak fits AOT_PROBE_ACCEPT_MARGIN. Costs one
-    extra XLA compile per probed policy, only ever near capacity.
-    """
-    import dataclasses as _dc
-
+    """Resolve a strategy's remat="auto" to the cheapest policy that fits:
+    "none" -> "dots" -> "full" against :func:`estimate_hbm` + :func:`check_fits`
+    for this arm's (batch, seq, mesh); the strategy unchanged unless remat ==
+    "auto". Unknown device kinds (CPU) are never refused, so they resolve to
+    "none". The analytic choice uses a STRICTER margin than the go/no-go
+    pre-flight (AUTO_REMAT_MARGIN vs check_fits' 0.95): measured peaks run up
+    to ~13% above the estimate (XLA temp buffers the model ignores), so a
+    nominal fit near capacity cannot be trusted; nor can a rejection there (a
+    cheaper policy that does fit is a quarter faster). So with ``aot_probe`` (a
+    callable (remat_policy) -> Optional[peak_bytes]: the harness wires
+    train.step.abstract_step_peak_bytes) a policy in the ambiguous band (the
+    margin rejects, the estimate still fits nominal capacity) is decided by an
+    abstract AOT compile of the real step: accepted iff XLA's buffer-assignment
+    peak fits AOT_PROBE_ACCEPT_MARGIN. One extra compile a probed policy."""
     if getattr(strategy, "remat", None) != "auto":
         return strategy
     cap = device_hbm_bytes(device_kind)
     for pol in ("none", "dots", "full"):
-        cand = _dc.replace(strategy, remat=pol)
-        cfg = _dc.replace(model_config, remat=pol)
+        cand = dataclasses.replace(strategy, remat=pol)
+        cfg = dataclasses.replace(model_config, remat=pol)
         est = estimate_hbm(
             cfg, cand, mesh, per_device_batch, seq_len, dataset_size=dataset_size
         )
@@ -382,4 +337,4 @@ def resolve_auto_remat(
                 return cand
     # Nothing fits; return the most memory-frugal policy and let the
     # pre-flight check downstream produce the refusal message.
-    return _dc.replace(strategy, remat="full")
+    return dataclasses.replace(strategy, remat="full")

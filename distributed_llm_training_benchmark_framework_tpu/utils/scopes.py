@@ -38,7 +38,7 @@ ROUTER, DISPATCH, EXPERTS, COMBINE = MOE_SCOPES = (
 # them (DeepSeek-class layers).
 SHARED = "shared"
 
-# Inside ``attention``, where it is latent attention (models/tinygpt.py
+# Inside ``attention``, where it is latent attention (models/mixers/attention.py
 # ``_latent_attention``): the projections with the latent's norm and rotary,
 # the attention itself (the flash kernels), the output projection.
 MLA_PROJ, MLA_CORE, MLA_OUT = MLA_SCOPES = ("mla_proj", "mla_core", "mla_out")
@@ -49,16 +49,16 @@ MLA_PROJ, MLA_CORE, MLA_OUT = MLA_SCOPES = ("mla_proj", "mla_core", "mla_out")
 # every earlier position, latent attention too), a KDA one (the gated
 # delta-rule recurrence, ``ops/kda.py``), an SSD one (a Mamba-2 mixer: the
 # scalar-decay state-space scan, ``ops/ssd.py``) or a conv one (a gated short
-# convolution and nothing else, ``ops/kda.py::gated_conv``).
+# convolution and nothing else, ``ops/short_conv.py::gated_conv``): ``models/mixers/``.
 WINDOW, GLOBAL, KDA, SSD, CONV = LAYER_KIND_SCOPES = ("window", "global", "kda", "ssd", "conv")
 
-# Inside ``attention`` / ``kda`` (``models/tinygpt.py::_kda_sublayer``): the
+# Inside ``attention`` / ``kda`` (``models/mixers/kda.py::sublayer``): the
 # projections with their convolutions, SiLU, l2norm, the decay and beta; the
 # recurrence itself (the Mosaic calls ``kda_fwd`` / ``kda_bwd``); the head
 # norm, the gate and the output projection.
 KDA_PREP, KDA_CORE, KDA_OUT = KDA_SCOPES = ("kda_prep", "kda_core", "kda_out")
 
-# Inside ``attention`` / ``ssd`` (``models/tinygpt.py::_ssd_sublayer``): what
+# Inside ``attention`` / ``ssd`` (``models/mixers/ssd.py::sublayer``): what
 # stands before the scan (``in_proj``'s three products, the convolution with
 # its bias and SiLU, the Mosaic calls ``kda_conv_fwd`` / ``kda_conv_bwd``, and
 # dt's softplus with the log-decay); the scan itself (``ssd_fwd`` / ``ssd_bwd``
@@ -66,7 +66,7 @@ KDA_PREP, KDA_CORE, KDA_OUT = KDA_SCOPES = ("kda_prep", "kda_core", "kda_out")
 # ``out_proj``.
 SSD_PREP, SSD_CORE, SSD_OUT = SSD_SCOPES = ("ssd_prep", "ssd_core", "ssd_out")
 
-# Inside ``attention`` / ``conv`` (``models/tinygpt.py::_conv_sublayer``): the
+# Inside ``attention`` / ``conv`` (``models/mixers/conv.py::sublayer``): the
 # input projection to B | C | x~; the gated convolution C * conv(B * x~) (the
 # Mosaic calls ``sconv_fwd`` / ``sconv_bwd``, or the ``jnp`` chain); the output
 # projection.

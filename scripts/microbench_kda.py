@@ -15,7 +15,7 @@ each: ms a layer forward and forward + backward, the GB/s of the bytes one pass
 needs (x in and q, k, v out; x and three cotangents in and dx out), and
 ``out_err`` / ``grad_err`` against the chain in float32 on the same operands.
 
-``--gated``: a gated short-convolution mixer's middle (``ops.kda.gated_conv``)
+``--gated``: a gated short-convolution mixer's middle (``ops.short_conv.gated_conv``)
 at (2, rows, 3 x 2048) and 3 taps: its two Mosaic calls (``sconv_fwd`` /
 ``sconv_bwd``, a line a tile height of ``--gated-rows``) against the ``jnp``
 chain (the two gates and the taps as shifted products, XLA's fusions) and
@@ -117,7 +117,7 @@ def prep_lines(args, timed, rel):
     import jax
     import jax.numpy as jnp
 
-    from distributed_llm_training_benchmark_framework_tpu.ops import kda
+    from distributed_llm_training_benchmark_framework_tpu.ops import short_conv
 
     H, d, K = args.heads, 128, 4
 
@@ -131,13 +131,13 @@ def prep_lines(args, timed, rel):
         def f(x, taps):
             S = x.shape[1]
             xf = jnp.pad(x.astype(jnp.float32), ((0, 0), (K - 1, 0), (0, 0)))
-            return kda.silu_l2norm(sum(xf[:, i:i + S] * taps[i] for i in range(K)), heads)
+            return short_conv.silu_l2norm(sum(xf[:, i:i + S] * taps[i] for i in range(K)), heads)
         return f
 
     paths = {
-        "parent_chain": lambda heads: lambda x, taps: kda.silu_l2norm(
-            kda.causal_conv(x, taps, interpret=False), heads),
-        "qkv_prologue": lambda heads: lambda x, taps: kda.qkv_prologue(
+        "parent_chain": lambda heads: lambda x, taps: short_conv.silu_l2norm(
+            short_conv.causal_conv(x, taps, interpret=False), heads),
+        "qkv_prologue": lambda heads: lambda x, taps: short_conv.qkv_prologue(
             x, taps, heads, interpret=False),
     }
     loss = lambda f: lambda x, taps: sum(jnp.sum(o.astype(jnp.float32) ** 2) for o in f(x, taps))
@@ -164,7 +164,7 @@ def gated_lines(args, timed, rel):
     import jax
     import jax.numpy as jnp
 
-    from distributed_llm_training_benchmark_framework_tpu.ops import kda
+    from distributed_llm_training_benchmark_framework_tpu.ops import short_conv
 
     C, K, B = 2048, 3, 2
 
@@ -184,9 +184,9 @@ def gated_lines(args, timed, rel):
 
     def around_causal_conv(bcx, taps):  # the gates in jnp around the bare convolution's kernels
         b, c, x = (bcx[..., i * C:(i + 1) * C] for i in range(3))
-        return c * kda.causal_conv(b * x, taps, interpret=False)
+        return c * short_conv.causal_conv(b * x, taps, interpret=False)
 
-    kernels = lambda bcx, taps: kda.gated_conv(bcx, taps, interpret=False)
+    kernels = lambda bcx, taps: short_conv.gated_conv(bcx, taps, interpret=False)
     # (name, path, the kernels' tile height where it is theirs)
     paths = [("jnp_chain", chain(jnp.bfloat16), None),
              ("gates_around_causal_conv", around_causal_conv, None),
@@ -200,8 +200,8 @@ def gated_lines(args, timed, rel):
     for name, path, tile_rows in paths:
         line = {"path": name, "batch": B, "rows": args.rows, "columns": 3 * C}
         if tile_rows is not None:
-            kda._GATED_ROWS = tile_rows
-            kda._gated_call.cache_clear()
+            short_conv._GATED_ROWS = tile_rows
+            short_conv._gated_call.cache_clear()
         try:
             grads = jax.jit(jax.grad(loss(path), argnums=(0, 1)))(*small)
             line["out_err"] = rel(jax.jit(path)(*small), want)

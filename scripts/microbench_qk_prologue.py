@@ -1,7 +1,7 @@
 #!/usr/bin/env python
 """QK-norm + rotary between the projections and the flash kernels, alone on
 the chip: ``ops/rotary.py``'s one pass against the ``jnp`` chain it stands in
-for (``models/tinygpt.py``: ``_rms_norm`` -> ``_rope``), the forward and the
+for (``models/mixers/attention.py``: ``_rms_norm`` -> ``_rope``), the forward and the
 backward apart, at a cell's operand.
 
     chiprun -- python scripts/microbench_qk_prologue.py [--rows 16384] [--batch 1]
@@ -52,7 +52,8 @@ def main():
     import jax
     import jax.numpy as jnp
 
-    from distributed_llm_training_benchmark_framework_tpu.models import tinygpt
+    from distributed_llm_training_benchmark_framework_tpu.models import common
+    from distributed_llm_training_benchmark_framework_tpu.models.mixers import attention
     from distributed_llm_training_benchmark_framework_tpu.ops import rotary
 
     B, S, H, KV, D, norm = args.batch, args.rows, args.heads, args.kv_heads, 128, bool(args.norm)
@@ -67,9 +68,9 @@ def main():
         pos = jnp.arange(S, dtype=jnp.int32)
         q, k = q.reshape(B, S, H, D), k.reshape(B, S, KV, D)
         if norm:
-            q, k = tinygpt._rms_norm(q, qs, eps), tinygpt._rms_norm(k, ks, eps)
-        return (tinygpt._rope(q, pos, theta, rotary_dim=part),
-                tinygpt._rope(k, pos, theta, rotary_dim=part))
+            q, k = common._rms_norm(q, qs, eps), common._rms_norm(k, ks, eps)
+        return (attention._rope(q, pos, theta, rotary_dim=part),
+                attention._rope(k, pos, theta, rotary_dim=part))
 
     def forward(fn, q, k, qs, ks, wq, wk):
         a, b = fn(q, k, qs, ks)  # (B, S, heads, D) -> head-major, as flash reads them

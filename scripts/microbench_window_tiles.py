@@ -8,7 +8,7 @@ than the default tile (``_window_tile``).
 
 A line a tile: ms of one layer's forward and of its forward + backward (the
 fused backward from 4096 rows), the true pairs a head, the pairs the two
-kernels multiply at that tile (``tinygpt.attn_mask_stats``'s arithmetic) and
+kernels multiply at that tile (``mixers.attention.attn_mask_stats``'s arithmetic) and
 the fill. K and V enter repeated to the query heads, as in the layer.
 """
 

@@ -20,7 +20,10 @@ import numpy as np
 import pytest
 from jax import lax
 
-from distributed_llm_training_benchmark_framework_tpu.ops import kda
+from distributed_llm_training_benchmark_framework_tpu.models.mixers import (
+    kda as kda_mixer,
+)
+from distributed_llm_training_benchmark_framework_tpu.ops import kda, short_conv
 from perfbench.harness import reference_kda
 
 TOLERANCE = 2e-5
@@ -318,22 +321,22 @@ def test_the_convolutions_kernels_are_the_equation_tap_by_tap(seq):
     for i in range(4):
         d = 3 - i
         want[:, d:] += np.asarray(x)[:, :seq - d] * np.asarray(taps)[i]
-    got = kda.causal_conv(x, taps, interpret=True)
+    got = short_conv.causal_conv(x, taps, interpret=True)
     np.testing.assert_allclose(got, want, atol=2e-6)
-    np.testing.assert_allclose(kda.causal_conv(x, taps), want, atol=2e-6)
-    loss = lambda interpret: lambda x, t: jnp.sum(kda.causal_conv(x, t, interpret=interpret) * weights)
+    np.testing.assert_allclose(short_conv.causal_conv(x, taps), want, atol=2e-6)
+    loss = lambda interpret: lambda x, t: jnp.sum(short_conv.causal_conv(x, t, interpret=interpret) * weights)
     (dx, dtaps), (dx_want, dtaps_want) = (jax.grad(loss(i), (0, 1))(x, taps) for i in (True, None))
     np.testing.assert_allclose(dx, dx_want, atol=2e-6)
     np.testing.assert_allclose(dtaps, dtaps_want, rtol=1e-4, atol=1e-3)
-    assert "kda_conv_fwd" in str(jax.make_jaxpr(lambda x, t: kda.causal_conv(x, t, interpret=True))(x, taps))
+    assert "kda_conv_fwd" in str(jax.make_jaxpr(lambda x, t: short_conv.causal_conv(x, t, interpret=True))(x, taps))
 
 
 def test_the_convolution_takes_any_width_on_its_jnp_path():
     x = jax.random.normal(jax.random.key(0), (1, 30, 48))  # no whole tile of rows or lanes
     taps = jax.random.normal(jax.random.key(1), (4, 48))
-    got = kda.causal_conv(x, taps, interpret=True)  # falls back: the kernels take whole tiles
+    got = short_conv.causal_conv(x, taps, interpret=True)  # falls back: the kernels take whole tiles
     assert "pallas_call" not in str(jax.make_jaxpr(
-        lambda x, t: kda.causal_conv(x, t, interpret=True))(x, taps))
+        lambda x, t: short_conv.causal_conv(x, t, interpret=True))(x, taps))
     assert float(jnp.abs(got[:, 0] - x[:, 0] * taps[3]).max()) < 1e-6  # zeros before the sequence
 
 
@@ -385,7 +388,7 @@ def test_the_prologues_kernels_are_the_chain_and_its_gradients(seq, dtype, toler
     float32 chain on the same operands."""
     x, taps, weights = prologue_operands(seq, dtype)
     want = prologue_results(lambda x, t: chain(x, t, 4), x.astype(jnp.float32), taps, weights)
-    got = prologue_results(lambda x, t: kda.qkv_prologue(x, t, 4, interpret=True), x, taps, weights)
+    got = prologue_results(lambda x, t: short_conv.qkv_prologue(x, t, 4, interpret=True), x, taps, weights)
     for name, a, b in zip(("q", "k", "v", "dx", "dtaps"), got, want):
         assert a.shape == b.shape and a.dtype == (taps.dtype if name == "dtaps" else dtype), name
         assert relative(a, b) < tolerance, (name, relative(a, b))
@@ -401,7 +404,7 @@ def test_the_prologues_jnp_path_is_the_same_chain(dtype):
     operands' type as a model's ``jnp`` chain rounds."""
     x, taps, weights = prologue_operands(256, dtype)
     want = prologue_results(lambda x, t: chain(x, t, 4), x.astype(jnp.float32), taps, weights)
-    got = prologue_results(lambda x, t: kda.qkv_prologue(x, t, 4), x, taps, weights)
+    got = prologue_results(lambda x, t: short_conv.qkv_prologue(x, t, 4), x, taps, weights)
     for name, a, b in zip(("q", "k", "v", "dx", "dtaps"), got, want):
         assert relative(a, b) < (TOLERANCE if dtype == jnp.float32 else 2e-2), name
 
@@ -413,7 +416,7 @@ def test_the_prologue_is_a_call_a_third_each_way_and_keeps_x_alone():
     concatenation of anything as wide as a third, and nothing kept for the
     backward but the operands."""
     x, taps, weights = prologue_operands(512, jnp.bfloat16, batch=1)
-    f = lambda x, t: kda.qkv_prologue(x, t, 4, interpret=True)
+    f = lambda x, t: short_conv.qkv_prologue(x, t, 4, interpret=True)
     loss = lambda x, t: sum(jnp.sum(o.astype(jnp.float32) * w) for o, w in zip(f(x, t), weights))
     text = str(jax.make_jaxpr(jax.grad(loss, (0, 1)))(x, taps))
     assert text.count("name=kda_conv_fwd") == 3 and text.count("name=kda_conv_bwd") == 3
@@ -429,7 +432,7 @@ def test_the_prologue_is_a_call_a_third_each_way_and_keeps_x_alone():
 
 def test_the_prologue_falls_back_where_a_head_is_not_whole_lanes():
     x, taps, weights = prologue_operands(64, jnp.float32, heads=2, width=48)
-    f = lambda x, t: kda.qkv_prologue(x, t, 2, interpret=True)
+    f = lambda x, t: short_conv.qkv_prologue(x, t, 2, interpret=True)
     assert "pallas_call" not in str(jax.make_jaxpr(f)(x, taps))
     want = chain(x, taps, 2)
     for a, b in zip(f(x, taps), want):
@@ -457,7 +460,7 @@ def test_on_the_kernel_path_kda_prep_holds_no_sigmoid_and_no_full_precision_prod
     params = jax.eval_shape(lambda k: tinygpt.init_params(config, k), jax.random.key(0))
     layer = jax.tree.map(lambda leaf: jnp.zeros(leaf.shape[1:], leaf.dtype), params["kda_blocks"])
     x = jnp.zeros((1, 128, 64), config.compute_dtype)
-    loss = lambda layer, x: jnp.sum(tinygpt._kda_sublayer(config, x, layer).astype(jnp.float32))
+    loss = lambda layer, x: jnp.sum(kda_mixer.sublayer(config, x, layer).astype(jnp.float32))
     eqns = list(_equations(jax.make_jaxpr(jax.grad(loss, (0, 1)))(layer, x).jaxpr))
     prep = [e for path, e in eqns if "kda_prep" in path]
     assert prep and len(prep) < len(eqns)
@@ -484,8 +487,8 @@ def test_the_prologues_calls_are_made_once_a_shape():
     ``pallas_call`` object again (``_conv_call`` is cached by shape and static
     choices), so the kernels' bodies are traced once a program."""
     x, taps, _ = prologue_operands(512, jnp.bfloat16, batch=1)
-    kda._conv_call.cache_clear()
+    short_conv._conv_call.cache_clear()
     for _ in range(3):
-        jax.make_jaxpr(lambda x, t: kda.qkv_prologue(x, t, 4, interpret=True))(x, taps)
-    info = kda._conv_call.cache_info()
+        jax.make_jaxpr(lambda x, t: short_conv.qkv_prologue(x, t, 4, interpret=True))(x, taps)
+    info = short_conv._conv_call.cache_info()
     assert info.misses == 3 and info.hits == 6

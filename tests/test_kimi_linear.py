@@ -19,6 +19,11 @@ import numpy as np
 import pytest
 from jax.sharding import PartitionSpec as P
 
+from distributed_llm_training_benchmark_framework_tpu.models import mixers
+from distributed_llm_training_benchmark_framework_tpu.models.mixers import (
+    attention as attention_mixer,
+    kda as kda_mixer,
+)
 from distributed_llm_training_benchmark_framework_tpu.models import moe, tinygpt
 from distributed_llm_training_benchmark_framework_tpu.models.tinygpt import TinyGPTConfig
 from distributed_llm_training_benchmark_framework_tpu.parallel import get_strategy, make_mesh
@@ -112,7 +117,7 @@ def relative(got, want):
 
 
 def test_the_builder_gives_each_layer_its_mixer_and_each_stack_equal_leaves():
-    assert CONFIG.layer_types == KINDS == SHAPE["kinds"] and CONFIG.has_kda
+    assert CONFIG.layer_types == KINDS == SHAPE["kinds"] and mixers.own_leaves(CONFIG.layer_types)
     assert CONFIG.layer_groups == (
         ("kda_dense_blocks", (0,)), ("kda_blocks", (1, 2, 4)), ("blocks", (3,)))
     assert CONFIG.mla_nope and CONFIG.router_score == "sigmoid" and CONFIG.attn_scale is None
@@ -144,7 +149,7 @@ def test_a_plain_stack_keeps_its_two_names_and_its_draws():
     'dense_blocks' as before, and a seed draws what it drew: a leaf's first
     values, pinned."""
     plain = TinyGPTConfig(vocab_size=64, n_embd=32, n_head=2, n_layer=3, block_size=16)
-    assert plain.layer_groups == (("blocks", (0, 1, 2)),) and not plain.has_kda
+    assert plain.layer_groups == (("blocks", (0, 1, 2)),) and not mixers.own_leaves(plain.layer_types)
     deepseek = dataclasses.replace(
         CONFIG, layer_types=None, mla_nope=False, kda_heads=0, kda_head_dim=0,
         router_score="softmax", routed_scaling_factor=1.0, router_aux_coef=0.001)
@@ -253,11 +258,11 @@ def test_the_selection_bias_moves_the_choice_and_not_the_gate():
 def test_nope_latent_attention_is_the_reference_and_not_the_rotated_one(weights, batch):
     layer = tinygpt.layer_weights(CONFIG, weights, 3)
     x = jax.random.normal(jax.random.key(7), (BATCH, SEQ, 64))
-    got = tinygpt._attention_sublayer(CONFIG, x, layer, None, True, GLOBAL)
+    got = attention_mixer.sublayer(CONFIG, x, layer, None, True, GLOBAL)
     with jax.default_matmul_precision("highest"):
         want = jax.vmap(lambda x: reference_kda.latent_sublayer(SHAPE, x, layer))(x)
     assert relative(got - x, want - x) < TOLERANCE["logits"]
-    rotated = tinygpt._attention_sublayer(
+    rotated = attention_mixer.sublayer(
         dataclasses.replace(CONFIG, mla_nope=False), x, layer, None, True, GLOBAL)
     assert relative(rotated - x, want - x) > 10 * TOLERANCE["logits"]
 
@@ -266,11 +271,11 @@ def test_a_kda_layer_alone_is_the_reference(weights):
     layer = tinygpt.layer_weights(CONFIG, weights, 1)
     x = jax.random.normal(jax.random.key(8), (BATCH, SEQ, 64))
     with jax.default_matmul_precision("highest"):
-        got = tinygpt._kda_sublayer(CONFIG, x, layer)
+        got = kda_mixer.sublayer(CONFIG, x, layer)
         want = jax.vmap(lambda x: reference_kda.kda_sublayer(SHAPE, x, layer))(x)
     assert relative(got - x, want - x) < TOLERANCE["logits"]
     # causal: a later token moves no earlier output (the convolutions too)
-    moved = tinygpt._kda_sublayer(CONFIG, x.at[:, 40].add(1.0), layer)
+    moved = kda_mixer.sublayer(CONFIG, x.at[:, 40].add(1.0), layer)
     assert float(jnp.abs(moved[:, :40] - got[:, :40]).max()) < 1e-6
     assert float(jnp.abs(moved[:, 40:] - got[:, 40:]).max()) > 1e-3
 
@@ -426,15 +431,15 @@ def test_flops_and_memory_count_a_kda_layer():
 
 
 def test_kda_stats_count_chunks_calls_and_what_the_forward_keeps():
-    stats = tinygpt.kda_stats(CONFIG, SEQ)
+    stats = kda_mixer.kda_stats(CONFIG, SEQ)
     assert stats["layers"] == 4 and stats["chunk"] == 16 and stats["chunks"] == 4
     assert stats["kernel_calls"] == {"kda_fwd": 0, "kda_bwd": 0}  # the jnp path: no kernel here
     assert stats["prep_kernel_calls"] == {"kda_conv_fwd": 0, "kda_conv_bwd": 0}
     assert stats["saved_state_bytes"] == 4 * 4 * 16 * 16 * 4  # heads x chunks x d^2 x float32
     cell = dataclasses.replace(CONFIG, kda_heads=32, kda_head_dim=128, kda_chunk=128,
                                compute_dtype=jnp.bfloat16)
-    assert tinygpt.kda_stats(cell, 16384)["saved_state_bytes"] == 32 * 128 * 128 * 128 * 2
-    assert set(tinygpt.attn_mask_stats(CONFIG, SEQ)) == {GLOBAL}  # a kda layer has no mask
+    assert kda_mixer.kda_stats(cell, 16384)["saved_state_bytes"] == 32 * 128 * 128 * 128 * 2
+    assert set(attention_mixer.attn_mask_stats(CONFIG, SEQ)) == {GLOBAL}  # a kda layer has no mask
 
 
 def test_kda_stats_count_the_prologues_calls_where_its_kernels_run(monkeypatch):
@@ -442,10 +447,10 @@ def test_kda_stats_count_the_prologues_calls_where_its_kernels_run(monkeypatch):
     ``kda_conv_fwd`` / ``kda_conv_bwd``, a call each for q, k and v a layer;
     at the test's 16-wide heads, or on another backend, the ``jnp`` chain."""
     cell = dataclasses.replace(CONFIG, kda_heads=32, kda_head_dim=128, kda_chunk=128)
-    assert tinygpt.kda_stats(cell, 16384)["prep_kernel_calls"] == {"kda_conv_fwd": 0, "kda_conv_bwd": 0}
+    assert kda_mixer.kda_stats(cell, 16384)["prep_kernel_calls"] == {"kda_conv_fwd": 0, "kda_conv_bwd": 0}
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    stats = tinygpt.kda_stats(cell, 16384)
+    stats = kda_mixer.kda_stats(cell, 16384)
     assert stats["kernel_calls"] == {"kda_fwd": 4, "kda_bwd": 4}
     assert stats["prep_kernel_calls"] == {"kda_conv_fwd": 12, "kda_conv_bwd": 12}
-    assert tinygpt.kda_stats(cell, 16380)["prep_kernel_calls"]["kda_conv_fwd"] == 0  # not whole 8-row tiles
-    assert tinygpt.kda_stats(CONFIG, SEQ)["prep_kernel_calls"] == {"kda_conv_fwd": 0, "kda_conv_bwd": 0}
+    assert kda_mixer.kda_stats(cell, 16380)["prep_kernel_calls"]["kda_conv_fwd"] == 0  # not whole 8-row tiles
+    assert kda_mixer.kda_stats(CONFIG, SEQ)["prep_kernel_calls"] == {"kda_conv_fwd": 0, "kda_conv_bwd": 0}

@@ -22,6 +22,10 @@ import numpy as np
 import pytest
 from jax.sharding import PartitionSpec as P
 
+from distributed_llm_training_benchmark_framework_tpu.models import mixers
+from distributed_llm_training_benchmark_framework_tpu.models.mixers import (
+    attention as attention_mixer,
+)
 from distributed_llm_training_benchmark_framework_tpu.models import moe, tinygpt
 from distributed_llm_training_benchmark_framework_tpu.models.tinygpt import Rotary, YarnScaling
 from distributed_llm_training_benchmark_framework_tpu.ops import flash_attention as fa
@@ -124,7 +128,7 @@ def relative(got, want):
 def test_the_builder_gives_each_kind_its_heads_its_table_and_its_stack():
     assert CONFIG.layer_types == (GLOBAL, WINDOW, WINDOW, WINDOW, GLOBAL)
     assert (CONFIG.heads(GLOBAL), CONFIG.heads(WINDOW), CONFIG.kv_heads) == (6, 8, 2)
-    assert CONFIG.heads_by_kind and CONFIG.stacks_unequal and not CONFIG.has_kda
+    assert CONFIG.heads_by_kind and CONFIG.stacks_unequal and not mixers.own_leaves(CONFIG.layer_types)
     assert CONFIG.layer_groups == (
         ("global_dense_blocks", (0,)), ("window_blocks", (1, 2, 3)), ("global_blocks", (4,)))
     assert CONFIG.attn_gate and CONFIG.first_k_dense == 1 and CONFIG.n_shared_experts == 1
@@ -163,7 +167,7 @@ def test_the_accepted_configurations_keep_their_stacks_and_draws():
 def test_a_layers_rotation_is_its_kinds_table_over_its_lanes(kind):
     x = jax.random.normal(jax.random.key(4), (1, SEQ, 2, 16))
     rotary = CONFIG.rotary(kind)
-    got = tinygpt._rope(x, jnp.arange(SEQ), rotary.theta, rotary.scaling, rotary.rotary_dim)[0]
+    got = attention_mixer._rope(x, jnp.arange(SEQ), rotary.theta, rotary.scaling, rotary.rotary_dim)[0]
     cos, sin, lanes = reference_laguna.rotary_table(SHAPE, kind, jnp.arange(SEQ))
     assert lanes == (8 if kind == GLOBAL else 16) and cos.shape == (SEQ, lanes // 2)
     np.testing.assert_allclose(got, reference_laguna._rotate(SHAPE, x[0], cos, sin, lanes), atol=1e-5)
@@ -332,8 +336,8 @@ def test_the_pass_over_a_part_of_a_head_is_the_chain(heads, kv_heads, part):
         return rotary_ops.qk_prologue(q, k, None, None, table, 1e-6, interpret=True, rotary_dim=part)
 
     def the_chain(q, k):
-        return (tinygpt._rope(q.reshape(BATCH, S, heads, D), pos, theta, scaling, part),
-                tinygpt._rope(k.reshape(BATCH, S, kv_heads, D), pos, theta, scaling, part))
+        return (attention_mixer._rope(q.reshape(BATCH, S, heads, D), pos, theta, scaling, part),
+                attention_mixer._rope(k.reshape(BATCH, S, kv_heads, D), pos, theta, scaling, part))
 
     loss = lambda fn: lambda q, k: sum(jnp.sum(y * w) for y, w in zip(fn(q, k), (wq, wk)))
     for got, want in zip(the_pass(q, k), the_chain(q, k)):
@@ -354,20 +358,20 @@ def test_a_layer_takes_the_pass_by_its_kind(monkeypatch):
     so a kind, with the heads and the bytes of each."""
     file = {**FILE, "head_dim": 128}
     config = build_laguna.laguna_config(JOB, file)
-    assert tinygpt.qk_prologue_stats(config, SEQ)["pass_layers"] == 0  # the CPU: the chain
+    assert attention_mixer.qk_prologue_stats(config, SEQ)["pass_layers"] == 0  # the CPU: the chain
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    stats = tinygpt.qk_prologue_stats(config, SEQ)
+    stats = attention_mixer.qk_prologue_stats(config, SEQ)
     assert (stats["rotary_layers"], stats["pass_layers"], stats["norm_stage_layers"]) == (5, 5, 0)
     full, sliding = stats["by_kind"][GLOBAL], stats["by_kind"][WINDOW]
     assert (full["heads"], full["rotary_lanes"], full["rotary_layers"], full["pass_layers"]) == (6, 64, 2, 2)
     assert (sliding["heads"], sliding["rotary_lanes"], sliding["pass_layers"]) == (8, 128, 3)
     assert full["forward_bytes"] == full["backward_bytes"] == 2 * SEQ * (6 + 2) * 128 * 2
     assert sliding["forward_bytes"] == 2 * SEQ * (8 + 2) * 128 * 2
-    tables = tinygpt.qk_prologue_tables(config, SEQ)
+    tables = attention_mixer.qk_prologue_tables(config, SEQ)
     assert set(tables) == {GLOBAL, WINDOW} and tables[GLOBAL].shape == (SEQ, 128)
     assert bool(jnp.all(tables[GLOBAL][:, 64:] == 0.0)) and bool(jnp.any(tables[WINDOW][:, 64:] != 0.0))
     # heads of 16 are not the pass's: every layer keeps the chain, and says so
-    small = tinygpt.qk_prologue_stats(build_laguna.laguna_config(JOB, FILE), SEQ)
+    small = attention_mixer.qk_prologue_stats(build_laguna.laguna_config(JOB, FILE), SEQ)
     assert small["pass_layers"] == 0 and small["by_kind"][GLOBAL]["rotary_lanes"] == 8
 
 
@@ -382,7 +386,7 @@ def test_through_the_pass_the_loss_and_gradients_are_the_chains(batch, monkeypat
         lambda p: tinygpt.loss_fn(config, p, batch, batch)))(weights)
     want_loss, want = run()
     monkeypatch.setattr(rotary_ops, "kernel_mode", lambda: True)  # as a chip, interpreted
-    assert tinygpt.qk_prologue_stats(config, SEQ)["pass_layers"] == 5
+    assert attention_mixer.qk_prologue_stats(config, SEQ)["pass_layers"] == 5
     got_loss, got = run()
     assert abs(float(got_loss) - float(want_loss)) / float(want_loss) < TOLERANCE["loss"]
     for stack in STACKS:
@@ -411,7 +415,7 @@ def test_the_published_cells_counters_follow_the_tiles_taken():
     _, workload, file = manifest.load_cell("laguna-xs.2.share16-seq16384")
     config = manifest.resolve(file["builder"])(workload, file)
     shape = build_laguna.laguna_shape(workload, file)
-    stats = tinygpt.attn_mask_stats(config, 16384)
+    stats = attention_mixer.attn_mask_stats(config, 16384)
     window, whole = stats[WINDOW], stats[GLOBAL]
     assert (window["layers"], window["heads"], whole["layers"], whole["heads"]) == (3, 64, 2, 48)
     assert window["fwd_tile"] == window["bwd_tile"] == (512, 512)
@@ -424,7 +428,7 @@ def test_the_published_cells_counters_follow_the_tiles_taken():
     fill = 2 * window["true_pairs"] / (window["fwd_pairs_multiplied"] + window["bwd_pairs_multiplied"])
     assert round(100 * fill, 1) == 59.4
     _, workload, file = manifest.load_cell("mellum2-12b-a2.5b.share4-seq16384")
-    mellum = tinygpt.attn_mask_stats(manifest.resolve(file["builder"])(workload, file), 16384)[WINDOW]
+    mellum = attention_mixer.attn_mask_stats(manifest.resolve(file["builder"])(workload, file), 16384)[WINDOW]
     assert mellum["fwd_tile"] == mellum["bwd_tile"] == (1024, 1024) and mellum["heads"] == 32
     assert mellum["fwd_pairs_multiplied"] == 15 * 1024 ** 2 + 16 * 36 * 128 ** 2
 

@@ -18,9 +18,14 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from distributed_llm_training_benchmark_framework_tpu.models import mixers
+from distributed_llm_training_benchmark_framework_tpu.models.mixers import (
+    attention as attention_mixer,
+    conv as conv_mixer,
+)
 from distributed_llm_training_benchmark_framework_tpu.models import moe, tinygpt
 from distributed_llm_training_benchmark_framework_tpu.models.tinygpt import TinyGPTConfig
-from distributed_llm_training_benchmark_framework_tpu.ops import kda as kda_ops
+from distributed_llm_training_benchmark_framework_tpu.ops import short_conv
 from distributed_llm_training_benchmark_framework_tpu.parallel import get_strategy, make_mesh
 from distributed_llm_training_benchmark_framework_tpu.parallel import strategies
 from distributed_llm_training_benchmark_framework_tpu.train.step import create_train_state
@@ -110,7 +115,7 @@ def relative(got, want):
 
 
 def test_the_builder_gives_each_kind_its_stack_and_the_dense_layer_the_convolutions_kind():
-    assert CONFIG.layer_types == KINDS == SHAPE["kinds"] and CONFIG.has_conv
+    assert CONFIG.layer_types == KINDS == SHAPE["kinds"] and mixers.own_leaves(CONFIG.layer_types)
     assert CONFIG.layer_groups == (
         ("conv_dense_blocks", (0,)), ("blocks", (1,)), ("conv_blocks", (2, 3, 4)))
     assert (CONFIG.first_k_dense, CONFIG.n_moe_layers, SHAPE["moe_layers"]) == (1, 4, 4)
@@ -178,17 +183,17 @@ def _three_shifted_products(bcx, taps):
     (None, (2, 40, 24, 3)), (None, (1, 16, 8, 4)), (True, (2, 256, 128, 3)),
     (True, (1, 136, 256, 4))], ids=["jnp-3", "jnp-4", "kernel-3-b2", "kernel-4"])
 def test_gated_conv_is_the_three_shifted_products_forward_and_every_gradient(mode, shape):
-    """``ops.kda.gated_conv`` in its ``jnp`` form and in its kernels' interpreted
+    """``ops.short_conv.gated_conv`` in its ``jnp`` form and in its kernels' interpreted
     form against the reference's convolution written out: the output and the
     gradients by b, c, x (the three thirds of the operand) and the taps."""
     bcx, taps, cotangent = _operands(*shape)
     C = shape[2]
     run = lambda f: jax.value_and_grad(
         lambda bcx, taps: jnp.sum(f(bcx, taps) * cotangent), argnums=(0, 1))(bcx, taps)
-    (got, (got_dbcx, got_dtaps)) = run(lambda a, t: kda_ops.gated_conv(a, t, interpret=mode))
+    (got, (got_dbcx, got_dtaps)) = run(lambda a, t: short_conv.gated_conv(a, t, interpret=mode))
     (want, (want_dbcx, want_dtaps)) = run(_three_shifted_products)
     assert abs(float(got - want)) <= 1e-5 * abs(float(want)) + 1e-3
-    np.testing.assert_allclose(np.asarray(kda_ops.gated_conv(bcx, taps, interpret=mode)),
+    np.testing.assert_allclose(np.asarray(short_conv.gated_conv(bcx, taps, interpret=mode)),
                                np.asarray(_three_shifted_products(bcx, taps)), rtol=1e-5, atol=1e-5)
     for third, name in enumerate("bcx"):
         cut = slice(third * C, (third + 1) * C)
@@ -200,18 +205,18 @@ def test_gated_conv_is_the_three_shifted_products_forward_and_every_gradient(mod
 
 def test_gated_conv_in_bfloat16_rounds_once_and_its_kernels_agree_with_the_chain():
     bcx, taps, _ = _operands(2, 128, 128, 3, jnp.bfloat16)
-    kernel = kda_ops.gated_conv(bcx, taps, interpret=True)
-    chain = kda_ops.gated_conv(bcx, taps, interpret=None)
+    kernel = short_conv.gated_conv(bcx, taps, interpret=True)
+    chain = short_conv.gated_conv(bcx, taps, interpret=None)
     assert kernel.dtype == chain.dtype == jnp.bfloat16
     exact = _three_shifted_products(bcx, taps)
     assert relative(kernel.astype(jnp.float32), exact) < 2 ** -7
     # one rounding each, of sums taken in another order: an ulp of bfloat16 apart at the most
     np.testing.assert_allclose(np.asarray(kernel, np.float32), np.asarray(chain, np.float32),
                                rtol=2 ** -7, atol=1e-6)
-    assert not kda_ops.conv_fits(128, 3, 64)  # such an operand takes the chain, whatever the mode
+    assert not short_conv.conv_fits(128, 3, 64)  # such an operand takes the chain, whatever the mode
     narrow, taps, _ = _operands(1, 16, 64, 3)
-    np.testing.assert_array_equal(np.asarray(kda_ops.gated_conv(narrow, taps, interpret=True)),
-                                  np.asarray(kda_ops.gated_conv(narrow, taps, interpret=None)))
+    np.testing.assert_array_equal(np.asarray(short_conv.gated_conv(narrow, taps, interpret=True)),
+                                  np.asarray(short_conv.gated_conv(narrow, taps, interpret=None)))
 
 
 @pytest.mark.parametrize("mode, shape", [(None, (1, 24, 8, 3)), (True, (1, 256, 128, 3))],
@@ -222,8 +227,8 @@ def test_gated_conv_is_causal_and_starts_from_zeros(mode, shape):
     bcx, taps, _ = _operands(*shape)
     B, S, C, K = shape
     t = S // 2 + 3  # inside a tile, past its first rows
-    got = kda_ops.gated_conv(bcx, taps, interpret=mode)
-    moved = kda_ops.gated_conv(bcx.at[:, t, 2 * C:].add(1.0), taps, interpret=mode)
+    got = short_conv.gated_conv(bcx, taps, interpret=mode)
+    moved = short_conv.gated_conv(bcx.at[:, t, 2 * C:].add(1.0), taps, interpret=mode)
     np.testing.assert_array_equal(np.asarray(got[:, :t]), np.asarray(moved[:, :t]))
     np.testing.assert_array_equal(np.asarray(got[:, t + K:]), np.asarray(moved[:, t + K:]))
     assert float(jnp.abs(got[:, t:t + K] - moved[:, t:t + K]).min(-1).min()) > 0.0
@@ -244,9 +249,9 @@ def reference_part(shape, part, x, w):
 
 def program_part(config, part, layer, x):
     if part == CONV:
-        return tinygpt._conv_sublayer(config, x, layer)
+        return conv_mixer.sublayer(config, x, layer)
     if part == GLOBAL:
-        return tinygpt._attention_sublayer(config, x, layer, None, True, GLOBAL)
+        return attention_mixer.sublayer(config, x, layer, None, True, GLOBAL)
     return tinygpt._mlp_sublayer(config, x, layer, None, True)[0]
 
 
@@ -289,7 +294,7 @@ def test_attention_at_head_width_64_with_qk_norm_rotary_and_four_query_heads_a_k
     shape = build_lfm2.lfm2_shape(job, file)
     config = dataclasses.replace(build_lfm2.lfm2_config(job, file), compute_dtype=jnp.float32)
     assert config.head_dim == shape["head_dim"] == 64 and config.kv_heads == 1
-    assert not tinygpt._takes_qk_prologue(config, 128, GLOBAL)  # heads narrower than a vreg
+    assert not attention_mixer._takes_qk_prologue(config, 128, GLOBAL)  # heads narrower than a vreg
     layer = tinygpt.layer_weights(config, seeded_weights(config), 1)
     x = jax.random.normal(jax.random.key(5), (2, 128, 256))
     got = jax.jit(lambda l, x: program_part(config, GLOBAL, l, x))(layer, x) - x
@@ -504,7 +509,7 @@ def test_the_kind_has_a_scope_and_its_three_parts(weights, batch):
     assert "mlp/shared" not in text and f"{GLOBAL}/qk_prologue" not in text  # heads of 64: the chain
     # the projection has a name, which full_keep_kernels keeps (_under_remat's rule; PR 55)
     jaxpr = str(jax.make_jaxpr(lambda p, b: tinygpt.loss_fn(CONFIG, p, b, b))(weights, batch))
-    assert "name=sconv_bcx" in jaxpr and tinygpt.SCONV_BCX in tinygpt.remat_kept_names()
+    assert "name=sconv_bcx" in jaxpr and conv_mixer.SCONV_BCX in tinygpt.remat_kept_names()
 
 
 @pytest.mark.parametrize("change, match", [
@@ -542,7 +547,7 @@ def test_flops_and_memory_count_the_layers_by_kind():
     half_position = 4 * 0.5 * 4 * 16  # (S + 1) / 2 against S / 2 keys, 4 heads of 16
     assert got == pytest.approx(want - half_position)
     D = 64
-    assert flops.conv_forward_flops_per_token(CONFIG) == 2 * D * 3 * D + 2 * 3 * D + 2 * D * D
+    assert conv_mixer.forward_flops_per_token(CONFIG) == 2 * D * 3 * D + 2 * 3 * D + 2 * D * D
     mesh = make_mesh((1, 1, 1, 1, 1), MESH_AXES, devices=jax.devices()[:1])
     estimate = lambda config, remat: memory.estimate_hbm(
         dataclasses.replace(config, remat=remat), get_strategy("zero2"), mesh,
@@ -555,19 +560,19 @@ def test_flops_and_memory_count_the_layers_by_kind():
 
 
 def test_sconv_stats_count_layers_calls_and_bytes(monkeypatch):
-    stats = tinygpt.sconv_stats(CONFIG, SEQ)
+    stats = conv_mixer.sconv_stats(CONFIG, SEQ)
     assert (stats["layers"], stats["taps"], stats["layers_in_kernel"]) == (4, 3, 0)
     assert stats["kernel_calls"] == {"sconv_fwd": 0, "sconv_bwd": 0}  # the jnp chain off a TPU
     cell = dataclasses.replace(CONFIG, n_embd=2048, n_head=32, n_kv_head=8,
                                compute_dtype=jnp.bfloat16)
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    stats = tinygpt.sconv_stats(cell, 16384)
+    stats = conv_mixer.sconv_stats(cell, 16384)
     assert stats["layers_in_kernel"] == 4
     assert stats["kernel_calls"] == {"sconv_fwd": 4, "sconv_bwd": 4}
     assert stats["forward_bytes"] == 4 * 16384 * 2048 * 2
     assert stats["backward_bytes"] == 7 * 16384 * 2048 * 2
-    assert tinygpt.sconv_stats(dataclasses.replace(cell, n_embd=2112, n_head=33, n_kv_head=11),
+    assert conv_mixer.sconv_stats(dataclasses.replace(cell, n_embd=2112, n_head=33, n_kv_head=11),
                                16384)["layers_in_kernel"] == 0  # 2112 is no whole 128-lane tiles
-    assert tinygpt.attn_mask_stats(cell, 16384).keys() == {"global"}
-    assert tinygpt.attn_mask_stats(cell, 16384)["global"]["kv_heads_in_kernel"] in (8, 32)
-    assert tinygpt.qk_prologue_stats(cell, 16384)["pass_layers"] == 0  # heads of 64: the jnp chain
+    assert attention_mixer.attn_mask_stats(cell, 16384).keys() == {"global"}
+    assert attention_mixer.attn_mask_stats(cell, 16384)["global"]["kv_heads_in_kernel"] in (8, 32)
+    assert attention_mixer.qk_prologue_stats(cell, 16384)["pass_layers"] == 0  # heads of 64: the jnp chain

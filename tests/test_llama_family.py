@@ -17,6 +17,9 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from distributed_llm_training_benchmark_framework_tpu.models.mixers import (
+    attention as attention_mixer,
+)
 from distributed_llm_training_benchmark_framework_tpu.models import (
     TinyGPTConfig,
     init_params,
@@ -175,7 +178,7 @@ def test_rope_position_convention():
     [0..S) vs a shifted window must change the logits (position-dependence),
     and the _rope helper must agree with slicing a longer position range —
     the property the sequence-manual offset (pos + S*axis_index) relies on."""
-    from distributed_llm_training_benchmark_framework_tpu.models.tinygpt import _rope
+    from distributed_llm_training_benchmark_framework_tpu.models.mixers.attention import _rope
 
     x = jax.random.normal(jax.random.key(0), (1, 8, 2, 16), jnp.float32)
     pos_a = jnp.arange(8, dtype=jnp.int32)
@@ -456,7 +459,7 @@ def test_through_the_one_pass_prologue_the_loss_and_gradients_are_the_chains(mon
     run = lambda: jax.value_and_grad(lambda p: tinygpt.loss_fn(cfg, p, idx, idx))(params)
     want_loss, want = run()
     monkeypatch.setattr(rotary, "kernel_mode", lambda: True)  # as a chip, interpreted
-    stats = tinygpt.qk_prologue_stats(cfg, 64)
+    stats = attention_mixer.qk_prologue_stats(cfg, 64)
     assert (stats["rotary_layers"], stats["pass_layers"], stats["norm_stage_layers"]) == (1, 1, 0)
     got_loss, got = run()
     np.testing.assert_allclose(float(got_loss), float(want_loss), rtol=1e-6)
@@ -502,13 +505,13 @@ def test_k_and_v_reach_the_flash_kernels_at_the_models_kv_heads(impl):
     x = jnp.zeros((B, S, cfg.n_embd), jnp.float32)
     names = sorted(layer)
     closed = jax.make_jaxpr(
-        lambda x, *leaves: tinygpt._attention_sublayer(cfg, x, dict(zip(names, leaves)), None, True)
+        lambda x, *leaves: attention_mixer.sublayer(cfg, x, dict(zip(names, leaves)), None, True)
     )(x, *(layer[n] for n in names))
     wkv = closed.jaxpr.invars[1 + names.index("wkv")]
     shapes, _ = _derived_shapes(closed.jaxpr, [wkv], stop=("custom_vjp_call", "custom_vjp_call_jaxpr"))
     assert (B, S, KV, Dh) in shapes
     whole = {(B, S, H, Dh), (B, H, S, Dh), (B * H, S, Dh)}
-    stats = tinygpt.attn_mask_stats(cfg, S)["global"]
+    stats = attention_mixer.attn_mask_stats(cfg, S)["global"]
     assert stats["heads"] == H
     if impl == "flash":
         assert not whole & set(shapes), sorted(whole & set(shapes))

@@ -19,6 +19,9 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from distributed_llm_training_benchmark_framework_tpu.models.mixers import (
+    attention as attention_mixer,
+)
 from distributed_llm_training_benchmark_framework_tpu.models import moe, tinygpt
 from distributed_llm_training_benchmark_framework_tpu.models.tinygpt import (
     Rotary, TinyGPTConfig, YarnScaling,
@@ -141,7 +144,7 @@ def test_yarn_frequencies_and_factor_are_the_closed_form():
 def test_a_layers_rotation_is_its_kinds_table(kind):
     x = jax.random.normal(jax.random.key(4), (1, SEQ, 2, 32))
     rotary = CONFIG.rotary(kind)
-    got = tinygpt._rope(x, jnp.arange(SEQ), rotary.theta, rotary.scaling)[0]
+    got = attention_mixer._rope(x, jnp.arange(SEQ), rotary.theta, rotary.scaling)[0]
     cos, sin = reference_mellum.rotary_table(SHAPE, kind, jnp.arange(SEQ))
     np.testing.assert_allclose(got, reference_mellum._rotate(x[0], cos, sin), atol=1e-5)
     grows = float(jnp.linalg.norm(got) / jnp.linalg.norm(x))
@@ -381,7 +384,7 @@ def test_flops_count_a_window_layers_pairs():
 
 def test_mask_stats_count_tiles_steps_and_pairs_by_kind():
     config, shape = published_cell()
-    stats = tinygpt.attn_mask_stats(config, 16384)
+    stats = attention_mixer.attn_mask_stats(config, 16384)
     assert sorted(stats) == [GLOBAL, WINDOW]
     window, whole = stats[WINDOW], stats[GLOBAL]
     assert (window["layers"], whole["layers"]) == (3, 1)
@@ -397,7 +400,7 @@ def test_mask_stats_count_tiles_steps_and_pairs_by_kind():
     fill = 2 * window["true_pairs"] / (window["fwd_pairs_multiplied"] + window["bwd_pairs_multiplied"])
     assert round(100 * fill, 1) == 63.3
     plain = dataclasses.replace(config, layer_types=None, sliding_window=None, layer_rotary=None)
-    assert tinygpt.attn_mask_stats(plain, 16384) == {GLOBAL: {**whole, "layers": 4}}
+    assert attention_mixer.attn_mask_stats(plain, 16384) == {GLOBAL: {**whole, "layers": 4}}
 
 
 def test_through_the_one_pass_prologue_the_loss_and_gradients_are_the_chains(batch, monkeypatch):
@@ -419,9 +422,9 @@ def test_through_the_one_pass_prologue_the_loss_and_gradients_are_the_chains(bat
         lambda p: tinygpt.loss_fn(config, p, batch, batch)))(weights)
     want_loss, want = run()
     monkeypatch.setattr(rotary, "kernel_mode", lambda: True)  # as a chip, interpreted
-    stats = tinygpt.qk_prologue_stats(config, SEQ)
+    stats = attention_mixer.qk_prologue_stats(config, SEQ)
     assert (stats["rotary_layers"], stats["pass_layers"], stats["norm_stage_layers"]) == (2, 2, 2)
-    assert set(tinygpt.qk_prologue_tables(config, SEQ)) == {WINDOW, GLOBAL}
+    assert set(attention_mixer.qk_prologue_tables(config, SEQ)) == {WINDOW, GLOBAL}
     got_loss, got = run()
     assert abs(float(got_loss) - float(want_loss)) / float(want_loss) < TOLERANCE["loss"]
     got["blocks"].pop("router"), want["blocks"].pop("router")  # not trained: zero on both

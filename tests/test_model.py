@@ -9,6 +9,9 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from distributed_llm_training_benchmark_framework_tpu.models.mixers import (
+    attention as attention_mixer,
+)
 from distributed_llm_training_benchmark_framework_tpu.models import (
     TinyGPTConfig,
     get_model_config,
@@ -230,8 +233,8 @@ def test_attention_dispatch_says_which_attention_never_how(
         seq_manual_axis=manual_axis,
     )
     q = jnp.zeros((1, 8, 2, 4))
-    assert tinygpt._attention(cfg, q, q, q, jax.random.key(0), False) is q
-    assert tinygpt._attention(cfg, q, q, q, jax.random.key(0), True) is q
+    assert attention_mixer._attention(cfg, q, q, q, jax.random.key(0), False) is q
+    assert attention_mixer._attention(cfg, q, q, q, jax.random.key(0), True) is q
     training, deterministic = calls
     seed = training.pop("dropout_seed")
     assert seed.dtype == jnp.uint32 and seed.shape == ()

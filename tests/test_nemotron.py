@@ -19,6 +19,10 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from distributed_llm_training_benchmark_framework_tpu.models.mixers import (
+    attention as attention_mixer,
+    ssd as ssd_mixer,
+)
 from distributed_llm_training_benchmark_framework_tpu.models import moe, tinygpt
 from distributed_llm_training_benchmark_framework_tpu.models.tinygpt import TinyGPTConfig
 from distributed_llm_training_benchmark_framework_tpu.parallel import get_strategy, make_mesh
@@ -432,7 +436,7 @@ def test_flops_and_memory_count_the_blocks_by_kind():
     half_position = 4 * 0.5 * 4 * 16  # (S + 1) / 2 against S / 2 keys, 4 heads of 16
     assert got == pytest.approx(want - half_position)
     D, inner, xbc, H, C, P, N = 64, 64, 128, 4, 16, 16, 16
-    assert flops.ssd_forward_flops_per_token(CONFIG) == (
+    assert ssd_mixer.forward_flops_per_token(CONFIG) == (
         2 * D * (inner + xbc + H) + 2 * inner * D + 2 * 4 * xbc
         + H * (2 * C * P + 4 * N * P) + 2 * 2 * C * N)
     assert flops_nemotron.scan_forward_flops_per_token({**SHAPE, "ssd_state": 16}) == (
@@ -441,22 +445,22 @@ def test_flops_and_memory_count_the_blocks_by_kind():
     estimate = lambda config, remat: memory.estimate_hbm(
         dataclasses.replace(config, remat=remat), get_strategy("zero2"), mesh,
         per_device_batch=1, seq_len=SEQ).activations
-    stats = tinygpt.ssd_stats(CONFIG, SEQ)
+    stats = ssd_mixer.ssd_stats(CONFIG, SEQ)
     assert stats["saved_state_bytes"] == (SEQ // 16) * 64 * 16 * 4
     kept = 4 * (stats["saved_state_bytes"] + SEQ * 64 * 4)
     assert estimate(CONFIG, "full_keep_kernels") - estimate(CONFIG, "full") >= kept
 
 
 def test_ssd_stats_count_chunks_steps_calls_and_what_the_forward_keeps(monkeypatch):
-    stats = tinygpt.ssd_stats(CONFIG, SEQ)
+    stats = ssd_mixer.ssd_stats(CONFIG, SEQ)
     assert (stats["layers"], stats["chunk"], stats["chunks"], stats["chunk_steps"]) == (4, 16, 2, 4)
     assert stats["kernel_calls"] == {"ssd_fwd": 0, "ssd_bwd": 0}  # the jnp path off a TPU
     cell = dataclasses.replace(CONFIG, ssd_heads=64, ssd_head_dim=64, ssd_groups=8, ssd_state=128,
                                ssd_chunk=128, compute_dtype=jnp.bfloat16)
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    stats = tinygpt.ssd_stats(cell, 16384)
+    stats = ssd_mixer.ssd_stats(cell, 16384)
     assert stats["kernel_calls"] == {"ssd_fwd": 4, "ssd_bwd": 4}
     assert stats["conv_kernel_calls"] == {"kda_conv_fwd": 4, "kda_conv_bwd": 4}
     assert stats["chunk_steps"] == 128 * 8 and stats["saved_state_bytes"] == 128 * 4096 * 128 * 2
-    assert tinygpt.attn_mask_stats(cell, 16384).keys() == {"global"}
-    assert tinygpt.qk_prologue_stats(cell, 16384)["rotary_layers"] == 0
+    assert attention_mixer.attn_mask_stats(cell, 16384).keys() == {"global"}
+    assert attention_mixer.qk_prologue_stats(cell, 16384)["rotary_layers"] == 0

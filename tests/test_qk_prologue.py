@@ -18,6 +18,10 @@ import numpy as np
 import pytest
 from jax.sharding import NamedSharding, PartitionSpec as P
 
+from distributed_llm_training_benchmark_framework_tpu.models import common
+from distributed_llm_training_benchmark_framework_tpu.models.mixers import (
+    attention as attention_mixer,
+)
 from distributed_llm_training_benchmark_framework_tpu.models import tinygpt
 from distributed_llm_training_benchmark_framework_tpu.models.tinygpt import (
     Rotary,
@@ -67,8 +71,8 @@ def chain(x, norm, positions, rot):
     def one(t, scale):
         t = t.reshape(*t.shape[:2], -1, D)
         if norm:
-            t = tinygpt._rms_norm(t, scale, EPS)
-        t = tinygpt._rope(t, positions.astype(jnp.int32), rot.theta, rot.scaling)
+            t = common._rms_norm(t, scale, EPS)
+        t = attention_mixer._rope(t, positions.astype(jnp.int32), rot.theta, rot.scaling)
         return t.reshape(*t.shape[:2], -1)
 
     return one(x["q"], x["q_scale"]), one(x["k"], x["k_scale"])
@@ -274,13 +278,13 @@ def test_the_choice_is_made_from_the_shapes_and_the_backend(name, monkeypatch):
     config, rotate, taken, normed = CHOICES[name]
     # a CPU backend keeps the jnp chain whatever the shapes are
     assert rotary.kernel_mode() is None
-    assert tinygpt.qk_prologue_tables(config, S) == {}
-    assert tinygpt.qk_prologue_stats(config, S)["pass_layers"] == 0
+    assert attention_mixer.qk_prologue_tables(config, S) == {}
+    assert attention_mixer.qk_prologue_stats(config, S)["pass_layers"] == 0
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    stats = tinygpt.qk_prologue_stats(config, S)
+    stats = attention_mixer.qk_prologue_stats(config, S)
     assert (stats["rotary_layers"], stats["pass_layers"], stats["norm_stage_layers"]) == (
         rotate, taken, normed)
-    assert set(tinygpt.qk_prologue_tables(config, S)) == ({None} if taken else set())
+    assert set(attention_mixer.qk_prologue_tables(config, S)) == ({None} if taken else set())
 
 
 def test_the_latent_operand_stays_on_rope(monkeypatch):
@@ -289,7 +293,7 @@ def test_the_latent_operand_stays_on_rope(monkeypatch):
     config = manifest.resolve(file["builder"])(workload, file)
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     assert config.latent_attention and config.qk_rope_head_dim == 64
-    assert tinygpt.qk_prologue_tables(config, workload["seq_len"]) == {}
+    assert attention_mixer.qk_prologue_tables(config, workload["seq_len"]) == {}
 
 
 #: cell: (layers that rotate, that take the pass, with the norm stage, q + k lanes)
@@ -313,7 +317,7 @@ def test_the_counter_at_the_six_configurations(cell, monkeypatch):
     builder = manifest.resolve(file.get("builder", "perfbench.harness.build:tinygpt_config"))
     config = builder(workload, file)
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    stats = tinygpt.qk_prologue_stats(config, workload["seq_len"])
+    stats = attention_mixer.qk_prologue_stats(config, workload["seq_len"])
     rotate, taken, normed, lanes = CELLS[cell]
     assert (stats["rotary_layers"], stats["pass_layers"], stats["norm_stage_layers"]) == (
         rotate, taken, normed)
@@ -321,7 +325,7 @@ def test_the_counter_at_the_six_configurations(cell, monkeypatch):
     assert stats["forward_bytes"] == 2 * rows * lanes * 2
     assert stats["backward_bytes"] == (3 if normed else 2) * rows * lanes * 2
     if config.layer_types and taken:  # one pair of tables a kind of layer
-        assert set(tinygpt.qk_prologue_tables(config, rows)) == set(config.layer_types)
+        assert set(attention_mixer.qk_prologue_tables(config, rows)) == set(config.layer_types)
 
 
 def test_a_layer_hands_the_pass_its_kinds_tables(monkeypatch):

@@ -28,6 +28,12 @@ import numpy as np
 import pytest
 from jax.sharding import NamedSharding, PartitionSpec as P
 
+from distributed_llm_training_benchmark_framework_tpu.models import common
+from distributed_llm_training_benchmark_framework_tpu.models.mixers import (
+    conv as conv_mixer,
+    kda as kda_mixer,
+    ssd as ssd_mixer,
+)
 from distributed_llm_training_benchmark_framework_tpu.models import (
     get_llama_config,
     get_model_config,
@@ -266,16 +272,16 @@ def test_the_list_is_one_and_names_what_the_rule_allows():
     ms_a_gb = {moe.MOE_GU: 6.6,  # sdar-30b-a3b.share8-bd8192: 4.95 ms for 0.755 GB
                moe.ROUTER_LOGITS: 1000.0, moe.ROUTER_CHOICE: 1000.0,  # kimi: 8.78 ms, 17 MB
                moe.MOE_PLAN: 1000.0,  # mellum2: 7.5 ms with combine's backward, 3 MB
-               tinygpt.KDA_QKV: 12.3,  # kimi: 19.86 ms for 1.611 GB
+               kda_mixer.KDA_QKV: 12.3,  # kimi: 19.86 ms for 1.611 GB
                tinygpt.MLP_GU: 11.1,  # kimi: 6.73 ms for 0.604 GB
                # nemotron-3-nano-30b-a3b.share16-seq16384, the parent traced (my chip run, PR
                # 52), four blocks each:
-               tinygpt.SSD_XBC: 14.9,  # 12.02 ms (4 x 3.005) for 0.805 GB
-               tinygpt.SSD_Z: 15.4,  # 8.28 ms (in_proj's 20.33 less x | B | C's and dt's) for 0.537 GB
-               tinygpt.SHARED_U: 14.5,  # 7.05 ms (4 x 1.763) for 0.487 GB
+               ssd_mixer.SSD_XBC: 14.9,  # 12.02 ms (4 x 3.005) for 0.805 GB
+               ssd_mixer.SSD_Z: 15.4,  # 8.28 ms (in_proj's 20.33 less x | B | C's and dt's) for 0.537 GB
+               common.SHARED_U: 14.5,  # 7.05 ms (4 x 1.763) for 0.487 GB
                # lfm2-8b-a1b.share4-seq16384, the parent traced (my chip run, PR 54, seed
                # 5400000101: scope ``sconv_in`` under remat), four layers:
-               tinygpt.SCONV_BCX: 10.9}  # 17.6 ms (4 x 4.4) for 1.611 GB
+               conv_mixer.SCONV_BCX: 10.9}  # 17.6 ms (4 x 4.4) for 1.611 GB
     names = tinygpt.remat_kept_names()
     assert len(set(names)) == len(names)
     assert set(names) == {*FLASH_RESIDUAL_NAMES, *KDA_RESIDUAL_NAMES, *SSD_RESIDUAL_NAMES, *ms_a_gb}

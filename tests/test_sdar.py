@@ -17,6 +17,9 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from distributed_llm_training_benchmark_framework_tpu.models.mixers import (
+    attention as attention_mixer,
+)
 from distributed_llm_training_benchmark_framework_tpu.models import moe, tinygpt
 from distributed_llm_training_benchmark_framework_tpu.models.tinygpt import (
     BlockDiffusionObjective,
@@ -329,7 +332,7 @@ def test_flops_and_memory_count_the_objective():
     assert flops.train_flops_per_token(config) == pytest.approx(want, rel=1e-12)
     assert round(want / 3e6) == 1456  # M a data token, forward
     assert flops_bd.true_pairs(shape) == 8192 * 8192 + 8192 * 4
-    stats = tinygpt.bd_mask_stats(config, 8192)
+    stats = attention_mixer.bd_mask_stats(config, 8192)
     assert stats["true_pairs"] == flops_bd.true_pairs(shape)
     # the unit is the piece a kernel skips by: 69.5 of 256 tiles' worth of forward
     # pieces of 128 x 128, 71 of 256 of backward pieces of 256 x 256
@@ -378,10 +381,10 @@ def test_through_the_one_pass_prologue_the_loss_and_gradients_are_the_chains(bat
     weights = seeded_weights(config)
     run = lambda: jax.jit(jax.value_and_grad(
         lambda p: tinygpt.loss_fn(config, p, batch, batch, dropout_key=KEY)))(weights)
-    assert tinygpt.qk_prologue_stats(config, SEQ)["pass_layers"] == 0
+    assert attention_mixer.qk_prologue_stats(config, SEQ)["pass_layers"] == 0
     want_loss, want = run()
     monkeypatch.setattr(rotary, "kernel_mode", lambda: True)  # as a chip, interpreted
-    stats = tinygpt.qk_prologue_stats(config, SEQ)
+    stats = attention_mixer.qk_prologue_stats(config, SEQ)
     assert (stats["rotary_layers"], stats["pass_layers"], stats["norm_stage_layers"]) == (1, 1, 1)
     got_loss, got = run()
     assert abs(float(got_loss) - float(want_loss)) / float(want_loss) < TOLERANCE["loss"]

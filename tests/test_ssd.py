@@ -2,7 +2,7 @@
 the recurrence position by position in float32: the ``jnp`` path at any widths
 and the two kernels interpreted at whole 128-lane slabs (two heads of 64
 channels side by side), output, the state and every gradient; and the
-convolution with its bias and SiLU (``ops/kda.py::conv_silu``) against XLA's.
+convolution with its bias and SiLU (``ops/short_conv.py::conv_silu``) against XLA's.
 
 Both sides compute in float32 here, so they differ by the order of summation
 only; the tolerances sit two orders above that.
@@ -14,7 +14,7 @@ import numpy as np
 import pytest
 from jax import lax
 
-from distributed_llm_training_benchmark_framework_tpu.ops import kda, ssd
+from distributed_llm_training_benchmark_framework_tpu.ops import short_conv, ssd
 
 # (head width P, state N, heads, groups, positions): the jnp path's and the kernels'
 SMALL = (16, 16, 4, 2, 64)
@@ -194,9 +194,9 @@ def conv_by_the_equation(x, taps, bias):
 @pytest.mark.parametrize("interpret", [None, True], ids=["xla", "kernels"])
 def test_the_convolution_with_bias_and_silu_is_the_equation_and_its_gradients(interpret):
     x, taps, bias, weight = conv_operands(seq=1032, width=256)  # three tiles of rows, the last short
-    got = kda.conv_silu(x, taps, bias, interpret=interpret)
+    got = short_conv.conv_silu(x, taps, bias, interpret=interpret)
     assert relative(got, conv_by_the_equation(x, taps, bias)) < 1e-5
-    grads = jax.grad(lambda *a: jnp.sum(kda.conv_silu(*a, interpret=interpret) * weight),
+    grads = jax.grad(lambda *a: jnp.sum(short_conv.conv_silu(*a, interpret=interpret) * weight),
                      (0, 1, 2))(x, taps, bias)
     wants = jax.grad(lambda *a: jnp.sum(conv_by_the_equation(*a) * weight), (0, 1, 2))(x, taps, bias)
     for name, a, b in zip(("x", "taps", "bias"), grads, wants):
@@ -206,9 +206,9 @@ def test_the_convolution_with_bias_and_silu_is_the_equation_and_its_gradients(in
 def test_the_convolutions_kernels_are_one_call_each_way_and_fall_back_off_whole_lanes():
     x, taps, bias, _ = conv_operands(seq=64, width=256)
     jaxpr = str(jax.make_jaxpr(jax.grad(
-        lambda x: jnp.sum(kda.conv_silu(x, taps, bias, interpret=True))))(x))
+        lambda x: jnp.sum(short_conv.conv_silu(x, taps, bias, interpret=True))))(x))
     assert jaxpr.count("name=kda_conv_fwd") == 1 and jaxpr.count("name=kda_conv_bwd") == 1
     narrow = conv_operands(seq=64, width=96)[:3]
     assert "pallas_call" not in str(jax.make_jaxpr(
-        lambda *a: kda.conv_silu(*a, interpret=True))(*narrow))
-    assert relative(kda.conv_silu(*narrow, interpret=True), conv_by_the_equation(*narrow)) < 1e-5
+        lambda *a: short_conv.conv_silu(*a, interpret=True))(*narrow))
+    assert relative(short_conv.conv_silu(*narrow, interpret=True), conv_by_the_equation(*narrow)) < 1e-5

@@ -18,6 +18,12 @@ import jax.numpy as jnp
 import pytest
 from jax.ad_checkpoint import checkpoint_name
 
+from distributed_llm_training_benchmark_framework_tpu.models import common
+from distributed_llm_training_benchmark_framework_tpu.models.mixers import (
+    conv as conv_mixer,
+    kda as kda_mixer,
+    ssd as ssd_mixer,
+)
 from distributed_llm_training_benchmark_framework_tpu.analysis import memory_anatomy
 from distributed_llm_training_benchmark_framework_tpu.models import moe, tinygpt
 from distributed_llm_training_benchmark_framework_tpu.models.tinygpt import TinyGPTConfig
@@ -153,9 +159,9 @@ def saved_of(which, policy):
 @pytest.mark.parametrize("policy", ("full_keep_kernels", "none"))
 def test_the_kda_states_are_the_counters_bytes(policy):
     """Under the recurrence's second name the account holds what
-    ``tinygpt.kda_stats`` counts a layer, times the KDA layers."""
+    ``kda_mixer.kda_stats`` counts a layer, times the KDA layers."""
     saved = saved_of("kimi", policy)
-    stats = tinygpt.kda_stats(dataclasses.replace(KIMI, compute_dtype=jnp.bfloat16), SEQ)
+    stats = kda_mixer.kda_stats(dataclasses.replace(KIMI, compute_dtype=jnp.bfloat16), SEQ)
     states = named(saved["kept"], KDA_RESIDUAL_NAMES[1])
     assert len(states) == stats["layers"] == 4
     assert total(states) == stats["saved_state_bytes"] * stats["layers"]
@@ -195,7 +201,7 @@ def test_the_kimi_layers_named_values_by_scope_and_bytes(policy):
     kept = saved_of("kimi", policy)["kept"]
     kda_layers = KIMI.layer_types.count(scopes.KDA)
     width = 3 * KIMI.kda_heads * KIMI.kda_head_dim
-    qkv, dense, gu = (named(kept, name) for name in (tinygpt.KDA_QKV, tinygpt.MLP_GU, moe.MOE_GU))
+    qkv, dense, gu = (named(kept, name) for name in (kda_mixer.KDA_QKV, tinygpt.MLP_GU, moe.MOE_GU))
     prep = (scopes.ATTENTION, scopes.KDA, scopes.KDA_PREP)
     products = [e for e in kept if e[:3] == (prep, "dot_general", (1, SEQ, width))]
     if policy == "full_keep_kernels":
@@ -220,7 +226,7 @@ def test_a_conv_layers_projection_by_scope_and_bytes(policy):
     least the 4 D columns a token the estimate counts) are kept without remat alone."""
     kept = saved_of("lfm2", policy)["kept"]
     layers, tokens, D = LFM2.layer_types.count(scopes.CONV), 2 * SEQ, LFM2.n_embd
-    bcx = named(kept, tinygpt.SCONV_BCX)
+    bcx = named(kept, conv_mixer.SCONV_BCX)
     into = (scopes.ATTENTION, scopes.CONV, scopes.SCONV_IN)
     products = [e for e in kept if e[:3] == (into, "dot_general", (2, SEQ, 3 * D))]
     core = [e for e in kept if e[0] == (scopes.ATTENTION, scopes.CONV, scopes.SCONV_CORE)]
@@ -282,9 +288,9 @@ def test_no_name_is_given_to_a_float32_in_front_of_its_cast(which):
     seen = {e.params["name"] for inner in _jaxprs(jaxpr) for e in inner.eqns
             if e.primitive.name == "name"}
     assert seen >= ({moe.MOE_GU, moe.ROUTER_LOGITS} | {
-        "kimi": {tinygpt.KDA_QKV, tinygpt.MLP_GU},
-        "nemotron": {tinygpt.SSD_XBC, tinygpt.SSD_Z, tinygpt.SHARED_U},
-        "lfm2": {tinygpt.SCONV_BCX, tinygpt.MLP_GU}}.get(which, set()))
+        "kimi": {kda_mixer.KDA_QKV, tinygpt.MLP_GU},
+        "nemotron": {ssd_mixer.SSD_XBC, ssd_mixer.SSD_Z, common.SHARED_U},
+        "lfm2": {conv_mixer.SCONV_BCX, tinygpt.MLP_GU}}.get(which, set()))
     assert _names_in_front_of_a_cast(jaxpr, names) == []
 
 

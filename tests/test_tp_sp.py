@@ -12,6 +12,9 @@ import numpy as np
 import pytest
 from jax.sharding import PartitionSpec as P
 
+from distributed_llm_training_benchmark_framework_tpu.models.mixers import (
+    attention as attention_mixer,
+)
 from distributed_llm_training_benchmark_framework_tpu.models import get_model_config
 from distributed_llm_training_benchmark_framework_tpu.parallel import (
     make_mesh,
@@ -127,14 +130,14 @@ def test_flash_under_a_heads_axis_takes_kv_heads_whole_or_repeats_them(eight_dev
     params = tinygpt.init_params(cfg, jax.random.key(0))
     idx = jax.random.randint(jax.random.key(1), (2, 64), 0, cfg.vocab_size)
     step = jax.jit(jax.value_and_grad(lambda p: tinygpt.loss_fn(cfg, p, idx, idx)))
-    assert tinygpt.attn_mask_stats(cfg, 64)["global"]["kv_heads_in_kernel"] == 2
+    assert attention_mixer.attn_mask_stats(cfg, 64)["global"]["kv_heads_in_kernel"] == 2
     want_loss, want = step(params)
     mesh = make_mesh((1, 1, degree), ("data", "seq", "model"), devices=eight_devices[:degree])
     specs = param_partition_specs(params, mesh, shard=False, kv_heads=cfg.kv_heads)
     assert ("model" in tuple(specs["blocks"]["wkv"])) == (degree == 2)
     placed = jax.tree.map(lambda leaf, spec: jax.device_put(leaf, NamedSharding(mesh, spec)), params, specs)
     with jax.set_mesh(mesh):
-        assert tinygpt.attn_mask_stats(cfg, 64)["global"]["kv_heads_in_kernel"] == kv_in_kernel
+        assert attention_mixer.attn_mask_stats(cfg, 64)["global"]["kv_heads_in_kernel"] == kv_in_kernel
         got_loss, got = step(placed)
     np.testing.assert_allclose(float(got_loss), float(want_loss), rtol=1e-5)
     for (path, g), w in zip(jax.tree_util.tree_leaves_with_path(got), jax.tree.leaves(want)):

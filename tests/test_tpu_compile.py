@@ -700,14 +700,14 @@ def test_the_kda_convolutions_kernels_compile_at_the_cells_operand(v5e_devices, 
     groups takes the chip's compiler minutes): bare as one call each way, and
     as a layer runs them, with SiLU and q's and k's l2norms inside, a call a
     third each way, the backward's three writing one dx with no copy."""
-    from distributed_llm_training_benchmark_framework_tpu.ops import kda
+    from distributed_llm_training_benchmark_framework_tpu.ops import short_conv
 
     one = SingleDeviceSharding(v5e_devices[0])
     aval = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype, sharding=one)
 
     def loss(x, taps):
-        out = (kda.qkv_prologue(x, taps, 32, interpret=False) if epilogue
-               else (kda.causal_conv(x, taps, interpret=False),))
+        out = (short_conv.qkv_prologue(x, taps, 32, interpret=False) if epilogue
+               else (short_conv.causal_conv(x, taps, interpret=False),))
         return sum(jnp.sum(jnp.square(o.astype(jnp.float32))) for o in out)
 
     text = _compile(jax.grad(loss, argnums=(0, 1)),
@@ -773,16 +773,16 @@ def test_the_ssd_kernels_compile_at_the_cells_operand(v5e_devices):
 
 
 def test_the_convolution_with_bias_and_silu_compiles_at_the_cells_operand(v5e_devices):
-    """``ops.kda.conv_silu`` over the 6144 columns of a Mamba-2 block's x | B |
+    """``ops.short_conv.conv_silu`` over the 6144 columns of a Mamba-2 block's x | B |
     C: the convolution's two kernels with the bias as the row behind the taps
     and SiLU as their epilogue, one call each way."""
-    from distributed_llm_training_benchmark_framework_tpu.ops import kda
+    from distributed_llm_training_benchmark_framework_tpu.ops import short_conv
 
     one = SingleDeviceSharding(v5e_devices[0])
     aval = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype, sharding=one)
 
     def loss(x, taps, bias):
-        return jnp.sum(jnp.square(kda.conv_silu(x, taps, bias, interpret=False).astype(jnp.float32)))
+        return jnp.sum(jnp.square(short_conv.conv_silu(x, taps, bias, interpret=False).astype(jnp.float32)))
 
     text = _compile(jax.grad(loss, argnums=(0, 1, 2)), aval((1, 16384, 6144), jnp.bfloat16),
                     aval((4, 6144), jnp.float32), aval((6144,), jnp.float32))
@@ -826,16 +826,16 @@ def test_the_nemotron_cells_stacks_compile_a_block_of_each_kind(v5e_devices, mon
 
 
 def test_the_gated_convolution_compiles_at_the_cells_operand(v5e_devices):
-    """``ops.kda.gated_conv`` over the (2, 16384, 6144) operand of a gated
+    """``ops.short_conv.gated_conv`` over the (2, 16384, 6144) operand of a gated
     short-convolution mixer, B | C | x~ of 2048 columns each and 3 taps: one
     Mosaic call each way, the three thirds found by the calls' block specs."""
-    from distributed_llm_training_benchmark_framework_tpu.ops import kda
+    from distributed_llm_training_benchmark_framework_tpu.ops import short_conv
 
     one = SingleDeviceSharding(v5e_devices[0])
     aval = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype, sharding=one)
 
     def loss(bcx, taps):
-        return jnp.sum(jnp.square(kda.gated_conv(bcx, taps, interpret=False).astype(jnp.float32)))
+        return jnp.sum(jnp.square(short_conv.gated_conv(bcx, taps, interpret=False).astype(jnp.float32)))
 
     text = _compile(jax.grad(loss, argnums=(0, 1)), aval((2, 16384, 6144), jnp.bfloat16),
                     aval((3, 2048), jnp.float32))
