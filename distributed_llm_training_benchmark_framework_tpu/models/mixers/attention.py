@@ -536,8 +536,13 @@ def bd_mask_stats(config: TinyGPTConfig, seq_len: int) -> Dict[str, int]:
     and in the unit each kernel skips by (``visited_units``): the (piece,
     piece) piece where the rule gives its tiles shapes (``*_live_tiles``
     pieces visited of ``*_tiles``, ``*_tile_pairs`` pairs a piece), the
-    whole tile where it does not; and ``kv_heads_in_kernel``, the heads of k
-    and v the kernels were handed (``_kv_heads_in_kernel``)."""
+    whole tile where it does not; ``*_grid_steps``, the steps a head's grid
+    makes, and ``*_tile_fetches``, the times its walk changes the tile its
+    inner operand's blocks address (K forward, q backward: the copies the
+    pipeline issues; ``ops.flash_attention.tile_fetches``, from the index
+    maps' own function: a dead step addresses a live tile of its row and
+    brings nothing); and ``kv_heads_in_kernel``, the heads of k and v the
+    kernels were handed (``_kv_heads_in_kernel``)."""
     from ...ops import flash_attention as fa
 
     S = 2 * seq_len
@@ -550,8 +555,19 @@ def bd_mask_stats(config: TinyGPTConfig, seq_len: int) -> Dict[str, int]:
         "true_pairs": rule.tile_counts(bq, bk)[2],
         "fwd_live_tiles": live_fwd, "fwd_tiles": all_fwd, "fwd_tile_pairs": unit_fwd,
         "bwd_live_tiles": live_bwd, "bwd_tiles": all_bwd, "bwd_tile_pairs": unit_bwd,
+        **_walk_counts(rule, S, bq, bk, bk_bwd),
         "kv_heads_in_kernel": _kv_heads_in_kernel(config),
     }
+
+
+def _walk_counts(rule, S: int, bq: int, bk: int, bk_bwd: int) -> Dict[str, int]:
+    """``*_grid_steps`` and ``*_tile_fetches`` of one head's forward and
+    fused-backward walk under ``rule`` (``bd_mask_stats``)."""
+    from ...ops import flash_attention as fa
+
+    return {f"{name}_{count}": fn(rule, S, bq, keys, name == "fwd")
+            for name, keys in (("fwd", bk), ("bwd", bk_bwd))
+            for count, fn in (("grid_steps", fa.grid_steps), ("tile_fetches", fa.tile_fetches))}
 
 
 def attn_mask_stats(config: TinyGPTConfig, seq_len: int) -> Dict[str, Dict[str, int]]:
@@ -565,8 +581,10 @@ def attn_mask_stats(config: TinyGPTConfig, seq_len: int) -> Dict[str, Dict[str, 
     repeated or nothing is shared), the (queries, keys) ``fwd_tile`` and
     ``bwd_tile`` taken, and for the forward and the fused backward kernel ``*_live_tiles``
     (tiles that hold a pair), ``*_grid_steps`` (steps a head's grid makes: the
-    square's under causal, the band's under a window; the difference brings a
-    tile, or holds the last one, and multiplies nothing) and
+    square's under causal, the band's under a window; the difference
+    multiplies nothing and brings nothing: it addresses a live tile of its
+    row), ``*_tile_fetches`` (``bd_mask_stats``: the live tiles, or fewer
+    where a row starts on the tile the row before ended on) and
     ``*_pairs_multiplied`` (the area of what the bodies walk: a *lower* tile's
     pieces on and below its piece diagonal, else whole tiles)."""
     from ...ops import flash_attention as fa
@@ -590,10 +608,8 @@ def attn_mask_stats(config: TinyGPTConfig, seq_len: int) -> Dict[str, Dict[str, 
             units, _, unit_pairs = fa.visited_units(rule, seq_len, bq, keys, piece)
             tiles = fa.tiles_by_shape(rule, seq_len, bq, keys, piece)
             entry[f"{name}_live_tiles"] = int(sum(t.sum() for t in tiles.values()))
-            entry[f"{name}_grid_steps"] = (
-                rule.grid_counts(seq_len, bq, keys, name == "fwd")[1] if window
-                else (seq_len // bq) * (seq_len // keys))
             entry[f"{name}_pairs_multiplied"] = units * unit_pairs
+        entry.update(_walk_counts(rule, seq_len, bq, bk, bk_bwd))
         stats[kind] = entry
     return stats
 

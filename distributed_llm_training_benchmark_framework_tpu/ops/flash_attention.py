@@ -19,9 +19,10 @@ Forward (Pallas kernel):
 - also emits the per-row logsumexp, the residual the backward pass needs;
 - the mask is a *rule* (``MaskRule``): none, causal, block diffusion over a
   stream of a noisy and a clean copy, or a causal sliding window; inside a
-  live tile it is computed from global positions, and tiles the rule leaves no
-  pair in are skipped, or, under a window, never brought: its band of live
-  tiles is the grid;
+  live tile it is computed from global positions; a grid step whose tile the
+  rule leaves no pair in multiplies nothing and brings nothing (its blocks
+  address a live tile of the same row: ``_addressed_tile``), and under a
+  window the band of live tiles is the grid;
 - a live tile has a *shape* (``_tile_shape``): full, or, on the diagonal of
   square tiles, lower; the forward and the fused backward run a body a shape,
   and the lower one leaves out the pieces above the piece diagonal.
@@ -60,6 +61,7 @@ from __future__ import annotations
 import dataclasses
 import functools
 import math
+import operator
 from typing import Optional, Tuple, Union
 
 import jax
@@ -144,6 +146,66 @@ class BlockDiffusion:
         return ((q_noisy & k_noisy & same_block) | (q_noisy & ~k_noisy & (k_lo < q_hi))
                 | (~q_noisy & ~k_noisy & (k_lo <= q_hi)))
 
+    def live_spans(self, S: int, bq: int, bk: int, keys_inner: bool, outer):
+        """Row ``outer`` of a kernel's walk (``_live_spans``) -> its live
+        tiles, two spans: a noisy query tile meets the noisy key tiles of its
+        own blocks and the clean past (empty for block 0), a clean one the
+        clean tiles up to its own (the second span empty); a noisy key tile
+        meets the noisy query tiles of its own blocks, a clean one the noisy
+        queries of later blocks (empty for the last) and the clean ones from
+        its own on. In tiles of the inner axis; positions inside a copy."""
+        L, B = self.seq_len, self.block
+        own, other = (bq, bk) if keys_inner else (bk, bq)
+        half, half_other = L // own, L // other  # tiles a copy
+        clean = _le(half, outer)
+        if own == other and own % B == 0:
+            # square tiles of whole blocks (what the Mosaic path takes), in
+            # tile numbers: four operations a map where positions take twenty
+            whole = int(own == B)  # a block a tile: its clean past ends a tile earlier
+            if keys_inner:
+                return ((_where(clean, half, outer), outer),
+                        (half, _where(clean, half - 1, _add(outer, half - whole))))
+            return ((_where(clean, _sub(outer, half - whole), outer), _where(clean, half - 1, outer)),
+                    (_where(clean, outer, 1), _where(clean, 2 * half - 1, 0)))
+        off = _mul(_sub(outer, _where(clean, half, 0)), own)
+        # the tile's blocks as positions [lo, hi]: the tile's own where it
+        # holds whole blocks
+        lo = off if own % B == 0 else _mul(_fdiv(off, B), B)
+        hi = _add(off, own - 1)
+        if own % B:
+            hi = _add(_mul(_fdiv(hi, B), B), B - 1)
+        if keys_inner:
+            past = _fdiv(_add(hi, other - B), other)  # clean key tiles before the last block
+            return ((_where(clean, half_other, _fdiv(lo, other)),
+                     _add(_fdiv(hi, other), _where(clean, half_other, 0))),
+                    (half_other, _where(clean, half_other - 1, _add(past, half_other - 1))))
+        own_first = _fdiv(lo, other)
+        return ((_where(clean, _fdiv(_add(lo, B), other), own_first),
+                 _where(clean, half_other - 1, _fdiv(hi, other))),
+                (_where(clean, _add(own_first, half_other), 1),
+                 _where(clean, 2 * half_other - 1, 0)))
+
+    # The forward's grid under this rule. A query tile meets few of the
+    # square's key tiles (its own and the clean past, or the clean tiles up to
+    # its own: 9 of 16 at most in the SDAR cell's stream), so its steps walk
+    # the most any query tile meets and no more, each row's live tiles packed
+    # against the row's end (``_packed_tile``), as a window's band is. A key
+    # tile's query tiles are nearly all of them for the first clean tiles (15
+    # of 16), so the fused backward keeps the square.
+    def band_steps(self, S: int, bq: int, bk: int, keys_inner: bool) -> Optional[int]:
+        """Inner grid extent of the forward: the most key tiles a query tile
+        meets. None for the fused backward: the square's."""
+        if not keys_inner:
+            return None
+        spans = self.live_spans(S, bq, bk, True, np.arange(S // bq, dtype=np.int64))
+        return int(sum(np.maximum(hi - lo + 1, 0) for lo, hi in spans).max())
+
+    def key_tile(self, qi, bq: int, bk: int, step, steps: int):
+        """The key tile that step ``step`` of ``steps`` brings to query tile
+        ``qi``, ascending, the last live one at the last step; -1 before the
+        first."""
+        return _packed_tile(self.live_spans(2 * self.seq_len, bq, bk, True, qi), step, steps)
+
     def in_tile(self, q_off, k_off, rows, cols):
         """The rule inside one live tile, whose queries lie in one copy and
         whose keys lie in one copy: one subtract and one unsigned compare a
@@ -203,7 +265,8 @@ class SlidingWindow:
     band is the grid of ``flash_fwd`` and ``flash_bwd_fused``: a query tile's
     steps walk its ``band_steps`` key tiles and no other (``key_tile``), a
     key tile's steps its query tiles (``query_tile``), where the other rules'
-    grids bring every tile of the square and skip the dead ones' bodies."""
+    grids step through every tile of the square, skip the dead ones' bodies
+    and hold a live tile through them."""
 
     window: int
 
@@ -247,8 +310,8 @@ class SlidingWindow:
     # the most any tile meets is the grid's inner extent, the same for every
     # tile; a tile near an end of the sequence meets fewer (the band's clipped
     # corner), and where bq != bk some tiles in the middle do too: those steps
-    # bring the nearest tile inside the sequence again (no new DMA: the block
-    # index repeats) and multiply nothing.
+    # address a live tile of the row's (``_addressed_tile``: no new DMA, the
+    # block index repeats) and multiply nothing.
     def band_steps(self, S: int, bq: int, bk: int, keys_inner: bool) -> int:
         """Inner grid extent: key tiles a query tile walks (the forward), or
         query tiles a key tile walks (the fused backward)."""
@@ -274,16 +337,163 @@ class SlidingWindow:
         diagonal's first, ascending; past the last tile in the clipped corner."""
         return (ki if bq == bk else (ki * bk) // bq) + step
 
+    def live_spans(self, S: int, bq: int, bk: int, keys_inner: bool, outer):
+        """Row ``outer`` of a kernel's walk (``_live_spans``) -> its live
+        tiles, one span: the band's, clipped to the sequence. Square tiles
+        (what the Mosaic path takes) in tile numbers, two operations a map."""
+        reach = self.window - 1  # positions a query sees before its own
+        if keys_inner:
+            if bq == bk:
+                return ((_max(_sub(outer, -(-reach // bk)), 0), outer),)
+            first = _fdiv(_max(_sub(_mul(outer, bq), reach), 0), bk)
+            return ((first, _tile_at(outer, bq, bk, last=True)),)
+        if bq == bk:
+            return ((outer, _min(_add(outer, (bk - 1 + reach) // bq), S // bq - 1)),)
+        last = _fdiv(_min(_add(_mul(outer, bk), bk - 1 + reach), S - 1), bq)
+        return ((_tile_at(outer, bk, bq), last),)
+
     def grid_counts(self, S: int, bq: int, bk: int, keys_inner: bool) -> Tuple[int, int]:
         """(live steps, all steps) of one head's band grid."""
-        steps = self.band_steps(S, bq, bk, keys_inner)
-        return self.tile_counts(S, bq, bk)[0], (S // (bq if keys_inner else bk)) * steps
+        return self.tile_counts(S, bq, bk)[0], grid_steps(self, S, bq, bk, keys_inner)
 
 
 #: The rule a kernel masks by: ``False`` every pair, ``True`` causal (query i
 #: sees keys j <= i), a ``BlockDiffusion`` or a ``SlidingWindow``.
 MaskRule = Union[bool, BlockDiffusion, SlidingWindow]
 _RULES = (BlockDiffusion, SlidingWindow)  # the rules that are objects
+
+
+# Which tile a grid step addresses. A grid step whose tile holds no allowed
+# pair multiplies nothing (``pl.when(live)``, on the step's own program ids),
+# and it brings nothing either: its blocks address a *live* tile of the same
+# row of the walk, the next one the walk will reach, so that the block index
+# equals a neighbour's and the pipeline issues no copy for it. The rule says
+# which tiles of a row are live, in closed form beside ``tile_live``
+# (``live_spans``: a span or two of consecutive tiles); the index maps and the
+# host's count of fetches read one function of it (``_addressed_tile``).
+# Scalars of an index map (traced int32, never negative) or numpy arrays.
+def _scalar_op(traced, host):
+    """One operation on tile numbers: ``lax``'s where an operand is an index
+    map's scalar (a ``jnp`` operator there is a jit of its own to trace, six
+    maps a differentiated call: the note above ``_fill_where``), the host's on
+    Python numbers and numpy arrays."""
+    def op(*operands):
+        if any(isinstance(x, jax.Array) for x in operands):
+            return traced(*(x if isinstance(x, jax.Array) else np.int32(x) for x in operands))
+        return host(*operands)
+    return op
+
+
+_add, _sub, _mul = (_scalar_op(*ops) for ops in (
+    (lax.add, operator.add), (lax.sub, operator.sub), (lax.mul, operator.mul)))
+_fdiv = _scalar_op(lax.div, operator.floordiv)  # operands >= 0
+_max, _min = _scalar_op(lax.max, np.maximum), _scalar_op(lax.min, np.minimum)
+_le, _lt = _scalar_op(lax.le, operator.le), _scalar_op(lax.lt, operator.lt)
+_both = _scalar_op(lax.bitwise_and, operator.and_)
+_where = _scalar_op(lax.select, np.where)  # (which, a, b): a where ``which``
+
+
+def _tile_at(tile, b: int, other: int, last: bool = False):
+    """The tile of ``other`` positions that holds the first (``last``: the
+    last) position of tile ``tile`` of ``b`` positions."""
+    return tile if b == other else _fdiv(_add(_mul(tile, b), b - 1 if last else 0), other)
+
+
+def _live_spans(mask: MaskRule, S: int, bq: int, bk: int, keys_inner: bool, outer):
+    """The live tiles of row ``outer`` of a kernel's walk over one head's
+    (S // bq, S // bk) tiles -> ((first, last), ...), spans of consecutive
+    inner tiles in ascending order, one of them at least not empty (first <=
+    last; every query has a live key and every key a live query). The forward
+    walks a query tile's key tiles (``keys_inner``), the fused backward a key
+    tile's query tiles. ``tile_live`` on the same tiles is what the tests hold
+    this to."""
+    if isinstance(mask, _RULES):
+        return mask.live_spans(S, bq, bk, keys_inner, outer)
+    if keys_inner:  # causal: the keys up to the diagonal's, the queries from it
+        return ((0, _tile_at(outer, bq, bk, last=True)),)
+    return ((_tile_at(outer, bk, bq), S // bq - 1),)
+
+
+def _held_tile(tile, spans):
+    """``tile`` where it lies in one of a row's live ``spans``; else the next
+    live tile after it, the one the walk reaches next (its copy is then under
+    way, or done, when the walk arrives); past the last, the last."""
+    if len(spans) == 1:
+        (lo, hi), = spans
+        return _min(_max(tile, lo), hi)
+    held = spans[0][1]
+    for lo, hi in spans[1:]:
+        held = _where(_le(lo, hi), hi, held)
+    for lo, hi in reversed(spans):
+        held = _where(_both(_le(lo, hi), _le(tile, hi)), _max(tile, lo), held)
+    return held
+
+
+def _packed_tile(spans, step, steps: int):
+    """The tile of step ``step`` of ``steps`` where a row's live ``spans`` are
+    walked in order, packed against the row's end: the steps in front of
+    them, one a tile the row has fewer than the widest row's ``steps``, are
+    -1 and multiply nothing."""
+    lengths = [_max(_add(_sub(hi, lo), 1), 0) for lo, hi in spans]
+    at = _sub(step, _sub(steps, functools.reduce(_add, lengths)))  # the live tile's number in its row
+    tile, first = -1, 0
+    for (lo, _), length in zip(spans, lengths):
+        last = _add(first, length)
+        tile = _where(_both(_le(first, at), _lt(at, last)), _add(lo, _sub(at, first)), tile)
+        first = last
+    return tile
+
+
+def _band_steps(mask: MaskRule, S: int, bq: int, bk: int, keys_inner: bool) -> Optional[int]:
+    """Inner extent of the grid a rule gives ``flash_fwd`` (``keys_inner``)
+    or ``flash_bwd_fused``: a window's band both ways, block diffusion's
+    packed rows forward; None where the steps are the square's tiles."""
+    return mask.band_steps(S, bq, bk, keys_inner) if isinstance(mask, _RULES) else None
+
+
+def _addressed_tile(mask: MaskRule, S: int, bq: int, bk: int, keys_inner: bool):
+    """(outer tile, inner step) -> the inner tile whose blocks the step is
+    handed, for the index maps of ``flash_fwd`` (``keys_inner``: K and V) and
+    ``flash_bwd_fused`` (q, dO and the two statistics). The step's own tile
+    where that is live, else a live tile of the row (``_held_tile``); without
+    a mask every tile is live and this is the step. Where the rule gives the
+    grid a band (``_band_steps``) the steps are the band's (``key_tile`` /
+    ``query_tile``)."""
+    if not mask:
+        return lambda outer, step: step
+    band = _band_steps(mask, S, bq, bk, keys_inner)
+
+    def addressed(outer, step):
+        if band:
+            step = (mask.key_tile(outer, bq, bk, step, band) if keys_inner
+                    else mask.query_tile(outer, bq, bk, step))
+        return _held_tile(step, _live_spans(mask, S, bq, bk, keys_inner, outer))
+
+    return addressed
+
+
+def grid_steps(mask: MaskRule, S: int, bq: int, bk: int, keys_inner: bool) -> int:
+    """Steps one head's grid makes in ``flash_fwd`` (``keys_inner``) or
+    ``flash_bwd_fused``: the square's, or the band's where the rule gives one
+    (``_band_steps``)."""
+    outer, inner = (S // bq, S // bk) if keys_inner else (S // bk, S // bq)
+    return outer * (_band_steps(mask, S, bq, bk, keys_inner) or inner)
+
+
+def tile_fetches(mask: MaskRule, S: int, bq: int, bk: int, keys_inner: bool) -> int:
+    """Times one head's walk changes the block index of the operand its inner
+    axis walks (K in the forward, q in the fused backward), the walk's first
+    step among them: the copies the pipeline issues for it, counted on the
+    host from the map the kernels use (``_addressed_tile``). The live tiles,
+    or fewer where a row starts on the tile the row before ended on; the grid's
+    steps without a mask."""
+    outer = S // (bq if keys_inner else bk)
+    inner = grid_steps(mask, S, bq, bk, keys_inner) // outer
+    walk = np.broadcast_to(
+        _addressed_tile(mask, S, bq, bk, keys_inner)(
+            np.arange(outer, dtype=np.int64)[:, None], np.arange(inner, dtype=np.int64)[None, :]),
+        (outer, inner)).ravel()
+    return 1 + int(np.count_nonzero(walk[1:] != walk[:-1]))
 
 
 def first_piece_live(mask: MaskRule) -> bool:
@@ -484,12 +694,19 @@ def _pick_block(seq_len: int, preferred: int = 512) -> int:
 # body a shape at the same piece: us a (1024, 1024) tile at D 128, no dropout
 # (my chip run, PR 37, the same script's by_shape line; in brackets the
 # bundles of the compiler's schedule for the body, 1.5 a ns). Every figure
-# holds the dead grid steps' DMAs, 2.2 a live tile under block diffusion, 0.6
-# causal, which is why a body's time does not fall as far as its area:
+# holds the head's dead grid steps, which is why a body's time does not fall
+# as far as its area. In PR 37 they were 2.2 a live tile under block
+# diffusion and 0.6 causal, each with a K and a V tile's DMA; since PR 57 a
+# dead step brings nothing (``_addressed_tile``) and block diffusion's forward
+# walks 9 steps a query tile, 0.8 dead a live tile (``BlockDiffusion
+# .band_steps``): the second column as re-measured (my chip runs, PR 57,
+# 2026-10-05, the same script and the parent beside it, which read 5.71 /
+# 4.40 again; k and v a row a head as in PR 37; at the cell's 4 kv rows a call
+# is 9.64 ms where the parent's is 10.08):
 #
-#   (BH, S, rule)                  (64, 4096, causal)   (32, 16384, BlockDiffusion(8192, 4))
-#   full, all 64 pieces of (128, 128)     4.36                 5.71 (5,085)
-#   lower, 36 of 64                       3.22                 4.40 (3,071)
+#   (BH, S, rule)                  (64, 4096, causal)   (32, 16384, BlockDiffusion(8192, 4))   since PR 57
+#   full, all 64 pieces of (128, 128)     4.36                 5.71 (5,085)                       4.20
+#   lower, 36 of 64                       3.22                 4.40 (3,071)                       2.95
 #   band, the 8 on the diagonal           -                    2.95 (929)
 #
 # The last row is a third body, for block diffusion's noisy -> noisy diagonal
@@ -538,10 +755,21 @@ _FWD_SUB_K = 128
 # The bodies a shape (PR 37; as above the forward's table; no dropout, so
 # pieces of 256 queries, 16 of (256, 256) a tile):
 #
-#   (BH, S, D / Dv, rule)      (64, 4096, 128, causal)  (32, 8192, 192 / 128, causal)  (32, 16384, 128, BlockDiffusion(8192, 4))
-#   full, all 16 pieces               8.33                    13.86 (15,928)                 9.34 (10,060)
-#   lower, 10 of 16                   5.80                    10.06 (10,010)                 6.76 (6,182)
+#   (BH, S, D / Dv, rule)      (64, 4096, 128, causal)  (32, 8192, 192 / 128, causal)  (32, 16384, 128, BlockDiffusion(8192, 4))   since PR 57
+#   full, all 16 pieces               8.33                    13.86 (15,928)                 9.34 (10,060)                              8.05
+#   lower, 10 of 16                   5.80                    10.06 (10,010)                 6.76 (6,182)                               5.65
 #   band, the 4 on the diagonal       -                       -                              5.00 (2,648): not taken, as in the forward
+#
+# The last column: the 176 dead steps a head of the square's 256 hold a live
+# q, dO, lse and delta tile where each brought 576 KiB (my chip runs, PR 57,
+# 2026-10-05; the parent beside it read 9.32 / 6.89): 0.57 us a dead step
+# less. One call at the cells' own kv rows, parent -> PR 57: the SDAR cell's
+# 21.58 -> 18.47 ms, Laguna's full layer (48 heads, causal, S 16,384) 50.05
+# -> 46.45, the DeepSeek cell's 14.97 -> 14.09, mistral-7b.d2's 4.51 -> 4.22.
+# An index map runs at every grid step for every operand it serves, about
+# 1 ns a scalar operation: ``live_spans`` in positions (45 operations a
+# block-diffusion map) cost the cell's backward 0.44 ms a call over the
+# tile numbers it answers in at square tiles (17).
 _FUSED_BWD_BLOCK_K = 1024
 _BWD_SUB_Q = 256
 _BWD_SUB_Q_DROPOUT = 128
@@ -627,11 +855,12 @@ def _flash_fwd_kernel(
     same update, each piece against the queries it may hold a pair with and
     no other (``_accumulate_lower``).
 
-    The grid's last axis is a query tile's steps. Under every rule but one a
-    step is a key tile, all of them in turn; under a ``SlidingWindow`` the
-    ``band`` steps are the key tiles of the query tile's band and no other
-    (``SlidingWindow.key_tile``; negative in the band's clipped corner, where
-    the step multiplies nothing).
+    The grid's last axis is a query tile's steps. Without a mask and under
+    causal a step is a key tile, all of them in turn; where the rule gives
+    the grid a band (``_band_steps``) the ``band`` steps are the key tiles
+    the query tile meets and no other (``key_tile`` of the rule: a window's
+    band, block diffusion's packed row; negative in front of a row that meets
+    fewer than the widest, where the step multiplies nothing).
     """
     bh = pl.program_id(0)
     qi = pl.program_id(1)
@@ -883,21 +1112,22 @@ def _forward_call(
     is the same either way. Whoever patches what a kernel's body reads
     (``_tile_shape``, ``first_piece_live``) calls ``forget_kernel_calls``.
 
-    Under a ``SlidingWindow`` the grid's last axis is the band and not the
-    square's row: ``band_steps`` steps a query tile, the key tile's block
-    index computed from the step (held at 0 in the clipped corner, where the
-    block repeats and nothing is fetched anew).
+    Under a ``SlidingWindow`` and under ``BlockDiffusion`` the grid's last
+    axis is a band and not the square's row (``_band_steps``): the most key
+    tiles a query tile meets. Under every rule the K and V blocks of a step
+    whose tile is dead are a live tile's of the same query tile, the next the
+    walk reaches (``_addressed_tile``): the block index repeats and nothing
+    is fetched for the step. A causal query tile holds its diagonal tile to
+    the row's end; a band's row holds its first live tile through the steps
+    in front of it (a window's clipped corner tile 0).
 
     k and v hold BH // ``rep`` rows, a kv head each: the grid's row ``b``, a
     (batch, query head) pair, reads row b // rep of them (``_kv_row``); q,
     out and lse keep ``b``."""
-    band = mask.band_steps(S, bq, bk, True) if isinstance(mask, SlidingWindow) else None
+    band = _band_steps(mask, S, bq, bk, True)
     kv = _kv_row(rep)
-    if band is None:
-        key_spec = lambda b, qi, ki: (kv(b), ki, 0)
-    else:
-        key_spec = lambda b, qi, step: (
-            kv(b), jnp.maximum(mask.key_tile(qi, bq, bk, step, band), 0), 0)
+    key_tile = _addressed_tile(mask, S, bq, bk, keys_inner=True)
+    key_spec = lambda b, qi, step: (kv(b), key_tile(qi, step), 0)
     return pl.pallas_call(
         functools.partial(
             _flash_fwd_kernel, bq=bq, bk=bk, sub_k=sub_k, scale=scale,
@@ -1469,8 +1699,14 @@ def _fused_call(
     """The fused backward's ``pallas_call`` on (seed, bhv, q, k, v, do, lse3,
     delta3), made once a process for a shape and its static choices, as
     ``_forward_call`` and for its reason; under a ``SlidingWindow`` its
-    grid's last axis is the band, as the forward's (the query tile's block
-    index held at the last tile in the clipped corner).
+    grid's last axis is the band, as the forward's. The q, dO, lse and delta
+    blocks of a step whose tile is dead are a live tile's of the same key
+    tile (``_addressed_tile``; a step brings 576 KiB of them at D 128), and
+    nothing is fetched for it: a causal key tile holds its diagonal's query
+    tile from step 0, block diffusion's noisy ones theirs before and behind
+    it, its clean ones the first noisy query tile that sees them and, between
+    the noisy queries and the clean, their own; a window's clipped corner the
+    last tile.
 
     k and v hold BH // ``rep`` rows and are read at row b // rep, as the
     forward reads them. dk and dv leave in one of two forms. ``grouped``: the
@@ -1482,12 +1718,9 @@ def _fused_call(
     window layer's). Else a query head, (BH, S, .), as they always have,
     for ``_fused_backward`` to sum (what a sequence too long for three
     resident rows takes)."""
-    band = mask.band_steps(S, bq, bk, False) if isinstance(mask, SlidingWindow) else None
+    band = _band_steps(mask, S, bq, bk, False)
     kv = _kv_row(rep)
-    if band is None:
-        q_tile = lambda ki, qi: qi
-    else:
-        q_tile = lambda ki, step: jnp.minimum(mask.query_tile(ki, bq, bk, step), S // bq - 1)
+    q_tile = _addressed_tile(mask, S, bq, bk, keys_inner=False)
     if grouped:
         # grid (kv rows, the group's heads, k tiles, q tiles): dk / dv rows
         # of a kv head stay in VMEM over its group and leave once
@@ -1842,7 +2075,9 @@ def flash_attention(
     ``causal`` is the mask's rule (``MaskRule``): False none, True causal, a
     ``BlockDiffusion`` over a stream of S = 2L positions, whose tiles must
     lie inside one copy of the document, or a ``SlidingWindow``, under which
-    the two kernels' grids walk the band of live tiles and not the square.
+    the two kernels' grids walk the band of live tiles and not the square
+    (as the forward's does under a ``BlockDiffusion``); a grid step whose tile
+    holds no allowed pair multiplies nothing and brings nothing.
 
     q and k share one width, v and the output another (latent attention:
     192-wide keys over 128-wide values, no padding of either); ``scale``

@@ -34,8 +34,8 @@ Under a mask rule a live tile has a shape (``fa._tile_shape``: *full* or
 bodies the kernel may run: ``all`` (what the model runs; the default) or
 ``none`` (every live tile the *full* body: the whole-tile walk the kernel was
 until PR 37). From the two the script prints us a tile by shape beside the
-mean over live tiles (the dead grid steps' DMAs are in every one), and the
-area a call visits (``fa.visited_units``).
+mean over live tiles (the dead grid steps, which bring nothing since PR 57,
+are in every one), and the area a call visits (``fa.visited_units``).
 
 ``bwd_pieced`` below is the prototype PR 33 swept (``PERF.md`` section 6 has
 the table): one grid step still brings the operands of a (bk, bq) score tile

@@ -761,7 +761,7 @@ CELL_CALLS = {
     "tinygpt-a.seq8192": (16, 8192, 64, 64, False, 0.1, (8, 8)),
     "mistral-7b.d2": (32, 4096, 128, 128, True, 0.0, (4, 4)),
     "deepseek-v2-lite.share8-seq8192": (16, 8192, 192, 128, True, 0.0, (8, 8)),
-    "sdar-30b-a3b.share8-bd8192": (32, 16384, 128, 128, fa.BlockDiffusion(8192, 4), 0.0, (16, 16)),
+    "sdar-30b-a3b.share8-bd8192": (32, 16384, 128, 128, fa.BlockDiffusion(8192, 4), 0.0, ((16, 9), (16, 16))),
     "mellum2-12b-a2.5b.global": (32, 16384, 128, 128, True, 0.0, (16, 16)),
     "mellum2-12b-a2.5b.window": (32, 16384, 128, 128, fa.SlidingWindow(1024), 0.0, (16, 2)),
 }
@@ -769,10 +769,13 @@ CELL_CALLS = {
 
 @pytest.mark.parametrize("cell", sorted(CELL_CALLS))
 def test_a_cells_call_is_two_kernels_on_the_grid_it_had(cell):
-    """Causal, no mask and block diffusion keep the square's grid, (heads, S /
-    1024, S / 1024), forward and backward; only a window's grid is its band.
-    One forward and one fused backward call a differentiated call."""
+    """Causal and no mask keep the square's grid, (heads, S / 1024, S / 1024),
+    forward and backward; a window's grid is its band, and block diffusion's
+    forward walks the 9 key tiles a query tile meets at most where its
+    backward keeps the square. One forward and one fused backward call a
+    differentiated call."""
     heads, S, d_qk, d_v, rule, rate, grid = CELL_CALLS[cell]
+    fwd_grid, bwd_grid = grid if isinstance(grid[0], tuple) else (grid, grid)
     q = jax.ShapeDtypeStruct((1, S, heads, d_qk), jnp.bfloat16)
     v = jax.ShapeDtypeStruct((1, S, heads, d_v), jnp.bfloat16)
 
@@ -783,7 +786,7 @@ def test_a_cells_call_is_two_kernels_on_the_grid_it_had(cell):
         return jnp.sum(out.astype(jnp.float32))
 
     calls = pallas_calls(jax.grad(loss, argnums=(0, 1, 2)), q, q, v)
-    assert calls == [("flash_fwd", (heads,) + grid), ("flash_bwd_fused", (heads,) + grid)]
+    assert calls == [("flash_fwd", (heads,) + fwd_grid), ("flash_bwd_fused", (heads,) + bwd_grid)]
 
 
 # Grouped-query attention: k and v enter ``flash_attention`` at their own head
@@ -929,3 +932,178 @@ def test_head_counts_that_do_not_divide_are_refused():
         fa.flash_attention(q, k[:, :, :1], v)  # one head of k under two of v
     with pytest.raises(ValueError, match="head count that divides"):
         fa.flash_attention(q[:, :, :3], k, v)  # 3 query heads over 2
+
+
+# ---------------------------------------------------------------------------
+# Which tile a grid step addresses (``fa._addressed_tile``): a dead step's
+# blocks are a live tile's of the same row, and the pipeline copies nothing
+# for it. The index maps the two ``pallas_call``s were built with, evaluated
+# on the host over the whole grid.
+# ---------------------------------------------------------------------------
+
+# (rule, S, (bq, bk)): the cells' shapes (causal at S 4096 and 16,384; the
+# SDAR cell's stream; the Mellum and the Laguna window at their tiles) and
+# small ones whose tiles are not square or narrower than a block.
+WALKS = {
+    "causal-4096": (True, 4096, (1024, 1024)),
+    "causal-16384": (True, 16384, (1024, 1024)),
+    "causal-bq>bk": (True, 512, (128, 32)),
+    "causal-bq<bk": (True, 512, (64, 128)),
+    "block-diffusion-8192": (fa.BlockDiffusion(8192, 4), 16384, (1024, 1024)),
+    "block-diffusion-a-block-four-tiles": (fa.BlockDiffusion(64, 32), 128, (16, 8)),
+    "block-diffusion-blocks-across-tiles": (fa.BlockDiffusion(96, 12), 192, (16, 32)),
+    "block-diffusion-one-tile-a-copy": (fa.BlockDiffusion(64, 4), 128, (64, 64)),
+    "block-diffusion-a-block-a-tile": (fa.BlockDiffusion(64, 16), 128, (16, 16)),
+    "window-mellum": (fa.SlidingWindow(1024), 16384, (1024, 1024)),
+    "window-laguna": (fa.SlidingWindow(512), 16384, (512, 512)),
+    "window-bq>bk": (fa.SlidingWindow(100), 512, (64, 32)),
+    "window-bq<bk": (fa.SlidingWindow(33), 512, (32, 128)),
+    "no-mask": (False, 512, (128, 64)),
+}
+WALK_REP = 4  # query heads a kv head
+
+
+def built_with(monkeypatch, build):
+    """What ``build`` handed ``pl.pallas_call``."""
+    seen = {}
+
+    def call(kernel, **kwargs):
+        seen.update(kwargs)
+        return lambda *operands: None
+
+    monkeypatch.setattr(fa.pl, "pallas_call", call)
+    build()
+    return seen
+
+
+@pytest.mark.parametrize("kernel", ["forward", "backward", "backward-grouped"])
+@pytest.mark.parametrize("walk", sorted(WALKS))
+def test_a_dead_step_addresses_a_live_tile_of_its_row(walk, kernel, monkeypatch):
+    """At every live step the walked operands' index maps return the step's
+    own tile; at every dead step a live tile of the same row, the next the
+    walk reaches where there is one (the copy is then under way when the walk
+    arrives), else the row's last; k and v keep the row's own tile and their
+    kv row. The block index then changes ``tile_fetches`` times a head: once
+    a live tile, or less where a row starts on the tile the row before ended
+    on."""
+    mask, S, (bq, bk) = WALKS[walk]
+    forward, grouped = kernel == "forward", kernel == "backward-grouped"
+    BH, rep, f32 = 2 * WALK_REP, 1 if kernel == "backward" else WALK_REP, jnp.float32
+    if forward:
+        built = built_with(monkeypatch, lambda: fa._forward_call(
+            BH, S, 128, 128, f32, frozenset(), mask, True, bq, bk, fa._fwd_sub_k(bk), 1.0, 0.0, rep))
+        walked, kept = (3, 4), (2,)  # k, v; q
+    else:
+        built = built_with(monkeypatch, lambda: fa._fused_call(
+            BH, S, 128, 128, (f32,) * 3, frozenset(), mask, True, bq, bk, fa._bwd_sub_q(bq, 0.0),
+            1.0, 0.0, rep, grouped))
+        walked, kept = (2, 5, 6, 7), (3, 4)  # q, dO, lse, delta; k, v
+    grid, specs = built["grid"], built["in_specs"]
+    outer_n, steps = grid[-2:]
+    assert (outer_n, steps) == (S // (bq if forward else bk),
+                                fa.grid_steps(mask, S, bq, bk, forward) // (S // (bq if forward else bk)))
+    heads = np.arange(BH)
+    head_ids = (heads // rep, heads % rep) if grouped else (heads,)
+    ids = np.meshgrid(heads, np.arange(outer_n), np.arange(steps), indexing="ij")
+    args = (*(h[ids[0]] for h in head_ids), ids[1], ids[2])
+    outer, step = ids[1], ids[2]
+    # the step's own tile, and whether the rule leaves a pair in it
+    if fa._band_steps(mask, S, bq, bk, forward):
+        own = mask.key_tile(outer, bq, bk, step, steps) if forward else mask.query_tile(outer, bq, bk, step)
+    else:
+        own = step
+    inner_n = S // (bk if forward else bq)
+
+    def live_at(tile):
+        inside = (tile >= 0) & (tile < inner_n)
+        qi, ki = (outer, tile) if forward else (tile, outer)
+        return inside & np.broadcast_to(fa._tile_rule(mask, qi * bq, bq, ki * bk, bk)[0], inside.shape)
+
+    live = live_at(own)
+    tiles_live = np.broadcast_to(fa._tile_rule(
+        mask, np.arange(S // bq)[:, None] * bq, bq, np.arange(S // bk)[None, :] * bk, bk)[0],
+        (S // bq, S // bk))
+    assert int(live[0].sum()) == int(tiles_live.sum())  # the walk reaches every live tile once
+    for at in walked:
+        index = specs[at].index_map(*args)
+        row, tile = index[0], index[2] if at in (6, 7) and not forward else index[1]
+        np.testing.assert_array_equal(np.broadcast_to(row, live.shape), ids[0] // rep if forward else ids[0])
+        tile = np.broadcast_to(tile, live.shape)
+        np.testing.assert_array_equal(tile[live], own[live])
+        assert live_at(tile).all()
+        # held ahead: the first live tile at or after the step's own, else the row's last
+        row_live = tiles_live if forward else tiles_live.T
+        for o in range(outer_n):
+            live_tiles = np.flatnonzero(row_live[o])
+            ahead = np.searchsorted(live_tiles, own[0, o])
+            want = live_tiles[np.minimum(ahead, len(live_tiles) - 1)]
+            np.testing.assert_array_equal(tile[0, o], want)
+        head_walk = tile[0].ravel()
+        fetches = 1 + int(np.count_nonzero(head_walk[1:] != head_walk[:-1]))
+        assert fetches == fa.tile_fetches(mask, S, bq, bk, forward)
+        if mask:
+            assert tiles_live.sum() - outer_n < fetches <= tiles_live.sum()
+        else:
+            assert fetches == outer_n * steps
+    for at in kept:  # the outer axis' operands: the row's own tile, and k and v at their kv row
+        index = specs[at].index_map(*args)
+        np.testing.assert_array_equal(np.broadcast_to(index[1], live.shape), outer)
+        want_row = ids[0] if forward else ids[0] // rep
+        np.testing.assert_array_equal(np.broadcast_to(index[0], live.shape), want_row)
+
+
+def test_the_cell_shape_fetches_its_80_live_tiles_and_no_dead_one():
+    """The SDAR cell's stream at (1024, 1024) tiles, 80 live tiles a head. The
+    forward walks 9 steps a query tile, the most a query tile meets (a noisy
+    tile its own and the clean past, a clean one the clean tiles up to its
+    own), 144 where the square has 256, and its K walk changes tile 79 times
+    (row 8 starts on the tile row 7 ended on); the backward keeps the square
+    (a clean key tile meets up to 15 of 16 query tiles) and its q walk
+    changes tile 80 times. Causal over the same 16,384 positions: 135 for
+    136."""
+    rule = fa.BlockDiffusion(8192, 4)
+    assert (rule.band_steps(16384, 1024, 1024, True), rule.band_steps(16384, 1024, 1024, False)) == (9, None)
+    assert (fa.grid_steps(rule, 16384, 1024, 1024, True), fa.grid_steps(rule, 16384, 1024, 1024, False)) == (144, 256)
+    assert (fa.tile_fetches(rule, 16384, 1024, 1024, True), fa.tile_fetches(rule, 16384, 1024, 1024, False)) == (79, 80)
+    assert (fa.tile_fetches(True, 16384, 1024, 1024, True), fa.tile_fetches(True, 16384, 1024, 1024, False)) == (135, 135)
+    assert fa.tile_fetches(False, 16384, 1024, 1024, True) == 256
+    # a query row's live tiles packed against its end: -1 in front of them
+    steps = np.arange(9)
+    assert list(rule.key_tile(np.int64(3), 1024, 1024, steps, 9)) == [-1, -1, -1, -1, 3, 8, 9, 10, 11]
+    assert list(rule.key_tile(np.int64(7), 1024, 1024, steps, 9)) == [7, 8, 9, 10, 11, 12, 13, 14, 15]
+    assert list(rule.key_tile(np.int64(11), 1024, 1024, steps, 9)) == [-1] * 5 + [8, 9, 10, 11]
+    # and the steps in front address the row's first live tile
+    tile = fa._addressed_tile(rule, 16384, 1024, 1024, True)
+    assert list(tile(np.int64(3), steps)) == [3, 3, 3, 3, 3, 8, 9, 10, 11]
+    assert list(tile(np.int64(11), steps)) == [8] * 6 + [9, 10, 11]
+    # a clean key row: the first noisy query tile that sees it, then its own
+    tile = fa._addressed_tile(rule, 16384, 1024, 1024, False)
+    assert list(tile(np.int64(10), np.arange(16))) == [2, 2, 2, 3, 4, 5, 6, 7, 10, 10, 10, 11, 12, 13, 14, 15]
+    assert list(tile(np.int64(2), np.arange(16))) == [2] * 16
+
+
+@pytest.mark.parametrize("rep", [1, 4], ids=["a-kv-head-a-head", "grouped"])
+def test_flash_attention_under_block_diffusion_differentiates_to_the_reference(rep):
+    """Through the public entry with the fused backward, interpreted: rows of
+    the walk whose dead steps lie in the middle (a noisy query tile between
+    its own tile and the clean past, a clean key tile between the noisy
+    queries that see it and its own), held, change no output and no
+    gradient."""
+    B_, H, KV = 1, 4, 4 // rep
+    keys = jax.random.split(jax.random.key(11), 4)
+    q, do = (jax.random.normal(key, (B_, 2 * L, H, D), jnp.float32) for key in keys[:2])
+    k, v = (jax.random.normal(key, (B_, 2 * L, KV, D), jnp.float32) for key in keys[2:])
+    assert fa.tile_fetches(RULE, 2 * L, PIECE, PIECE, True) < fa.grid_steps(RULE, 2 * L, PIECE, PIECE, True)
+
+    def ours(q, k, v):
+        return fa.flash_attention(q, k, v, causal=RULE, interpret=True, pallas_backward=True,
+                                  block_q=PIECE, block_k=PIECE, block_k_bwd=PIECE)
+
+    def reference(q, k, v):
+        return fa.reference_attention(q, jnp.repeat(k, rep, axis=2), jnp.repeat(v, rep, axis=2), RULE)
+
+    np.testing.assert_allclose(ours(q, k, v), reference(q, k, v), atol=2e-5, rtol=2e-5)
+    got = jax.grad(lambda *a: jnp.sum(ours(*a) * do), (0, 1, 2))(q, k, v)
+    want = jax.grad(lambda *a: jnp.sum(reference(*a) * do), (0, 1, 2))(q, k, v)
+    for g, w in zip(got, want):
+        np.testing.assert_allclose(g, w, atol=5e-5, rtol=5e-5)

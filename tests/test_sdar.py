@@ -30,7 +30,7 @@ from distributed_llm_training_benchmark_framework_tpu.parallel import strategies
 from distributed_llm_training_benchmark_framework_tpu.train.step import create_train_state
 from distributed_llm_training_benchmark_framework_tpu.utils import flops, memory
 from distributed_llm_training_benchmark_framework_tpu.utils.scopes import NOISE
-from perfbench.harness import build_bd, flops_bd, reference_bd
+from perfbench.harness import build_bd, flops_bd, manifest, reference_bd
 
 TOLERANCE = {"logits": 1e-4, "loss": 1e-5, "grad_leaf": 1e-3}
 SEQ, BATCH, EXPERTS, HELD, TOP_K, BLOCK = 64, 2, 16, (4, 2), 3, 4
@@ -343,6 +343,10 @@ def test_flops_and_memory_count_the_objective():
     visited = (stats["fwd_live_tiles"] * stats["fwd_tile_pairs"]
                + stats["bwd_live_tiles"] * stats["bwd_tile_pairs"])
     assert round(100 * 2 * stats["true_pairs"] / visited, 2) == 91.15  # bd_live_fill_pct's arithmetic
+    # 80 live tiles a head in 144 forward steps (9 a query tile) and the square's
+    # 256 backward: the dead ones bring nothing
+    assert (stats["fwd_grid_steps"], stats["fwd_tile_fetches"]) == (144, 79)
+    assert (stats["bwd_grid_steps"], stats["bwd_tile_fetches"]) == (256, 80)
     # the causal next-token model of the same widths: one copy, half the pairs
     plain = dataclasses.replace(config, block_diffusion=None, causal=True)
     D, H, Dh = 2048, 32, 128
@@ -393,3 +397,36 @@ def test_through_the_one_pass_prologue_the_loss_and_gradients_are_the_chains(bat
         lambda g, w: float(jnp.linalg.norm(g - w) / jnp.linalg.norm(w)), got, want)
     for path, error in jax.tree_util.tree_leaves_with_path(errors):
         assert error < TOLERANCE["grad_leaf"], (jax.tree_util.keystr(path), error)
+
+
+@pytest.mark.parametrize("cell,kind,steps,live,fetches", [
+    ("sdar-30b-a3b.share8-bd8192", None, (144, 256), 80, (79, 80)),
+    ("mellum2-12b-a2.5b.share4-seq16384", "global", (256, 256), 136, (135, 135)),
+    ("mellum2-12b-a2.5b.share4-seq16384", "window", (32, 32), 31, (16, 16)),
+    ("laguna-xs.2.share16-seq16384", "global", (256, 256), 136, (135, 135)),
+    ("laguna-xs.2.share16-seq16384", "window", (64, 64), 63, (32, 32)),
+], ids=["block-diffusion", "causal-mellum", "window-1024", "causal-laguna", "window-512"])
+def test_a_cells_walks_fetch_their_live_tiles_and_no_dead_one(cell, kind, steps, live, fetches):
+    """``bd_mask_stats`` / ``attn_mask_stats`` at the cells' own configs:
+    ``*_tile_fetches`` (the copies a head's walk issues for the operand its
+    inner axis walks) read the live tiles or fewer where ``*_grid_steps``
+    read the square's or the band's steps: 144 / 256 against 79 / 80 under
+    block diffusion, 256 against 135 under causal; a window's rows share a
+    tile with the next."""
+    from distributed_llm_training_benchmark_framework_tpu.ops import flash_attention as fa
+
+    _, workload, file = manifest.load_cell(cell)
+    config = manifest.resolve(file["builder"])(workload, file)
+    S = workload["seq_len"]
+    if kind is None:
+        stats, S = attention_mixer.bd_mask_stats(config, S), 2 * S
+        rule = config.mask_rule(S)
+    else:
+        stats, rule = attention_mixer.attn_mask_stats(config, S)[kind], config.mask_rule(S, kind)
+        assert (stats["fwd_live_tiles"], stats["bwd_live_tiles"]) == (live, live)
+    assert (stats["fwd_grid_steps"], stats["bwd_grid_steps"]) == steps
+    assert (stats["fwd_tile_fetches"], stats["bwd_tile_fetches"]) == fetches
+    bq, bk, bk_bwd, _ = fa.pick_tiles(S, config.qk_dim, config.compute_dtype, causal=rule)
+    tiles = fa.tiles_by_shape(rule, S, bq, bk, fa._fwd_sub_k(bk))
+    assert sum(int(t.sum()) for t in tiles.values()) == live
+    assert all(live - S // bq < n <= live for n in fetches)
